@@ -44,6 +44,20 @@ def make_batches(dict_size, n, batch_size, max_src=32, max_trg=33):
     return batches
 
 
+def make_train_step(m, opt):
+    """The jitted Adam step over ``m.loss``: (params, opt_state, batch) ->
+    (loss, params, opt_state).  chip_smoke.py drives this same step at the
+    flagship's full width."""
+
+    @jax.jit
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(m.loss)(params, batch)
+        params, opt_state = opt.update(params, grads, opt_state)
+        return loss, params, opt_state
+
+    return step
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--passes", type=int, default=1)
@@ -63,13 +77,7 @@ def main(argv=None):
     params = m.init(jax.random.PRNGKey(0))
     opt = Adam(learning_rate=1e-3)
     opt_state = opt.init_state(params)
-
-    @jax.jit
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(m.loss)(params, batch)
-        params, opt_state = opt.update(params, grads, opt_state)
-        return loss, params, opt_state
-
+    step = make_train_step(m, opt)
     batches = make_batches(args.dict_size, args.n, args.batch_size)
     for pass_id in range(args.passes):
         t0 = time.time()

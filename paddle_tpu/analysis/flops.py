@@ -54,31 +54,34 @@ CHIP_HBM_BYTES = (
 )
 
 
-def _chip_lookup(kind: str, table, default) -> Optional[float]:
+def _chip_lookup(kind: str, table) -> Optional[float]:
+    """None off-TPU; a TPU generation the table does not name is an
+    error — a peak borrowed from another chip makes every share computed
+    from it wrong without saying so."""
     k = (kind or "").lower()
     if "tpu" not in k:
         return None
     for sub, val in table:
         if sub in k:
             return val
-    return default
+    raise ValueError(
+        f"unknown TPU device_kind {kind!r}: add its peaks to "
+        f"paddle_tpu/analysis/flops.py with their source")
 
 
 def chip_peak_flops(kind: str) -> Optional[float]:
-    """Peak dense FLOP/s for a ``device_kind`` string; None off-TPU
-    (an unknown TPU generation assumes v5e rather than dividing by 0)."""
-    return _chip_lookup(kind, CHIP_PEAK_FLOPS, 197e12)
+    """Peak dense FLOP/s for a ``device_kind`` string; None off-TPU."""
+    return _chip_lookup(kind, CHIP_PEAK_FLOPS)
 
 
 def chip_peak_bandwidth(kind: str) -> Optional[float]:
     """Peak HBM bytes/s for a ``device_kind`` string; None off-TPU."""
-    return _chip_lookup(kind, CHIP_PEAK_BW, 819e9)
+    return _chip_lookup(kind, CHIP_PEAK_BW)
 
 
 def chip_hbm_bytes(kind: str) -> Optional[float]:
-    """HBM bytes per chip for a ``device_kind`` string; None off-TPU
-    (an unknown TPU generation assumes v5e)."""
-    return _chip_lookup(kind, CHIP_HBM_BYTES, 16 * _GiB)
+    """HBM bytes per chip for a ``device_kind`` string; None off-TPU."""
+    return _chip_lookup(kind, CHIP_HBM_BYTES)
 
 
 def count_jaxpr_flops(jaxpr) -> float:

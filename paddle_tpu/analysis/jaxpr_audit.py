@@ -188,10 +188,11 @@ def _check_unsharded(closed, label, ctx) -> List[Finding]:
 
 
 def _block_dims(block_shape) -> List[Optional[int]]:
-    dims: List[Optional[int]] = []
-    for d in block_shape:
-        dims.append(int(d) if isinstance(d, (int, np.integer)) else None)
-    return dims
+    # jax 0.9.0 block dims are pallas ``Blocked(block_size=n)`` objects;
+    # squeezed / element-indexed dims carry no tile size to check
+    sizes = [getattr(d, "block_size", None) for d in block_shape]
+    return [int(n) if isinstance(n, (int, np.integer)) else None
+            for n in sizes]
 
 
 def _check_pallas_tiles(closed, label, ctx) -> List[Finding]:
@@ -206,7 +207,7 @@ def _check_pallas_tiles(closed, label, ctx) -> List[Finding]:
             continue
         for bm in mappings:
             dims = _block_dims(getattr(bm, "block_shape", ()))
-            arr = getattr(getattr(bm, "array_shape_dtype", None), "shape", None)
+            arr = getattr(getattr(bm, "array_aval", None), "shape", None)
             if len(dims) < 2:
                 continue
             bad = []

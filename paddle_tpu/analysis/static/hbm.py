@@ -149,13 +149,13 @@ def audit_hbm_jaxpr(closed, *, donate_argnums: Sequence[int] = (),
     findings: List[Finding] = []
     stats = peak_live_bytes(closed, donate_argnums)
     peak = stats["peak_bytes"]
-    cap = None
     try:
         import jax
 
-        cap = chip_hbm_bytes(jax.devices()[0].device_kind)
+        kind = jax.devices()[0].device_kind
     except Exception:  # no backend at all: report the estimate bare
-        cap = None
+        kind = ""
+    cap = chip_hbm_bytes(kind)  # an unknown TPU raises — see flops.py
     msg = (f"static peak live {_fmt_bytes(peak)} (args "
            f"{_fmt_bytes(stats['args_bytes'])}, consts "
            f"{_fmt_bytes(stats['consts_bytes'])}, outputs "

@@ -21,9 +21,11 @@ Entries are keyed by model fingerprint + exact feed signature and
 self-describe their platform + jax version; a stale or corrupt entry is a
 LOGGED MISS that falls back to a fresh compile — never a crash, never a
 wrong executable (the loaded callable is smoke-called once before it is
-trusted).  When the backend cannot serialize executables at all,
-:func:`wire_jax_compilation_cache` falls back to JAX's own persistent
-compilation-cache directory so repeat boots still skip XLA proper.
+trusted).  An entry records the devices its executable was compiled for
+and is loaded for exactly those, so a sound entry is never rejected on a
+host with more devices than the program uses.  What goes into an entry is
+always compiled here and now (:func:`compile_fresh`), never handed over by
+JAX's own persistent compilation cache.
 """
 
 from __future__ import annotations
@@ -33,6 +35,7 @@ import json
 import os
 import pickle
 import tempfile
+import threading
 import zipfile
 import zlib
 from typing import Any, Callable, Dict, List, Optional
@@ -41,25 +44,12 @@ from paddle_tpu.utils.log import logger
 
 __all__ = ["CompileCacheDir", "BundleAotCache", "ChainCache",
            "cache_key", "platform_fingerprint", "open_cache",
-           "serialization_supported", "wire_jax_compilation_cache",
-           "warm_bundle", "AOT_PREFIX"]
+           "warm_bundle", "compile_fresh", "AOT_PREFIX"]
 
 _AOTX_MAGIC = "paddle_tpu.aotx.v1"
 #: zip member prefix for executables embedded in a .ptz bundle
 AOT_PREFIX = "aot/"
 _SUFFIX = ".aotx"
-
-
-def serialization_supported() -> bool:
-    """Whether this jax can serialize AOT executables at all (the storage
-    layer probes per-executable too — some backends import fine but fail
-    at serialize time)."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
 
 
 def platform_fingerprint() -> str:
@@ -70,6 +60,35 @@ def platform_fingerprint() -> str:
 
     dev = jax.devices()[0]
     return f"{jax.default_backend()}:{dev.device_kind}"
+
+
+_fresh_lock = threading.Lock()
+
+
+def compile_fresh(lowered):
+    """``lowered.compile()`` with JAX's persistent compilation cache out of
+    the way — how every executable destined for an ``.aotx`` entry is
+    compiled.  An executable that cache *served* is not the compiler's own
+    output: XLA:CPU of jaxlib 0.9.0 re-serializes one into a payload that
+    loads and then fails on its first call ("Function ... not found"), so
+    the entry written from it was rejected by its smoke call on the next
+    boot and a warm boot silently became a cold one.  ``.aotx`` is these
+    programs' cache; JAX's is neither read nor written for them.  JAX
+    memoizes whether its cache is in use, hence the resets; a compile
+    that another thread runs inside the window merely skips that cache."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with _fresh_lock:
+        if not jax.config.jax_enable_compilation_cache:
+            return lowered.compile()
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            return lowered.compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            cc.reset_cache()
 
 
 def cache_key(kind: str, *parts: Any) -> str:
@@ -93,6 +112,10 @@ def _encode_entry(compiled, *, key: str, label: str) -> bytes:
         "label": label,
         "platform": platform_fingerprint(),
         "jax": jax.__version__,
+        # the devices the executable runs on, in assignment order — it is
+        # loaded for these and no others (see _decode_entry)
+        "devices": [d.id for d in
+                    compiled._executable._unloaded_executable.device_list],
         "crc32": zlib.crc32(body),
     }
     return json.dumps(header).encode() + b"\n" + body
@@ -126,6 +149,14 @@ def _decode_entry(blob: bytes, *, key: str, where: str
                      f"{platform_fingerprint()!r}")
     if header.get("jax") != jax.__version__:
         stale.append(f"jax {header.get('jax')!r} != {jax.__version__!r}")
+    # load for the devices the executable was compiled for: left to its
+    # default, jax loads it for EVERY device of the backend, and a
+    # one-device program then demands one shard per device at call time
+    by_id = {d.id: d for d in jax.devices()}
+    device_ids = header.get("devices")
+    if not device_ids or any(i not in by_id for i in device_ids):
+        stale.append(f"compiled for devices {device_ids!r}, backend has "
+                     f"{sorted(by_id)!r}")
     if stale:
         logger.warning("compile cache: %s is stale (%s) — recompiling",
                        where, "; ".join(stale))
@@ -138,7 +169,9 @@ def _decode_entry(blob: bytes, *, key: str, where: str
         from jax.experimental import serialize_executable as se
 
         payload, in_tree, out_tree = pickle.loads(body)
-        return se.deserialize_and_load(payload, in_tree, out_tree)
+        return se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids])
     except Exception as e:  # noqa: BLE001 — a bad entry must never crash
         logger.warning("compile cache: %s failed to deserialize (%s: %s) "
                        "— recompiling", where, type(e).__name__, e)
@@ -176,14 +209,11 @@ class _CacheBase:
         return fn
 
     def store(self, key: str, compiled, *, label: str = "") -> bool:
-        if not serialization_supported():
-            return False
         try:
             blob = _encode_entry(compiled, key=key, label=label)
         except Exception as e:  # noqa: BLE001 — backend can't serialize
             logger.warning("compile cache: executable %r not serializable "
-                           "on this backend (%s: %s) — not cached; consider "
-                           "wire_jax_compilation_cache()", label,
+                           "on this backend (%s: %s) — not cached", label,
                            type(e).__name__, e)
             return False
         return self._write(key, blob)
@@ -328,13 +358,8 @@ def open_cache(bundle: Optional[str] = None, cache_dir: str = ""
                ) -> Optional[_CacheBase]:
     """The serve-CLI policy: read bundle-embedded ``aot/`` members when
     the bundle carries any (read-only — a fleet shares the artifact),
-    plus a writable ``--compile_cache_dir``.  Returns None (with the JAX
-    persistent compilation cache wired instead, when a dir was given)
-    if this backend cannot serialize executables."""
-    if not serialization_supported():
-        if cache_dir:
-            wire_jax_compilation_cache(cache_dir)
-        return None
+    plus a writable ``--compile_cache_dir``.  Returns None when neither
+    layer exists."""
     layers: List[_CacheBase] = []
     if bundle:
         b = BundleAotCache(bundle)
@@ -345,33 +370,6 @@ def open_cache(bundle: Optional[str] = None, cache_dir: str = ""
     if not layers:
         return None
     return layers[0] if len(layers) == 1 else ChainCache(layers)
-
-
-def wire_jax_compilation_cache(cache_dir: str) -> bool:
-    """Fallback when executable serialization is unsupported on the
-    backend: point JAX's own persistent compilation cache at
-    ``cache_dir`` (and drop its min-compile-time/entry-size gates so
-    warmup-sized programs qualify).  Weaker than aotx entries — tracing
-    and executable load still run — but repeat boots skip XLA proper."""
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(cache_dir))
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                          ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # noqa: BLE001 — knob renamed across versions
-                pass
-        logger.info("compile cache: executable serialization unavailable; "
-                    "wired jax persistent compilation cache at %r",
-                    cache_dir)
-        return True
-    except Exception as e:  # noqa: BLE001 — advisory fallback
-        logger.warning("compile cache: could not wire jax compilation "
-                       "cache (%s: %s)", type(e).__name__, e)
-        return False
 
 
 def warm_bundle(bundle_path: str, *, max_batch: int = 8,

@@ -533,8 +533,14 @@ class InferenceModel:
             fn = cache.load(key)
             if fn is not None and self._install_aot(k, fn, feed):
                 return "hit"
-        compiled = jax.jit(self._make_run(names)).lower(
-            self.params, self.state, feed).compile()
+        lowered = jax.jit(self._make_run(names)).lower(
+            self.params, self.state, feed)
+        if cache is not None:
+            from paddle_tpu.config.compile_cache import compile_fresh
+
+            compiled = compile_fresh(lowered)
+        else:
+            compiled = lowered.compile()
         self.compile_events += 1
         self._aot[k] = compiled
         if cache is not None:
@@ -549,7 +555,9 @@ class InferenceModel:
         from paddle_tpu.utils import logger
 
         try:
-            out = fn(self.params, self.state, feed)
+            # wait for the result: dispatch is asynchronous, and an
+            # executable that cannot run reports it only when read
+            out = jax.block_until_ready(fn(self.params, self.state, feed))
             if set(out) != set(k[0]):
                 raise ValueError(f"output names {sorted(out)} != "
                                  f"{sorted(k[0])}")

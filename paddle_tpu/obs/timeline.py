@@ -111,9 +111,10 @@ class StepTimeline:
         try:
             import jax
 
-            chip = chip_peak_flops(jax.devices()[0].device_kind)
-        except Exception:
+            kind = jax.devices()[0].device_kind
+        except Exception:  # no backend at all: no peak, no MFU
             return None
+        chip = chip_peak_flops(kind)  # an unknown TPU raises — see flops.py
         return None if chip is None else chip * max(1, int(n_devices))
 
     def set_devices(self, n_devices: int) -> None:

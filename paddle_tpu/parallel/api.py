@@ -103,14 +103,42 @@ def make_parallel_train_step(
         return make_hierarchical_train_step(loss_fn, optimizer, mesh,
                                             compress=False, donate=donate)
 
+    built = as_mesh(mesh)
+
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        # fused multi-tensor apply only without tensor-parallel rules:
+        # never the fused multi-tensor apply under tensor-parallel rules:
         # concatenating differently-sharded leaves mispartitions under
-        # GSPMD (see Optimizer.update's caller contract)
-        new_params, new_opt = optimizer.update(params, grads, opt_state,
-                                               fused=rules is None)
-        return loss, new_params, new_opt
+        # GSPMD (see Optimizer.update's caller contract); without rules
+        # --fused_apply decides, as it does in the trainer
+        new_params, new_opt = optimizer.update(
+            params, grads, opt_state,
+            fused=False if rules is not None else None)
+        # hand the state back placed as the rules place it: left to the
+        # partitioner, the outputs come back in shardings of its own
+        # choosing, and the next step recompiles for them
+        return loss, placed(new_params, params), placed(new_opt, params)
+
+    def placed(tree, params):
+        def one(path, leaf):
+            spec = P()
+            for entry in reversed(path):
+                name = getattr(entry, "key", None)
+                if (rules is not None and name in params
+                        and jnp.shape(leaf) == jnp.shape(params[name])):
+                    spec = rules.spec_for(name, leaf.ndim)
+                    break
+            return jax.lax.with_sharding_constraint(
+                leaf, NamedSharding(built, spec))
+
+        return jax.tree_util.tree_map_with_path(one, tree)
+
+    if built.size > 1:
+        # jit partitions this step over the mesh by itself, which Mosaic
+        # kernels do not survive: the gates keep to their XLA paths
+        from paddle_tpu.ops.pallas_kernels import xla_paths_only
+
+        step = xla_paths_only()(step)
 
     donate_argnums = (0, 1) if donate else ()
     return jax.jit(step, donate_argnums=donate_argnums)

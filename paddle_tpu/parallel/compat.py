@@ -1,47 +1,17 @@
-"""jax-version compatibility for the SPMD layer.
+"""The two SPMD names the parallel tier imports from one place.
 
-The parallel tier is written against the modern ``jax.shard_map`` surface
-(``check_vma=``, ``lax.axis_size``); the installed runtime may predate it
-(0.4.x ships ``jax.experimental.shard_map.shard_map`` with ``check_rep=``
-and no ``lax.axis_size``).  One shim, same policy as the
-``_compiler_params`` rename shim in ops/pallas_kernels.py: resolve the
-rename ONCE here so every shard_map call site stays written against the
-current API.
+``shard_map`` defaults ``check_vma`` off: the parallel bodies use manual
+collectives whose replication the checker cannot prove.
 """
 
 from __future__ import annotations
 
-from typing import Any
+import functools
 
 import jax
 from jax import lax
 
 __all__ = ["shard_map", "axis_size"]
 
-
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    ``check_vma`` (the modern name for replication checking) maps to the
-    legacy ``check_rep``; both default off here — the parallel bodies use
-    manual collectives whose replication the checker cannot prove.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
-
-def axis_size(name: str) -> Any:
-    """Static size of a named mesh axis from inside a shard_map body.
-
-    Legacy jax has no ``lax.axis_size``; ``lax.psum(1, name)`` of the
-    python constant 1 constant-folds to the same static int there, so the
-    result remains usable in shapes and fori_loop bounds.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
+shard_map = functools.partial(jax.shard_map, check_vma=False)
+axis_size = lax.axis_size

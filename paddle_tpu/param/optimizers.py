@@ -24,6 +24,10 @@ import jax.numpy as jnp
 
 from paddle_tpu.utils.registry import Registry
 
+#: lanes of the TPU's (8, 128) tile: an array whose minor dimension is not a
+#: multiple of this is stored padded, and raveling it moves data
+_LANES = 128
+
 __all__ = [
     "dedup_rows",
     "Optimizer",
@@ -252,6 +256,14 @@ class Optimizer:
                         and sparse_rows.get(k) is not False:
                     continue
                 if not hasattr(p, "dtype") or p.size == 0:
+                    continue
+                if p.ndim >= 2 and p.shape[-1] % _LANES:
+                    # raveling it is a relayout on the TPU, not a view, and
+                    # one such leaf (an fc [512, 2]) in the segment made
+                    # the TPU compiler spend time in proportion to the
+                    # whole segment: 40 s per million elements, 351 s for
+                    # the LSTM text classifier's step (jaxlib 0.9.0 /
+                    # libtpu 0.0.34, compile-only).  It keeps its own chain
                     continue
                 key = (str(p.dtype),
                        lr_scales.get(k, 1.0) if lr_scales else 1.0,

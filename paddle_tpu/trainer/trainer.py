@@ -488,7 +488,13 @@ class SGDTrainer:
             # params/opt slots were placed ONCE at init (or after load) with
             # their rule-derived shardings; the jitted step consumes and
             # donates them in place — no per-batch host re-placement
-            jitted = jax.jit(step, donate_argnums=(0, 2, 3))
+            # (jit partitions the step over the mesh by itself, which
+            # Mosaic kernels do not survive: their gates keep to XLA paths)
+            from paddle_tpu.ops.pallas_kernels import xla_paths_only
+
+            traced = (xla_paths_only()(step) if self.mesh.size > 1
+                      else step)
+            jitted = jax.jit(traced, donate_argnums=(0, 2, 3))
 
             def run(params, state, opt_state, ps, rng, feed):
                 feed = self._shard_feed(feed)

@@ -378,8 +378,10 @@ def _fuse_fixtures():
     global _FUSE_PARAMS
     if _FUSE_PARAMS is None:
         rs = np.random.RandomState(0)
-        shapes = [(4, 8), (8,), (3, 3, 2), (16,), (2, 2), (5, 5), (7,),
-                  (4, 4, 4), (10,), (6, 2), (8, 8), (3,)]
+        # vectors, and arrays whose minor dimension fills whole lanes:
+        # what the fused apply takes into a segment
+        shapes = [(4, 128), (8,), (3, 3, 128), (16,), (2, 256), (5, 128),
+                  (7,), (4, 4, 128), (10,), (6, 384), (8, 128), (3,)]
         params = {f"p{i}": jnp.asarray(rs.randn(*s).astype(np.float32))
                   for i, s in enumerate(shapes)}
         grads = {k: jnp.asarray(rs.randn(*v.shape).astype(np.float32))
@@ -449,6 +451,33 @@ def test_fused_apply_excludes_sparse_rows_and_matches():
                       sparse_rows={"emb": 8})
     for k in params:
         np.testing.assert_array_equal(np.asarray(pa[k]), np.asarray(pb[k]))
+
+
+def test_fused_apply_leaves_narrow_minor_dimensions_per_leaf():
+    """A leaf whose minor dimension does not fill whole lanes ([512, 2])
+    is stored padded on the TPU, so raveling it into the segment moves
+    data — and made the installed TPU compiler take minutes over one
+    trainer step.  It stays out of the segment (nothing of its size is
+    concatenated) and the result is still bit-identical."""
+    rs = np.random.RandomState(1)
+    shapes = {"w": (64, 128), "b": (128,), "fc_w": (64, 2), "fc_b": (2,)}
+    params = {k: jnp.asarray(rs.randn(*v).astype(np.float32))
+              for k, v in shapes.items()}
+    grads = {k: jnp.asarray(rs.randn(*v).astype(np.float32))
+             for k, v in shapes.items()}
+    opt = Adam(learning_rate=0.1)
+    s = opt.init_state(params)
+    jx = jax.make_jaxpr(lambda p, g, st: opt.update(p, g, st, fused=True))(
+        params, grads, s)
+    packed = [e for e in jx.jaxpr.eqns if e.primitive.name == "concatenate"]
+    assert packed
+    for e in packed:
+        assert sorted(v.aval.size for v in e.invars) == [2, 128, 64 * 128]
+    pa, sa = opt.update(params, grads, s, fused=False)
+    pb, sb = opt.update(params, grads, s, fused=True)
+    for x, y in zip(jax.tree_util.tree_leaves((pa, sa)),
+                    jax.tree_util.tree_leaves((pb, sb))):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 #: primitives that are pure data layout — XLA folds them into the

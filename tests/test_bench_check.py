@@ -159,10 +159,25 @@ def test_check_unknown_row_is_usage_error(tmp_path, capsys):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("argv", [[], ["--check", "--rows", "smallnet_b64"]],
+                         ids=["capture", "check"])
+def test_no_tpu_is_a_failure_not_a_cpu_capture(argv, capsys):
+    """A measurement path that finds no chip fails and names the device it
+    did find — it never prints CPU timings under device metric names."""
+    with pytest.raises(SystemExit) as e:
+        bench.main(argv)
+    assert "no TPU found" in str(e.value)
+    err = capsys.readouterr().err
+    assert '"platform": "cpu"' in err and '"count": 8' in err
+
+
 @pytest.mark.slow
-def test_check_end_to_end_smallnet(tmp_path, capsys):
+def test_check_end_to_end_smallnet(tmp_path, capsys, monkeypatch):
     """Measure smallnet_b64 against a generous baseline (rc 0), then
-    against an unbeatable one (rc 1) — the full gate wiring."""
+    against an unbeatable one (rc 1) — the full gate wiring (the TPU
+    requirement stepped over: this drives the wiring, not a timing)."""
+    monkeypatch.setattr(bench, "_require_tpu", lambda: {
+        "platform": "cpu", "kind": "cpu", "count": 1})
     good = tmp_path / "BENCH_r01.json"
     good.write_text(json.dumps(
         {"summary": {"smallnet_b64": [1e9, 1e-9, None]}}))

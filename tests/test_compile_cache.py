@@ -15,18 +15,12 @@ import pytest
 import paddle_tpu.nn as nn
 from paddle_tpu.config import load_inference_model, merge_model, warm_bundle
 from paddle_tpu.config.compile_cache import (BundleAotCache, CompileCacheDir,
-                                             cache_key, open_cache,
-                                             serialization_supported)
+                                             cache_key, open_cache)
 from paddle_tpu.param.optimizers import Adam
 from paddle_tpu.resilience import chaos
 from paddle_tpu.serving.server import InferenceServer
 from paddle_tpu.serving.slots import example_slot_backend
 from paddle_tpu.trainer import SGDTrainer
-
-pytestmark = pytest.mark.skipif(
-    not serialization_supported(),
-    reason="this jax cannot serialize AOT executables")
-
 
 def _bundle(tmp_path, rng, quantize=None, name="cc"):
     nn.reset_naming()
@@ -75,6 +69,39 @@ def test_cache_dir_roundtrip_and_counters(tmp_path):
                                   np.full((4,), 3.0, np.float32))
     # a different key never returns this entry
     assert cache.load(cache_key("unit", "fp", "other")) is None
+
+
+def test_one_device_entry_loads_on_a_many_device_backend(tmp_path):
+    """The shape of the jax 0.9.0 bug: an executable compiled for ONE of the
+    backend's eight devices must load for that device alone — loaded for
+    every device it demands eight argument shards at call time and the
+    sound entry is rejected.  Also holds for a device other than 0, and an
+    entry naming a device this backend lacks is a stale miss."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    assert len(jax.devices()) == 8
+    cache = CompileCacheDir(str(tmp_path / "cache"))
+    for dev in (jax.devices()[0], jax.devices()[5]):
+        x = jax.device_put(jnp.ones((4,)), dev)
+        compiled = jax.jit(lambda x: x * 3).lower(x).compile()
+        key = cache_key("unit", "one-device", dev.id)
+        assert cache.store(key, compiled, label="unit")
+        head = json.loads(open(cache._path(key), "rb").read().split(b"\n")[0])
+        assert head["devices"] == [dev.id]
+        fn = cache.load(key)
+        assert fn is not None
+        out = fn(x)
+        assert out.devices() == {dev}
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.full((4,), 3.0, np.float32))
+    path = cache._path(key)
+    head_raw, body = open(path, "rb").read().split(b"\n", 1)
+    moved = dict(json.loads(head_raw), devices=[64])
+    open(path, "wb").write(json.dumps(moved).encode() + b"\n" + body)
+    assert cache.load(key) is None
 
 
 def test_cache_entry_staleness_and_corruption(tmp_path):
@@ -301,6 +328,82 @@ def test_generation_slot_closures_cache(tmp_path):
     assert warm * 3 <= cold, f"warm {warm:.3f}s vs cold {cold:.3f}s"
     np.testing.assert_array_equal(out1["tokens"], out2["tokens"])
     np.testing.assert_array_equal(out1["scores"], out2["scores"])
+
+
+@pytest.fixture
+def jax_cache_serves_everything(tmp_path):
+    """JAX's persistent compilation cache in a directory of the test's own
+    with its store threshold at zero, so every program compiled through it
+    is stored and served from then on; yields the hit counter."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    keep = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "jaxcc"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cc.reset_cache()
+    seen = {"hits": 0}
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            seen["hits"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        yield seen
+    finally:
+        from jax._src import monitoring
+
+        monitoring.unregister_event_listener(on_event)
+        jax.config.update("jax_compilation_cache_dir", keep[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          keep[1])
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("mode", ["bucket", "generation"])
+def test_aotx_entries_sound_when_jax_cache_holds_the_programs(
+        tmp_path, rng, jax_cache_serves_everything, mode):
+    """An executable JAX's persistent cache SERVED re-serializes (XLA:CPU,
+    jaxlib 0.9.0) into an entry that loads and fails its first call, so
+    the next boot logged "rejected by its smoke call" and went cold.  The
+    server's programs are therefore compiled past that cache
+    (``compile_fresh``): with every one of them sitting in JAX's cache, the
+    first ``.aotx`` boot still stores sound entries and the second boot
+    loads them all."""
+    seen = jax_cache_serves_everything
+    if mode == "bucket":
+        bundle = _bundle(tmp_path, rng)
+
+        def boot(cache):
+            srv, model, _ = _boot(bundle, cache)
+            return srv, model
+    else:
+        def boot(cache):
+            return _boot_generation(cache)[0], None
+
+    # no .aotx cache: the programs go through JAX's cache, twice — the
+    # second boot is served from it, so it does hold them
+    boot(None)[0].close()
+    before = seen["hits"]
+    boot(None)[0].close()
+    assert seen["hits"] > before, "JAX's cache served nothing"
+
+    cache_dir = str(tmp_path / "aotx")
+    srv1, _ = boot(CompileCacheDir(cache_dir))
+    hz1 = srv1.healthz()["cold_start"]
+    srv1.close()
+    assert hz1["compile_cache_misses"] > 0
+
+    srv2, model2 = boot(CompileCacheDir(cache_dir))
+    hz2 = srv2.healthz()["cold_start"]
+    srv2.close()
+    assert hz2["compile_cache_misses"] == 0, "a stored entry was rejected"
+    assert hz2["compile_cache_hits"] == hz1["compile_cache_misses"]
+    assert hz2["warmup_compiles"] == 0
+    if model2 is not None:
+        assert model2.compile_events == 0
 
 
 def test_slot_prime_is_idempotent_across_caches(tmp_path):

@@ -236,7 +236,8 @@ def test_chip_peak_tables_resolve_tpu_kinds_only():
 
     assert chip_peak_flops("TPU v5e") == 197e12
     assert chip_peak_flops("TPU v4") == 275e12
-    assert chip_peak_flops("TPU v99") == 197e12    # unknown TPU: assume v5e
+    with pytest.raises(ValueError, match="unknown TPU"):
+        chip_peak_flops("TPU v99")                 # never another chip's peak
     assert chip_peak_flops("cpu") is None          # off-TPU: no peak
     assert chip_peak_bandwidth("TPU v4") == 1228e9
     assert chip_peak_bandwidth("Host CPU") is None

@@ -138,10 +138,10 @@ class TestBackwardKernels:
 
         # reference: identical function with the scan backward (gate off)
         monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H: False)
+                            lambda B, H, gates: False)
         g_ref = jax.grad(obj(lstm_sequence_fused), (0, 1))(xp, w_h)
         monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H: True)
+                            lambda B, H, gates: True)
         g_pal = jax.grad(obj(lstm_sequence_fused), (0, 1))(xp, w_h)
         for a, b in zip(g_ref, g_pal):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -162,10 +162,10 @@ class TestBackwardKernels:
             return f
 
         monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H: False)
+                            lambda B, H, gates: False)
         g_ref = jax.grad(obj(), (0, 1))(xp, w_h)
         monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H: True)
+                            lambda B, H, gates: True)
         g_pal = jax.grad(obj(), (0, 1))(xp, w_h)
         for a, b in zip(g_ref, g_pal):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -1,0 +1,286 @@
+"""The main path's kernels, compiled at real widths for a TPU v5e that is
+described, not attached (section 2 of the on-chip-measurement guide).
+
+Nothing runs: each case asserts that the installed TPU compiler accepts the
+program and that a Mosaic kernel (``tpu_custom_call``) is in the compiled
+text — interpret mode passes shapes the chip's compiler refuses (scoped VMEM,
+tile alignment).  The topology is described inside a module-scoped fixture
+(never at import: only one process may load libtpu, and every xdist worker
+imports this file), the compiles run in the test's own process, and JAX's
+persistent cache is off around them (such an entry cannot be read back
+without a chip).  The gates ask ``jax.default_backend()``; the tests steer
+them with monkeypatch, and compile under the production bf16 policy.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture
+def no_persistent_jax_cache():
+    """These compiles ask the TPU compiler a question; an answer served
+    from JAX's persistent cache would not be the compiler's."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+pytestmark = pytest.mark.usefixtures("no_persistent_jax_cache")
+
+T_RNN = 100
+#: benchmark/README's LSTM rows (bench.py) + the remat row's B512
+RNN_SHAPES = [(64, 256), (64, 512), (64, 1280), (128, 256), (256, 256),
+              (512, 256)]
+B, S, T, D, V = 384, 32, 32, 512, 30000   # the flagship train step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Gates take their TPU branch, under the production compute policy."""
+    from paddle_tpu.utils.flags import FLAGS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(FLAGS, "compute_dtype", "bfloat16")
+
+
+def _struct(one_chip, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _kernels(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; Mosaic kernels in its text."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+@pytest.mark.parametrize("batch,hidden", RNN_SHAPES,
+                         ids=[f"b{b}h{h}" for b, h in RNN_SHAPES])
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_rnn_forward_and_gradient(cell, batch, hidden, one_chip, on_tpu):
+    """Every published row: both gates admit it, and the forward and the
+    reverse time-loop kernels compile (peepholes live — the widest LSTM
+    variant)."""
+    from paddle_tpu.ops import rnn, rnn_fused
+
+    gates = 4 if cell == "lstm" else 3
+    assert rnn._use_pallas_rnn(batch, hidden, gates)
+    assert rnn_fused._bwd_pallas_ok(batch, hidden, gates)
+    zeros = jnp.zeros((batch, hidden), jnp.float32)
+
+    def lstm_loss(xp, mask, w_h, pi, pf, po):
+        h, h_f, c_f = rnn_fused.lstm_sequence_fused(
+            xp, mask, w_h, zeros, zeros, pi, pf, po, True, True)
+        return h.sum() + h_f.sum() + c_f.sum()
+
+    def gru_loss(xp, mask, w_h):
+        h, h_f = rnn_fused.gru_sequence_fused(xp, mask, w_h, zeros, True)
+        return h.sum() + h_f.sum()
+
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+    args = [s(batch, T_RNN, gates * hidden), s(batch, T_RNN),
+            s(hidden, gates * hidden)]
+    if cell == "lstm":
+        args += [s(hidden)] * 3
+        fn = jax.value_and_grad(lstm_loss, argnums=(0, 2, 3, 4, 5))
+    else:
+        fn = jax.value_and_grad(gru_loss, argnums=(0, 2))
+    assert _kernels(fn, *args) == 2
+
+
+def test_rnn_gate_bounds_the_resident_weight(on_tpu):
+    """What the gate admits it has counted: the [H, gates*H] weight grows
+    with H^2 and is refused once it alone outgrows the scoped limit, however
+    small B*H is; the estimate matches what the compiler reports at the
+    shape it used to refuse (LSTM reverse kernel, B64 H1280: 33.76 MiB)."""
+    from paddle_tpu.ops import rnn
+    from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
+                                               rnn_vmem_bytes)
+
+    need = rnn_vmem_bytes(64, 1280, 4, backward=True, residual_itemsize=4)
+    assert abs(need / 2**20 - 33.76) < 0.35
+    assert need < RNN_VMEM_LIMIT_BYTES
+    assert rnn._use_pallas_rnn(72, 1792, 4, backward=True)
+    assert not rnn._use_pallas_rnn(8, 2048, 4)            # 64 MiB of weight
+    assert rnn._use_pallas_rnn(96, 2048, 3, backward=True)
+    assert not rnn._use_pallas_rnn(392, 512, 3)           # past B*H cap
+    assert not rnn._use_pallas_rnn(64, 200, 4)            # lane-misaligned
+
+
+@pytest.mark.parametrize("batch,hidden,cell", [
+    (152, 1280, "lstm"), (72, 1792, "lstm"), (384, 512, "lstm"),
+    (96, 2048, "gru"), (16, 2304, "gru")])
+def test_rnn_gate_edge_compiles(batch, hidden, cell, one_chip, on_tpu):
+    """The largest shapes the reverse-kernel gate admits at a few widths:
+    every shape a gate admits must compile, not only the published ones."""
+    from paddle_tpu.ops import rnn, rnn_fused
+
+    gates = 4 if cell == "lstm" else 3
+    assert rnn_fused._bwd_pallas_ok(batch, hidden, gates)
+    assert not rnn._use_pallas_rnn(batch + 8, hidden, gates, backward=True)
+    zeros = jnp.zeros((batch, hidden), jnp.float32)
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+
+    def loss(xp, mask, w_h, *peep):
+        if cell == "lstm":
+            out = rnn_fused.lstm_sequence_fused(
+                xp, mask, w_h, zeros, zeros, *peep, True, True)
+        else:
+            out = rnn_fused.gru_sequence_fused(xp, mask, w_h, zeros, True)
+        return sum(o.sum() for o in out)
+
+    args = [s(batch, 20, gates * hidden), s(batch, 20),
+            s(hidden, gates * hidden)]
+    args += [s(hidden)] * 3 if cell == "lstm" else []
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 2)), *args) == 2
+
+
+@pytest.mark.parametrize("policy,batch,hidden", [
+    ("bfloat16", 512, 512), ("float32", 512, 512), ("float32", 256, 1024),
+    ("float32", 140, 1280)])
+def test_bigru_gate_edge_compiles(policy, batch, hidden, one_chip, on_tpu,
+                                  monkeypatch):
+    """The fused bidirectional GRU (--use_pallas_bigru, off by default): the
+    largest 2B-row batch its gate admits compiles, forward and reverse, in
+    both dtype policies, and the next step up is refused (B768 H512 is
+    what the float32 policy's reverse kernel does not fit)."""
+    from paddle_tpu.ops import rnn_fused
+    from paddle_tpu.utils.flags import FLAGS
+
+    monkeypatch.setattr(FLAGS, "compute_dtype", policy)
+    monkeypatch.setattr(FLAGS, "use_pallas_bigru", True)
+    assert rnn_fused._use_pallas_bigru(batch, hidden)
+    assert not rnn_fused._use_pallas_bigru(batch + 4, hidden)
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+
+    def loss(xp2, mask2, w_fw, w_bw):
+        h, h_f = rnn_fused.bigru_sequence_fused(xp2, mask2, w_fw, w_bw, batch)
+        return h.sum() + h_f.sum()
+
+    args = [s(2 * batch, 20, 3 * hidden), s(2 * batch, 20),
+            s(hidden, 3 * hidden), s(hidden, 3 * hidden)]
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 2, 3)), *args) == 2
+
+
+def test_attention_decoder_forward_and_backward(one_chip, on_tpu):
+    """The fused attention-GRU decoder at the flagship's train shape."""
+    from paddle_tpu.ops.attention_decoder import (_attn_pallas_block,
+                                                  attention_gru_decoder)
+
+    assert _attn_pallas_block(B, S, D, D, 2 * D) is not None
+    s = lambda *shape, dt=jnp.float32: _struct(one_chip, shape, dt)  # noqa: E731
+
+    def loss(y_emb, s0, enc, enc_proj, att_w, att_v, wx, b, wh, smask, tmask):
+        return attention_gru_decoder(y_emb, s0, enc, enc_proj, smask, tmask,
+                                     att_w, att_v, wx, b, wh).sum()
+
+    n = _kernels(
+        jax.value_and_grad(loss, argnums=tuple(range(9))),
+        s(B, T, D), s(B, D), s(B, S, 2 * D, dt=jnp.bfloat16),
+        s(B, S, D, dt=jnp.bfloat16), s(D, D), s(D), s(D + 2 * D, 3 * D),
+        s(3 * D), s(D, 3 * D), s(B, S), s(B, T))
+    assert n >= 2
+
+
+def test_vocab_tiled_ce_forward_and_backward(one_chip, on_tpu):
+    """The fused readout + token CE over the 30k vocabulary."""
+    from paddle_tpu.ops.losses import (_tiled_ce_cfg,
+                                       sequence_softmax_ce_readout)
+
+    assert _tiled_ce_cfg(B, T, D, V) is not None
+    n = _kernels(
+        jax.value_and_grad(sequence_softmax_ce_readout, argnums=(0, 1, 2)),
+        _struct(one_chip, (B, T, D)), _struct(one_chip, (D, V)),
+        _struct(one_chip, (V,)), _struct(one_chip, (B, T), jnp.int32),
+        _struct(one_chip, (B, T)))
+    assert n >= 2
+
+
+def test_ce_gate_counts_the_weight_tiles(one_chip, on_tpu):
+    """The backward keeps a double-buffered w tile and d_w tile that grow
+    with D, not with N: the two shapes the compiler refuses (130.9 and
+    131.1 MiB of a 128 MiB VMEM) are gated off, and the largest D=2048
+    shape still admitted compiles."""
+    from paddle_tpu.ops.losses import (_tiled_ce_cfg,
+                                       sequence_softmax_ce_readout)
+
+    assert _tiled_ce_cfg(192, 32, 2048, V) is None     # N=6144
+    assert _tiled_ce_cfg(96, 32, 4096, V) is None      # N=3072
+    assert _tiled_ce_cfg(160, 32, 2048, V) is not None  # N=5120
+    n = _kernels(
+        jax.value_and_grad(sequence_softmax_ce_readout, argnums=(0, 1, 2)),
+        _struct(one_chip, (160, 32, 2048)), _struct(one_chip, (2048, V)),
+        _struct(one_chip, (V,)), _struct(one_chip, (160, 32), jnp.int32),
+        _struct(one_chip, (160, 32)))
+    assert n >= 2
+
+
+def test_topk_lse_readout(one_chip, on_tpu):
+    """Beam-3 over B=64: the top-k + logsumexp readout at B*K = 192 rows."""
+    from paddle_tpu.ops.decode import LinearReadout, decode_kernel_config
+
+    assert decode_kernel_config(192, D, V, 3) is not None
+    n = _kernels(lambda st, w, b: LinearReadout(w, b)(st, 3),
+                 _struct(one_chip, (192, D)), _struct(one_chip, (D, V)),
+                 _struct(one_chip, (V,)))
+    assert n == 1
+
+
+def test_slot_table_decode_step(one_chip, on_tpu):
+    """The generation server's step program over the full-width flagship:
+    weights as arguments, 8 slots x beam 3 (SlotScheduler's step_prog)."""
+    from paddle_tpu.models import Seq2SeqAttention
+    from paddle_tpu.ops.decode import decode_step, init_slot_carry
+    from paddle_tpu.serving.slots import Seq2SeqSlotBackend
+
+    m = Seq2SeqAttention()
+    shapes = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    backend = Seq2SeqSlotBackend(m, shapes, src_len=S, beam_size=3,
+                                 max_len=32)
+    state = jax.eval_shape(lambda p, f: backend.with_params(p).prefill(f),
+                           shapes, backend.example_feed(1))
+    carry = jax.eval_shape(lambda: init_slot_carry(
+        state, slots=8, beam_size=3, max_len=32, eos=backend.eos))
+
+    def step(p, c):
+        b = backend.with_params(p)
+        return decode_step(b.step_fn, b.readout, c,
+                           vocab_size=backend.vocab_size, eos=backend.eos,
+                           use_kernel=backend.use_kernel)
+
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _struct(one_chip, a.shape, a.dtype), tree)
+    compiled = jax.jit(step).lower(place(shapes), place(carry)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+    # the weights ride as arguments: none is folded into the executable
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+    assert compiled.memory_analysis().generated_code_size_in_bytes \
+        < weights // 8

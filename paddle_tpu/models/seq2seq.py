@@ -126,20 +126,27 @@ class Seq2SeqAttention:
         S, T = src_ids.shape[1], trg_in.shape[1]
         src_mask = O.mask_from_lengths(src_len, S)
         trg_mask = O.mask_from_lengths(trg_len, T)
-        enc, enc_proj, s0 = self.encode(params, src_ids, src_mask)
-        y_emb = O.embedding_lookup(params["trg_emb"], trg_in)  # [B,T,E]
-        # fused-backward decoder: same math as scanning _dec_step, but with
-        # a hand-written VJP that batches the big cotangent contractions
-        # after the reverse scan (see ops/attention_decoder.py; ~2x faster
-        # backward at WMT14 shapes on v5e than XLA's scan autodiff)
-        states = attention_gru_decoder(
-            y_emb, s0, enc, enc_proj, src_mask, trg_mask,
-            params["att_dec_w"], params["att_v"], params["dec_wx"],
-            params["dec_b"], params["dec_wh"])  # [B,T,D]
+        # named_scope: the device trace names every operation of the step by
+        # these three (and their transpose(jvp(...)) forms in the backward);
+        # benchmark/trace_scopes.py reads them (docs/observability.md)
+        with jax.named_scope("encoder"):
+            enc, enc_proj, s0 = self.encode(params, src_ids, src_mask)
+        with jax.named_scope("decoder"):
+            y_emb = O.embedding_lookup(params["trg_emb"], trg_in)  # [B,T,E]
+            # fused-backward decoder: same math as scanning _dec_step, but
+            # with a hand-written VJP that batches the big cotangent
+            # contractions after the reverse scan (see
+            # ops/attention_decoder.py; ~2x faster backward at WMT14 shapes
+            # on v5e than XLA's scan autodiff)
+            states = attention_gru_decoder(
+                y_emb, s0, enc, enc_proj, src_mask, trg_mask,
+                params["att_dec_w"], params["att_v"], params["dec_wx"],
+                params["dec_b"], params["dec_wh"])  # [B,T,D]
         # fused readout+CE: the [B,T,30k] logits buffer stays in the bf16
         # compute dtype (the f32 version dominates HBM traffic otherwise)
-        return O.sequence_softmax_ce_readout(
-            states, params["out_w"], params["out_b"], trg_next, trg_mask)
+        with jax.named_scope("readout_ce"):
+            return O.sequence_softmax_ce_readout(
+                states, params["out_w"], params["out_b"], trg_next, trg_mask)
 
     # ------------------------------------------------------------------
     # generation — both paths drive the fused decode engine (ops/decode.py):

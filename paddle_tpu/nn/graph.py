@@ -376,7 +376,8 @@ class Topology:
         want = set(outputs) if outputs is not None else None
         needed = self.layers if want is None else self._needed_layers(want)
         for layer in needed:
-            with layer_scope(layer.name):
+            # named_scope: the device trace names each operation by its layer
+            with layer_scope(layer.name), jax.named_scope(layer.name):
                 if layer.is_data:
                     act = _coerce_feed(layer, feed)
                 else:

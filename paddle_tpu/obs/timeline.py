@@ -26,7 +26,10 @@ divided by measured step seconds and chip peak FLOP/s
 virtual-device runs).
 
 Everything here is host-side ``perf_counter`` bookkeeping around the
-existing per-batch host sync (the loop already pulls ``float(loss)``);
+existing per-batch host sync (the loop's ``float(loss)``, which ends the
+``step`` phase); ``SGDTrainer._ph`` takes the boundaries once and hands
+them to this timeline, to the profiler's ``paddle_tpu.trainer.<phase>``
+span and to the request tracer;
 the compiled program is byte-identical with telemetry on or off (gated by
 ``lint --obs``) and the loop overhead is bounded <3% by test.
 """
@@ -34,8 +37,7 @@ the compiled program is byte-identical with telemetry on or off (gated by
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Optional
 
 __all__ = ["StepTimeline", "PHASES"]
 
@@ -126,26 +128,10 @@ class StepTimeline:
 
     # -- recording -------------------------------------------------------
 
-    @contextmanager
-    def phase(self, name: str, *, sync: Any = None) -> Iterator[None]:
-        """Time a block; ``sync`` (a jax array or a callable returning
-        one) is blocked on before the clock stops, so device work lands
-        in the phase that dispatched it."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            if sync is not None:
-                obj = sync() if callable(sync) else sync
-                try:
-                    import jax
-
-                    jax.block_until_ready(obj)
-                except Exception:
-                    pass
-            self.add(name, time.perf_counter() - t0)
-
     def add(self, name: str, seconds: float) -> None:
+        """One phase took ``seconds``: ``SGDTrainer._ph`` is the one caller
+        in the loop, with the same pair of boundaries it gives the
+        profiler's ``paddle_tpu.trainer.<phase>`` span."""
         self.last[name] = seconds
         stat = self._pass_stats.get(name)
         if stat is None:

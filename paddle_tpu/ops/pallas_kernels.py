@@ -247,6 +247,7 @@ def _lstm_pallas_raw(xp_tb, mask_tb, w_h, pi, pf, po, *,
         ]
     return pl.pallas_call(
         kernel,
+        name="lstm_seq_fwd",
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, B, H4), step),
@@ -425,6 +426,7 @@ def _gru_pallas_raw(xp_tb, mask_tb, w_h, *, residuals: bool = True,
         ]
     return pl.pallas_call(
         kernel,
+        name="gru_seq_fwd",
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, B, H3), step),
@@ -586,6 +588,7 @@ def _lstm_bwd_pallas_raw(dout_tb, m_tb, z_tb, cp_tb, w_t, pi, pf, po,
     ]
     outs = pl.pallas_call(
         kernel,
+        name="lstm_seq_bwd",
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, B, H), rev),
@@ -683,6 +686,7 @@ def _gru_bwd_pallas_raw(dout_tb, m_tb, z_tb, hp_tb, w_t, d_hfin, *,
                                batch_split=batch_split)
     return pl.pallas_call(
         kernel,
+        name="gru_seq_bwd",
         grid=(T,),
         in_specs=[
             pl.BlockSpec((1, B, H), rev),
@@ -741,6 +745,7 @@ def logsumexp_rows_pallas(x, *, row_tile: int = 64):
         raise ValueError(f"N={N} not divisible by row_tile={row_tile}")
     out = pl.pallas_call(
         _lse_kernel,
+        name="lse_rows",
         grid=(N // row_tile,),
         in_specs=[pl.BlockSpec((row_tile, V), lambda n: (n, 0))],
         out_specs=pl.BlockSpec((row_tile, 1), lambda n: (n, 0)),
@@ -856,6 +861,7 @@ def attn_dec_fwd_pallas(xp_y_tb, m_tb, s0, enc, enc_proj, src_mask,
     const = lambda b, t: (0, 0)
     return pl.pallas_call(
         kernel,
+        name="attn_dec_fwd",
         grid=(nB, T),
         in_specs=[
             pl.BlockSpec((1, Bb, D3), step),         # xp_y
@@ -1001,6 +1007,7 @@ def attn_dec_bwd_pallas(dout_tb, m_tb, sp_tb, r_tb, u_tb, cand_tb, q_tb,
     const = lambda b, t: (0, 0)
     outs = pl.pallas_call(
         kernel,
+        name="attn_dec_bwd",
         grid=(nB, T),
         in_specs=[
             pl.BlockSpec((1, Bb, D), rev),           # d_out
@@ -1123,6 +1130,7 @@ def ce_readout_fwd_pallas(states_c, w_c, b_f, labels, *,
     kernel = functools.partial(_ce_fwd_kernel, v_tile=Vt)
     return pl.pallas_call(
         kernel,
+        name="ce_readout_fwd",
         grid=(nR, nV),
         in_specs=[
             pl.BlockSpec((Rb, D), lambda r, v: (r, 0)),    # states (resident)
@@ -1198,6 +1206,7 @@ def ce_readout_bwd_pallas(logits_c, states_c, w_c, labels, lse, scale, *,
                                mxu_dtype=compute_dtype())
     return pl.pallas_call(
         kernel,
+        name="ce_readout_bwd",
         grid=(nV,),
         in_specs=[
             pl.BlockSpec((N, Vt), lambda v: (0, v)),       # logits tile
@@ -1380,6 +1389,7 @@ def topk_lse_readout_pallas(states_c, w_p, b_p, *, vocab: int, k: int,
                                v_tile=Vt)
     return pl.pallas_call(
         kernel,
+        name="topk_lse_readout",
         grid=(nR, nV),
         in_specs=[
             pl.BlockSpec((Rb, D), lambda r, v: (r, 0)),    # states (resident)
@@ -1445,6 +1455,7 @@ def topk_lse_logits_pallas(logits, *, vocab: int, k: int, row_block: int,
                                v_tile=Vt)
     return pl.pallas_call(
         kernel,
+        name="topk_lse_logits",
         grid=(nR, nV),
         in_specs=[pl.BlockSpec((Rb, Vt), lambda r, v: (r, v))],
         out_specs=[

@@ -189,6 +189,10 @@ class Optimizer:
             "slots": {k: self.init_leaf(p) for k, p in params.items()},
         }
 
+    # named_scope: every step that applies an optimizer (the trainer's, the
+    # demos', parallel/) shows its update under this one name on a device
+    # trace (docs/observability.md "Names on the device trace")
+    @jax.named_scope("optimizer_apply")
     def update(
         self,
         params: Dict[str, Any],

@@ -42,6 +42,18 @@ from paddle_tpu.utils import FLAGS, logger
 
 __all__ = ["SGDTrainer"]
 
+#: every host span of the loop carries this prefix on a profiler trace
+#: (docs/observability.md "Names on the device trace")
+SPAN_PREFIX = "paddle_tpu.trainer."
+
+
+def _sync_span(reason: str):
+    """One span per blocking fetch from the device inside ``step``: their
+    count per ``iteration`` is the count of host syncs a step pays."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + "step.sync",
+                                        reason=reason)
+
+
 #: consecutive SDC rollbacks a survivor tolerates before declaring the
 #: divergence persistent (a flaky host the vote cannot pin down) and
 #: aborting with the typed error instead of looping forever
@@ -382,9 +394,10 @@ class SGDTrainer:
 
             def loss_fn(p, px):
                 # named_scope: the backward ops XLA derives from this
-                # trace inherit "transpose(forward)" provenance, so an
-                # on-demand profiler capture (obs/profiler.py) reads as
-                # forward / backward / optimizer_apply in XProf
+                # trace inherit "transpose(jvp(forward))" provenance, so a
+                # profiler capture (obs/profiler.py) reads as forward /
+                # backward / optimizer_apply (the scope Optimizer.update
+                # opens), each layer under its own name inside (nn/graph.py)
                 with jax.named_scope("forward"):
                     overrides = (tier.make_overrides(ps["tables"], px)
                                  if tier is not None else None)
@@ -416,10 +429,6 @@ class SGDTrainer:
                     params, proxies))
 
             def do_update(pack, gpack, o):
-                with jax.named_scope("optimizer_apply"):
-                    return do_update_inner(pack, gpack, o)
-
-            def do_update_inner(pack, gpack, o):
                 p, ps_in = pack
                 g, pxg = gpack
                 clip = True
@@ -576,42 +585,27 @@ class SGDTrainer:
 
     # -- telemetry helpers (paddle_tpu/obs) ----------------------------
 
-    def _ph(self, name: str, sync: Any = None):
-        """Timeline phase context (nullcontext when the timeline is off —
-        the uninstrumented loop pays one attribute check per phase).
-        With a step-span open (request tracing armed), the phase is ALSO
-        recorded as a child span of the current batch's trace."""
-        from contextlib import nullcontext
-
-        tl = self.timeline
-        sp = self._step_span
-        if sp is None:
-            return tl.phase(name, sync=sync) if tl is not None \
-                else nullcontext()
-        return self._ph_traced(name, tl, sp, sync)
-
     @contextmanager
-    def _ph_traced(self, name: str, tl, sp, sync: Any):
-        span = sp.child(name)
+    def _ph(self, name: str):
+        """The ONE place a phase of the loop is recorded, three ways from
+        one pair of boundaries: a ``jax.profiler.TraceAnnotation``
+        ``paddle_tpu.trainer.<name>`` (always: a flag check while no
+        profiler is attached, and the only record on the device trace's
+        clock), the ``StepTimeline`` phase (``--obs_timeline``) and a child
+        span of the current batch's trace (request tracing armed).  A
+        phase that dispatches device work fetches its result inside the
+        phase (``step`` ends in the loss fetch), so no record syncs."""
+        tl, sp = self.timeline, self._step_span
+        span = sp.child(name) if sp is not None else None
+        t0 = time.perf_counter()
         try:
-            if tl is not None:
-                with tl.phase(name, sync=sync):
-                    yield
-            else:
-                try:
-                    yield
-                finally:
-                    # the timeline normally owns the device sync; with it
-                    # off the span must still charge dispatched work to
-                    # the phase that launched it
-                    if sync is not None:
-                        obj = sync() if callable(sync) else sync
-                        try:
-                            jax.block_until_ready(obj)
-                        except Exception:
-                            pass
+            with jax.profiler.TraceAnnotation(SPAN_PREFIX + name):
+                yield
         finally:
-            span.end()
+            if tl is not None:
+                tl.add(name, time.perf_counter() - t0)
+            if span is not None:
+                span.end()
 
     @property
     def _h2d_measurable(self) -> bool:
@@ -727,9 +721,10 @@ class SGDTrainer:
         recover by skipping."""
         self._rng, key = jax.random.split(self._rng)
         ps = self.pserver.state() if self.pserver is not None else {}
-        loss, self.params, self.state, self.opt_state, new_ps, extras = (
-            self._step(self.params, self.state, self.opt_state, ps, key,
-                       feed))
+        with jax.profiler.TraceAnnotation(SPAN_PREFIX + "step.dispatch"):
+            loss, self.params, self.state, self.opt_state, new_ps, extras = (
+                self._step(self.params, self.state, self.opt_state, ps, key,
+                           feed))
         if self.pserver is not None:
             self.pserver.adopt(new_ps)
         if self.averager is not None:
@@ -748,7 +743,9 @@ class SGDTrainer:
                 "last_resize_reason": self._last_resize_reason,
             }
         if self.amp and "amp_overflow" in extras:
-            if bool(jax.device_get(extras["amp_overflow"])):
+            with _sync_span("amp"):
+                overflow = bool(jax.device_get(extras["amp_overflow"]))
+            if overflow:
                 self.amp_overflows_total += 1
                 scale = float(jax.device_get(extras["loss_scale"]))
                 if self._journal is not None:
@@ -761,7 +758,9 @@ class SGDTrainer:
                     "loss scale halved to %g (overflow %d)", scale,
                     self.amp_overflows_total)
         if (self.guard_nonfinite or self.amp) and "bad_step" in extras:
-            if bool(jax.device_get(extras["bad_step"])):
+            with _sync_span("guard"):
+                bad = bool(jax.device_get(extras["bad_step"]))
+            if bad:
                 self.bad_steps_total += 1
                 self._bad_streak += 1
                 self._obs_counters["bad_steps"].inc()
@@ -828,16 +827,17 @@ class SGDTrainer:
         an all-ranks barrier, and auto-resume follows the COORDINATOR's
         notion of the latest valid pass.
 
-        Instrumentation mirrors the reference's Stat plane: named timers
-        around data-wait / step / eval (REGISTER_TIMER in
-        TrainerInternal.cpp:118), a per-pass timing table behind
-        ``--enable_timers`` (Stat.h:70-247 print-per-pass), and an opt-in
-        ``jax.profiler`` trace via ``--profile_dir`` — the hl_profiler_start/
-        end analog (hl_cuda.h:338-343), viewable in TensorBoard/XProf."""
+        Instrumentation (docs/observability.md): every phase of the loop
+        goes through ``_ph``, which names it ``paddle_tpu.trainer.<phase>``
+        on a profiler trace and feeds the step timeline (the reference's
+        REGISTER_TIMER plane, TrainerInternal.cpp:118; ``--enable_timers``
+        prints its per-pass table, Stat.h:70-247) and the request tracer;
+        ``--profile_dir`` records the ``jax.profiler`` trace — the
+        hl_profiler_start/end analog (hl_cuda.h:338-343), viewable in
+        TensorBoard/XProf."""
         from paddle_tpu.obs import (ProfilerCapture, StepTimeline,
                                     ensure_metrics_server, get_journal)
         from paddle_tpu.obs.trace import get_tracer
-        from paddle_tpu.utils.stat import print_stats, timer
 
         handler = event_handler or (lambda e: None)
         log_period = FLAGS.log_period
@@ -850,7 +850,8 @@ class SGDTrainer:
         ensure_metrics_server()
         tl = self.timeline = (StepTimeline(
             n_devices=(self.mesh.devices.size if self.mesh is not None
-                       else 1)) if FLAGS.obs_timeline else None)
+                       else 1))
+            if FLAGS.obs_timeline or FLAGS.enable_timers else None)
         jr = self._journal = get_journal(
             rank=(getattr(gang, "rank", 0) if gang is not None else 0),
             world_size=(gang.world_size if gang is not None else 1))
@@ -1003,217 +1004,228 @@ class SGDTrainer:
                     _wrap_prefetch()
                 batch_id = first_batch
                 while True:
-                    if tracer.enabled and not skip \
-                            and self._step_span is None:
-                        # the step-span opens BEFORE the gang poll so a
-                        # resize adopted at this boundary lands inside the
-                        # very trace whose latency it explains
-                        self._step_span = tracer.start_trace(
-                            "train_step", batch=batch_id)
-                    if gang is not None:
-                        # liveness signal from the MAIN thread: a rank
-                        # stuck in a collective stops heartbeating here
-                        # and the supervisor's watchdog gang-restarts it
-                        gang.heartbeat()
-                        # elastic resize (docs/resilience.md): a published
-                        # world change is adopted HERE, at the batch
-                        # boundary — the natural drain point.  While the
-                        # reader is still fast-forwarding (skip > 0) the
-                        # params already include every batch up to
-                        # batch_id + skip — recording the skip cursor
-                        # instead would make a restore re-apply batches
-                        # the state has already seen
-                        world = gang.poll_world()
-                        if world is not None:
-                            self._gang_resize(gang, world, pass_id,
-                                              batch_id + skip, handler)
-                            if self._source_resharded:
-                                # the source re-split the permutation for
-                                # the new world: drop the old split's
-                                # read-ahead and re-enter the pass at the
-                                # same batch boundary.  The reshard
-                                # positioned the cursor at batch_id+skip,
-                                # so any remaining fast-forward (a
-                                # datapipe source resuming without a
-                                # manifest cursor) is cancelled — the
-                                # skip loop would otherwise discard
-                                # never-trained batches
-                                self._source_resharded = False
-                                self._close_prefetcher()
-                                batch_id, skip = batch_id + skip, 0
-                                it = iter(reader())
-                                _wrap_prefetch()
-                    if preemption is not None and preemption.poll():
-                        if self._step_span is not None:
-                            # a preempted step is an incident: keep it
-                            self._step_span.retain("preempt")
-                            self._step_span.end(status="preempt")
-                            self._step_span = None
-                        # the prefetcher's read-ahead is abandoned HERE, at
-                        # the drain point: the checkpoint records the
-                        # batches the STEP consumed, so resume re-reads
-                        # the prepared-but-unstepped ones — batch-exact
-                        self._close_prefetcher()
-                        self._preempt_exit(pass_id, batch_id + skip,
-                                           preemption, handler)
-                        return
-                    with timer("DataWaitTimer"), self._ph("data_wait"):
-                        try:
-                            data_batch = next(it, None)
-                        except PrepareError as e:
-                            # a prefetched batch failed in PREPARE/H2D,
-                            # not in the reader: re-raise the original so
-                            # a feeder bug keeps its own type, exactly as
-                            # it would without prefetch
-                            raise (e.__cause__ if e.__cause__ is not None
-                                   else e)
-                        except Exception as e:
-                            raise _reader_failed(e) from e
-                    if data_batch is None:
-                        if self._step_span is not None:
-                            # no batch behind this span: not a step, not
-                            # a story — never reaches the journal
-                            self._step_span.cancel()
-                            self._step_span = None
-                        break
-                    if skip:
-                        # fast-forward a deterministic reader to the batch
-                        # the preemption checkpoint recorded (raw items —
-                        # the prefetcher attaches once the skip is done).
-                        # Plain-reader FALLBACK only: a datapipe source
-                        # resumes by cursor and never enters this branch
-                        skip -= 1
-                        batch_id += 1
-                        self.resume_replayed_batches += 1
-                        if not skip:
-                            _wrap_prefetch()
-                        continue
-                    if jr is not None:
-                        jr.set_context(batch_id=batch_id)
-                    with self._ph("callback"):
-                        handler(ev.BeginIteration(pass_id, batch_id))
-                    prefetched = isinstance(data_batch, PreparedFeed)
-                    with timer("PrepareBatch"), self._ph("prepare"):
-                        feed = (data_batch.feed if prefetched
-                                else feeder(data_batch) if feeder
-                                else data_batch)
-                    if tl is not None and self._h2d_measurable \
-                            and not prefetched:
-                        # explicit, synced host->device transfer: the h2d
-                        # phase is real transfer time, and the step phase
-                        # that follows starts device-resident (on single-
-                        # device CPU there is no boundary to measure —
-                        # skipped, the alias-copy rides inside `step`)
-                        with tl.phase("h2d"):
-                            feed = self._device_feed(feed)
-                    if profiler is not None:
-                        # BEFORE the step: a window armed at batch b
-                        # traces batches b..b+N-1 exactly — ticking after
-                        # the step would shift the capture one step late
-                        # and make the first post-compile step untraceable
-                        profiler.tick()
-                    try:
-                        with timer("TrainBatch", sync=lambda: loss), \
-                                self._ph("step", sync=lambda: loss):
-                            loss = self.train_batch(feed)
-                    except TooManyBadSteps:
-                        if self._step_span is not None:
-                            self._step_span.retain("train_abort")
-                            self._step_span.end(status="train_abort")
-                            self._step_span = None
-                        handler(ev.EndPass(pass_id))
-                        if jr is not None:
-                            jr.record("train_abort",
-                                      reason="too_many_bad_steps")
-                        raise
-                    if tl is not None and tl.wants_mfu and \
-                            not tl.flops_attempted:
-                        # ONE extra host-side trace per compiled program,
-                        # only when a chip peak is resolvable — a failed
-                        # trace (None) is not retried per batch
-                        tl.set_flops(self.step_flops(feed))
-                        tl.recompute_mfu()
-                    if src is not None:
-                        # corrupt shard records the source skipped under
-                        # its skip-and-count policy (datapipe/iterator.py)
-                        # — surfaced next to the step extras like
-                        # dropped_features
-                        self._last_extras = {
-                            **self._last_extras,
-                            "dropped_records":
-                                int(getattr(src, "dropped_records", 0))}
-                    drops = getattr(feeder, "dropped_features", None)
-                    if drops is not None:
-                        # sparse-bag truncation is a data-loss event, not a
-                        # debug log line: surface the feeder's counter next
-                        # to the step extras (serving mirrors it in
-                        # healthz())
-                        self._last_extras = {**self._last_extras,
-                                             "dropped_features": int(drops)}
-                    cost = float(loss)
-                    costs.append(cost)
-                    if tl is not None:
-                        self._obs_gauges["cost"].set(cost)
-                        self._last_extras = {
-                            **self._last_extras,
-                            "step_time_s": tl.last.get("step"),
-                            "mfu": tl.mfu,
-                        }
-                    with self._ph("callback"):
-                        handler(ev.EndIteration(pass_id, batch_id, cost))
-                    if self._step_span is not None:
-                        # the root closes here: tail sampling decides —
-                        # bad-step/resize/preempt marks always keep, the
-                        # p99 reservoir keeps outlier-slow steps, the
-                        # rest head-sample at --trace_sample
-                        sp, self._step_span = self._step_span, None
-                        sp.end(status="ok", cost=round(cost, 6))
-                    if (gang is not None and self.sdc_check_every
-                            and gang.world_size > 1
-                            and (batch_id + 1) % self.sdc_check_every == 0):
-                        # cross-replica integrity check (the SDC
-                        # firewall): exchange the step's in-jit state
-                        # fingerprint and majority-vote it
-                        try:
-                            self._sdc_check(gang, pass_id, batch_id,
-                                            handler)
-                        # invariant: _SdcRollback is not a one-rank
-                        # escape — the vote itself is the collective, and
-                        # _sdc_check raises on EVERY rank or on none, so
-                        # no peer is left blocked in exchange_json
-                        except _SdcRollback as rb:  # tpu-lint: disable=protocol-exception
-                            start_pass = rb.start_pass
-                            start_batch = rb.start_batch
-                            cursor_restored = False
-                            if rb.cursor_ready:
-                                cursor_restored = True
-                            elif (src is not None
-                                  and self._pending_cursor is not None):
-                                src.restore(self._pending_cursor)
-                                cursor_restored = True
-                                self._pending_cursor = None
-                            schedule.rewind(start_pass)
-                            rolled_back = True
+                    # one StepTraceAnnotation per batch (XProf groups by
+                    # step_num); its self time is the loop's own
+                    # bookkeeping: gang poll, extras, gauges, journal
+                    with jax.profiler.StepTraceAnnotation(
+                            SPAN_PREFIX + "iteration", step_num=batch_id):
+                        if tracer.enabled and not skip \
+                                and self._step_span is None:
+                            # the step-span opens BEFORE the gang poll so a
+                            # resize adopted at this boundary lands inside the
+                            # very trace whose latency it explains
+                            self._step_span = tracer.start_trace(
+                                "train_step", batch=batch_id)
+                        if gang is not None:
+                            # liveness signal from the MAIN thread: a rank
+                            # stuck in a collective stops heartbeating here
+                            # and the supervisor's watchdog gang-restarts it
+                            gang.heartbeat()
+                            # elastic resize (docs/resilience.md): a published
+                            # world change is adopted HERE, at the batch
+                            # boundary — the natural drain point.  While the
+                            # reader is still fast-forwarding (skip > 0) the
+                            # params already include every batch up to
+                            # batch_id + skip — recording the skip cursor
+                            # instead would make a restore re-apply batches
+                            # the state has already seen
+                            world = gang.poll_world()
+                            if world is not None:
+                                self._gang_resize(gang, world, pass_id,
+                                                  batch_id + skip, handler)
+                                if self._source_resharded:
+                                    # the source re-split the permutation for
+                                    # the new world: drop the old split's
+                                    # read-ahead and re-enter the pass at the
+                                    # same batch boundary.  The reshard
+                                    # positioned the cursor at batch_id+skip,
+                                    # so any remaining fast-forward (a
+                                    # datapipe source resuming without a
+                                    # manifest cursor) is cancelled — the
+                                    # skip loop would otherwise discard
+                                    # never-trained batches
+                                    self._source_resharded = False
+                                    self._close_prefetcher()
+                                    batch_id, skip = batch_id + skip, 0
+                                    it = iter(reader())
+                                    _wrap_prefetch()
+                        if preemption is not None and preemption.poll():
+                            if self._step_span is not None:
+                                # a preempted step is an incident: keep it
+                                self._step_span.retain("preempt")
+                                self._step_span.end(status="preempt")
+                                self._step_span = None
+                            # the prefetcher's read-ahead is abandoned HERE, at
+                            # the drain point: the checkpoint records the
+                            # batches the STEP consumed, so resume re-reads
+                            # the prepared-but-unstepped ones — batch-exact
+                            self._close_prefetcher()
+                            self._preempt_exit(pass_id, batch_id + skip,
+                                               preemption, handler)
+                            return
+                        with self._ph("data_wait"):
+                            try:
+                                data_batch = next(it, None)
+                            except PrepareError as e:
+                                # a prefetched batch failed in PREPARE/H2D,
+                                # not in the reader: re-raise the original so
+                                # a feeder bug keeps its own type, exactly as
+                                # it would without prefetch
+                                raise (e.__cause__ if e.__cause__ is not None
+                                       else e)
+                            except Exception as e:
+                                raise _reader_failed(e) from e
+                        if data_batch is None:
+                            if self._step_span is not None:
+                                # no batch behind this span: not a step, not
+                                # a story — never reaches the journal
+                                self._step_span.cancel()
+                                self._step_span = None
                             break
-                    if log_period and (batch_id + 1) % log_period == 0:
-                        logger.info(
-                            "Pass %d, Batch %d, Cost %.5f (%.1f batch/s)",
-                            pass_id, batch_id + 1, float(np.mean(costs[-log_period:])),
-                            log_period / max(time.time() - t0, 1e-9),
-                        )
-                        t0 = time.time()
-                    psp = FLAGS.show_parameter_stats_period
-                    if psp and (batch_id + 1) % psp == 0:
-                        self._log_parameter_stats()
-                    tp = FLAGS.test_period
-                    if (tp and test_reader is not None
-                            and (batch_id + 1) % tp == 0):
-                        # mid-pass eval — test_period batches (Trainer.cpp
-                        # trainOneBatch "testing" branch; 0 = per pass only)
-                        with timer("TestTimer"), self._ph("eval"):
-                            mid = self.test(test_reader, feeder=feeder)
-                        logger.info("Pass %d, Batch %d, Test cost %.5f",
-                                    pass_id, batch_id + 1, mid["cost"])
+                        if skip:
+                            # fast-forward a deterministic reader to the batch
+                            # the preemption checkpoint recorded (raw items —
+                            # the prefetcher attaches once the skip is done).
+                            # Plain-reader FALLBACK only: a datapipe source
+                            # resumes by cursor and never enters this branch
+                            skip -= 1
+                            batch_id += 1
+                            self.resume_replayed_batches += 1
+                            if not skip:
+                                _wrap_prefetch()
+                            continue
+                        if jr is not None:
+                            jr.set_context(batch_id=batch_id)
+                        with self._ph("callback"):
+                            handler(ev.BeginIteration(pass_id, batch_id))
+                        prefetched = isinstance(data_batch, PreparedFeed)
+                        with self._ph("prepare"):
+                            feed = (data_batch.feed if prefetched
+                                    else feeder(data_batch) if feeder
+                                    else data_batch)
+                        if tl is not None and self._h2d_measurable \
+                                and not prefetched:
+                            # explicit, synced host->device transfer: the h2d
+                            # phase is real transfer time, and the step phase
+                            # that follows starts device-resident (on single-
+                            # device CPU there is no boundary to measure —
+                            # skipped, the alias-copy rides inside `step`)
+                            with self._ph("h2d"):
+                                feed = self._device_feed(feed)
+                        if profiler is not None:
+                            # BEFORE the step: a window armed at batch b
+                            # traces batches b..b+N-1 exactly — ticking after
+                            # the step would shift the capture one step late
+                            # and make the first post-compile step untraceable
+                            profiler.tick()
+                        try:
+                            with self._ph("step"):
+                                loss = self.train_batch(feed)
+                                # the loop's own fetch: the phase ends with
+                                # the step's device work done
+                                with _sync_span("loss"):
+                                    cost = float(loss)
+                        except TooManyBadSteps:
+                            if self._step_span is not None:
+                                self._step_span.retain("train_abort")
+                                self._step_span.end(status="train_abort")
+                                self._step_span = None
+                            handler(ev.EndPass(pass_id))
+                            if jr is not None:
+                                jr.record("train_abort",
+                                          reason="too_many_bad_steps")
+                            raise
+                        if tl is not None and tl.wants_mfu and \
+                                not tl.flops_attempted:
+                            # ONE extra host-side trace per compiled program,
+                            # only when a chip peak is resolvable — a failed
+                            # trace (None) is not retried per batch
+                            tl.set_flops(self.step_flops(feed))
+                            tl.recompute_mfu()
+                        if src is not None:
+                            # corrupt shard records the source skipped under
+                            # its skip-and-count policy (datapipe/iterator.py)
+                            # — surfaced next to the step extras like
+                            # dropped_features
+                            self._last_extras = {
+                                **self._last_extras,
+                                "dropped_records":
+                                    int(getattr(src, "dropped_records", 0))}
+                        drops = getattr(feeder, "dropped_features", None)
+                        if drops is not None:
+                            # sparse-bag truncation is a data-loss event, not a
+                            # debug log line: surface the feeder's counter next
+                            # to the step extras (serving mirrors it in
+                            # healthz())
+                            self._last_extras = {
+                                **self._last_extras,
+                                "dropped_features": int(drops)}
+                        costs.append(cost)
+                        if tl is not None:
+                            self._obs_gauges["cost"].set(cost)
+                            self._last_extras = {
+                                **self._last_extras,
+                                "step_time_s": tl.last.get("step"),
+                                "mfu": tl.mfu,
+                            }
+                        with self._ph("callback"):
+                            handler(ev.EndIteration(pass_id, batch_id, cost))
+                        if self._step_span is not None:
+                            # the root closes here: tail sampling decides —
+                            # bad-step/resize/preempt marks always keep, the
+                            # p99 reservoir keeps outlier-slow steps, the
+                            # rest head-sample at --trace_sample
+                            sp, self._step_span = self._step_span, None
+                            sp.end(status="ok", cost=round(cost, 6))
+                        if (gang is not None and self.sdc_check_every
+                                and gang.world_size > 1
+                                and (batch_id + 1)
+                                % self.sdc_check_every == 0):
+                            # cross-replica integrity check (the SDC
+                            # firewall): exchange the step's in-jit state
+                            # fingerprint and majority-vote it
+                            try:
+                                self._sdc_check(gang, pass_id, batch_id,
+                                                handler)
+                            # invariant: _SdcRollback is not a one-rank
+                            # escape — the vote itself is the collective, and
+                            # _sdc_check raises on EVERY rank or on none, so
+                            # no peer is left blocked in exchange_json
+                            except _SdcRollback as rb:  # tpu-lint: disable=protocol-exception
+                                start_pass = rb.start_pass
+                                start_batch = rb.start_batch
+                                cursor_restored = False
+                                if rb.cursor_ready:
+                                    cursor_restored = True
+                                elif (src is not None
+                                      and self._pending_cursor is not None):
+                                    src.restore(self._pending_cursor)
+                                    cursor_restored = True
+                                    self._pending_cursor = None
+                                schedule.rewind(start_pass)
+                                rolled_back = True
+                                break
+                        if log_period and (batch_id + 1) % log_period == 0:
+                            logger.info(
+                                "Pass %d, Batch %d, Cost %.5f (%.1f batch/s)",
+                                pass_id, batch_id + 1,
+                                float(np.mean(costs[-log_period:])),
+                                log_period / max(time.time() - t0, 1e-9),
+                            )
+                            t0 = time.time()
+                        psp = FLAGS.show_parameter_stats_period
+                        if psp and (batch_id + 1) % psp == 0:
+                            self._log_parameter_stats()
+                        tp = FLAGS.test_period
+                        if (tp and test_reader is not None
+                                and (batch_id + 1) % tp == 0):
+                            # mid-pass eval — test_period batches
+                            # (Trainer.cpp trainOneBatch "testing" branch;
+                            # 0 = per pass only)
+                            with self._ph("eval"):
+                                mid = self.test(test_reader, feeder=feeder)
+                            logger.info("Pass %d, Batch %d, Test cost %.5f",
+                                        pass_id, batch_id + 1, mid["cost"])
                     batch_id += 1
                 self._close_prefetcher()
                 if rolled_back:
@@ -1224,18 +1236,16 @@ class SGDTrainer:
                     continue
                 result = {}
                 if test_reader is not None:
-                    with timer("TestTimer"), self._ph("eval"):
+                    with self._ph("eval"):
                         result = self.test(test_reader, feeder=feeder)
                 with self._ph("callback"):
                     handler(ev.EndPass(pass_id, evaluator=result))
                 if jr is not None:
                     jr.record("end_pass", batches=batch_id)
-                if FLAGS.enable_timers:
-                    print_stats()
                 if FLAGS.save_dir and FLAGS.saving_period and (
                     (pass_id + 1) % FLAGS.saving_period == 0
                 ):
-                    with timer("SaveCheckpoint"), self._ph("checkpoint"):
+                    with self._ph("checkpoint"):
                         try:
                             self.save(FLAGS.save_dir, pass_id)
                         except GangResized as e:

@@ -7,7 +7,6 @@ from paddle_tpu.utils.error import (
     ShapeError,
     layer_scope,
 )
-from paddle_tpu.utils.stat import timer, global_stat, reset_stats, print_stats
 from paddle_tpu.utils import devices
 
 __all__ = [
@@ -21,9 +20,5 @@ __all__ = [
     "ConfigError",
     "ShapeError",
     "layer_scope",
-    "timer",
-    "global_stat",
-    "reset_stats",
-    "print_stats",
     "devices",
 ]

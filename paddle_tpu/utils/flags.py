@@ -529,7 +529,8 @@ define_flag("compile_cache_dir", "auto", "persistent compiled-executable "
             "an explicit empty value (--compile_cache_dir=) to opt out")
 
 # Profiling / timers (replaces WITH_TIMER + log_barrier_* ...)
-define_flag("enable_timers", False, "collect Stat timer registry stats")
+define_flag("enable_timers", False, "log the step timeline's per-phase "
+            "table at the end of every pass")
 define_flag("profile_dir", "", "write a jax.profiler trace here during train() "
             "(hl_profiler_start/end analog; view with TensorBoard/XProf)")
 define_flag("profile_steps", 0, "capture bounded jax.profiler windows of N "

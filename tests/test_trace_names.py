@@ -1,0 +1,216 @@
+"""The names the program writes into a profiler trace (docs/observability.md
+"Names on the device trace"): a literal ``name=`` on every ``pl.pallas_call``,
+the model scopes inside both jitted training steps (names and metadata only:
+the equations stay what they were), and the ``paddle_tpu.trainer.*`` host
+spans of ``SGDTrainer.train``, read back from a trace recorded here on the
+CPU.  The per-layer metrics that read them are tested in
+tests/benchmark/test_trace_scopes.py."""
+
+import ast
+import contextlib
+import glob
+import os
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu.nn as nn
+from paddle_tpu.param.optimizers import Adam, Optimizer
+from paddle_tpu.trainer import SGDTrainer
+from paddle_tpu.trainer.trainer import SPAN_PREFIX
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
+           "lse_rows", "attn_dec_fwd", "attn_dec_bwd", "ce_readout_fwd",
+           "ce_readout_bwd", "topk_lse_readout", "topk_lse_logits"]
+
+
+# -- (a) kernels ----------------------------------------------------------
+
+
+def _pallas_call_sites():
+    """``name=`` of every ``pl.pallas_call(...)`` in ops/pallas_kernels.py,
+    in the file's order; ``None`` where a call passes none or no literal."""
+    path = os.path.join(ROOT, "paddle_tpu", "ops", "pallas_kernels.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    sites = []
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == "pallas_call"):
+            name = next((kw.value for kw in node.keywords
+                         if kw.arg == "name"), None)
+            sites.append((node.lineno, name.value if isinstance(
+                name, ast.Constant) and isinstance(name.value, str)
+                else None))
+    return [name for _, name in sorted(sites)]
+
+
+@pytest.mark.parametrize("site", range(len(KERNELS)))
+def test_every_pallas_call_passes_a_literal_unique_name(site):
+    names = _pallas_call_sites()
+    assert len(names) == len(KERNELS), "a pallas_call site came or went: " \
+        "name it, and list it in docs/observability.md"
+    assert names[site] == KERNELS[site]
+    assert names.count(names[site]) == 1
+
+
+# -- (b) scopes in the two jitted steps -----------------------------------
+
+
+def _name_stacks(jaxpr, out=None):
+    """Every equation's name stack, through sub-jaxprs."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        out.add(str(eqn.source_info.name_stack))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _name_stacks(sub, out)
+    return out
+
+
+def _scope_names(stacks):
+    import re
+
+    return {part for s in stacks for part in re.split(r"[/()]+", s) if part}
+
+
+@contextlib.contextmanager
+def _no_scopes(monkeypatch):
+    """The program as it was before it named anything."""
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope",
+                  lambda name: contextlib.nullcontext())
+        m.setattr(Optimizer, "update", Optimizer.update.__wrapped__)
+        yield
+
+
+def _seq2seq_step_jaxpr():
+    from benchmark.manifest import load_module
+    from paddle_tpu.models import Seq2SeqAttention
+
+    demo = load_module(os.path.join(ROOT, "demo", "seqToseq", "train.py"),
+                       "trace_names_seqToseq_demo")
+    model = Seq2SeqAttention(src_vocab=50, trg_vocab=50, emb_dim=8,
+                             enc_dim=8, dec_dim=8, att_dim=8)
+    opt = Adam(learning_rate=1e-3)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"src_ids": np.ones((4, 5), np.int32),
+             "src_len": np.full((4,), 5, np.int32),
+             "trg_in": np.ones((4, 6), np.int32),
+             "trg_next": np.ones((4, 6), np.int32),
+             "trg_len": np.full((4,), 6, np.int32)}
+    step = demo.make_train_step(model, opt)
+    return jax.make_jaxpr(step)(params, opt.init_state(params), batch)
+
+
+def _trainer_step_jaxpr():
+    from paddle_tpu.models import lstm_benchmark_net
+
+    nn.reset_naming()
+    cost, _ = lstm_benchmark_net(50, emb_dim=8, hid_dim=8, num_layers=2,
+                                 num_classes=2)
+    tr = SGDTrainer(cost, Adam(learning_rate=1e-3), seed=0)
+    feed = {name: value for name, value in zip(
+        sorted(l.name for l in tr.topology.layers if l.is_data),
+        [np.zeros((4,), np.int32),
+         (np.ones((4, 6), np.int32), np.full((4,), 6, np.int32))])}
+    return jax.make_jaxpr(tr._step_fn)(
+        tr.params, tr.state, tr.opt_state, {}, jax.random.PRNGKey(0), feed)
+
+
+@pytest.mark.parametrize("build, scopes", [
+    (_seq2seq_step_jaxpr,
+     {"encoder", "decoder", "readout_ce", "optimizer_apply"}),
+    (_trainer_step_jaxpr,
+     {"forward", "emb", "lstm0", "lstm1", "logits", "optimizer_apply"}),
+], ids=["seqToseq_demo_step", "trainer_step"])
+def test_jitted_step_holds_the_scopes_and_the_same_equations(
+        build, scopes, monkeypatch):
+    named = build()
+    assert scopes <= _scope_names(_name_stacks(named.jaxpr))
+    with _no_scopes(monkeypatch):
+        bare = build()
+    assert not scopes & _scope_names(_name_stacks(bare.jaxpr))
+    # names and metadata only: the same equations on the same shapes
+    assert str(named) == str(bare)
+
+
+# -- (c) the trainer's host spans -------------------------------------------
+
+
+def _spans_of_one_pass(tmp_path, batches=3):
+    """``SGDTrainer.train`` over a few toy batches under the profiler: the
+    ``paddle_tpu.trainer.*`` events of the thread that drove the loop, as
+    ``(start, end, name without the prefix, stats)`` by start."""
+    from jax.profiler import ProfileData
+
+    nn.reset_naming()
+    x = nn.data("x", size=4)
+    cost = nn.mse_cost(input=nn.fc(x, 2, name="o"),
+                       label=nn.data("y", size=2))
+    tr = SGDTrainer(cost, Adam(learning_rate=0.01), seed=0)
+    rng = np.random.RandomState(0)
+
+    def reader():
+        for _ in range(batches):
+            yield {"x": rng.rand(4, 4).astype(np.float32),
+                   "y": rng.rand(4, 2).astype(np.float32)}
+
+    tr.train(reader, num_passes=1)          # compiles outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        tr.train(reader, num_passes=1)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(found) == 1
+    lines = []
+    for plane in ProfileData.from_file(found[0]).planes:
+        for line in plane.lines:
+            spans = [(e.start_ns, e.start_ns + e.duration_ns,
+                      e.name[len(SPAN_PREFIX):], dict(e.stats))
+                     for e in line.events if e.name.startswith(SPAN_PREFIX)]
+            if spans:
+                lines.append(sorted(spans, key=lambda s: (s[0], -s[1])))
+    assert len(lines) == 1, "the loop's spans are on one thread"
+    return lines[0]
+
+
+@pytest.mark.parametrize("timeline", [True, False],
+                         ids=["obs_timeline", "no_obs_timeline"])
+def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
+                                                   monkeypatch):
+    from paddle_tpu.utils.flags import FLAGS
+
+    monkeypatch.setattr(FLAGS, "obs_timeline", timeline)
+    spans = _spans_of_one_pass(tmp_path)
+
+    def inside(outer):
+        return [s for s in spans if s is not outer
+                and outer[0] <= s[0] and s[1] <= outer[1]]
+
+    iterations = [s for s in spans if s[2] == "iteration"]
+    # one per batch, and the one that found the reader empty
+    assert [s[3]["step_num"] for s in iterations] == [0, 1, 2, 3]
+    assert [c[2] for c in inside(iterations[3])] == ["data_wait"]
+    for it in iterations[:3]:
+        children = [c[2] for c in inside(it)]
+        assert children == ["data_wait", "callback", "prepare", "step",
+                            "step.dispatch", "step.sync", "step.sync",
+                            "callback"]
+        step = next(c for c in inside(it) if c[2] == "step")
+        in_step = inside(step)
+        assert [c[2] for c in in_step] == ["step.dispatch", "step.sync",
+                                           "step.sync"]
+        # one span per blocking fetch, with its reason
+        assert [c[3]["reason"] for c in in_step[1:]] == ["guard", "loss"]
+    # what a pass does once (here the EndPass callback) is outside them
+    outside = [s[2] for s in spans if s[2] != "iteration" and not any(
+        it[0] <= s[0] and s[1] <= it[1] for it in iterations)]
+    assert outside == ["callback"]

@@ -147,10 +147,12 @@ def test_synthetic_datasets_shapes():
 
 
 def test_stat_timers_populate(rng):
-    """--enable_timers wires Stat spans around data-wait/step (Stat.h
-    analog); the registry fills during train() and prints per pass."""
+    """--enable_timers (the reference's Stat print-per-pass, Stat.h:70-247)
+    keeps the step timeline on, even under --obs_timeline=false, and logs
+    its per-phase table at the end of a pass; the phases fill during
+    train() through the trainer's one phase wrapper."""
+    from paddle_tpu.trainer import events as ev
     from paddle_tpu.utils.flags import FLAGS
-    from paddle_tpu.utils.stat import global_stat, reset_stats
 
     nn.reset_naming()
     x = nn.data("x", size=4)
@@ -162,18 +164,23 @@ def test_stat_timers_populate(rng):
             yield {"x": rng.rand(4, 4).astype(np.float32),
                    "y": rng.rand(4, 2).astype(np.float32)}
 
-    reset_stats()
-    old = FLAGS.enable_timers
-    FLAGS.enable_timers = True
+    seen = {}
+
+    def at_end_of_pass(e):   # end_pass() resets the per-pass stats after it
+        if isinstance(e, ev.EndPass):
+            seen.update(tr.timeline.pass_stats())
+            seen["table"] = tr.timeline.table()
+
+    old = FLAGS.enable_timers, FLAGS.obs_timeline
+    FLAGS.enable_timers, FLAGS.obs_timeline = True, False
     try:
-        tr.train(reader, num_passes=1)
+        tr.train(reader, num_passes=1, event_handler=at_end_of_pass)
     finally:
-        FLAGS.enable_timers = old
-    names = {s.name for s in global_stat._stats.values()}
-    assert {"DataWaitTimer", "TrainBatch"} <= names
-    assert global_stat.get("TrainBatch").count == 3
-    assert global_stat.get("TrainBatch").total > 0
-    reset_stats()
+        FLAGS.enable_timers, FLAGS.obs_timeline = old
+    assert {"data_wait", "prepare", "step", "callback"} <= set(seen)
+    assert seen["step"]["count"] == 3 and seen["step"]["total"] > 0
+    assert seen["data_wait"]["count"] == 4   # the last finds the reader empty
+    assert "step" in seen["table"] and "share" in seen["table"]
 
 
 def test_trainer_test_with_wired_evaluators(rng):

@@ -349,7 +349,7 @@ def run(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--amp", action="store_true",
                    help="audit the mixed-precision contract: the compiled "
                         "--amp train step (forward + backward + loss "
-                        "scaling + fused apply) must contain ZERO "
+                        "scaling + optimizer apply) must contain ZERO "
                         "non-allowlisted all-f32 dot_general/conv eqns "
                         "(docs/mixed_precision.md)")
     p.add_argument("--serve", action="append", default=[],

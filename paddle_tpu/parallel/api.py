@@ -107,13 +107,7 @@ def make_parallel_train_step(
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        # never the fused multi-tensor apply under tensor-parallel rules:
-        # concatenating differently-sharded leaves mispartitions under
-        # GSPMD (see Optimizer.update's caller contract); without rules
-        # --fused_apply decides, as it does in the trainer
-        new_params, new_opt = optimizer.update(
-            params, grads, opt_state,
-            fused=False if rules is not None else None)
+        new_params, new_opt = optimizer.update(params, grads, opt_state)
         # hand the state back placed as the rules place it: left to the
         # partitioner, the outputs come back in shardings of its own
         # choosing, and the next step recompiles for them

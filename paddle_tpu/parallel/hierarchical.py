@@ -196,8 +196,7 @@ def make_hierarchical_train_step(
         grads = jax.tree_util.tree_map(
             lambda g: hierarchical_psum(g, data, dcn, ici_size=ici_size,
                                         dcn_size=dcn_size) / n, grads)
-        new_params, new_opt = optimizer.update(params, grads, opt_state,
-                                               fused=True)
+        new_params, new_opt = optimizer.update(params, grads, opt_state)
         return reduce_loss(loss), new_params, new_opt
 
     def compressed_body(params, opt_state, residuals, batch):
@@ -213,8 +212,7 @@ def make_hierarchical_train_step(
             out_r.append(nr.reshape(r.shape))
         grads = jax.tree_util.tree_unflatten(treedef, out_g)
         new_res = jax.tree_util.tree_unflatten(treedef, out_r)
-        new_params, new_opt = optimizer.update(params, grads, opt_state,
-                                               fused=True)
+        new_params, new_opt = optimizer.update(params, grads, opt_state)
         return reduce_loss(loss), new_params, new_opt, new_res
 
     rep = P()  # params/opt replicated across both axes

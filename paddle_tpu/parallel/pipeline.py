@@ -181,11 +181,8 @@ def make_pipeline_train_step(
             return loss_fn(y, labels)
 
         loss, grads = jax.value_and_grad(objective)(stacked_params)
-        # stage-stacked params are sharded over the stage axis: never fuse
-        # (Optimizer.update's caller contract — concat of sharded leaves
-        # mispartitions under GSPMD)
         new_params, new_opt = optimizer.update(stacked_params, grads,
-                                               opt_state, fused=False)
+                                               opt_state)
         return loss, new_params, new_opt
 
     donate_argnums = (0, 1) if donate else ()

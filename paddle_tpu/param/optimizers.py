@@ -24,10 +24,6 @@ import jax.numpy as jnp
 
 from paddle_tpu.utils.registry import Registry
 
-#: lanes of the TPU's (8, 128) tile: an array whose minor dimension is not a
-#: multiple of this is stored padded, and raveling it moves data
-_LANES = 128
-
 __all__ = [
     "dedup_rows",
     "Optimizer",
@@ -204,7 +200,6 @@ class Optimizer:
         statics: Optional[Dict[str, bool]] = None,
         sparse_rows: Optional[Dict[str, Any]] = None,  # bool mask path or int K
         clip: bool = True,  # False: caller already applied global-norm clip
-        fused: Optional[bool] = None,  # None = FLAGS.fused_apply
     ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
         """``sparse_rows`` marks row-sparse parameters (embedding tables with
         ParamAttr(sparse_grad=True)): rows a batch never touched keep their
@@ -233,49 +228,6 @@ class Optimizer:
         lr = self.lr_at(step)
         if self.gradient_clipping_threshold > 0 and clip:
             grads, _ = clip_by_global_norm(grads, self.gradient_clipping_threshold)
-        if fused is None:
-            from paddle_tpu.utils.flags import FLAGS
-
-            fused = bool(FLAGS.fused_apply)
-        # fused multi-tensor apply (ROADMAP item 3): dense leaves sharing
-        # (dtype, lr scale, decay) flatten into ONE concatenated segment and
-        # update as a single fused kernel chain instead of one launch chain
-        # per leaf — the update rules are elementwise, and the scalars are
-        # identical per group, so the result is BIT-identical to the
-        # per-leaf path (pinned by tests/test_amp.py).  Static, pruned-out
-        # zero-size, and row-sparse leaves keep their dedicated paths.
-        # CALLER CONTRACT: pass ``fused=False`` when leaves carry
-        # heterogeneous tensor-parallel shardings (the trainer does this
-        # automatically for sharding_rules/pipeline) — concatenating
-        # differently-sharded leaves under a mesh with a data axis makes
-        # GSPMD mispartition the segment (measured: results scaled by the
-        # data-axis size on the DPxTP test mesh); shardings are not
-        # visible on tracers, so the optimizer cannot detect this itself.
-        fuse_groups: Dict[Any, list] = {}
-        if fused:
-            for k, p in params.items():
-                if statics and statics.get(k):
-                    continue
-                if sparse_rows and sparse_rows.get(k) is not None \
-                        and sparse_rows.get(k) is not False:
-                    continue
-                if not hasattr(p, "dtype") or p.size == 0:
-                    continue
-                if p.ndim >= 2 and p.shape[-1] % _LANES:
-                    # raveling it is a relayout on the TPU, not a view, and
-                    # one such leaf (an fc [512, 2]) in the segment made
-                    # the TPU compiler spend time in proportion to the
-                    # whole segment: 40 s per million elements, 351 s for
-                    # the LSTM text classifier's step (jaxlib 0.9.0 /
-                    # libtpu 0.0.34, compile-only).  It keeps its own chain
-                    continue
-                key = (str(p.dtype),
-                       lr_scales.get(k, 1.0) if lr_scales else 1.0,
-                       (decays.get(k, 0.0) if decays else 0.0))
-                fuse_groups.setdefault(key, []).append(k)
-            fuse_groups = {key: names for key, names in fuse_groups.items()
-                           if len(names) >= 2}
-        fused_names = {k for names in fuse_groups.values() for k in names}
 
         def _masked_update(p, g, old_slots, touched, lr_eff):
             """Full-tensor update with untouched rows held — the ONE masked
@@ -299,14 +251,7 @@ class Optimizer:
             return p2.astype(p.dtype), s2
 
         new_params, new_slots = {}, {}
-        for key, names in fuse_groups.items():
-            _, scale, decay = key
-            self._fused_apply(names, params, grads, opt_state["slots"],
-                              new_params, new_slots, lr * scale, step,
-                              decay + self.l2_rate)
         for k, p in params.items():
-            if k in fused_names:
-                continue
             g = grads[k]
             if statics and statics.get(k):
                 new_params[k], new_slots[k] = p, opt_state["slots"][k]
@@ -353,56 +298,6 @@ class Optimizer:
             new_params[k] = p2.astype(p.dtype)
             new_slots[k] = s2
         return new_params, {"step": step, "slots": new_slots}
-
-    # ------------------------------------------------------------------
-    # fused multi-tensor apply
-    # ------------------------------------------------------------------
-
-    def _fused_apply(self, names, params, grads, slots, new_params,
-                     new_slots, lr_eff, step, decay) -> None:
-        """Update the leaves in ``names`` as ONE flattened segment.
-
-        Every leaf is raveled to 1-D and concatenated (params, grads, and
-        each slot stream — slot structure is uniform per optimizer class),
-        ``update_leaf`` runs once on the [N] segment, and the results are
-        sliced back to leaf shapes.  ``update_leaf`` rules are elementwise
-        in (p, g, slots) with scalar hyperparameters, and every leaf in
-        the group shares the same effective lr and decay, so each element
-        sees the EXACT arithmetic of its per-leaf update — bit-identity by
-        construction, with the O(leaves) kernel-launch chain replaced by
-        one fused chain (plus layout ops XLA folds into its neighbors)."""
-        sizes = [int(params[k].size) for k in names]
-        offsets = []
-        off = 0
-        for s in sizes:
-            offsets.append(off)
-            off += s
-
-        def pack(leaves):
-            return jnp.concatenate([x.reshape(-1) for x in leaves])
-
-        def unpack(flat, k_idx):
-            k = names[k_idx]
-            seg = jax.lax.slice(flat, (offsets[k_idx],),
-                                (offsets[k_idx] + sizes[k_idx],))
-            return seg.reshape(params[k].shape)
-
-        p_f = pack([params[k] for k in names])
-        g_f = pack([grads[k] for k in names])
-        g_f = _regularize(p_f, g_f, decay, self.l1_rate)
-        # slot streams: zip the per-leaf slot pytrees (same structure for
-        # every leaf of one optimizer class) and concat leaf-wise
-        s_f = jax.tree_util.tree_map(lambda *xs: pack(xs),
-                                     *[slots[k] for k in names])
-        p2_f, s2_f = self.update_leaf(p_f, g_f, s_f, lr_eff, step)
-        p2_f = p2_f.astype(p_f.dtype)
-        for i, k in enumerate(names):
-            new_params[k] = unpack(p2_f, i)
-            new_slots[k] = jax.tree_util.tree_map(
-                lambda flat, i=i, k=k: jax.lax.slice(
-                    flat, (offsets[i],),
-                    (offsets[i] + sizes[i],)).reshape(params[k].shape),
-                s2_f)
 
     def row_apply(self, p, rows, g_rows, old_slots, live, lr_eff, step, *,
                   decay: float = 0.0, oob_drop: bool = False):

@@ -248,14 +248,6 @@ class SGDTrainer:
                 f"set FLAGS.amp (or --amp) to toggle mixed precision")
         self.amp = bool(FLAGS.amp if amp is None else amp)
         self.remat = bool(FLAGS.remat if remat is None else remat)
-        # fused multi-tensor apply is safe only when every dense leaf
-        # shares placement: tensor-parallel sharding rules and pipeline
-        # stage-stacked params mix shardings, and concatenating those
-        # mispartitions under GSPMD (see Optimizer.update) — data-parallel
-        # replicated params (the common case) fuse freely
-        self.fused_apply = bool(FLAGS.fused_apply
-                                and sharding_rules is None
-                                and pipeline is None)
         self.amp_overflows_total = 0
         self.opt_state = self.optimizer.init_state(self.params)
         if self.amp:
@@ -365,7 +357,6 @@ class SGDTrainer:
         tier = self.pserver
         amp = self.amp
         remat = self.remat
-        fused_apply = self.fused_apply
         growth_interval = int(FLAGS.loss_scale_growth)
         max_scale = float(FLAGS.loss_scale_max)
         # SDC firewall: fold the post-update params + optimizer slots
@@ -449,7 +440,7 @@ class SGDTrainer:
                 np_, no_ = opt.update(
                     p, g, o,
                     lr_scales=lr_scales, decays=decays, statics=statics,
-                    sparse_rows=sparse_rows, clip=clip, fused=fused_apply,
+                    sparse_rows=sparse_rows, clip=clip,
                 )
                 ps_out = (tier.apply_grads(ps_in, feed, pxg)
                           if tier is not None else ps_in)

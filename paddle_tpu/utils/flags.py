@@ -196,11 +196,6 @@ define_flag("remat", False, "rematerialize the forward inside the "
             "backward (jax.checkpoint around the loss closure): trades "
             "~1/3 more FLOPs for O(layer) activation memory, buying the "
             "larger batches the MFU-starved recurrent models need")
-define_flag("fused_apply", True, "fused multi-tensor optimizer apply: "
-            "same-dtype/same-attribute parameter leaves are flattened "
-            "into one concatenated segment so SGD/Momentum/Adam/... "
-            "update as O(1) fused kernels instead of one launch chain "
-            "per leaf — bit-identical to the per-leaf path")
 
 # Trainer loop (log_period, test_period, checkgrad ...)
 define_flag("log_period", 100, "log every N batches")

@@ -14,9 +14,10 @@ Coverage map (the PR's acceptance bars):
 - checkpoint/resume: masters restore bit-exact, a resumed --amp run
   (scale state included) matches an uninterrupted one exactly;
 - convergence parity bf16-vs-f32 on a small model within tolerance;
-- fused multi-tensor apply: bit-identical params AND slots vs the
-  per-leaf path for every shipped optimizer (clipping, lr scales, decays,
-  statics, sparse exclusions), with a >=5x compute-equation reduction;
+- the optimizer apply: ``update`` == ``update_leaf`` on each leaf alone,
+  bit-identical params AND slots for every shipped optimizer (clipping,
+  lr scales, decays, statics, row-sparse leaves beside dense ones), with
+  no concatenate in its jaxpr and one chain per leaf;
 - --remat: identical training trajectory with remat in the jaxpr.
 """
 
@@ -48,11 +49,11 @@ def amp_on(monkeypatch):
     yield
 
 
-def _mse_trainer(seed=0, **kw):
+def _mse_trainer(seed=0, opt=None, **kw):
     x = nn.data("x", size=4)
     y = nn.data("y", size=2)
     cost = nn.mse_cost(input=nn.fc(x, 2, act="relu", name="h"), label=y)
-    return SGDTrainer(cost, Adam(learning_rate=0.05), seed=seed, **kw)
+    return SGDTrainer(cost, opt or Adam(learning_rate=0.05), seed=seed, **kw)
 
 
 def _feeds(n=6, batch=4):
@@ -152,7 +153,7 @@ def test_amp_masters_stay_f32_and_loss_tracks_f32(amp_on, monkeypatch):
 
 def test_real_lstm_step_has_zero_f32_matmuls_under_amp(amp_on):
     """Acceptance: the compiled --amp train step (embedding + LSTM + CE +
-    loss scaling + guarded fused apply) contains ZERO non-allowlisted f32
+    loss scaling + guarded optimizer apply) contains ZERO non-allowlisted f32
     dot_generals — asserted over the REAL trainer step jaxpr."""
     from paddle_tpu.analysis import audit_amp_matmuls
 
@@ -367,27 +368,60 @@ def test_amp_convergence_parity_small_model(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# fused multi-tensor apply
+# the optimizer apply: update_leaf on every dense leaf, on its own
 # ---------------------------------------------------------------------------
 
 
-_FUSE_PARAMS = None
+_APPLY_LEAVES = None
 
 
-def _fuse_fixtures():
-    global _FUSE_PARAMS
-    if _FUSE_PARAMS is None:
+def _apply_fixtures():
+    global _APPLY_LEAVES
+    if _APPLY_LEAVES is None:
         rs = np.random.RandomState(0)
-        # vectors, and arrays whose minor dimension fills whole lanes:
-        # what the fused apply takes into a segment
         shapes = [(4, 128), (8,), (3, 3, 128), (16,), (2, 256), (5, 128),
                   (7,), (4, 4, 128), (10,), (6, 384), (8, 128), (3,)]
         params = {f"p{i}": jnp.asarray(rs.randn(*s).astype(np.float32))
                   for i, s in enumerate(shapes)}
         grads = {k: jnp.asarray(rs.randn(*v.shape).astype(np.float32))
                  for k, v in params.items()}
-        _FUSE_PARAMS = (params, grads)
-    return _FUSE_PARAMS
+        _APPLY_LEAVES = (params, grads)
+    return _APPLY_LEAVES
+
+
+def _hand_apply(opt, params, grads, opt_state, *, lr_scales=None,
+                decays=None, statics=None, **_):
+    """What ``Optimizer.update`` has to equal on dense leaves, written out:
+    ``update_leaf`` on each leaf alone, with that leaf's own scalars,
+    parameter and slots (clipping first, over all the gradients)."""
+    from paddle_tpu.param.optimizers import clip_by_global_norm
+
+    step = opt_state["step"] + 1
+    lr = opt.lr_at(step)
+    if opt.gradient_clipping_threshold > 0:
+        grads, _ = clip_by_global_norm(grads,
+                                       opt.gradient_clipping_threshold)
+    new_params, new_slots = {}, {}
+    for k, p in params.items():
+        slots = opt_state["slots"][k]
+        if (statics or {}).get(k):
+            new_params[k], new_slots[k] = p, slots
+            continue
+        g = grads[k]
+        decay = (decays or {}).get(k, 0.0) + opt.l2_rate
+        if decay:
+            g = g + decay * p
+        p2, new_slots[k] = opt.update_leaf(
+            p, g, slots, lr * (lr_scales or {}).get(k, 1.0), step)
+        new_params[k] = p2.astype(p.dtype)
+    return new_params, {"step": step, "slots": new_slots}
+
+
+def _assert_trees_bit_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 @pytest.mark.parametrize("opt", [
@@ -406,144 +440,155 @@ def _fuse_fixtures():
        f"{'_clip' if o.gradient_clipping_threshold else ''}"
        f"{'_bf16slots' if getattr(o, 'slot_dtype', None) else ''}"
        f"{'_nesterov' if getattr(o, 'use_nesterov', False) else ''}")
-def test_fused_apply_bit_identical_params_and_slots(opt):
-    """Acceptance: fused multi-tensor apply == per-leaf path, bit for bit,
-    params AND slots, for all shipped optimizers incl. clipping — with
-    mixed per-param attributes so several fuse groups exist."""
-    import copy
-
-    params, grads = _fuse_fixtures()
-    a, b = opt, copy.deepcopy(opt)
-    kw = dict(lr_scales={"p1": 0.5}, decays={"p2": 0.01},
-              statics={"p3": True})
-    sa, sb = a.init_state(params), b.init_state(params)
-    pa, pb = dict(params), dict(params)
+def test_update_is_update_leaf_on_each_leaf_alone(opt):
+    """Every shipped optimizer's ``update`` over twelve mixed leaves (an lr
+    scale, a decay and a static among them) == ``update_leaf`` called on
+    each leaf alone, bit for bit, params AND slots, over three steps; the
+    clipping case feeds the per-leaf side the clipped gradients."""
+    params, grads = _apply_fixtures()
+    kw = dict(lr_scales={"p1": 0.5, "p4": 2.0},
+              decays={"p2": 0.01, "p9": 0.003}, statics={"p3": True})
+    sa = sb = opt.init_state(params)
+    pa = pb = dict(params)
     for _ in range(3):
-        pa, sa = a.update(pa, grads, sa, fused=False, **kw)
-        pb, sb = b.update(pb, grads, sb, fused=True, **kw)
-    for k in params:
-        np.testing.assert_array_equal(np.asarray(pa[k]), np.asarray(pb[k]),
-                                      err_msg=k)
-    for x, y in zip(jax.tree_util.tree_leaves(sa),
-                    jax.tree_util.tree_leaves(sb)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        pa, sa = opt.update(pa, grads, sa, **kw)
+        pb, sb = _hand_apply(opt, pb, grads, sb, **kw)
+    _assert_trees_bit_equal((pa, sa), (pb, sb))
 
 
-def test_fused_apply_excludes_sparse_rows_and_matches():
-    """Row-sparse leaves keep their dedicated paths under the fused
-    default (no pserver interference); results match the unfused call."""
+def test_update_of_dense_and_row_sparse_leaves_mixed_equals_each_alone():
+    """Dense leaves, a row-sparse table on the K fast path and one on the
+    masked path in ONE call == each leaf updated in a call of its own:
+    no leaf's update depends on which other leaves ride along."""
     rs = np.random.RandomState(3)
     V, D = 50, 8
     params = {"emb": jnp.asarray(rs.randn(V, D).astype(np.float32)),
+              "emb_masked": jnp.asarray(rs.randn(V, D).astype(np.float32)),
               "w": jnp.asarray(rs.randn(D, 4).astype(np.float32)),
               "b": jnp.asarray(rs.randn(4).astype(np.float32))}
     ge = np.zeros((V, D), np.float32)
     for r in (3, 7, 20):
         ge[r] = rs.randn(D)
-    grads = {"emb": jnp.asarray(ge),
+    grads = {"emb": jnp.asarray(ge), "emb_masked": jnp.asarray(ge[::-1]),
              "w": jnp.asarray(rs.randn(D, 4).astype(np.float32)),
              "b": jnp.asarray(rs.randn(4).astype(np.float32))}
-    a, b = Adam(learning_rate=0.1), Adam(learning_rate=0.1)
-    sa, sb = a.init_state(params), b.init_state(params)
-    pa, sa = a.update(dict(params), grads, sa, fused=False,
-                      sparse_rows={"emb": 8})
-    pb, sb = b.update(dict(params), grads, sb, fused=True,
-                      sparse_rows={"emb": 8})
-    for k in params:
-        np.testing.assert_array_equal(np.asarray(pa[k]), np.asarray(pb[k]))
-
-
-def test_fused_apply_leaves_narrow_minor_dimensions_per_leaf():
-    """A leaf whose minor dimension does not fill whole lanes ([512, 2])
-    is stored padded on the TPU, so raveling it into the segment moves
-    data — and made the installed TPU compiler take minutes over one
-    trainer step.  It stays out of the segment (nothing of its size is
-    concatenated) and the result is still bit-identical."""
-    rs = np.random.RandomState(1)
-    shapes = {"w": (64, 128), "b": (128,), "fc_w": (64, 2), "fc_b": (2,)}
-    params = {k: jnp.asarray(rs.randn(*v).astype(np.float32))
-              for k, v in shapes.items()}
-    grads = {k: jnp.asarray(rs.randn(*v).astype(np.float32))
-             for k, v in shapes.items()}
+    sparse_rows = {"emb": 8, "emb_masked": True}
     opt = Adam(learning_rate=0.1)
-    s = opt.init_state(params)
-    jx = jax.make_jaxpr(lambda p, g, st: opt.update(p, g, st, fused=True))(
-        params, grads, s)
-    packed = [e for e in jx.jaxpr.eqns if e.primitive.name == "concatenate"]
-    assert packed
-    for e in packed:
-        assert sorted(v.aval.size for v in e.invars) == [2, 128, 64 * 128]
-    pa, sa = opt.update(params, grads, s, fused=False)
-    pb, sb = opt.update(params, grads, s, fused=True)
-    for x, y in zip(jax.tree_util.tree_leaves((pa, sa)),
-                    jax.tree_util.tree_leaves((pb, sb))):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    state = opt.init_state(params)
+    together = opt.update(params, grads, state, sparse_rows=sparse_rows,
+                          decays={"w": 0.01})
+    for k in params:
+        p1, s1 = opt.update(
+            {k: params[k]}, {k: grads[k]},
+            {"step": state["step"], "slots": {k: state["slots"][k]}},
+            sparse_rows=sparse_rows, decays={"w": 0.01})
+        _assert_trees_bit_equal((together[0][k], together[1]["slots"][k]),
+                                (p1[k], s1["slots"][k]))
+    # the untouched rows of both tables did not move
+    for k in ("emb", "emb_masked"):
+        still = np.all(np.asarray(grads[k]) == 0, axis=1)
+        np.testing.assert_array_equal(np.asarray(together[0][k])[still],
+                                      np.asarray(params[k])[still])
 
 
-#: primitives that are pure data layout — XLA folds them into the
-#: adjacent fused kernels, so they do not launch work of their own
-_LAYOUT_PRIMS = {"reshape", "concatenate", "slice", "squeeze", "transpose",
-                 "broadcast_in_dim"}
+def _primitives(jaxpr):
+    from collections import Counter
 
-
-def test_fused_apply_reduces_compute_equations_5x():
-    """Acceptance: the fused apply reduces the optimizer-apply equation
-    count by >=5x on a multi-leaf model.  Counted over COMPUTE equations
-    (layout-only reshape/concat/slice excluded — they are free data
-    movement XLA folds into neighbors; the per-leaf path's cost is one
-    elementwise kernel CHAIN per leaf, which is exactly what collapses)."""
     from paddle_tpu.analysis.jaxpr_walk import walk_eqns
 
-    params, grads = _fuse_fixtures()
+    return Counter(e.primitive.name for e, _ in walk_eqns(jaxpr))
+
+
+def test_update_jaxpr_has_no_concatenate_and_one_chain_per_leaf():
+    """Structure of the one path: nothing is gathered into a segment (no
+    ``concatenate``, no ``slice``), and the update is one ``update_leaf``
+    chain per leaf — every primitive ``Adam.update_leaf`` holds once
+    appears once per leaf, no more."""
+    params, grads = _apply_fixtures()
     opt = Adam(learning_rate=0.1)
-    s = opt.init_state(params)
+    state = opt.init_state(params)
+    whole = _primitives(jax.make_jaxpr(opt.update)(params, grads,
+                                                   state).jaxpr)
+    assert not {"concatenate", "slice", "dynamic_slice"} & set(whole)
+    p = params["p0"]
+    chain = _primitives(jax.make_jaxpr(
+        lambda p, g, s, lr, t: opt.update_leaf(p, g, s, lr, t))(
+        p, p, state["slots"]["p0"], 0.1, state["step"]).jaxpr)
+    once = [name for name, n in chain.items() if n == 1]
+    assert "sqrt" in once, chain
+    for name in once:
+        assert whole[name] == len(params), (name, whole[name])
 
-    def count(fused):
-        jx = jax.make_jaxpr(
-            lambda p, g, st: opt.update(p, g, st, fused=fused))(
-            params, grads, s)
-        return sum(1 for e, _ in walk_eqns(jx.jaxpr)
-                   if e.primitive.name not in _LAYOUT_PRIMS)
 
-    per_leaf, fused = count(False), count(True)
-    assert per_leaf >= 5 * fused, (per_leaf, fused)
+def test_narrow_leaf_lowers_beside_wide_leaves_as_it_does_alone():
+    """A leaf whose minor dimension does not fill whole lanes (an fc
+    ``[512, 2]``: stored padded on the TPU, where reshaping it moves data
+    and once cost a 351 s compile inside a concatenated segment) gets the
+    same equations on the same shapes whether wide leaves ride along or
+    not: nothing reshapes, ravels or joins it."""
+    rs = np.random.RandomState(1)
+    shapes = {"w": (64, 128), "b": (128,), "fc_w": (512, 2)}
+    params = {k: jnp.asarray(rs.randn(*v).astype(np.float32))
+              for k, v in shapes.items()}
+    opt = Adam(learning_rate=0.1)
+
+    def narrow_eqns(names):
+        sub = {k: params[k] for k in names}
+        jx = jax.make_jaxpr(opt.update)(sub, sub, opt.init_state(sub))
+        return [(e.primitive.name,
+                 tuple(v.aval.shape for v in e.invars),
+                 tuple(v.aval.shape for v in e.outvars))
+                for e in jx.jaxpr.eqns
+                if any(v.aval.shape == shapes["fc_w"]
+                       for v in list(e.invars) + list(e.outvars))]
+
+    alone, beside = narrow_eqns(["fc_w"]), narrow_eqns(list(shapes))
+    assert alone and alone == beside
+    assert not {"reshape", "concatenate", "slice"} & {e[0] for e in beside}
 
 
-def test_trainer_disables_fusion_under_tensor_parallel_shardings():
-    """Caller contract: concatenating differently-sharded leaves
-    mispartitions under GSPMD (measured: results scaled by the data-axis
-    size on a DPxTP mesh), and shardings are invisible on tracers — so
-    the trainer must disable fusion whenever sharding rules or pipeline
-    stages mix placements, and keep it for replicated data-parallel."""
+def test_trainer_on_data_mesh_agrees_with_and_without_replicating_rules():
+    """One path for every mesh: a trainer on a data mesh whose sharding
+    rules replicate every parameter ends three steps where the trainer
+    with no rules at all does."""
     import paddle_tpu.parallel as par
     from paddle_tpu.utils.devices import make_mesh
 
-    tr = _mse_trainer()
-    assert tr.fused_apply                        # no mesh: fuse freely
+    feeds = _feeds(3, batch=8)
     mesh = make_mesh((8,), ("data",))
-    nn.reset_naming()
     tr_dp = _mse_trainer(mesh=mesh)
-    assert tr_dp.fused_apply                     # replicated params: safe
-    rules = par.ShardingRules([("*", par.P())])
     nn.reset_naming()
-    tr_tp = _mse_trainer(mesh=mesh, sharding_rules=rules)
-    assert not tr_tp.fused_apply                 # rules may mix shardings
+    tr_rules = _mse_trainer(
+        mesh=mesh, sharding_rules=par.ShardingRules([("*", par.P())]))
+    for f in feeds:
+        tr_dp.train_batch(f)
+        tr_rules.train_batch(f)
+    assert set(tr_dp.params) == set(tr_rules.params)
+    for k in tr_dp.params:
+        np.testing.assert_array_equal(np.asarray(tr_dp.params[k]),
+                                      np.asarray(tr_rules.params[k]), k)
 
 
-def test_fused_apply_in_real_trainer_matches_unfused(monkeypatch):
+class _HandAppliedAdam(Adam):
+    """Adam whose ``update`` is the per-leaf loop written out by hand."""
+
+    def update(self, params, grads, opt_state, **kw):
+        return _hand_apply(self, params, grads, opt_state, **kw)
+
+
+def test_trainer_step_matches_hand_applied_per_leaf_update():
+    """The real trainer's jitted step (guard, donation, masks) ends three
+    batches bit-equal to the same step with the hand-written per-leaf
+    loop in the optimizer's place."""
     feeds = _feeds(3)
-    monkeypatch.setattr(FLAGS, "fused_apply", True)
     tr_a = _mse_trainer()
+    nn.reset_naming()
+    tr_b = _mse_trainer(opt=_HandAppliedAdam(learning_rate=0.05))
     for f in feeds:
         tr_a.train_batch(f)
-    monkeypatch.setattr(FLAGS, "fused_apply", False)
-    nn.reset_naming()
-    tr_b = _mse_trainer()
-    for f in feeds:
         tr_b.train_batch(f)
-    for k in tr_a.params:
-        np.testing.assert_array_equal(np.asarray(tr_a.params[k]),
-                                      np.asarray(tr_b.params[k]))
+    _assert_trees_bit_equal((tr_a.params, tr_a.opt_state),
+                            (tr_b.params, tr_b.opt_state))
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ Unit tier of the SDC defense (docs/resilience.md "Silent corruption"):
 
 - the in-jit fingerprint is BIT-STABLE — golden-pinned, identical to its
   host (numpy) twin for every supported dtype, invariant under jit
-  recompiles, device placement, mesh shape, and ``--fused_apply`` —
+  recompiles, device placement and mesh shape —
   while being decisively sensitive to a single flipped bit (and to
   WHERE it flipped);
 - the vote identifies a strict-majority minority exactly and falls back
@@ -142,7 +142,7 @@ def test_fingerprint_stable_across_recompile_and_placement():
         == fingerprint_int(np_tree_fingerprint(big))
 
 
-def _tiny_trainer(seed=0):
+def _tiny_trainer(seed=0, opt=None):
     from paddle_tpu.param.optimizers import Adam
     from paddle_tpu.trainer import SGDTrainer
 
@@ -150,7 +150,7 @@ def _tiny_trainer(seed=0):
     x = nn.data("ix", size=4)
     y = nn.data("iy", size=2)
     cost = nn.mse_cost(input=nn.fc(x, 2, act="relu", name="ih"), label=y)
-    return SGDTrainer(cost, Adam(learning_rate=0.05), seed=seed)
+    return SGDTrainer(cost, opt or Adam(learning_rate=0.05), seed=seed)
 
 
 def _feed(rs):
@@ -158,22 +158,34 @@ def _feed(rs):
             "iy": rs.randn(4, 2).astype(np.float32)}
 
 
-def test_step_fingerprint_stable_under_fused_apply_toggle(monkeypatch):
-    """Satellite pin: the per-leaf fingerprint must be bit-stable with
-    --fused_apply on vs off (the fused apply is bit-identical, so the
-    digests must be too) — a refactor cannot quietly turn agreement
-    checks into false alarms."""
+def test_step_fingerprint_matches_hand_applied_per_leaf_update(monkeypatch):
+    """Satellite pin: the in-step fingerprint of the trainer's state equals
+    that of the same step with ``update_leaf`` applied to each leaf by hand
+    in the optimizer's place — a refactor of ``Optimizer.update`` cannot
+    quietly turn agreement checks into false alarms."""
+    from paddle_tpu.param.optimizers import Adam
+
+    class HandApplied(Adam):
+        def update(self, params, grads, opt_state, **_):
+            step = opt_state["step"] + 1
+            out = {k: self.update_leaf(p, grads[k], opt_state["slots"][k],
+                                       self.lr_at(step), step)
+                   for k, p in params.items()}
+            return ({k: v[0] for k, v in out.items()},
+                    {"step": step,
+                     "slots": {k: v[1] for k, v in out.items()}})
+
     monkeypatch.setattr(FLAGS, "sdc_check_every", 2)
     fps = {}
-    for fused in (True, False):
-        monkeypatch.setattr(FLAGS, "fused_apply", fused)
-        tr = _tiny_trainer()
+    for opt in (Adam(learning_rate=0.05), HandApplied(learning_rate=0.05)):
+        nn.reset_naming()
+        tr = _tiny_trainer(opt=opt)
         rs = np.random.RandomState(7)
         tr.train_batch(_feed(rs))
         tr.train_batch(_feed(rs))
-        fps[fused] = fingerprint_int(
+        fps[type(opt).__name__] = fingerprint_int(
             jax.device_get(tr._last_extras["sdc_fp"]))
-    assert fps[True] == fps[False]
+    assert fps["Adam"] == fps["HandApplied"]
 
 
 def test_step_fingerprint_detects_inprocess_bit_flip(monkeypatch):

@@ -87,16 +87,21 @@ def _no_scopes(monkeypatch):
         yield
 
 
-def _seq2seq_step_jaxpr():
-    from benchmark.manifest import load_module
+def _seq2seq_demo():
     from paddle_tpu.models import Seq2SeqAttention
 
-    demo = load_module(os.path.join(ROOT, "demo", "seqToseq", "train.py"),
-                       "trace_names_seqToseq_demo")
     model = Seq2SeqAttention(src_vocab=50, trg_vocab=50, emb_dim=8,
                              enc_dim=8, dec_dim=8, att_dim=8)
     opt = Adam(learning_rate=1e-3)
-    params = model.init(jax.random.PRNGKey(0))
+    return model, opt, model.init(jax.random.PRNGKey(0))
+
+
+def _seq2seq_step_jaxpr():
+    from benchmark.manifest import load_module
+
+    demo = load_module(os.path.join(ROOT, "demo", "seqToseq", "train.py"),
+                       "trace_names_seqToseq_demo")
+    model, opt, params = _seq2seq_demo()
     batch = {"src_ids": np.ones((4, 5), np.int32),
              "src_len": np.full((4,), 5, np.int32),
              "trg_in": np.ones((4, 6), np.int32),
@@ -106,13 +111,17 @@ def _seq2seq_step_jaxpr():
     return jax.make_jaxpr(step)(params, opt.init_state(params), batch)
 
 
-def _trainer_step_jaxpr():
+def _lstm_trainer():
     from paddle_tpu.models import lstm_benchmark_net
 
     nn.reset_naming()
     cost, _ = lstm_benchmark_net(50, emb_dim=8, hid_dim=8, num_layers=2,
                                  num_classes=2)
-    tr = SGDTrainer(cost, Adam(learning_rate=1e-3), seed=0)
+    return SGDTrainer(cost, Adam(learning_rate=1e-3), seed=0)
+
+
+def _trainer_step_jaxpr():
+    tr = _lstm_trainer()
     feed = {name: value for name, value in zip(
         sorted(l.name for l in tr.topology.layers if l.is_data),
         [np.zeros((4,), np.int32),
@@ -136,6 +145,55 @@ def test_jitted_step_holds_the_scopes_and_the_same_equations(
     assert not scopes & _scope_names(_name_stacks(bare.jaxpr))
     # names and metadata only: the same equations on the same shapes
     assert str(named) == str(bare)
+
+
+def _primitives_under(jaxpr, scope, inside=False, out=None):
+    """The primitives of the equations ``scope`` encloses, through
+    sub-jaxprs: an equation is inside when its own name stack holds the
+    scope or the equation that carries its jaxpr is inside.  A carrier
+    (``pjit``, ``cond``) counts through what it carries, not itself."""
+    from collections import Counter
+
+    out = Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        within = inside or scope in _scope_names(
+            {str(eqn.source_info.name_stack)})
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if within and not subs:
+            out[eqn.primitive.name] += 1
+        for sub in subs:
+            _primitives_under(sub, scope, within, out)
+    return out
+
+
+def _seq2seq_update_jaxpr():
+    _, opt, params = _seq2seq_demo()
+    return jax.make_jaxpr(opt.update)(params, params, opt.init_state(params))
+
+
+def _trainer_update_jaxpr():
+    tr = _lstm_trainer()
+    return jax.make_jaxpr(lambda p, g, o: tr.optimizer.update(
+        p, g, o, lr_scales=tr.lr_scales, decays=tr.decays,
+        statics=tr.statics, sparse_rows=tr.sparse_rows))(
+        tr.params, tr.params, tr.opt_state)
+
+
+@pytest.mark.parametrize("build_step, build_update", [
+    (_seq2seq_step_jaxpr, _seq2seq_update_jaxpr),
+    (_trainer_step_jaxpr, _trainer_update_jaxpr),
+], ids=["seqToseq_demo_step", "trainer_step"])
+def test_optimizer_apply_encloses_every_equation_of_the_update(
+        build_step, build_update):
+    """``device_ms_per_step.optimizer`` reads the scope ``optimizer_apply``:
+    in both jitted steps the scope holds exactly the equations that
+    ``Optimizer.update`` traces to on its own, every leaf's chain among
+    them, so none of the update's device time goes unscoped."""
+    update = build_update().jaxpr
+    alone = _primitives_under(update, "optimizer_apply")
+    assert alone == _primitives_under(update, "optimizer_apply", inside=True)
+    assert alone["sqrt"] >= 2          # Adam's chain, once per leaf
+    assert _primitives_under(build_step().jaxpr, "optimizer_apply") == alone
 
 
 # -- (c) the trainer's host spans -------------------------------------------

@@ -4,3 +4,4 @@ from paddle_tpu.models.text import (stacked_lstm_net, stacked_lstm_pp_net,
 from paddle_tpu.models.seq2seq import Seq2SeqAttention
 from paddle_tpu.models.recommender import movielens_net, movielens_feature_net
 from paddle_tpu.models.image_bench import alexnet, googlenet
+from paddle_tpu.models.lfm2 import lfm2_moe_net

@@ -19,6 +19,7 @@ from paddle_tpu.nn.layers import *  # noqa: F401,F403
 from paddle_tpu.nn.layers_extra import *  # noqa: F401,F403
 from paddle_tpu.nn.layers_extra2 import *  # noqa: F401,F403
 from paddle_tpu.nn.projections import *  # noqa: F401,F403
+from paddle_tpu.nn.layers_decoder import *  # noqa: F401,F403
 from paddle_tpu.nn.recurrent import (Memory, StaticInput, GeneratedInput,
                                      recurrent_group, beam_search, SequenceGenerator)
 from paddle_tpu.nn.steps import lstm_step, gru_step

@@ -353,6 +353,7 @@ class Topology:
         outputs: Optional[Sequence[str]] = None,
         device_specs: Optional[Dict[str, Any]] = None,
         param_overrides: Optional[Dict[str, Any]] = None,
+        remat_layers: bool = False,
     ) -> Tuple[Dict[str, Act], Dict[str, Any]]:
         """Run the graph. ``feed`` maps data-layer name -> Act | array |
         (value, lengths). Returns ({layer_name: Act}, new_state).
@@ -369,33 +370,100 @@ class Topology:
         config_parser.py:1772-1848).  Layers tagged via ``device_pin(node,
         tag)`` get ``lax.with_sharding_constraint(value, device_specs[tag])``
         on their output — XLA/GSPMD then places per-layer compute on the
-        matching mesh shards instead of spawning per-device threads."""
+        matching mesh shards instead of spawning per-device threads.
+
+        Recomputation: layers marked as one block (``nn.remat_block``) run
+        under one ``jax.checkpoint``; ``remat_layers`` makes every layer
+        that is in no block a block of its own (``SGDTrainer(remat=True)``,
+        ``--remat``)."""
         ctx = ApplyContext(train, rng)
         env: Dict[str, Act] = {}
         all_params = {**params, **state, **(param_overrides or {})}
         want = set(outputs) if outputs is not None else None
         needed = self.layers if want is None else self._needed_layers(want)
+
+        def block_of(layer):
+            if layer.is_data:
+                return None
+            return layer.meta.get("remat") or (
+                layer.name if remat_layers else None)
+
+        done = set()
         for layer in needed:
-            # named_scope: the device trace names each operation by its layer
-            with layer_scope(layer.name), jax.named_scope(layer.name):
-                if layer.is_data:
-                    act = _coerce_feed(layer, feed)
-                else:
-                    parent_acts = [env[p.name] for p in layer.parents]
-                    local = {s.name: all_params[s.name] for s in layer.param_specs}
-                    act = layer.forward(ctx, local, *parent_acts)
-                tag = layer.meta.get("device")
-                if device_specs and tag is not None and tag in device_specs:
-                    act = replace(
-                        act,
-                        value=jax.lax.with_sharding_constraint(
-                            act.value, device_specs[tag]
-                        ),
-                    )
-                env[layer.name] = act
+            tag = block_of(layer)
+            if tag is None:
+                env[layer.name] = self._run_layer(
+                    layer, env, all_params, ctx, feed, device_specs)
+            elif tag not in done:
+                done.add(tag)
+                self._run_remat_block(
+                    tag, [l for l in needed if block_of(l) == tag], env,
+                    all_params, ctx, feed, device_specs)
         new_state = {**state, **ctx.updated_state}
         result = {l.name: env[l.name] for l in self.layers if l.name in env}
         return result, new_state
+
+    @staticmethod
+    def _run_layer(layer, env, all_params, ctx, feed, device_specs) -> Act:
+        # named_scope: the device trace names each operation by its layer
+        with layer_scope(layer.name), jax.named_scope(layer.name):
+            if layer.is_data:
+                act = _coerce_feed(layer, feed)
+            else:
+                parent_acts = [env[p.name] for p in layer.parents]
+                local = {s.name: all_params[s.name] for s in layer.param_specs}
+                act = layer.forward(ctx, local, *parent_acts)
+            tag = layer.meta.get("device")
+            if device_specs and tag is not None and tag in device_specs:
+                act = replace(
+                    act,
+                    value=jax.lax.with_sharding_constraint(
+                        act.value, device_specs[tag]
+                    ),
+                )
+        return act
+
+    def _run_remat_block(self, tag, block, env, all_params, ctx, feed,
+                         device_specs) -> None:
+        """The layers of one recomputation block (``nn.remat_block``, or one
+        layer under ``remat_layers``) under ONE ``jax.checkpoint``: the
+        backward pass holds what the block reads from outside it and
+        recomputes its layers, so the activations of one block at a time
+        are live, not of the whole stack."""
+        inside = {l.name for l in block}
+        reads = sorted({p.name for l in block for p in l.parents
+                        if p.name not in inside})
+        late = [n for n in reads if n not in env]
+        if late:
+            raise ConfigError(
+                f"recomputation block {tag!r} is not closed: it reads "
+                f"{late}, computed after its first layer {block[0].name!r}")
+        local = {s.name: all_params[s.name]
+                 for l in block for s in l.param_specs}
+        if any(hasattr(v, "pserver_lookup") for v in local.values()):
+            # a table routed through the pserver tier is a proxy, not an
+            # array jax.checkpoint could take: its layers run as they are
+            for l in block:
+                env[l.name] = self._run_layer(l, env, all_params, ctx, feed,
+                                              device_specs)
+            return
+
+        def run(local, read, key):
+            sub = ApplyContext(ctx.train, key)
+            acts = dict(read)
+            for l in block:
+                acts[l.name] = self._run_layer(l, acts, local, sub, feed,
+                                               device_specs)
+            return {l.name: acts[l.name] for l in block}, sub.updated_state
+
+        # what an op names "remat_keep" (attention's output, a routing's
+        # sort) is held with the block's inputs and not computed twice
+        keep = jax.checkpoint_policies.save_only_these_names("remat_keep")
+        acts, updated = jax.checkpoint(run, policy=keep)(
+            local, {n: env[n] for n in reads},
+            ctx.next_rng() if ctx.train else None)
+        env.update(acts)
+        ctx.updated_state.update(updated)
 
     def _needed_layers(self, want: set) -> List[LayerOutput]:
         by_name = {l.name: l for l in self.layers}

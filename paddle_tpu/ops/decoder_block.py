@@ -1,0 +1,200 @@
+"""The sequence mixers and norms of a decoder-only block.
+
+Nothing of the reference (2016) has these; they are what today's decoder
+stacks are made of: RMSNorm, rotary position embedding (half-rotation form),
+a gated short causal convolution (depthwise, a few taps), and causal
+grouped-query attention computed blockwise so that the ``[T, T]`` scores of
+a long row never exist in HBM.
+
+Attention has two paths behind one ``jax.custom_vjp``: on the TPU, at
+tile-aligned shapes, the flash kernels of ops/pallas_kernels.py
+(``flash_attn_fwd`` / ``flash_attn_dq`` / ``flash_attn_dkv``); elsewhere a
+loop over blocks of queries in XLA, each block against the keys at or before
+its last row, with the same saved statistics (the output and the rows'
+log-sum-exp) and a backward that recomputes each block's scores.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from paddle_tpu.ops.numerics import acc_dtype, dot_dtype, mxu_cast
+
+__all__ = ["rms_norm", "rotary_embedding", "causal_short_conv",
+           "causal_attention", "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
+
+#: queries per block of the XLA path
+ATTN_XLA_BLOCK = 512
+
+
+def rms_norm(x, w, eps: float):
+    """``x / rms(x) * w`` over the last axis; statistics in float32."""
+    xf = x.astype(acc_dtype())
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (xf * inv * w.astype(acc_dtype())).astype(x.dtype)
+
+
+def rotary_embedding(x, theta: float):
+    """x ``[B, T, heads, dh]`` at positions ``0..T-1``: the half-rotation
+    form (the first half of a head's channels pairs with the second)."""
+    T, dh = x.shape[1], x.shape[-1]
+    f32 = acc_dtype()
+    inv = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
+    ang = jnp.arange(T, dtype=f32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None, :]
+    xf = x.astype(f32)
+    x1, x2 = xf[..., :dh // 2], xf[..., dh // 2:]
+    return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+
+
+def causal_short_conv(z, kernel):
+    """Depthwise causal convolution over time: ``z`` ``[B, T, D]``, ``kernel``
+    ``[L, D]``; ``out_t = sum_j kernel[j] * z[t - (L-1) + j]``, zero before
+    the row's start.  ``L`` shifted multiply-adds: at a few taps this is
+    bandwidth, not a convolution worth a kernel."""
+    L, T = kernel.shape[0], z.shape[1]
+    zp = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
+    k = kernel.astype(z.dtype)
+    out = k[0] * zp[:, 0:T]
+    for j in range(1, L):
+        out = out + k[j] * zp[:, j:j + T]
+    return out
+
+
+# ---------------------------------------------------------------------------
+# blockwise causal attention
+# ---------------------------------------------------------------------------
+
+
+def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int):
+    """The flash kernels' gate: ``(block_q, block_k)`` or ``None`` for the
+    XLA path.  Needs the TPU backend, a row length the blocks divide, a head
+    size that is a multiple of 64 and whole groups of query heads."""
+    from paddle_tpu.ops.pallas_kernels import compiled_kernels
+
+    if not compiled_kernels():
+        return None
+    if dh % 64 or H % Hkv:
+        return None
+    for blk in (1024, 512, 256, 128):
+        if T % blk == 0 and T >= 2 * blk:
+            return blk, blk
+    return None
+
+
+def _xla_fwd(q, k, v, scale, block):
+    """q ``[B, T, Hkv, G, dh]``, k/v ``[B, T, Hkv, dh]`` in the compute dtype
+    -> (out like q in float32, lse ``[B, Hkv, G, T]``)."""
+    T = q.shape[1]
+    f32 = acc_dtype()
+    outs, lses = [], []
+    for lo in range(0, T, block):
+        hi = min(T, lo + block)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, :hi],
+                       preferred_element_type=f32) * scale
+        rows = lo + jnp.arange(hi - lo)[:, None]
+        s = jnp.where(jnp.arange(hi)[None, :] <= rows, s, -jnp.inf)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v[:, :hi],
+                       preferred_element_type=f32)
+        outs.append(o / jnp.moveaxis(l, 3, 1))     # l [b,h,g,q,1]->[b,q,h,g,1]
+        lses.append((m + jnp.log(l))[..., 0])
+    return jnp.concatenate(outs, axis=1), jnp.concatenate(lses, axis=-1)
+
+
+def _xla_bwd(q, k, v, out, lse, d_out, scale, block):
+    T = q.shape[1]
+    f32 = acc_dtype()
+    delta = jnp.sum(d_out.astype(f32) * out.astype(f32), -1)   # [b,q,h,g]
+    dq = []
+    dk = jnp.zeros(k.shape, f32)
+    dv = jnp.zeros(v.shape, f32)
+    do_c = d_out.astype(v.dtype)
+    for lo in range(0, T, block):
+        hi = min(T, lo + block)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, :hi],
+                       preferred_element_type=f32) * scale
+        rows = lo + jnp.arange(hi - lo)[:, None]
+        live = jnp.arange(hi)[None, :] <= rows
+        p = jnp.where(live, jnp.exp(s - lse[..., lo:hi, None]), 0.0)
+        dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_c[:, lo:hi], v[:, :hi],
+                        preferred_element_type=f32)
+        dl = jnp.moveaxis(delta[:, lo:hi], 1, 3)[..., None]    # [b,h,g,q,1]
+        ds = (p * (dp - dl) * scale).astype(q.dtype)
+        dq.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, k[:, :hi],
+                             preferred_element_type=f32))
+        dk = dk.at[:, :hi].add(jnp.einsum(
+            "bhgqk,bqhgd->bkhd", ds, q[:, lo:hi], preferred_element_type=f32))
+        dv = dv.at[:, :hi].add(jnp.einsum(
+            "bhgqk,bqhgd->bkhd", p.astype(v.dtype), do_c[:, lo:hi],
+            preferred_element_type=f32))
+    return jnp.concatenate(dq, axis=1), dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attention(q, k, v, scale):
+    return _attention_fwd(q, k, v, scale)[0]
+
+
+def _attention_fwd(q, k, v, scale):
+    B, T, H, dh = q.shape
+    Hkv = k.shape[2]
+    qc, kc, vc = mxu_cast(q, k, v)
+    like = tuple(jnp.zeros((0,), a.dtype) for a in (q, k, v))
+    blocks = attention_kernel_blocks(T, dh, H, Hkv)
+    if blocks is not None:
+        from paddle_tpu.ops.pallas_kernels import flash_attn_fwd_pallas
+
+        # heads-major: a (head, block of rows) is one contiguous tile
+        qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (qc, kc, vc))
+        oh, lse = flash_attn_fwd_pallas(qh, kh, vh, scale=scale,
+                                        block_q=blocks[0], block_k=blocks[1])
+        # kept across a recomputation block: the backward's second forward
+        # recomputes the projections, not the attention
+        oh, lse = (checkpoint_name(a, "remat_keep") for a in (oh, lse))
+        out = jnp.swapaxes(oh, 1, 2)
+        return out.astype(dot_dtype()), (qh, kh, vh, oh, lse, like)
+    qg = qc.reshape(B, T, Hkv, H // Hkv, dh)
+    out, lse = _xla_fwd(qg, kc, vc, scale, ATTN_XLA_BLOCK)
+    return (out.reshape(B, T, H, dh).astype(dot_dtype()),
+            (qg, kc, vc, out, lse, like))
+
+
+def _attention_bwd(scale, res, d_out):
+    q, k, v, out, lse, like = res
+    if out.ndim == 4:       # the kernels' heads-major residuals
+        from paddle_tpu.ops.pallas_kernels import flash_attn_bwd_pallas
+
+        blocks = attention_kernel_blocks(q.shape[2], q.shape[3], q.shape[1],
+                                         k.shape[1])
+        do = jnp.swapaxes(d_out, 1, 2).astype(q.dtype)
+        dq, dk, dv = flash_attn_bwd_pallas(
+            q, k, v, out, lse, do, scale=scale, block_q=blocks[0],
+            block_k=blocks[1])
+        return tuple(jnp.swapaxes(a, 1, 2).astype(z.dtype)
+                     for a, z in zip((dq, dk, dv), like))
+    B, T, Hkv, G, dh = q.shape
+    dq, dk, dv = _xla_bwd(q, k, v, out, lse,
+                          d_out.reshape(B, T, Hkv, G, dh), scale,
+                          ATTN_XLA_BLOCK)
+    return tuple(a.astype(z.dtype) for a, z in zip(
+        (dq.reshape(B, T, Hkv * G, dh), dk, dv), like))
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+def causal_attention(q, k, v, *, scale: float):
+    """Causal softmax attention with grouped key-value heads: q ``[B, T, H,
+    dh]``, k/v ``[B, T, Hkv, dh]`` (key-value head ``j`` serves query heads
+    ``j*G .. j*G+G-1``, ``G = H / Hkv``) -> ``[B, T, H, dh]``.  bf16
+    operands under the default policy, float32 scores and statistics; the
+    scores exist one block at a time, forward and backward."""
+    return _attention(q, k, v, float(scale))

@@ -1,0 +1,289 @@
+"""A dropless expert layer as ONE chip of an expert-parallel deployment runs
+it: route every token over ALL the experts, keep the assignments that land
+on the experts held here, group them by expert (ragged groups, no capacity,
+nothing dropped), run the gated MLP of each group's expert as grouped matrix
+products, and combine the results back onto the tokens with the router's
+weights.  What the experts held elsewhere would have added is left out: on
+one chip the layer runs without its exchange, and nothing stands in for it.
+
+Layout.  An assignment is a (token, choice) pair.  The assignments to the
+experts held are sorted by expert and every group is padded to whole row
+tiles of ``tm`` rows, so a tile belongs to one expert and the grouped
+products are tile-by-tile dense products (``ops/pallas_kernels.py``
+``moe_gmm`` / ``moe_tgmm`` on the TPU; a masked loop over the experts
+elsewhere).  The row buffer has a usual size, twice the even share, and a
+worst-case size (every token sends ``min(k, held)`` choices here); the layer
+takes the small one whenever the step's routing fits it, so no routing drops
+a token; only the tiles that hold rows are computed.
+
+Precision.  The router (``x W_r``, the sigmoid, the selection and the
+weights) runs in float32 at ``highest`` precision whatever the operand
+policy: a selection made on bf16 scores picks other experts than the float32
+reference on near ties.  The experts' products take operands in the compute
+dtype with float32 accumulation, like every other matrix product.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from paddle_tpu.ops.numerics import acc_dtype, compute_dtype, dot_dtype
+
+__all__ = ["route_tokens", "count_assignments", "group_assignments",
+           "grouped_expert_mlp", "expert_layer", "buffer_rows",
+           "moe_kernel_row_tile", "Grouping"]
+
+
+def route_tokens(x, w_router, bias, *, top_k: int, norm_topk: bool,
+                 scaling: float):
+    """x ``[N, D]`` -> (experts ``[N, k]`` int32, weights ``[N, k]``
+    float32).  Scores are ``sigmoid(x W_r)``; the bias enters the selection
+    only (so its gradient is exactly zero)."""
+    f32 = acc_dtype()
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(f32), w_router.astype(f32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias.astype(f32)), top_k)
+    idx = checkpoint_name(idx, "remat_keep")
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        chosen = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), chosen * scaling
+
+
+class Grouping(NamedTuple):
+    """Where every assignment to an expert held sits in the row buffer."""
+    row_assign: jax.Array    # [M]     flat assignment of the row; N*k if none
+    tile_expert: jax.Array   # [M/tm]  expert (0..held-1) of the tile
+    n_active: jax.Array      # [1]     tiles that hold rows
+    counts: jax.Array        # [held]  assignments per expert held
+    uncomputed: jax.Array    # []      assignments held that got no row
+
+
+def moe_kernel_row_tile(d_model: int, d_expert: int, assignments: int):
+    """The grouped-product kernels' gate: their row tile, or ``None`` for
+    the XLA path.  Needs the TPU backend and lane-aligned widths."""
+    from paddle_tpu.ops.pallas_kernels import compiled_kernels
+
+    if not compiled_kernels():
+        return None
+    if d_model % 128 or d_expert % 128 or assignments < 2048:
+        return None
+    return 256
+
+
+def buffer_rows(tokens: int, top_k: int, experts: int, held: int, tm: int):
+    """``(usual, worst)`` sizes of the row buffer.  ``worst`` holds any
+    routing: every token sends ``min(k, held)`` choices here, and every
+    group wastes less than a tile.  ``usual`` holds twice the even share
+    (``tokens * k * held / experts``); the layer takes it whenever the
+    step's routing fits, so that its gathers, scatters and gates move a
+    third of the rows."""
+    worst = (-(-tokens * min(top_k, held) // tm) + held) * tm
+    even = -(-tokens * top_k * held // experts)
+    return min(worst, (-(-2 * even // tm) + held) * tm), worst
+
+
+def count_assignments(idx, *, first_expert: int, held: int):
+    """idx ``[N, k]``, the experts each token chose over ALL experts ->
+    (key ``[N*k]``: the expert held, 0..held-1, or ``held`` for an expert
+    held elsewhere; counts ``[held]``; order ``[N*k]``: the assignments
+    sorted by key, stably).  The sort is kept across a recomputation block
+    (``remat_keep``): the backward's second forward does not sort again."""
+    local = idx.reshape(-1) - first_expert
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    key = key.astype(jnp.int32)
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                     dtype=jnp.int32)
+    order = checkpoint_name(jnp.argsort(key, stable=True).astype(jnp.int32),
+                            "remat_keep")
+    return key, counts, order
+
+
+def group_assignments(key, counts, order, *, tm: int, rows: int) -> Grouping:
+    """Rows for the assignments held, sorted by expert, every group padded
+    to whole tiles of ``tm``, in a buffer of ``rows``."""
+    A, held = key.shape[0], counts.shape[0]
+    sorted_key = key[order]
+    tiles = -(-counts // tm)
+    group_row0 = (jnp.cumsum(tiles) - tiles) * tm             # [held]
+    sorted_start = jnp.cumsum(counts) - counts
+    safe = jnp.minimum(sorted_key, held - 1)
+    rank = jnp.arange(A, dtype=jnp.int32) - sorted_start[safe]
+    row = jnp.where(sorted_key < held, group_row0[safe] + rank, rows)
+    row = jnp.minimum(row, rows).astype(jnp.int32)   # past the buffer: none
+    row_assign = jnp.full((rows,), A, jnp.int32).at[row].set(order,
+                                                             mode="drop")
+    ends = jnp.cumsum(tiles)                                   # [held]
+    n_tiles = rows // tm
+    tile_expert = jnp.minimum(
+        jnp.sum(jnp.arange(n_tiles)[:, None] >= ends[None, :], axis=1),
+        held - 1).astype(jnp.int32)
+    placed = jnp.sum(row < rows, dtype=jnp.int32)
+    return Grouping(row_assign, tile_expert,
+                    jnp.minimum(ends[-1:], n_tiles).astype(jnp.int32),
+                    counts, jnp.sum(counts) - placed)
+
+
+# -- the grouped products ----------------------------------------------------
+
+def _largest_tile(n: int, cap: int) -> int:
+    if n <= cap:
+        return n
+    return next((t for t in range(cap - cap % 128, 0, -128) if n % t == 0), n)
+
+
+def _row_expert(g: Grouping, tm: int):
+    """[M] expert of each row, ``-1`` past the active tiles."""
+    live = jnp.arange(g.tile_expert.shape[0]) < g.n_active[0]
+    return jnp.repeat(jnp.where(live, g.tile_expert, -1), tm)
+
+
+def _gmm(lhs, rhs, g: Grouping, tm, kernels, transpose_rhs=False,
+         out_dtype=None):
+    out_dtype = out_dtype or acc_dtype()
+    if kernels:
+        from paddle_tpu.ops.pallas_kernels import gmm_pallas
+
+        n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+        return gmm_pallas(lhs, rhs, g.tile_expert, g.n_active, tm=tm,
+                          tn=_largest_tile(n, 512),
+                          transpose_rhs=transpose_rhs, out_dtype=out_dtype)
+    row_e = _row_expert(g, tm)
+    out = None
+    for e in range(rhs.shape[0]):
+        w = rhs[e].astype(lhs.dtype)
+        y = jnp.matmul(lhs, w.T if transpose_rhs else w,
+                       preferred_element_type=acc_dtype())
+        y = jnp.where((row_e == e)[:, None], y, 0.0)
+        out = y if out is None else out + y
+    return out.astype(out_dtype)
+
+
+def _tgmm(lhs, rhs, g: Grouping, tm, kernels):
+    experts = g.counts.shape[0]
+    if kernels:
+        from paddle_tpu.ops.pallas_kernels import tgmm_pallas
+
+        out = tgmm_pallas(lhs, rhs, g.tile_expert, g.n_active,
+                          experts=experts, tm=tm,
+                          tk=_largest_tile(lhs.shape[1], 1024),
+                          tn=_largest_tile(rhs.shape[1], 1024))
+        # an expert without a tile has a block the kernel never wrote
+        return jnp.where((g.counts > 0)[:, None, None], out, 0.0)
+    row_e = _row_expert(g, tm)
+    return jnp.stack([
+        jnp.matmul(jnp.where((row_e == e)[:, None], lhs, 0).T, rhs,
+                   preferred_element_type=acc_dtype())
+        for e in range(experts)])
+
+
+def _take_rows(a, idx):
+    """``a[idx]`` with zeros where ``idx`` is out of range (the sentinels)."""
+    return jnp.take(a, idx, axis=0, mode="fill", fill_value=0)
+
+
+def _add_rows(rows, idx, n):
+    """``out[idx[r]] += rows[r]`` into ``n`` zero rows; sentinels dropped."""
+    return jnp.zeros((n,) + rows.shape[1:], rows.dtype).at[idx].add(
+        rows, mode="drop")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels):
+    return _expert_mlp_fwd(x, weights, w1, w3, w2, g, tm, kernels)[0]
+
+
+def _expert_mlp_fwd(x, weights, w1, w3, w2, g: Grouping, tm, kernels):
+    N, k = weights.shape
+    f32, cd = acc_dtype(), compute_dtype()
+    with jax.named_scope("moe_grouping"):
+        row_token = g.row_assign // k                         # N where none
+        xs = _take_rows(x.astype(cd), row_token)              # [M, D]
+    with jax.named_scope("moe_experts"):
+        h1 = _gmm(xs, w1, g, tm, kernels, out_dtype=cd)
+        h3 = _gmm(xs, w3, g, tm, kernels, out_dtype=cd)
+        a = (jax.nn.silu(h1.astype(f32)) * h3.astype(f32)).astype(cd)
+        ys = _gmm(a, w2, g, tm, kernels, out_dtype=cd)        # [M, D]
+    with jax.named_scope("moe_combine"):
+        row_w = _take_rows(weights.reshape(-1).astype(f32), g.row_assign)
+        y = _add_rows(ys.astype(f32) * row_w[:, None], row_token, N)
+    return y.astype(dot_dtype()), (xs, h1, h3, a, ys, row_w, w1, w3, w2, g,
+                                   jnp.zeros((0,), x.dtype),
+                                   jnp.zeros((0, k), weights.dtype))
+
+
+def _expert_mlp_bwd(tm, kernels, res, dy):
+    xs, h1, h3, a, ys, row_w, w1, w3, w2, g, x_like, w_like = res
+    N, k = dy.shape[0], w_like.shape[1]
+    f32, cd = acc_dtype(), compute_dtype()
+    row_token = g.row_assign // k
+    with jax.named_scope("moe_combine"):
+        dyr = _take_rows(dy.astype(f32), row_token)               # [M, D]
+        d_row_w = jnp.sum(dyr * ys.astype(f32), axis=-1)
+        d_weights = _add_rows(d_row_w, g.row_assign, N * k).reshape(N, k)
+        dys = (dyr * row_w[:, None]).astype(cd)
+    with jax.named_scope("moe_experts"):
+        da = _gmm(dys, w2, g, tm, kernels, transpose_rhs=True)   # [M, F] f32
+        d_w2 = _tgmm(a, dys, g, tm, kernels)
+        h1f, h3f = h1.astype(f32), h3.astype(f32)
+        sig = jax.nn.sigmoid(h1f)
+        dh3 = (da * h1f * sig).astype(cd)
+        dh1 = (da * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(cd)
+        d_w1 = _tgmm(xs, dh1, g, tm, kernels)
+        d_w3 = _tgmm(xs, dh3, g, tm, kernels)
+        dxs = (_gmm(dh1, w1, g, tm, kernels, transpose_rhs=True)
+               + _gmm(dh3, w3, g, tm, kernels, transpose_rhs=True))
+    with jax.named_scope("moe_grouping"):
+        dx = _add_rows(dxs, row_token, N)
+    return (dx.astype(x_like.dtype), d_weights.astype(w_like.dtype),
+            d_w1.astype(w1.dtype), d_w3.astype(w3.dtype),
+            d_w2.astype(w2.dtype), None)
+
+
+_expert_mlp.defvjp(_expert_mlp_fwd, _expert_mlp_bwd)
+
+
+def grouped_expert_mlp(x, weights, g: Grouping, w1, w3, w2, *, tm: int,
+                       kernels: bool):
+    """x ``[N, D]``, weights ``[N, k]`` (the router's, of every choice), the
+    experts held ``w1``/``w3`` ``[held, D, F]`` and ``w2`` ``[held, F, D]``
+    -> ``[N, D]``: ``sum over the choices held of weight * W_2e(silu(W_1e x)
+    * W_3e x)``.  Only the buffer's rows move: tokens are gathered into
+    rows and rows added back onto tokens, forward and backward, and the
+    backward reads the rows the forward wrote (no second sort)."""
+    return _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels)
+
+
+def expert_layer(x, idx, weights, w1, w3, w2, *, num_experts: int,
+                 first_expert: int, tm: int, kernels: bool):
+    """The part of a dropless expert layer's result that the experts held
+    give -> (y ``[N, D]``, assignments per expert held, assignments held
+    that got no row: 0).  The row buffer has its usual size when the
+    step's routing fits it and the worst routing's size when not
+    (:func:`buffer_rows`): one ``lax.cond``, the same rows either way."""
+    N, k = idx.shape
+    held = w1.shape[0]
+    with jax.named_scope("moe_grouping"):
+        key, counts, order = count_assignments(
+            idx, first_expert=first_expert, held=held)
+    usual, worst = buffer_rows(N, k, num_experts, held, tm)
+
+    def run(rows):
+        with jax.named_scope("moe_grouping"):
+            g = group_assignments(key, counts, order, tm=tm, rows=rows)
+        return (grouped_expert_mlp(x, weights, g, w1, w3, w2, tm=tm,
+                                   kernels=kernels), g.uncomputed)
+
+    if usual == worst:
+        y, uncomputed = run(worst)
+    else:
+        fits = jnp.sum(-(-counts // tm)) * tm <= usual
+        y, uncomputed = jax.lax.cond(fits, lambda: run(usual),
+                                     lambda: run(worst))
+    return y, counts, uncomputed

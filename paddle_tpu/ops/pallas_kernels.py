@@ -32,7 +32,9 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "kernel_flags_on",
            "lstm_forward_pallas", "gru_forward_pallas",
            "attn_dec_fwd_pallas", "attn_dec_bwd_pallas",
-           "topk_lse_readout_pallas", "topk_lse_logits_pallas", "TOPK_LANES"]
+           "topk_lse_readout_pallas", "topk_lse_logits_pallas", "TOPK_LANES",
+           "flash_attn_fwd_pallas", "flash_attn_bwd_pallas",
+           "gmm_pallas", "tgmm_pallas"]
 
 
 def _compiler_params(**kw):
@@ -1479,3 +1481,360 @@ def topk_lse_logits_pallas(logits, *, vocab: int, k: int, row_block: int,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_interpret(),
     )(logits)
+
+
+# ---------------------------------------------------------------------------
+# Causal flash attention (decoder-only blocks): forward, dq, dk/dv
+# ---------------------------------------------------------------------------
+# Heads-major operands: q/o/do [B, H, T, dh], k/v [B, Hkv, T, dh] (key-value
+# head j serves query heads j*G..j*G+G-1), lse [B, H, T, 1] float32.  A grid
+# step is one (block of queries, block of keys); blocks above the diagonal
+# are skipped, and their key/value (or query) block index is clamped to the
+# last one needed so that a skipped step moves nothing.  The scores of one
+# block pair live in VMEM only.
+
+#: scoped VMEM the three attention kernels ask for (score tiles of
+#: 1024 x 1024 float32 and their bf16 copies, beside the operand blocks)
+FLASH_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+
+
+def _causal_scores(q, k, qi, kj, *, scale, block_q, block_k):
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(cols <= rows, s, -jnp.inf)
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                      m_scr, l_scr, acc_scr, *, scale, block_q, block_k):
+    from jax.experimental import pallas as pl
+
+    qi, kj = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kj * block_k <= qi * block_q + block_q - 1)
+    def _block():
+        v = v_ref[0, 0]
+        s = _causal_scores(q_ref[0, 0], k_ref[0, 0], qi, kj, scale=scale,
+                           block_q=block_q, block_k=block_k)
+        m_old = m_scr[...]
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_old - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _fin():
+        o_ref[0, 0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l_scr[...])
+
+
+def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
+                          block_k: int):
+    """-> (out like q, lse [B, H, T, 1] float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, dh = q.shape
+    G = H // k.shape[1]
+    nq, nk = T // block_q, T // block_k
+
+    def kv_map(b, h, qi, kj):
+        last = (qi * block_q + block_q - 1) // block_k
+        return (b, h // G, jnp.minimum(kj, last), 0)
+
+    return pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k),
+        name="flash_attn_fwd",
+        grid=(B, H, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, kj: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, dh), kv_map),
+            pl.BlockSpec((1, 1, block_k, dh), kv_map),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, kj: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qi, kj: (b, h, qi, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, dh), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+    )(q, k, v)
+
+
+def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k):
+    """(p, ds) of one block pair from the saved statistics, float32."""
+    f32 = jnp.float32
+    s = _causal_scores(q, k, qi, kj, scale=scale, block_q=block_q,
+                       block_k=block_k)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=f32)
+    delta = jnp.sum(do.astype(f32) * o.astype(f32), axis=-1, keepdims=True)
+    return p, p * (dp - delta) * scale
+
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+                     acc_scr, *, scale, block_q, block_k):
+    from jax.experimental import pallas as pl
+
+    qi, kj = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kj * block_k <= qi * block_q + block_q - 1)
+    def _block():
+        k = k_ref[0, 0]
+        _, ds = _flash_probs(q_ref[0, 0], k, v_ref[0, 0], o_ref[0, 0],
+                             do_ref[0, 0], lse_ref[0, 0], qi, kj,
+                             scale=scale, block_q=block_q, block_k=block_k)
+        acc_scr[...] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(kj == pl.num_programs(3) - 1)
+    def _fin():
+        dq_ref[0, 0] = acc_scr[...]
+
+
+def _flash_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale, block_q,
+                      block_k, n_q):
+    from jax.experimental import pallas as pl
+
+    kj, t = pl.program_id(2), pl.program_id(3)
+    qi = t % n_q
+
+    @pl.when(t == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(kj * block_k <= qi * block_q + block_q - 1)
+    def _block():
+        q, do = q_ref[0, 0], do_ref[0, 0]
+        p, ds = _flash_probs(q, k_ref[0, 0], v_ref[0, 0], o_ref[0, 0], do,
+                             lse_ref[0, 0], qi, kj, scale=scale,
+                             block_q=block_q, block_k=block_k)
+        contract_rows = (((0,), (0,)), ((), ()))
+        dv_scr[...] += jax.lax.dot_general(
+            p.astype(do.dtype), do, contract_rows,
+            preferred_element_type=jnp.float32)
+        dk_scr[...] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, contract_rows,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _fin():
+        dk_ref[0, 0] = dk_scr[...]
+        dv_ref[0, 0] = dv_scr[...]
+
+
+def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
+                          block_q: int, block_k: int):
+    """-> (dq [B, H, T, dh], dk, dv [B, Hkv, T, dh]), float32.  Two kernels,
+    each recomputing a block pair's probabilities from ``lse``: one walks
+    the keys of a block of queries (dq), one the queries (of every head of
+    the group) of a block of keys (dk, dv)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, dh = q.shape
+    Hkv = k.shape[1]
+    G = H // Hkv
+    nq, nk = T // block_q, T // block_k
+    kw = dict(scale=scale, block_q=block_q, block_k=block_k)
+
+    def q_map(b, h, qi, kj):
+        return (b, h, qi, 0)
+
+    def kv_map(b, h, qi, kj):
+        last = (qi * block_q + block_q - 1) // block_k
+        return (b, h // G, jnp.minimum(kj, last), 0)
+
+    q_spec = pl.BlockSpec((1, 1, block_q, dh), q_map)
+    kv_spec = pl.BlockSpec((1, 1, block_k, dh), kv_map)
+    dq = pl.pallas_call(
+        functools.partial(_flash_dq_kernel, **kw),
+        name="flash_attn_dq",
+        grid=(B, H, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                  pl.BlockSpec((1, 1, block_q, 1), q_map)],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+    )(q, k, v, o, do, lse)
+
+    def qh_map(b, hk, kj, t):
+        first = (kj * block_k) // block_q
+        return (b, hk * G + t // nq, jnp.maximum(t % nq, first), 0)
+
+    def k_map(b, hk, kj, t):
+        return (b, hk, kj, 0)
+
+    qh_spec = pl.BlockSpec((1, 1, block_q, dh), qh_map)
+    k_spec = pl.BlockSpec((1, 1, block_k, dh), k_map)
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_dkv_kernel, n_q=nq, **kw),
+        name="flash_attn_dkv",
+        grid=(B, Hkv, nk, G * nq),
+        in_specs=[qh_spec, k_spec, k_spec, qh_spec, qh_spec,
+                  pl.BlockSpec((1, 1, block_q, 1), qh_map)],
+        out_specs=[k_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32)] * 2,
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+    )(q, k, v, o, do, lse)
+    return dq, dk, dv
+
+
+# ---------------------------------------------------------------------------
+# Grouped matrix products over the experts held (dropless expert layer)
+# ---------------------------------------------------------------------------
+# Rows are grouped by expert and every group starts on a row tile (ops/moe.py
+# pads each group to whole tiles), so a tile of ``tm`` rows belongs to ONE
+# expert: ``tile_expert[i]``.  Only the first ``n_active[0]`` tiles hold
+# rows; the buffer is sized for the worst routing, and a grid step past the
+# active tiles computes nothing and moves nothing (its block indices are
+# clamped to the last active tile's).  Tiles of one expert are consecutive,
+# so with the tile index innermost a weight block is fetched once per run.
+
+GMM_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _last_active(i, na_ref):
+    return jnp.minimum(i, jnp.maximum(na_ref[0] - 1, 0))
+
+
+def _gmm_kernel(te_ref, na_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) < na_ref[0])
+    def _tile():
+        x = lhs_ref[...]
+        w = rhs_ref[0].astype(x.dtype)
+        dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+            else (((1,), (0,)), ((), ()))
+        out_ref[...] = jax.lax.dot_general(
+            x, w, dims, preferred_element_type=jnp.float32
+        ).astype(out_ref.dtype)
+
+
+def gmm_pallas(lhs, rhs, tile_expert, n_active, *, tm: int, tn: int,
+               transpose_rhs: bool = False, out_dtype=jnp.float32):
+    """``out[rows of tile i] = lhs[rows of tile i] @ rhs[tile_expert[i]]``
+    for the active tiles (other rows are left unwritten).  lhs ``[M, K]`` in
+    the compute dtype, rhs ``[E, K, N]`` (``[E, N, K]`` with
+    ``transpose_rhs``) in its stored dtype, cast a block at a time."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    M, K = lhs.shape
+    N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    rhs_block = (1, tn, K) if transpose_rhs else (1, K, tn)
+
+    def rhs_map(j, i, te, na):
+        e = te[_last_active(i, na)]
+        return (e, j, 0) if transpose_rhs else (e, 0, j)
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        name="moe_gmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N // tn, M // tm),
+            in_specs=[
+                pl.BlockSpec((tm, K),
+                             lambda j, i, te, na: (_last_active(i, na), 0)),
+                pl.BlockSpec(rhs_block, rhs_map),
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda j, i, te, na: (_last_active(i, na), j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        compiler_params=_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=GMM_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+    )(tile_expert, n_active, lhs, rhs)
+
+
+def _tgmm_kernel(te_ref, na_ref, lhs_ref, rhs_ref, out_ref):
+    from jax.experimental import pallas as pl
+
+    i = pl.program_id(2)
+    active = i < na_ref[0]
+    first = jnp.logical_or(i == 0,
+                           te_ref[i] != te_ref[jnp.maximum(i - 1, 0)])
+
+    @pl.when(jnp.logical_and(active, first))
+    def _zero():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(active)
+    def _tile():
+        out_ref[0] += jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def tgmm_pallas(lhs, rhs, tile_expert, n_active, *, experts: int, tm: int,
+                tk: int, tn: int):
+    """``out[e] = sum over the tiles of expert e of lhs_tile^T @ rhs_tile``:
+    lhs ``[M, K]``, rhs ``[M, N]`` -> ``[experts, K, N]`` float32.  The
+    block of an expert no tile belongs to is never written: the caller
+    zeroes the experts whose count is 0."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    M, K = lhs.shape
+    N = rhs.shape[1]
+    return pl.pallas_call(
+        _tgmm_kernel,
+        name="moe_tgmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(K // tk, N // tn, M // tm),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda a, b, i, te, na:
+                             (_last_active(i, na), a)),
+                pl.BlockSpec((tm, tn), lambda a, b, i, te, na:
+                             (_last_active(i, na), b)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn), lambda a, b, i, te, na:
+                (te[_last_active(i, na)], a, b)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((experts, K, N), jnp.float32),
+        compiler_params=_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=GMM_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+    )(tile_expert, n_active, lhs, rhs)

@@ -272,10 +272,12 @@ class PipelinedTopology(Topology):
             env[layer.name] = layer.forward(ctx, local, *parent_acts)
 
     def apply(self, params, state, feed, *, train=False, rng=None,
-              outputs=None, device_specs=None, param_overrides=None):
+              outputs=None, device_specs=None, param_overrides=None,
+              remat_layers=False):
         # param_overrides (the pserver TableProxy hook) is accepted for
         # trainer-signature parity; pipelined stage layers consume plain
-        # arrays, so overrides only reach head/tail layers
+        # arrays, so overrides only reach head/tail layers.  remat_layers
+        # (--remat): a stage is the recomputation block here
         ctx = ApplyContext(train, rng)
         env: Dict[str, Act] = {}
         stage0 = self.stage_layers[0]
@@ -305,6 +307,8 @@ class PipelinedTopology(Topology):
             return tuple(strip(senv[stage0[i].name])
                          for i in self.seam_out_pos)
 
+        if remat_layers:
+            stage_fn = jax.checkpoint(stage_fn)
         ys = pipeline_apply(stage_fn, stacked, xs, mesh=self.mesh,
                             n_microbatches=self.n_microbatches,
                             stage_axis=self.stage_axis,

@@ -145,6 +145,14 @@ class SGDTrainer:
             raise ValueError("cost_weights must match the number of costs")
         self.cost_name = costs[0].name
         self.extra_names = [e.name for e in extra_outputs]
+        # extra outputs a model marked for a counter (``meta["obs_counter"]``:
+        # name, labels, and for a vector the label its index goes under):
+        # the loop adds each step's value to the registry after its loss
+        # fetch, when the step's work is done (_feed_counters)
+        self._counter_feeds = [(e.name, e.meta["obs_counter"])
+                               for e in extra_outputs
+                               if "obs_counter" in e.meta]
+        self._counter_children: Dict[str, list] = {}
         if pipeline is not None:
             # pp:<k> device_pin tags become GPipe stages over
             # mesh[pipeline['stage_axis']] (parallel/pipeline_dsl.py);
@@ -396,6 +404,12 @@ class SGDTrainer:
                         p, state, feed, train=True, rng=rng,
                         device_specs=device_specs,
                         param_overrides=overrides,
+                        # --remat: every layer outside a recomputation
+                        # block (nn.remat_block) becomes a block of its
+                        # own, so the backward holds each layer's inputs
+                        # and recomputes the rest: O(layers) activations
+                        # for about a third more FLOPs
+                        remat_layers=remat,
                     )
                     extras = {k: outs[k].value for k in extra_names}
                     total = sum(
@@ -407,13 +421,6 @@ class SGDTrainer:
                 # range; the reported loss (aux) stays unscaled
                 scaled = total * amp_state["scale"] if amp else total
                 return scaled, (total, new_state, extras)
-
-            if remat:
-                # jax.checkpoint: the backward recomputes the forward
-                # instead of holding every activation — O(layers) memory
-                # for ~1/3 extra FLOPs (the larger-batch lever for the
-                # MFU-starved recurrent models, ROADMAP item 3)
-                loss_fn = jax.checkpoint(loss_fn)
 
             (_, (loss, new_state, extras)), (grads, px_grads) = (
                 jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
@@ -722,6 +729,9 @@ class SGDTrainer:
             self.avg_params = self.averager.update(self.avg_params, self.params)
         self._obs_counters["batches"].inc()
         self._last_extras = extras
+        for name, _ in self._counter_feeds:
+            # starts the copy now; _feed_counters reads it after the loss
+            extras[name].copy_to_host_async()
         if self._gang is not None:
             self._obs_gauges["world"].set(self._gang.world_size)
             # elastic observability: the live world, whether it is running
@@ -778,6 +788,30 @@ class SGDTrainer:
             else:
                 self._bad_streak = 0
         return loss
+
+    def _feed_counters(self) -> None:
+        """Add the last step's marked extras to their counters.  Called
+        once the step's loss is on the host: the values were computed by
+        the same program and their copies started at dispatch, so this
+        waits for nothing the loop had not waited for already."""
+        for name, spec in self._counter_feeds:
+            values = np.asarray(self._last_extras[name]).reshape(-1)
+            children = self._counter_children.get(name)
+            if children is None:
+                from paddle_tpu.obs import get_registry
+
+                reg, children = get_registry(), []
+                labels = dict(spec.get("labels", {}))
+                for i in range(values.size):
+                    if spec.get("index_label"):
+                        labels[spec["index_label"]] = (
+                            spec.get("first_index", 0) + i)
+                    children.append(reg.counter(
+                        spec["name"], spec.get("help", ""),
+                        labels=tuple(sorted(labels)), **labels))
+                self._counter_children[name] = children
+            for child, v in zip(children, values):
+                child.inc(float(v))
 
     @property
     def bad_steps_streak(self) -> int:
@@ -1116,6 +1150,8 @@ class SGDTrainer:
                                 # the step's device work done
                                 with _sync_span("loss"):
                                     cost = float(loss)
+                                if self._counter_feeds:
+                                    self._feed_counters()
                         except TooManyBadSteps:
                             if self._step_span is not None:
                                 self._step_span.retain("train_abort")

@@ -192,10 +192,11 @@ define_flag("loss_scale_growth", 2000, "double the loss scale after N "
 define_flag("loss_scale_max", 16777216.0, "dynamic loss scale ceiling "
             "(growth never doubles past this; halving floors at 1.0)",
             validator=lambda v: v >= 1.0)
-define_flag("remat", False, "rematerialize the forward inside the "
-            "backward (jax.checkpoint around the loss closure): trades "
-            "~1/3 more FLOPs for O(layer) activation memory, buying the "
-            "larger batches the MFU-starved recurrent models need")
+define_flag("remat", False, "rematerialize each layer inside the "
+            "backward (one jax.checkpoint per layer that is in no "
+            "nn.remat_block): trades ~1/3 more FLOPs for O(layer) "
+            "activation memory, buying the larger batches the MFU-starved "
+            "recurrent models need")
 
 # Trainer loop (log_period, test_period, checkgrad ...)
 define_flag("log_period", 100, "log every N batches")

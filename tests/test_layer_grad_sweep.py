@@ -632,6 +632,48 @@ def case_print_value(rng):
     return nn.print_value(_pre_fc(x)), feed
 
 
+# -- the decoder-only block (PR 28) ------------------------------------------
+# each behind an fc so that the layer's own VJP carries a parameter's gradient
+
+
+def case_rms_norm(rng):
+    xs, feed = _seq(rng)
+    return nn.rms_norm(_pre_fc(xs)), feed
+
+
+def case_gated_short_conv(rng):
+    xs, feed = _seq(rng)
+    return nn.gated_short_conv(_pre_fc(xs), kernel_size=3), feed
+
+
+def case_causal_self_attention(rng):
+    xs, feed = _seq(rng)
+    return nn.causal_self_attention(_pre_fc(xs, size=8), num_heads=4,
+                                    num_kv_heads=2, head_dim=2), feed
+
+
+def case_gated_mlp(rng):
+    xs, feed = _seq(rng)
+    return nn.gated_mlp(_pre_fc(xs), 8), feed
+
+
+def case_expert_mlp(rng):
+    # every expert chosen (top_k = num_experts): a finite difference must not
+    # move a token to another expert; the share of two of three is computed
+    xs, feed = _seq(rng)
+    return nn.expert_mlp(_pre_fc(xs), 8, num_experts=3, experts_held=(1, 2),
+                         top_k=3), feed
+
+
+def case_lm_head_cost(rng):
+    ids, feed = _ids(rng)
+    lab = nn.data("next", size=0, is_seq=True, dtype="int32")
+    emb = nn.embedding(ids, D, vocab_size=V, name="tied")
+    feed["next"] = (rng.randint(0, V, (B, T)).astype(np.int32),
+                    feed["ids"][1])
+    return nn.lm_head_cost(nn.rms_norm(emb), lab, embedding=emb), feed
+
+
 FORWARD_ONLY = {"maxid", "sampling_id", "eos_id", "eos_trim", "crf_decoding",
                 "priorbox"}
 

@@ -284,3 +284,54 @@ def test_slot_table_decode_step(one_chip, on_tpu):
                   for a in jax.tree_util.tree_leaves(shapes))
     assert compiled.memory_analysis().generated_code_size_in_bytes \
         < weights // 8
+
+
+# -- the decoder-only block (PR 28): LFM2-24B-A2B's published widths, the ----
+# -- benchmark cell's row of 8192 tokens and its share of 8 of 64 experts ----
+
+LFM2 = dict(T=8192, D=2048, H=32, Hkv=8, dh=64, F=1536, held=8, top_k=4)
+
+
+def test_causal_attention_kernels(one_chip, on_tpu):
+    """The gate admits the cell's row, and the forward, dq and dk/dv flash
+    kernels compile with the heads of 64 the configuration has."""
+    from paddle_tpu.ops import decoder_block as DB
+
+    c = LFM2
+    assert DB.attention_kernel_blocks(c["T"], c["dh"], c["H"], c["Hkv"])
+    assert DB.attention_kernel_blocks(c["T"] + 8, c["dh"], c["H"],
+                                      c["Hkv"]) is None
+
+    def loss(q, k, v):
+        return DB.causal_attention(q, k, v, scale=c["dh"] ** -0.5).sum()
+
+    q = _struct(one_chip, (1, c["T"], c["H"], c["dh"]))
+    kv = _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"]))
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    q, kv, kv) == 3
+
+
+def test_grouped_expert_products(one_chip, on_tpu):
+    """The dropless expert layer at the cell's sizes, with both of its row
+    buffers (the usual one, twice the even share, and the worst routing's:
+    every token sends all its choices here): in each, moe_gmm three times
+    forward and three backward (transposed: the gate's input gradient, the
+    two halves of dx) and moe_tgmm for the three weight gradients."""
+    from paddle_tpu.ops import moe as M
+
+    c = LFM2
+    N, k = c["T"], c["top_k"]
+    tm = M.moe_kernel_row_tile(c["D"], c["F"], N * k)
+    assert tm == 256
+    assert M.buffer_rows(N, k, 64, c["held"], tm) == (10240, 34816)
+
+    def loss(x, w, w1, w3, w2, idx):
+        return M.expert_layer(x, idx, w, w1, w3, w2, num_experts=64,
+                              first_expert=0, tm=tm, kernels=True)[0].sum()
+
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+    args = [s(N, c["D"]), s(N, k), s(c["held"], c["D"], c["F"]),
+            s(c["held"], c["D"], c["F"]), s(c["held"], c["F"], c["D"]),
+            _struct(one_chip, (N, k), jnp.int32)]
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    *args) == 18

@@ -122,25 +122,29 @@ def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
     (one buffer — its block index never changes), every per-step block is
     double-buffered, finals/seeds and the carry scratches are ``[B, H]``
     f32.  ``gates``: 4 = LSTM (two carries), 3 = GRU (one).  Counted with
-    the widest variant (training residuals; the LSTM's peephole ``c_new``
-    stream).  Agrees to within 1% with what the v5e compiler of the
-    installed libtpu reports when it refuses a kernel (jax 0.9.0,
+    the widest variant (training residuals; the LSTM reverse kernel's
+    peephole accumulators).  Agrees to within 1% with what the v5e compiler
+    of the installed libtpu reports when it refuses a kernel (jax 0.9.0,
     tests/test_tpu_compile.py holds the gates to it)."""
     carries = 2 if gates == 4 else 1
     rs = residual_itemsize
     weight = 4 * gates * hidden * hidden * directions
+    fixed = 0
     if backward:
         # d_out in, z + held-carry residuals in, d_z out, then per carry:
-        # seed in, d_0 out, scratch; LSTM adds the c_new stream out
-        per_unit = (8 + 2 * rs * (gates + 1) + 8 * gates + 12 * carries
-                    + (8 if gates == 4 else 0))
+        # seed in, d_0 out, scratch
+        per_unit = 8 + 2 * rs * (gates + 1) + 8 * gates + 12 * carries
+        if gates == 4:
+            # the LSTM's bias and peephole gradients, which do not grow with
+            # B: f32 sublane-tile accumulators [8, 4H] + [3, 8, H], and the
+            # double-buffered blocks they leave in, [1, 4H] and [3 -> 4, H]
+            fixed = 4 * hidden * (8 * (gates + 3) + 2 * gates + 2 * 4)
     else:
         # xp in, h_seq out, z + held-carry residuals out, then per carry:
         # final out, scratch
         per_unit = (8 * gates + 8 + 2 * rs * (gates + carries)
                     + 8 * carries)
-    return weight + per_unit * batch * hidden
-
+    return weight + fixed + per_unit * batch * hidden
 
 
 def _lstm_kernel(xp_ref, m_ref, wh_ref, pi_ref, pf_ref, po_ref,
@@ -495,25 +499,27 @@ gru_forward_pallas.defvjp(_gru_fwd, _gru_bwd)
 # cotangent carries live in VMEM scratch, the transposed recurrent weight
 # stays resident, and the per-step d_z cotangent streams out — the
 # hand-written reverse half of hl_cuda_lstm.cu, TPU-style.  The batched
-# d_w_h einsum and d_xp remain outside (they are one-shot MXU ops).
+# d_w_h einsum and d_xp remain outside (they are one-shot MXU ops); the
+# LSTM's bias and peephole gradients are accumulated inside its loop.
 # ---------------------------------------------------------------------------
 
 
 def _lstm_bwd_kernel(dout_ref, m_ref, z_ref, cp_ref, wt_ref, pi_ref,
                      pf_ref, po_ref, dhfin_ref, dcfin_ref,
-                     dz_ref, *rest, hidden: int):
+                     dz_ref, dh0_ref, dc0_ref, db_ref, *rest, hidden: int):
     """One reverse step (grid runs t = T-1 .. 0 via the index maps).
     Mirrors rnn_fused._lstm_seq_bwd.rev_step numerics exactly (f32),
-    including peephole feedthrough; streams c_new back out for the d_po
-    reduction when peepholes are live (rest = (cn_ref, dh0, dc0, scratches)
-    or (dh0, dc0, scratches))."""
+    including peephole feedthrough.  The parameter-sized reductions of
+    ``d_z`` over (time, batch) are accumulated here, where every operand is
+    already in VMEM: the bias gradient always, the three peephole gradients
+    when peepholes are live (rest = (dpeep_ref, scratches) or scratches)."""
     from jax.experimental import pallas as pl
 
     if len(rest) == 5:
-        cn_ref, dh0_ref, dc0_ref, dh_scr, dc_scr = rest
+        dpeep_ref, dh_scr, dc_scr, db_scr, dpeep_scr = rest
     else:
-        cn_ref = None
-        dh0_ref, dc0_ref, dh_scr, dc_scr = rest
+        dpeep_ref = dpeep_scr = None
+        dh_scr, dc_scr, db_scr = rest
 
     t = pl.program_id(0)
     T = pl.num_programs(0)
@@ -523,6 +529,9 @@ def _lstm_bwd_kernel(dout_ref, m_ref, z_ref, cp_ref, wt_ref, pi_ref,
     def _init():
         dh_scr[...] = dhfin_ref[...]
         dc_scr[...] = dcfin_ref[...]
+        db_scr[...] = jnp.zeros_like(db_scr)
+        if dpeep_scr is not None:
+            dpeep_scr[...] = jnp.zeros_like(dpeep_scr)
 
     d_h = dh_scr[...]
     d_c = dc_scr[...]
@@ -551,43 +560,67 @@ def _lstm_bwd_kernel(dout_ref, m_ref, z_ref, cp_ref, wt_ref, pi_ref,
     dc_scr[...] = ((1.0 - mcol) * d_c + d_cnew * f
                    + d_zi * pi + d_zf * pf)
     dz_ref[0] = d_z
-    if cn_ref is not None:
-        cn_ref[0] = cn
+
+    def sublane_partial(x):
+        # [B, n] -> [8, n]: the B/8 sublane tiles added together, vreg by
+        # vreg; the 8 -> 1 reduction across sublanes waits for the last step
+        return x.reshape(x.shape[0] // 8, 8, x.shape[1]).sum(0)
+
+    # masked steps add zero: every term of d_z carries mcol
+    db_scr[...] += sublane_partial(d_z)
+    if dpeep_scr is not None:
+        dpeep_scr[0] += sublane_partial(d_zi * cp)
+        dpeep_scr[1] += sublane_partial(d_zf * cp)
+        dpeep_scr[2] += sublane_partial(d_zo * cn)
 
     @pl.when(t == T - 1)  # last grid step == timestep 0
     def _fin():
         dh0_ref[...] = dh_scr[...]
         dc0_ref[...] = dc_scr[...]
+        db_ref[...] = db_scr[...].sum(0, keepdims=True)
+        if dpeep_scr is not None:
+            dpeep_ref[...] = dpeep_scr[...].sum(1)
 
 
 def _lstm_bwd_pallas_raw(dout_tb, m_tb, z_tb, cp_tb, w_t, pi, pf, po,
-                         d_hfin, d_cfin, *, want_cn: bool = True):
+                         d_hfin, d_cfin, *, has_peepholes: bool = True):
     """TIME-MAJOR: dout/m/z/cp [T,B,*] f32; w_t: [4H,H] (w_h transposed);
     pi/pf/po: [1,H] peephole rows; d_hfin/d_cfin: [B,H] cotangent seeds
     (loaded into the carry scratch at the last timestep — they propagate
     through masked tails exactly as the scan's initial carry does).
-    Returns (d_z [T,B,4H], c_new [T,B,H] for the d_po reduction, d_h0,
-    d_c0)."""
+    Returns (d_z [T,B,4H], d_h0, d_c0, d_b [1,4H] = d_z summed over (t, b),
+    d_peep [3,H] = rows d_pi, d_pf, d_po, or None without peepholes).
+    B must be a multiple of 8 (the gate's tile constraint): the
+    accumulators hold one sublane tile per feature column."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     T, B, H4 = z_tb.shape
     H = H4 // 4
     rev = lambda t: (T - 1 - t, 0, 0)
+    resident = lambda t: (0, 0)
     kernel = functools.partial(_lstm_bwd_kernel, hidden=H)
-    out_specs = [pl.BlockSpec((1, B, H4), rev)]
-    out_shape = [jax.ShapeDtypeStruct((T, B, H4), jnp.float32)]
-    if want_cn:  # c_new residual only feeds d_po — skip it for zero peeps
-        out_specs.append(pl.BlockSpec((1, B, H), rev))
-        out_shape.append(jax.ShapeDtypeStruct((T, B, H), jnp.float32))
-    out_specs += [
-        pl.BlockSpec((B, H), lambda t: (0, 0)),
-        pl.BlockSpec((B, H), lambda t: (0, 0)),
+    out_specs = [
+        pl.BlockSpec((1, B, H4), rev),
+        pl.BlockSpec((B, H), resident),
+        pl.BlockSpec((B, H), resident),
+        pl.BlockSpec((1, H4), resident),
     ]
-    out_shape += [
+    out_shape = [
+        jax.ShapeDtypeStruct((T, B, H4), jnp.float32),
         jax.ShapeDtypeStruct((B, H), jnp.float32),
         jax.ShapeDtypeStruct((B, H), jnp.float32),
+        jax.ShapeDtypeStruct((1, H4), jnp.float32),
     ]
+    scratch_shapes = [
+        pltpu.VMEM((B, H), jnp.float32),
+        pltpu.VMEM((B, H), jnp.float32),
+        pltpu.VMEM((8, H4), jnp.float32),
+    ]
+    if has_peepholes:
+        out_specs.append(pl.BlockSpec((3, H), resident))
+        out_shape.append(jax.ShapeDtypeStruct((3, H), jnp.float32))
+        scratch_shapes.append(pltpu.VMEM((3, 8, H), jnp.float32))
     outs = pl.pallas_call(
         kernel,
         name="lstm_seq_bwd",
@@ -597,30 +630,23 @@ def _lstm_bwd_pallas_raw(dout_tb, m_tb, z_tb, cp_tb, w_t, pi, pf, po,
             pl.BlockSpec((1, B, 1), rev),
             pl.BlockSpec((1, B, H4), rev),
             pl.BlockSpec((1, B, H), rev),
-            pl.BlockSpec((H4, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((B, H), lambda t: (0, 0)),
-            pl.BlockSpec((B, H), lambda t: (0, 0)),
+            pl.BlockSpec((H4, H), resident),
+            pl.BlockSpec((1, H), resident),
+            pl.BlockSpec((1, H), resident),
+            pl.BlockSpec((1, H), resident),
+            pl.BlockSpec((B, H), resident),
+            pl.BlockSpec((B, H), resident),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((B, H), jnp.float32),
-            pltpu.VMEM((B, H), jnp.float32),
-        ],
+        scratch_shapes=scratch_shapes,
         compiler_params=_compiler_params(
             vmem_limit_bytes=RNN_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
     )(dout_tb, m_tb[..., None], z_tb, cp_tb, w_t, pi, pf, po,
       d_hfin, d_cfin)
-    if want_cn:
-        d_z, cn, d_h0, d_c0 = outs
-    else:
-        d_z, d_h0, d_c0 = outs
-        cn = None
-    return d_z, cn, d_h0, d_c0
+    d_z, d_h0, d_c0, d_b, *d_peep = outs
+    return d_z, d_h0, d_c0, d_b, (d_peep[0] if has_peepholes else None)
 
 
 def _gru_bwd_kernel(dout_ref, m_ref, z_ref, hp_ref, wt_ref, dhfin_ref,

@@ -183,7 +183,6 @@ def lstm_layer(x, mask, w_x, w_h, b, *, h0=None, c0=None, reverse=False,
     """
     B, T, _ = x.shape
     H = w_h.shape[0]
-    xp = (x + b.astype(x.dtype)) if w_x is None else linear(x, w_x, b)
     if reset is None and \
             (act, gate_act, state_act) == ("tanh", "sigmoid", "tanh"):
         # default cell (peepholes included — zeros degenerate exactly):
@@ -191,8 +190,11 @@ def lstm_layer(x, mask, w_x, w_h, b, *, h0=None, c0=None, reverse=False,
         # the reverse loop; Pallas fwd+bwd kernels when the gate allows —
         # see ops/rnn_fused.py).  reverse rides a flip: identical to
         # scan_rnn(reverse=True) including mask hold/zero semantics.
+        # The op adds the bias itself (to the projection's output, where
+        # linear() would), because its backward owns the bias gradient.
         from paddle_tpu.ops.rnn_fused import lstm_sequence_fused
 
+        xp = x if w_x is None else linear(x, w_x)
         allow_pallas = h0 is None and c0 is None
         h0a = jnp.zeros((B, H), xp.dtype) if h0 is None else h0
         c0a = jnp.zeros((B, H), xp.dtype) if c0 is None else c0
@@ -206,12 +208,13 @@ def lstm_layer(x, mask, w_x, w_h, b, *, h0=None, c0=None, reverse=False,
         po = zp if peep_o is None else peep_o.astype(xp.dtype)
         xp_r = jnp.flip(xp, 1) if reverse else xp
         m_r = jnp.flip(mask, 1) if reverse else mask
-        h_seq, h_fin, c_fin = lstm_sequence_fused(xp_r, m_r, w_h, h0a, c0a,
-                                                  pi, pf, po, allow_pallas,
-                                                  has_peeps)
+        h_seq, h_fin, c_fin = lstm_sequence_fused(xp_r, b, m_r, w_h, h0a,
+                                                  c0a, pi, pf, po,
+                                                  allow_pallas, has_peeps)
         if reverse:
             h_seq = jnp.flip(h_seq, 1)
         return h_seq, (h_fin, c_fin)
+    xp = (x + b.astype(x.dtype)) if w_x is None else linear(x, w_x, b)
     h0 = jnp.zeros((B, H), xp.dtype) if h0 is None else h0
     c0 = jnp.zeros((B, H), xp.dtype) if c0 is None else c0
 

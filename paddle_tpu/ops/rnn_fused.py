@@ -2,7 +2,7 @@
 
 Same restructuring as ops/attention_decoder.py, applied to the plain
 recurrent layers (the encoder of the seq2seq flagship, stacked LSTM/GRU text
-models).  Two structural changes vs XLA's autodiff of the time scan:
+models).  Three structural changes vs XLA's autodiff of the time scan:
 
 1. The forward (Pallas kernel or masked lax.scan — one numerics source of
    truth either way) SAVES the per-step pre-activations ``z`` and the held
@@ -16,6 +16,12 @@ models).  Two structural changes vs XLA's autodiff of the time scan:
    is reconstructed afterwards as one batched MXU contraction
    (``einsum('tbh,tbz->hz', h_prev, d_z)``), which also serves as ``d_xp``
    directly since the input projection enters the cell additively.
+3. (LSTM) The gradients that reduce ``d_z`` over (time, batch) to the size of
+   a parameter, the bias's and the three peepholes', are accumulated inside
+   the reverse Pallas kernel, where ``d_z``, ``c_prev`` and ``c_new`` are in
+   VMEM anyway; XLA would stream ``d_z`` from HBM once more for each.  That is
+   why the op takes the bias.  The lax.scan backward (CPU, boot states, shapes
+   past the gate) leaves them to XLA as one batched reduction each.
 
 Semantics match ``scan_rnn`` + ``gru_step``/``lstm_step`` exactly (carry
 held and outputs zeroed at masked steps); equivalence is pinned by
@@ -259,21 +265,29 @@ def _lstm_fwd_scan(xp, mask, w_h, h0, c0, pi, pf, po):
             z_tb, hprev_tb, cprev_tb)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def lstm_sequence_fused(xp, mask, w_h, h0, c0, pi, pf, po,
+@partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def lstm_sequence_fused(xp, b, mask, w_h, h0, c0, pi, pf, po,
                         allow_pallas=False, has_peepholes=True):
-    """pi/pf/po: [H] peephole vectors (pass zeros for the plain cell — the
+    """LSTM over a padded batch given the input projection WITHOUT its
+    bias, ``xp`` [B,T,4H], and the bias ``b`` [4H]: the op adds the bias
+    itself, so that its backward can hand back ``d_b`` from the reverse
+    kernel's accumulator and XLA never reads ``d_z`` for it.
+    pi/pf/po: [H] peephole vectors (pass zeros for the plain cell — the
     math degenerates exactly).  ``has_peepholes`` (static) lets the
-    backward skip the c_new residual stream and the d_peep reductions when
-    the caller statically knows the peepholes are zeros."""
+    backward skip the d_peep reductions when the caller statically knows
+    the peepholes are zeros."""
     # primal-only call (inference): residual-free variant — see GRU twin
-    h_seq, h_fin, c_fin = _lstm_core_fwd(xp, mask, w_h, h0, c0, pi, pf, po,
-                                         allow_pallas, residuals=False)[:3]
+    h_seq, h_fin, c_fin = _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf,
+                                         po, allow_pallas,
+                                         residuals=False)[:3]
     return h_seq, h_fin, c_fin
 
 
-def _lstm_core_fwd(xp, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
+def _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
                    residuals=True):
+    # the same add, in the same place, as linear(x, w_x, b) makes: XLA fuses
+    # it into the projection's output
+    xp = xp + b.astype(xp.dtype)
     if allow_pallas:
         from paddle_tpu.ops.rnn import _use_pallas_rnn
 
@@ -299,40 +313,43 @@ def _lstm_core_fwd(xp, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
     return out if residuals else (out[0], out[1], out[2], None, None, None)
 
 
-def _lstm_seq_fwd(xp, mask, w_h, h0, c0, pi, pf, po, allow_pallas,
+def _lstm_seq_fwd(xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas,
                   has_peepholes):
     h_seq, h_fin, c_fin, z_tb, hprev_tb, cprev_tb = _lstm_core_fwd(
-        xp, mask, w_h, h0, c0, pi, pf, po, allow_pallas)
+        xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas)
     meta = (jnp.zeros((0,), xp.dtype), jnp.zeros((0,), h0.dtype),
-            jnp.zeros((0,), c0.dtype))  # dtype sentinels (see GRU fwd)
+            jnp.zeros((0,), c0.dtype),
+            jnp.zeros((0,), b.dtype))  # dtype sentinels (see GRU fwd)
     return ((h_seq, h_fin, c_fin),
             (mask, w_h, pi, pf, po, z_tb, hprev_tb, cprev_tb, meta))
 
 
 def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
     mask, w_h, pi, pf, po, z_r, hprev_r, cprev_r, meta = res
-    xp_s, h0_s, c0_s = meta
-    xp_dt, h0_dt, c0_dt = xp_s.dtype, h0_s.dtype, c0_s.dtype
+    xp_dt, h0_dt, c0_dt, b_dt = (s.dtype for s in meta)
     d_hseq, d_hfin, d_cfin = ct
     H = w_h.shape[0]
     B = mask.shape[0]
     f32 = jnp.float32
     w_f = w_h.astype(f32)
     pi_f, pf_f, po_f = (p.astype(f32) for p in (pi, pf, po))
+    d_peep = None
 
-    cp_f = cprev_r.astype(f32)                   # residuals are [T,B,*]
     if allow_pallas and _bwd_pallas_ok(B, H, 4):
         from paddle_tpu.ops.pallas_kernels import _lstm_bwd_pallas_raw
 
-        # residual streams enter in their STORED dtype (see GRU twin)
-        d_z_tb, cn_tb, d_h0, d_c0 = _lstm_bwd_pallas_raw(
+        # residual streams enter in their STORED dtype (see GRU twin); the
+        # bias and peephole gradients leave the kernel already reduced
+        d_z_tb, d_h0, d_c0, d_b, d_peep = _lstm_bwd_pallas_raw(
             jnp.moveaxis(d_hseq, 1, 0).astype(f32),
             jnp.moveaxis(mask, 1, 0).astype(f32),
             z_r, cprev_r, w_f.T.copy(),
             pi_f[None], pf_f[None], po_f[None],
             d_hfin.astype(f32), d_cfin.astype(f32),
-            want_cn=has_peepholes)
+            has_peepholes=has_peepholes)
+        d_b = d_b[0]
     else:
+        cp_f = cprev_r.astype(f32)               # residuals are [T,B,*]
         m_tb = jnp.moveaxis(mask, 1, 0)
         d_out_tb = jnp.moveaxis(d_hseq, 1, 0).astype(f32)
         # gate math vectorized over every timestep from the saved z/c_prev —
@@ -370,25 +387,26 @@ def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
         (d_h0, d_c0), d_z_tb = lax.scan(
             rev_step, (d_hfin.astype(f32), d_cfin.astype(f32)),
             (d_out_tb, m_tb, i, f, o, g, tc, cp_f), reverse=True)
+        # the scan leaves the parameter-sized reductions to XLA: one batched
+        # pass over d_z each (the kernel path accumulates them in its loop)
+        d_b = jnp.sum(d_z_tb, axis=(0, 1))
+        if has_peepholes:
+            d_peep = (
+                _bwd_einsum("tbh,tbh->h", d_z_tb[..., :H], cp_f),
+                _bwd_einsum("tbh,tbh->h", d_z_tb[..., H: 2 * H], cp_f),
+                _bwd_einsum("tbh,tbh->h", d_z_tb[..., 2 * H: 3 * H], cn_tb))
 
-    # shared tail (ONE copy for both reverse-loop implementations)
-    if has_peepholes:
-        # peephole gradients: one batched reduction each, outside the loop
-        d_pi = _bwd_einsum("tbh,tbh->h", d_z_tb[..., :H],
-                           cp_f).astype(pi.dtype)
-        d_pf = _bwd_einsum("tbh,tbh->h",
-                           d_z_tb[..., H: 2 * H], cp_f).astype(pf.dtype)
-        d_po = _bwd_einsum("tbh,tbh->h",
-                           d_z_tb[..., 2 * H: 3 * H], cn_tb).astype(po.dtype)
+    if d_peep is None:
+        d_pi, d_pf, d_po = (jnp.zeros_like(p) for p in (pi, pf, po))
     else:
-        d_pi = jnp.zeros_like(pi)
-        d_pf = jnp.zeros_like(pf)
-        d_po = jnp.zeros_like(po)
+        d_pi, d_pf, d_po = (d.astype(p.dtype)
+                            for d, p in zip(d_peep, (pi, pf, po)))
+    # shared tail (ONE copy for both reverse-loop implementations)
     d_wh = _bwd_einsum("tbh,tbz->hz",
                        hprev_r.astype(f32), d_z_tb).astype(w_h.dtype)
     d_xp = jnp.moveaxis(d_z_tb, 0, 1).astype(xp_dt)
-    return (d_xp, None, d_wh, d_h0.astype(h0_dt), d_c0.astype(c0_dt),
-            d_pi, d_pf, d_po)
+    return (d_xp, d_b.astype(b_dt), None, d_wh, d_h0.astype(h0_dt),
+            d_c0.astype(c0_dt), d_pi, d_pf, d_po)
 
 
 lstm_sequence_fused.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)

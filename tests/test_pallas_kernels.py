@@ -24,7 +24,7 @@ pytestmark = pytest.mark.skipif(not pallas_available(), reason="pallas unavailab
 
 def _data(rng, B=4, T=6, H=8, gates=4, dtype=np.float32):
     xp = jnp.asarray(rng.randn(B, T, gates * H).astype(dtype) * 0.3)
-    lengths = jnp.asarray(np.array([6, 3, 5, 1], np.int32)[:B])
+    lengths = jnp.asarray(np.array([6, 3, 5, 1, 2, 6, 4, 1], np.int32)[:B])
     mask = O.mask_from_lengths(lengths, T)
     w_h = jnp.asarray(rng.randn(H, gates * H).astype(dtype) * 0.2)
     return xp, mask, w_h
@@ -116,33 +116,43 @@ class TestBackwardKernels:
     """The Pallas reverse-loop kernels (interpret mode) must produce the
     exact gradients of the scan forward they pair with in rnn_fused."""
 
-    def test_lstm_fused_grads_with_pallas_bwd(self, rng, monkeypatch):
+    @pytest.mark.parametrize("has_peepholes", [True, False],
+                             ids=["peepholes", "plain"])
+    def test_lstm_fused_grads_with_pallas_bwd(self, rng, monkeypatch,
+                                              has_peepholes):
+        """Both variants of the kernel: the bias gradient always leaves it
+        reduced, the three peephole gradients when peepholes are live."""
         from paddle_tpu.ops.rnn_fused import lstm_sequence_fused
-        B, T, H = 4, 6, 8
+        # B fills a sublane tile, as the gate demands of every shape it
+        # admits: the kernel's accumulators hold one tile per column
+        B, T, H = 8, 6, 8
         xp, mask, w_h = _data(rng, B=B, T=T, H=H, gates=4)
+        b = jnp.asarray(rng.randn(4 * H).astype(np.float32) * 0.1)
         z = jnp.zeros((B, H), jnp.float32)
         ct_seq = jnp.asarray(rng.randn(B, T, H).astype(np.float32))
         ct_h = jnp.asarray(rng.randn(B, H).astype(np.float32))
         ct_c = jnp.asarray(rng.randn(B, H).astype(np.float32))
 
-        pi = jnp.asarray(rng.randn(H).astype(np.float32) * 0.3)
-        pf = jnp.asarray(rng.randn(H).astype(np.float32) * 0.3)
-        po = jnp.asarray(rng.randn(H).astype(np.float32) * 0.3)
+        pi, pf, po = (
+            jnp.asarray(rng.randn(H).astype(np.float32) * 0.3 * has_peepholes)
+            for _ in range(3))
 
         def obj(fn):
-            def f(xp, w_h):
-                h_seq, h_f, c_f = fn(xp, mask, w_h, z, z, pi, pf, po, True)
+            def f(xp, w_h, b, pi, pf, po):
+                h_seq, h_f, c_f = fn(xp, b, mask, w_h, z, z, pi, pf, po,
+                                     True, has_peepholes)
                 return ((h_seq * ct_seq).sum() + (h_f * ct_h).sum()
                         + (c_f * ct_c).sum())
             return f
 
         # reference: identical function with the scan backward (gate off)
+        args = (xp, w_h, b, pi, pf, po)
         monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
                             lambda B, H, gates: False)
-        g_ref = jax.grad(obj(lstm_sequence_fused), (0, 1))(xp, w_h)
+        g_ref = jax.grad(obj(lstm_sequence_fused), tuple(range(6)))(*args)
         monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
                             lambda B, H, gates: True)
-        g_pal = jax.grad(obj(lstm_sequence_fused), (0, 1))(xp, w_h)
+        g_pal = jax.grad(obj(lstm_sequence_fused), tuple(range(6)))(*args)
         for a, b in zip(g_ref, g_pal):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)

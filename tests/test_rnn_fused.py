@@ -1,14 +1,18 @@
 """Fused-backward GRU/LSTM sequence ops vs scan_rnn autodiff — values and
 gradients, covering masks, reverse (flip routing in gru_layer/lstm_layer),
-and non-zero boot state."""
+non-zero boot state, and the LSTM's reverse Pallas kernel (interpret mode)
+with the bias and peephole gradients it accumulates in its time loop."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
+import paddle_tpu.nn as nn
 import paddle_tpu.ops as O
+from paddle_tpu.analysis.jaxpr_walk import walk_eqns
 from paddle_tpu.ops.rnn_fused import gru_sequence_fused, lstm_sequence_fused
+from paddle_tpu.utils.flags import FLAGS
 
 
 def _mask(lens, T):
@@ -121,7 +125,8 @@ class TestLstmFused:
 
         (rf, rc), rseq = self._ref(xp, mask, wh, h0, c0)
         zp = jnp.zeros((H,), jnp.float32)
-        nseq, nf, nc = lstm_sequence_fused(xp, mask, wh, h0, c0,
+        zb = jnp.zeros((4 * H,), jnp.float32)
+        nseq, nf, nc = lstm_sequence_fused(xp, zb, mask, wh, h0, c0,
                                            zp, zp, zp, False)
         np.testing.assert_allclose(np.asarray(rseq), np.asarray(nseq),
                                    rtol=1e-5, atol=1e-6)
@@ -135,7 +140,7 @@ class TestLstmFused:
             return jnp.sum(seq * ct_seq) + jnp.sum(f) + jnp.sum(c)
 
         def loss_new(xp, wh, h0, c0):
-            seq, f, c = lstm_sequence_fused(xp, mask, wh, h0, c0,
+            seq, f, c = lstm_sequence_fused(xp, zb, mask, wh, h0, c0,
                                             zp, zp, zp, False)
             return jnp.sum(seq * ct_seq) + jnp.sum(f) + jnp.sum(c)
 
@@ -171,8 +176,8 @@ class TestLstmFusedPeepholes:
             return jnp.sum(seq * ct_seq) + jnp.sum(f) + 2.0 * jnp.sum(c)
 
         def new(xp, wh, pi, pf, po):
-            seq, f, c = lstm_sequence_fused(xp, mask, wh, z, z,
-                                            pi, pf, po, False)
+            seq, f, c = lstm_sequence_fused(xp, jnp.zeros((4 * H,)), mask,
+                                            wh, z, z, pi, pf, po, False)
             return jnp.sum(seq * ct_seq) + jnp.sum(f) + 2.0 * jnp.sum(c)
 
         np.testing.assert_allclose(
@@ -183,3 +188,177 @@ class TestLstmFusedPeepholes:
         for name, a, b in zip(("xp", "wh", "pi", "pf", "po"), g_ref, g_new):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+def _backward_kernel(monkeypatch, on):
+    """Which reverse loop lstm_sequence_fused(..., allow_pallas=True) takes:
+    the Pallas kernel (interpret mode here) or the lax.scan."""
+    monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
+                        lambda B, H, gates: on)
+
+
+class TestLstmKernelBackward:
+    """The reverse kernel hands back d_b, d_pi, d_pf, d_po beside d_z, d_h0
+    and d_c0; nothing but the three matrix products reads d_z after it."""
+
+    B, T, H = 16, 6, 8            # two sublane tiles of rows
+    RAGGED = (6, 3, 1, 5, 2, 6, 4, 1, 6, 1, 2, 3, 5, 4, 6, 2)
+
+    @pytest.mark.parametrize("residuals", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("peepholes", [True, False],
+                             ids=["peepholes", "plain"])
+    @pytest.mark.parametrize("lens", [RAGGED, (6,) * 16],
+                             ids=["ragged", "full"])
+    def test_every_gradient_matches_scan_reference(self, monkeypatch, lens,
+                                                   peepholes, residuals):
+        """All eight gradients of the kernel path against autodiff of
+        scan_rnn(lstm_step), over masked tails (rows of length 1, full
+        rows), with and without peepholes, with float32 and with bf16
+        residual streams (the production policy's).  bf16 residuals round
+        what the backward recomputes its gates from, so there the tight
+        comparison is with the lax.scan backward over the same residuals."""
+        rs = np.random.RandomState(7)
+        B, T, H = self.B, self.T, self.H
+        monkeypatch.setattr(FLAGS, "compute_dtype", residuals)
+        r = lambda *shape, scale=1.0: jnp.asarray(  # noqa: E731
+            scale * rs.randn(*shape).astype(np.float32))
+        xp, b, wh = r(B, T, 4 * H), r(4 * H, scale=0.3), r(H, 4 * H, scale=0.4)
+        h0, c0 = r(B, H), r(B, H)
+        peeps = tuple(r(H, scale=0.3 * peepholes) for _ in range(3))
+        ct_seq, ct_h, ct_c = r(B, T, H), r(B, H), r(B, H)
+        mask = _mask(lens, T)
+
+        def objective(seq, f, c):
+            return (jnp.sum(seq * ct_seq) + jnp.sum(f * ct_h)
+                    + jnp.sum(c * ct_c))
+
+        def ref(xp, b, wh, h0, c0, pi, pf, po):
+            def step(carry, xp_t):
+                h, c = carry
+                h2, c2 = O.lstm_step(xp_t, h, c, wh, peep_i=pi, peep_f=pf,
+                                     peep_o=po)
+                return (h2, c2), h2
+            (f, c), seq = O.scan_rnn(step, (h0, c0), xp + b, mask)
+            return objective(seq, f, c)
+
+        def new(xp, b, wh, h0, c0, pi, pf, po):
+            return objective(*lstm_sequence_fused(
+                xp, b, mask, wh, h0, c0, pi, pf, po, True, peepholes))
+
+        args = (xp, b, wh, h0, c0) + peeps
+        names = ("xp", "b", "wh", "h0", "c0", "pi", "pf", "po")
+        argnums = tuple(range(8 if peepholes else 5))
+        g_ref = jax.grad(ref, argnums)(*args)
+        _backward_kernel(monkeypatch, False)
+        g_scan = jax.grad(new, argnums)(*args)
+        _backward_kernel(monkeypatch, True)
+        g_new = jax.grad(new, tuple(range(8)))(*args)
+        tol = 1e-4 if residuals == "float32" else 3e-2
+        for name, a, s, k in zip(names, g_ref, g_scan, g_new):
+            scale = float(jnp.max(jnp.abs(a)))
+            np.testing.assert_allclose(np.asarray(k), np.asarray(a),
+                                       rtol=tol, atol=tol * scale,
+                                       err_msg=name)
+            np.testing.assert_allclose(np.asarray(k), np.asarray(s),
+                                       rtol=1e-5, atol=1e-5 * scale,
+                                       err_msg=name + " (scan backward)")
+        if not peepholes:       # no accumulator: the gradients are zeros
+            assert all(not np.asarray(g).any() for g in g_new[5:])
+
+    @pytest.mark.parametrize("options", [
+        dict(reverse=True), dict(use_peepholes=False), dict(bias_attr=False),
+        dict(projected_input=True)],
+        ids=["reverse", "no_peepholes", "no_bias", "projected_input"])
+    def test_lstmemory_options_on_the_kernel_path(self, monkeypatch,
+                                                  options):
+        """nn.lstmemory's variants reach the kernel path with the right
+        operands: the flip for ``reverse``, no peephole accumulators, a
+        bias that is no parameter, an input that is the projection."""
+        rs = np.random.RandomState(11)
+        B, T, H = self.B, self.T, self.H
+        D = 4 * H if options.get("projected_input") else 5
+        reverse = options.get("reverse", False)
+        nn.reset_naming()
+        layer = nn.lstmemory(nn.data("x", size=D, is_seq=True), H, name="l",
+                             **options)
+        topo = nn.Topology([layer])
+        params, state = topo.init(jax.random.PRNGKey(0))
+        params = {k: jnp.asarray(0.3 * rs.randn(*v.shape).astype(np.float32))
+                  for k, v in params.items()}
+        xs = jnp.asarray(rs.randn(B, T, D).astype(np.float32))
+        lengths = np.asarray(self.RAGGED, np.int32)
+        ct = jnp.asarray(rs.randn(B, T, H).astype(np.float32))
+
+        def new(params, xs):
+            out = topo.apply(params, state, {"x": (xs, lengths)})[0]["l"]
+            return (jnp.sum(out.value * ct) + jnp.sum(out.state["final_h"])
+                    + 2.0 * jnp.sum(out.state["final_c"]))
+
+        def ref(params, xs):
+            xp = xs if "_l.wx" not in params else O.linear(xs, params["_l.wx"])
+            if "_l.wbias" in params:
+                xp = xp + params["_l.wbias"]
+            pk = {f"peep_{g}": params[f"_l.check_{g}"] for g in "ifo"
+                  if f"_l.check_{g}" in params}
+
+            def step(carry, xp_t):
+                h2, c2 = O.lstm_step(xp_t, *carry, params["_l.w0"], **pk)
+                return (h2, c2), h2
+            zeros = jnp.zeros((B, H), jnp.float32)
+            (f, c), seq = O.scan_rnn(step, (zeros, zeros), xp,
+                                     _mask(lengths, T), reverse=reverse)
+            return jnp.sum(seq * ct) + jnp.sum(f) + 2.0 * jnp.sum(c)
+
+        _backward_kernel(monkeypatch, True)
+        np.testing.assert_allclose(float(new(params, xs)),
+                                   float(ref(params, xs)), rtol=1e-5)
+        g_new = jax.grad(new, (0, 1))(params, xs)
+        g_ref = jax.grad(ref, (0, 1))(params, xs)
+        assert set(g_new[0]) == set(g_ref[0])
+        for name in g_ref[0]:
+            np.testing.assert_allclose(
+                np.asarray(g_new[0][name]), np.asarray(g_ref[0][name]),
+                rtol=1e-4, atol=1e-5, err_msg=name)
+        np.testing.assert_allclose(np.asarray(g_new[1]), np.asarray(g_ref[1]),
+                                   rtol=1e-4, atol=1e-5, err_msg="x")
+
+    def test_only_the_three_products_read_d_z(self, monkeypatch):
+        """Structure of the kernel path's backward: XLA reads a [T,B,4H] or
+        [T,B,H] array for nothing but dW_x, dx and dW_h: no reduce_sum over
+        one (the bias), no product against one (the peepholes).  The scan
+        path, which keeps those four reductions, shows that they are seen."""
+        B, T, H, D = self.B, self.T, self.H, 5
+        f32 = jnp.float32
+        mask = _mask(self.RAGGED, T)
+
+        args = [jnp.zeros(s, f32) for s in [
+            (B, T, D), (D, 4 * H), (H, 4 * H), (4 * H,), (H,), (H,), (H,)]]
+        cts = (jnp.zeros((B, T, H), f32),
+               (jnp.zeros((B, H), f32), jnp.zeros((B, H), f32)))
+
+        def passes(kernel):
+            _backward_kernel(monkeypatch, kernel)
+
+            # a function of its own for each trace: JAX keys its caches on it
+            def layer(x, w_x, w_h, b, pi, pf, po):
+                return O.lstm_layer(x, mask, w_x, w_h, b, peep_i=pi,
+                                    peep_f=pf, peep_o=po)
+
+            def backward(args, cotangents):  # no loss: it would reduce too
+                return jax.vjp(layer, *args)[1](cotangents)
+
+            jaxpr = jax.make_jaxpr(backward)(args, cts)
+            seen = {"dot_general": 0, "reduce_sum": 0}
+            for eqn, path in walk_eqns(jaxpr):
+                if "pallas_call" in path:     # XLA does not see inside
+                    continue
+                big = [v.aval.shape for v in eqn.invars
+                       if getattr(v.aval, "ndim", 0) == 3
+                       and v.aval.shape[-1] in (H, 4 * H)
+                       and set(v.aval.shape[:2]) == {B, T}]
+                if big and eqn.primitive.name in seen:
+                    seen[eqn.primitive.name] += 1
+            return seen
+
+        assert passes(kernel=True) == {"dot_general": 3, "reduce_sum": 0}
+        assert passes(kernel=False) == {"dot_general": 6, "reduce_sum": 1}

@@ -344,8 +344,7 @@ def bench_seq2seq_decode(rtt, peak):
     Since the fused decode engine (ops/decode.py) this row runs the
     vocab-tiled Pallas top-k+logsumexp readout under the early-exit while
     loop; random inputs essentially never finish every beam early, so the
-    measured time is the honest full-max_len cost.  The kernel-vs-fallback
-    delta is isolated in the pallas_decode_ab row."""
+    measured time is the honest full-max_len cost."""
     import jax
     import jax.numpy as jnp
 
@@ -599,142 +598,10 @@ def bench_googlenet(rtt, peak, batch_size=128):
         published={64: 613.0, 128: 1149.0, 256: 2348.0})
 
 
-def bench_pallas_lstm_ab(rtt, peak):
-    """A/B the fused Pallas LSTM time-loop kernel vs the XLA scan path at
-    tile-aligned shapes (B%8==0, H%128==0) — settles FLAGS.use_pallas_rnn."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops import lstm_layer
-    from paddle_tpu.utils.flags import FLAGS
-
-    B, T, H = 64, 100, 256
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(B, T, 2 * H).astype(np.float32) * 0.1)
-    mask = jnp.ones((B, T), jnp.float32)
-    w_x = jnp.asarray(rng.randn(2 * H, 4 * H).astype(np.float32) * 0.05)
-    w_h = jnp.asarray(rng.randn(H, 4 * H).astype(np.float32) * 0.05)
-    b = jnp.zeros((4 * H,), jnp.float32)
-
-    def run_variant(use_pallas: bool):
-        old = FLAGS.use_pallas_rnn
-        FLAGS.use_pallas_rnn = use_pallas
-        try:
-            # flag is read at trace time: fresh python fn -> fresh jit cache
-            def fwd_bwd(x, w_x, w_h, b):
-                def f(w_x, w_h, b):
-                    h, _ = lstm_layer(x, mask, w_x, w_h, b)
-                    return (h * h).sum()
-
-                return jax.value_and_grad(f, argnums=(0, 1, 2))(w_x, w_h, b)
-
-            def one_step(carry):
-                x, w_x, w_h, b = carry
-                loss, (gx, gh, gb) = fwd_bwd(x, w_x, w_h, b)
-                # feed grads back in so the loop can't be collapsed
-                return (x, w_x - 1e-6 * gx, w_h - 1e-6 * gh, b - 1e-6 * gb), loss
-
-            sec, _, spread = _time_chain(one_step, (x, w_x, w_h, b), iters=100,
-                                         rtt=rtt, reps=5)
-            return sec, spread
-        finally:
-            FLAGS.use_pallas_rnn = old
-
-    xla_sec, xla_spread = run_variant(False)
-    # a kernel that fails to compile or run fails the row (and the exit
-    # code), it does not lose an A/B quietly
-    pallas_sec, pallas_spread = run_variant(True)
-    # <5% deltas are run-to-run noise at these kernel sizes
-    if pallas_sec < 0.95 * xla_sec:
-        winner = "pallas"
-    elif xla_sec < 0.95 * pallas_sec:
-        winner = "xla_scan"
-    else:
-        winner = "tie"
-    best = min(xla_sec, pallas_sec)
-    return {
-        "metric": "pallas_lstm_ab_fwd_bwd_ms(b64,h256,T100)",
-        "short": "pallas_lstm_ab",
-        "value": round(best * 1e3, 3),
-        "unit": "ms",
-        "vs_baseline": None,
-        "xla_scan_ms": round(xla_sec * 1e3, 3),
-        "xla_scan_ms_min": round(xla_spread[0] * 1e3, 3),
-        "xla_scan_ms_max": round(xla_spread[1] * 1e3, 3),
-        "pallas_ms": round(pallas_sec * 1e3, 3),
-        "pallas_ms_min": round(pallas_spread[0] * 1e3, 3),
-        "pallas_ms_max": round(pallas_spread[1] * 1e3, 3),
-        "winner": winner,
-        "default_flag": bool(FLAGS.use_pallas_rnn),
-    }
-
-
-def bench_pallas_decode_ab(rtt, peak):
-    """A/B the fused decode engine's vocab-tiled Pallas top-k+logsumexp
-    readout vs the XLA ``top_k`` fallback at the gen bench shape — settles
-    FLAGS.use_pallas_decode (mirrors pallas_lstm_ab's winner/default_flag
-    contract).  Both variants run the SAME engine (early-exit while loop,
-    packed gather); only the per-step readout differs."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.models import Seq2SeqAttention
-    from paddle_tpu.utils.flags import FLAGS
-
-    B, S, K, L = 64, 32, 3, 32
-    m = Seq2SeqAttention()
-    params = m.init(jax.random.PRNGKey(0))
-    rng = np.random.RandomState(0)
-    src = jnp.asarray(rng.randint(3, m.src_vocab, (B, S)).astype(np.int32))
-    src_len = jnp.full((B,), S, jnp.int32)
-
-    def run_variant(use_kernel: bool):
-        # flag is read at trace time: fresh python fn -> fresh jit cache
-        def one_step(carry):
-            params, src, src_len = carry
-            toks, scores = m.beam_search(params, src, src_len, beam_size=K,
-                                         max_len=L, use_kernel=use_kernel)
-            # feed the decode back so XLA can't hoist it (see decode row)
-            src = (src + toks[:, 0, :S]) % (m.src_vocab - 3) + 3
-            return (params, src, src_len), scores.sum()
-
-        sec, _, spread = _time_chain(one_step, (params, src, src_len),
-                                     iters=10, rtt=rtt, reps=5)
-        return sec, spread
-
-    xla_sec, xla_spread = run_variant(False)
-    # use_kernel=True bypasses the backend half of the gate; main() has
-    # already refused to run without a TPU, and a kernel failure fails the
-    # row (see pallas_lstm_ab)
-    pallas_sec, pallas_spread = run_variant(True)
-    if pallas_sec < 0.95 * xla_sec:
-        winner = "pallas"
-    elif xla_sec < 0.95 * pallas_sec:
-        winner = "xla_topk"
-    else:
-        winner = "tie"
-    best = min(xla_sec, pallas_sec)
-    return {
-        "metric": f"pallas_decode_ab_beam{K}_ms(B{B},S{S},L{L})",
-        "short": "pallas_decode_ab",
-        "value": round(best * 1e3, 3),
-        "unit": "ms",
-        "vs_baseline": None,
-        "xla_topk_ms": round(xla_sec * 1e3, 3),
-        "xla_topk_ms_min": round(xla_spread[0] * 1e3, 3),
-        "xla_topk_ms_max": round(xla_spread[1] * 1e3, 3),
-        "pallas_ms": round(pallas_sec * 1e3, 3),
-        "pallas_ms_min": round(pallas_spread[0] * 1e3, 3),
-        "pallas_ms_max": round(pallas_spread[1] * 1e3, 3),
-        "winner": winner,
-        "default_flag": bool(FLAGS.use_pallas_decode),
-    }
-
-
 def bench_amp_ab(rtt, peak):
     """A/B mixed-precision (--amp) vs the default policy on the headline
-    seq2seq shape AND one LSTM text-clf config — settles FLAGS.amp the way
-    pallas_lstm_ab settles its kernel flag (winner/default_flag contract).
+    seq2seq shape AND one LSTM text-clf config — settles FLAGS.amp
+    (winner/default_flag contract).
 
     The baseline on TPU already runs bf16 MATMUL OPERANDS with f32
     activations (FLAGS.compute_dtype); --amp additionally keeps
@@ -2244,8 +2111,6 @@ def main(argv=None) -> int:
         safe(bench_googlenet),
         safe(bench_googlenet, batch_size=256),
         safe(bench_lstm_textclf, batch_size=512, hidden=256, remat=True),
-        safe(bench_pallas_lstm_ab),
-        safe(bench_pallas_decode_ab),
         safe(bench_amp_ab),
         safe(bench_seq_packing_ab),
         safe(bench_serving_continuous_ab),

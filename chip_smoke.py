@@ -492,7 +492,6 @@ def phase_sharded_train(sizes: Sizes, seed: int, devices) -> None:
     from jax.sharding import Mesh
 
     import paddle_tpu.parallel as par
-    from paddle_tpu.ops.pallas_kernels import kernel_flags_on
     from paddle_tpu.param.optimizers import Adam
 
     t0 = time.perf_counter()
@@ -546,9 +545,8 @@ def phase_sharded_train(sizes: Sizes, seed: int, devices) -> None:
     emit("sharded_train", t0, mesh={"data": 2, "model": 2},
          losses=got, one_chip_losses=ref, rtol=FOUR_CHIP_RTOL,
          all_reduces=all_reduces, tpu_custom_calls=kernels,
-         kernel_flags_overridden=kernel_flags_on(),
          note="Mosaic kernels cannot be partitioned by jit: the sharded "
-              "step runs the XLA paths, whatever these flags say")
+              "step runs the XLA paths")
 
 
 def phase_sharded_lookup(sizes: Sizes, seed: int, devices) -> None:

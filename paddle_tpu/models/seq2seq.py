@@ -82,9 +82,8 @@ class Seq2SeqAttention:
         """[B,S] ids -> (enc [B,S,2H], enc_proj [B,S,A], s0 [B,D])."""
         emb = O.embedding_lookup(params["src_emb"], src_ids)
         emb = emb * src_mask[..., None].astype(emb.dtype)
-        # both directions in ONE fused time loop where the bidirectional
-        # Pallas kernel applies (ops/rnn.bigru_layer) — the two scans
-        # otherwise serialize on the single core
+        # a forward and a reversed GRU over the same embeddings; the two
+        # time loops run one after the other on the single core
         h_fw, h_bw, h_bw_fin = O.bigru_layer(
             emb, src_mask, params["enc_fw_wx"], params["enc_fw_wh"],
             params["enc_fw_b"], params["enc_bw_wx"], params["enc_bw_wh"],

@@ -340,8 +340,8 @@ class SequenceGenerator:
         Without beam-control callbacks the search runs on the fused decode
         engine (ops/decode.py): per-row top-k + logsumexp straight from the
         step logits (one HBM pass, no f32 log-softmax buffer; Pallas kernel
-        on TPU via ``FLAGS.use_pallas_decode``), all-beams-finished early
-        exit, packed beam-state gather — output-identical to the scan path.
+        on TPU where ``decode_kernel_config`` admits the shape),
+        all-beams-finished early exit, packed beam-state gather — output-identical to the scan path.
         The callback/trace protocol below needs the full [B,K,V] per-step
         log-probs (and, for the trace, a record at every one of the
         ``max_len`` steps), so those runs keep the fixed-length scan.

@@ -50,16 +50,13 @@ __all__ = ["attention_gru_decoder"]
 def _attn_pallas_block(B, S, D, A, H2):
     """Batch-block size for the VMEM-resident Pallas decoder kernels
     (ops/pallas_kernels.py: attn_dec_fwd_pallas / attn_dec_bwd_pallas), or
-    None to use the XLA scan path.  Gates: flag + TPU backend + lane/tile
+    None to use the XLA scan path.  Gates: TPU backend + lane/tile
     alignment (the kernels slice [Bb, S, A]/[Bb, gates*D] blocks) + the
     resident working set (enc, enc_proj, the backward's d_enc_proj
     accumulator and its d_pre temporary, all per block) must fit the raised
     VMEM budget."""
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
-    from paddle_tpu.utils.flags import FLAGS
 
-    if not FLAGS.use_pallas_attention:
-        return None
     if not compiled_kernels():
         return None
     if D % 128 or A % 128 or H2 % 128 or S % 8:

@@ -41,8 +41,7 @@ ids are bit-identical to the unfused path and scores match to float
 re-association (~1e-7).
 
 Kernel gating mirrors ``losses._tiled_ce_cfg``: TPU backend + tile-aligned
-shapes + ``FLAGS.use_pallas_decode``, with the XLA ``top_k`` fallback
-otherwise (A/B benched as ``pallas_decode_ab`` in bench.py).  The lowered
+shapes, with the XLA ``top_k`` fallback otherwise.  The lowered
 decode fn is auditable host-transfer-free via
 ``paddle_tpu.analysis.audit_decode``.
 """
@@ -94,23 +93,19 @@ def decode_kernel_config(n_rows: int, depth: Optional[int], vocab: int,
     """Gate for the vocab-tiled top-k readout kernel: (row_block, v_tile)
     or None for the XLA ``top_k`` fallback.  ``depth`` is the readout
     contraction dim (None for the pre-materialized-logits variant, which
-    has no MXU operand to align).  Needs a TPU backend, the flag on,
-    lane-aligned depth, a sublane-aligned row block dividing the rows, and
-    a small static k (the kernel unrolls k merge passes per tile)."""
-    from paddle_tpu.utils.flags import FLAGS
-
+    has no MXU operand to align).  Needs a TPU backend, lane-aligned depth,
+    a sublane-aligned row block dividing the rows, and a small static k (the
+    kernel unrolls k merge passes per tile)."""
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
 
-    if not FLAGS.use_pallas_decode:
-        return None
     if not compiled_kernels():
         return None
     return _forced_kernel_config(n_rows, depth, vocab, k)
 
 
 def _forced_kernel_config(n_rows, depth, vocab, k):
-    """Shape-only half of the gate (backend/flag checks skipped) — used by
-    tests and the A/B bench to exercise the kernel in interpret mode."""
+    """Shape-only half of the gate (backend check skipped) — used by tests
+    to exercise the kernel in interpret mode."""
     if depth is not None and depth % 128:
         return None
     if not 1 <= k <= _MAX_KERNEL_K or vocab < k:

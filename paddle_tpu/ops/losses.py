@@ -205,10 +205,7 @@ def _tiled_ce_cfg(B, T, D, V):
     V itself only sets tile padding (handled in the wrapper)."""
     from paddle_tpu.ops.numerics import compute_dtype
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
-    from paddle_tpu.utils.flags import FLAGS
 
-    if not FLAGS.use_pallas_ce:
-        return None
     if not compiled_kernels():
         return None
     if D % 128:

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the hot ops.
+"""Pallas TPU kernels for the hot ops, and the backend half of their gates.
 
 The reference's performance tier is hand-written CUDA: fused LSTM cell with
 intra-sequence parallelism (paddle/cuda/src/hl_cuda_lstm.cu:26-58, PTX
@@ -8,13 +8,32 @@ time, recurrent weights stay resident in VMEM across all timesteps, and the
 h/c state lives in VMEM scratch, so per-step HBM traffic is just the input
 projection block in and the hidden block out.
 
-Forward-only kernels wrapped in ``jax.custom_vjp``: the backward pass
-recomputes via the pure-JAX scan implementation (rematerialization trades
-FLOPs for memory, and keeps one numerics source of truth for gradients).
+What the file holds, family by family, with the gate that chooses each
+kernel over its XLA twin.  A gate lives in the module that calls the kernel
+and is a function of the backend (:func:`compiled_kernels`: the ``"tpu"``
+backend, outside :func:`xla_paths_only`) and of the shape; nothing a user
+sets takes part:
 
-All kernels are shape-gated: ``lstm_layer``/``gru_layer`` in ops.rnn call
-these automatically on TPU when dims are tile-aligned; otherwise the lax.scan
-path runs.  CPU tests run both paths and compare (interpret mode).
+- LSTM / GRU time loops, forward (``lstm_seq_fwd``, ``gru_seq_fwd``; they
+  stream out the residuals the backward needs) and reverse (``lstm_seq_bwd``,
+  ``gru_seq_bwd``; no forward replay).  Gate: ``ops/rnn_fused.rnn_kernel_ok``
+  (``backward=`` for the reverse kernels), sized by :func:`rnn_vmem_bytes`.
+  ``lstm_forward_pallas`` / ``gru_forward_pallas`` are direct entries for
+  tests, with an autodiff-of-reference backward.
+- Attention GRU decoder, forward and reverse.  Gate:
+  ``ops/attention_decoder._attn_pallas_block``.
+- Vocab-tiled readout + softmax cross-entropy, forward and backward.  Gate:
+  ``ops/losses._tiled_ce_cfg``.
+- Vocab-tiled top-k + logsumexp readout (decode).  Gate:
+  ``ops/decode.decode_kernel_config``.
+- Causal flash attention, forward, dq and dk/dv.  Gate:
+  ``ops/decoder_block.attention_kernel_blocks``.
+- Grouped matrix products over experts.  Gate:
+  ``ops/moe.moe_kernel_row_tile``.
+
+Where a gate is closed (the CPU, a shape past it, a step that jit partitions
+over a mesh) the caller's lax.scan / XLA path runs.  Off the TPU the kernels
+run in interpret mode, which is how the CPU tests compare both paths.
 """
 
 from __future__ import annotations
@@ -29,7 +48,6 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
-           "kernel_flags_on",
            "lstm_forward_pallas", "gru_forward_pallas",
            "attn_dec_fwd_pallas", "attn_dec_bwd_pallas",
            "topk_lse_readout_pallas", "topk_lse_logits_pallas", "TOPK_LANES",
@@ -59,15 +77,6 @@ _mesh_trace = threading.local()
 _warned_kernels_off = False
 
 
-def kernel_flags_on() -> list:
-    """The ``--use_pallas_*`` flags that are on — what :func:`xla_paths_only`
-    overrides while it is entered."""
-    from paddle_tpu.utils.flags import FLAGS
-
-    return sorted(k for k, v in FLAGS.as_dict().items()
-                  if k.startswith("use_pallas_") and v)
-
-
 @contextlib.contextmanager
 def xla_paths_only():
     """Entered (also as a decorator) around the trace of a step that jit
@@ -87,8 +96,8 @@ def xla_paths_only():
 
         logger.warning(
             "this step is partitioned over a mesh by jit, which Mosaic "
-            "kernels do not survive: it runs the XLA paths, whatever %s "
-            "say", ", ".join("--" + f for f in kernel_flags_on()))
+            "kernels do not survive: every kernel gate is closed while it "
+            "is traced, and it runs the XLA paths")
     _mesh_trace.depth = getattr(_mesh_trace, "depth", 0) + 1
     try:
         yield
@@ -111,12 +120,13 @@ def compiled_kernels() -> bool:
 #: scoped VMEM the four recurrent time-loop kernels (LSTM/GRU, forward and
 #: reverse) ask of Mosaic.  The default is 16 MiB, which the resident
 #: recurrent weight alone exceeds at H=1280 ([H,4H] f32 = 25 MiB); the
-#: gates in ops/rnn.py admit a shape only when rnn_vmem_bytes() fits it.
+#: gate (ops/rnn_fused.rnn_kernel_ok) admits a shape only when
+#: rnn_vmem_bytes() fits it.
 RNN_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
-                   residual_itemsize: int, directions: int = 1) -> int:
+                   residual_itemsize: int) -> int:
     """Scoped VMEM one recurrent time-loop kernel holds, from its
     BlockSpecs: the recurrent weight ``[H, gates*H]`` f32 stays resident
     (one buffer — its block index never changes), every per-step block is
@@ -128,7 +138,7 @@ def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
     tests/test_tpu_compile.py holds the gates to it)."""
     carries = 2 if gates == 4 else 1
     rs = residual_itemsize
-    weight = 4 * gates * hidden * hidden * directions
+    weight = 4 * gates * hidden * hidden
     fixed = 0
     if backward:
         # d_out in, z + held-carry residuals in, d_z out, then per carry:
@@ -338,11 +348,7 @@ lstm_forward_pallas.defvjp(_lstm_fwd, _lstm_bwd)
 
 
 def _gru_kernel(xp_ref, m_ref, wh_ref, hseq_ref, hfin_ref, *rest,
-                hidden: int, mxu_dtype, batch_split: int = 0):
-    """``batch_split`` > 0 runs a BIDIRECTIONAL batch: rows [:split] use
-    weight rows [:H] (forward direction) and rows [split:] use rows [H:]
-    (backward direction, its inputs time-flipped by the caller) — both
-    directions advance in ONE sequential time loop instead of two."""
+                hidden: int, mxu_dtype):
     from jax.experimental import pallas as pl
 
     save_residuals = len(rest) == 3  # (zseq, hprev, h_scr) vs (h_scr,)
@@ -361,17 +367,11 @@ def _gru_kernel(xp_ref, m_ref, wh_ref, hseq_ref, hfin_ref, *rest,
     h = h_scr[...]
     H = hidden
     xp = xp_ref[0]                                      # [B, 3H]
-    w = wh_ref[...].astype(mxu_dtype)                   # [H or 2H, 3H]
+    w = wh_ref[...].astype(mxu_dtype)                   # [H, 3H]
 
     def rdot(v, lo, hi):
-        vc = v.astype(mxu_dtype)
-        if batch_split:
-            return jnp.concatenate([
-                jnp.dot(vc[:batch_split], w[:H, lo:hi],
-                        preferred_element_type=jnp.float32),
-                jnp.dot(vc[batch_split:], w[H:, lo:hi],
-                        preferred_element_type=jnp.float32)], 0)
-        return jnp.dot(vc, w[:, lo:hi], preferred_element_type=jnp.float32)
+        return jnp.dot(v.astype(mxu_dtype), w[:, lo:hi],
+                       preferred_element_type=jnp.float32)
 
     zr = xp[:, : 2 * H] + rdot(h, 0, 2 * H)
     r = jax.nn.sigmoid(zr[:, :H])
@@ -394,11 +394,9 @@ def _gru_kernel(xp_ref, m_ref, wh_ref, hseq_ref, hfin_ref, *rest,
         hfin_ref[...] = h_new
 
 
-def _gru_pallas_raw(xp_tb, mask_tb, w_h, *, residuals: bool = True,
-                    batch_split: int = 0):
+def _gru_pallas_raw(xp_tb, mask_tb, w_h, *, residuals: bool = True):
     """TIME-MAJOR (see _lstm_pallas_raw).  ``residuals=False``: inference
-    variant without the z/h_prev outputs.  ``batch_split``: bidirectional
-    batch with stacked [2H, 3H] weights (see _gru_kernel)."""
+    variant without the z/h_prev outputs."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -407,8 +405,7 @@ def _gru_pallas_raw(xp_tb, mask_tb, w_h, *, residuals: bool = True,
     T, B, H3 = xp_tb.shape
     H = H3 // 3
     kernel = functools.partial(_gru_kernel, hidden=H,
-                               mxu_dtype=compute_dtype(),
-                               batch_split=batch_split)
+                               mxu_dtype=compute_dtype())
     step = lambda t: (t, 0, 0)
     out_specs = [
         pl.BlockSpec((1, B, H), step),
@@ -437,7 +434,7 @@ def _gru_pallas_raw(xp_tb, mask_tb, w_h, *, residuals: bool = True,
         in_specs=[
             pl.BlockSpec((1, B, H3), step),
             pl.BlockSpec((1, B, 1), step),
-            pl.BlockSpec((w_h.shape[0], H3), lambda t: (0, 0)),
+            pl.BlockSpec((H, H3), lambda t: (0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -650,11 +647,8 @@ def _lstm_bwd_pallas_raw(dout_tb, m_tb, z_tb, cp_tb, w_t, pi, pf, po,
 
 
 def _gru_bwd_kernel(dout_ref, m_ref, z_ref, hp_ref, wt_ref, dhfin_ref,
-                    dz_ref, dh0_ref, dh_scr, *, hidden: int,
-                    batch_split: int = 0):
-    """Reverse GRU step — mirrors rnn_fused._gru_seq_bwd.rev_step (f32).
-    ``batch_split``: bidirectional batch; w_t carries both directions'
-    transposed weights stacked on the column axis [3H, 2H]."""
+                    dz_ref, dh0_ref, dh_scr, *, hidden: int):
+    """Reverse GRU step — mirrors rnn_fused._gru_seq_bwd.rev_step (f32)."""
     from jax.experimental import pallas as pl
 
     t = pl.program_id(0)
@@ -679,12 +673,6 @@ def _gru_bwd_kernel(dout_ref, m_ref, z_ref, hp_ref, wt_ref, dhfin_ref,
     w_t = wt_ref[...]
 
     def rtdot(v, lo, hi):
-        if batch_split:
-            return jnp.concatenate([
-                jnp.dot(v[:batch_split], w_t[lo:hi, :H],
-                        preferred_element_type=jnp.float32),
-                jnp.dot(v[batch_split:], w_t[lo:hi, H:],
-                        preferred_element_type=jnp.float32)], 0)
         return jnp.dot(v, w_t[lo:hi, :], preferred_element_type=jnp.float32)
 
     d_rh = rtdot(d_zc, 2 * H, 3 * H)
@@ -700,18 +688,15 @@ def _gru_bwd_kernel(dout_ref, m_ref, z_ref, hp_ref, wt_ref, dhfin_ref,
         dh0_ref[...] = dh_scr[...]
 
 
-def _gru_bwd_pallas_raw(dout_tb, m_tb, z_tb, hp_tb, w_t, d_hfin, *,
-                        batch_split: int = 0):
-    """TIME-MAJOR twin of _lstm_bwd_pallas_raw for the GRU.
-    ``batch_split``: bidirectional batch, w_t stacked [3H, 2H]."""
+def _gru_bwd_pallas_raw(dout_tb, m_tb, z_tb, hp_tb, w_t, d_hfin):
+    """TIME-MAJOR twin of _lstm_bwd_pallas_raw for the GRU; w_t: [3H,H]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     T, B, H3 = z_tb.shape
     H = H3 // 3
     rev = lambda t: (T - 1 - t, 0, 0)
-    kernel = functools.partial(_gru_bwd_kernel, hidden=H,
-                               batch_split=batch_split)
+    kernel = functools.partial(_gru_bwd_kernel, hidden=H)
     return pl.pallas_call(
         kernel,
         name="gru_seq_bwd",
@@ -721,7 +706,7 @@ def _gru_bwd_pallas_raw(dout_tb, m_tb, z_tb, hp_tb, w_t, d_hfin, *,
             pl.BlockSpec((1, B, 1), rev),
             pl.BlockSpec((1, B, H3), rev),
             pl.BlockSpec((1, B, H), rev),
-            pl.BlockSpec((H3, w_t.shape[1]), lambda t: (0, 0)),
+            pl.BlockSpec((H3, H), lambda t: (0, 0)),
             pl.BlockSpec((B, H), lambda t: (0, 0)),
         ],
         out_specs=[

@@ -40,39 +40,6 @@ __all__ = [
 ]
 
 
-def _use_pallas_rnn(batch, hidden, gates, *, backward=False) -> bool:
-    """Fused Pallas time-loop kernels run on TPU for the default-activation
-    cell (callers enforce acts; peepholes are supported in-kernel; boot
-    state and reverse ride flip/flag upstream) and only for tile-aligned
-    shapes: the kernels slice gate blocks out of [B, gates*H], so H must
-    fill whole 128-lane tiles and B whole 8-sublane tiles or Mosaic rejects
-    the lowering.  ``gates``: 4 = LSTM, 3 = GRU."""
-    if hidden % 128 != 0 or batch % 8 != 0:
-        return False
-    # the kernel's in-register/VMEM temporaries grow with the [B, gates*H]
-    # step tile and are not part of the estimate below: B*H = 384*512 (the
-    # flagship's encoder) is the largest tile the kernels are compiled at
-    # (tests/test_tpu_compile.py); beyond it the scan path runs
-    if batch * hidden > 384 * 512:
-        return False
-    # what the kernel keeps in VMEM (the resident [H, gates*H] weight grows
-    # with H^2, the per-step blocks with B*H) must fit the scoped limit the
-    # kernels ask for
-    from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
-                                               compiled_kernels,
-                                               rnn_vmem_bytes)
-    from paddle_tpu.ops.rnn_fused import residual_dtype
-
-    need = rnn_vmem_bytes(
-        batch, hidden, gates, backward=backward,
-        residual_itemsize=jnp.dtype(residual_dtype(hidden)).itemsize)
-    if need > RNN_VMEM_LIMIT_BYTES:
-        return False
-    from paddle_tpu.utils.flags import FLAGS
-
-    return FLAGS.use_pallas_rnn and compiled_kernels()
-
-
 def lstm_step(xp, h, c, w_h, *, peep_i=None, peep_f=None, peep_o=None,
               act="tanh", gate_act="sigmoid", state_act="tanh"):
     """One LSTM step. xp: [B, 4H] precomputed input projection (+bias),
@@ -269,29 +236,11 @@ def gru_layer(x, mask, w_x, w_h, b, *, h0=None, reverse=False,
 def bigru_layer(x, mask, wx_fw, wh_fw, b_fw, wx_bw, wh_bw, b_bw):
     """Bidirectional GRU over a padded batch — the encoder composition of
     the seq2seq flagship (reference: seqToseq_net.py's forward + backward
-    grumemory pair) as ONE sequential time loop when the fused Pallas
-    kernel is available (see rnn_fused.bigru_sequence_fused), else two
-    ``gru_layer`` calls.
+    grumemory pair): a forward and a reversed ``gru_layer`` over the same
+    input, each choosing its own kernel.
 
     Returns (h_fw [B,T,H], h_bw [B,T,H], h_bw_final [B,H]).
     """
-    from paddle_tpu.ops.rnn_fused import (_use_pallas_bigru,
-                                          bigru_sequence_fused)
-
-    B, T, _ = x.shape
-    H = wh_fw.shape[0]
-    if not _use_pallas_bigru(B, H):
-        h_fw, _ = gru_layer(x, mask, wx_fw, wh_fw, b_fw)
-        h_bw, h_bw_fin = gru_layer(x, mask, wx_bw, wh_bw, b_bw, reverse=True)
-        return h_fw, h_bw, h_bw_fin
-    xp_fw = linear(x, wx_fw, b_fw)
-    xp_bw = linear(x, wx_bw, b_bw)
-    # flip the backward direction whole: padding moves to the FRONT where
-    # the zero carry holds through masked steps (scan_rnn semantics), so a
-    # forward pass over the flip IS the reverse GRU; outputs flip back
-    xp2 = jnp.concatenate([xp_fw, jnp.flip(xp_bw, 1)], 0)
-    mask2 = jnp.concatenate([mask, jnp.flip(mask, 1)], 0)
-    h2, h_fin2 = bigru_sequence_fused(xp2, mask2, wh_fw, wh_bw, B)
-    h_fw = h2[:B]
-    h_bw = jnp.flip(h2[B:], 1)
-    return h_fw, h_bw, h_fin2[B:]
+    h_fw, _ = gru_layer(x, mask, wx_fw, wh_fw, b_fw)
+    h_bw, h_bw_fin = gru_layer(x, mask, wx_bw, wh_bw, b_bw, reverse=True)
+    return h_fw, h_bw, h_bw_fin

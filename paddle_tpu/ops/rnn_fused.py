@@ -47,8 +47,7 @@ from jax import lax
 
 from paddle_tpu.ops.matmul import linear
 
-__all__ = ["gru_sequence_fused", "lstm_sequence_fused",
-           "bigru_sequence_fused"]
+__all__ = ["gru_sequence_fused", "lstm_sequence_fused", "rnn_kernel_ok"]
 
 
 def residual_dtype(hidden: int):
@@ -70,14 +69,38 @@ from paddle_tpu.ops.numerics import bwd_einsum as _bwd_einsum  # noqa: E402
 from paddle_tpu.ops.numerics import bwd_mm as _bwd_mm  # noqa: E402
 
 
-def _bwd_pallas_ok(batch: int, hidden: int, gates: int) -> bool:
-    """Backward Pallas gate: the forward's tile constraints, with the
-    reverse kernel's own VMEM working set (z + d_z [B,gates*H] blocks, the
-    transposed weight, the carry scratches — larger than the forward's)
-    held to the same scoped limit."""
-    from paddle_tpu.ops.rnn import _use_pallas_rnn
+def rnn_kernel_ok(batch: int, hidden: int, gates: int, *,
+                  backward: bool = False) -> bool:
+    """The recurrent time-loop kernels' gate, forward and (``backward``)
+    reverse: True for the Pallas kernel, False for the lax.scan path.
+    ``gates``: 4 = LSTM, 3 = GRU.  Needs the TPU backend and tile-aligned
+    shapes: the kernels slice gate blocks out of [B, gates*H], so H must
+    fill whole 128-lane tiles and B whole 8-sublane tiles or Mosaic rejects
+    the lowering.  The callers see to the rest: the default-activation cell
+    and a zero boot state (peepholes are supported in-kernel; reverse rides
+    a flip upstream)."""
+    from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
+                                               compiled_kernels,
+                                               rnn_vmem_bytes)
 
-    return _use_pallas_rnn(batch, hidden, gates, backward=True)
+    if not compiled_kernels():
+        return False
+    if hidden % 128 != 0 or batch % 8 != 0:
+        return False
+    # the kernel's in-register/VMEM temporaries grow with the [B, gates*H]
+    # step tile and are not part of the estimate below: B*H = 384*512 (the
+    # flagship's encoder) is the largest tile the kernels are compiled at
+    # (tests/test_tpu_compile.py); beyond it the scan path runs
+    if batch * hidden > 384 * 512:
+        return False
+    # what the kernel keeps in VMEM (the resident [H, gates*H] weight grows
+    # with H^2, the per-step blocks with B*H; the reverse kernel's z + d_z
+    # blocks make its working set the larger) must fit the scoped limit the
+    # kernels ask for
+    need = rnn_vmem_bytes(
+        batch, hidden, gates, backward=backward,
+        residual_itemsize=jnp.dtype(residual_dtype(hidden)).itemsize)
+    return need <= RNN_VMEM_LIMIT_BYTES
 
 
 # ---------------------------------------------------------------------------
@@ -134,11 +157,9 @@ def gru_sequence_fused(xp, mask, w_h, h0, allow_pallas=False):
 
 def _gru_core_fwd(xp, mask, w_h, h0, allow_pallas, *, residuals=True):
     if allow_pallas:
-        from paddle_tpu.ops.rnn import _use_pallas_rnn
-
         B, T, H3 = xp.shape
         H = H3 // 3
-        if _use_pallas_rnn(B, H, 3):
+        if rnn_kernel_ok(B, H, 3):
             from paddle_tpu.ops.pallas_kernels import _gru_pallas_raw
 
             xp_tb = jnp.moveaxis(xp.astype(jnp.float32), 1, 0)
@@ -171,7 +192,7 @@ def _gru_seq_bwd(allow_pallas, res, ct):
     w_f = w_h.astype(f32)
 
     hp_f = hprev_r.astype(f32)                   # residuals are [T,B,*]
-    if allow_pallas and _bwd_pallas_ok(B, H, 3):
+    if allow_pallas and rnn_kernel_ok(B, H, 3, backward=True):
         from paddle_tpu.ops.pallas_kernels import _gru_bwd_pallas_raw
 
         # residual streams enter the kernel in their STORED dtype (bf16
@@ -289,11 +310,9 @@ def _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
     # it into the projection's output
     xp = xp + b.astype(xp.dtype)
     if allow_pallas:
-        from paddle_tpu.ops.rnn import _use_pallas_rnn
-
         B, T, H4 = xp.shape
         H = H4 // 4
-        if _use_pallas_rnn(B, H, 4):
+        if rnn_kernel_ok(B, H, 4):
             from paddle_tpu.ops.pallas_kernels import _lstm_pallas_raw
 
             xp_tb = jnp.moveaxis(xp.astype(jnp.float32), 1, 0)
@@ -335,7 +354,7 @@ def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
     pi_f, pf_f, po_f = (p.astype(f32) for p in (pi, pf, po))
     d_peep = None
 
-    if allow_pallas and _bwd_pallas_ok(B, H, 4):
+    if allow_pallas and rnn_kernel_ok(B, H, 4, backward=True):
         from paddle_tpu.ops.pallas_kernels import _lstm_bwd_pallas_raw
 
         # residual streams enter in their STORED dtype (see GRU twin); the
@@ -410,124 +429,3 @@ def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
 
 
 lstm_sequence_fused.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Bidirectional GRU: BOTH directions in one sequential time loop.
-#
-# A bidirectional encoder is two INDEPENDENT scans over the same T steps —
-# run separately they serialize (one TPU core runs one kernel at a time),
-# paying the per-step launch/latency floor twice.  Here the batch carries
-# both directions ([fw; time-flipped bw] rows) through ONE Pallas time
-# loop whose per-step recurrent matmuls split the rows across the two
-# directions' weights (pallas_kernels._gru_kernel batch_split) — half the
-# sequential steps for the same FLOPs.  The flip trick is exact for
-# right-padded sequences: flipping moves padding to the FRONT, where the
-# masked steps hold the zero initial carry (scan_rnn semantics), then the
-# real tokens arrive reversed; flipping the outputs back restores the
-# reverse-GRU layout, and the final carry IS the reverse direction's final
-# state.
-# ---------------------------------------------------------------------------
-
-
-def _use_pallas_bigru(batch: int, hidden: int) -> bool:
-    """Gate for the fused bidirectional kernel: the working set is the
-    2B-row batch against both directions' weights.
-
-    DEFAULT OFF (FLAGS.use_pallas_bigru): A/B-measured a TIE at the WMT14
-    encoder shape on v5e (full train step 21.14 ms fused vs 21.04/21.24 ms
-    two-scan, same process) — halving the sequential step count is offset
-    by the doubled per-step latency chain (two row-half dots + concat).
-    Kept as a recorded neutral A/B with its equivalence tests; flip the
-    flag to re-test on other hardware/shapes."""
-    from paddle_tpu.utils.flags import FLAGS
-
-    if not FLAGS.use_pallas_bigru:
-        return False
-    if not FLAGS.use_pallas_rnn:
-        return False
-    from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
-                                               compiled_kernels,
-                                               rnn_vmem_bytes)
-
-    if not compiled_kernels():
-        return False
-    if hidden % 128 != 0 or (2 * batch) % 8 != 0:
-        return False
-    # as in ops/rnn.py's gate, the kernel's temporaries grow with the step
-    # tile and are not in the estimate below: 2B*H = 1024*512 is the largest
-    # tile the kernels are compiled at in both dtype policies
-    # (tests/test_tpu_compile.py; B768 H512 is refused under float32)
-    if 2 * batch * hidden > 1024 * 512:
-        return False
-
-    # the 2B-row batch against both directions' stacked weights; the
-    # reverse kernel is the larger of the two
-    need = rnn_vmem_bytes(
-        2 * batch, hidden, 3, backward=True, directions=2,
-        residual_itemsize=jnp.dtype(residual_dtype(hidden)).itemsize)
-    return need <= RNN_VMEM_LIMIT_BYTES
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def bigru_sequence_fused(xp2, mask2, w_fw, w_bw, batch: int = 0):
-    """Fused bidirectional GRU core: xp2 [2B,T,3H] carries the forward
-    rows then the TIME-FLIPPED backward rows (mask2 likewise), w_fw/w_bw
-    are the per-direction recurrent weights.  Returns (h_seq2 [2B,T,H],
-    h_fin2 [2B,H]) in the same stacked layout (caller un-flips the second
-    half).  Callers must gate on ``_use_pallas_bigru`` — this core always
-    takes the Pallas kernels (interpret mode off-TPU)."""
-    h_seq2, h_fin2 = _bigru_fwd(xp2, mask2, w_fw, w_bw, batch)[0]
-    return h_seq2, h_fin2
-
-
-def _bigru_fwd(xp2, mask2, w_fw, w_bw, batch):
-    from paddle_tpu.ops.pallas_kernels import _gru_pallas_raw
-
-    f32 = jnp.float32
-    w2 = jnp.concatenate([w_fw, w_bw], 0).astype(f32)    # [2H, 3H]
-    xp_tb = jnp.moveaxis(xp2.astype(f32), 1, 0)
-    m_tb = jnp.moveaxis(mask2.astype(f32), 1, 0)
-    h_tb, h_fin, z_r, hprev_r = _gru_pallas_raw(
-        xp_tb, m_tb, w2, residuals=True, batch_split=batch)
-    out = (jnp.moveaxis(h_tb, 0, 1), h_fin)
-    meta = (jnp.zeros((0,), xp2.dtype),)
-    return out, (mask2, w_fw, w_bw, z_r, hprev_r, meta)
-
-
-def _bigru_bwd(batch, res, ct):
-    from paddle_tpu.ops.pallas_kernels import _gru_bwd_pallas_raw
-
-    mask2, w_fw, w_bw, z_r, hprev_r, (xp_s,) = res
-    d_hseq, d_hfin = ct
-    H = w_fw.shape[0]
-    f32 = jnp.float32
-    # transposed weights stacked on COLUMNS [3H, 2H] (fw cols then bw)
-    w_t = jnp.concatenate([w_fw.astype(f32).T, w_bw.astype(f32).T], 1).copy()
-    d_xp_tb, d_h02 = _gru_bwd_pallas_raw(
-        jnp.moveaxis(d_hseq, 1, 0).astype(f32),
-        jnp.moveaxis(mask2, 1, 0).astype(f32),
-        z_r, hprev_r, w_t, d_hfin.astype(f32), batch_split=batch)
-    # per-direction weight grads: one batched contraction over each half's
-    # rows (residuals are time-major [T, 2B, *])
-    hp_f = hprev_r.astype(f32)
-    rh = jax.nn.sigmoid(z_r[..., :H].astype(f32)) * hp_f
-
-    def d_w(rows):
-        gates = jnp.einsum("tbh,tbz->hz", hp_f[:, rows],
-                           d_xp_tb[:, rows, : 2 * H])
-        cand = jnp.einsum("tbh,tbz->hz", rh[:, rows],
-                          d_xp_tb[:, rows, 2 * H:])
-        return jnp.concatenate([gates, cand], axis=1)
-
-    fw_rows = slice(0, batch)
-    bw_rows = slice(batch, None)
-    d_xp = jnp.moveaxis(d_xp_tb, 0, 1).astype(xp_s.dtype)
-    return (d_xp, None,
-            d_w(fw_rows).astype(w_fw.dtype), d_w(bw_rows).astype(w_bw.dtype))
-
-
-bigru_sequence_fused.defvjp(
-    lambda xp2, mask2, w_fw, w_bw, batch: _bigru_fwd(
-        xp2, mask2, w_fw, w_bw, batch),
-    _bigru_bwd)

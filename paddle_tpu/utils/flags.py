@@ -460,33 +460,8 @@ define_flag("pserver_pad_vocab", True, "pad table vocabs up to a shard "
 define_flag("beam_size", 3, "default beam width for sequence generation")
 define_flag("max_gen_length", 100, "max generated sequence length")
 
-# Kernel selection
-# Decided by the END-TO-END seqToseq A/B on v5e (paired, alternating order,
-# same process): pallas on = 15.4-17.6 ms/batch, off = 17.3-19.2 — the fused
-# kernel wins or ties every pairing, so it stays default-on.  The micro
-# LSTM-only A/B (bench_pallas_lstm_ab, B=64,T=100,H=256) was NOISY in the
-# captures (winner flips between runs: 0.470-vs-0.498 round 1,
-# 0.494-vs-0.194 round 2, 0.393-vs-0.560 re-run) — treat the pallas_lstm_ab
-# row in BENCH_r*.json as informational; the seq2seq headline is decisive.
-# (All of these predate PR 1 and were taken on another installation.)
-# Gate: ops/rnn.py:_use_pallas_rnn; non-tile-aligned shapes always use scan.
-define_flag("use_pallas_rnn", True, "use fused Pallas LSTM/GRU time-loop kernels on TPU")
-# Gate: ops/attention_decoder.py:_attn_pallas_block (VMEM-resident decoder)
-define_flag("use_pallas_attention", True,
-            "use the VMEM-resident Pallas attention-decoder kernels on TPU")
-# Gate: ops/losses.py:_tiled_ce_cfg (vocab-tiled fused readout+CE)
-define_flag("use_pallas_ce", True,
-            "use the vocab-tiled Pallas softmax-CE readout kernels on TPU")
-# Gate: ops/rnn_fused.py:_use_pallas_bigru — A/B-measured a TIE on v5e at
-# the WMT14 encoder shape, kept off (see the gate's docstring)
-define_flag("use_pallas_bigru", False,
-            "fuse bidirectional GRU pairs into one Pallas time loop")
-# Gate: ops/decode.py:decode_kernel_config (vocab-tiled top-k+logsumexp
-# readout inside the fused decode engine; docs/decode.md).  A/B row:
-# pallas_decode_ab in bench.py.
-define_flag("use_pallas_decode", True,
-            "use the vocab-tiled Pallas top-k/logsumexp readout kernel in "
-            "the decode engine on TPU")
+# Kernel selection is not a flag: each kernel family's gate (listed at the
+# top of ops/pallas_kernels.py) decides from the backend and the shape.
 define_flag("decode_early_exit", True,
             "beam/greedy decode exits its token loop once every beam has "
             "emitted EOS (lax.while_loop); off = fixed-max_len lax.scan "

@@ -180,9 +180,6 @@ def test_gate_rejects_cpu_and_misaligned_shapes(monkeypatch):
     """The production gate must route CPU backends and non-tile-aligned
     shapes to the XLA scan path (returning None), never to a kernel that
     cannot lower."""
-    from paddle_tpu.utils.flags import FLAGS
-
-    monkeypatch.setattr(FLAGS, "use_pallas_attention", True)
     if jax.default_backend() != "tpu":
         assert ad._attn_pallas_block(384, 32, 512, 512, 1024) is None
     # force the backend probe open so the alignment branches execute on
@@ -194,19 +191,3 @@ def test_gate_rejects_cpu_and_misaligned_shapes(monkeypatch):
     assert ad._attn_pallas_block(384, 30, 512, 512, 1024) is None
     # a batch with no sublane-aligned divisor
     assert ad._attn_pallas_block(7, 32, 512, 512, 1024) is None
-    monkeypatch.setattr(FLAGS, "use_pallas_attention", False)
-    assert ad._attn_pallas_block(384, 32, 512, 512, 1024) is None
-
-
-def test_flag_off_matches_flag_on(monkeypatch):
-    """Flipping use_pallas_attention must not change results (CPU: both
-    sides take the scan; the on-device equivalence is pinned by the
-    A/B-verified kernels + test_aligned_shapes_real_lowering)."""
-    from paddle_tpu.utils.flags import FLAGS
-
-    vals = [make_args()[k] for k in ORDER]
-    monkeypatch.setattr(FLAGS, "use_pallas_attention", False)
-    off = np.asarray(attention_gru_decoder(*vals))
-    monkeypatch.setattr(FLAGS, "use_pallas_attention", True)
-    on = np.asarray(attention_gru_decoder(*vals))
-    np.testing.assert_allclose(off, on, **_tols())

@@ -74,15 +74,7 @@ def test_gate_rejects_cpu_and_bad_shapes():
     if _jax.default_backend() != "tpu":
         assert L._tiled_ce_cfg(4, 8, 128, 300) is None  # CPU backend
     # lane-misaligned D can never tile
-    from paddle_tpu.utils.flags import FLAGS
-
-    old = FLAGS.use_pallas_ce
-    try:
-        FLAGS.use_pallas_ce = True
-        assert L._tiled_ce_cfg(4, 8, 100, 300) is None or \
-            _jax.default_backend() != "tpu"
-    finally:
-        FLAGS.use_pallas_ce = old
+    assert L._tiled_ce_cfg(4, 8, 100, 300) is None
 
 
 def test_lse_readout_falls_back_below_sublane(monkeypatch, rng):

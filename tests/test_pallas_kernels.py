@@ -11,6 +11,8 @@ import numpy as np
 import pytest
 
 import paddle_tpu.ops as O
+from paddle_tpu.ops import (attention_decoder, decode, decoder_block, losses,
+                            moe, rnn_fused)
 from paddle_tpu.ops.pallas_kernels import (
     _gru_reference,
     _lstm_reference,
@@ -18,6 +20,7 @@ from paddle_tpu.ops.pallas_kernels import (
     lstm_forward_pallas,
     pallas_available,
 )
+from test_rnn_fused import _backward_kernel
 
 pytestmark = pytest.mark.skipif(not pallas_available(), reason="pallas unavailable")
 
@@ -147,11 +150,9 @@ class TestBackwardKernels:
 
         # reference: identical function with the scan backward (gate off)
         args = (xp, w_h, b, pi, pf, po)
-        monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H, gates: False)
+        _backward_kernel(monkeypatch, False)
         g_ref = jax.grad(obj(lstm_sequence_fused), tuple(range(6)))(*args)
-        monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H, gates: True)
+        _backward_kernel(monkeypatch, True)
         g_pal = jax.grad(obj(lstm_sequence_fused), tuple(range(6)))(*args)
         for a, b in zip(g_ref, g_pal):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -171,11 +172,9 @@ class TestBackwardKernels:
                 return (h_seq * ct_seq).sum() + (h_f * ct_h).sum()
             return f
 
-        monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H, gates: False)
+        _backward_kernel(monkeypatch, False)
         g_ref = jax.grad(obj(), (0, 1))(xp, w_h)
-        monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                            lambda B, H, gates: True)
+        _backward_kernel(monkeypatch, True)
         g_pal = jax.grad(obj(), (0, 1))(xp, w_h)
         for a, b in zip(g_ref, g_pal):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -223,3 +222,50 @@ class TestFusedCEReadout:
         for name, a, c in zip(("states", "w", "b"), g_ref, g_new):
             np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                        rtol=rtol, atol=atol, err_msg=name)
+
+
+def _rnn_gate(backward):
+    # the flagship's encoder GRU and the LSTM cell's layers
+    return lambda: (
+        rnn_fused.rnn_kernel_ok(384, 512, 3, backward=backward)
+        and rnn_fused.rnn_kernel_ok(256, 512, 4, backward=backward))
+
+
+@pytest.mark.parametrize("gate,opens_with", [
+    pytest.param(_rnn_gate(False), True, id="rnn_forward"),
+    pytest.param(_rnn_gate(True), True, id="rnn_reverse"),
+    pytest.param(lambda: attention_decoder._attn_pallas_block(
+        384, 96, 512, 512, 1024), 32, id="attention_decoder"),
+    pytest.param(lambda: losses._tiled_ce_cfg(384, 32, 512, 30000),
+                 (2048, 512), id="tiled_ce"),             # N = 12288 rows
+    # no cell generates: the flagship's beam-3 rows, 384 x 3
+    pytest.param(lambda: decode.decode_kernel_config(1152, 512, 30000, 3),
+                 (128, 512), id="topk_readout"),
+    pytest.param(lambda: decoder_block.attention_kernel_blocks(
+        8192, 64, 32, 8), (1024, 1024), id="flash_attention"),
+    pytest.param(lambda: moe.moe_kernel_row_tile(2048, 1536, 8192 * 4),
+                 256, id="grouped_products"),
+])
+def test_gate_follows_backend_and_mesh(monkeypatch, gate, opens_with):
+    """Every kernel family's gate, at the shape its benchmark cell runs: the
+    backend and the shape decide.  Closed on the CPU; open on the ``"tpu"``
+    backend; closed again while a step that jit partitions over a mesh is
+    traced (``xla_paths_only``), also as a decorator, and open after it."""
+    from paddle_tpu.ops import pallas_kernels as pk
+    from paddle_tpu.utils.flags import FLAGS
+
+    # the cells run the production policy, and the VMEM estimates of the
+    # RNN and CE gates count its operand widths
+    monkeypatch.setattr(FLAGS, "compute_dtype", "bfloat16")
+    if jax.default_backend() != "tpu":
+        assert not gate()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pk, "_warned_kernels_off", True)   # keep it quiet
+    assert gate() == opens_with
+    with pk.xla_paths_only():
+        assert not gate()
+        with pk.xla_paths_only():                          # nests
+            assert not gate()
+        assert not gate()
+    assert pk.xla_paths_only()(gate)() in (None, False)
+    assert gate() == opens_with

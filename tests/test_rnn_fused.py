@@ -58,50 +58,65 @@ class TestGruFused:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5, err_msg=name)
 
-    def test_gru_layer_reverse_matches_scan_reference(self):
-        """gru_layer's flip-routed reverse == scan_rnn(reverse=True)."""
+    @pytest.mark.parametrize("layer,lens", [
+        ("gru_reverse", (6, 4, 2)), ("bigru", (6, 4, 2)),
+        ("bigru", (6, 3, 5, 1))],
+        ids=["gru_reverse", "bigru_ragged3", "bigru_ragged4"])
+    def test_layers_match_scan_reference(self, layer, lens):
+        """gru_layer's flip-routed reverse == scan_rnn(reverse=True), and
+        bigru_layer == a forward and a reversed scan over the same input,
+        over ragged masks: values, final state and every gradient."""
         rs = np.random.RandomState(1)
-        B, T, D, H = 3, 6, 5, 4
+        B, T, D, H = len(lens), 6, 5, 4
         x = jnp.asarray(rs.randn(B, T, D).astype(np.float32))
-        mask = _mask((6, 4, 2), T)
-        wx = jnp.asarray(0.4 * rs.randn(D, 3 * H).astype(np.float32))
-        wh = jnp.asarray(0.4 * rs.randn(H, 3 * H).astype(np.float32))
-        b = jnp.asarray(0.1 * rs.randn(3 * H).astype(np.float32))
+        mask = _mask(lens, T)
+        r = lambda *shape, scale=0.4: jnp.asarray(  # noqa: E731
+            scale * rs.randn(*shape).astype(np.float32))
+        # (wx, wh, b) of the reversed direction, then of the forward one
+        bw = (r(D, 3 * H), r(H, 3 * H), r(3 * H, scale=0.1))
+        fw = (r(D, 3 * H), r(H, 3 * H), r(3 * H, scale=0.1))
+        ct = r(B, T, H, scale=1.0)
 
-        h_seq, h_fin = O.gru_layer(x, mask, wx, wh, b, reverse=True)
-
-        xp = O.linear(x, wx, b)
-        def step(h, xp_t):
-            h2 = O.gru_step(xp_t, h, wh)
-            return h2, h2
-        rf, rseq = O.scan_rnn(step, jnp.zeros((B, H)), xp, mask, reverse=True)
-        np.testing.assert_allclose(np.asarray(rseq), np.asarray(h_seq),
-                                   rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(rf), np.asarray(h_fin),
-                                   rtol=1e-5, atol=1e-6)
-
-        # grads through the layer stay finite and match the scan reference
-        def loss_layer(wx, wh):
-            s, f = O.gru_layer(x, mask, wx, wh, b, reverse=True)
-            return jnp.sum(s ** 2) + jnp.sum(f ** 2)
-
-        def loss_ref(wx, wh):
-            xp = O.linear(x, wx, b)
-            f, s = O.scan_rnn(step_w(wh), jnp.zeros((B, H)), xp, mask,
-                              reverse=True)
-            return jnp.sum(s ** 2) + jnp.sum(f ** 2)
-
-        def step_w(wh):
+        def scan(x, wx, wh, b, reverse):
             def step(h, xp_t):
                 h2 = O.gru_step(xp_t, h, wh)
                 return h2, h2
-            return step
+            fin, seq = O.scan_rnn(step, jnp.zeros((B, H)), O.linear(x, wx, b),
+                                  mask, reverse=reverse)
+            return seq, fin
 
-        ga = jax.grad(loss_layer, argnums=(0, 1))(wx, wh)
-        gb = jax.grad(loss_ref, argnums=(0, 1))(wx, wh)
-        for name, a, b2 in zip(("wx", "wh"), ga, gb):
+        if layer == "gru_reverse":
+            def new(x, bw, fw):
+                return O.gru_layer(x, mask, *bw, reverse=True)
+
+            def ref(x, bw, fw):
+                return scan(x, *bw, True)
+        else:
+            def new(x, bw, fw):
+                return O.bigru_layer(x, mask, *fw, *bw)
+
+            def ref(x, bw, fw):
+                return (scan(x, *fw, False)[0],) + scan(x, *bw, True)
+
+        got, want = new(x, bw, fw), ref(x, bw, fw)
+        assert len(got) == len(want)      # (h_fw,) h_bw, h_bw_final
+        for a, b2 in zip(got, want):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b2),
-                                       rtol=1e-4, atol=1e-5, err_msg=name)
+                                       rtol=1e-5, atol=1e-6)
+
+        def loss(fn):
+            def f(x, bw, fw):
+                *seqs, fin = fn(x, bw, fw)
+                return (sum(jnp.sum(s * ct * (i + 1.0))
+                            for i, s in enumerate(seqs)) + jnp.sum(fin ** 2))
+            return f
+
+        ga = jax.grad(loss(new), argnums=(0, 1, 2))(x, bw, fw)
+        gb = jax.grad(loss(ref), argnums=(0, 1, 2))(x, bw, fw)
+        leaves = jax.tree_util.tree_leaves
+        for a, b2 in zip(leaves(ga), leaves(gb)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b2),
+                                       rtol=1e-4, atol=1e-5)
 
 
 class TestLstmFused:
@@ -193,8 +208,8 @@ class TestLstmFusedPeepholes:
 def _backward_kernel(monkeypatch, on):
     """Which reverse loop lstm_sequence_fused(..., allow_pallas=True) takes:
     the Pallas kernel (interpret mode here) or the lax.scan."""
-    monkeypatch.setattr("paddle_tpu.ops.rnn_fused._bwd_pallas_ok",
-                        lambda B, H, gates: on)
+    monkeypatch.setattr("paddle_tpu.ops.rnn_fused.rnn_kernel_ok",
+                        lambda B, H, gates, backward=False: backward and on)
 
 
 class TestLstmKernelBackward:

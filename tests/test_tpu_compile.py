@@ -88,11 +88,11 @@ def test_rnn_forward_and_gradient(cell, batch, hidden, one_chip, on_tpu):
     """Every published row: both gates admit it, and the forward and the
     reverse time-loop kernels compile (peepholes live — the widest LSTM
     variant)."""
-    from paddle_tpu.ops import rnn, rnn_fused
+    from paddle_tpu.ops import rnn_fused
 
     gates = 4 if cell == "lstm" else 3
-    assert rnn._use_pallas_rnn(batch, hidden, gates)
-    assert rnn_fused._bwd_pallas_ok(batch, hidden, gates)
+    assert rnn_fused.rnn_kernel_ok(batch, hidden, gates)
+    assert rnn_fused.rnn_kernel_ok(batch, hidden, gates, backward=True)
     zeros = jnp.zeros((batch, hidden), jnp.float32)
 
     def lstm_loss(xp, mask, w_h, pi, pf, po):
@@ -123,18 +123,18 @@ def test_rnn_gate_bounds_the_resident_weight(on_tpu):
     LSTM reverse kernel at B64 H1280, peepholes live (33.45 MiB when asked
     to fit it into less: the ``c_new`` stream it used to write is gone, the
     bias and peephole accumulators are in)."""
-    from paddle_tpu.ops import rnn
     from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
                                                rnn_vmem_bytes)
+    from paddle_tpu.ops.rnn_fused import rnn_kernel_ok
 
     need = rnn_vmem_bytes(64, 1280, 4, backward=True, residual_itemsize=4)
     assert abs(need / 2**20 - 33.45) < 0.33
     assert need < RNN_VMEM_LIMIT_BYTES
-    assert rnn._use_pallas_rnn(72, 1792, 4, backward=True)
-    assert not rnn._use_pallas_rnn(8, 2048, 4)            # 64 MiB of weight
-    assert rnn._use_pallas_rnn(96, 2048, 3, backward=True)
-    assert not rnn._use_pallas_rnn(392, 512, 3)           # past B*H cap
-    assert not rnn._use_pallas_rnn(64, 200, 4)            # lane-misaligned
+    assert rnn_kernel_ok(72, 1792, 4, backward=True)
+    assert not rnn_kernel_ok(8, 2048, 4)                  # 64 MiB of weight
+    assert rnn_kernel_ok(96, 2048, 3, backward=True)
+    assert not rnn_kernel_ok(392, 512, 3)                 # past B*H cap
+    assert not rnn_kernel_ok(64, 200, 4)                  # lane-misaligned
 
 
 @pytest.mark.parametrize("batch,hidden,cell", [
@@ -143,11 +143,12 @@ def test_rnn_gate_bounds_the_resident_weight(on_tpu):
 def test_rnn_gate_edge_compiles(batch, hidden, cell, one_chip, on_tpu):
     """The largest shapes the reverse-kernel gate admits at a few widths:
     every shape a gate admits must compile, not only the published ones."""
-    from paddle_tpu.ops import rnn, rnn_fused
+    from paddle_tpu.ops import rnn_fused
 
     gates = 4 if cell == "lstm" else 3
-    assert rnn_fused._bwd_pallas_ok(batch, hidden, gates)
-    assert not rnn._use_pallas_rnn(batch + 8, hidden, gates, backward=True)
+    assert rnn_fused.rnn_kernel_ok(batch, hidden, gates, backward=True)
+    assert not rnn_fused.rnn_kernel_ok(batch + 8, hidden, gates,
+                                       backward=True)
     zeros = jnp.zeros((batch, hidden), jnp.float32)
     s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
 
@@ -178,7 +179,7 @@ def test_lstm_reverse_kernel_reduces_bias_and_peepholes(batch, peepholes,
     from paddle_tpu.ops import pallas_kernels, rnn_fused
 
     hidden, steps = 512, 20
-    assert rnn_fused._bwd_pallas_ok(batch, hidden, 4)
+    assert rnn_fused.rnn_kernel_ok(batch, hidden, 4, backward=True)
     s = lambda *shape, dt=jnp.float32: _struct(one_chip, shape, dt)  # noqa: E731
     rd = rnn_fused.residual_dtype(hidden)
     assert rd == jnp.bfloat16
@@ -198,33 +199,6 @@ def test_lstm_reverse_kernel_reduces_bias_and_peepholes(batch, peepholes,
     assert f"f32[1,{4 * hidden}]" in results
     assert (f"f32[3,{hidden}]" in results) == peepholes
     assert f"f32[{steps},{batch},{hidden}]" not in results
-
-
-@pytest.mark.parametrize("policy,batch,hidden", [
-    ("bfloat16", 512, 512), ("float32", 512, 512), ("float32", 256, 1024),
-    ("float32", 140, 1280)])
-def test_bigru_gate_edge_compiles(policy, batch, hidden, one_chip, on_tpu,
-                                  monkeypatch):
-    """The fused bidirectional GRU (--use_pallas_bigru, off by default): the
-    largest 2B-row batch its gate admits compiles, forward and reverse, in
-    both dtype policies, and the next step up is refused (B768 H512 is
-    what the float32 policy's reverse kernel does not fit)."""
-    from paddle_tpu.ops import rnn_fused
-    from paddle_tpu.utils.flags import FLAGS
-
-    monkeypatch.setattr(FLAGS, "compute_dtype", policy)
-    monkeypatch.setattr(FLAGS, "use_pallas_bigru", True)
-    assert rnn_fused._use_pallas_bigru(batch, hidden)
-    assert not rnn_fused._use_pallas_bigru(batch + 4, hidden)
-    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
-
-    def loss(xp2, mask2, w_fw, w_bw):
-        h, h_f = rnn_fused.bigru_sequence_fused(xp2, mask2, w_fw, w_bw, batch)
-        return h.sum() + h_f.sum()
-
-    args = [s(2 * batch, 20, 3 * hidden), s(2 * batch, 20),
-            s(hidden, 3 * hidden), s(hidden, 3 * hidden)]
-    assert _kernels(jax.value_and_grad(loss, argnums=(0, 2, 3)), *args) == 2
 
 
 def test_attention_decoder_forward_and_backward(one_chip, on_tpu):

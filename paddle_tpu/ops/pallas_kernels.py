@@ -133,9 +133,10 @@ def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
     double-buffered, finals/seeds and the carry scratches are ``[B, H]``
     f32.  ``gates``: 4 = LSTM (two carries), 3 = GRU (one).  Counted with
     the widest variant (training residuals; the LSTM reverse kernel's
-    peephole accumulators).  Agrees to within 1% with what the v5e compiler
-    of the installed libtpu reports when it refuses a kernel (jax 0.9.0,
-    tests/test_tpu_compile.py holds the gates to it)."""
+    peephole accumulators and its ``d_z`` in f32, which is half that where
+    the op owns the input projection).  Agrees to within 1% with what the
+    v5e compiler of the installed libtpu reports when it refuses a kernel
+    (jax 0.9.0, tests/test_tpu_compile.py holds the gates to it)."""
     carries = 2 if gates == 4 else 1
     rs = residual_itemsize
     weight = 4 * gates * hidden * hidden
@@ -556,7 +557,9 @@ def _lstm_bwd_kernel(dout_ref, m_ref, z_ref, cp_ref, wt_ref, pi_ref,
     dh_scr[...] = (1.0 - mcol) * d_h + d_hp
     dc_scr[...] = ((1.0 - mcol) * d_c + d_cnew * f
                    + d_zi * pi + d_zf * pf)
-    dz_ref[0] = d_z
+    # the only rounding of d_z: the HBM copy takes the block's dtype; the
+    # carry product above and the accumulators below keep the float32 value
+    dz_ref[0] = d_z.astype(dz_ref.dtype)
 
     def sublane_partial(x):
         # [B, n] -> [8, n]: the B/8 sublane tiles added together, vreg by
@@ -580,13 +583,15 @@ def _lstm_bwd_kernel(dout_ref, m_ref, z_ref, cp_ref, wt_ref, pi_ref,
 
 
 def _lstm_bwd_pallas_raw(dout_tb, m_tb, z_tb, cp_tb, w_t, pi, pf, po,
-                         d_hfin, d_cfin, *, has_peepholes: bool = True):
+                         d_hfin, d_cfin, *, has_peepholes: bool = True,
+                         dz_dtype=jnp.float32):
     """TIME-MAJOR: dout/m/z/cp [T,B,*] f32; w_t: [4H,H] (w_h transposed);
     pi/pf/po: [1,H] peephole rows; d_hfin/d_cfin: [B,H] cotangent seeds
     (loaded into the carry scratch at the last timestep — they propagate
     through masked tails exactly as the scan's initial carry does).
-    Returns (d_z [T,B,4H], d_h0, d_c0, d_b [1,4H] = d_z summed over (t, b),
-    d_peep [3,H] = rows d_pi, d_pf, d_po, or None without peepholes).
+    Returns (d_z [T,B,4H] stored as ``dz_dtype``, d_h0, d_c0, d_b [1,4H] =
+    the float32 d_z summed over (t, b), d_peep [3,H] = rows d_pi, d_pf,
+    d_po, or None without peepholes).
     B must be a multiple of 8 (the gate's tile constraint): the
     accumulators hold one sublane tile per feature column."""
     from jax.experimental import pallas as pl
@@ -604,7 +609,7 @@ def _lstm_bwd_pallas_raw(dout_tb, m_tb, z_tb, cp_tb, w_t, pi, pf, po,
         pl.BlockSpec((1, H4), resident),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((T, B, H4), jnp.float32),
+        jax.ShapeDtypeStruct((T, B, H4), dz_dtype),
         jax.ShapeDtypeStruct((B, H), jnp.float32),
         jax.ShapeDtypeStruct((B, H), jnp.float32),
         jax.ShapeDtypeStruct((1, H4), jnp.float32),

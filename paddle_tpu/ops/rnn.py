@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.ops.matmul import linear, matmul
+from paddle_tpu.ops.numerics import dot_dtype
 from paddle_tpu.ops.activations import get_activation
 
 __all__ = [
@@ -157,27 +158,29 @@ def lstm_layer(x, mask, w_x, w_h, b, *, h0=None, c0=None, reverse=False,
         # the reverse loop; Pallas fwd+bwd kernels when the gate allows —
         # see ops/rnn_fused.py).  reverse rides a flip: identical to
         # scan_rnn(reverse=True) including mask hold/zero semantics.
-        # The op adds the bias itself (to the projection's output, where
-        # linear() would), because its backward owns the bias gradient.
+        # The op makes the projection and adds the bias itself (to the
+        # projection's output, where linear() would), because its backward
+        # owns the bias gradient and the projection's two products.
         from paddle_tpu.ops.rnn_fused import lstm_sequence_fused
 
-        xp = x if w_x is None else linear(x, w_x)
+        xp_dt = x.dtype if w_x is None else dot_dtype()
         allow_pallas = h0 is None and c0 is None
-        h0a = jnp.zeros((B, H), xp.dtype) if h0 is None else h0
-        c0a = jnp.zeros((B, H), xp.dtype) if c0 is None else c0
+        h0a = jnp.zeros((B, H), xp_dt) if h0 is None else h0
+        c0a = jnp.zeros((B, H), xp_dt) if c0 is None else c0
         has_peeps = any(p is not None for p in (peep_i, peep_f, peep_o))
-        zp = jnp.zeros((H,), xp.dtype)
+        zp = jnp.zeros((H,), xp_dt)
         # peepholes join the carry arithmetic: f32 check params would
         # promote the bf16 scan carry under --amp (scan requires a stable
         # carry dtype) — cast at the boundary like every other operand
-        pi = zp if peep_i is None else peep_i.astype(xp.dtype)
-        pf = zp if peep_f is None else peep_f.astype(xp.dtype)
-        po = zp if peep_o is None else peep_o.astype(xp.dtype)
-        xp_r = jnp.flip(xp, 1) if reverse else xp
+        pi = zp if peep_i is None else peep_i.astype(xp_dt)
+        pf = zp if peep_f is None else peep_f.astype(xp_dt)
+        po = zp if peep_o is None else peep_o.astype(xp_dt)
+        x_r = jnp.flip(x, 1) if reverse else x
         m_r = jnp.flip(mask, 1) if reverse else mask
-        h_seq, h_fin, c_fin = lstm_sequence_fused(xp_r, b, m_r, w_h, h0a,
+        h_seq, h_fin, c_fin = lstm_sequence_fused(x_r, b, m_r, w_h, h0a,
                                                   c0a, pi, pf, po,
-                                                  allow_pallas, has_peeps)
+                                                  allow_pallas, has_peeps,
+                                                  w_x)
         if reverse:
             h_seq = jnp.flip(h_seq, 1)
         return h_seq, (h_fin, c_fin)

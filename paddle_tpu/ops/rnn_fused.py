@@ -22,6 +22,23 @@ models).  Three structural changes vs XLA's autodiff of the time scan:
    VMEM anyway; XLA would stream ``d_z`` from HBM once more for each.  That is
    why the op takes the bias.  The lax.scan backward (CPU, boot states, shapes
    past the gate) leaves them to XLA as one batched reduction each.
+4. (LSTM) The op takes the input projection's operands (``x``, ``w_x``)
+   when the caller has them, and then every reader of ``d_z`` outside the
+   reverse loop is a matrix product the op itself writes: ``d_w_h``, and
+   ``dx`` and ``d_w_x``, the transpose of its own ``linear(x, w_x)``.  All
+   three round their operands to the compute dtype, and the float32 readers
+   (the bias's and the peepholes' reductions, the carry product) are inside
+   the loop since point 3, so the reverse kernel then stores ``d_z`` in
+   ``residual_dtype(H)`` like the streams it reads (``z``, ``h_prev``,
+   ``c_prev``): bf16 under the production policy at H <= 512, half the
+   stream that the loop writes once and the products read three times.
+   Rounded once where it is produced, the products get the operand they
+   would have rounded it to.  A caller that passes the projection itself
+   (``w_x=None``: ``lstmemory(projected_input=True)``, whose ``d_xp`` goes on
+   into whatever made ``xp``, a bias's reduction or an activation's backward)
+   gets ``d_xp`` in float32, unrounded, as does the lax.scan backward, where
+   narrowing would be one more pass and save none.  The GRU's ``d_xp`` stays
+   float32: its bias gradient is still an XLA reduction over it.
 
 Semantics match ``scan_rnn`` + ``gru_step``/``lstm_step`` exactly (carry
 held and outputs zeroed at masked steps); equivalence is pinned by
@@ -51,11 +68,12 @@ __all__ = ["gru_sequence_fused", "lstm_sequence_fused", "rnn_kernel_ok"]
 
 
 def residual_dtype(hidden: int):
-    """Dtype of the z/h_prev/c_prev residual streams: bf16 under the prod
-    compute policy for H <= 512 (halves the backward's residual HBM
-    traffic and the VMEM those blocks take), f32 otherwise — bf16
-    residuals at H > 512 have not been compiled or measured on the
-    installed stack."""
+    """Dtype of the streams the kernel pair exchanges with HBM: the
+    z/h_prev/c_prev residuals and, where the LSTM op owns the input
+    projection, the reverse kernel's d_z (module docstring, point 4).  bf16
+    under the prod compute policy for H <= 512 (halves that HBM traffic and
+    the VMEM those blocks take), f32 otherwise — bf16 streams at H > 512
+    have not been compiled or measured on the installed stack."""
     from paddle_tpu.ops.numerics import compute_dtype
 
     cd = compute_dtype()
@@ -287,17 +305,22 @@ def _lstm_fwd_scan(xp, mask, w_h, h0, c0, pi, pf, po):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(9, 10))
-def lstm_sequence_fused(xp, b, mask, w_h, h0, c0, pi, pf, po,
-                        allow_pallas=False, has_peepholes=True):
-    """LSTM over a padded batch given the input projection WITHOUT its
-    bias, ``xp`` [B,T,4H], and the bias ``b`` [4H]: the op adds the bias
-    itself, so that its backward can hand back ``d_b`` from the reverse
-    kernel's accumulator and XLA never reads ``d_z`` for it.
+def lstm_sequence_fused(x, b, mask, w_h, h0, c0, pi, pf, po,
+                        allow_pallas=False, has_peepholes=True, w_x=None):
+    """LSTM over a padded batch given the layer input ``x`` [B,T,D] and the
+    input matrix ``w_x`` [D,4H], or, with ``w_x=None``, the input projection
+    WITHOUT its bias as ``x`` [B,T,4H]; and the bias ``b`` [4H]: the op adds
+    the bias itself, so that its backward can hand back ``d_b`` from the
+    reverse kernel's accumulator and XLA never reads ``d_z`` for it.  Given
+    ``w_x`` the op makes the projection (``linear(x, w_x)``) and its
+    backward the two products that transpose it, so that ``d_z`` can cross
+    HBM narrow between them (module docstring, point 4).
     pi/pf/po: [H] peephole vectors (pass zeros for the plain cell — the
     math degenerates exactly).  ``has_peepholes`` (static) lets the
     backward skip the d_peep reductions when the caller statically knows
     the peepholes are zeros."""
     # primal-only call (inference): residual-free variant — see GRU twin
+    xp = x if w_x is None else linear(x, w_x)
     h_seq, h_fin, c_fin = _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf,
                                          po, allow_pallas,
                                          residuals=False)[:3]
@@ -332,19 +355,24 @@ def _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
     return out if residuals else (out[0], out[1], out[2], None, None, None)
 
 
-def _lstm_seq_fwd(xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas,
-                  has_peepholes):
+def _lstm_seq_fwd(x, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas,
+                  has_peepholes, w_x):
+    # the projection's transpose travels as autodiff writes it for the
+    # linear() the forward ran (one operand policy, ops/matmul.py), holding
+    # the compute-dtype copies of x and w_x it multiplies with
+    xp, proj_vjp = (x, None) if w_x is None else jax.vjp(linear, x, w_x)
     h_seq, h_fin, c_fin, z_tb, hprev_tb, cprev_tb = _lstm_core_fwd(
         xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas)
     meta = (jnp.zeros((0,), xp.dtype), jnp.zeros((0,), h0.dtype),
             jnp.zeros((0,), c0.dtype),
             jnp.zeros((0,), b.dtype))  # dtype sentinels (see GRU fwd)
     return ((h_seq, h_fin, c_fin),
-            (mask, w_h, pi, pf, po, z_tb, hprev_tb, cprev_tb, meta))
+            (mask, w_h, pi, pf, po, z_tb, hprev_tb, cprev_tb, meta,
+             proj_vjp))
 
 
 def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
-    mask, w_h, pi, pf, po, z_r, hprev_r, cprev_r, meta = res
+    mask, w_h, pi, pf, po, z_r, hprev_r, cprev_r, meta, proj_vjp = res
     xp_dt, h0_dt, c0_dt, b_dt = (s.dtype for s in meta)
     d_hseq, d_hfin, d_cfin = ct
     H = w_h.shape[0]
@@ -358,14 +386,17 @@ def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
         from paddle_tpu.ops.pallas_kernels import _lstm_bwd_pallas_raw
 
         # residual streams enter in their STORED dtype (see GRU twin); the
-        # bias and peephole gradients leave the kernel already reduced
+        # bias and peephole gradients leave the kernel already reduced.
+        # d_z leaves narrow where the op owns every product that reads it,
+        # and float32 where it is the caller's d_xp (module docstring, 4)
         d_z_tb, d_h0, d_c0, d_b, d_peep = _lstm_bwd_pallas_raw(
             jnp.moveaxis(d_hseq, 1, 0).astype(f32),
             jnp.moveaxis(mask, 1, 0).astype(f32),
             z_r, cprev_r, w_f.T.copy(),
             pi_f[None], pf_f[None], po_f[None],
             d_hfin.astype(f32), d_cfin.astype(f32),
-            has_peepholes=has_peepholes)
+            has_peepholes=has_peepholes,
+            dz_dtype=f32 if proj_vjp is None else residual_dtype(H))
         d_b = d_b[0]
     else:
         cp_f = cprev_r.astype(f32)               # residuals are [T,B,*]
@@ -420,12 +451,16 @@ def _lstm_seq_bwd(allow_pallas, has_peepholes, res, ct):
     else:
         d_pi, d_pf, d_po = (d.astype(p.dtype)
                             for d, p in zip(d_peep, (pi, pf, po)))
-    # shared tail (ONE copy for both reverse-loop implementations)
-    d_wh = _bwd_einsum("tbh,tbz->hz",
-                       hprev_r.astype(f32), d_z_tb).astype(w_h.dtype)
+    # shared tail (ONE copy for both reverse-loop implementations): the
+    # products that read d_z
+    d_wh = _bwd_einsum("tbh,tbz->hz", hprev_r.astype(d_z_tb.dtype),
+                       d_z_tb).astype(w_h.dtype)
     d_xp = jnp.moveaxis(d_z_tb, 0, 1).astype(xp_dt)
-    return (d_xp, d_b.astype(b_dt), None, d_wh, d_h0.astype(h0_dt),
-            d_c0.astype(c0_dt), d_pi, d_pf, d_po)
+    # given w_x, XLA fuses the widening of a narrow d_z into the operand
+    # reads of dx and d_w_x (tests/test_tpu_compile.py holds it to that)
+    d_x, d_wx = (d_xp, None) if proj_vjp is None else proj_vjp(d_xp)
+    return (d_x, d_b.astype(b_dt), None, d_wh, d_h0.astype(h0_dt),
+            d_c0.astype(c0_dt), d_pi, d_pf, d_po, d_wx)
 
 
 lstm_sequence_fused.defvjp(_lstm_seq_fwd, _lstm_seq_bwd)

@@ -219,25 +219,35 @@ class TestLstmKernelBackward:
     B, T, H = 16, 6, 8            # two sublane tiles of rows
     RAGGED = (6, 3, 1, 5, 2, 6, 4, 1, 6, 1, 2, 3, 5, 4, 6, 2)
 
+    @pytest.mark.parametrize("projection", ["caller", "op"])
     @pytest.mark.parametrize("residuals", ["float32", "bfloat16"])
     @pytest.mark.parametrize("peepholes", [True, False],
                              ids=["peepholes", "plain"])
     @pytest.mark.parametrize("lens", [RAGGED, (6,) * 16],
                              ids=["ragged", "full"])
     def test_every_gradient_matches_scan_reference(self, monkeypatch, lens,
-                                                   peepholes, residuals):
-        """All eight gradients of the kernel path against autodiff of
+                                                   peepholes, residuals,
+                                                   projection):
+        """Every gradient of the kernel path against autodiff of
         scan_rnn(lstm_step), over masked tails (rows of length 1, full
         rows), with and without peepholes, with float32 and with bf16
-        residual streams (the production policy's).  bf16 residuals round
-        what the backward recomputes its gates from, so there the tight
-        comparison is with the lax.scan backward over the same residuals."""
+        residual streams (the production policy's), with the projection
+        made by the caller (the op's first gradient is d_xp) and by the op
+        (dx and d_w_x).  bf16 residuals round what the backward recomputes
+        its gates from, so there the tight comparison is with the lax.scan
+        backward over the same residuals.  Under the bf16 policy an op that
+        owns the projection stores d_z in bf16 on the kernel path, which
+        reaches the three products that read it (dx, d_w_x, d_w_h) and
+        nothing else; a caller's d_xp is never rounded."""
         rs = np.random.RandomState(7)
-        B, T, H = self.B, self.T, self.H
+        B, T, H, D = self.B, self.T, self.H, 5
         monkeypatch.setattr(FLAGS, "compute_dtype", residuals)
         r = lambda *shape, scale=1.0: jnp.asarray(  # noqa: E731
             scale * rs.randn(*shape).astype(np.float32))
-        xp, b, wh = r(B, T, 4 * H), r(4 * H, scale=0.3), r(H, 4 * H, scale=0.4)
+        owned = projection == "op"
+        x = r(B, T, D) if owned else r(B, T, 4 * H)
+        wx = r(D, 4 * H, scale=0.4) if owned else None
+        b, wh = r(4 * H, scale=0.3), r(H, 4 * H, scale=0.4)
         h0, c0 = r(B, H), r(B, H)
         peeps = tuple(r(H, scale=0.3 * peepholes) for _ in range(3))
         ct_seq, ct_h, ct_c = r(B, T, H), r(B, H), r(B, H)
@@ -247,38 +257,79 @@ class TestLstmKernelBackward:
             return (jnp.sum(seq * ct_seq) + jnp.sum(f * ct_h)
                     + jnp.sum(c * ct_c))
 
-        def ref(xp, b, wh, h0, c0, pi, pf, po):
+        def ref(x, b, wh, h0, c0, pi, pf, po, wx):
             def step(carry, xp_t):
                 h, c = carry
                 h2, c2 = O.lstm_step(xp_t, h, c, wh, peep_i=pi, peep_f=pf,
                                      peep_o=po)
                 return (h2, c2), h2
+            xp = O.linear(x, wx) if owned else x
             (f, c), seq = O.scan_rnn(step, (h0, c0), xp + b, mask)
             return objective(seq, f, c)
 
-        def new(xp, b, wh, h0, c0, pi, pf, po):
+        def new(x, b, wh, h0, c0, pi, pf, po, wx):
             return objective(*lstm_sequence_fused(
-                xp, b, mask, wh, h0, c0, pi, pf, po, True, peepholes))
+                x, b, mask, wh, h0, c0, pi, pf, po, True, peepholes, wx))
 
-        args = (xp, b, wh, h0, c0) + peeps
-        names = ("xp", "b", "wh", "h0", "c0", "pi", "pf", "po")
-        argnums = tuple(range(8 if peepholes else 5))
-        g_ref = jax.grad(ref, argnums)(*args)
+        args = (x, b, wh, h0, c0) + peeps + (wx,)
+        names = ("x", "b", "wh", "h0", "c0", "pi", "pf", "po", "wx")
+        every = tuple(range(9 if owned else 8))
+        argnums = every if peepholes else (0, 1, 2, 3, 4) + every[8:]
+        g_ref = dict(zip(argnums, jax.grad(ref, argnums)(*args)))
         _backward_kernel(monkeypatch, False)
-        g_scan = jax.grad(new, argnums)(*args)
+        g_scan = dict(zip(argnums, jax.grad(new, argnums)(*args)))
         _backward_kernel(monkeypatch, True)
-        g_new = jax.grad(new, tuple(range(8)))(*args)
+        g_new = jax.grad(new, every)(*args)
         tol = 1e-4 if residuals == "float32" else 3e-2
-        for name, a, s, k in zip(names, g_ref, g_scan, g_new):
+        for n in argnums:
+            a, s, k = g_ref[n], g_scan[n], g_new[n]
             scale = float(jnp.max(jnp.abs(a)))
             np.testing.assert_allclose(np.asarray(k), np.asarray(a),
                                        rtol=tol, atol=tol * scale,
-                                       err_msg=name)
+                                       err_msg=names[n])
+            reads_narrow_d_z = owned and names[n] in ("x", "wx", "wh")
+            tight = tol if reads_narrow_d_z else 1e-5
             np.testing.assert_allclose(np.asarray(k), np.asarray(s),
-                                       rtol=1e-5, atol=1e-5 * scale,
-                                       err_msg=name + " (scan backward)")
+                                       rtol=tight, atol=tight * scale,
+                                       err_msg=names[n] + " (scan backward)")
         if not peepholes:       # no accumulator: the gradients are zeros
-            assert all(not np.asarray(g).any() for g in g_new[5:])
+            assert all(not np.asarray(g).any() for g in g_new[5:8])
+
+    @pytest.mark.parametrize("peepholes", [True, False],
+                             ids=["peepholes", "plain"])
+    def test_only_the_store_of_d_z_rounds(self, peepholes):
+        """Asked for a bf16 d_z, the reverse kernel returns the float32
+        variant's d_z rounded to nearest even, bit for bit; what the kernel
+        computes from d_z itself (the carry product behind d_h0 and d_c0,
+        the bias and peephole accumulators) reads the float32 value, so
+        those results are the float32 variant's bit for bit."""
+        from paddle_tpu.ops.pallas_kernels import _lstm_bwd_pallas_raw
+
+        rs = np.random.RandomState(3)
+        B, T, H = self.B, self.T, self.H
+        r = lambda *shape, scale=1.0, dt=jnp.float32: jnp.asarray(  # noqa: E731
+            scale * rs.randn(*shape).astype(np.float32)).astype(dt)
+        bf16 = jnp.bfloat16
+        args = (r(T, B, H), jnp.moveaxis(_mask(self.RAGGED, T), 1, 0),
+                r(T, B, 4 * H, dt=bf16), r(T, B, H, dt=bf16),
+                r(4 * H, H, scale=0.4),
+                *(r(1, H, scale=0.3 * peepholes) for _ in range(3)),
+                r(B, H), r(B, H))
+        wide = _lstm_bwd_pallas_raw(*args, has_peepholes=peepholes)
+        narrow = _lstm_bwd_pallas_raw(*args, has_peepholes=peepholes,
+                                      dz_dtype=bf16)
+        assert wide[0].dtype == jnp.float32 and narrow[0].dtype == bf16
+        assert np.asarray(wide[0]).any()
+        np.testing.assert_array_equal(
+            np.asarray(narrow[0]).view(np.uint16),
+            np.asarray(wide[0].astype(bf16)).view(np.uint16))
+        assert (narrow[4] is None) == (not peepholes)
+        for name, w, n in zip(("d_h0", "d_c0", "d_b", "d_peep"),
+                              wide[1:], narrow[1:]):
+            if w is not None:
+                assert n.dtype == jnp.float32
+                np.testing.assert_array_equal(np.asarray(n), np.asarray(w),
+                                              err_msg=name)
 
     @pytest.mark.parametrize("options", [
         dict(reverse=True), dict(use_peepholes=False), dict(bias_attr=False),

@@ -75,6 +75,11 @@ def _struct(one_chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
+def _first_result(call: str) -> str:
+    """``bf16[20,256,2048]`` of ``%name = (bf16[20,256,2048]{...}, ...) op(``."""
+    return call.split(" = (")[1].split("{")[0]
+
+
 def _kernels(fn, *args) -> int:
     """Compile ``fn`` for the described chip; Mosaic kernels in its text."""
     return jax.jit(fn).lower(*args).compile().as_text().count(
@@ -120,16 +125,22 @@ def test_rnn_gate_bounds_the_resident_weight(on_tpu):
     """What the gate admits it has counted: the [H, gates*H] weight grows
     with H^2 and is refused once it alone outgrows the scoped limit, however
     small B*H is; the estimate matches what the compiler reports for the
-    LSTM reverse kernel at B64 H1280, peepholes live (33.45 MiB when asked
-    to fit it into less: the ``c_new`` stream it used to write is gone, the
-    bias and peephole accumulators are in)."""
+    LSTM reverse kernel, peepholes live and ``d_z`` float32 (its widest
+    variant, which the gate counts), when asked to fit it into 2 MiB: 33.45
+    MiB at B64 H1280 (float32 residuals), and with bf16 residuals 19.88 MiB
+    at the gate's corner B384 H512 and 14.63 MiB at the benchmark cell's
+    tile B256 H512 (with ``d_z`` in bf16 too, as where the op owns the
+    projection, the compiler says 16.88 and 12.63)."""
     from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
                                                rnn_vmem_bytes)
     from paddle_tpu.ops.rnn_fused import rnn_kernel_ok
 
-    need = rnn_vmem_bytes(64, 1280, 4, backward=True, residual_itemsize=4)
-    assert abs(need / 2**20 - 33.45) < 0.33
-    assert need < RNN_VMEM_LIMIT_BYTES
+    for batch, hidden, itemsize, compiler_mib in [
+            (64, 1280, 4, 33.45), (384, 512, 2, 19.88), (256, 512, 2, 14.63)]:
+        need = rnn_vmem_bytes(batch, hidden, 4, backward=True,
+                              residual_itemsize=itemsize)
+        assert abs(need / 2**20 - compiler_mib) < 0.01 * compiler_mib
+        assert need < RNN_VMEM_LIMIT_BYTES
     assert rnn_kernel_ok(72, 1792, 4, backward=True)
     assert not rnn_kernel_ok(8, 2048, 4)                  # 64 MiB of weight
     assert rnn_kernel_ok(96, 2048, 3, backward=True)
@@ -175,7 +186,8 @@ def test_lstm_reverse_kernel_reduces_bias_and_peepholes(batch, peepholes,
     """The LSTM benchmark cell's tile (B256 H512) and the gate's corner
     (B384 H512), both variants of the reverse kernel: it compiles, the bias
     gradient leaves it as ``f32[1,4H]`` and the peephole gradients as
-    ``f32[3,H]``, and it writes no ``[T,B,H]`` stream beside ``d_z``."""
+    ``f32[3,H]``, ``d_z``, its first result, leaves as ``bf16[T,B,4H]`` when
+    asked for so, and it writes no ``[T,B,H]`` stream beside it."""
     from paddle_tpu.ops import pallas_kernels, rnn_fused
 
     hidden, steps = 512, 20
@@ -186,7 +198,7 @@ def test_lstm_reverse_kernel_reduces_bias_and_peepholes(batch, peepholes,
 
     def reverse(*args):
         return pallas_kernels._lstm_bwd_pallas_raw(
-            *args, has_peepholes=peepholes)
+            *args, has_peepholes=peepholes, dz_dtype=rd)
 
     text = jax.jit(reverse).lower(
         s(steps, batch, hidden), s(steps, batch),
@@ -196,9 +208,50 @@ def test_lstm_reverse_kernel_reduces_bias_and_peepholes(batch, peepholes,
     call = next(line for line in text.splitlines()
                 if "tpu_custom_call" in line and "lstm_seq_bwd" in line)
     results = call.split(" custom-call(")[0]
+    assert _first_result(call) == f"bf16[{steps},{batch},{4 * hidden}]"
     assert f"f32[1,{4 * hidden}]" in results
     assert (f"f32[3,{hidden}]" in results) == peepholes
     assert f"f32[{steps},{batch},{hidden}]" not in results
+
+
+def test_lstm_stack_backward_keeps_d_z_narrow(one_chip, on_tpu):
+    """Two LSTM layers at the benchmark cell's tile (B256 H512, embedding
+    128), each with its input projection: in the optimised program both
+    ``lstm_seq_bwd`` calls hand ``d_z`` on as ``bf16[T,B,4H]``, and once the
+    backward has begun nothing produces a float32 array of that size, in
+    either layout: the widening that the projection's transpose asks of
+    ``d_xp`` fuses into the operand reads of ``dx`` and ``dW_x``.  The forward's two
+    projections do write one each, which shows that the search sees them."""
+    from paddle_tpu.ops.rnn import lstm_layer
+
+    batch, steps, emb, hidden = 256, 24, 128, 512
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+    layer = lambda d: dict(  # noqa: E731
+        wx=s(d, 4 * hidden), wh=s(hidden, 4 * hidden), b=s(4 * hidden),
+        pi=s(hidden), pf=s(hidden), po=s(hidden))
+
+    def loss(x, mask, layers):
+        for p in layers:
+            x, _ = lstm_layer(x, mask, p["wx"], p["wh"], p["b"],
+                              peep_i=p["pi"], peep_f=p["pf"], peep_o=p["po"])
+        return (x * x).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 2))).lower(
+        s(batch, steps, emb), s(batch, steps),
+        [layer(emb), layer(hidden)]).compile().as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()
+    reverse = [i for i, line in enumerate(entry)
+               if "tpu_custom_call" in line and "lstm_seq_bwd" in line]
+    assert len(reverse) == 2
+    for i in reverse:
+        assert _first_result(entry[i]) == f"bf16[{steps},{batch},{4 * hidden}]"
+    wide = (f"f32[{steps},{batch},{4 * hidden}]",
+            f"f32[{batch},{steps},{4 * hidden}]")
+    made = lambda lines: sum(  # noqa: E731
+        any(w in line.split(" = ")[1].split("(%")[0] for w in wide)
+        for line in lines if " = " in line)
+    assert made(entry[:reverse[0]]) == 2
+    assert made(entry[reverse[0]:]) == 0
 
 
 def test_attention_decoder_forward_and_backward(one_chip, on_tpu):

@@ -273,7 +273,7 @@ def lambda_cost(score: LayerOutput, label: LayerOutput, *,
         rel_gt = (rel[:, :, None] > rel[:, None, :]).astype(s.dtype)
         pair_mask = mask[:, :, None] * mask[:, None, :]
         loss = jnp.log1p(jnp.exp(-jnp.clip(ds, -30, 30))) * rel_gt * dndcg * pair_mask
-        return Act(value=jnp.sum(loss) / jnp.maximum(jnp.sum(mask), 1.0))
+        return Act(value=jnp.sum(loss) / O.token_count(mask))
 
     return LayerOutput(name, "lambda_cost", 1, [score, label], forward, [])
 

@@ -18,6 +18,7 @@ from paddle_tpu.ops.losses import (
     huber,
     smooth_l1,
     rank_cost,
+    token_count,
     masked_token_mean,
     sequence_cross_entropy,
     sequence_softmax_ce_readout,

@@ -15,10 +15,13 @@ flat-sequence costs (no padding there by construction).
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = [
     "cross_entropy",
@@ -29,6 +32,8 @@ __all__ = [
     "huber",
     "smooth_l1",
     "rank_cost",
+    "token_count",
+    "token_mean_over_shards",
     "masked_token_mean",
     "sequence_cross_entropy",
 ]
@@ -95,12 +100,46 @@ def rank_cost(score_left, score_right, label, weight=None):
     return cost
 
 
+_shard_trace = threading.local()
+
+
+@contextlib.contextmanager
+def token_mean_over_shards(axes):
+    """Entered by a data-parallel ``shard_map`` body (``parallel/api.py``
+    ``data_parallel_body``) around the trace of its loss and gradient: the
+    rows are split over the mesh axes ``axes``, and :func:`token_count`
+    then counts over all of them.  Acts at trace time only, like
+    ``ops/pallas_kernels.xla_paths_only``."""
+    held = getattr(_shard_trace, "axes", None)
+    _shard_trace.axes = axes
+    try:
+        yield
+    finally:
+        _shard_trace.axes = held
+
+
+def token_count(mask):
+    """What a mean over real tokens divides by, forward and in the
+    hand-written backwards: ``max(sum(mask), 1)``.
+
+    Inside a data-parallel body (:func:`token_mean_over_shards`) it is the
+    MEAN count over the shards, ``max(psum(sum(mask)), 1) / n``: the mean
+    over the ``n`` shards of ``shard_total / token_count`` is then the token
+    mean of the global batch, ragged lengths and all, and not a mean of
+    per-shard means.  The count depends on the mask alone, so no gradient
+    passes through the collective."""
+    count = jnp.sum(mask)
+    axes = getattr(_shard_trace, "axes", None)
+    if axes is None:
+        return jnp.maximum(count, 1.0)
+    return jnp.maximum(lax.psum(count, axes), 1.0) / lax.axis_size(axes)
+
+
 def masked_token_mean(per_token, mask):
     """Mean over real (mask>0) positions — the sequence-cost reduction."""
     mask = mask.astype(per_token.dtype)
     total = jnp.sum(per_token * mask)
-    count = jnp.maximum(jnp.sum(mask), 1.0)
-    return total / count
+    return total / token_count(mask)
 
 
 def sequence_cross_entropy(logits, labels, mask):
@@ -170,7 +209,7 @@ def _ce_readout_bwd(res, d):
     states, w, logits, lse, labels, mask = res
     f32 = jnp.float32
     mask_f = mask.astype(f32)
-    denom = jnp.maximum(jnp.sum(mask_f), 1.0)
+    denom = token_count(mask_f)
     scale = (d * mask_f / denom)                       # [B, T]
     # d_logits = (softmax - onehot) * scale, materialized once in the
     # compute dtype; softmax recomputed from the saved logits + lse
@@ -275,7 +314,7 @@ def _tiled_ce_fn(rb, vt, V, sdt, wdt, bdt):
         N, D = sc.shape
         B, T = mask.shape
         mask_f = mask.astype(f32)
-        denom = jnp.maximum(jnp.sum(mask_f), 1.0)
+        denom = token_count(mask_f)
         scale = (d * mask_f / denom).reshape(N, 1)
         d_states, d_w_p, d_b_p = ce_readout_bwd_pallas(
             logits, sc, w_p, lab, lse, scale, v_tile=vt)

@@ -32,7 +32,8 @@ sets takes part:
   ``ops/moe.moe_kernel_row_tile``.
 
 Where a gate is closed (the CPU, a shape past it, a step that jit partitions
-over a mesh) the caller's lax.scan / XLA path runs.  Off the TPU the kernels
+over a mesh: one with sharding rules, or ``SGDTrainer(mesh=...)``) the
+caller's lax.scan / XLA path runs.  Off the TPU the kernels
 run in interpret mode, which is how the CPU tests compare both paths.
 """
 
@@ -86,18 +87,26 @@ def xla_paths_only():
     partitioned. Please wrap the call in a shard_map") — inside a
     ``shard_map`` body the kernels are fine and this is not needed.  The
     switch acts at trace time only: a function jitted and traced before it
-    was entered keeps the kernels it was traced with.  On a TPU the loss
-    of the kernels is logged, once per process (ROADMAP S2 owns the
-    repair: ``shard_map`` or ``custom_partitioning`` around the kernels)."""
+    was entered keeps the kernels it was traced with.
+
+    Two steps still enter it and lose the kernels: a
+    ``parallel.make_parallel_train_step`` with ``rules`` (a model axis),
+    and ``SGDTrainer(mesh=...)`` (guard, pserver tiers and extras inside
+    one partitioned step).  The pure data-parallel step does not: it is a
+    ``shard_map`` whose body sees one chip's rows
+    (``parallel/api.py`` ``data_parallel_body``).  On a TPU the loss of the
+    kernels is logged, once per process."""
     global _warned_kernels_off
     if not _warned_kernels_off and jax.default_backend() == "tpu":
         _warned_kernels_off = True
         from paddle_tpu.utils.log import logger
 
         logger.warning(
-            "this step is partitioned over a mesh by jit, which Mosaic "
+            "this step (sharding rules over a model axis, or SGDTrainer "
+            "with a mesh) is partitioned over the mesh by jit, which Mosaic "
             "kernels do not survive: every kernel gate is closed while it "
-            "is traced, and it runs the XLA paths")
+            "is traced, and it runs the XLA paths; a pure data-parallel "
+            "parallel.make_parallel_train_step (no rules) keeps the kernels")
     _mesh_trace.depth = getattr(_mesh_trace, "depth", 0) + 1
     try:
         yield

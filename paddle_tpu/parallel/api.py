@@ -3,9 +3,18 @@
 Replaces the reference's updater/machine selection matrix (local vs remote vs
 sparse-remote updaters, TrainerInternal.cpp:217-292; MultiGradientMachine) with
 one function: give it a loss function (or Topology), a mesh, and sharding
-rules — get back a compiled SPMD train step.  Collectives are chosen by XLA
-GSPMD from the shardings; there is no separate communication code path to
-maintain.
+rules — get back a compiled SPMD train step.  Who chooses the collectives
+depends on the case:
+
+- **pure data parallelism** (``rules is None``): the step is one
+  ``shard_map`` over the mesh.  Every chip runs :func:`data_parallel_body`
+  on its own rows, kernels and all, and the body reduces the gradients
+  itself: one ``psum`` a leaf over the data axis, or the two-level schedule
+  of ``parallel/hierarchical.py`` where the mesh binds a ``dcn`` axis.
+- **a model axis** (``rules`` given): jit partitions a global step, and XLA
+  GSPMD chooses the collectives from the operand shardings.  The Mosaic
+  kernels do not survive that partitioner, so the step is traced inside
+  ``ops/pallas_kernels.xla_paths_only()``.
 """
 
 from __future__ import annotations
@@ -14,13 +23,17 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddle_tpu.ops.losses import token_mean_over_shards
+from paddle_tpu.parallel import compat
 from paddle_tpu.parallel.mesh import as_mesh
 from paddle_tpu.parallel.sharding import ShardingRules, batch_sharding, replicated
 from paddle_tpu.param.optimizers import Optimizer
 
-__all__ = ["make_parallel_train_step", "shard_batch", "agreement_spec"]
+__all__ = ["make_parallel_train_step", "data_parallel_body", "shard_batch",
+           "agreement_spec"]
 
 
 def agreement_spec(mesh, axis: Optional[str] = None):
@@ -69,6 +82,49 @@ def shard_batch(mesh, feed: Dict[str, Any], axis: str = "data") -> Dict[str, Any
     return out
 
 
+def _psum_leaves(axes):
+    def reduce_grads(grads):
+        return (jax.tree_util.tree_map(lambda g: lax.psum(g, axes), grads),)
+
+    return reduce_grads
+
+
+def data_parallel_body(loss_fn, optimizer: Optimizer, axes,
+                       reduce_grads: Optional[Callable] = None) -> Callable:
+    """What ONE chip does in a data-parallel step, for ``shard_map`` over a
+    mesh whose ``axes`` (a name or a tuple of names) split the batch's rows:
+    ``body(params, opt_state, *carry, batch) -> (loss, params, opt_state,
+    *carry)``, with ``params`` and ``opt_state`` replicated.
+
+    The body differentiates ``loss_fn`` on the chip's own rows, so the
+    kernel gates see the chip's shape and a Mosaic kernel is an ordinary
+    call; the embeddings' gradients are scattered over those rows alone and
+    leave as ``[V, emb]`` tables.  ``reduce_grads(grads, *carry) -> (grads
+    summed over the shards, *carry)`` is the exchange: ``lax.psum`` leaf by
+    leaf unless the builder brings its own (``parallel/hierarchical.py``:
+    the two-level schedule; its compressed variant carries residuals).
+    Every chip then applies the same mean gradient to its replica, so the
+    replicas stay equal to the bit.
+
+    The loss and gradient are those of the GLOBAL batch.  A mean over rows
+    is the mean of the shards' means, since shards hold equal rows; a mean
+    over real tokens divides by ``ops.losses.token_count``, which is the
+    mean count over the shards while this body is traced."""
+    reduce_grads = reduce_grads or _psum_leaves(axes)
+
+    def body(params, opt_state, *rest):
+        *carry, batch = rest
+        n = compat.axis_size(axes)
+        with token_mean_over_shards(axes):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        grads, *carry = reduce_grads(grads, *carry)
+        grads = jax.tree_util.tree_map(lambda g: g / n, grads)
+        new_params, new_opt = optimizer.update(params, grads, opt_state)
+        return (lax.psum(loss, axes) / n, new_params, new_opt, *carry)
+
+    return body
+
+
 def make_parallel_train_step(
     loss_fn: Callable[[Dict[str, Any], Dict[str, Any]], jax.Array],
     optimizer: Optimizer,
@@ -82,16 +138,23 @@ def make_parallel_train_step(
 
     ``loss_fn(params, batch) -> scalar`` must be pure. Params should be placed
     with ``shard_params(mesh, params, rules)`` and the batch with
-    ``shard_batch`` — jit then infers all collectives (grad all-reduce over
-    'data', activation collectives over 'model') from the operand shardings.
+    ``shard_batch``.
 
-    A ``MeshConfig`` that binds a ``dcn_axis`` (``--dcn_axis``) routes the
-    pure data-parallel case (``rules is None``) through the two-level
-    ICI-reduce-scatter / DCN-allreduce / ICI-allgather schedule
+    Without ``rules`` the step is pure data parallel: one ``shard_map`` of
+    :func:`data_parallel_body` over the mesh, the batch's rows split over
+    the data axis, parameters and optimizer state replicated in and out (so
+    the next call finds them placed as it left them).  A ``MeshConfig``
+    that binds a ``dcn_axis`` (``--dcn_axis``) gets the same body with the
+    two-level ICI-reduce-scatter / DCN-allreduce / ICI-allgather exchange
     (``parallel/hierarchical.py``) — same signature, same sum (bit-equal
     to flat on a single pod).  The bf16-compressed DCN variant changes
     the signature (it threads error-feedback residuals), so it is only
     available via ``make_hierarchical_train_step`` directly.
+
+    With ``rules`` (a model axis) jit partitions the global step and infers
+    all collectives (grad all-reduce over 'data', activation collectives
+    over 'model') from the operand shardings; that step runs the XLA paths
+    (``xla_paths_only``).
     """
     from paddle_tpu.parallel.mesh import MeshConfig
 
@@ -103,7 +166,14 @@ def make_parallel_train_step(
         return make_hierarchical_train_step(loss_fn, optimizer, mesh,
                                             compress=False, donate=donate)
 
+    data = mesh.role_axis("data") if isinstance(mesh, MeshConfig) else "data"
     built = as_mesh(mesh)
+    donate_argnums = (0, 1) if donate else ()
+    if rules is None and built.size > 1 and data in built.axis_names:
+        shm = compat.shard_map(
+            data_parallel_body(loss_fn, optimizer, data), mesh=built,
+            in_specs=(P(), P(), P(data)), out_specs=(P(), P(), P()))
+        return jax.jit(shm, donate_argnums=donate_argnums)
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -134,5 +204,4 @@ def make_parallel_train_step(
 
         step = xla_paths_only()(step)
 
-    donate_argnums = (0, 1) if donate else ()
     return jax.jit(step, donate_argnums=donate_argnums)

@@ -31,12 +31,11 @@ a one-step-delayed correction instead of a bias.  Not bit-exact with the
 uncompressed path — gated by the convergence tier, not by the
 bit-equality pins (``--dcn_compress``).
 
-``make_hierarchical_train_step`` is the step-builder twin of
-``parallel.api.make_parallel_train_step`` for dcn-bound data-parallel
-meshes: it computes per-shard gradients inside ``shard_map`` (GSPMD's
-implicit ``value_and_grad`` reduction would already be global — summing
-it again would multiply by the world size) and routes them through the
-two-level schedule above.
+``make_hierarchical_train_step`` builds the data-parallel step of
+``parallel.api`` (``data_parallel_body``: every chip differentiates the loss
+on its own rows inside ``shard_map``) for dcn-bound meshes: the same body,
+with the two-level schedule above in place of the flat ``psum`` as its
+exchange.
 """
 
 from __future__ import annotations
@@ -49,6 +48,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.parallel import compat
+from paddle_tpu.parallel.api import data_parallel_body
 from paddle_tpu.parallel.mesh import MeshConfig
 from paddle_tpu.param.optimizers import Optimizer
 from paddle_tpu.utils import FLAGS
@@ -173,59 +173,40 @@ def make_hierarchical_train_step(
     opt_state, residuals)`` where ``residuals`` starts as
     :func:`init_dcn_residuals`.
 
-    Gradients are computed PER SHARD inside shard_map and reduced by the
-    explicit two-level schedule — data-parallel only (params replicated;
+    The step is ``parallel.api.data_parallel_body`` with the two-level
+    schedule as its exchange — data-parallel only (params replicated;
     tensor-parallel rules need GSPMD's implicit reduction and keep using
-    ``make_parallel_train_step``).  The batch shards over ``(dcn,
-    data)`` jointly, exactly how ``shard_batch`` places it when both
-    axes exist."""
+    ``make_parallel_train_step``).  The batch's rows split over ``(dcn,
+    data)`` jointly."""
     cfg, built, dcn, data, dcn_size, ici_size = _resolve(mesh)
     if compress is None:
         compress = bool(FLAGS.dcn_compress)
-    n = dcn_size * ici_size
-    batch_spec = P((dcn, data))
+    sizes = dict(ici_size=ici_size, dcn_size=dcn_size)
 
-    def reduce_loss(loss):
-        loss = lax.psum(loss, data)
-        if dcn_size > 1:
-            loss = lax.psum(loss, dcn)
-        return loss / n
+    def two_level(grads):
+        return (jax.tree_util.tree_map(
+            lambda g: hierarchical_psum(g, data, dcn, **sizes), grads),)
 
-    def plain_body(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        grads = jax.tree_util.tree_map(
-            lambda g: hierarchical_psum(g, data, dcn, ici_size=ici_size,
-                                        dcn_size=dcn_size) / n, grads)
-        new_params, new_opt = optimizer.update(params, grads, opt_state)
-        return reduce_loss(loss), new_params, new_opt
-
-    def compressed_body(params, opt_state, residuals, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+    def two_level_compressed(grads, residuals):
         leaves, treedef = jax.tree_util.tree_flatten(grads)
-        res_leaves = treedef.flatten_up_to(residuals)
         out_g, out_r = [], []
-        for g, r in zip(leaves, res_leaves):
+        for g, r in zip(leaves, treedef.flatten_up_to(residuals)):
             red, nr = hierarchical_psum_compressed(
-                g, r.reshape(-1), data, dcn, ici_size=ici_size,
-                dcn_size=dcn_size)
-            out_g.append(red / n)
+                g, r.reshape(-1), data, dcn, **sizes)
+            out_g.append(red)
             out_r.append(nr.reshape(r.shape))
-        grads = jax.tree_util.tree_unflatten(treedef, out_g)
-        new_res = jax.tree_util.tree_unflatten(treedef, out_r)
-        new_params, new_opt = optimizer.update(params, grads, opt_state)
-        return reduce_loss(loss), new_params, new_opt, new_res
+        return (jax.tree_util.tree_unflatten(treedef, out_g),
+                jax.tree_util.tree_unflatten(treedef, out_r))
 
-    rep = P()  # params/opt replicated across both axes
-    if compress:
-        shm = compat.shard_map(
-            compressed_body, mesh=built,
-            in_specs=(rep, rep, P(dcn, data), batch_spec),
-            out_specs=(rep, rep, rep, P(dcn, data)))
-        donate_argnums = (0, 1, 2) if donate else ()
-    else:
-        shm = compat.shard_map(
-            plain_body, mesh=built,
-            in_specs=(rep, rep, batch_spec),
-            out_specs=(rep, rep, rep))
-        donate_argnums = (0, 1) if donate else ()
+    # params/opt replicated across both axes, the batch's rows split over
+    # them jointly; a residual leaf lives where its scattered partial does
+    rep, rows, res = P(), P((dcn, data)), P(dcn, data)
+    body = data_parallel_body(
+        loss_fn, optimizer, (dcn, data),
+        two_level_compressed if compress else two_level)
+    carry = (res,) if compress else ()
+    shm = compat.shard_map(body, mesh=built,
+                           in_specs=(rep, rep, *carry, rows),
+                           out_specs=(rep, rep, rep, *carry))
+    donate_argnums = tuple(range(2 + len(carry))) if donate else ()
     return jax.jit(shm, donate_argnums=donate_argnums)

@@ -21,6 +21,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from paddle_tpu.utils.registry import Registry
 
@@ -180,10 +181,24 @@ class Optimizer:
         raise NotImplementedError
 
     def init_state(self, params) -> Dict[str, Any]:
-        return {
-            "step": jnp.zeros((), jnp.int32),
-            "slots": {k: self.init_leaf(p) for k, p in params.items()},
-        }
+        """The state for ``params``, placed like them: a slot of a parameter
+        that lives on a mesh (``parallel.shard_params``) is put where its
+        parameter is, and the step count replicated on that mesh.  A step
+        over the mesh hands the state back so placed; a state that went in
+        placed otherwise is another input type to jit, and the step's
+        second call would trace, lower and load it all over again."""
+        on_mesh = {k: p.sharding for k, p in params.items()
+                   if isinstance(p, jax.Array)
+                   and not isinstance(p, jax.core.Tracer)
+                   and isinstance(p.sharding, NamedSharding)}
+        slots = {k: self.init_leaf(p) for k, p in params.items()}
+        for k, sharding in on_mesh.items():
+            slots[k] = jax.device_put(slots[k], sharding)
+        step = jnp.zeros((), jnp.int32)
+        if on_mesh:
+            mesh = next(iter(on_mesh.values())).mesh
+            step = jax.device_put(step, NamedSharding(mesh, PartitionSpec()))
+        return {"step": step, "slots": slots}
 
     # named_scope: every step that applies an optimizer (the trainer's, the
     # demos', parallel/) shows its update under this one name on a device

@@ -455,6 +455,31 @@ def test_hierarchical_train_step_matches_flat_and_api_dispatch(rng):
 
 
 @mesh_skip
+def test_hierarchical_step_token_mean_is_the_global_batchs(rng):
+    """The two-level step shares the data-parallel body, so a loss that is
+    a mean over real tokens divides by the count over BOTH axes: ragged
+    lengths, two pods of four, one row a chip, against one device."""
+    from tests.test_parallel import _one_device_step, _seq2seq_batch
+
+    m, params, batch = _seq2seq_batch(rng)
+    opt = Adam(learning_rate=1e-3)
+    loss_ref, p_ref, _ = _one_device_step(m.loss, opt, params, batch)
+
+    cfg = MeshConfig(axes=(("dcn", 2), ("data", 4)), dcn_axis="dcn")
+    built = cfg.build()
+    rep = NamedSharding(built, P())
+    joint = NamedSharding(built, P(("dcn", "data")))
+    ph = {k: jax.device_put(jnp.asarray(v), rep) for k, v in params.items()}
+    bh = {k: jax.device_put(jnp.asarray(v), joint) for k, v in batch.items()}
+    loss_h, p_h, _ = par.make_parallel_train_step(
+        m.loss, opt, cfg, donate=False)(ph, opt.init_state(ph), bh)
+    np.testing.assert_allclose(float(loss_ref), float(loss_h), rtol=1e-5)
+    for k in p_ref:
+        np.testing.assert_allclose(np.asarray(p_ref[k]), np.asarray(p_h[k]),
+                                   rtol=1e-4, atol=1e-6, err_msg=k)
+
+
+@mesh_skip
 def test_compressed_step_error_feedback_converges(rng):
     """--dcn_compress end to end: the bf16-DCN step with error feedback
     still drives the loss down (the convergence-tier gate for the

@@ -66,6 +66,183 @@ def test_data_parallel_matches_single_device(rng):
         )
 
 
+def _text_clf_batch(rng, V=40, B=16, T=8):
+    """``lstm_benchmark_net`` at a toy size: its cost is a mean over rows."""
+    cost, _ = models.lstm_benchmark_net(V, emb_dim=8, hid_dim=8, num_layers=1)
+    topo = nn.Topology(cost)
+    params, state = topo.init(jax.random.PRNGKey(0))
+    batch = {"words": (rng.randint(3, V, (B, T)).astype(np.int32),
+                       rng.randint(2, T + 1, B).astype(np.int32)),
+             "label": rng.randint(0, 2, (B, 1)).astype(np.int32)}
+
+    def loss(p, b):
+        return topo.apply(p, state, b, train=True)[0]["cost"].value
+
+    return loss, params, batch
+
+
+def _one_device_step(loss_fn, opt, params, batch):
+    put = lambda t: jax.tree_util.tree_map(jnp.asarray, t)  # noqa: E731
+    step = par.make_parallel_train_step(
+        loss_fn, opt, make_mesh((1,), ("data",)), donate=False)
+    return step(put(params), opt.init_state(put(params)), put(batch))
+
+
+@pytest.mark.parametrize("case", ["token_mean_two_rows_a_shard",
+                                  "row_mean_two_rows_a_shard"])
+def test_data_parallel_step_is_the_global_batchs(rng, case):
+    """Ragged lengths, two rows a shard: the eight-way step's loss and
+    update are the one-device step's on the whole batch, for a loss that is
+    a mean over real tokens (a mean of the shards' token means is another
+    number there) and for one that is a mean over rows."""
+    if case.startswith("token_mean"):
+        m, params, batch = _seq2seq_batch(rng, B=16)
+        loss_fn = m.loss
+    else:
+        loss_fn, params, batch = _text_clf_batch(rng)
+    opt = Adam(learning_rate=1e-3)
+    loss_ref, p_ref, _ = _one_device_step(loss_fn, opt, params, batch)
+
+    mesh = make_mesh((8,), ("data",))
+    p8 = par.shard_params(mesh, params)
+    loss8, p8_new, _ = par.make_parallel_train_step(
+        loss_fn, opt, mesh, donate=False)(
+            p8, opt.init_state(p8), par.shard_batch(mesh, batch))
+    np.testing.assert_allclose(float(loss_ref), float(loss8), rtol=1e-5)
+    for k in p_ref:
+        np.testing.assert_allclose(np.asarray(p_ref[k]),
+                                   np.asarray(p8_new[k]), rtol=1e-4,
+                                   atol=1e-6, err_msg=k)
+
+
+@pytest.mark.parametrize("lengths", [[3, 1, 0, 4, 2, 2, 0, 1], [0] * 8],
+                         ids=["ragged", "no_real_token"])
+def test_token_count_outside_and_inside_a_data_parallel_body(lengths):
+    """Outside a data-parallel body the count is ``max(sum(mask), 1)`` to
+    the bit; inside it is the mean over the shards of the global count, on
+    every shard, so that shard totals over it average to the global mean."""
+    from paddle_tpu.ops.losses import token_count, token_mean_over_shards
+
+    mask = O.mask_from_lengths(jnp.asarray(lengths, jnp.int32), 4).astype(
+        jnp.float32)
+    want = jnp.maximum(jnp.sum(mask), 1.0)
+    got = token_count(mask)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    assert np.array_equal(jax.jit(token_count)(mask), want)
+
+    mesh = make_mesh((4,), ("data",))
+
+    def body(rows):
+        with token_mean_over_shards("data"):
+            return token_count(rows)[None]
+
+    inside = par.compat.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"))(mask)
+    np.testing.assert_array_equal(np.asarray(inside),
+                                  np.full(4, float(want) / 4, np.float32))
+    assert np.array_equal(token_count(mask), want)      # and closed again
+
+
+@pytest.mark.parametrize("with_rules", [False, True],
+                         ids=["data_parallel", "with_rules"])
+def test_kernel_gates_inside_the_parallel_step(monkeypatch, with_rules):
+    """At trace time, from inside ``loss_fn``: the pure data-parallel step
+    leaves the gates' mesh half open and shows the loss one chip's rows
+    (``shard_map``: a Mosaic kernel is an ordinary call there); a step with
+    ``rules`` is partitioned by jit, sees the global batch and keeps the
+    gates closed (``xla_paths_only``)."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_warned_kernels_off", True)   # keep it quiet
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    seen = []
+
+    def loss_fn(p, b):
+        seen.append((pk.compiled_kernels(), b["x"].shape[0]))
+        return jnp.mean((b["x"] @ p["w"]) ** 2)
+
+    mesh = make_mesh((4,), ("data",))
+    rules = par.ShardingRules([("*", P())]) if with_rules else None
+    params = par.shard_params(mesh, {"w": np.ones((4, 2), np.float32)}, rules)
+    opt = Adam(learning_rate=1e-3)
+    batch = par.shard_batch(mesh, {"x": np.ones((16, 4), np.float32)})
+    par.make_parallel_train_step(loss_fn, opt, mesh, rules=rules).lower(
+        params, opt.init_state(params), batch)
+    assert seen and set(seen) == ({(False, 16)} if with_rules
+                                  else {(True, 4)})
+    assert not getattr(pk._mesh_trace, "depth", 0)
+
+
+def _four_way_seq2seq(rng):
+    m, params, batch = _seq2seq_batch(rng, V=40, B=16, S=5, T=6)
+    mesh = make_mesh((4,), ("data",))
+    opt = Adam(learning_rate=1e-3)
+    p4 = par.shard_params(mesh, params)
+    step = par.make_parallel_train_step(m.loss, opt, mesh, donate=False)
+    return step, p4, opt.init_state(p4), par.shard_batch(mesh, batch)
+
+
+def test_data_parallel_step_reduces_the_embedding_as_a_table(rng):
+    """The four-device step's compiled text: the source embedding's gradient
+    crosses the chips as the ``[V, emb]`` table each chip scattered its own
+    rows into, and no all-reduce carries the rows of the global batch
+    (16 x 5 source positions; the partitioned step reduced those, 302 MB a
+    step at the flagship's size) nor of one shard."""
+    step, p4, s4, b4 = _four_way_seq2seq(rng)
+    text = step.lower(p4, s4, b4).compile().as_text()
+    reduces = [l for l in text.splitlines() if " all-reduce(" in l
+               or " all-reduce-start(" in l]
+    assert reduces
+    assert any("f32[40,8]" in l for l in reduces), reduces
+    for l in reduces:
+        assert not any(rows in l for rows in ("[80,", "[16,5", "[20,",
+                                              "[4,5")), l
+    assert " all-gather(" not in text and " all-to-all(" not in text
+
+
+def test_data_parallel_replicas_stay_bit_equal(rng):
+    """Every chip applies the same reduced gradient to the same replica:
+    after three steps the four copies of every leaf are equal to the bit."""
+    step, p4, s4, b4 = _four_way_seq2seq(rng)
+    p0 = p4
+    for _ in range(3):
+        _, p4, s4 = step(p4, s4, b4)
+    for name, leaf in p4.items():
+        copies = [np.asarray(s.data) for s in leaf.addressable_shards]
+        assert len(copies) == 4 and copies[0].shape == leaf.shape, name
+        for other in copies[1:]:
+            assert np.array_equal(copies[0], other), name
+    assert float(jnp.abs(p4["src_emb"] - p0["src_emb"]).max()) > 0
+
+
+@pytest.mark.parametrize("with_rules", [False, True],
+                         ids=["data_parallel", "with_rules"])
+def test_parallel_step_is_traced_once(rng, with_rules):
+    """``init_state`` places a state like the placed parameters it is made
+    from, which is how the step hands it back: the second call, on the
+    state the first returned, finds the first call's trace (a second one
+    costs a trace, a lowering and an executable load in every set-up)."""
+    m, params, batch = _seq2seq_batch(rng)
+    opt = Adam(learning_rate=1e-3)
+    if with_rules:
+        mesh = make_mesh((4, 2), ("data", "model"))
+        rules = par.ShardingRules([("out_w", P(None, "model")), ("*", P())])
+    else:
+        mesh, rules = make_mesh((4,), ("data",)), None
+    p = par.shard_params(mesh, params, rules)
+    s = opt.init_state(p)
+    for slot in s["slots"]["out_w"]:
+        assert slot.sharding == p["out_w"].sharding
+    assert s["step"].sharding.is_fully_replicated
+    assert len(s["step"].sharding.device_set) == mesh.size
+    step = par.make_parallel_train_step(m.loss, opt, mesh, rules=rules,
+                                        donate=False)
+    b = par.shard_batch(mesh, batch)
+    for _ in range(3):
+        _, p, s = step(p, s, b)
+    assert step._cache_size() == 1
+
+
 def test_tensor_parallel_matches_single_device(rng):
     """DP x TP sharded step == single-device step (the ParallelNeuralNetwork /
     model-parallel equivalence, but via GSPMD)."""

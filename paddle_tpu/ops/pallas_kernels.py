@@ -6,7 +6,9 @@ bar.sync), fused GRU (hl_gru_ops.cuh).  The TPU analog: the *whole* LSTM/GRU
 time loop runs inside ONE Pallas kernel — the grid's sequential dimension is
 time, recurrent weights stay resident in VMEM across all timesteps, and the
 h/c state lives in VMEM scratch, so per-step HBM traffic is just the input
-projection block in and the hidden block out.
+block in (the LSTM forward takes the layer's input and its input matrix and
+makes the projection itself; the others take the projection) and the hidden
+block out.
 
 What the file holds, family by family, with the gate that chooses each
 kernel over its XLA twin.  A gate lives in the module that calls the kernel
@@ -17,7 +19,8 @@ sets takes part:
 - LSTM / GRU time loops, forward (``lstm_seq_fwd``, ``gru_seq_fwd``; they
   stream out the residuals the backward needs) and reverse (``lstm_seq_bwd``,
   ``gru_seq_bwd``; no forward replay).  Gate: ``ops/rnn_fused.rnn_kernel_ok``
-  (``backward=`` for the reverse kernels), sized by :func:`rnn_vmem_bytes`.
+  (``backward=`` for the reverse kernels, ``proj_dim=`` for the LSTM forward
+  that makes the input projection), sized by :func:`rnn_vmem_bytes`.
   ``lstm_forward_pallas`` / ``gru_forward_pallas`` are direct entries for
   tests, with an autodiff-of-reference backward.
 - Attention GRU decoder, forward and reverse.  Gate:
@@ -135,7 +138,8 @@ RNN_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
-                   residual_itemsize: int) -> int:
+                   residual_itemsize: int,
+                   proj_dim: Optional[int] = None) -> int:
     """Scoped VMEM one recurrent time-loop kernel holds, from its
     BlockSpecs: the recurrent weight ``[H, gates*H]`` f32 stays resident
     (one buffer — its block index never changes), every per-step block is
@@ -143,9 +147,12 @@ def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
     f32.  ``gates``: 4 = LSTM (two carries), 3 = GRU (one).  Counted with
     the widest variant (training residuals; the LSTM reverse kernel's
     peephole accumulators and its ``d_z`` in f32, which is half that where
-    the op owns the input projection).  Agrees to within 1% with what the
-    v5e compiler of the installed libtpu reports when it refuses a kernel
-    (jax 0.9.0, tests/test_tpu_compile.py holds the gates to it)."""
+    the op owns the input projection).  Given ``proj_dim`` = D, the LSTM
+    forward kernel makes the input projection itself: a resident ``[D, 4H]``
+    f32 weight in, and a ``[B, D]`` f32 block a time step in place of the
+    ``[B, 4H]`` one.  Agrees to within 1% with what the v5e compiler of the
+    installed libtpu reports when it refuses a kernel (jax 0.9.0,
+    tests/test_tpu_compile.py holds the gates to it)."""
     carries = 2 if gates == 4 else 1
     rs = residual_itemsize
     weight = 4 * gates * hidden * hidden
@@ -160,21 +167,26 @@ def rnn_vmem_bytes(batch: int, hidden: int, gates: int, *, backward: bool,
             # double-buffered blocks they leave in, [1, 4H] and [3 -> 4, H]
             fixed = 4 * hidden * (8 * (gates + 3) + 2 * gates + 2 * 4)
     else:
-        # xp in, h_seq out, z + held-carry residuals out, then per carry:
-        # final out, scratch
-        per_unit = (8 * gates + 8 + 2 * rs * (gates + carries)
-                    + 8 * carries)
+        # xp (or x) in, h_seq out, z + held-carry residuals out, then per
+        # carry: final out, scratch
+        per_unit = 8 + 2 * rs * (gates + carries) + 8 * carries
+        if proj_dim is None:
+            per_unit += 8 * gates
+        else:
+            fixed = 4 * proj_dim * (gates * hidden + 2 * batch)
     return weight + fixed + per_unit * batch * hidden
 
 
-def _lstm_kernel(xp_ref, m_ref, wh_ref, pi_ref, pf_ref, po_ref,
-                 hseq_ref, hfin_ref, cfin_ref,
-                 *rest, hidden: int, mxu_dtype):
+def _lstm_kernel(x_ref, m_ref, wh_ref, *rest, hidden: int, mxu_dtype,
+                 xp_dtype, project: bool):
     from jax.experimental import pallas as pl
 
-    # rest carries the optional residual outputs before the two scratch
-    # refs: (zseq, hprev, cprev, h_scr, c_scr) in training, (h_scr, c_scr)
-    # on the residual-free inference variant
+    # rest: with ``project`` the input matrix and the bias rows first; the
+    # peepholes; the outputs, of which the residuals (zseq, hprev, cprev) are
+    # there in training only; the two carry scratches
+    if project:
+        wx_ref, b_ref, *rest = rest
+    pi_ref, pf_ref, po_ref, hseq_ref, hfin_ref, cfin_ref, *rest = rest
     save_residuals = len(rest) == 5
     if save_residuals:
         zseq_ref, hprev_ref, cprev_ref, h_scr, c_scr = rest
@@ -189,15 +201,25 @@ def _lstm_kernel(xp_ref, m_ref, wh_ref, pi_ref, pf_ref, po_ref,
         h_scr[...] = jnp.zeros_like(h_scr)
         c_scr[...] = jnp.zeros_like(c_scr)
 
+    H = hidden
+    f32 = jnp.float32
     h = h_scr[...]
     c = c_scr[...]
-    xp = xp_ref[0]                          # [B, 4H]
+    if project:
+        # linear(x, w_x) + b as XLA makes it: operands in the compute dtype,
+        # float32 accumulation, the product and then the sum rounded to the
+        # projection's dtype (float32 unless --amp)
+        xp = jnp.dot(x_ref[0].astype(mxu_dtype), wx_ref[...].astype(mxu_dtype),
+                     preferred_element_type=f32)
+        xp = xp.astype(xp_dtype).astype(f32) + b_ref[0]
+        xp = xp.astype(xp_dtype).astype(f32)
+    else:
+        xp = x_ref[0]                       # [B, 4H], bias added
     # matmul operands follow the framework's compute-dtype policy (bf16 by
     # default) so this kernel computes the same function as the lax.scan
     # path (linear()/mxu_cast) that the custom_vjp backward differentiates
     z = xp + jnp.dot(h.astype(mxu_dtype), wh_ref[...].astype(mxu_dtype),
-                     preferred_element_type=jnp.float32)
-    H = hidden
+                     preferred_element_type=f32)
     # peephole ("check") vectors ride resident [1,H] blocks; zeros = plain
     # cell (hl_lstm_ops.cuh: i,f see c_prev, o sees c_new)
     i = jax.nn.sigmoid(z[:, :H] + pi_ref[0] * c)
@@ -229,9 +251,15 @@ def _lstm_kernel(xp_ref, m_ref, wh_ref, pi_ref, pf_ref, po_ref,
         cfin_ref[...] = c_new
 
 
-def _lstm_pallas_raw(xp_tb, mask_tb, w_h, pi, pf, po, *,
-                     residuals: bool = True):
-    """TIME-MAJOR: xp [T,B,4H], mask [T,B] — Mosaic requires the last two
+def _lstm_pallas_raw(x_tb, mask_tb, w_h, pi, pf, po, *, w_x=None, b=None,
+                     xp_dtype=jnp.float32, residuals: bool = True):
+    """TIME-MAJOR: mask [T,B] and either the input projection with its bias,
+    ``x_tb`` [T,B,4H] float32, or (``w_x`` [D,4H] and ``b`` [4H] given) the
+    layer input ``x_tb`` [T,B,D] as the layer got it, from which the kernel
+    makes ``z_t = (x_t W_x + b) + h_{t-1} W_h`` itself, so that the [T,B,4H]
+    float32 projection never crosses HBM; ``xp_dtype`` is the dtype
+    ``linear(x, w_x)`` would have given it.  The kernel rounds the products'
+    operands to the compute dtype in VMEM.  Mosaic requires the last two
     block dims tile-aligned or full, so time must lead; callers transpose
     once per layer.  ``residuals=False`` (inference / primal-only forward)
     skips the z/h_prev/c_prev outputs entirely — pallas_call is opaque to
@@ -241,16 +269,36 @@ def _lstm_pallas_raw(xp_tb, mask_tb, w_h, pi, pf, po, *,
     from jax.experimental.pallas import tpu as pltpu
 
     from paddle_tpu.ops.numerics import compute_dtype
+    from paddle_tpu.ops.rnn_fused import residual_dtype
 
-    T, B, H4 = xp_tb.shape
-    H = H4 // 4
+    T, B, D = x_tb.shape
+    H = w_h.shape[0]
+    H4 = 4 * H
+    project = w_x is not None
+    rd = residual_dtype(H)
     kernel = functools.partial(_lstm_kernel, hidden=H,
-                               mxu_dtype=compute_dtype())
+                               mxu_dtype=compute_dtype(),
+                               xp_dtype=jnp.dtype(xp_dtype), project=project)
     step = lambda t: (t, 0, 0)
+    resident = lambda t: (0, 0)
+    in_specs = [
+        pl.BlockSpec((1, B, D), step),
+        pl.BlockSpec((1, B, 1), step),
+        pl.BlockSpec((H, H4), resident),
+    ]
+    operands = [x_tb, mask_tb[..., None], w_h]
+    if project:
+        in_specs += [pl.BlockSpec((D, H4), resident),
+                     pl.BlockSpec((1, H4), resident)]
+        # the bias as the projection's dtype holds it, widened once here
+        operands += [w_x, b.astype(xp_dtype).astype(jnp.float32)
+                     .reshape(1, H4)]
+    in_specs += [pl.BlockSpec((1, H), resident)] * 3
+    operands += [pi.reshape(1, H), pf.reshape(1, H), po.reshape(1, H)]
     out_specs = [
         pl.BlockSpec((1, B, H), step),
-        pl.BlockSpec((B, H), lambda t: (0, 0)),
-        pl.BlockSpec((B, H), lambda t: (0, 0)),
+        pl.BlockSpec((B, H), resident),
+        pl.BlockSpec((B, H), resident),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((T, B, H), jnp.float32),
@@ -258,9 +306,6 @@ def _lstm_pallas_raw(xp_tb, mask_tb, w_h, pi, pf, po, *,
         jax.ShapeDtypeStruct((B, H), jnp.float32),
     ]
     if residuals:
-        from paddle_tpu.ops.rnn_fused import residual_dtype
-
-        rd = residual_dtype(H)
         out_specs += [
             pl.BlockSpec((1, B, H4), step),
             pl.BlockSpec((1, B, H), step),
@@ -275,14 +320,7 @@ def _lstm_pallas_raw(xp_tb, mask_tb, w_h, pi, pf, po, *,
         kernel,
         name="lstm_seq_fwd",
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, B, H4), step),
-            pl.BlockSpec((1, B, 1), step),
-            pl.BlockSpec((H, H4), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-            pl.BlockSpec((1, H), lambda t: (0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -292,8 +330,7 @@ def _lstm_pallas_raw(xp_tb, mask_tb, w_h, pi, pf, po, *,
         compiler_params=_compiler_params(
             vmem_limit_bytes=RNN_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
-    )(xp_tb, mask_tb[..., None], w_h, pi.reshape(1, H), pf.reshape(1, H),
-      po.reshape(1, H))
+    )(*operands)
 
 
 def _lstm_reference(xp, mask, w_h):

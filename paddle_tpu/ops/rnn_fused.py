@@ -39,6 +39,25 @@ models).  Three structural changes vs XLA's autodiff of the time scan:
    gets ``d_xp`` in float32, unrounded, as does the lax.scan backward, where
    narrowing would be one more pass and save none.  The GRU's ``d_xp`` stays
    float32: its bias gradient is still an XLA reduction over it.
+5. (LSTM) Given the same operands, the forward kernel makes the input
+   projection too: it takes ``x`` time-major [T,B,D] as the layer got it,
+   ``w_x`` [D,4H] (resident, like ``w_h``) and ``b``, and forms
+   ``z_t = (x_t W_x + b) + h_{t-1} W_h`` itself: the operands, the float32
+   accumulation and the two float32 additions of ``linear()`` and the carry
+   product, in their order.  The [T,B,4H] float32 projection, which XLA
+   would write and the kernel read straight back, never crosses HBM, and
+   nothing else wants it: the backward works from ``z``, ``h_prev``,
+   ``c_prev`` and from ``x`` itself, and ``jax.vjp(linear, x, w_x)`` with
+   its primal result unread is two transposes and no product.  A layer
+   stacked on another reads that layer's [T,B,H] kernel output where it
+   lies.  The kernel is handed the projection, as before, by a caller
+   that made it (``w_x=None``: ``lstmemory(projected_input=True)``,
+   ``lstm_forward_pallas``), and where ``rnn_kernel_ok(proj_dim=D)`` says
+   no: below B = D/4 rows, where passing [D,4H] through the MXU on every
+   time step costs more than the [B,4H] block's way through HBM (measured
+   on the v5e: PERF.md section 6, PR 34), or where ``w_x`` does not fit
+   VMEM beside ``w_h``.  The lax.scan path (CPU, a boot state, ``reset``, a
+   shape past the gate, ``xla_paths_only()``) makes it with ``linear()``.
 
 Semantics match ``scan_rnn`` + ``gru_step``/``lstm_step`` exactly (carry
 held and outputs zeroed at masked steps); equivalence is pinned by
@@ -88,15 +107,20 @@ from paddle_tpu.ops.numerics import bwd_mm as _bwd_mm  # noqa: E402
 
 
 def rnn_kernel_ok(batch: int, hidden: int, gates: int, *,
-                  backward: bool = False) -> bool:
+                  backward: bool = False,
+                  proj_dim: int | None = None) -> bool:
     """The recurrent time-loop kernels' gate, forward and (``backward``)
     reverse: True for the Pallas kernel, False for the lax.scan path.
-    ``gates``: 4 = LSTM, 3 = GRU.  Needs the TPU backend and tile-aligned
-    shapes: the kernels slice gate blocks out of [B, gates*H], so H must
-    fill whole 128-lane tiles and B whole 8-sublane tiles or Mosaic rejects
-    the lowering.  The callers see to the rest: the default-activation cell
-    and a zero boot state (peepholes are supported in-kernel; reverse rides
-    a flip upstream)."""
+    ``gates``: 4 = LSTM, 3 = GRU.  ``proj_dim`` = D asks for the LSTM
+    forward kernel that makes the input projection of a [B,T,D] input
+    itself (module docstring, point 5): refused where the resident [D,4H]
+    input matrix does not fit beside the recurrent one, and the forward then
+    asks again without it.  Needs the TPU backend and tile-aligned shapes:
+    the kernels slice gate blocks out of [B, gates*H], so H must fill whole
+    128-lane tiles and B whole 8-sublane tiles or Mosaic rejects the
+    lowering.  The callers see to the rest: the default-activation cell and
+    a zero boot state (peepholes are supported in-kernel; reverse rides a
+    flip upstream)."""
     from paddle_tpu.ops.pallas_kernels import (RNN_VMEM_LIMIT_BYTES,
                                                compiled_kernels,
                                                rnn_vmem_bytes)
@@ -111,13 +135,22 @@ def rnn_kernel_ok(batch: int, hidden: int, gates: int, *,
     # (tests/test_tpu_compile.py); beyond it the scan path runs
     if batch * hidden > 384 * 512:
         return False
+    # the kernel's own projection streams the [D, 4H] input matrix through
+    # the MXU on every time step, which costs the same for any B up to about
+    # 270 rows, and spares the [B, 4H] block's way out to HBM and back,
+    # which grows with B: measured on the v5e, it loses 40% at B = D/8
+    # (B64 H512 D512, B16 H1024 D1024) and wins from B = D/4 on (PERF.md
+    # section 6, PR 34)
+    if proj_dim is not None and 4 * batch < proj_dim:
+        return False
     # what the kernel keeps in VMEM (the resident [H, gates*H] weight grows
     # with H^2, the per-step blocks with B*H; the reverse kernel's z + d_z
     # blocks make its working set the larger) must fit the scoped limit the
     # kernels ask for
     need = rnn_vmem_bytes(
         batch, hidden, gates, backward=backward,
-        residual_itemsize=jnp.dtype(residual_dtype(hidden)).itemsize)
+        residual_itemsize=jnp.dtype(residual_dtype(hidden)).itemsize,
+        proj_dim=proj_dim)
     return need <= RNN_VMEM_LIMIT_BYTES
 
 
@@ -320,38 +353,55 @@ def lstm_sequence_fused(x, b, mask, w_h, h0, c0, pi, pf, po,
     backward skip the d_peep reductions when the caller statically knows
     the peepholes are zeros."""
     # primal-only call (inference): residual-free variant — see GRU twin
-    xp = x if w_x is None else linear(x, w_x)
-    h_seq, h_fin, c_fin = _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf,
-                                         po, allow_pallas,
+    h_seq, h_fin, c_fin = _lstm_core_fwd(x, w_x, b, mask, w_h, h0, c0, pi,
+                                         pf, po, allow_pallas,
                                          residuals=False)[:3]
     return h_seq, h_fin, c_fin
 
 
-def _lstm_core_fwd(xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
-                   residuals=True):
-    # the same add, in the same place, as linear(x, w_x, b) makes: XLA fuses
-    # it into the projection's output
-    xp = xp + b.astype(xp.dtype)
-    if allow_pallas:
-        B, T, H4 = xp.shape
-        H = H4 // 4
-        if rnn_kernel_ok(B, H, 4):
-            from paddle_tpu.ops.pallas_kernels import _lstm_pallas_raw
+def _lstm_core_fwd(x, w_x, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas, *,
+                   residuals=True, xp=None):
+    """``xp``: ``linear(x, w_x)`` where the caller has made it already (the
+    VJP's forward, which keeps its transpose)."""
+    from paddle_tpu.ops.numerics import dot_dtype
 
-            xp_tb = jnp.moveaxis(xp.astype(jnp.float32), 1, 0)
-            m_tb = jnp.moveaxis(mask.astype(jnp.float32), 1, 0)
-            outs = _lstm_pallas_raw(xp_tb, m_tb, w_h.astype(jnp.float32),
-                                    pi.astype(jnp.float32),
-                                    pf.astype(jnp.float32),
-                                    po.astype(jnp.float32),
-                                    residuals=residuals)
-            h_tb, h_fin, c_fin = outs[0], outs[1], outs[2]
-            z_r, hprev_r, cprev_r = (
-                (outs[3], outs[4], outs[5]) if residuals
-                else (None, None, None))
-            return (jnp.moveaxis(h_tb, 0, 1), h_fin, c_fin,
-                    z_r, hprev_r, cprev_r)
-    out = _lstm_fwd_scan(xp, mask, w_h, h0, c0, pi, pf, po)
+    def projection():
+        # the same add, in the same place, as linear(x, w_x, b) makes: XLA
+        # fuses it into the projection's output
+        p = xp
+        if p is None:
+            p = x if w_x is None else linear(x, w_x)
+        return p + b.astype(p.dtype)
+
+    B, H = mask.shape[0], w_h.shape[0]
+    # the forward kernel makes the projection where the op has its operands
+    # and the input matrix fits beside the recurrent one (point 5)
+    owned = (allow_pallas and w_x is not None
+             and rnn_kernel_ok(B, H, 4, proj_dim=x.shape[-1]))
+    if owned or (allow_pallas and rnn_kernel_ok(B, H, 4)):
+        from paddle_tpu.ops.pallas_kernels import _lstm_pallas_raw
+
+        f32 = jnp.float32
+        if owned:
+            # x as the layer got it (the kernel rounds its block in VMEM): a
+            # layer stacked on another reads that one's [T,B,H] output
+            # where it lies, with no pass in between
+            x_in, proj = x, dict(w_x=w_x.astype(f32), b=b,
+                                 xp_dtype=dot_dtype())
+        else:
+            x_in, proj = projection().astype(f32), {}
+        outs = _lstm_pallas_raw(
+            jnp.moveaxis(x_in, 1, 0),
+            jnp.moveaxis(mask.astype(f32), 1, 0), w_h.astype(f32),
+            pi.astype(f32), pf.astype(f32), po.astype(f32),
+            residuals=residuals, **proj)
+        h_tb, h_fin, c_fin = outs[0], outs[1], outs[2]
+        z_r, hprev_r, cprev_r = (
+            (outs[3], outs[4], outs[5]) if residuals
+            else (None, None, None))
+        return (jnp.moveaxis(h_tb, 0, 1), h_fin, c_fin,
+                z_r, hprev_r, cprev_r)
+    out = _lstm_fwd_scan(projection(), mask, w_h, h0, c0, pi, pf, po)
     return out if residuals else (out[0], out[1], out[2], None, None, None)
 
 
@@ -359,10 +409,12 @@ def _lstm_seq_fwd(x, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas,
                   has_peepholes, w_x):
     # the projection's transpose travels as autodiff writes it for the
     # linear() the forward ran (one operand policy, ops/matmul.py), holding
-    # the compute-dtype copies of x and w_x it multiplies with
+    # the compute-dtype copies of x and w_x it multiplies with.  Where the
+    # forward kernel makes the projection, nothing reads this primal result
+    # and XLA drops the product
     xp, proj_vjp = (x, None) if w_x is None else jax.vjp(linear, x, w_x)
     h_seq, h_fin, c_fin, z_tb, hprev_tb, cprev_tb = _lstm_core_fwd(
-        xp, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas)
+        x, w_x, b, mask, w_h, h0, c0, pi, pf, po, allow_pallas, xp=xp)
     meta = (jnp.zeros((0,), xp.dtype), jnp.zeros((0,), h0.dtype),
             jnp.zeros((0,), c0.dtype),
             jnp.zeros((0,), b.dtype))  # dtype sentinels (see GRU fwd)

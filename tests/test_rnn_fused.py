@@ -1,7 +1,8 @@
 """Fused-backward GRU/LSTM sequence ops vs scan_rnn autodiff — values and
 gradients, covering masks, reverse (flip routing in gru_layer/lstm_layer),
-non-zero boot state, and the LSTM's reverse Pallas kernel (interpret mode)
-with the bias and peephole gradients it accumulates in its time loop."""
+non-zero boot state, the LSTM's reverse Pallas kernel (interpret mode) with
+the bias and peephole gradients it accumulates in its time loop, and its
+forward kernel with the input projection it makes itself."""
 
 import numpy as np
 import jax
@@ -205,11 +206,15 @@ class TestLstmFusedPeepholes:
                                        rtol=1e-4, atol=1e-5, err_msg=name)
 
 
-def _backward_kernel(monkeypatch, on):
-    """Which reverse loop lstm_sequence_fused(..., allow_pallas=True) takes:
-    the Pallas kernel (interpret mode here) or the lax.scan."""
-    monkeypatch.setattr("paddle_tpu.ops.rnn_fused.rnn_kernel_ok",
-                        lambda B, H, gates, backward=False: backward and on)
+def _backward_kernel(monkeypatch, on, forward=False):
+    """Which loops lstm_sequence_fused(..., allow_pallas=True) takes: the
+    reverse Pallas kernel (interpret mode here) or the lax.scan, and
+    (``forward``) the forward kernel, which makes the input projection where
+    the op has its operands, or the lax.scan."""
+    monkeypatch.setattr(
+        "paddle_tpu.ops.rnn_fused.rnn_kernel_ok",
+        lambda B, H, gates, backward=False, proj_dim=None:
+        on if backward else forward)
 
 
 class TestLstmKernelBackward:
@@ -219,7 +224,9 @@ class TestLstmKernelBackward:
     B, T, H = 16, 6, 8            # two sublane tiles of rows
     RAGGED = (6, 3, 1, 5, 2, 6, 4, 1, 6, 1, 2, 3, 5, 4, 6, 2)
 
-    @pytest.mark.parametrize("projection", ["caller", "op"])
+    @pytest.mark.parametrize("projection", [
+        "caller", "op", "forward_kernel_xp", "forward_kernel_d128",
+        "forward_kernel_dH"])
     @pytest.mark.parametrize("residuals", ["float32", "bfloat16"])
     @pytest.mark.parametrize("peepholes", [True, False],
                              ids=["peepholes", "plain"])
@@ -238,17 +245,26 @@ class TestLstmKernelBackward:
         backward over the same residuals.  Under the bf16 policy an op that
         owns the projection stores d_z in bf16 on the kernel path, which
         reaches the three products that read it (dx, d_w_x, d_w_h) and
-        nothing else; a caller's d_xp is never rounded."""
+        nothing else; a caller's d_xp is never rounded.  The
+        ``forward_kernel`` cases run the forward kernel too, handed the
+        caller's projection (``_xp``) or ``x`` [B,T,D], ``w_x`` and ``b``, from
+        which it makes the projection itself, for D=128 and D=H; there the
+        primal-only call (no residuals) is compared as well."""
         rs = np.random.RandomState(7)
-        B, T, H, D = self.B, self.T, self.H, 5
+        B, T, H = self.B, self.T, self.H
+        D = {"forward_kernel_d128": 128, "forward_kernel_dH": H}.get(
+            projection, 5)
         monkeypatch.setattr(FLAGS, "compute_dtype", residuals)
         r = lambda *shape, scale=1.0: jnp.asarray(  # noqa: E731
             scale * rs.randn(*shape).astype(np.float32))
-        owned = projection == "op"
+        forward = projection.startswith("forward_kernel")
+        owned = projection not in ("caller", "forward_kernel_xp")
         x = r(B, T, D) if owned else r(B, T, 4 * H)
         wx = r(D, 4 * H, scale=0.4) if owned else None
         b, wh = r(4 * H, scale=0.3), r(H, 4 * H, scale=0.4)
         h0, c0 = r(B, H), r(B, H)
+        if forward:             # the forward kernel boots from zeros
+            h0, c0 = jnp.zeros_like(h0), jnp.zeros_like(c0)
         peeps = tuple(r(H, scale=0.3 * peepholes) for _ in range(3))
         ct_seq, ct_h, ct_c = r(B, T, H), r(B, H), r(B, H)
         mask = _mask(lens, T)
@@ -276,11 +292,14 @@ class TestLstmKernelBackward:
         every = tuple(range(9 if owned else 8))
         argnums = every if peepholes else (0, 1, 2, 3, 4) + every[8:]
         g_ref = dict(zip(argnums, jax.grad(ref, argnums)(*args)))
-        _backward_kernel(monkeypatch, False)
+        _backward_kernel(monkeypatch, False, forward)
         g_scan = dict(zip(argnums, jax.grad(new, argnums)(*args)))
-        _backward_kernel(monkeypatch, True)
+        _backward_kernel(monkeypatch, True, forward)
         g_new = jax.grad(new, every)(*args)
         tol = 1e-4 if residuals == "float32" else 3e-2
+        if forward:     # outputs and finals of the residual-free kernel
+            np.testing.assert_allclose(float(new(*args)), float(ref(*args)),
+                                       rtol=tol)
         for n in argnums:
             a, s, k = g_ref[n], g_scan[n], g_new[n]
             scale = float(jnp.max(jnp.abs(a)))
@@ -335,11 +354,15 @@ class TestLstmKernelBackward:
         dict(reverse=True), dict(use_peepholes=False), dict(bias_attr=False),
         dict(projected_input=True)],
         ids=["reverse", "no_peepholes", "no_bias", "projected_input"])
+    @pytest.mark.parametrize("forward", [False, True],
+                             ids=["scan_forward", "kernel_forward"])
     def test_lstmemory_options_on_the_kernel_path(self, monkeypatch,
-                                                  options):
+                                                  options, forward):
         """nn.lstmemory's variants reach the kernel path with the right
         operands: the flip for ``reverse``, no peephole accumulators, a
-        bias that is no parameter, an input that is the projection."""
+        bias that is no parameter, an input that is the projection (which
+        the forward kernel then takes as it is, where the others hand it
+        ``x``, ``w_x`` and the bias)."""
         rs = np.random.RandomState(11)
         B, T, H = self.B, self.T, self.H
         D = 4 * H if options.get("projected_input") else 5
@@ -375,7 +398,7 @@ class TestLstmKernelBackward:
                                      _mask(lengths, T), reverse=reverse)
             return jnp.sum(seq * ct) + jnp.sum(f) + 2.0 * jnp.sum(c)
 
-        _backward_kernel(monkeypatch, True)
+        _backward_kernel(monkeypatch, True, forward)
         np.testing.assert_allclose(float(new(params, xs)),
                                    float(ref(params, xs)), rtol=1e-5)
         g_new = jax.grad(new, (0, 1))(params, xs)
@@ -387,6 +410,35 @@ class TestLstmKernelBackward:
                 rtol=1e-4, atol=1e-5, err_msg=name)
         np.testing.assert_allclose(np.asarray(g_new[1]), np.asarray(g_ref[1]),
                                    rtol=1e-4, atol=1e-5, err_msg="x")
+
+    @pytest.mark.parametrize("xp_dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("D", [128, 8], ids=["d128", "dH"])
+    def test_forward_kernel_rounds_the_projection_as_linear_does(self, D,
+                                                                 xp_dtype):
+        """The projection the forward kernel makes, read off its first
+        pre-activations (the carry is zero there): linear(x, w_x) + b with
+        the operands in the compute dtype and the result in ``xp_dtype``,
+        float32 by default and bf16 under --amp, where the product and then
+        the sum are each rounded to it."""
+        from paddle_tpu.ops.pallas_kernels import _lstm_pallas_raw
+
+        rs = np.random.RandomState(13)
+        B, T, H = self.B, 2, self.H
+        r = lambda *shape, scale=1.0: jnp.asarray(  # noqa: E731
+            scale * rs.randn(*shape).astype(np.float32))
+        x, wx, b, wh = r(T, B, D), r(D, 4 * H, scale=0.4), r(4 * H), r(H, 4 * H)
+        dt = jnp.dtype(xp_dtype)
+        z = _lstm_pallas_raw(x, jnp.ones((T, B)), wh, *(jnp.zeros(H),) * 3,
+                             w_x=wx, b=b, xp_dtype=dt)[3]
+        want = jnp.dot(x[0], wx, preferred_element_type=dt) + b.astype(dt)
+        assert want.dtype == dt and z.dtype == jnp.float32
+        ulp = 2.0 ** -8 if xp_dtype == "bfloat16" else 2.0 ** -22
+        np.testing.assert_allclose(np.asarray(z[0]),
+                                   np.asarray(want.astype(jnp.float32)),
+                                   rtol=ulp, atol=ulp)
+        if xp_dtype == "bfloat16":      # z is a bf16 value, widened
+            np.testing.assert_array_equal(
+                np.asarray(z[0]), np.asarray(z[0].astype(dt).astype(z.dtype)))
 
     def test_only_the_three_products_read_d_z(self, monkeypatch):
         """Structure of the kernel path's backward: XLA reads a [T,B,4H] or

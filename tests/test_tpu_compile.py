@@ -13,6 +13,7 @@ them with monkeypatch, and compile under the production bf16 policy.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -217,11 +218,12 @@ def test_lstm_reverse_kernel_reduces_bias_and_peepholes(batch, peepholes,
 def test_lstm_stack_backward_keeps_d_z_narrow(one_chip, on_tpu):
     """Two LSTM layers at the benchmark cell's tile (B256 H512, embedding
     128), each with its input projection: in the optimised program both
-    ``lstm_seq_bwd`` calls hand ``d_z`` on as ``bf16[T,B,4H]``, and once the
-    backward has begun nothing produces a float32 array of that size, in
-    either layout: the widening that the projection's transpose asks of
-    ``d_xp`` fuses into the operand reads of ``dx`` and ``dW_x``.  The forward's two
-    projections do write one each, which shows that the search sees them."""
+    ``lstm_seq_bwd`` calls hand ``d_z`` on as ``bf16[T,B,4H]``, and nothing
+    in the whole step produces a float32 array of that size, in either
+    layout.  Forward: both ``lstm_seq_fwd`` calls take the layer's input as
+    ``f32[T,B,D]`` and make the projection themselves.  Backward: the
+    widening that the projection's transpose asks of ``d_xp`` fuses into the
+    operand reads of ``dx`` and ``dW_x``."""
     from paddle_tpu.ops.rnn import lstm_layer
 
     batch, steps, emb, hidden = 256, 24, 128, 512
@@ -240,18 +242,97 @@ def test_lstm_stack_backward_keeps_d_z_narrow(one_chip, on_tpu):
         s(batch, steps, emb), s(batch, steps),
         [layer(emb), layer(hidden)]).compile().as_text()
     entry = text[text.index("\nENTRY "):].splitlines()
-    reverse = [i for i, line in enumerate(entry)
-               if "tpu_custom_call" in line and "lstm_seq_bwd" in line]
-    assert len(reverse) == 2
-    for i in reverse:
-        assert _first_result(entry[i]) == f"bf16[{steps},{batch},{4 * hidden}]"
+    calls = lambda name: [  # noqa: E731
+        line for line in entry if "tpu_custom_call" in line and name in line]
+    reverse, forward = calls("lstm_seq_bwd"), calls("lstm_seq_fwd")
+    assert len(reverse) == 2 and len(forward) == 2
+    for call in reverse:
+        assert _first_result(call) == f"bf16[{steps},{batch},{4 * hidden}]"
+    for call, d in zip(forward, (emb, hidden)):
+        operands = call.split(" custom-call(")[1]
+        assert f"f32[{steps},{batch},{d}]" in operands
+        assert f"[{steps},{batch},{4 * hidden}]" not in operands
     wide = (f"f32[{steps},{batch},{4 * hidden}]",
             f"f32[{batch},{steps},{4 * hidden}]")
-    made = lambda lines: sum(  # noqa: E731
-        any(w in line.split(" = ")[1].split("(%")[0] for w in wide)
-        for line in lines if " = " in line)
-    assert made(entry[:reverse[0]]) == 2
-    assert made(entry[reverse[0]:]) == 0
+    made = sum(any(w in line.split(" = ")[1].split("(%")[0] for w in wide)
+               for line in text.splitlines() if " = " in line)
+    assert made == 0
+
+
+@pytest.mark.parametrize("batch,hidden,in_dim", [
+    (256, 512, 128), (256, 512, 512), (384, 512, 512), (64, 1280, 128)],
+    ids=["cell_lstm0", "cell_lstm1", "gate_corner", "h1280"])
+def test_lstm_forward_kernel_makes_the_projection(batch, hidden, in_dim,
+                                                  one_chip, on_tpu,
+                                                  monkeypatch):
+    """The forward kernel that takes ``x`` [T,B,D], ``w_x`` and ``b`` in
+    place of the projection, at the benchmark cell's two layers, the gate's
+    corner and the widest published row (its first layer, on the embedding):
+    the gate admits it, the compiler accepts it in a differentiated step
+    (forward and reverse kernel; ``x`` and ``w_x`` into the forward one and
+    no [T,B,4H] operand), and, asked to fit it into 2 MiB, reports the
+    scoped VMEM that ``rnn_vmem_bytes`` counted, to within 1%."""
+    from paddle_tpu.ops import pallas_kernels, rnn_fused
+
+    steps = 20
+    assert rnn_fused.rnn_kernel_ok(batch, hidden, 4, proj_dim=in_dim)
+    zeros = jnp.zeros((batch, hidden), jnp.float32)
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+
+    def loss(x, w_x, b, mask, w_h, *peep):
+        return sum(o.sum() for o in rnn_fused.lstm_sequence_fused(
+            x, b, mask, w_h, zeros, zeros, *peep, True, True, w_x))
+
+    args = [s(batch, steps, in_dim), s(in_dim, 4 * hidden), s(4 * hidden),
+            s(batch, steps), s(hidden, 4 * hidden)] + [s(hidden)] * 3
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 4, 5, 6, 7)))
+    text = step.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    forward = next(line for line in text.splitlines()
+                   if "tpu_custom_call" in line and "lstm_seq_fwd" in line)
+    operands = forward.split(" custom-call(")[1]
+    assert f"f32[{steps},{batch},{in_dim}]" in operands
+    assert f"f32[{in_dim},{4 * hidden}]" in operands
+    assert f"[{steps},{batch},{4 * hidden}]" not in operands
+
+    need = pallas_kernels.rnn_vmem_bytes(
+        batch, hidden, 4, backward=False, proj_dim=in_dim,
+        residual_itemsize=jnp.dtype(rnn_fused.residual_dtype(hidden)).itemsize)
+    monkeypatch.setattr(pallas_kernels, "RNN_VMEM_LIMIT_BYTES", 2 * 2**20)
+
+    def kernel(x_tb, mask_tb, w_h, w_x, b, *peep):
+        return pallas_kernels._lstm_pallas_raw(x_tb, mask_tb, w_h, *peep,
+                                               w_x=w_x, b=b)
+
+    with pytest.raises(Exception, match="exceeded scoped vmem limit") as e:
+        jax.jit(kernel).lower(
+            s(steps, batch, in_dim), s(steps, batch), s(hidden, 4 * hidden),
+            s(in_dim, 4 * hidden), s(4 * hidden), *[s(hidden)] * 3).compile()
+    said = float(re.search(r"Scoped allocation with size ([\d.]+)M",
+                           str(e.value)).group(1))
+    assert abs(need / 2**20 - said) < 0.01 * said
+
+
+def test_lstm_projection_gate_is_a_function_of_the_shape(on_tpu):
+    """Where the forward kernel makes the projection: from B = D/4 rows on
+    (below that, streaming the input matrix through the MXU on every time
+    step costs more than the [B,4H] block's way through HBM: measured, PR
+    34), and where the [D,4H] matrix fits beside the recurrent one.  A shape
+    it refuses keeps the kernel it had, handed the projection."""
+    from paddle_tpu.ops.rnn_fused import rnn_kernel_ok
+
+    for batch, hidden, in_dim, makes_it in [
+            (256, 512, 128, True), (256, 512, 512, True),
+            (64, 256, 256, True), (64, 1280, 128, True),
+            (64, 512, 512, False), (64, 1280, 1280, False),
+            (16, 1024, 1024, False),
+            (152, 1280, 608, True),       # 50.9 MiB
+            (144, 1280, 3072, False)]:    # 4 B < D; and 101 MiB
+        assert rnn_kernel_ok(batch, hidden, 4)
+        assert rnn_kernel_ok(batch, hidden, 4, proj_dim=in_dim) == makes_it
+    assert rnn_kernel_ok(72, 1792, 4, proj_dim=128)
+    assert not rnn_kernel_ok(72, 1792, 4, proj_dim=256)   # 65.0 MiB
+    assert not rnn_kernel_ok(392, 512, 4, proj_dim=128)   # past B*H cap
 
 
 def test_attention_decoder_forward_and_backward(one_chip, on_tpu):

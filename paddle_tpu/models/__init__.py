@@ -5,3 +5,5 @@ from paddle_tpu.models.seq2seq import Seq2SeqAttention
 from paddle_tpu.models.recommender import movielens_net, movielens_feature_net
 from paddle_tpu.models.image_bench import alexnet, googlenet
 from paddle_tpu.models.lfm2 import lfm2_moe_net
+from paddle_tpu.models.decoder import decoder_stack
+from paddle_tpu.models.kanana2 import kanana2_moe_net

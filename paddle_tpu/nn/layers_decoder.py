@@ -1,7 +1,9 @@
 """Layers of a decoder-only block: RMSNorm, a gated short causal convolution,
-causal grouped-query self-attention with QK-norm and rotary embedding, a
-gated MLP, a dropless expert layer that holds a share of the experts, and a
-next-token cost over a head tied to the embedding.
+causal grouped-query self-attention with QK-norm and rotary embedding, latent
+attention (keys and values made from one narrow latent a token), a gated MLP,
+a dropless expert layer that holds a share of the experts (with shared
+experts beside them, where the model has them), and a next-token cost over a
+head that is the embedding (tied) or a matrix of its own.
 
 The reference (2016) has none of these; they follow its DSL conventions all
 the same (``input=`` first, ``name=``, parameters ``_<name>.<leaf>``, one
@@ -26,7 +28,8 @@ from paddle_tpu.ops import moe as M
 from paddle_tpu.utils.error import ConfigError
 
 __all__ = ["rms_norm", "gated_short_conv", "causal_self_attention",
-           "gated_mlp", "expert_mlp", "lm_head_cost", "remat_block"]
+           "latent_attention", "gated_mlp", "expert_mlp", "lm_head_cost",
+           "remat_block"]
 
 
 def _fan_in(name: str, fan_in: int):
@@ -131,22 +134,91 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
                        specs)
 
 
+def latent_attention(input: LayerOutput, *, num_heads: int,
+                     kv_lora_rank: int, qk_nope_head_dim: int,
+                     qk_rope_head_dim: int, v_head_dim: int,
+                     rope_theta: float = 10000.0, norm_eps: float = 1e-6,
+                     name: Optional[str] = None) -> LayerOutput:
+    """Causal multi-head latent attention in the expanded form that training
+    uses.  A token's keys and values come from one latent of ``kv_lora_rank``
+    channels: ``[c | k_rope] = x W_kv_a``; ``c`` is RMS-normed (a weight of
+    its own) and projected up by ``W_kv_b`` to every head's ``k_nope``
+    (``qk_nope_head_dim``) and ``v`` (``v_head_dim``); ``k_rope``
+    (``qk_rope_head_dim``) is ONE head that every query head shares.  Queries
+    are ``x W_q`` split per head into ``q_nope | q_rope`` (no query latent).
+    The rotary embedding turns ``q_rope`` and ``k_rope`` only; scores are
+    ``[q_nope | q_rope] . [k_nope | k_rope]`` at scale ``(qk_nope_head_dim
+    + qk_rope_head_dim) ** -0.5``; no bias, no QK-norm.
+
+    Scopes inside the layer's own: ``mla_proj`` (the query projection, the
+    down- and up-projection, the latent's norm, rotary, assembling q and k)
+    and ``attn_core`` (``causal_attention`` with keys wider than values);
+    the output projection is the rest."""
+    name = name or next_name("latent_attention")
+    D, H = input.size, num_heads
+    r, dn, dr, dv = (kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+                     v_head_dim)
+    specs = [
+        ParamSpec(f"_{name}.wq", (D, H * (dn + dr)),
+                  _fan_in(f"_{name}.wq", D)),
+        ParamSpec(f"_{name}.wkv_a", (D, r + dr),
+                  _fan_in(f"_{name}.wkv_a", D)),
+        ParamSpec(f"_{name}.kv_norm", (r,),
+                  _pa(None, f"_{name}.kv_norm", init="ones")),
+        ParamSpec(f"_{name}.wkv_b", (r, H * (dn + dv)),
+                  _fan_in(f"_{name}.wkv_b", r)),
+        ParamSpec(f"_{name}.wo", (H * dv, D), _fan_in(f"_{name}.wo", H * dv)),
+    ]
+
+    def forward(ctx, params, a: Act) -> Act:
+        if not a.is_seq:
+            raise ConfigError(f"latent_attention {name!r} needs a sequence")
+        _refuse_packed(a, name, "latent_attention")
+        p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
+        x = a.value
+        B, T = x.shape[:2]
+        with jax.named_scope("mla_proj"):
+            q = O.linear(x, p["wq"]).reshape(B, T, H, dn + dr)
+            ckv = O.linear(x, p["wkv_a"])
+            c = DB.rms_norm(ckv[..., :r], p["kv_norm"], norm_eps)
+            k_rope = DB.rotary_embedding(
+                ckv[..., r:].reshape(B, T, 1, dr), rope_theta)
+            kv = O.linear(c, p["wkv_b"]).reshape(B, T, H, dn + dv)
+            q = jnp.concatenate(
+                [q[..., :dn], DB.rotary_embedding(q[..., dn:], rope_theta)],
+                axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_rope, (B, T, H, dr))],
+                axis=-1)
+            v = kv[..., dn:]
+        with jax.named_scope("attn_core"):
+            o = DB.causal_attention(q, k, v, scale=(dn + dr) ** -0.5)
+        return _seq_like(a, O.linear(o.reshape(B, T, H * dv), p["wo"]))
+
+    return LayerOutput(name, "latent_attention", D, [input], forward, specs)
+
+
+def _gated_mlp_specs(prefix: str, D: int, size: int):
+    return [
+        ParamSpec(f"{prefix}w1", (D, size), _fan_in(f"{prefix}w1", D)),
+        ParamSpec(f"{prefix}w3", (D, size), _fan_in(f"{prefix}w3", D)),
+        ParamSpec(f"{prefix}w2", (size, D), _fan_in(f"{prefix}w2", size)),
+    ]
+
+
+def _gated_mlp(x, w1, w3, w2):
+    return O.linear(jax.nn.silu(O.linear(x, w1)) * O.linear(x, w3), w2)
+
+
 def gated_mlp(input: LayerOutput, size: int, *,
               name: Optional[str] = None) -> LayerOutput:
     """``W_2(silu(W_1 x) * W_3 x)`` with ``size`` hidden units."""
     name = name or next_name("gated_mlp")
     D = input.size
-    specs = [
-        ParamSpec(f"_{name}.w1", (D, size), _fan_in(f"_{name}.w1", D)),
-        ParamSpec(f"_{name}.w3", (D, size), _fan_in(f"_{name}.w3", D)),
-        ParamSpec(f"_{name}.w2", (size, D), _fan_in(f"_{name}.w2", size)),
-    ]
+    specs = _gated_mlp_specs(f"_{name}.", D, size)
 
     def forward(ctx, params, a: Act) -> Act:
-        x = a.value
-        gate = jax.nn.silu(O.linear(x, params[specs[0].name]))
-        out = O.linear(gate * O.linear(x, params[specs[1].name]),
-                       params[specs[2].name])
+        out = _gated_mlp(a.value, *(params[s.name] for s in specs))
         return _seq_like(a, out) if a.is_seq else Act(value=out)
 
     return LayerOutput(name, "gated_mlp", D, [input], forward, specs)
@@ -155,6 +227,7 @@ def gated_mlp(input: LayerOutput, size: int, *,
 def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                experts_held: Optional[Sequence[int]] = None, top_k: int,
                norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
+               shared_size: int = 0,
                name: Optional[str] = None) -> LayerOutput:
     """A dropless mixture of gated-MLP experts of ``size`` hidden units, as
     the chip that holds experts ``experts_held = (first, count)`` of
@@ -164,6 +237,11 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
     layer's value is the part of the result that the experts held give; no
     assignment to an expert held is dropped, whatever the routing.  On one
     chip there is no exchange, and nothing stands in for the other chips.
+
+    ``shared_size``: the hidden units of the shared experts, ONE gated MLP
+    (``_<name>.shared_w1`` / ``w3`` / ``w2``) that every token passes
+    through, unweighted, added to the routed result; every chip of the
+    deployment computes it whole on its own tokens.  Scope ``moe_shared``.
 
     ``Act.state`` carries ``expert_load`` (assignments per expert held,
     int32) and ``uncomputed`` (assignments to an expert held that no row
@@ -183,6 +261,8 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
         ParamSpec(f"_{name}.w2", (held, size, D),
                   _fan_in(f"_{name}.w2", size)),
     ]
+    shared = (_gated_mlp_specs(f"_{name}.shared_", D, shared_size)
+              if shared_size else [])
 
     def forward(ctx, params, a: Act) -> Act:
         p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
@@ -197,6 +277,9 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
         y, load, uncomputed = M.expert_layer(
             x, idx, weights, p["w1"], p["w3"], p["w2"], num_experts=E,
             first_expert=first, tm=tm or 8, kernels=tm is not None)
+        if shared:
+            with jax.named_scope("moe_shared"):
+                y = y + _gated_mlp(x, *(params[s.name] for s in shared))
         y = y.reshape(a.value.shape)
         state = {"expert_load": load, "uncomputed": uncomputed}
         if a.is_seq:
@@ -205,29 +288,37 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
             return out
         return Act(value=y, state=state)
 
-    return LayerOutput(name, "expert_mlp", D, [input], forward, specs)
+    return LayerOutput(name, "expert_mlp", D, [input], forward,
+                       specs + shared)
 
 
 def lm_head_cost(input: LayerOutput, label: LayerOutput, *,
-                 embedding: LayerOutput,
+                 embedding: Optional[LayerOutput] = None,
                  name: Optional[str] = None) -> LayerOutput:
-    """Mean next-token cross-entropy over the real positions, the head
-    being the embedding matrix of ``embedding`` (tied): ``logits = h E^T``.
-    Through ``ops.sequence_softmax_ce_readout``, so the logits are held once,
-    in the compute dtype."""
+    """Mean next-token cross-entropy over the real positions.  With
+    ``embedding`` the head is that layer's matrix (tied): ``logits = h
+    E^T``.  Without, the head is a parameter of this layer, ``_<name>.w``
+    ``[hidden, label.size]`` (untied): ``logits = h W``.  Through
+    ``ops.sequence_softmax_ce_readout``, so the logits are held once, in the
+    compute dtype."""
     name = name or next_name("lm_cost")
-    table = embedding.param_specs[0]
-    if table.shape[1] != input.size:
-        raise ConfigError(f"{name!r}: the embedding is {table.shape[1]} wide, "
-                          f"the hidden state {input.size}")
+    if embedding is None:
+        head = ParamSpec(f"_{name}.w", (input.size, label.size),
+                         _fan_in(f"_{name}.w", input.size))
+    else:
+        head = embedding.param_specs[0]
+        if head.shape[1] != input.size:
+            raise ConfigError(f"{name!r}: the embedding is {head.shape[1]} "
+                              f"wide, the hidden state {input.size}")
 
     def forward(ctx, params, h: Act, lab: Act) -> Act:
-        w = params[table.name].T
+        w = params[head.name]
+        w = w if embedding is None else w.T
         return Act(value=O.sequence_softmax_ce_readout(
             h.value, w, jnp.zeros((w.shape[1],), w.dtype), lab.value, h.mask))
 
     return LayerOutput(name, "lm_head_cost", 1, [input, label], forward,
-                       [table])
+                       [head])
 
 
 from paddle_tpu.config.capture import wrap_module as _wrap_module  # noqa: E402

@@ -71,15 +71,19 @@ def causal_short_conv(z, kernel):
 # ---------------------------------------------------------------------------
 
 
-def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int):
+def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None):
     """The flash kernels' gate: ``(block_q, block_k)`` or ``None`` for the
-    XLA path.  Needs the TPU backend, a row length the blocks divide, a head
-    size that is a multiple of 64 and whole groups of query heads."""
+    XLA path.  ``dh`` is the width of a query and key head, ``dv`` that of a
+    value head (``dh`` where not given).  Needs the TPU backend, a row length
+    the blocks divide, both widths multiples of 64 (a block's minor axis is
+    the whole head, so 192 beside 128 is as good as 64 beside 64) and whole
+    groups of query heads."""
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
 
     if not compiled_kernels():
         return None
-    if dh % 64 or H % Hkv:
+    dv = dh if dv is None else dv
+    if dh % 64 or dv % 64 or H % Hkv:
         return None
     for blk in (1024, 512, 256, 128):
         if T % blk == 0 and T >= 2 * blk:
@@ -88,8 +92,9 @@ def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int):
 
 
 def _xla_fwd(q, k, v, scale, block):
-    """q ``[B, T, Hkv, G, dh]``, k/v ``[B, T, Hkv, dh]`` in the compute dtype
-    -> (out like q in float32, lse ``[B, Hkv, G, T]``)."""
+    """q ``[B, T, Hkv, G, dh]``, k ``[B, T, Hkv, dh]``, v ``[B, T, Hkv, dv]``
+    in the compute dtype -> (out ``[B, T, Hkv, G, dv]`` in float32, lse
+    ``[B, Hkv, G, T]``)."""
     T = q.shape[1]
     f32 = acc_dtype()
     outs, lses = [], []
@@ -145,10 +150,10 @@ def _attention(q, k, v, scale):
 
 def _attention_fwd(q, k, v, scale):
     B, T, H, dh = q.shape
-    Hkv = k.shape[2]
+    Hkv, dv = k.shape[2], v.shape[3]
     qc, kc, vc = mxu_cast(q, k, v)
     like = tuple(jnp.zeros((0,), a.dtype) for a in (q, k, v))
-    blocks = attention_kernel_blocks(T, dh, H, Hkv)
+    blocks = attention_kernel_blocks(T, dh, H, Hkv, dv)
     if blocks is not None:
         from paddle_tpu.ops.pallas_kernels import flash_attn_fwd_pallas
 
@@ -163,7 +168,7 @@ def _attention_fwd(q, k, v, scale):
         return out.astype(dot_dtype()), (qh, kh, vh, oh, lse, like)
     qg = qc.reshape(B, T, Hkv, H // Hkv, dh)
     out, lse = _xla_fwd(qg, kc, vc, scale, ATTN_XLA_BLOCK)
-    return (out.reshape(B, T, H, dh).astype(dot_dtype()),
+    return (out.reshape(B, T, H, dv).astype(dot_dtype()),
             (qg, kc, vc, out, lse, like))
 
 
@@ -173,7 +178,7 @@ def _attention_bwd(scale, res, d_out):
         from paddle_tpu.ops.pallas_kernels import flash_attn_bwd_pallas
 
         blocks = attention_kernel_blocks(q.shape[2], q.shape[3], q.shape[1],
-                                         k.shape[1])
+                                         k.shape[1], v.shape[3])
         do = jnp.swapaxes(d_out, 1, 2).astype(q.dtype)
         dq, dk, dv = flash_attn_bwd_pallas(
             q, k, v, out, lse, do, scale=scale, block_q=blocks[0],
@@ -182,7 +187,7 @@ def _attention_bwd(scale, res, d_out):
                      for a, z in zip((dq, dk, dv), like))
     B, T, Hkv, G, dh = q.shape
     dq, dk, dv = _xla_bwd(q, k, v, out, lse,
-                          d_out.reshape(B, T, Hkv, G, dh), scale,
+                          d_out.reshape(B, T, Hkv, G, v.shape[3]), scale,
                           ATTN_XLA_BLOCK)
     return tuple(a.astype(z.dtype) for a, z in zip(
         (dq.reshape(B, T, Hkv * G, dh), dk, dv), like))
@@ -193,8 +198,10 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 
 def causal_attention(q, k, v, *, scale: float):
     """Causal softmax attention with grouped key-value heads: q ``[B, T, H,
-    dh]``, k/v ``[B, T, Hkv, dh]`` (key-value head ``j`` serves query heads
-    ``j*G .. j*G+G-1``, ``G = H / Hkv``) -> ``[B, T, H, dh]``.  bf16
-    operands under the default policy, float32 scores and statistics; the
-    scores exist one block at a time, forward and backward."""
+    dh]``, k ``[B, T, Hkv, dh]``, v ``[B, T, Hkv, dv]`` (key-value head ``j``
+    serves query heads ``j*G .. j*G+G-1``, ``G = H / Hkv``) -> ``[B, T, H,
+    dv]``.  The values' width is their own: latent attention scores with
+    192-wide queries and keys and sums 128-wide values.  bf16 operands under
+    the default policy, float32 scores and statistics; the scores exist one
+    block at a time, forward and backward."""
     return _attention(q, k, v, float(scale))

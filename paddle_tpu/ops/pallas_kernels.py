@@ -1548,8 +1548,10 @@ def topk_lse_logits_pallas(logits, *, vocab: int, k: int, row_block: int,
 # ---------------------------------------------------------------------------
 # Causal flash attention (decoder-only blocks): forward, dq, dk/dv
 # ---------------------------------------------------------------------------
-# Heads-major operands: q/o/do [B, H, T, dh], k/v [B, Hkv, T, dh] (key-value
-# head j serves query heads j*G..j*G+G-1), lse [B, H, T, 1] float32.  A grid
+# Heads-major operands: q [B, H, T, dh], k [B, Hkv, T, dh], v [B, Hkv, T, dv],
+# o/do [B, H, T, dv] (key-value head j serves query heads j*G..j*G+G-1; dv is
+# dh in grouped-query attention, 128 beside 192 in latent attention), lse
+# [B, H, T, 1] float32.  A block's minor axis is the whole head.  A grid
 # step is one (block of queries, block of keys); blocks above the diagonal
 # are skipped, and their key/value (or query) block index is clamped to the
 # last one needed so that a skipped step moves nothing.  The scores of one
@@ -1602,11 +1604,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
                           block_k: int):
-    """-> (out like q, lse [B, H, T, 1] float32)."""
+    """-> (out [B, H, T, dv] in q's dtype, lse [B, H, T, 1] float32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, dh = q.shape
+    dv = v.shape[3]
     G = H // k.shape[1]
     nq, nk = T // block_q, T // block_k
 
@@ -1622,17 +1625,17 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, kj: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, dh), kv_map),
-            pl.BlockSpec((1, 1, block_k, dh), kv_map),
+            pl.BlockSpec((1, 1, block_k, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, kj: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b, h, qi, kj: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, qi, kj: (b, h, qi, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), q.dtype),
                    jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, dh), jnp.float32)],
+                        pltpu.VMEM((block_q, dv), jnp.float32)],
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -1712,7 +1715,8 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
                           block_q: int, block_k: int):
-    """-> (dq [B, H, T, dh], dk, dv [B, Hkv, T, dh]), float32.  Two kernels,
+    """-> (dq [B, H, T, dh], dk [B, Hkv, T, dh], dv [B, Hkv, T, dv]),
+    float32; ``o`` and ``do`` are [B, H, T, dv].  Two kernels,
     each recomputing a block pair's probabilities from ``lse``: one walks
     the keys of a block of queries (dq), one the queries (of every head of
     the group) of a block of keys (dk, dv)."""
@@ -1720,7 +1724,7 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, dh = q.shape
-    Hkv = k.shape[1]
+    Hkv, dv = k.shape[1], v.shape[3]
     G = H // Hkv
     nq, nk = T // block_q, T // block_k
     kw = dict(scale=scale, block_q=block_q, block_k=block_k)
@@ -1733,12 +1737,13 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
         return (b, h // G, jnp.minimum(kj, last), 0)
 
     q_spec = pl.BlockSpec((1, 1, block_q, dh), q_map)
-    kv_spec = pl.BlockSpec((1, 1, block_k, dh), kv_map)
+    o_spec = pl.BlockSpec((1, 1, block_q, dv), q_map)
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **kw),
         name="flash_attn_dq",
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec,
+        in_specs=[q_spec, pl.BlockSpec((1, 1, block_k, dh), kv_map),
+                  pl.BlockSpec((1, 1, block_k, dv), kv_map), o_spec, o_spec,
                   pl.BlockSpec((1, 1, block_q, 1), q_map)],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
@@ -1758,23 +1763,27 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
         return (b, hk, kj, 0)
 
     qh_spec = pl.BlockSpec((1, 1, block_q, dh), qh_map)
+    oh_spec = pl.BlockSpec((1, 1, block_q, dv), qh_map)
     k_spec = pl.BlockSpec((1, 1, block_k, dh), k_map)
-    dk, dv = pl.pallas_call(
+    v_spec = pl.BlockSpec((1, 1, block_k, dv), k_map)
+    d_k, d_v = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, n_q=nq, **kw),
         name="flash_attn_dkv",
         grid=(B, Hkv, nk, G * nq),
-        in_specs=[qh_spec, k_spec, k_spec, qh_spec, qh_spec,
+        in_specs=[qh_spec, k_spec, v_spec, oh_spec, oh_spec,
                   pl.BlockSpec((1, 1, block_q, 1), qh_map)],
-        out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32)] * 2,
+        out_specs=[k_spec, v_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
             vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
     )(q, k, v, o, do, lse)
-    return dq, dk, dv
+    return dq, d_k, d_v
 
 
 # ---------------------------------------------------------------------------

@@ -652,6 +652,14 @@ def case_causal_self_attention(rng):
                                     num_kv_heads=2, head_dim=2), feed
 
 
+def case_latent_attention(rng):
+    # keys of 4 + 2 rotary channels beside values of 3, from a latent of 4
+    xs, feed = _seq(rng)
+    return nn.latent_attention(_pre_fc(xs, size=8), num_heads=2,
+                               kv_lora_rank=4, qk_nope_head_dim=4,
+                               qk_rope_head_dim=2, v_head_dim=3), feed
+
+
 def case_gated_mlp(rng):
     xs, feed = _seq(rng)
     return nn.gated_mlp(_pre_fc(xs), 8), feed

@@ -481,3 +481,57 @@ def test_grouped_expert_products(one_chip, on_tpu):
             _struct(one_chip, (N, k), jnp.int32)]
     assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
                     *args) == 18
+
+
+# -- latent attention and 16 narrow experts (PR 35): Kanana-2-30B-A3B's ------
+# -- published widths at the benchmark cell's row of 8192 tokens -------------
+
+KANANA2 = dict(T=8192, D=2048, H=32, dqk=192, dv=128, F=768, held=16,
+               top_k=6)
+
+
+def test_causal_attention_kernels_with_a_value_width_of_its_own(one_chip,
+                                                                on_tpu):
+    """Keys and queries of 192 (one and a half lane tiles) beside values of
+    128: the gate is a function of both widths, and the three flash kernels
+    compile with every head its own key-value head."""
+    from paddle_tpu.ops import decoder_block as DB
+
+    c = KANANA2
+    assert DB.attention_kernel_blocks(c["T"], c["dqk"], c["H"], c["H"],
+                                      c["dv"])
+    assert DB.attention_kernel_blocks(c["T"], c["dqk"], c["H"], c["H"],
+                                      100) is None
+    assert DB.attention_kernel_blocks(c["T"], 100, c["H"], c["H"],
+                                      c["dv"]) is None
+
+    def loss(q, k, v):
+        return DB.causal_attention(q, k, v, scale=c["dqk"] ** -0.5).sum()
+
+    qk = _struct(one_chip, (1, c["T"], c["H"], c["dqk"]))
+    v = _struct(one_chip, (1, c["T"], c["H"], c["dv"]))
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    qk, qk, v) == 3
+
+
+def test_grouped_expert_products_sixteen_narrow_experts(one_chip, on_tpu):
+    """16 experts of 768 held of 128, 6 a token: 384 assignments an expert
+    a step against the row tile of 256, both row buffers."""
+    from paddle_tpu.ops import moe as M
+
+    c = KANANA2
+    N, k = c["T"], c["top_k"]
+    tm = M.moe_kernel_row_tile(c["D"], c["F"], N * k)
+    assert tm == 256
+    assert M.buffer_rows(N, k, 128, c["held"], tm) == (16384, 53248)
+
+    def loss(x, w, w1, w3, w2, idx):
+        return M.expert_layer(x, idx, w, w1, w3, w2, num_experts=128,
+                              first_expert=0, tm=tm, kernels=True)[0].sum()
+
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+    args = [s(N, c["D"]), s(N, k), s(c["held"], c["D"], c["F"]),
+            s(c["held"], c["D"], c["F"]), s(c["held"], c["F"], c["D"]),
+            _struct(one_chip, (N, k), jnp.int32)]
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    *args) == 18

@@ -8,10 +8,14 @@ a long row never exist in HBM.
 
 Attention has two paths behind one ``jax.custom_vjp``: on the TPU, at
 tile-aligned shapes, the flash kernels of ops/pallas_kernels.py
-(``flash_attn_fwd`` / ``flash_attn_dq`` / ``flash_attn_dkv``); elsewhere a
-loop over blocks of queries in XLA, each block against the keys at or before
-its last row, with the same saved statistics (the output and the rows'
-log-sum-exp) and a backward that recomputes each block's scores.
+(``flash_attn_fwd`` and ONE backward, ``flash_attn_bwd``, that makes a block
+pair's probabilities once for ``dq``, ``dk`` and ``dv``, with ``dk`` and
+``dv`` of a key-value head resident in VMEM; a row too long for that is cut
+into super-blocks of keys by ``flash_bwd_key_rows``, and no row leaves the
+kernels for it); elsewhere a loop over blocks of queries in XLA, each block
+against the keys at or before its last row, with the same saved statistics
+(the output and the rows' log-sum-exp) and a backward that recomputes each
+block's scores.
 """
 
 from __future__ import annotations
@@ -77,7 +81,11 @@ def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None):
     value head (``dh`` where not given).  Needs the TPU backend, a row length
     the blocks divide, both widths multiples of 64 (a block's minor axis is
     the whole head, so 192 beside 128 is as good as 64 beside 64) and whole
-    groups of query heads."""
+    groups of query heads.  The row's length closes nothing: what the
+    backward keeps resident is reckoned against the kernels' VMEM budget by
+    ``pallas_kernels.flash_bwd_key_rows`` (the whole row's ``dk`` and ``dv``
+    up to 23k rows at 192/128 and 35k at 64/64 with blocks of 1024, beyond
+    that super-blocks of keys), inside ``flash_attn_bwd_pallas``."""
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
 
     if not compiled_kernels():

@@ -29,8 +29,10 @@ sets takes part:
   ``ops/losses._tiled_ce_cfg``.
 - Vocab-tiled top-k + logsumexp readout (decode).  Gate:
   ``ops/decode.decode_kernel_config``.
-- Causal flash attention, forward, dq and dk/dv.  Gate:
-  ``ops/decoder_block.attention_kernel_blocks``.
+- Causal flash attention, forward (``flash_attn_fwd``) and one backward
+  (``flash_attn_bwd``: dq, dk and dv from one set of probabilities).  Gate:
+  ``ops/decoder_block.attention_kernel_blocks``; how many keys' ``dk`` and
+  ``dv`` a backward call keeps in VMEM: :func:`flash_bwd_key_rows`.
 - Grouped matrix products over experts.  Gate:
   ``ops/moe.moe_kernel_row_tile``.
 
@@ -56,6 +58,7 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "attn_dec_fwd_pallas", "attn_dec_bwd_pallas",
            "topk_lse_readout_pallas", "topk_lse_logits_pallas", "TOPK_LANES",
            "flash_attn_fwd_pallas", "flash_attn_bwd_pallas",
+           "flash_bwd_key_rows",
            "gmm_pallas", "tgmm_pallas"]
 
 
@@ -1546,19 +1549,30 @@ def topk_lse_logits_pallas(logits, *, vocab: int, k: int, row_block: int,
 
 
 # ---------------------------------------------------------------------------
-# Causal flash attention (decoder-only blocks): forward, dq, dk/dv
+# Causal flash attention (decoder-only blocks): forward, backward
 # ---------------------------------------------------------------------------
 # Heads-major operands: q [B, H, T, dh], k [B, Hkv, T, dh], v [B, Hkv, T, dv],
 # o/do [B, H, T, dv] (key-value head j serves query heads j*G..j*G+G-1; dv is
 # dh in grouped-query attention, 128 beside 192 in latent attention), lse
 # [B, H, T, 1] float32.  A block's minor axis is the whole head.  A grid
 # step is one (block of queries, block of keys); blocks above the diagonal
-# are skipped, and their key/value (or query) block index is clamped to the
-# last one needed so that a skipped step moves nothing.  The scores of one
-# block pair live in VMEM only.
+# are skipped, and their key/value block index is clamped to the last one
+# needed so that a skipped step moves nothing.  The scores of one block pair
+# live in VMEM only.  Both kernels walk the keys of a block of queries.  The
+# backward is ONE kernel: a pair's probabilities and ``ds`` are made once and
+# feed dq, dk and dv (two kernels, one for dq and one for dk/dv, made them
+# twice: 11 MXU passes of 1024 x 1024 x 128 a pair at 192/128 where 8 are
+# needed, 7 for 5 at 64/64).  Of the two accumulations that cannot both
+# follow the walk, dk/dv is the one kept resident in VMEM over a whole
+# key-value head (its query heads add into it in turn): [T, dh] + [T, dv]
+# float32 is 8 + 4 MiB at 192/128 and 4 + 4 at 64/64 (lanes padded), where a
+# resident dq is G x [T, dh], 8 and 16; the walk then fetches a key and a
+# value block a step (0.64 MiB) and not a query, an output and a gradient
+# block (0.9).  No partial sum crosses HBM.
 
-#: scoped VMEM the three attention kernels ask for (score tiles of
-#: 1024 x 1024 float32 and their bf16 copies, beside the operand blocks)
+#: scoped VMEM the attention kernels ask for (score tiles of 1024 x 1024
+#: float32 and their bf16 copies, beside the operand blocks and, in the
+#: backward, the resident dk and dv)
 FLASH_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 
@@ -1644,6 +1658,32 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
     )(q, k, v)
 
 
+def flash_bwd_key_rows(T: int, dh: int, dv: int, block_q: int,
+                       block_k: int) -> int:
+    """Rows of keys whose ``dk`` and ``dv`` one ``flash_attn_bwd`` call keeps
+    resident in VMEM: the whole row where that fits the kernels' budget (both
+    benchmark cells' 8192; up to 23k rows at 192/128 and 35k at 64/64 with
+    blocks of 1024), else the row cut into equal super-blocks of whole key
+    blocks.  Counted against ``FLASH_VMEM_LIMIT_BYTES``: the resident pair in
+    float32 with lanes padded to 128, twice (as if the pipeline kept two
+    buffers of an output block), the block pair's four float32 score tiles
+    and four bf16 ones (``p``, ``ds`` and their transposes), and the operand,
+    ``lse`` and ``dq`` blocks, twice each.  The v5e compiler takes every row
+    this admits, and some it does not (28k at 192/128)."""
+    def lanes(d):
+        return -(-d // 128) * 128
+
+    tiles = block_q * block_k * (4 * 4 + 4 * 2)
+    blocks = 2 * (2 * (block_q + block_k) * lanes(dh)
+                  + 2 * (2 * block_q + block_k) * lanes(dv)
+                  + 4 * block_q * (lanes(dh) + 128))
+    per_row = 2 * 4 * (lanes(dh) + lanes(dv))
+    fit = (FLASH_VMEM_LIMIT_BYTES - tiles - blocks) // (per_row * block_k)
+    nk = T // block_k
+    n_super = -(-nk // max(1, fit))
+    return -(-nk // n_super) * block_k
+
+
 def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k):
     """(p, ds) of one block pair from the saved statistics, float32."""
     f32 = jnp.float32
@@ -1656,134 +1696,120 @@ def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k):
     return p, p * (dp - delta) * scale
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-                     acc_scr, *, scale, block_q, block_k):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, *, scale, block_q, block_k,
+                      n_q, q_first, k_first):
+    """One (block of queries, block of keys) of the backward: the pair's
+    probabilities and ``ds`` are made once and feed all three gradients.
+    ``dq`` of the block of queries accumulates in its output block over the
+    walk along the keys; ``dk`` and ``dv`` of ALL the call's keys stay in
+    their output blocks over both inner axes, and every query block (of
+    every head of the group, in turn) adds its part at its key block's
+    rows."""
     from jax.experimental import pallas as pl
 
-    qi, kj = pl.program_id(2), pl.program_id(3)
+    t, kj = pl.program_id(2), pl.program_id(3)
+    qi, kg = q_first + t % n_q, k_first + kj    # blocks of the whole row
+
+    @pl.when((t == 0) & (kj == 0))
+    def _zero():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
 
     @pl.when(kj == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        dq_ref[...] = jnp.zeros_like(dq_ref)
 
-    @pl.when(kj * block_k <= qi * block_q + block_q - 1)
+    @pl.when(kg * block_k <= qi * block_q + block_q - 1)
     def _block():
-        k = k_ref[0, 0]
-        _, ds = _flash_probs(q_ref[0, 0], k, v_ref[0, 0], o_ref[0, 0],
-                             do_ref[0, 0], lse_ref[0, 0], qi, kj,
-                             scale=scale, block_q=block_q, block_k=block_k)
-        acc_scr[...] += jnp.dot(ds.astype(k.dtype), k,
-                                preferred_element_type=jnp.float32)
-
-    @pl.when(kj == pl.num_programs(3) - 1)
-    def _fin():
-        dq_ref[0, 0] = acc_scr[...]
-
-
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale, block_q,
-                      block_k, n_q):
-    from jax.experimental import pallas as pl
-
-    kj, t = pl.program_id(2), pl.program_id(3)
-    qi = t % n_q
-
-    @pl.when(t == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    @pl.when(kj * block_k <= qi * block_q + block_q - 1)
-    def _block():
-        q, do = q_ref[0, 0], do_ref[0, 0]
-        p, ds = _flash_probs(q, k_ref[0, 0], v_ref[0, 0], o_ref[0, 0], do,
-                             lse_ref[0, 0], qi, kj, scale=scale,
+        q, k, do = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
+        p, ds = _flash_probs(q, k, v_ref[0, 0], o_ref[0, 0], do,
+                             lse_ref[0, 0], qi, kg, scale=scale,
                              block_q=block_q, block_k=block_k)
+        ds = ds.astype(q.dtype)
         contract_rows = (((0,), (0,)), ((), ()))
-        dv_scr[...] += jax.lax.dot_general(
+        keys = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
+        dv_ref[0, 0, keys, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, contract_rows,
             preferred_element_type=jnp.float32)
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, contract_rows,
-            preferred_element_type=jnp.float32)
-
-    @pl.when(t == pl.num_programs(3) - 1)
-    def _fin():
-        dk_ref[0, 0] = dk_scr[...]
-        dv_ref[0, 0] = dv_scr[...]
+        dk_ref[0, 0, keys, :] += jax.lax.dot_general(
+            ds, q, contract_rows, preferred_element_type=jnp.float32)
+        dq_ref[0, 0] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
 
 def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
                           block_q: int, block_k: int):
     """-> (dq [B, H, T, dh], dk [B, Hkv, T, dh], dv [B, Hkv, T, dv]),
-    float32; ``o`` and ``do`` are [B, H, T, dv].  Two kernels,
-    each recomputing a block pair's probabilities from ``lse``: one walks
-    the keys of a block of queries (dq), one the queries (of every head of
-    the group) of a block of keys (dk, dv)."""
+    float32; ``o`` and ``do`` are [B, H, T, dv].  ONE kernel,
+    ``flash_attn_bwd``, that visits each live block pair once, recomputes
+    its probabilities from ``lse`` and accumulates all three gradients from
+    them.  It walks the keys of a block of queries (``dq`` in its output
+    block), and the ``dk`` [T, dh] and ``dv`` [T, dv] of the key-value head
+    stay in VMEM over all the head's (and its group's) query blocks, so no
+    partial sum crosses HBM.  Where a row is too long for that
+    (:func:`flash_bwd_key_rows`), the keys are cut
+    into super-blocks, one call each over the queries at or after its first
+    key, and the calls' ``dq`` are summed here."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, dh = q.shape
     Hkv, dv = k.shape[1], v.shape[3]
     G = H // Hkv
-    nq, nk = T // block_q, T // block_k
-    kw = dict(scale=scale, block_q=block_q, block_k=block_k)
+    key_rows = flash_bwd_key_rows(T, dh, dv, block_q, block_k)
 
-    def q_map(b, h, qi, kj):
-        return (b, h, qi, 0)
+    def part(k_lo, k_hi):
+        """The gradients of keys ``k_lo..k_hi`` and what they add to the
+        ``dq`` of the queries from ``q_lo`` (the first that see them) on."""
+        k_first, q_first = k_lo // block_k, k_lo // block_q
+        q_lo = q_first * block_q
+        nq, nk = (T - q_lo) // block_q, (k_hi - k_lo) // block_k
 
-    def kv_map(b, h, qi, kj):
-        last = (qi * block_q + block_q - 1) // block_k
-        return (b, h // G, jnp.minimum(kj, last), 0)
+        def q_map(b, hk, t, kj):
+            return (b, hk * G + t // nq, q_first + t % nq, 0)
 
-    q_spec = pl.BlockSpec((1, 1, block_q, dh), q_map)
-    o_spec = pl.BlockSpec((1, 1, block_q, dv), q_map)
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, **kw),
-        name="flash_attn_dq",
-        grid=(B, H, nq, nk),
-        in_specs=[q_spec, pl.BlockSpec((1, 1, block_k, dh), kv_map),
-                  pl.BlockSpec((1, 1, block_k, dv), kv_map), o_spec, o_spec,
-                  pl.BlockSpec((1, 1, block_q, 1), q_map)],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
-        interpret=_interpret(),
-    )(q, k, v, o, do, lse)
+        def kv_map(b, hk, t, kj):
+            last = ((q_first + t % nq) * block_q + block_q - 1) // block_k
+            return (b, hk, jnp.minimum(k_first + kj, last), 0)
 
-    def qh_map(b, hk, kj, t):
-        first = (kj * block_k) // block_q
-        return (b, hk * G + t // nq, jnp.maximum(t % nq, first), 0)
+        def resident(b, hk, t, kj):
+            return (b, hk, 0, 0)
 
-    def k_map(b, hk, kj, t):
-        return (b, hk, kj, 0)
+        def dq_map(b, hk, t, kj):
+            return (b, hk * G + t // nq, t % nq, 0)
 
-    qh_spec = pl.BlockSpec((1, 1, block_q, dh), qh_map)
-    oh_spec = pl.BlockSpec((1, 1, block_q, dv), qh_map)
-    k_spec = pl.BlockSpec((1, 1, block_k, dh), k_map)
-    v_spec = pl.BlockSpec((1, 1, block_k, dv), k_map)
-    d_k, d_v = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, n_q=nq, **kw),
-        name="flash_attn_dkv",
-        grid=(B, Hkv, nk, G * nq),
-        in_specs=[qh_spec, k_spec, v_spec, oh_spec, oh_spec,
-                  pl.BlockSpec((1, 1, block_q, 1), qh_map)],
-        out_specs=[k_spec, v_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(v.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
-                        pltpu.VMEM((block_k, dv), jnp.float32)],
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-            vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
-        interpret=_interpret(),
-    )(q, k, v, o, do, lse)
-    return dq, d_k, d_v
+        q_spec = pl.BlockSpec((1, 1, block_q, dh), q_map)
+        o_spec = pl.BlockSpec((1, 1, block_q, dv), q_map)
+        return pl.pallas_call(
+            functools.partial(_flash_bwd_kernel, scale=scale,
+                              block_q=block_q, block_k=block_k, n_q=nq,
+                              q_first=q_first, k_first=k_first),
+            name="flash_attn_bwd",
+            grid=(B, Hkv, G * nq, nk),
+            in_specs=[q_spec, pl.BlockSpec((1, 1, block_k, dh), kv_map),
+                      pl.BlockSpec((1, 1, block_k, dv), kv_map), o_spec,
+                      o_spec, pl.BlockSpec((1, 1, block_q, 1), q_map)],
+            out_specs=[pl.BlockSpec((1, 1, block_q, dh), dq_map),
+                       pl.BlockSpec((1, 1, k_hi - k_lo, dh), resident),
+                       pl.BlockSpec((1, 1, k_hi - k_lo, dv), resident)],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, T - q_lo, dh), jnp.float32),
+                jax.ShapeDtypeStruct((B, Hkv, k_hi - k_lo, dh), jnp.float32),
+                jax.ShapeDtypeStruct((B, Hkv, k_hi - k_lo, dv), jnp.float32)],
+            compiler_params=_compiler_params(
+                dimension_semantics=("parallel", "parallel", "arbitrary",
+                                     "arbitrary"),
+                vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
+            interpret=_interpret(),
+        )(q, k, v, o, do, lse)
+
+    parts = [part(lo, min(lo + key_rows, T)) for lo in range(0, T, key_rows)]
+    if len(parts) == 1:
+        return tuple(parts[0])
+    dqs, d_ks, d_vs = zip(*parts)
+    dq = dqs[0]
+    for more in dqs[1:]:
+        dq = dq.at[:, :, T - more.shape[2]:].add(more)
+    return dq, jnp.concatenate(d_ks, axis=2), jnp.concatenate(d_vs, axis=2)
 
 
 # ---------------------------------------------------------------------------

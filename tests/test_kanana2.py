@@ -387,9 +387,9 @@ def test_blockwise_attention_with_a_value_width_of_its_own(length, hkv,
 @pytest.mark.parametrize("h,hkv,dqk,dv", [(2, 2, 192, 128), (4, 2, 64, 64)],
                          ids=["kanana2_192_128", "lfm2_64_64"])
 def test_flash_kernels_match_the_xla_path(h, hkv, dqk, dv):
-    """Interpret mode: forward, dq and dk/dv kernels against the XLA blocks
-    (float32 operands here, so rounding only), at latent attention's widths
-    and at grouped-query attention's."""
+    """Interpret mode: the forward and the backward kernel against the XLA
+    blocks (float32 operands here, so rounding only), at latent attention's
+    widths and at grouped-query attention's."""
     from paddle_tpu.ops import pallas_kernels as PK
 
     scale = dqk ** -0.5
@@ -410,10 +410,82 @@ def test_flash_kernels_match_the_xla_path(h, hkv, dqk, dv):
     assert all(rel(heads(a), b) <= 1e-5 for a, b in zip(got, (dq, dk, d_v)))
 
 
+def _xla_grads(q, k, v, w, scale):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(DB.causal_attention(q, k, v, scale=scale) * w),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _kernel_grads(q, k, v, w, scale):
+    from paddle_tpu.ops import pallas_kernels as PK
+
+    heads = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
+    out, lse = PK.flash_attn_fwd_pallas(heads(q), heads(k), heads(v),
+                                        scale=scale, block_q=128, block_k=128)
+    got = PK.flash_attn_bwd_pallas(heads(q), heads(k), heads(v), out, lse,
+                                   heads(w), scale=scale, block_q=128,
+                                   block_k=128)
+    return [heads(a) for a in got]
+
+
+@pytest.mark.parametrize("blocks", [2, 4])
+@pytest.mark.parametrize("h,hkv,dqk,dv", [(8, 2, 64, 64), (2, 2, 192, 128),
+                                          (4, 2, 128, 128)],
+                         ids=["gqa_64_64", "mla_192_128", "gqa_128_128"])
+def test_flash_backward_kernel_matches_the_xla_path(h, hkv, dqk, dv, blocks):
+    """Interpret mode: ``flash_attn_bwd``, the one kernel that makes a block
+    pair's probabilities once for dq, dk and dv, against the XLA path's
+    gradients, over rows of 2 and of 4 blocks (dk and dv of the whole row
+    resident, query blocks and the group's heads adding into them)."""
+    scale = dqk ** -0.5
+    q, k, v, w = _qkv(128 * blocks, h, hkv, dqk, dv, seed=11)
+    got = _kernel_grads(q, k, v, w, scale)
+    assert [a.shape for a in got] == [q.shape, k.shape, v.shape]
+    assert all(a.dtype == jnp.float32 for a in got)
+    assert all(rel(a, b) <= 1e-5
+               for a, b in zip(got, _xla_grads(q, k, v, w, scale)))
+
+
+def test_flash_backward_adds_the_groups_heads_into_one_dk_and_dv():
+    """Four query heads on ONE key-value head: its resident dk and dv are the
+    sums of what each query head alone gives (the same head run as its own
+    key-value head), and each head's dq is that run's."""
+    q, k, v, w = _qkv(384, 4, 1, 64, 64, seed=13)
+    dq, dk, dv = _kernel_grads(q, k, v, w, 0.125)
+    alone = [_kernel_grads(q[:, :, g:g + 1], k, v, w[:, :, g:g + 1], 0.125)
+             for g in range(4)]
+    assert rel(dq, jnp.concatenate([a[0] for a in alone], axis=2)) <= 1e-6
+    assert rel(dk, sum(a[1] for a in alone)) <= 1e-6
+    assert rel(dv, sum(a[2] for a in alone)) <= 1e-6
+    assert rel(dk, alone[0][1]) > 0.1      # one head alone is not the sum
+
+
+@pytest.mark.parametrize("key_rows", [256, 384, 128],
+                         ids=["two_even", "uneven", "one_block_each"])
+def test_flash_backward_in_super_blocks_of_keys(key_rows, monkeypatch):
+    """A row too long for the resident dk and dv (here: told so) is cut into
+    super-blocks of keys, one call each over the queries from its first key
+    on; the calls' dq are summed, their dk and dv laid end to end: the whole
+    row's numbers."""
+    q, k, v, w = _qkv(512, 4, 2, 192, 128, seed=17)
+    scale = 192 ** -0.5
+    from paddle_tpu.ops import pallas_kernels as PK
+
+    whole = _kernel_grads(q, k, v, w, scale)
+    monkeypatch.setattr(PK, "flash_bwd_key_rows", lambda *shape: key_rows)
+    text = str(jax.make_jaxpr(lambda: _kernel_grads(q, k, v, w, scale))())
+    assert text.count("flash_attn_bwd") == -(-512 // key_rows)
+    cut = _kernel_grads(q, k, v, w, scale)
+    assert all(rel(a, b) <= 1e-6 for a, b in zip(cut, whole))
+    assert all(rel(a, b) <= 1e-5
+               for a, b in zip(cut, _xla_grads(q, k, v, w, scale)))
+
+
 def test_custom_vjp_takes_the_kernels_where_the_gate_opens(monkeypatch):
     """With the gate opened by hand (interpret mode), ``causal_attention``
-    itself runs the three kernels at 192/128 and gives the XLA path's
-    numbers: residuals, layouts and dtypes of the kernel branch."""
+    itself runs the forward kernel and the ONE backward kernel at 192/128
+    and gives the XLA path's numbers: residuals, layouts and dtypes of the
+    kernel branch."""
     q, k, v, w = _qkv(256, 2, 2, 192, 128, seed=7)
 
     def run():
@@ -425,8 +497,8 @@ def test_custom_vjp_takes_the_kernels_where_the_gate_opens(monkeypatch):
     monkeypatch.setattr(DB, "attention_kernel_blocks",
                         lambda T, dh, H, Hkv, dv=None: (128, 128))
     text = str(jax.make_jaxpr(lambda: run())())
-    assert all(n in text for n in ("flash_attn_fwd", "flash_attn_dq",
-                                   "flash_attn_dkv"))
+    assert text.count("flash_attn_fwd") == text.count("flash_attn_bwd") == 1
+    assert "flash_attn_dq" not in text and "flash_attn_dkv" not in text
     got, got_g = run()
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     assert all(rel(a, b) <= 1e-5 for a, b in zip(got_g, want_g))
@@ -446,6 +518,32 @@ def test_gate_is_a_function_of_both_widths(monkeypatch):
     assert DB.attention_kernel_blocks(8192, 160, 32, 32, 128) is None
     assert DB.attention_kernel_blocks(8200, 192, 32, 32, 128) is None
     assert DB.attention_kernel_blocks(8192, 192, 32, 5, 128) is None
+
+
+@pytest.mark.parametrize("dqk,dv,hkv", [(192, 128, 32), (64, 64, 8)],
+                         ids=["kanana2", "lfm2"])
+def test_gate_and_the_backward_s_resident_keys(monkeypatch, dqk, dv, hkv):
+    """At both cells' shapes: blocks of 1024 and ONE backward call with the
+    whole row's dk and dv resident.  A row too long for that keeps the
+    kernels (the parent gave it blocks; it never falls to the XLA path): the
+    backward runs in super-blocks of whole key blocks whose dk and dv,
+    double-buffered, fit the kernels' VMEM budget, fewer rows the wider the
+    heads."""
+    from paddle_tpu.ops import pallas_kernels as PK
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert DB.attention_kernel_blocks(8192, dqk, 32, hkv, dv) == (1024, 1024)
+    assert PK.flash_bwd_key_rows(8192, dqk, dv, 1024, 1024) == 8192
+    for T in (16384, 65536, 131072, 1 << 20):
+        assert DB.attention_kernel_blocks(T, dqk, 32, hkv, dv) == (1024, 1024)
+        rows = PK.flash_bwd_key_rows(T, dqk, dv, 1024, 1024)
+        assert rows % 1024 == 0 and 0 < rows <= T
+        lanes = -(-dqk // 128) * 128 + -(-dv // 128) * 128
+        assert 2 * 4 * rows * lanes < PK.FLASH_VMEM_LIMIT_BYTES
+    assert PK.flash_bwd_key_rows(16384, dqk, dv, 1024, 1024) == 16384
+    assert PK.flash_bwd_key_rows(65536, dqk, dv, 1024, 1024) < 65536
+    assert PK.flash_bwd_key_rows(65536, 64, 64, 1024, 1024) \
+        > PK.flash_bwd_key_rows(65536, 192, 128, 1024, 1024)
 
 
 # -- through the trainer ---------------------------------------------------------

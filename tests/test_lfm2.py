@@ -408,8 +408,8 @@ def test_blockwise_attention_off_the_block_size(length, monkeypatch):
 
 
 def test_flash_kernels_match_the_xla_path():
-    """Interpret mode: forward, dq and dk/dv kernels against the XLA blocks
-    (float32 operands here, so rounding only)."""
+    """Interpret mode: the forward and the backward kernel against the XLA
+    blocks (float32 operands here, so rounding only)."""
     from paddle_tpu.ops import pallas_kernels as PK
 
     q, k, v, w = _qkv(256, dh=64, seed=5)

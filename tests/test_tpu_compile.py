@@ -439,8 +439,10 @@ LFM2 = dict(T=8192, D=2048, H=32, Hkv=8, dh=64, F=1536, held=8, top_k=4)
 
 
 def test_causal_attention_kernels(one_chip, on_tpu):
-    """The gate admits the cell's row, and the forward, dq and dk/dv flash
-    kernels compile with the heads of 64 the configuration has."""
+    """The gate admits the cell's row, and the forward and the one backward
+    flash kernel (dk and dv of a key-value head's 8192 rows resident, its
+    four query heads adding into them) compile with the heads of 64 the
+    configuration has."""
     from paddle_tpu.ops import decoder_block as DB
 
     c = LFM2
@@ -454,7 +456,7 @@ def test_causal_attention_kernels(one_chip, on_tpu):
     q = _struct(one_chip, (1, c["T"], c["H"], c["dh"]))
     kv = _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"]))
     assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                    q, kv, kv) == 3
+                    q, kv, kv) == 2
 
 
 def test_grouped_expert_products(one_chip, on_tpu):
@@ -493,9 +495,13 @@ KANANA2 = dict(T=8192, D=2048, H=32, dqk=192, dv=128, F=768, held=16,
 def test_causal_attention_kernels_with_a_value_width_of_its_own(one_chip,
                                                                 on_tpu):
     """Keys and queries of 192 (one and a half lane tiles) beside values of
-    128: the gate is a function of both widths, and the three flash kernels
-    compile with every head its own key-value head."""
+    128: the gate is a function of both widths, and the forward and the one
+    backward kernel compile with every head its own key-value head, dk
+    ``[8192, 192]`` and dv ``[8192, 128]`` resident.  A row of 65,536 keeps
+    the kernels: its backward is three calls, each with as many keys' dk and
+    dv resident as the reckoning allows (the longest the compiler is shown)."""
     from paddle_tpu.ops import decoder_block as DB
+    from paddle_tpu.ops import pallas_kernels as PK
 
     c = KANANA2
     assert DB.attention_kernel_blocks(c["T"], c["dqk"], c["H"], c["H"],
@@ -511,7 +517,13 @@ def test_causal_attention_kernels_with_a_value_width_of_its_own(one_chip,
     qk = _struct(one_chip, (1, c["T"], c["H"], c["dqk"]))
     v = _struct(one_chip, (1, c["T"], c["H"], c["dv"]))
     assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                    qk, qk, v) == 3
+                    qk, qk, v) == 2
+    T = 65536
+    assert PK.flash_bwd_key_rows(T, c["dqk"], c["dv"], 1024, 1024) == 22528
+    assert DB.attention_kernel_blocks(T, c["dqk"], 2, 2, c["dv"])
+    qk, v = (_struct(one_chip, (1, T, 2, w)) for w in (c["dqk"], c["dv"]))
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    qk, qk, v) == 4
 
 
 def test_grouped_expert_products_sixteen_narrow_experts(one_chip, on_tpu):

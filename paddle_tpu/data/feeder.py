@@ -131,6 +131,12 @@ class BatchPrefetcher:
     """
 
     _DONE = object()
+    #: the producer thread's spans on a profiler trace: ``prepare``, ``h2d``
+    #: (the ``transfer`` call) and ``put`` (waiting for room in the queue),
+    #: one of each a batch.  Not under ``paddle_tpu.trainer.``: the loop's
+    #: spans stay on one thread (docs/observability.md "Names on the
+    #: device trace")
+    SPAN_PREFIX = "paddle_tpu.data.prefetch."
 
     def __init__(self, it: Iterator, *, prepare: Optional[Callable] = None,
                  transfer: Optional[Callable] = None, depth: int = 2) -> None:
@@ -152,21 +158,28 @@ class BatchPrefetcher:
         return False
 
     def _run(self, it: Iterator) -> None:
+        from jax.profiler import TraceAnnotation  # a flag check when off
+
+        prefix = self.SPAN_PREFIX
         try:
             for raw in it:
                 if self._stop.is_set():
                     return
                 try:
-                    feed = self._prepare(raw) if self._prepare else raw
+                    with TraceAnnotation(prefix + "prepare"):
+                        feed = self._prepare(raw) if self._prepare else raw
                     if self._transfer is not None:
-                        feed = self._transfer(feed)
+                        with TraceAnnotation(prefix + "h2d"):
+                            feed = self._transfer(feed)
                 except BaseException as e:
                     # prepare/h2d failures keep their own identity — the
                     # reader did NOT raise (see PrepareError)
                     raise PrepareError(
                         f"batch prepare/transfer failed: "
                         f"{type(e).__name__}: {e}") from e
-                if not self._put(PreparedFeed(feed)):
+                with TraceAnnotation(prefix + "put"):
+                    room = self._put(PreparedFeed(feed))
+                if not room:
                     return
             self._put(self._DONE)
         except BaseException as e:  # noqa: BLE001 — delivered to consumer

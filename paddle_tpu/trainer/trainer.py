@@ -47,11 +47,15 @@ __all__ = ["SGDTrainer"]
 SPAN_PREFIX = "paddle_tpu.trainer."
 
 
-def _sync_span(reason: str):
-    """One span per blocking fetch from the device inside ``step``: their
-    count per ``iteration`` is the count of host syncs a step pays."""
-    return jax.profiler.TraceAnnotation(SPAN_PREFIX + "step.sync",
-                                        reason=reason)
+def _span(name: str, **stats):
+    """A span that is on the profiler's trace ONLY: a part of a phase or of
+    ``iteration``'s own time, named so that a device-idle instant has an
+    owner (not a ``StepTimeline`` phase and not a child of the request
+    tracer: those are ``SGDTrainer._ph``'s).  Outside a profiler session
+    it is a flag check.  ``step.sync`` carries ``reason``: one span per
+    blocking fetch from the device inside ``step``, so their count per
+    ``iteration`` is the count of host syncs a step pays."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **stats)
 
 
 #: consecutive SDC rollbacks a survivor tolerates before declaring the
@@ -717,34 +721,43 @@ class SGDTrainer:
         ``max_bad_steps`` CONSECUTIVE skips the step raises
         ``TooManyBadSteps`` — persistent non-finite training cannot
         recover by skipping."""
-        self._rng, key = jax.random.split(self._rng)
-        ps = self.pserver.state() if self.pserver is not None else {}
-        with jax.profiler.TraceAnnotation(SPAN_PREFIX + "step.dispatch"):
+        with _span("step.rng"):
+            # what the step takes besides the trainer's own state: the key,
+            # split op by op before every step, and the pserver's tables
+            self._rng, key = jax.random.split(self._rng)
+            ps = self.pserver.state() if self.pserver is not None else {}
+        with _span("step.dispatch"):
             loss, self.params, self.state, self.opt_state, new_ps, extras = (
                 self._step(self.params, self.state, self.opt_state, ps, key,
                            feed))
-        if self.pserver is not None:
-            self.pserver.adopt(new_ps)
-        if self.averager is not None:
-            self.avg_params = self.averager.update(self.avg_params, self.params)
-        self._obs_counters["batches"].inc()
-        self._last_extras = extras
-        for name, _ in self._counter_feeds:
-            # starts the copy now; _feed_counters reads it after the loss
-            extras[name].copy_to_host_async()
-        if self._gang is not None:
-            self._obs_gauges["world"].set(self._gang.world_size)
-            # elastic observability: the live world, whether it is running
-            # degraded (fewer ranks than configured), and the resize story
-            self._last_extras = {
-                **self._last_extras,
-                "world_size": self._gang.world_size,
-                "degraded": self._gang.degraded,
-                "resize_count": self._resize_count,
-                "last_resize_reason": self._last_resize_reason,
-            }
+        with _span("step.post"):
+            # the device is running the step; the host's own lines up to
+            # the first fetch
+            if self.pserver is not None:
+                self.pserver.adopt(new_ps)
+            if self.averager is not None:
+                self.avg_params = self.averager.update(self.avg_params,
+                                                       self.params)
+            self._obs_counters["batches"].inc()
+            self._last_extras = extras
+            for name, _ in self._counter_feeds:
+                # starts the copy now; _feed_counters reads it after the
+                # loss
+                extras[name].copy_to_host_async()
+            if self._gang is not None:
+                self._obs_gauges["world"].set(self._gang.world_size)
+                # elastic observability: the live world, whether it is
+                # running degraded (fewer ranks than configured), and the
+                # resize story
+                self._last_extras = {
+                    **self._last_extras,
+                    "world_size": self._gang.world_size,
+                    "degraded": self._gang.degraded,
+                    "resize_count": self._resize_count,
+                    "last_resize_reason": self._last_resize_reason,
+                }
         if self.amp and "amp_overflow" in extras:
-            with _sync_span("amp"):
+            with _span("step.sync", reason="amp"):
                 overflow = bool(jax.device_get(extras["amp_overflow"]))
             if overflow:
                 self.amp_overflows_total += 1
@@ -759,7 +772,7 @@ class SGDTrainer:
                     "loss scale halved to %g (overflow %d)", scale,
                     self.amp_overflows_total)
         if (self.guard_nonfinite or self.amp) and "bad_step" in extras:
-            with _sync_span("guard"):
+            with _span("step.sync", reason="guard"):
                 bad = bool(jax.device_get(extras["bad_step"]))
             if bad:
                 self.bad_steps_total += 1
@@ -857,7 +870,9 @@ class SGDTrainer:
         on a profiler trace and feeds the step timeline (the reference's
         REGISTER_TIMER plane, TrainerInternal.cpp:118; ``--enable_timers``
         prints its per-pass table, Stat.h:70-247) and the request tracer;
-        ``--profile_dir`` records the ``jax.profiler`` trace — the
+        what the loop does between two phases, and the parts of ``step``,
+        go through ``_span``, which names them on the trace and nowhere
+        else; ``--profile_dir`` records the ``jax.profiler`` trace — the
         hl_profiler_start/end analog (hl_cuda.h:338-343), viewable in
         TensorBoard/XProf."""
         from paddle_tpu.obs import (ProfilerCapture, StepTimeline,
@@ -1030,64 +1045,70 @@ class SGDTrainer:
                 batch_id = first_batch
                 while True:
                     # one StepTraceAnnotation per batch (XProf groups by
-                    # step_num); its self time is the loop's own
-                    # bookkeeping: gang poll, extras, gauges, journal
+                    # step_num).  Everything the loop does in it lies in a
+                    # phase (_ph) or in a trace-only part (_span: poll,
+                    # extras, close), so its self time is the glue between
+                    # two ``with`` blocks (docs/observability.md)
                     with jax.profiler.StepTraceAnnotation(
                             SPAN_PREFIX + "iteration", step_num=batch_id):
-                        if tracer.enabled and not skip \
-                                and self._step_span is None:
-                            # the step-span opens BEFORE the gang poll so a
-                            # resize adopted at this boundary lands inside the
-                            # very trace whose latency it explains
-                            self._step_span = tracer.start_trace(
-                                "train_step", batch=batch_id)
-                        if gang is not None:
-                            # liveness signal from the MAIN thread: a rank
-                            # stuck in a collective stops heartbeating here
-                            # and the supervisor's watchdog gang-restarts it
-                            gang.heartbeat()
-                            # elastic resize (docs/resilience.md): a published
-                            # world change is adopted HERE, at the batch
-                            # boundary — the natural drain point.  While the
-                            # reader is still fast-forwarding (skip > 0) the
-                            # params already include every batch up to
-                            # batch_id + skip — recording the skip cursor
-                            # instead would make a restore re-apply batches
-                            # the state has already seen
-                            world = gang.poll_world()
-                            if world is not None:
-                                self._gang_resize(gang, world, pass_id,
-                                                  batch_id + skip, handler)
-                                if self._source_resharded:
-                                    # the source re-split the permutation for
-                                    # the new world: drop the old split's
-                                    # read-ahead and re-enter the pass at the
-                                    # same batch boundary.  The reshard
-                                    # positioned the cursor at batch_id+skip,
-                                    # so any remaining fast-forward (a
-                                    # datapipe source resuming without a
-                                    # manifest cursor) is cancelled — the
-                                    # skip loop would otherwise discard
-                                    # never-trained batches
-                                    self._source_resharded = False
-                                    self._close_prefetcher()
-                                    batch_id, skip = batch_id + skip, 0
-                                    it = iter(reader())
-                                    _wrap_prefetch()
-                        if preemption is not None and preemption.poll():
-                            if self._step_span is not None:
-                                # a preempted step is an incident: keep it
-                                self._step_span.retain("preempt")
-                                self._step_span.end(status="preempt")
-                                self._step_span = None
-                            # the prefetcher's read-ahead is abandoned HERE, at
-                            # the drain point: the checkpoint records the
-                            # batches the STEP consumed, so resume re-reads
-                            # the prepared-but-unstepped ones — batch-exact
-                            self._close_prefetcher()
-                            self._preempt_exit(pass_id, batch_id + skip,
-                                               preemption, handler)
-                            return
+                        with _span("poll"):
+                            if tracer.enabled and not skip \
+                                    and self._step_span is None:
+                                # the step-span opens BEFORE the gang poll so a
+                                # resize adopted at this boundary lands inside
+                                # the very trace whose latency it explains
+                                self._step_span = tracer.start_trace(
+                                    "train_step", batch=batch_id)
+                            if gang is not None:
+                                # liveness signal from the MAIN thread: a rank
+                                # stuck in a collective stops heartbeating here
+                                # and the supervisor's watchdog gang-restarts
+                                # it
+                                gang.heartbeat()
+                                # elastic resize (docs/resilience.md): a
+                                # published world change is adopted HERE, at
+                                # the batch boundary — the natural drain point.
+                                # While the reader is still fast-forwarding
+                                # (skip > 0) the params already include every
+                                # batch up to batch_id + skip — recording the
+                                # skip cursor instead would make a restore
+                                # re-apply batches the state has already seen
+                                world = gang.poll_world()
+                                if world is not None:
+                                    self._gang_resize(gang, world, pass_id,
+                                                      batch_id + skip, handler)
+                                    if self._source_resharded:
+                                        # the source re-split the permutation
+                                        # for the new world: drop the old
+                                        # split's read-ahead and re-enter the
+                                        # pass at the same batch boundary.  The
+                                        # reshard positioned the cursor at
+                                        # batch_id+skip, so any remaining
+                                        # fast-forward (a datapipe source
+                                        # resuming without a manifest cursor)
+                                        # is cancelled — the skip loop would
+                                        # otherwise discard never-trained
+                                        # batches
+                                        self._source_resharded = False
+                                        self._close_prefetcher()
+                                        batch_id, skip = batch_id + skip, 0
+                                        it = iter(reader())
+                                        _wrap_prefetch()
+                            if preemption is not None and preemption.poll():
+                                if self._step_span is not None:
+                                    # a preempted step is an incident: keep it
+                                    self._step_span.retain("preempt")
+                                    self._step_span.end(status="preempt")
+                                    self._step_span = None
+                                # the prefetcher's read-ahead is abandoned
+                                # HERE, at the drain point: the checkpoint
+                                # records the batches the STEP consumed, so
+                                # resume re-reads the prepared-but-unstepped
+                                # ones — batch-exact
+                                self._close_prefetcher()
+                                self._preempt_exit(pass_id, batch_id + skip,
+                                                   preemption, handler)
+                                return
                         with self._ph("data_wait"):
                             try:
                                 data_batch = next(it, None)
@@ -1148,10 +1169,11 @@ class SGDTrainer:
                                 loss = self.train_batch(feed)
                                 # the loop's own fetch: the phase ends with
                                 # the step's device work done
-                                with _sync_span("loss"):
+                                with _span("step.sync", reason="loss"):
                                     cost = float(loss)
                                 if self._counter_feeds:
-                                    self._feed_counters()
+                                    with _span("step.counters"):
+                                        self._feed_counters()
                         except TooManyBadSteps:
                             if self._step_span is not None:
                                 self._step_span.retain("train_abort")
@@ -1162,97 +1184,103 @@ class SGDTrainer:
                                 jr.record("train_abort",
                                           reason="too_many_bad_steps")
                             raise
-                        if tl is not None and tl.wants_mfu and \
-                                not tl.flops_attempted:
-                            # ONE extra host-side trace per compiled program,
-                            # only when a chip peak is resolvable — a failed
-                            # trace (None) is not retried per batch
-                            tl.set_flops(self.step_flops(feed))
-                            tl.recompute_mfu()
-                        if src is not None:
-                            # corrupt shard records the source skipped under
-                            # its skip-and-count policy (datapipe/iterator.py)
-                            # — surfaced next to the step extras like
-                            # dropped_features
-                            self._last_extras = {
-                                **self._last_extras,
-                                "dropped_records":
-                                    int(getattr(src, "dropped_records", 0))}
-                        drops = getattr(feeder, "dropped_features", None)
-                        if drops is not None:
-                            # sparse-bag truncation is a data-loss event, not a
-                            # debug log line: surface the feeder's counter next
-                            # to the step extras (serving mirrors it in
-                            # healthz())
-                            self._last_extras = {
-                                **self._last_extras,
-                                "dropped_features": int(drops)}
-                        costs.append(cost)
-                        if tl is not None:
-                            self._obs_gauges["cost"].set(cost)
-                            self._last_extras = {
-                                **self._last_extras,
-                                "step_time_s": tl.last.get("step"),
-                                "mfu": tl.mfu,
-                            }
+                        with _span("extras"):
+                            if tl is not None and tl.wants_mfu and \
+                                    not tl.flops_attempted:
+                                # ONE extra host-side trace per compiled
+                                # program, only when a chip peak is resolvable
+                                # — a failed trace (None) is not retried per
+                                # batch
+                                tl.set_flops(self.step_flops(feed))
+                                tl.recompute_mfu()
+                            if src is not None:
+                                # corrupt shard records the source skipped
+                                # under its skip-and-count policy
+                                # (datapipe/iterator.py) — surfaced next to the
+                                # step extras like dropped_features
+                                self._last_extras = {
+                                    **self._last_extras,
+                                    "dropped_records": int(getattr(
+                                        src, "dropped_records", 0))}
+                            drops = getattr(feeder, "dropped_features", None)
+                            if drops is not None:
+                                # sparse-bag truncation is a data-loss event,
+                                # not a debug log line: surface the feeder's
+                                # counter next to the step extras (serving
+                                # mirrors it in healthz())
+                                self._last_extras = {
+                                    **self._last_extras,
+                                    "dropped_features": int(drops)}
+                            costs.append(cost)
+                            if tl is not None:
+                                self._obs_gauges["cost"].set(cost)
+                                self._last_extras = {
+                                    **self._last_extras,
+                                    "step_time_s": tl.last.get("step"),
+                                    "mfu": tl.mfu,
+                                }
                         with self._ph("callback"):
                             handler(ev.EndIteration(pass_id, batch_id, cost))
-                        if self._step_span is not None:
-                            # the root closes here: tail sampling decides —
-                            # bad-step/resize/preempt marks always keep, the
-                            # p99 reservoir keeps outlier-slow steps, the
-                            # rest head-sample at --trace_sample
-                            sp, self._step_span = self._step_span, None
-                            sp.end(status="ok", cost=round(cost, 6))
-                        if (gang is not None and self.sdc_check_every
-                                and gang.world_size > 1
-                                and (batch_id + 1)
-                                % self.sdc_check_every == 0):
-                            # cross-replica integrity check (the SDC
-                            # firewall): exchange the step's in-jit state
-                            # fingerprint and majority-vote it
-                            try:
-                                self._sdc_check(gang, pass_id, batch_id,
-                                                handler)
-                            # invariant: _SdcRollback is not a one-rank
-                            # escape — the vote itself is the collective, and
-                            # _sdc_check raises on EVERY rank or on none, so
-                            # no peer is left blocked in exchange_json
-                            except _SdcRollback as rb:  # tpu-lint: disable=protocol-exception
-                                start_pass = rb.start_pass
-                                start_batch = rb.start_batch
-                                cursor_restored = False
-                                if rb.cursor_ready:
-                                    cursor_restored = True
-                                elif (src is not None
-                                      and self._pending_cursor is not None):
-                                    src.restore(self._pending_cursor)
-                                    cursor_restored = True
-                                    self._pending_cursor = None
-                                schedule.rewind(start_pass)
-                                rolled_back = True
-                                break
-                        if log_period and (batch_id + 1) % log_period == 0:
-                            logger.info(
-                                "Pass %d, Batch %d, Cost %.5f (%.1f batch/s)",
-                                pass_id, batch_id + 1,
-                                float(np.mean(costs[-log_period:])),
-                                log_period / max(time.time() - t0, 1e-9),
-                            )
-                            t0 = time.time()
-                        psp = FLAGS.show_parameter_stats_period
-                        if psp and (batch_id + 1) % psp == 0:
-                            self._log_parameter_stats()
-                        tp = FLAGS.test_period
-                        if (tp and test_reader is not None
-                                and (batch_id + 1) % tp == 0):
-                            # mid-pass eval — test_period batches
-                            # (Trainer.cpp trainOneBatch "testing" branch;
-                            # 0 = per pass only)
-                            with self._ph("eval"):
-                                mid = self.test(test_reader, feeder=feeder)
-                            logger.info("Pass %d, Batch %d, Test cost %.5f",
-                                        pass_id, batch_id + 1, mid["cost"])
+                        with _span("close"):
+                            if self._step_span is not None:
+                                # the root closes here: tail sampling decides —
+                                # bad-step/resize/preempt marks always keep,
+                                # the p99 reservoir keeps outlier-slow steps,
+                                # the rest head-sample at --trace_sample
+                                sp, self._step_span = self._step_span, None
+                                sp.end(status="ok", cost=round(cost, 6))
+                            if (gang is not None and self.sdc_check_every
+                                    and gang.world_size > 1
+                                    and (batch_id + 1)
+                                    % self.sdc_check_every == 0):
+                                # cross-replica integrity check (the SDC
+                                # firewall): exchange the step's in-jit state
+                                # fingerprint and majority-vote it
+                                try:
+                                    self._sdc_check(gang, pass_id, batch_id,
+                                                    handler)
+                                # invariant: _SdcRollback is not a one-rank
+                                # escape — the vote itself is the collective,
+                                # and _sdc_check raises on EVERY rank or on
+                                # none, so no peer is left blocked in
+                                # exchange_json
+                                except _SdcRollback as rb:  # tpu-lint: disable=protocol-exception
+                                    start_pass = rb.start_pass
+                                    start_batch = rb.start_batch
+                                    cursor_restored = False
+                                    if rb.cursor_ready:
+                                        cursor_restored = True
+                                    elif (src is not None and
+                                          self._pending_cursor is not None):
+                                        src.restore(self._pending_cursor)
+                                        cursor_restored = True
+                                        self._pending_cursor = None
+                                    schedule.rewind(start_pass)
+                                    rolled_back = True
+                                    break
+                            if log_period and (batch_id + 1) % log_period == 0:
+                                logger.info(
+                                    "Pass %d, Batch %d, Cost %.5f "
+                                    "(%.1f batch/s)",
+                                    pass_id, batch_id + 1,
+                                    float(np.mean(costs[-log_period:])),
+                                    log_period / max(time.time() - t0, 1e-9),
+                                )
+                                t0 = time.time()
+                            psp = FLAGS.show_parameter_stats_period
+                            if psp and (batch_id + 1) % psp == 0:
+                                self._log_parameter_stats()
+                            tp = FLAGS.test_period
+                            if (tp and test_reader is not None
+                                    and (batch_id + 1) % tp == 0):
+                                # mid-pass eval — test_period batches
+                                # (Trainer.cpp trainOneBatch "testing" branch;
+                                # 0 = per pass only)
+                                with self._ph("eval"):
+                                    mid = self.test(test_reader, feeder=feeder)
+                                logger.info(
+                                    "Pass %d, Batch %d, Test cost %.5f",
+                                    pass_id, batch_id + 1, mid["cost"])
                     batch_id += 1
                 self._close_prefetcher()
                 if rolled_back:

@@ -53,6 +53,19 @@ def rng():
     return np.random.RandomState(0)
 
 
+@pytest.fixture
+def own_registry():
+    """The metrics registry is the process's: a test that feeds counters
+    under names other files use too (``moe_assignments{layer, expert}``: the
+    benchmark's tiny cells hold experts 0 and 1 of the same layers) starts
+    from an empty one and leaves none of its own for the worker's next."""
+    from paddle_tpu.obs import reset_registry
+
+    reset_registry()
+    yield
+    reset_registry()
+
+
 def on_accelerator() -> bool:
     """True when the suite was launched in hardware mode
     (PADDLE_TPU_TEST_BACKEND=tpu): matmul precision is bf16-passes, FD

@@ -549,7 +549,7 @@ def test_gate_and_the_backward_s_resident_keys(monkeypatch, dqk, dv, hkv):
 # -- through the trainer ---------------------------------------------------------
 
 
-def test_trainer_feeds_the_routing_counters(ref, program_file):
+def test_trainer_feeds_the_routing_counters(own_registry, ref, program_file):
     from paddle_tpu.obs import get_registry
     from paddle_tpu.param.optimizers import Adam
     from paddle_tpu.trainer import SGDTrainer

@@ -459,7 +459,7 @@ def test_grouped_product_kernels_match_the_masked_loop():
 # -- through the trainer ---------------------------------------------------------
 
 
-def test_trainer_feeds_the_routing_counters(ref):
+def test_trainer_feeds_the_routing_counters(own_registry, ref):
     from paddle_tpu.obs import get_registry
     from paddle_tpu.param.optimizers import Adam
     from paddle_tpu.trainer import SGDTrainer

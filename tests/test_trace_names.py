@@ -200,12 +200,17 @@ def test_optimizer_apply_encloses_every_equation_of_the_update(
 # -- (c) the trainer's host spans -------------------------------------------
 
 
-def _spans_of_one_pass(tmp_path, batches=3):
-    """``SGDTrainer.train`` over a few toy batches under the profiler: the
-    ``paddle_tpu.trainer.*`` events of the thread that drove the loop, as
-    ``(start, end, name without the prefix, stats)`` by start."""
-    from jax.profiler import ProfileData
+#: what may lie between two ``with`` blocks of the loop: a test of a
+#: condition, an assignment, the exit of one annotation and the entry of the
+#: next, a phase's own record in ``_ph`` (1-13 us here, the smallest of five
+#: readings); a line of work left without a span costs more (the key split
+#: alone is 400-600 us here)
+GLUE_NS = 50_000
 
+
+def _trace_of_one_pass(tmp_path, batches=5, feeder=None):
+    """``SGDTrainer.train`` over a few toy batches under the profiler: the
+    trace's file."""
     nn.reset_naming()
     x = nn.data("x", size=4)
     cost = nn.mse_cost(input=nn.fc(x, 2, name="o"),
@@ -218,25 +223,40 @@ def _spans_of_one_pass(tmp_path, batches=3):
             yield {"x": rng.rand(4, 4).astype(np.float32),
                    "y": rng.rand(4, 2).astype(np.float32)}
 
-    tr.train(reader, num_passes=1)          # compiles outside the trace
+    tr.train(reader, num_passes=1, feeder=feeder)   # compiles outside
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
-        tr.train(reader, num_passes=1)
+        tr.train(reader, num_passes=1, feeder=feeder)
     finally:
         jax.profiler.stop_trace()
     found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                       recursive=True)
     assert len(found) == 1
+    return found[0]
+
+
+def _threads_with(path, prefix):
+    """The events under ``prefix`` of every thread that has one, as
+    ``(start, end, name without the prefix, stats)`` by start."""
+    from jax.profiler import ProfileData
+
     lines = []
-    for plane in ProfileData.from_file(found[0]).planes:
+    for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             spans = [(e.start_ns, e.start_ns + e.duration_ns,
-                      e.name[len(SPAN_PREFIX):], dict(e.stats))
-                     for e in line.events if e.name.startswith(SPAN_PREFIX)]
+                      e.name[len(prefix):], dict(e.stats))
+                     for e in line.events if e.name.startswith(prefix)]
             if spans:
                 lines.append(sorted(spans, key=lambda s: (s[0], -s[1])))
+    return lines
+
+
+def _loop_spans(path):
+    """The ``paddle_tpu.trainer.*`` events of the thread that drove the
+    loop."""
+    lines = _threads_with(path, SPAN_PREFIX)
     assert len(lines) == 1, "the loop's spans are on one thread"
     return lines[0]
 
@@ -248,7 +268,7 @@ def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
     from paddle_tpu.utils.flags import FLAGS
 
     monkeypatch.setattr(FLAGS, "obs_timeline", timeline)
-    spans = _spans_of_one_pass(tmp_path)
+    spans = _loop_spans(_trace_of_one_pass(tmp_path))
 
     def inside(outer):
         return [s for s in spans if s is not outer
@@ -256,20 +276,64 @@ def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
 
     iterations = [s for s in spans if s[2] == "iteration"]
     # one per batch, and the one that found the reader empty
-    assert [s[3]["step_num"] for s in iterations] == [0, 1, 2, 3]
-    assert [c[2] for c in inside(iterations[3])] == ["data_wait"]
-    for it in iterations[:3]:
+    assert [s[3]["step_num"] for s in iterations] == [0, 1, 2, 3, 4, 5]
+    assert [c[2] for c in inside(iterations[-1])] == ["poll", "data_wait"]
+    holes = []
+    for it in iterations[:-1]:
         children = [c[2] for c in inside(it)]
-        assert children == ["data_wait", "callback", "prepare", "step",
-                            "step.dispatch", "step.sync", "step.sync",
-                            "callback"]
+        assert children == ["poll", "data_wait", "callback", "prepare",
+                            "step", "step.rng", "step.dispatch",
+                            "step.post", "step.sync", "step.sync", "extras",
+                            "callback", "close"]
         step = next(c for c in inside(it) if c[2] == "step")
         in_step = inside(step)
-        assert [c[2] for c in in_step] == ["step.dispatch", "step.sync",
+        assert [c[2] for c in in_step] == ["step.rng", "step.dispatch",
+                                           "step.post", "step.sync",
                                            "step.sync"]
         # one span per blocking fetch, with its reason
-        assert [c[3]["reason"] for c in in_step[1:]] == ["guard", "loss"]
+        assert [c[3]["reason"] for c in in_step[3:]] == ["guard", "loss"]
+        # the host's turn-around is named in full: from the end of one
+        # step.dispatch to the start of the next, so all through the
+        # iteration, what no child of ``iteration`` or of ``step`` covers
+        # is the glue between two ``with`` blocks
+        named = [c for c in inside(it) if c[2] != "step"]
+        edges = [it[0]] + [t for c in named for t in c[:2]] + [it[1]]
+        holes.append([edges[i + 1] - edges[i]
+                      for i in range(0, len(edges), 2)])
+    # the same holes every iteration; each one's smallest reading, so that
+    # a thread switch inside one of them does not decide (ns on the CPU)
+    assert len({len(h) for h in holes}) == 1
+    assert max(min(h) for h in zip(*holes)) < GLUE_NS, holes
     # what a pass does once (here the EndPass callback) is outside them
     outside = [s[2] for s in spans if s[2] != "iteration" and not any(
         it[0] <= s[0] and s[1] <= it[1] for it in iterations)]
     assert outside == ["callback"]
+
+
+@pytest.mark.parametrize("depth", [2, 0], ids=["prefetch_2", "prefetch_off"])
+def test_prefetch_thread_names_its_work_on_a_thread_of_its_own(
+        depth, tmp_path, monkeypatch):
+    """With ``--prefetch_depth`` the loop's ``prepare`` is an attribute read:
+    the feeder and the transfer run on the ``batch-prefetch`` thread, which
+    records ``paddle_tpu.data.prefetch.prepare`` / ``.h2d`` / ``.put``, one of
+    each a batch, under a prefix the loop's readers do not match."""
+    from paddle_tpu.data.feeder import BatchPrefetcher
+    from paddle_tpu.utils.flags import FLAGS
+
+    monkeypatch.setattr(FLAGS, "prefetch_depth", depth)
+    # the CPU aliases host buffers, so the trainer passes no transfer here:
+    # make it pass the one it passes on an accelerator
+    monkeypatch.setattr(SGDTrainer, "_h2d_measurable", True)
+    prefix = BatchPrefetcher.SPAN_PREFIX
+    assert not prefix.startswith(SPAN_PREFIX)
+    path = _trace_of_one_pass(tmp_path, feeder=lambda batch: dict(batch))
+    threads = _threads_with(path, prefix)
+    loop = {s[2] for s in _loop_spans(path)}    # still on ONE thread
+    assert {"iteration", "prepare", "step.rng"} <= loop
+    # the transfer is the loop's own phase only while nothing prefetches
+    assert "put" not in loop and ("h2d" in loop) == (not depth)
+    if not depth:
+        assert threads == []
+        return
+    assert len(threads) == 1
+    assert [s[2] for s in threads[0]] == ["prepare", "h2d", "put"] * 5

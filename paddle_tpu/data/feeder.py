@@ -113,21 +113,41 @@ class BatchPrefetcher:
 
     Wraps a raw batch iterator: a background thread pulls batch N+1..N+depth,
     runs ``prepare`` (the DataFeeder) and ``transfer`` (synced ``device_put``)
-    on them, and parks the results in a bounded queue — all of it OVERLAPPED
-    with the device step of batch N, so the training loop's ``data_wait`` /
-    ``prepare`` / ``h2d`` phases collapse to a queue pop.  Semantics are
-    loop-equivalent to serial feeding:
+    on them, and parks the results in a bounded queue, so the training loop's
+    ``data_wait`` / ``prepare`` / ``h2d`` phases collapse to a queue pop.
 
-    - order is preserved exactly (single producer, FIFO queue);
+    WHEN the thread works is the consumer's choice.  The thread sleeps in
+    ``put`` on a full queue and is woken by whoever frees a place.  A
+    consumer that only calls ``next()`` frees it when it NEEDS a batch, which
+    in a training loop is the turn-around between two device steps: the
+    thread's ``prepare`` and ``h2d`` then run while the device waits, on the
+    interpreter the loop needs to launch the next step (PERF.md section 6,
+    PR 38).  ``take()`` is for that: the trainer calls it right after it
+    has dispatched a step, the next item moves from the queue into ONE
+    consumer-side slot without blocking, and the thread's work on a later
+    batch runs under the device step; the following ``next()`` returns the
+    slot and touches no queue, so the thread sleeps across the turn-around.
+    ``prefetch_taken_total{when="dispatch"|"late"}`` counts, one a batch,
+    whether ``next()`` found the slot filled or had to go to the queue
+    (the queue was empty at ``take()``, ``take()`` was never called, or it
+    is a pass's first batch).  Semantics are loop-equivalent to serial
+    feeding:
+
+    - order is preserved exactly (single producer, FIFO queue, and the slot
+      holds the queue's head);
     - a reader/feeder exception is re-raised at the consumer's ``next()``,
-      so the trainer's reader-attribution path is unchanged;
-    - the queue depth bounds read-ahead: at most ``depth`` prepared batches
-      (plus the one in flight) exist, so a preemption or resize at a batch
-      boundary abandons a bounded amount of work and the resume point —
-      which counts batches the STEP consumed, not batches read ahead —
-      stays batch-exact;
-    - ``close()`` stops the producer and joins it (called by the trainer at
-      pass end, preemption exit, and on any loop exception).
+      so the trainer's reader-attribution path is unchanged: one that
+      ``take()`` finds waits in the slot, like the end marker, for the
+      ``next()`` that would have met it;
+    - read-ahead is bounded: at most ``depth`` prepared batches in the
+      queue, one in the slot and the one in the producer's hands exist
+      (``depth + 2``; ``depth + 1`` for a consumer that never calls
+      ``take()``), so a preemption or resize at a batch boundary abandons a
+      bounded amount of work and the resume point — which counts batches
+      the STEP consumed, not batches read ahead — stays batch-exact;
+    - ``close()`` drops the slot, stops the producer and joins it (called
+      by the trainer at pass end, preemption exit, and on any loop
+      exception).
     """
 
     _DONE = object()
@@ -140,7 +160,17 @@ class BatchPrefetcher:
 
     def __init__(self, it: Iterator, *, prepare: Optional[Callable] = None,
                  transfer: Optional[Callable] = None, depth: int = 2) -> None:
+        from paddle_tpu.obs import get_registry
+
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(depth)))
+        self._slot: Any = None  # the consumer thread's alone
+        self._taken = {
+            when: get_registry().counter(
+                "prefetch_taken_total",
+                "batches by where the loop took them from the prefetch "
+                "queue: at the step's dispatch, or late in data_wait",
+                labels=("when",), when=when)
+            for when in ("dispatch", "late")}
         self._stop = threading.Event()
         self._prepare = prepare
         self._transfer = transfer
@@ -188,19 +218,36 @@ class BatchPrefetcher:
     def __iter__(self) -> "BatchPrefetcher":
         return self
 
+    def take(self) -> bool:
+        """Move the queue's head into the slot if there is one and the slot
+        is free; never blocks.  Whatever it is (a batch, the end marker, an
+        exception) is delivered by the next ``next()``.  True where the
+        slot is filled afterwards."""
+        if self._slot is None:
+            try:
+                self._slot = self._q.get_nowait()
+            except queue.Empty:
+                return False
+        return True
+
     def __next__(self) -> PreparedFeed:
-        item = self._q.get()
+        item, self._slot, when = self._slot, None, "dispatch"
+        if item is None:
+            item, when = self._q.get(), "late"
         if item is self._DONE:
             raise StopIteration
         if isinstance(item, BaseException):
             raise item
+        self._taken[when].inc()
         return item
 
     def close(self) -> None:
-        """Stop the producer and join it; pending prepared batches are
-        dropped (the consumer's batch counter, not the read-ahead cursor,
-        is the resume point — docs/mixed_precision.md 'feeding')."""
+        """Stop the producer and join it; pending prepared batches, the
+        slot's among them, are dropped (the consumer's batch counter, not
+        the read-ahead cursor, is the resume point —
+        docs/mixed_precision.md 'feeding')."""
         self._stop.set()
+        self._slot = None
         while True:  # unblock a producer stuck in put()
             try:
                 self._q.get_nowait()

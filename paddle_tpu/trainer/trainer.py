@@ -19,7 +19,7 @@ from __future__ import annotations
 import json
 import os
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -495,6 +495,20 @@ class SGDTrainer:
 
         # kept un-jitted for the lint auditor (audit() re-traces it)
         self._step_fn = step
+
+        def step_and_split(params, state, opt_state, ps, rng, feed):
+            # what jit compiles: the trainer's key is split INSIDE the
+            # program (the same threefry split, so the same keys bit for
+            # bit as a split on the host before every step) and the half
+            # the step does not use comes back as the next ``self._rng``:
+            # one executable a step, not two
+            rng, key = jax.random.split(rng)
+            return step(params, state, opt_state, ps, key, feed) + (rng,)
+
+        # the function jit traces, for step_flops: tracing THIS one again
+        # finds jit's own trace in the cache, where tracing ``step`` would
+        # walk the whole model a second time (seconds of set-up)
+        self._step_jitted_fn = step_and_split
         if self.mesh is not None:
             # params/opt slots were placed ONCE at init (or after load) with
             # their rule-derived shardings; the jitted step consumes and
@@ -503,16 +517,16 @@ class SGDTrainer:
             # Mosaic kernels do not survive: their gates keep to XLA paths)
             from paddle_tpu.ops.pallas_kernels import xla_paths_only
 
-            traced = (xla_paths_only()(step) if self.mesh.size > 1
-                      else step)
-            jitted = jax.jit(traced, donate_argnums=(0, 2, 3))
+            traced = (xla_paths_only()(step_and_split)
+                      if self.mesh.size > 1 else step_and_split)
+            jitted = jax.jit(traced, donate_argnums=(0, 2, 3, 4))
 
             def run(params, state, opt_state, ps, rng, feed):
                 feed = self._shard_feed(feed)
                 return jitted(params, state, opt_state, ps, rng, feed)
 
             return run
-        return jax.jit(step, donate_argnums=(0, 2, 3))
+        return jax.jit(step_and_split, donate_argnums=(0, 2, 3, 4))
 
     def _param_shardings(self):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -533,13 +547,17 @@ class SGDTrainer:
 
     def _place_sharded(self) -> None:
         """Place params at their rule shardings and every optimizer slot at
-        its parameter's sharding; BN state and scalars replicated."""
+        its parameter's sharding; BN state, scalars and the key replicated."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         sh = self._param_shardings()
         repl = NamedSharding(self.mesh, P())
         self.params = {k: jax.device_put(v, sh[k]) for k, v in self.params.items()}
         self.state = jax.device_put(self.state, repl)
+        # the key the step returns is replicated over the mesh: a fresh one
+        # (init, load) placed otherwise would show the second call another
+        # sharding, and the step would compile again
+        self._rng = jax.device_put(self._rng, repl)
 
         def put_like(name):
             def put(leaf):
@@ -641,14 +659,16 @@ class SGDTrainer:
         """Analytic matmul+conv FLOPs of ONE train step (forward +
         backward + optimizer), from the SAME ``analysis.flops`` walker
         ``bench.py`` uses — the live MFU gauge and the bench rows cannot
-        disagree (pinned by tests/test_obs.py)."""
+        disagree (pinned by tests/test_obs.py).  Traced through the
+        function jit wraps (the step with the key's split before it, which
+        adds no product), so after a first step this is a look-up."""
         from paddle_tpu.analysis.flops import jaxpr_flops
 
         if self.mesh is not None:
             feed = self._shard_feed(feed)
         ps = self.pserver.state() if self.pserver is not None else {}
         rng = jax.random.PRNGKey(0)
-        return jaxpr_flops(self._step_fn, self.params, self.state,
+        return jaxpr_flops(self._step_jitted_fn, self.params, self.state,
                            self.opt_state, ps, rng, feed)
 
     # ------------------------------------------------------------------
@@ -713,6 +733,20 @@ class SGDTrainer:
     def train_batch(self, feed: Dict[str, Any]) -> float:
         """Run one optimizer step on a prepared feed dict; returns cost.
 
+        Between the end of one step on the device and the launch of the
+        next the host makes ONE dispatch and ONE fetch: the trainer's key
+        is split inside the compiled step (``_build_step``; the program
+        returns the next ``self._rng``), and everything the host reads of
+        a step (the guard's flag, ``amp_overflow`` and the scale under
+        ``--amp``, the loss, the counter outputs) starts its copy to the
+        host at dispatch, so the one ``step.sync`` here waits for them
+        together and the ``float()`` of the returned cost waits for
+        nothing.  With neither guard nor ``--amp`` this does not block at
+        all and the caller's ``float()`` is the fetch.  Inside ``train()``
+        the prefetcher is told at dispatch to hand over the next batch
+        (``BatchPrefetcher.take``), so its thread works under this step
+        and sleeps across the turn-around.
+
         With the bad-step guard on, a non-finite loss/grad step leaves
         params, optimizer slots, and layer state untouched (the skip
         happens inside the jitted step — resilience/guard.py); the skip
@@ -722,17 +756,31 @@ class SGDTrainer:
         ``TooManyBadSteps`` — persistent non-finite training cannot
         recover by skipping."""
         with _span("step.rng"):
-            # what the step takes besides the trainer's own state: the key,
-            # split op by op before every step, and the pserver's tables
-            self._rng, key = jax.random.split(self._rng)
+            # what the step takes besides the trainer's own state: the
+            # pserver's tables (the key is split inside the program)
             ps = self.pserver.state() if self.pserver is not None else {}
         with _span("step.dispatch"):
-            loss, self.params, self.state, self.opt_state, new_ps, extras = (
-                self._step(self.params, self.state, self.opt_state, ps, key,
-                           feed))
+            (loss, self.params, self.state, self.opt_state, new_ps, extras,
+             self._rng) = self._step(self.params, self.state, self.opt_state,
+                                     ps, self._rng, feed)
+        amp = self.amp and "amp_overflow" in extras
+        guard = (self.guard_nonfinite or self.amp) and "bad_step" in extras
+        # what the host reads of every step, in the order it reads it
+        fetched = ([extras["amp_overflow"], extras["loss_scale"]]
+                   if amp else []) + ([extras["bad_step"]] if guard else [])
+        fetched.append(loss)
         with _span("step.post"):
             # the device is running the step; the host's own lines up to
-            # the first fetch
+            # the fetch.  Every copy to the host starts now, behind the
+            # step: the fetch below (or the caller's) is one wait, and
+            # _feed_counters reads after it
+            for arr in [*fetched,
+                        *(extras[name] for name, _ in self._counter_feeds)]:
+                arr.copy_to_host_async()
+            if self._prefetcher is not None:
+                # the next batch leaves the queue NOW, so the producer's
+                # prepare and h2d of a later one run under this step
+                self._prefetcher.take()
             if self.pserver is not None:
                 self.pserver.adopt(new_ps)
             if self.averager is not None:
@@ -740,10 +788,6 @@ class SGDTrainer:
                                                        self.params)
             self._obs_counters["batches"].inc()
             self._last_extras = extras
-            for name, _ in self._counter_feeds:
-                # starts the copy now; _feed_counters reads it after the
-                # loss
-                extras[name].copy_to_host_async()
             if self._gang is not None:
                 self._obs_gauges["world"].set(self._gang.world_size)
                 # elastic observability: the live world, whether it is
@@ -756,25 +800,27 @@ class SGDTrainer:
                     "resize_count": self._resize_count,
                     "last_resize_reason": self._last_resize_reason,
                 }
-        if self.amp and "amp_overflow" in extras:
-            with _span("step.sync", reason="amp"):
-                overflow = bool(jax.device_get(extras["amp_overflow"]))
-            if overflow:
-                self.amp_overflows_total += 1
-                scale = float(jax.device_get(extras["loss_scale"]))
-                if self._journal is not None:
-                    # a rescale is part of the causal story of an --amp
-                    # run — journaled like bad_step, next to its context
-                    self._journal.record("amp_overflow", scale=scale,
-                                         total=self.amp_overflows_total)
-                logger.warning(
-                    "amp: non-finite scaled gradients — step skipped, "
-                    "loss scale halved to %g (overflow %d)", scale,
-                    self.amp_overflows_total)
-        if (self.guard_nonfinite or self.amp) and "bad_step" in extras:
-            with _span("step.sync", reason="guard"):
-                bad = bool(jax.device_get(extras["bad_step"]))
-            if bad:
+        if amp or guard:
+            # the ONE blocking fetch of a step: the flags and the loss
+            # arrive together, so the reads below and the caller's
+            # float(loss) find them on the host.  ``reason``: the first
+            # thing waited for
+            with _span("step.sync", reason="amp" if amp else "guard"):
+                jax.device_get(fetched)
+        if amp and bool(extras["amp_overflow"]):
+            self.amp_overflows_total += 1
+            scale = float(extras["loss_scale"])
+            if self._journal is not None:
+                # a rescale is part of the causal story of an --amp
+                # run — journaled like bad_step, next to its context
+                self._journal.record("amp_overflow", scale=scale,
+                                     total=self.amp_overflows_total)
+            logger.warning(
+                "amp: non-finite scaled gradients — step skipped, "
+                "loss scale halved to %g (overflow %d)", scale,
+                self.amp_overflows_total)
+        if guard:
+            if bool(extras["bad_step"]):
                 self.bad_steps_total += 1
                 self._bad_streak += 1
                 self._obs_counters["bad_steps"].inc()
@@ -1017,18 +1063,20 @@ class SGDTrainer:
 
                 def _wrap_prefetch():
                     # double-buffered async feeding (--prefetch_depth):
-                    # prepare + h2d of batch N+1 overlap the device step
-                    # of batch N in a background thread; the loop below
-                    # sees PreparedFeed markers and skips its own
-                    # prepare/h2d phases.  Built lazily AFTER the resume
-                    # fast-forward (skipped batches are consumed raw — no
-                    # prepare/h2d paid for batches the skip discards) and
+                    # prepare + h2d of a later batch run in a background
+                    # thread, under the device step of batch N because
+                    # train_batch takes batch N+1 out of the queue when it
+                    # has dispatched N; the loop below sees PreparedFeed
+                    # markers and skips its own prepare/h2d phases.  Built
+                    # lazily AFTER the resume fast-forward (skipped batches
+                    # are consumed raw — no prepare/h2d paid for batches
+                    # the skip discards) and
                     # closed at every loop exit (pass end, preemption,
                     # exception) so a drain point never leaves a torn
                     # batch.  An elastic resize mid-pass needs no rebuild:
                     # ``transfer`` reads self.mesh at call time, and the
                     # jitted runner re-shards every feed per batch, so the
-                    # <=depth feeds prepared under the old mesh are
+                    # <=depth+1 feeds prepared under the old mesh are
                     # re-placed exactly like the params themselves.
                     nonlocal it
                     if FLAGS.prefetch_depth > 0:
@@ -1167,9 +1215,12 @@ class SGDTrainer:
                         try:
                             with self._ph("step"):
                                 loss = self.train_batch(feed)
-                                # the loop's own fetch: the phase ends with
-                                # the step's device work done
-                                with _span("step.sync", reason="loss"):
+                                # the phase ends with the step's device work
+                                # done: where train_batch fetched a flag the
+                                # loss came with it, else this is the fetch
+                                with (nullcontext()
+                                      if self.guard_nonfinite or self.amp
+                                      else _span("step.sync", reason="loss")):
                                     cost = float(loss)
                                 if self._counter_feeds:
                                     with _span("step.counters"):

@@ -231,6 +231,31 @@ def test_bench_and_live_mfu_paths_report_identical_flops():
     assert live > 0
 
 
+def test_live_gauge_reads_the_trace_jit_made_of_the_step():
+    """``step_flops`` runs in the first iteration of every ``train()``: it
+    asks for the trace of the function the trainer's jit wraps, which the
+    first step left in the tracing cache, and does not walk the model a
+    second time (seconds of set-up at a real size)."""
+    import jax.monitoring
+
+    traces, armed = [], [False]
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **kw: traces.append(name)
+        if armed[0] and name.endswith("jaxpr_trace_duration") else None)
+    tr = _tiny_trainer()
+    feed = _feeds(1)[0]
+    armed[0] = True
+    try:
+        tr.train_batch(feed)
+        of_the_step, traces[:] = len(traces), []
+        flops = tr.step_flops(feed)
+        of_the_gauge = len(traces)
+    finally:
+        armed[0] = False
+    assert flops and of_the_step > 10 * max(of_the_gauge, 1), (
+        of_the_step, of_the_gauge)
+
+
 def test_chip_peak_tables_resolve_tpu_kinds_only():
     from paddle_tpu.analysis.flops import chip_peak_bandwidth, chip_peak_flops
 

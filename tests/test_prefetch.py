@@ -8,7 +8,12 @@ prepare + h2d of batch N+1 overlap the device step of batch N.  Semantics
 are loop-equivalent: identical training trajectory, reader errors still
 attributed to the data tier, bounded read-ahead, and clean drains at
 preemption boundaries (resume stays batch-exact — the checkpoint records
-batches the STEP consumed, not the read-ahead cursor).
+batches the STEP consumed, not the read-ahead cursor).  The trainer takes
+the next batch out of the queue when it DISPATCHES a step
+(``BatchPrefetcher.take``), so the producer works under the device step and
+sleeps across the turn-around; what is taken early is delivered where it
+would have been.  Those tests wait on events the reader or the feeder sets,
+never on a clock.
 """
 
 import threading
@@ -82,24 +87,139 @@ def test_prefetcher_propagates_reader_exception():
     pf.close()
 
 
-def test_prefetcher_bounded_readahead():
-    """The producer reads at most depth (queued) + 1 (in flight) batches
-    ahead of the consumer — bounded abandoned work at a drain point."""
-    pulled = []
-    gate = threading.Event()
+WAIT_S = 30.0   # an event that does not come is a failure, not a slow box
 
-    def gen():
-        for i in range(50):
-            pulled.append(i)
-            yield i
 
-    pf = BatchPrefetcher(iter(gen()), depth=2)
-    gate.wait(0.3)  # let the producer run ahead as far as it can
-    assert len(pulled) <= 2 + 1
-    next(pf)
-    gate.wait(0.2)
-    assert len(pulled) <= 2 + 2
+class _Pulls:
+    """A reader that says how far the producer has read: ``wait(n)`` returns
+    once item ``n`` (0-based) has been asked for, or the reader has ended."""
+
+    def __init__(self, items, *, fail=None):
+        self.items, self.fail = list(items), fail
+        self.pulled = []
+        self._events = [threading.Event() for _ in range(len(self.items) + 1)]
+
+    def __iter__(self):
+        for i, item in enumerate(self.items):
+            self.pulled.append(i)
+            self._events[i].set()
+            yield item
+        self._events[-1].set()
+        if self.fail is not None:
+            raise self.fail
+
+    def wait(self, n):
+        assert self._events[min(n, len(self.items))].wait(WAIT_S)
+
+
+def _taken():
+    from paddle_tpu.obs import get_registry
+
+    return {when: get_registry().counter(
+        "prefetch_taken_total", labels=("when",), when=when).value
+        for when in ("dispatch", "late")}
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("take", [False, True], ids=["next_only", "take"])
+def test_prefetcher_bounded_readahead(depth, take):
+    """The producer reads at most depth (queued) + 1 (in its hands) batches
+    ahead of a consumer that only calls ``next()``, and one more, the slot's,
+    ahead of one that takes at dispatch — bounded abandoned work at a drain
+    point.  The bound holds at every instant, not after a pause: the
+    producer asks the reader for an item only once the one before is in the
+    queue."""
+    src = _Pulls(range(50))
+    pf = BatchPrefetcher(iter(src), depth=depth)
+    bound = depth + 1
+    src.wait(bound - 1)             # it has read as far ahead as it can
+    assert len(src.pulled) == bound
+    if take:
+        assert pf.take()            # frees a place: one more is read
+        assert pf.take()            # the slot is filled: nothing moves
+        bound += 1
+        src.wait(bound - 1)
+        assert len(src.pulled) == bound
+        assert next(pf).feed == 0   # the slot's: the queue is not touched
+        assert len(src.pulled) == bound
+    assert next(pf).feed == int(take)       # the queue's head: a place free
+    src.wait(bound)
+    assert len(src.pulled) == bound + 1
+    first = int(take) + 1
+    assert [next(pf).feed for _ in range(3)] == [first, first + 1, first + 2]
     pf.close()
+
+
+def test_take_at_dispatch_wakes_the_producer_and_next_lets_it_sleep():
+    """``take()`` is what frees the queue's place: the producer, asleep in
+    ``put`` with a prepared batch in its hands, puts it and goes on to
+    prepare the next one; the ``next()`` that follows returns the slot and
+    touches no queue, so nothing wakes."""
+    prepared = []
+    seen = [threading.Event() for _ in range(8)]
+
+    def prepare(raw):
+        prepared.append(raw)
+        seen[raw].set()
+        return raw
+
+    before = _taken()
+    pf = BatchPrefetcher(iter(range(8)), prepare=prepare, depth=1)
+    assert seen[1].wait(WAIT_S)      # 0 queued, 1 in its hands: asleep
+    assert prepared == [0, 1] and pf._q.full()
+    assert pf.take()
+    assert seen[2].wait(WAIT_S)      # woken by the take alone
+    assert prepared == [0, 1, 2] and pf._q.full()
+    assert next(pf).feed == 0        # from the slot
+    assert pf._q.full() and prepared == [0, 1, 2]    # the queue untouched
+    assert next(pf).feed == 1        # no take before it: from the queue
+    assert seen[3].wait(WAIT_S)
+    pf.close()
+    after = _taken()
+    assert after["dispatch"] - before["dispatch"] == 1
+    assert after["late"] - before["late"] == 1
+
+
+@pytest.mark.parametrize("end", ["end_marker", "reader_error",
+                                 "prepare_error"])
+def test_what_is_taken_early_is_delivered_at_its_own_next(end):
+    """An exception or the end marker that ``take()`` finds at the queue's
+    head waits in the slot: it surfaces at the ``next()`` where it would
+    have without a take, after the batch before it was handed over."""
+    from paddle_tpu.data.feeder import PrepareError
+
+    src = _Pulls([1, 2],
+                 fail=IOError("disk gone") if end == "reader_error" else None)
+
+    def prepare(raw):
+        if end == "prepare_error" and raw == 2:
+            raise TypeError("bad slot")
+        return raw
+
+    pf = BatchPrefetcher(iter(src), prepare=prepare, depth=2)
+    last = 1 if end == "prepare_error" else 2
+    src.wait(last)                   # read to its end (or to the bad batch)
+    for want in range(1, last + 1):
+        assert pf.take()             # a batch goes into the slot
+        assert next(pf).feed == want
+    pf._thread.join(WAIT_S)          # the terminal item is in the queue
+    assert pf.take()                 # taken early: nothing is raised here
+    assert pf.take()
+    with pytest.raises({"end_marker": StopIteration,
+                        "reader_error": IOError,
+                        "prepare_error": PrepareError}[end]):
+        next(pf)
+    assert pf._slot is None
+    pf.close()
+
+
+def test_close_drops_a_filled_slot_and_joins():
+    src = _Pulls(range(50))
+    pf = BatchPrefetcher(iter(src), depth=2)
+    src.wait(2)
+    assert pf.take() and pf._slot is not None
+    pf.close()
+    assert pf._slot is None and not pf._thread.is_alive()
 
 
 def test_prefetcher_close_joins_producer_quickly():
@@ -136,6 +256,67 @@ def test_prefetch_training_trajectory_identical(monkeypatch):
     np.testing.assert_array_equal(losses[0][0], losses[2][0])
     for k in losses[0][1]:
         np.testing.assert_array_equal(losses[0][1][k], losses[2][1][k])
+
+
+@pytest.mark.parametrize("reader_is", ["ahead", "behind"])
+def test_trainer_takes_the_next_batch_at_dispatch(reader_is, monkeypatch):
+    """``train_batch`` takes the next batch out of the queue right after it
+    has dispatched the step.  Where the producer is ahead (batch k+1 queued
+    before step k starts) every batch but a pass's first is taken at
+    dispatch; where it is behind (batch k+1 is read only after step k's
+    EndIteration) every batch is waited for in ``data_wait``: one count a
+    batch either way, the same costs, in the same order of events."""
+    monkeypatch.setattr(FLAGS, "prefetch_depth", 2)
+    feeds, passes = _feeds(6), 2
+    stepped = [threading.Event() for _ in feeds]
+    src = None
+    events = []
+
+    def reader():
+        nonlocal src
+        for e in stepped:
+            e.clear()
+        src = _Pulls(feeds)
+        if reader_is == "ahead":
+            return iter(src)
+
+        def paced():
+            for i, feed in enumerate(src):
+                if i:   # handed over only once the step before has ended
+                    assert stepped[i - 1].wait(WAIT_S)
+                yield feed
+        return paced()
+
+    def handler(e):
+        events.append((type(e).__name__, getattr(e, "batch_id", None)))
+        if isinstance(e, ev.BeginIteration) and reader_is == "ahead":
+            src.wait(e.batch_id + 2)    # so batch_id + 1 is in the queue
+        if isinstance(e, ev.EndIteration):
+            costs.append(e.cost)
+            stepped[e.batch_id].set()
+
+    costs = []
+    before = _taken()
+    tr = _mse_trainer()
+    tr.train(reader, num_passes=passes, event_handler=handler)
+    after = _taken()
+    n = len(feeds) * passes
+    late = passes if reader_is == "ahead" else n
+    assert after["late"] - before["late"] == late
+    assert after["dispatch"] - before["dispatch"] == n - late
+    # EndIteration of a batch still comes before BeginIteration of the next
+    per_pass = [("BeginPass", None)] + [
+        (kind, i) for i in range(len(feeds))
+        for kind in ("BeginIteration", "EndIteration")] + [("EndPass", None)]
+    assert events == per_pass * passes
+
+    monkeypatch.setattr(FLAGS, "prefetch_depth", 0)
+    nn.reset_naming()
+    plain, want = _mse_trainer(), []
+    plain.train(lambda: iter(feeds), num_passes=passes,
+                event_handler=lambda e: want.append(e.cost)
+                if isinstance(e, ev.EndIteration) else None)
+    np.testing.assert_array_equal(costs, want)
 
 
 def test_prefetch_reader_error_attributed_to_data_tier(monkeypatch):
@@ -211,18 +392,34 @@ def test_prefetch_attaches_after_resume_fast_forward(tmp_path, monkeypatch):
     assert len(prepared) == 2, prepared
 
 
+@pytest.mark.parametrize("slot", ["as_it_falls", "filled"])
 def test_prefetch_preemption_drains_clean_and_resumes_batch_exact(
-        tmp_path, monkeypatch):
+        slot, tmp_path, monkeypatch):
     """Acceptance: preemption mid-pass WITH prefetch on — no torn batch
     (the checkpoint's next_batch counts stepped batches, not read-ahead),
-    and the resumed run matches the uninterrupted one exactly."""
+    and the resumed run matches the uninterrupted one exactly.  ``filled``:
+    the batch after the last stepped one is in the consumer's slot, taken at
+    dispatch, when the drain point drops it."""
     from paddle_tpu.resilience.checkpoint_io import pass_dir, read_manifest
 
     monkeypatch.setattr(FLAGS, "prefetch_depth", 2)
     feeds = _feeds(6)
+    src = None
 
     def reader():
-        return iter(feeds)
+        nonlocal src
+        src = _Pulls(feeds)
+        return iter(src)
+
+    held = []
+
+    def fill_slot(e):
+        # at the end of the last batch stepped before the drain point
+        if (slot == "filled" and isinstance(e, ev.EndIteration)
+                and (e.pass_id, e.batch_id) == (1, 2)):
+            src.wait(4)                  # batch 3 has left the producer
+            assert tr_b._prefetcher.take()
+            held.append(tr_b._prefetcher._slot)
 
     monkeypatch.setattr(FLAGS, "save_dir", "")
     tr_a = _mse_trainer()
@@ -234,8 +431,11 @@ def test_prefetch_preemption_drains_clean_and_resumes_batch_exact(
     tr_b = _mse_trainer()
     h = PreemptionHandler()
     tr_b.train(reader, num_passes=3, preemption=h,
-               event_handler=chaos.preempt_at(h, batch=2, pass_id=1))
+               event_handler=chaos.preempt_at(h, batch=2, pass_id=1,
+                                              inner=fill_slot))
     assert tr_b.preempted
+    assert [type(b) for b in held] == (
+        [PreparedFeed] if slot == "filled" else [])
     assert tr_b._prefetcher is None            # drained at the boundary
     m = read_manifest(pass_dir(str(tmp_path), 1))
     assert m["meta"]["preempted"] and m["meta"]["next_batch"] == 3

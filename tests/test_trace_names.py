@@ -203,19 +203,19 @@ def test_optimizer_apply_encloses_every_equation_of_the_update(
 #: what may lie between two ``with`` blocks of the loop: a test of a
 #: condition, an assignment, the exit of one annotation and the entry of the
 #: next, a phase's own record in ``_ph`` (1-13 us here, the smallest of five
-#: readings); a line of work left without a span costs more (the key split
-#: alone is 400-600 us here)
+#: readings); a line of work left without a span costs more (the key split,
+#: while the host made it, was 400-600 us here)
 GLUE_NS = 50_000
 
 
-def _trace_of_one_pass(tmp_path, batches=5, feeder=None):
+def _trace_of_one_pass(tmp_path, batches=5, feeder=None, **trainer_kw):
     """``SGDTrainer.train`` over a few toy batches under the profiler: the
     trace's file."""
     nn.reset_naming()
     x = nn.data("x", size=4)
     cost = nn.mse_cost(input=nn.fc(x, 2, name="o"),
                        label=nn.data("y", size=2))
-    tr = SGDTrainer(cost, Adam(learning_rate=0.01), seed=0)
+    tr = SGDTrainer(cost, Adam(learning_rate=0.01), seed=0, **trainer_kw)
     rng = np.random.RandomState(0)
 
     def reader():
@@ -283,15 +283,15 @@ def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
         children = [c[2] for c in inside(it)]
         assert children == ["poll", "data_wait", "callback", "prepare",
                             "step", "step.rng", "step.dispatch",
-                            "step.post", "step.sync", "step.sync", "extras",
+                            "step.post", "step.sync", "extras",
                             "callback", "close"]
         step = next(c for c in inside(it) if c[2] == "step")
         in_step = inside(step)
         assert [c[2] for c in in_step] == ["step.rng", "step.dispatch",
-                                           "step.post", "step.sync",
-                                           "step.sync"]
-        # one span per blocking fetch, with its reason
-        assert [c[3]["reason"] for c in in_step[3:]] == ["guard", "loss"]
+                                           "step.post", "step.sync"]
+        # ONE blocking fetch a step (the guard is on by default): the flag
+        # and the loss come together, under the first one waited for
+        assert [c[3]["reason"] for c in in_step[3:]] == ["guard"]
         # the host's turn-around is named in full: from the end of one
         # step.dispatch to the start of the next, so all through the
         # iteration, what no child of ``iteration`` or of ``step`` covers
@@ -308,6 +308,40 @@ def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
     outside = [s[2] for s in spans if s[2] != "iteration" and not any(
         it[0] <= s[0] and s[1] <= it[1] for it in iterations)]
     assert outside == ["callback"]
+
+
+@pytest.mark.parametrize("mode, reason", [
+    ("guard", "guard"), ("no_guard", "loss"), ("amp", "amp")])
+def test_a_step_is_one_executable_and_one_fetch(mode, reason, tmp_path,
+                                                monkeypatch):
+    """Between two steps the host launches ONE program (the key is split
+    inside it) and blocks ONCE: whichever flags the step has, they and the
+    loss start their copies at dispatch and one ``step.sync`` waits for
+    them, named after the first one read."""
+    from paddle_tpu.utils.flags import FLAGS
+
+    monkeypatch.setattr(FLAGS, "amp", mode == "amp")
+    path = _trace_of_one_pass(tmp_path,
+                              guard_nonfinite=(mode != "no_guard"))
+    spans = _loop_spans(path)
+    iterations = [s for s in spans if s[2] == "iteration"][:-1]
+    assert len(iterations) == 5
+    # what the runtime itself records of a launch, on any thread
+    launches = [s[0] for line in _threads_with(path, "PjRt") for s in line
+                if s[2].endswith("::Execute")]
+    jitted = [s for line in _threads_with(path, "PjitFunction(")
+              for s in line]
+    for it in iterations:
+        assert len([t for t in launches if it[0] <= t <= it[1]]) == 1
+        assert {s[2] for s in jitted if it[0] <= s[0] <= it[1]} == {
+            "step_and_split)"}
+        syncs = [s for s in spans
+                 if s[2] == "step.sync" and it[0] <= s[0] <= it[1]]
+        assert [s[3]["reason"] for s in syncs] == [reason]
+        # the key's span is still there (the readers' marker), and empty
+        rng = [s for s in spans
+               if s[2] == "step.rng" and it[0] <= s[0] <= it[1]]
+        assert len(rng) == 1 and rng[0][1] - rng[0][0] < GLUE_NS
 
 
 @pytest.mark.parametrize("depth", [2, 0], ids=["prefetch_2", "prefetch_off"])

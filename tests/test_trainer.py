@@ -91,6 +91,61 @@ def test_checkpoint_roundtrip(tmp_path):
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
 
 
+def _dropout_trainer(seed, **kw):
+    nn.reset_naming()
+    x = nn.data("x", size=6)
+    h = nn.dropout(nn.fc(x, 16, act="relu", name="h"), 0.5)
+    cost = nn.mse_cost(input=nn.fc(h, 2, act="linear", name="o"),
+                       label=nn.data("y", size=2))
+    return SGDTrainer(cost, Adam(learning_rate=0.05), seed=seed, **kw)
+
+
+@pytest.mark.parametrize("seed", [0, 11])
+@pytest.mark.parametrize("guard", [True, False], ids=["guard", "no_guard"])
+def test_key_split_inside_the_step_keeps_the_host_side_chain(
+        seed, guard, tmp_path):
+    """The compiled step splits the trainer's key itself and returns the
+    half it does not use.  Every step sees the key that
+    ``self._rng, key = jax.random.split(self._rng)`` on the host gave it,
+    bit for bit: the losses of a net with dropout, ``save()``'s ``rng_key``
+    after N steps, and the stream a ``load()`` continues."""
+    from paddle_tpu.resilience.checkpoint_io import pass_dir, read_manifest
+
+    rs = np.random.RandomState(seed)
+    feeds = [{"x": rs.randn(8, 6).astype(np.float32),
+              "y": rs.randn(8, 2).astype(np.float32)} for _ in range(6)]
+    n = 4
+    # the chain as the host made it: split before every step, hand the
+    # un-jitted step (the one the auditors trace) its half
+    ref = _dropout_trainer(seed, guard_nonfinite=guard)
+    step = jax.jit(ref._step_fn)
+    rng, params, state, opt = ref._rng, ref.params, ref.state, ref.opt_state
+    want, chain = [], []
+    for feed in feeds:
+        rng, key = jax.random.split(rng)
+        loss, params, state, opt = step(params, state, opt, {}, key, feed)[:4]
+        want.append(np.asarray(loss))
+        chain.append(np.asarray(rng))
+    assert len({w.tobytes() for w in want}) == len(want)  # dropout is live
+
+    tr = _dropout_trainer(seed, guard_nonfinite=guard)
+    got = [np.asarray(tr.train_batch(f)) for f in feeds[:n]]
+    np.testing.assert_array_equal(got, want[:n])
+    np.testing.assert_array_equal(np.asarray(tr._rng), chain[n - 1])
+    tr.save(str(tmp_path), 0)
+    meta = read_manifest(pass_dir(str(tmp_path), 0))["meta"]
+    assert meta["rng_key"] == [int(v) for v in chain[n - 1]]
+
+    tr2 = _dropout_trainer(seed + 1, guard_nonfinite=guard)
+    tr2.load(str(tmp_path), 0)
+    got2 = [np.asarray(tr2.train_batch(f)) for f in feeds[n:]]
+    np.testing.assert_array_equal(got2, want[n:])
+    np.testing.assert_array_equal(np.asarray(tr2._rng), chain[-1])
+    # the trainer that saved goes on along the same stream
+    np.testing.assert_array_equal(
+        [np.asarray(tr.train_batch(f)) for f in feeds[n:]], want[n:])
+
+
 def test_checkgrad_mode(rng):
     x_val = jnp.asarray(rng.randn(4, 6).astype(np.float32))
     y_val = jnp.asarray(rng.randint(0, 3, (4, 1)))

@@ -7,3 +7,4 @@ from paddle_tpu.models.image_bench import alexnet, googlenet
 from paddle_tpu.models.lfm2 import lfm2_moe_net
 from paddle_tpu.models.decoder import decoder_stack
 from paddle_tpu.models.kanana2 import kanana2_moe_net
+from paddle_tpu.models.qwen3_next import qwen3_next_net

@@ -1,8 +1,9 @@
 """The decoder-only stack that the models of this package share: an
 embedding, pre-norm residual layers whose sequence mixer is chosen by kind
-and whose feed-forward is a gated MLP in the leading dense layers and a
-dropless expert layer in the rest, a final RMSNorm, and the next-token cost
-over a head that is the embedding or a matrix of its own.
+and whose feed-forward is a gated MLP in the first ``num_dense_layers``
+layers (a model may have none) and a dropless expert layer in the rest, a
+final RMSNorm, and the next-token cost over a head that is the embedding or a
+matrix of its own.
 
 Layer ``i``: ``h = x + Op_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
 Layer names (and so the device trace's scopes and the parameters'
@@ -26,10 +27,11 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                   num_experts: int, num_experts_per_tok: int,
                   norm_topk_prob: bool = True,
                   routed_scaling_factor: float = 1.0,
-                  shared_size: int = 0,
+                  shared_size: int = 0, scoring: str = "sigmoid",
+                  shared_gate: bool = False,
                   experts_held: Optional[Sequence[int]] = None,
-                  norm_eps: float = 1e-5, tie_head: bool = True,
-                  recompute_layers=True):
+                  norm_eps: float = 1e-5, zero_centered_norm: bool = False,
+                  tie_head: bool = True, recompute_layers=True):
     """Returns ``(cost, extras)``: the mean next-token cross-entropy over
     ``tokens`` / ``next_tokens`` (two int sequence feeds of one length), and
     two extra outputs per expert layer, marked for the counters
@@ -43,7 +45,11 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     default); ``vocab_size`` may likewise be a slice of the published
     vocabulary, ids, logits and loss then being over the slice.
     ``shared_size``: hidden units of the shared experts beside the routed
-    ones (0: none).  ``recompute_layers`` marks decoder layers as
+    ones (0: none), ``shared_gate``: whether a sigmoid gate weighs them;
+    ``scoring``: how the router scores (``nn.expert_mlp``).
+    ``num_dense_layers`` may be 0: every layer is then an expert layer.
+    ``zero_centered_norm``: the stack's RMSNorms are ``x / rms(x) * (1 +
+    w)``.  ``recompute_layers`` marks decoder layers as
     recomputation blocks, one block a layer: ``True`` for every layer, or
     the indices of the layers to recompute (the others hold their
     activations)."""
@@ -54,13 +60,22 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                        param_attr=nn.ParamAttr(initial_std=0.02,
                                                init="normal"))
     x, extras = emb, []
+    # passed only where asked for: the two older models' graphs (and their
+    # captured configurations) stay what they were
+    centred = {"zero_centered": True} if zero_centered_norm else {}
+    routing = {}
+    if scoring != "sigmoid":
+        routing["scoring"] = scoring
+    if shared_gate:
+        routing["shared_gate"] = True
     for i, kind in enumerate(layer_types):
         if kind not in mixers:
             raise ValueError(f"layer {i}: unknown layer type {kind!r}")
-        normed = nn.rms_norm(x, eps=norm_eps, name=f"norm_op{i}")
+        normed = nn.rms_norm(x, eps=norm_eps, name=f"norm_op{i}", **centred)
         op = mixers[kind](normed, i)
         h = nn.addto([x, op], name=f"res_op{i}")
-        normed2 = nn.rms_norm(h, eps=norm_eps, name=f"norm_ffn{i}")
+        normed2 = nn.rms_norm(h, eps=norm_eps, name=f"norm_ffn{i}",
+                              **centred)
         block = [normed, op, h, normed2]
         if i < num_dense_layers:
             ffn = nn.gated_mlp(normed2, intermediate_size, name=f"mlp{i}")
@@ -70,7 +85,7 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                 experts_held=experts_held, top_k=num_experts_per_tok,
                 norm_topk_prob=norm_topk_prob,
                 routed_scaling_factor=routed_scaling_factor,
-                shared_size=shared_size, name=f"moe{i}")
+                shared_size=shared_size, name=f"moe{i}", **routing)
             load = nn.get_output(ffn, "expert_load", size=1,
                                  name=f"moe{i}_load")
             load.meta["obs_counter"] = {
@@ -89,7 +104,7 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
         if recompute_layers is True or (recompute_layers
                                         and i in recompute_layers):
             nn.remat_block(block, f"layer{i}")
-    out = nn.rms_norm(x, eps=norm_eps, name="norm_out")
+    out = nn.rms_norm(x, eps=norm_eps, name="norm_out", **centred)
     cost = nn.lm_head_cost(out, targets, embedding=emb if tie_head else None,
                            name="cost")
     return cost, extras

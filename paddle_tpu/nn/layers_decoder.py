@@ -1,6 +1,8 @@
 """Layers of a decoder-only block: RMSNorm, a gated short causal convolution,
-causal grouped-query self-attention with QK-norm and rotary embedding, latent
-attention (keys and values made from one narrow latent a token), a gated MLP,
+causal grouped-query self-attention with QK-norm and rotary embedding (and,
+where the model has one, an output gate), latent attention (keys and values
+made from one narrow latent a token), a gated delta net (linear attention:
+a state a head, updated by the gated delta rule), a gated MLP,
 a dropless expert layer that holds a share of the experts (with shared
 experts beside them, where the model has them), and a next-token cost over a
 head that is the embedding (tied) or a matrix of its own.
@@ -24,11 +26,13 @@ import paddle_tpu.ops as O
 from paddle_tpu.nn.graph import Act, LayerOutput, ParamSpec, next_name
 from paddle_tpu.nn.layers import AttrLike, _pa, _refuse_packed, _seq_like
 from paddle_tpu.ops import decoder_block as DB
+from paddle_tpu.ops import delta_rule as DR
 from paddle_tpu.ops import moe as M
 from paddle_tpu.utils.error import ConfigError
 
 __all__ = ["rms_norm", "gated_short_conv", "causal_self_attention",
-           "latent_attention", "gated_mlp", "expert_mlp", "lm_head_cost",
+           "latent_attention", "gated_delta_net", "gated_mlp", "expert_mlp",
+           "lm_head_cost",
            "remat_block"]
 
 
@@ -49,15 +53,17 @@ def remat_block(layers: Sequence[LayerOutput], tag: str) -> None:
 
 
 def rms_norm(input: LayerOutput, *, eps: float = 1e-5,
-             name: Optional[str] = None,
+             zero_centered: bool = False, name: Optional[str] = None,
              param_attr: AttrLike = None) -> LayerOutput:
-    """``x / rms(x) * w`` over the feature axis, statistics in float32."""
+    """``x / rms(x) * w`` over the feature axis, statistics in float32.
+    ``zero_centered``: ``x / rms(x) * (1 + w)``, the weight starting at 0."""
     name = name or next_name("rms_norm")
-    pa = _pa(param_attr, f"_{name}.w", init="ones")
+    pa = _pa(param_attr, f"_{name}.w",
+             init="zeros" if zero_centered else "ones")
     spec = ParamSpec(name=pa.name, shape=(input.size,), attr=pa)
 
     def forward(ctx, params, a: Act) -> Act:
-        out = DB.rms_norm(a.value, params[spec.name], eps)
+        out = DB.rms_norm(a.value, params[spec.name], eps, zero_centered)
         return _seq_like(a, out) if a.is_seq else Act(value=out)
 
     return LayerOutput(name, "rms_norm", input.size, [input], forward, [spec])
@@ -91,24 +97,38 @@ def gated_short_conv(input: LayerOutput, *, kernel_size: int = 3,
 def causal_self_attention(input: LayerOutput, *, num_heads: int,
                           num_kv_heads: int, head_dim: int,
                           rope_theta: float = 10000.0, norm_eps: float = 1e-5,
+                          output_gate: bool = False,
+                          rotary_dim: Optional[int] = None,
+                          zero_centered_norm: bool = False,
                           name: Optional[str] = None) -> LayerOutput:
     """Causal grouped-query self-attention: RMSNorm over every query head
-    and every key head (one weight vector each), rotary embedding, softmax
-    at scale ``head_dim ** -0.5`` computed blockwise, output projection."""
+    and every key head (one weight vector each; ``zero_centered_norm``: the
+    ``1 + w`` form), rotary embedding (on the first ``rotary_dim`` channels
+    of a head where given, else on all), softmax at scale ``head_dim **
+    -0.5`` computed blockwise, output projection.  ``output_gate``: ``W_q``
+    gives every head ``[q | gate]`` of ``head_dim`` each, and the attention's
+    result is multiplied by ``sigmoid(gate)`` before the output
+    projection."""
     name = name or next_name("self_attention")
     if num_heads % num_kv_heads:
         raise ConfigError(f"{name!r}: {num_heads} query heads are not whole "
                           f"groups over {num_kv_heads} key-value heads")
     D, H, Hkv, dh = input.size, num_heads, num_kv_heads, head_dim
-    ones = lambda leaf: _pa(None, f"_{name}.{leaf}", init="ones")  # noqa: E731
+    rd = dh if rotary_dim is None else rotary_dim
+    if rd % 2 or not 0 < rd <= dh:
+        raise ConfigError(f"{name!r}: rotary width {rd} of a head of {dh}")
+    dq = 2 * dh if output_gate else dh
+    norm_init = "zeros" if zero_centered_norm else "ones"
+    norm_w = lambda leaf: _pa(  # noqa: E731
+        None, f"_{name}.{leaf}", init=norm_init)
     specs = [
-        ParamSpec(f"_{name}.wq", (D, H * dh), _fan_in(f"_{name}.wq", D)),
+        ParamSpec(f"_{name}.wq", (D, H * dq), _fan_in(f"_{name}.wq", D)),
         ParamSpec(f"_{name}.wk", (D, Hkv * dh), _fan_in(f"_{name}.wk", D)),
         ParamSpec(f"_{name}.wv", (D, Hkv * dh), _fan_in(f"_{name}.wv", D)),
         ParamSpec(f"_{name}.wo", (H * dh, D),
                   _fan_in(f"_{name}.wo", H * dh)),
-        ParamSpec(f"_{name}.q_norm", (dh,), ones("q_norm")),
-        ParamSpec(f"_{name}.k_norm", (dh,), ones("k_norm")),
+        ParamSpec(f"_{name}.q_norm", (dh,), norm_w("q_norm")),
+        ParamSpec(f"_{name}.k_norm", (dh,), norm_w("k_norm")),
     ]
 
     def forward(ctx, params, a: Act) -> Act:
@@ -119,15 +139,21 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
         p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
         x = a.value
         B, T = x.shape[:2]
-        q = O.linear(x, p["wq"]).reshape(B, T, H, dh)
+        q = O.linear(x, p["wq"]).reshape(B, T, H, dq)
+        if output_gate:
+            q, gate = q[..., :dh], q[..., dh:]
         k = O.linear(x, p["wk"]).reshape(B, T, Hkv, dh)
         v = O.linear(x, p["wv"]).reshape(B, T, Hkv, dh)
-        q = DB.rotary_embedding(DB.rms_norm(q, p["q_norm"], norm_eps),
-                                rope_theta)
-        k = DB.rotary_embedding(DB.rms_norm(k, p["k_norm"], norm_eps),
-                                rope_theta)
+        q = DB.rotary_embedding(
+            DB.rms_norm(q, p["q_norm"], norm_eps, zero_centered_norm),
+            rope_theta, rotary_dim)
+        k = DB.rotary_embedding(
+            DB.rms_norm(k, p["k_norm"], norm_eps, zero_centered_norm),
+            rope_theta, rotary_dim)
         with jax.named_scope("attn_core"):
             o = DB.causal_attention(q, k, v, scale=dh ** -0.5)
+        if output_gate:
+            o = o * jax.nn.sigmoid(gate.astype(o.dtype))
         return _seq_like(a, O.linear(o.reshape(B, T, H * dh), p["wo"]))
 
     return LayerOutput(name, "causal_self_attention", D, [input], forward,
@@ -198,6 +224,82 @@ def latent_attention(input: LayerOutput, *, num_heads: int,
     return LayerOutput(name, "latent_attention", D, [input], forward, specs)
 
 
+def gated_delta_net(input: LayerOutput, *, num_key_heads: int,
+                    num_value_heads: int, key_head_dim: int,
+                    value_head_dim: int, conv_kernel_size: int = 4,
+                    norm_eps: float = 1e-6,
+                    name: Optional[str] = None) -> LayerOutput:
+    """Linear attention by the gated delta rule.  ``[q | k | v | z] = x
+    W_qkvz`` (``Hk dk + Hk dk + Hv dv + Hv dv`` columns), ``[b | a] = x
+    W_ba`` (``Hv + Hv``).  ``[q | k | v]`` goes through a depthwise causal
+    convolution of ``conv_kernel_size`` taps (no bias), then SiLU.  ``q`` and
+    ``k`` are L2-normalised over a head's channels (``x * rsqrt(sum x^2 +
+    1e-6)``), every key head serves ``Hv / Hk`` value heads, and ``q`` is
+    scaled by ``dk ** -0.5``.  ``beta = sigmoid(b)``, ``g = -exp(A_log) *
+    softplus(a + dt_bias)`` (float32; ``A_log`` and ``dt_bias`` one a value
+    head).  Every value head keeps a state ``[dk, dv]`` that
+    ``ops.delta_rule`` carries along the row (zero at its start).  The
+    result is RMS-normed over each head's ``dv`` channels (a plain weight)
+    and gated, ``norm(o) * silu(z)``, then projected by ``W_out``.
+
+    Scopes inside the layer's own: ``gdn_proj`` (both in-projections, the
+    convolution and SiLU, the L2 norms, the head repeat, ``g`` and ``beta``)
+    and ``gdn_scan`` (the delta rule: the kernels ``gdn_chunk_fwd`` /
+    ``gdn_chunk_bwd`` on the TPU, and the layout changes around them); the
+    gated norm and the output projection are the rest."""
+    name = name or next_name("gated_delta_net")
+    D = input.size
+    Hk, Hv, dk, dv = (num_key_heads, num_value_heads, key_head_dim,
+                      value_head_dim)
+    if Hv % Hk:
+        raise ConfigError(f"{name!r}: {Hv} value heads are not whole groups "
+                          f"over {Hk} key heads")
+    nk, nv = Hk * dk, Hv * dv
+    conv = 2 * nk + nv
+    normal = lambda leaf, std: _pa(None, f"_{name}.{leaf}",   # noqa: E731
+                                   init="normal", initial_std=std)
+    specs = [
+        ParamSpec(f"_{name}.w_qkvz", (D, conv + nv),
+                  _fan_in(f"_{name}.w_qkvz", D)),
+        ParamSpec(f"_{name}.w_ba", (D, 2 * Hv), _fan_in(f"_{name}.w_ba", D)),
+        ParamSpec(f"_{name}.kernel", (conv_kernel_size, conv),
+                  _fan_in(f"_{name}.kernel", conv_kernel_size)),
+        ParamSpec(f"_{name}.a_log", (Hv,), normal("a_log", 1.0)),
+        ParamSpec(f"_{name}.dt_bias", (Hv,), normal("dt_bias", 1.0)),
+        ParamSpec(f"_{name}.norm", (dv,),
+                  _pa(None, f"_{name}.norm", init="ones")),
+        ParamSpec(f"_{name}.w_out", (nv, D), _fan_in(f"_{name}.w_out", nv)),
+    ]
+
+    def forward(ctx, params, a: Act) -> Act:
+        if not a.is_seq:
+            raise ConfigError(f"gated_delta_net {name!r} needs a sequence")
+        _refuse_packed(a, name, "gated_delta_net")
+        p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
+        x = a.value
+        B, T = x.shape[:2]
+        f32 = jnp.float32
+        with jax.named_scope("gdn_proj"):
+            qkvz = O.linear(x, p["w_qkvz"])
+            ba = O.linear(x, p["w_ba"]).astype(f32)
+            qkv = jax.nn.silu(DB.causal_short_conv(qkvz[..., :conv],
+                                                   p["kernel"]))
+            z = qkvz[..., conv:].reshape(B, T, Hv, dv)
+            q = DB.unit_norm(qkv[..., :nk].reshape(B, T, Hk, dk)) * dk ** -0.5
+            k = DB.unit_norm(qkv[..., nk:2 * nk].reshape(B, T, Hk, dk))
+            q, k = (jnp.repeat(h, Hv // Hk, axis=2) for h in (q, k))
+            v = qkv[..., 2 * nk:].reshape(B, T, Hv, dv)
+            beta = jax.nn.sigmoid(ba[..., :Hv])
+            g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., Hv:] + p["dt_bias"].astype(f32))
+        with jax.named_scope("gdn_scan"):
+            o = DR.delta_rule(q, k, v, g, beta)
+        y = DB.rms_norm(o, p["norm"], norm_eps) * jax.nn.silu(z)
+        return _seq_like(a, O.linear(y.reshape(B, T, nv), p["w_out"]))
+
+    return LayerOutput(name, "gated_delta_net", D, [input], forward, specs)
+
+
 def _gated_mlp_specs(prefix: str, D: int, size: int):
     return [
         ParamSpec(f"{prefix}w1", (D, size), _fan_in(f"{prefix}w1", D)),
@@ -227,21 +329,28 @@ def gated_mlp(input: LayerOutput, size: int, *,
 def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                experts_held: Optional[Sequence[int]] = None, top_k: int,
                norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
-               shared_size: int = 0,
+               shared_size: int = 0, scoring: str = "sigmoid",
+               shared_gate: bool = False,
                name: Optional[str] = None) -> LayerOutput:
     """A dropless mixture of gated-MLP experts of ``size`` hidden units, as
     the chip that holds experts ``experts_held = (first, count)`` of
     ``num_experts`` computes it (default: all of them).  Every token is
-    routed over all ``num_experts`` (sigmoid scores, the ``top_k`` largest
-    of ``score + expert_bias``, weights normalised over the chosen), and the
-    layer's value is the part of the result that the experts held give; no
-    assignment to an expert held is dropped, whatever the routing.  On one
-    chip there is no exchange, and nothing stands in for the other chips.
+    routed over all ``num_experts``: with ``scoring="sigmoid"`` by sigmoid
+    scores, the ``top_k`` largest of ``score + expert_bias``; with
+    ``scoring="softmax"`` by the softmax over all the router's outputs, the
+    ``top_k`` largest, and the layer has no ``expert_bias``; the weights are
+    normalised over the chosen where ``norm_topk_prob``
+    (``ops.moe.route_tokens``).  The layer's value is the part of the result
+    that the experts held give; no assignment to an expert held is dropped,
+    whatever the routing.  On one chip there is no exchange, and nothing
+    stands in for the other chips.
 
     ``shared_size``: the hidden units of the shared experts, ONE gated MLP
     (``_<name>.shared_w1`` / ``w3`` / ``w2``) that every token passes
-    through, unweighted, added to the routed result; every chip of the
-    deployment computes it whole on its own tokens.  Scope ``moe_shared``.
+    through, added to the routed result: unweighted, or, with
+    ``shared_gate``, times ``sigmoid(x . w_g)`` (``_<name>.shared_gate``,
+    one weight a hidden channel); every chip of the deployment computes it
+    whole on its own tokens.  Scope ``moe_shared``.
 
     ``Act.state`` carries ``expert_load`` (assignments per expert held,
     int32) and ``uncomputed`` (assignments to an expert held that no row
@@ -252,10 +361,16 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
     if held < 1 or first < 0 or first + held > E or top_k > E:
         raise ConfigError(f"{name!r}: experts {first}..{first + held - 1} "
                           f"and {top_k} a token do not fit {E} experts")
+    if scoring not in ("sigmoid", "softmax"):
+        raise ConfigError(f"{name!r}: unknown scoring {scoring!r}")
+    if shared_gate and not shared_size:
+        raise ConfigError(f"{name!r}: a gate without a shared expert")
+    bias = [ParamSpec(f"_{name}.expert_bias", (E,),
+                      _pa(None, f"_{name}.expert_bias", init="zeros"))
+            ] if scoring == "sigmoid" else []
     specs = [
         ParamSpec(f"_{name}.router", (D, E), _fan_in(f"_{name}.router", D)),
-        ParamSpec(f"_{name}.expert_bias", (E,),
-                  _pa(None, f"_{name}.expert_bias", init="zeros")),
+        *bias,
         ParamSpec(f"_{name}.w1", (held, D, size), _fan_in(f"_{name}.w1", D)),
         ParamSpec(f"_{name}.w3", (held, D, size), _fan_in(f"_{name}.w3", D)),
         ParamSpec(f"_{name}.w2", (held, size, D),
@@ -263,14 +378,18 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
     ]
     shared = (_gated_mlp_specs(f"_{name}.shared_", D, shared_size)
               if shared_size else [])
+    gate = [ParamSpec(f"_{name}.shared_gate", (D,),
+                      _fan_in(f"_{name}.shared_gate", D))
+            ] if shared_gate else []
 
     def forward(ctx, params, a: Act) -> Act:
         p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
         x = a.value.reshape(-1, D)
         with jax.named_scope("moe_routing"):
             idx, weights = M.route_tokens(
-                x, p["router"], p["expert_bias"], top_k=top_k,
-                norm_topk=norm_topk_prob, scaling=routed_scaling_factor)
+                x, p["router"], p.get("expert_bias"), top_k=top_k,
+                norm_topk=norm_topk_prob, scaling=routed_scaling_factor,
+                scoring=scoring)
             if a.is_seq:     # a padded position is no token: nothing held
                 idx = jnp.where(a.mask.reshape(-1, 1) > 0, idx, -1)
         tm = M.moe_kernel_row_tile(D, size, idx.size)
@@ -279,7 +398,13 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
             first_expert=first, tm=tm or 8, kernels=tm is not None)
         if shared:
             with jax.named_scope("moe_shared"):
-                y = y + _gated_mlp(x, *(params[s.name] for s in shared))
+                ys = _gated_mlp(x, *(params[s.name] for s in shared))
+                if gate:
+                    wg = params[gate[0].name].astype(jnp.float32)
+                    ys = ys * jax.nn.sigmoid(jnp.sum(
+                        x.astype(jnp.float32) * wg, -1, keepdims=True)
+                    ).astype(ys.dtype)
+                y = y + ys
         y = y.reshape(a.value.shape)
         state = {"expert_load": load, "uncomputed": uncomputed}
         if a.is_seq:
@@ -289,7 +414,7 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
         return Act(value=y, state=state)
 
     return LayerOutput(name, "expert_mlp", D, [input], forward,
-                       specs + shared)
+                       specs + shared + gate)
 
 
 def lm_head_cost(input: LayerOutput, label: LayerOutput, *,

@@ -28,23 +28,40 @@ from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.numerics import acc_dtype, dot_dtype, mxu_cast
 
-__all__ = ["rms_norm", "rotary_embedding", "causal_short_conv",
+__all__ = ["rms_norm", "unit_norm", "rotary_embedding", "causal_short_conv",
            "causal_attention", "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
 
 #: queries per block of the XLA path
 ATTN_XLA_BLOCK = 512
 
 
-def rms_norm(x, w, eps: float):
-    """``x / rms(x) * w`` over the last axis; statistics in float32."""
+def rms_norm(x, w, eps: float, zero_centered: bool = False):
+    """``x / rms(x) * w`` over the last axis; statistics in float32.
+    ``zero_centered``: the weight is stored about zero and applied as
+    ``1 + w``."""
     xf = x.astype(acc_dtype())
     inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
-    return (xf * inv * w.astype(acc_dtype())).astype(x.dtype)
+    w = w.astype(acc_dtype())
+    return (xf * inv * (1.0 + w if zero_centered else w)).astype(x.dtype)
 
 
-def rotary_embedding(x, theta: float):
+def unit_norm(x, eps: float = 1e-6):
+    """``x * rsqrt(sum x^2 + eps)`` over the last axis (an L2 norm with no
+    weight); statistics in float32."""
+    xf = x.astype(acc_dtype())
+    return (xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True)
+                               + eps)).astype(x.dtype)
+
+
+def rotary_embedding(x, theta: float, rotary_dim=None):
     """x ``[B, T, heads, dh]`` at positions ``0..T-1``: the half-rotation
-    form (the first half of a head's channels pairs with the second)."""
+    form (the first half of a head's channels pairs with the second).
+    ``rotary_dim``: turn the first ``rotary_dim`` channels only (paired
+    among themselves) and pass the rest through."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        return jnp.concatenate(
+            [rotary_embedding(x[..., :rotary_dim], theta),
+             x[..., rotary_dim:]], axis=-1)
     T, dh = x.shape[1], x.shape[-1]
     f32 = acc_dtype()
     inv = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)

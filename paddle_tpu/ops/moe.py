@@ -16,8 +16,8 @@ worst-case size (every token sends ``min(k, held)`` choices here); the layer
 takes the small one whenever the step's routing fits it, so no routing drops
 a token; only the tiles that hold rows are computed.
 
-Precision.  The router (``x W_r``, the sigmoid, the selection and the
-weights) runs in float32 at ``highest`` precision whatever the operand
+Precision.  The router (``x W_r``, the sigmoid or softmax, the selection and
+the weights) runs in float32 at ``highest`` precision whatever the operand
 policy: a selection made on bf16 scores picks other experts than the float32
 reference on near ties.  The experts' products take operands in the compute
 dtype with float32 accumulation, like every other matrix product.
@@ -40,19 +40,32 @@ __all__ = ["route_tokens", "count_assignments", "group_assignments",
 
 
 def route_tokens(x, w_router, bias, *, top_k: int, norm_topk: bool,
-                 scaling: float):
+                 scaling: float, scoring: str = "sigmoid"):
     """x ``[N, D]`` -> (experts ``[N, k]`` int32, weights ``[N, k]``
-    float32).  Scores are ``sigmoid(x W_r)``; the bias enters the selection
-    only (so its gradient is exactly zero)."""
+    float32).  ``scoring="sigmoid"``: scores are ``sigmoid(x W_r)``, the
+    ``top_k`` largest of ``score + bias`` are chosen (the bias enters the
+    selection only, so its gradient is exactly zero) and, with
+    ``norm_topk``, the chosen scores are divided by their sum plus 1e-6.
+    ``scoring="softmax"``: scores are the softmax over all the router's
+    outputs, the ``top_k`` largest are chosen (``bias`` is ``None``) and,
+    with ``norm_topk``, divided by their sum, with no epsilon."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"route_tokens: unknown scoring {scoring!r}")
     f32 = acc_dtype()
-    s = jax.nn.sigmoid(jnp.matmul(
-        x.astype(f32), w_router.astype(f32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias.astype(f32)), top_k)
+    logits = jnp.matmul(x.astype(f32), w_router.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    else:
+        s = jax.nn.softmax(logits, axis=-1)
+    picked = s if bias is None else s + jax.lax.stop_gradient(
+        bias.astype(f32))
+    _, idx = jax.lax.top_k(picked, top_k)
     idx = checkpoint_name(idx, "remat_keep")
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
-        chosen = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-6)
+        chosen = chosen / (jnp.sum(chosen, -1, keepdims=True)
+                           + (1e-6 if scoring == "sigmoid" else 0.0))
     return idx.astype(jnp.int32), chosen * scaling
 
 

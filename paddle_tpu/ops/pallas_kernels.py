@@ -35,6 +35,9 @@ sets takes part:
   ``dv`` a backward call keeps in VMEM: :func:`flash_bwd_key_rows`.
 - Grouped matrix products over experts.  Gate:
   ``ops/moe.moe_kernel_row_tile``.
+- The gated delta rule's chunked scan, forward (``gdn_chunk_fwd``) and
+  reverse (``gdn_chunk_bwd``).  Gate:
+  ``ops/delta_rule.delta_rule_kernel_chunk``.
 
 Where a gate is closed (the CPU, a shape past it, a step that jit partitions
 over a mesh: one with sharding rules, or ``SGDTrainer(mesh=...)``) the
@@ -59,7 +62,8 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "topk_lse_readout_pallas", "topk_lse_logits_pallas", "TOPK_LANES",
            "flash_attn_fwd_pallas", "flash_attn_bwd_pallas",
            "flash_bwd_key_rows",
-           "gmm_pallas", "tgmm_pallas"]
+           "gmm_pallas", "tgmm_pallas",
+           "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas"]
 
 
 def _compiler_params(**kw):
@@ -1935,3 +1939,148 @@ def tgmm_pallas(lhs, rhs, tile_expert, n_active, *, experts: int, tm: int,
             vmem_limit_bytes=GMM_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
     )(tile_expert, n_active, lhs, rhs)
+
+
+# ---------------------------------------------------------------------------
+# The gated delta rule as a chunked scan (ops/delta_rule.py has the algebra)
+# ---------------------------------------------------------------------------
+# Grid (batch, head, block of chunks); the last axis is sequential and the
+# head's state ``S`` [dk, dv] float32 (``dS`` in the reverse kernel) stays in
+# VMEM scratch across it.  A grid step takes ``KERNEL_BLOCK_CHUNKS`` chunks,
+# unrolled: a chunk's ``k k^T``, its 64 x 64 solve and ``q k^T`` do not wait
+# for the state, so the scheduler has them to run beside the three products
+# that do.  ``g``'s sums and ``beta`` come lane-dense, one chunk a row
+# ([.., N, 64]); the column forms the algebra needs are made in VMEM.  The
+# forward writes every chunk's STARTING state out ([B, H, N, dk, dv]
+# float32); the reverse kernel reads it back and makes the chunk's T, R and
+# Vn again.
+
+def _gdn_block(T: int, chunk: int):
+    from paddle_tpu.ops.delta_rule import KERNEL_BLOCK_CHUNKS
+
+    n = T // chunk
+    per = min(n, KERNEL_BLOCK_CHUNKS)
+    if n % per:
+        raise ValueError(f"a row of {n} chunks is not whole blocks of {per}")
+    return n, per
+
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, S_scr,
+                    *, chunk, per):
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops import delta_rule as DR
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        S_scr[...] = jnp.zeros_like(S_scr)
+
+    for c in range(per):
+        rows = slice(c * chunk, (c + 1) * chunk)
+        grow, brow = g_ref[0, 0, c:c + 1, :], b_ref[0, 0, c:c + 1, :]
+        S = S_scr[...]
+        s_ref[0, 0, c] = S
+        q = q_ref[0, 0, rows, :]
+        o, S_end = DR.chunk_forward(
+            q, k_ref[0, 0, rows, :], v_ref[0, 0, rows, :],
+            DR.col_of_row(grow), grow, DR.col_of_row(brow), S, q.dtype)
+        o_ref[0, 0, rows, :] = o.astype(o_ref.dtype)
+        S_scr[...] = S_end
+
+
+def gdn_chunk_fwd_pallas(q, k, v, gamma, beta):
+    """q, k ``[B, H, T, dk]``, v ``[B, H, T, dv]`` in the compute dtype;
+    gamma (``g`` summed from each chunk's start) and beta ``[B, H, N, C]``
+    float32 -> (o ``[B, H, T, dv]`` in q's dtype, every chunk's starting
+    state ``[B, H, N, dk, dv]`` float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, dk = q.shape
+    dv, chunk = v.shape[3], gamma.shape[3]
+    n, per = _gdn_block(T, chunk)
+    rows = lambda b, h, i: (b, h, i, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, chunk=chunk, per=per),
+        name="gdn_chunk_fwd",
+        grid=(B, H, n // per),
+        in_specs=[pl.BlockSpec((1, 1, per * chunk, dk), rows),
+                  pl.BlockSpec((1, 1, per * chunk, dk), rows),
+                  pl.BlockSpec((1, 1, per * chunk, dv), rows),
+                  pl.BlockSpec((1, 1, per, chunk), rows),
+                  pl.BlockSpec((1, 1, per, chunk), rows)],
+        out_specs=[pl.BlockSpec((1, 1, per * chunk, dv), rows),
+                   pl.BlockSpec((1, 1, per, dk, dv),
+                                lambda b, h, i: (b, h, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, n, dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+    )(q, k, v, gamma, beta)
+
+
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dS_scr,
+                    *, chunk, per):
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops import delta_rule as DR
+
+    @pl.when(pl.program_id(2) == 0)     # the row's LAST block: the walk is
+    def _init():                        # reversed by the index maps
+        dS_scr[...] = jnp.zeros_like(dS_scr)
+
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    for c in reversed(range(per)):
+        rows = slice(c * chunk, (c + 1) * chunk)
+        grow, brow = g_ref[0, 0, c:c + 1, :], b_ref[0, 0, c:c + 1, :]
+        q = q_ref[0, 0, rows, :]
+        dq, dk, dv, dg_col, dg_row, dg_last, db, dS = DR.chunk_backward(
+            q, k_ref[0, 0, rows, :], v_ref[0, 0, rows, :],
+            DR.col_of_row(grow), grow, DR.col_of_row(brow), s_ref[0, 0, c],
+            do_ref[0, 0, rows, :], dS_scr[...], q.dtype)
+        dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, 0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0, rows, :] = dv.astype(dv_ref.dtype)
+        dg_ref[0, 0, c:c + 1, :] = (DR.row_of_col(dg_col) + dg_row
+                                    + jnp.where(last, dg_last, 0.0))
+        db_ref[0, 0, c:c + 1, :] = DR.row_of_col(db)
+        dS_scr[...] = dS
+
+
+def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do):
+    """The reverse walk: what :func:`gdn_chunk_fwd_pallas` took and wrote,
+    and ``do`` ``[B, H, T, dv]`` -> (dq, dk, dv in q's dtype; dgamma, dbeta
+    ``[B, H, N, C]`` float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, dk = q.shape
+    dv, chunk = v.shape[3], gamma.shape[3]
+    n, per = _gdn_block(T, chunk)
+    nb = n // per
+    rows = lambda b, h, i: (b, h, nb - 1 - i, 0)  # noqa: E731
+    wide_k = pl.BlockSpec((1, 1, per * chunk, dk), rows)
+    wide_v = pl.BlockSpec((1, 1, per * chunk, dv), rows)
+    scalars = pl.BlockSpec((1, 1, per, chunk), rows)
+    return tuple(pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, chunk=chunk, per=per),
+        name="gdn_chunk_bwd",
+        grid=(B, H, nb),
+        in_specs=[wide_k, wide_k, wide_v, scalars, scalars,
+                  pl.BlockSpec((1, 1, per, dk, dv),
+                               lambda b, h, i: (b, h, nb - 1 - i, 0, 0)),
+                  wide_v],
+        out_specs=[wide_k, wide_k, wide_v, scalars, scalars],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(gamma.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(beta.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=_interpret(),
+    )(q, k, v, gamma, beta, states, do))

@@ -48,6 +48,35 @@ def pytest_configure(config):
         "runs like the 100M-row pserver table")
 
 
+#: Pins of an earlier cell's test file that the benchmark's own rule makes
+#: false.  A PR that adds a cell appends its entries to ``BENCHMARK.json`` and
+#: may edit no file the benchmark already has (``tests/benchmark/`` is one of
+#: its ``paths``), so a test there that says "the LAST entries are mine" holds
+#: for the PR that wrote it and for no later one.  Marked expected to fail,
+#: with the reason, until a ``benchmark`` PR rewrites the pin as a position
+#: relative to its neighbours (PERF.md section 7); what else each asserted is
+#: asserted again in ``tests/benchmark/test_qwen3next_cell.py``.  (Here and
+#: not in a ``tests/benchmark/conftest.py``: the tests import this file as
+#: ``conftest``, and a second module of that name shadows it.)
+OUTDATED_PINS = {
+    "tests/benchmark/test_kanana2_cell.py::"
+    "test_new_metrics_are_this_cells_alone":
+        "pins Kanana-2's cell and configuration as the LAST entries of "
+        "BENCHMARK.json; PR 41 appended qwen3next-train-b1-t8192 after them",
+    "tests/benchmark/test_trace_spans.py::"
+    "test_the_eight_come_last_and_the_cells_pinned_sets_do_not_hold_them":
+        "pins PR 38's eight idle parts as the LAST per-layer metrics of "
+        "BENCHMARK.json; PR 41 appended its six after them",
+}
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        why = OUTDATED_PINS.get(item.nodeid)
+        if why:
+            item.add_marker(pytest.mark.xfail(reason=why, strict=False))
+
+
 @pytest.fixture
 def rng():
     return np.random.RandomState(0)

@@ -660,6 +660,28 @@ def case_latent_attention(rng):
                                qk_rope_head_dim=2, v_head_dim=3), feed
 
 
+def case_gated_delta_net(rng):
+    # 1 key head serving 2 value heads, a state of 4 x 3 a head (PR 41)
+    xs, feed = _seq(rng)
+    return nn.gated_delta_net(_pre_fc(xs, size=8), num_key_heads=1,
+                              num_value_heads=2, key_head_dim=4,
+                              value_head_dim=3), feed
+
+
+def case_gated_self_attention(rng):
+    # an output gate, rotary on 2 of a head's 4 channels, 1 + w norms (PR 41)
+    xs, feed = _seq(rng)
+    return nn.causal_self_attention(_pre_fc(xs, size=8), num_heads=4,
+                                    num_kv_heads=2, head_dim=4,
+                                    output_gate=True, rotary_dim=2,
+                                    zero_centered_norm=True), feed
+
+
+def case_zero_centered_rms_norm(rng):
+    xs, feed = _seq(rng)
+    return nn.rms_norm(_pre_fc(xs), zero_centered=True), feed
+
+
 def case_gated_mlp(rng):
     xs, feed = _seq(rng)
     return nn.gated_mlp(_pre_fc(xs), 8), feed
@@ -671,6 +693,15 @@ def case_expert_mlp(rng):
     xs, feed = _seq(rng)
     return nn.expert_mlp(_pre_fc(xs), 8, num_experts=3, experts_held=(1, 2),
                          top_k=3), feed
+
+
+def case_softmax_expert_mlp_with_a_gated_shared_expert(rng):
+    # softmax scores, every expert chosen, and the shared expert behind its
+    # sigmoid gate (PR 41)
+    xs, feed = _seq(rng)
+    return nn.expert_mlp(_pre_fc(xs), 8, num_experts=3, experts_held=(1, 2),
+                         top_k=3, shared_size=6, scoring="softmax",
+                         shared_gate=True), feed
 
 
 def case_lm_head_cost(rng):

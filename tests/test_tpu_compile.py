@@ -547,3 +547,95 @@ def test_grouped_expert_products_sixteen_narrow_experts(one_chip, on_tpu):
             _struct(one_chip, (N, k), jnp.int32)]
     assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
                     *args) == 18
+
+
+QWEN3NEXT = dict(T=8192, Hv=32, dk=128, dv=128, H=16, Hkv=2, dh=256)
+
+
+def test_delta_rule_scan_kernels(one_chip, on_tpu):
+    """The gate admits the cell's row of 8192 at heads of 128, and the
+    forward and the reverse scan kernel (a head's 128 x 128 state, and its
+    gradient, in VMEM scratch across 16 grid steps of 8 chunks) compile;
+    interpret mode passed a broadcast from lane 63 that Mosaic refuses."""
+    from paddle_tpu.ops import delta_rule as DR
+
+    c = QWEN3NEXT
+    assert DR.delta_rule_kernel_chunk(c["T"], c["dk"], c["dv"]) == 64
+    assert DR.delta_rule_kernel_chunk(c["T"], 64, c["dv"]) is None
+    assert DR.delta_rule_kernel_chunk(c["T"] + 8, c["dk"], c["dv"]) is None
+
+    def loss(q, k, v, g, beta):
+        return DR.delta_rule(q, k, v, g, beta).sum()
+
+    wide = _struct(one_chip, (1, c["T"], c["Hv"], c["dk"]))
+    one = _struct(one_chip, (1, c["T"], c["Hv"]))
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    wide, wide, wide, one, one) == 2
+
+
+def test_causal_attention_kernels_at_heads_of_256(one_chip, on_tpu):
+    """Qwen3-Next's full-attention layer: 16 query heads of 256 over 2
+    key-value heads, through the same two flash kernels."""
+    from paddle_tpu.ops import decoder_block as DB
+
+    c = QWEN3NEXT
+    assert DB.attention_kernel_blocks(c["T"], c["dh"], c["H"], c["Hkv"])
+
+    def loss(q, k, v):
+        return DB.causal_attention(q, k, v, scale=c["dh"] ** -0.5).sum()
+
+    q = _struct(one_chip, (1, c["T"], c["H"], c["dh"]))
+    kv = _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"]))
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    q, kv, kv) == 2
+
+
+def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's whole step (the model's loss and gradient under its
+    recomputation blocks, per-leaf Adam, state donated) compiled for the
+    described v5e from shapes alone: the compiler's own count of arguments,
+    results and temporaries stays under 15 GB of the chip's 16, with the
+    delta rule's and the attention's kernels in the program."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import manifest
+
+    import paddle_tpu.nn as nn
+    from paddle_tpu.param.optimizers import Adam
+
+    cell = manifest.cell("qwen3next-train-b1-t8192")
+    cfg, T = cell["config"], cell["traffic"]["seq_len"]
+    cost, extras = manifest.program(cfg).net(cfg)
+    topo = nn.Topology([cost] + extras)
+    params = {k: _struct(one_chip, spec.shape)
+              for k, spec in topo.param_specs.items()}
+    o = cfg["optimizer"]
+    opt = Adam(learning_rate=o["learning_rate"], beta1=o["beta1"],
+               beta2=o["beta2"], epsilon=o["epsilon"])
+    opt_state = jax.tree_util.tree_map(
+        lambda a: _struct(one_chip, a.shape, a.dtype),
+        jax.eval_shape(opt.init_state, params))
+    ids = (_struct(one_chip, (1, T), jnp.int32),
+           _struct(one_chip, (1,), jnp.int32))
+
+    def step(params, opt_state, feed):
+        def loss(p):
+            outs, _ = topo.apply(p, {}, feed, train=True)
+            return outs["cost"].value, [outs[e.name].value for e in extras]
+
+        (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        return (value, counts) + opt.update(params, grads, opt_state)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
+    text = compiled.as_text()
+    assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+    assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 3 * 4 * 424_340_544 < m.argument_size_in_bytes     # p, m, v
+    assert held < 15e9, held

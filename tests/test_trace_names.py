@@ -25,7 +25,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
            "lse_rows", "attn_dec_fwd", "attn_dec_bwd", "ce_readout_fwd",
            "ce_readout_bwd", "topk_lse_readout", "topk_lse_logits",
-           "flash_attn_fwd", "flash_attn_bwd", "moe_gmm", "moe_tgmm"]
+           "flash_attn_fwd", "flash_attn_bwd", "moe_gmm", "moe_tgmm",
+           "gdn_chunk_fwd", "gdn_chunk_bwd"]
 
 
 # -- (a) kernels ----------------------------------------------------------

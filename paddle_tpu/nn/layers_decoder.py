@@ -243,10 +243,22 @@ def gated_delta_net(input: LayerOutput, *, num_key_heads: int,
     and gated, ``norm(o) * silu(z)``, then projected by ``W_out``.
 
     Scopes inside the layer's own: ``gdn_proj`` (both in-projections, the
-    convolution and SiLU, the L2 norms, the head repeat, ``g`` and ``beta``)
-    and ``gdn_scan`` (the delta rule: the kernels ``gdn_chunk_fwd`` /
-    ``gdn_chunk_bwd`` on the TPU, and the layout changes around them); the
-    gated norm and the output projection are the rest."""
+    convolution and SiLU, the L2 norms, ``q``'s scale, the cast and the move
+    to the scan's heads-major layout, ``g`` and ``beta``) and ``gdn_scan``
+    (the delta rule: the kernels ``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` on the
+    TPU, which read a key head for each of its value heads, and the layout
+    of ``g``, ``beta`` and the output around them); the gated norm and the
+    output projection are the rest.  Where ``ops.delta_rule.prep_kernel_rows``
+    opens (the TPU backend, head widths that are multiples of 128, a row of
+    whole blocks of rows), what lies between the projection and the scan is
+    the kernel pair ``gdn_prep_fwd`` / ``gdn_prep_bwd``, one pass over the
+    projection each way (``ops.delta_rule.conv_delta_rule``): ``W_qkvz`` is
+    then read as two products, its ``[q | k | v]`` columns grouped by key
+    head (the kernels' block is a group) and its ``z`` columns, so the
+    kernels' ``[B, T, 2 Hk dk + Hv dv]`` float32 array and its gradient are
+    what the products write and read, and nothing is sliced or joined at
+    the activations' size.  Elsewhere the chain below runs in ``jax.numpy``,
+    with the key heads repeated inside ``ops.delta_rule.delta_rule``."""
     name = name or next_name("gated_delta_net")
     D = input.size
     Hk, Hv, dk, dv = (num_key_heads, num_value_heads, key_head_dim,
@@ -279,21 +291,33 @@ def gated_delta_net(input: LayerOutput, *, num_key_heads: int,
         x = a.value
         B, T = x.shape[:2]
         f32 = jnp.float32
+        w, kernel = p["w_qkvz"], p["kernel"]
+        rows = DR.prep_kernel_rows(T, Hk, Hv, dk, dv, conv_kernel_size)
         with jax.named_scope("gdn_proj"):
-            qkvz = O.linear(x, p["w_qkvz"])
             ba = O.linear(x, p["w_ba"]).astype(f32)
-            qkv = jax.nn.silu(DB.causal_short_conv(qkvz[..., :conv],
-                                                   p["kernel"]))
-            z = qkvz[..., conv:].reshape(B, T, Hv, dv)
-            q = DB.unit_norm(qkv[..., :nk].reshape(B, T, Hk, dk)) * dk ** -0.5
-            k = DB.unit_norm(qkv[..., nk:2 * nk].reshape(B, T, Hk, dk))
-            q, k = (jnp.repeat(h, Hv // Hk, axis=2) for h in (q, k))
-            v = qkv[..., 2 * nk:].reshape(B, T, Hv, dv)
             beta = jax.nn.sigmoid(ba[..., :Hv])
             g = -jnp.exp(p["a_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., Hv:] + p["dt_bias"].astype(f32))
-        with jax.named_scope("gdn_scan"):
-            o = DR.delta_rule(q, k, v, g, beta)
+            if rows is None:
+                qkvz = O.linear(x, w)
+                qkv = jax.nn.silu(DB.causal_short_conv(qkvz[..., :conv],
+                                                       kernel))
+                z = qkvz[..., conv:]
+                q = DB.unit_norm(qkv[..., :nk].reshape(B, T, Hk, dk)) \
+                    * dk ** -0.5
+                k = DB.unit_norm(qkv[..., nk:2 * nk].reshape(B, T, Hk, dk))
+                v = qkv[..., 2 * nk:].reshape(B, T, Hv, dv)
+            else:       # the kernels' array: no z, a key head's group a block
+                qkv = O.linear(x, DR.group_columns(w[:, :conv], Hk, dk))
+                z = O.linear(x, w[:, conv:])
+        if rows is None:
+            with jax.named_scope("gdn_scan"):
+                o = DR.delta_rule(q, k, v, g, beta)
+        else:
+            o = DR.conv_delta_rule(qkv, DR.group_columns(kernel, Hk, dk), g,
+                                   beta, key_head_dim=dk, value_head_dim=dv,
+                                   rows=rows)
+        z = z.reshape(B, T, Hv, dv)
         y = DB.rms_norm(o, p["norm"], norm_eps) * jax.nn.silu(z)
         return _seq_like(a, O.linear(y.reshape(B, T, nv), p["w_out"]))
 

@@ -42,9 +42,32 @@ in ``jax.numpy`` with a ``lax.scan`` over chunks, differentiated by JAX.
 The chunk's algebra is written once, on 2-D arrays, and both paths call it
 (:func:`chunk_forward`; the kernels' :func:`chunk_backward` is its
 transpose by hand).
+
+Heads: ``q`` and ``k`` come at ``Hk`` key heads, ``v``, ``g`` and ``beta`` at
+``Hv`` value heads, whole groups over the key heads; value head ``h`` reads
+key head ``h // (Hv // Hk)``.  The kernels read it through their index maps
+and write ``dq``, ``dk`` a value head; the XLA path repeats the key heads
+(float32, before the cast, as the layer did until PR 42).  Who casts to the
+compute dtype: :func:`delta_rule` (its ``heads_major`` on the XLA path, the
+kernels' ``custom_vjp`` on theirs, so the group's gradient is summed in
+float32 and stays float32); :func:`conv_delta_rule`'s kernel.
+
+:func:`conv_delta_rule` is the delta net's whole way from its in-projection
+to the scan's output on kernels alone, behind a gate of its own,
+:func:`prep_kernel_rows` (the scan's gate, whole groups, a convolution of at
+most 9 taps, a row that whole blocks of rows divide and that the scan does
+not pad): ``gdn_prep_fwd`` makes the convolution, SiLU, the L2 norms, ``q``'s
+scale and the heads-major compute-dtype layout in ONE pass over the
+projection, ``gdn_prep_bwd`` their transpose in one, and one
+``jax.custom_vjp`` holds both pairs, so the scan's per-value-head ``dq``,
+``dk`` go to ``gdn_prep_bwd`` as they are and are summed over a group there,
+in VMEM.  Behind the closed gate the layer's ``jax.numpy`` chain and
+:func:`delta_rule` run (``nn.gated_delta_net``).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +76,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.numerics import acc_dtype, compute_dtype, dot_dtype
 
-__all__ = ["delta_rule", "delta_rule_kernel_chunk", "CHUNK",
+__all__ = ["delta_rule", "delta_rule_kernel_chunk", "conv_delta_rule",
+           "prep_kernel_rows", "group_columns", "CHUNK",
            "KERNEL_BLOCK_CHUNKS", "chunk_forward", "chunk_backward"]
 
 #: tokens per chunk of the WY form
@@ -227,18 +251,26 @@ def _scan_xla(q, k, v, gamma, beta):
 
 # -- the kernels' path ------------------------------------------------------
 
-@jax.custom_vjp
-def _scan_kernels(q, k, v, gamma, beta):
-    return _scan_kernels_fwd(q, k, v, gamma, beta)[0]
-
-
-def _scan_kernels_fwd(q, k, v, gamma, beta):
+def _scan_fwd(q, k, v, gamma, beta):
     from paddle_tpu.ops.pallas_kernels import gdn_chunk_fwd_pallas
 
     o, states = gdn_chunk_fwd_pallas(q, k, v, gamma, beta)
     # kept across a recomputation block, as attention's output is: the
     # backward's second forward makes the projections again, not the scan
-    o, states = (checkpoint_name(a, "remat_keep") for a in (o, states))
+    return tuple(checkpoint_name(a, "remat_keep") for a in (o, states))
+
+
+@jax.custom_vjp
+def _scan_kernels(q, k, v, gamma, beta):
+    """Heads-major float32 ``q``, ``k`` ``[B, Hk, T, dk]``, ``v`` ``[B, H, T,
+    dv]``; the cast to the compute dtype is inside, so the gradients come
+    back float32 and a group's ``dq``, ``dk`` are summed in float32."""
+    return _scan_kernels_fwd(q, k, v, gamma, beta)[0]
+
+
+def _scan_kernels_fwd(q, k, v, gamma, beta):
+    q, k, v = (a.astype(compute_dtype()) for a in (q, k, v))
+    o, states = _scan_fwd(q, k, v, gamma, beta)
     return o, (q, k, v, gamma, beta, states)
 
 
@@ -246,23 +278,40 @@ def _scan_kernels_bwd(res, do):
     from paddle_tpu.ops.pallas_kernels import gdn_chunk_bwd_pallas
 
     q, k, v, gamma, beta, states = res
-    return gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states,
-                                do.astype(q.dtype))
+    dq, dk, dv, dgamma, dbeta = gdn_chunk_bwd_pallas(
+        q, k, v, gamma, beta, states, do.astype(q.dtype))
+    B, Hk, T, d = q.shape
+    f32 = jnp.float32
+    # the transpose of the heads' repeat: a group's sum
+    dq, dk = (a.reshape(B, Hk, -1, T, d).astype(f32).sum(2) for a in (dq, dk))
+    return dq, dk, dv.astype(f32), dgamma, dbeta
 
 
 _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
+def _chunked_gates(g, beta, N: int):
+    """``g``, ``beta`` ``[B, T, H]`` (``T = N`` chunks) -> ``g`` summed from
+    each chunk's start and ``beta``, ``[B, H, N, CHUNK]`` float32."""
+    B, _, H = g.shape
+    gh, bh = (jnp.moveaxis(a.astype(acc_dtype()), 2, 1).reshape(
+        B, H, N, CHUNK) for a in (g, beta))
+    return jnp.cumsum(gh, axis=-1), bh
+
+
 def delta_rule(q, k, v, g, beta):
-    """The gated delta rule over a row: ``q``, ``k`` ``[B, T, H, dk]`` (the
+    """The gated delta rule over a row: ``q``, ``k`` ``[B, T, Hk, dk]`` (the
     caller has normalised and scaled them), ``v`` ``[B, T, H, dv]``, ``g``
     (the log of a token's decay, ``<= 0``) and ``beta`` ``[B, T, H]`` ->
-    ``o`` ``[B, T, H, dv]``.  The state is zero at the row's start.  bf16
-    operands under the default policy; ``g``'s sums, the solve inside a
-    chunk and the state in float32."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
-    f32 = acc_dtype()
+    ``o`` ``[B, T, H, dv]``; ``H`` is whole groups over ``Hk``, and value
+    head ``h`` reads key head ``h // (H // Hk)``.  The state is zero at the
+    row's start.  bf16 operands under the default policy; ``g``'s sums, the
+    solve inside a chunk and the state in float32."""
+    B, T, Hk, dk = q.shape
+    H, dv = v.shape[2:]
+    if H % Hk:
+        raise ValueError(f"{H} value heads are not whole groups over {Hk} "
+                         "key heads")
     kernels = delta_rule_kernel_chunk(T, dk, dv) is not None
     N = -(-T // CHUNK)
     if kernels and N > KERNEL_BLOCK_CHUNKS:
@@ -277,14 +326,119 @@ def delta_rule(q, k, v, g, beta):
                         + ((0, 0),) * (a.ndim - 3))
         return a
 
-    cd = compute_dtype()
-    qh, kh, vh = (heads_major(a, cd) for a in (q, k, v))
-    gh, bh = (heads_major(a, f32).reshape(B, H, N, CHUNK) for a in (g, beta))
-    gamma = jnp.cumsum(gh, axis=-1)
+    if pad:
+        g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (g, beta))
+    gamma, bh = _chunked_gates(g, beta, N)
     if kernels:
-        o = _scan_kernels(qh, kh, vh, gamma, bh)
+        o = _scan_kernels(*(heads_major(a, acc_dtype()) for a in (q, k, v)),
+                          gamma, bh)
     else:
+        cd = compute_dtype()
+        qh, kh, vh = (heads_major(a, cd) for a in (
+            *(jnp.repeat(a, H // Hk, axis=2) for a in (q, k)), v))
         o = _scan_xla(*(a.reshape(B, H, N, CHUNK, a.shape[-1])
                         for a in (qh, kh, vh)), gamma, bh)
         o = o.reshape(B, H, N * CHUNK, dv)
     return jnp.moveaxis(o[:, :, :T], 1, 2).astype(dot_dtype())
+
+
+# -- from the in-projection to the scan's output, on kernels alone ----------
+
+def prep_kernel_rows(T: int, Hk: int, Hv: int, dk: int, dv: int, taps: int):
+    """The gate of ``gdn_prep_fwd`` / ``gdn_prep_bwd``: the rows of a block,
+    or ``None`` for the layer's ``jax.numpy`` chain.  Needs the scan's gate
+    open (the TPU backend, head widths that are multiples of 128, a row of
+    whole chunks), whole groups of value heads, a convolution whose history
+    fits the halo, a row that the scan does not pad and that whole blocks
+    divide, and a block (its float32 rows in and out, the gradients' rows
+    and two scratch copies, the streamed ones twice) within the kernels'
+    VMEM."""
+    from paddle_tpu.ops.pallas_kernels import (GDN_PREP_HALO,
+                                               GDN_PREP_VMEM_LIMIT_BYTES)
+
+    if delta_rule_kernel_chunk(T, dk, dv) is None or Hv % Hk:
+        return None
+    n = T // CHUNK
+    if not 1 <= taps <= GDN_PREP_HALO + 1 or (
+            n > KERNEL_BLOCK_CHUNKS and n % KERNEL_BLOCK_CHUNKS):
+        return None
+    group = Hv // Hk
+    # a row of the reverse kernel's block: six float32 copies of a group's
+    # columns (x and dx, each in two buffers, and the two scratch copies)
+    # and two buffers of the gradients' compute-dtype rows; as much again
+    # is left for the body's own temporaries
+    row_bytes = (6 * 4 * (2 * dk + group * dv)
+                 + 2 * 2 * group * (2 * dk + dv))
+    for rows in (512, 256, 128, 64):
+        if T % rows == 0 and 2 * rows * row_bytes <= GDN_PREP_VMEM_LIMIT_BYTES:
+            return rows
+    return None
+
+
+def group_columns(a, Hk: int, dk: int):
+    """The last axis from ``[q | k | v]`` (``Hk dk + Hk dk + Hv dv``) to
+    groups by key head, ``Hk`` times ``[q_j | k_j | v of the group's value
+    heads]``: the column order the prep kernels read, for the projection's
+    weight and the convolution's kernel alike."""
+    nk, lead = Hk * dk, a.shape[:-1]
+    parts = (a[..., :nk], a[..., nk:2 * nk], a[..., 2 * nk:])
+    return jnp.concatenate([p.reshape(*lead, Hk, -1) for p in parts],
+                           axis=-1).reshape(a.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _conv_scan_kernels(x, w, gamma, beta, dk, dv, rows):
+    return _conv_scan_fwd(x, w, gamma, beta, dk, dv, rows)[0]
+
+
+def _conv_scan_fwd(x, w, gamma, beta, dk, dv, rows):
+    from paddle_tpu.ops.pallas_kernels import gdn_prep_fwd_pallas
+
+    with jax.named_scope("gdn_proj"):
+        q, k, v = gdn_prep_fwd_pallas(x, w, dk=dk, dv=dv, rows=rows,
+                                      out_dtype=compute_dtype())
+    with jax.named_scope("gdn_scan"):
+        o, states = _scan_fwd(q, k, v, gamma, beta)
+    return o, (x, w, q, k, v, gamma, beta, states)
+
+
+def _conv_scan_bwd(dk, dv, rows, res, do):
+    from paddle_tpu.ops.pallas_kernels import (gdn_chunk_bwd_pallas,
+                                               gdn_prep_bwd_pallas)
+
+    x, w, q, k, v, gamma, beta, states = res
+    with jax.named_scope("gdn_scan"):
+        dq, dk_, dv_, dgamma, dbeta = gdn_chunk_bwd_pallas(
+            q, k, v, gamma, beta, states, do.astype(q.dtype))
+    with jax.named_scope("gdn_proj"):
+        dx, dw = gdn_prep_bwd_pallas(x, w, dq, dk_, dv_, rows=rows)
+    return dx, dw, dgamma, dbeta
+
+
+_conv_scan_kernels.defvjp(_conv_scan_fwd, _conv_scan_bwd)
+
+
+def conv_delta_rule(x, kernel, g, beta, *, key_head_dim: int,
+                    value_head_dim: int, rows: int):
+    """A delta net from its in-projection to the scan's output, where
+    :func:`prep_kernel_rows` gave ``rows``: ``x`` ``[B, T, 2 Hk dk + Hv dv]``
+    float32 with its columns grouped by key head (:func:`group_columns`, on
+    the projection's weight), ``kernel`` ``[L, 2 Hk dk + Hv dv]`` the
+    convolution's, grouped alike, ``g`` and ``beta`` ``[B, T, Hv]`` -> ``o``
+    ``[B, T, Hv, dv]``: the depthwise causal convolution, SiLU, ``q`` and
+    ``k`` L2-normalised over a head (float32 statistics), ``q`` times ``dk **
+    -0.5``, one cast to the compute dtype, and :func:`delta_rule` with the key
+    heads read by their groups.  Scopes ``gdn_proj`` (the kernels
+    ``gdn_prep_fwd`` / ``gdn_prep_bwd``) and ``gdn_scan`` (``gdn_chunk_fwd`` /
+    ``gdn_chunk_bwd`` and the layout of ``g``, ``beta`` and ``o``)."""
+    dk, dv, f32 = key_head_dim, value_head_dim, jnp.float32
+    T, Hv = g.shape[1:]
+    Hk = (x.shape[-1] - Hv * dv) // (2 * dk)
+    with jax.named_scope("gdn_proj"):       # [L, Hk, W] -> a group a block
+        w = jnp.moveaxis(kernel.astype(f32).reshape(-1, Hk, x.shape[-1] // Hk),
+                         1, 0)
+    with jax.named_scope("gdn_scan"):
+        gamma, bh = _chunked_gates(g, beta, T // CHUNK)
+    o = _conv_scan_kernels(x.astype(f32), w, gamma, bh, dk, dv, rows)
+    with jax.named_scope("gdn_scan"):
+        return jnp.moveaxis(o, 1, 2).astype(dot_dtype())

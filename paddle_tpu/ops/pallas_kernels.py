@@ -36,8 +36,15 @@ sets takes part:
 - Grouped matrix products over experts.  Gate:
   ``ops/moe.moe_kernel_row_tile``.
 - The gated delta rule's chunked scan, forward (``gdn_chunk_fwd``) and
-  reverse (``gdn_chunk_bwd``).  Gate:
+  reverse (``gdn_chunk_bwd``); ``q`` and ``k`` come at the key heads and a
+  value head reads its key head through the index maps.  Gate:
   ``ops/delta_rule.delta_rule_kernel_chunk``.
+- A delta net's way from its in-projection to that scan, one pass forward
+  (``gdn_prep_fwd``: the depthwise causal convolution, SiLU, the L2 norms,
+  ``q``'s scale, the cast, the heads-major layout) and one back
+  (``gdn_prep_bwd``, which also sums ``dq``, ``dk`` over a key head's value
+  heads and accumulates the convolution kernel's gradient).  Gate:
+  ``ops/delta_rule.prep_kernel_rows``.
 
 Where a gate is closed (the CPU, a shape past it, a step that jit partitions
 over a mesh: one with sharding rules, or ``SGDTrainer(mesh=...)``) the
@@ -63,7 +70,8 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "flash_attn_fwd_pallas", "flash_attn_bwd_pallas",
            "flash_bwd_key_rows",
            "gmm_pallas", "tgmm_pallas",
-           "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas"]
+           "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas",
+           "gdn_prep_fwd_pallas", "gdn_prep_bwd_pallas", "GDN_PREP_HALO"]
 
 
 def _compiler_params(**kw):
@@ -1955,6 +1963,25 @@ def tgmm_pallas(lhs, rhs, tile_expert, n_active, *, experts: int, tm: int,
 # float32); the reverse kernel reads it back and makes the chunk's T, R and
 # Vn again.
 
+def _traced_once(*static):
+    """``jax.jit`` around a kernel's wrapper.  A step calls such a wrapper
+    once a layer and pass with the same shapes, and a process traces its
+    step several times; inside ``jit`` the kernel's body is traced, and
+    lowered into a module, once for all of them.  What the trace takes from
+    the backend (interpret mode) goes in as a static argument, so that it
+    is part of the key."""
+    def wrap(fn):
+        inner = jax.jit(fn, static_argnames=(*static, "interpret"))
+
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            return inner(*args, interpret=_interpret(), **kwargs)
+
+        return call
+
+    return wrap
+
+
 def _gdn_block(T: int, chunk: int):
     from paddle_tpu.ops.delta_rule import KERNEL_BLOCK_CHUNKS
 
@@ -1988,24 +2015,28 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, S_scr,
         S_scr[...] = S_end
 
 
-def gdn_chunk_fwd_pallas(q, k, v, gamma, beta):
-    """q, k ``[B, H, T, dk]``, v ``[B, H, T, dv]`` in the compute dtype;
-    gamma (``g`` summed from each chunk's start) and beta ``[B, H, N, C]``
-    float32 -> (o ``[B, H, T, dv]`` in q's dtype, every chunk's starting
-    state ``[B, H, N, dk, dv]`` float32)."""
+@_traced_once()
+def gdn_chunk_fwd_pallas(q, k, v, gamma, beta, *, interpret):
+    """q, k ``[B, Hk, T, dk]``, v ``[B, H, T, dv]`` in the compute dtype, ``H``
+    whole groups over ``Hk``: value head ``h`` reads key head ``h // (H //
+    Hk)`` through the index maps, and nothing is repeated in HBM; gamma
+    (``g`` summed from each chunk's start) and beta ``[B, H, N, C]`` float32
+    -> (o ``[B, H, T, dv]`` in q's dtype, every chunk's starting state ``[B,
+    H, N, dk, dv]`` float32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, T, dk = q.shape
-    dv, chunk = v.shape[3], gamma.shape[3]
+    B, H, T, dv = v.shape
+    dk, chunk, group = q.shape[3], gamma.shape[3], H // q.shape[1]
     n, per = _gdn_block(T, chunk)
     rows = lambda b, h, i: (b, h, i, 0)  # noqa: E731
+    key_rows = lambda b, h, i: (b, h // group, i, 0)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_gdn_fwd_kernel, chunk=chunk, per=per),
         name="gdn_chunk_fwd",
         grid=(B, H, n // per),
-        in_specs=[pl.BlockSpec((1, 1, per * chunk, dk), rows),
-                  pl.BlockSpec((1, 1, per * chunk, dk), rows),
+        in_specs=[pl.BlockSpec((1, 1, per * chunk, dk), key_rows),
+                  pl.BlockSpec((1, 1, per * chunk, dk), key_rows),
                   pl.BlockSpec((1, 1, per * chunk, dv), rows),
                   pl.BlockSpec((1, 1, per, chunk), rows),
                   pl.BlockSpec((1, 1, per, chunk), rows)],
@@ -2017,7 +2048,7 @@ def gdn_chunk_fwd_pallas(q, k, v, gamma, beta):
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, gamma, beta)
 
 
@@ -2050,18 +2081,22 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
         dS_scr[...] = dS
 
 
-def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do):
+@_traced_once()
+def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do, *, interpret):
     """The reverse walk: what :func:`gdn_chunk_fwd_pallas` took and wrote,
-    and ``do`` ``[B, H, T, dv]`` -> (dq, dk, dv in q's dtype; dgamma, dbeta
-    ``[B, H, N, C]`` float32)."""
+    and ``do`` ``[B, H, T, dv]`` -> (dq, dk ``[B, H, T, dk]``, a VALUE head
+    each: the sum over a key head's group is the caller's; dv in q's dtype;
+    dgamma, dbeta ``[B, H, N, C]`` float32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, T, dk = q.shape
-    dv, chunk = v.shape[3], gamma.shape[3]
+    B, H, T, dv = v.shape
+    dk, chunk, group = q.shape[3], gamma.shape[3], H // q.shape[1]
     n, per = _gdn_block(T, chunk)
     nb = n // per
     rows = lambda b, h, i: (b, h, nb - 1 - i, 0)  # noqa: E731
+    key_in = pl.BlockSpec((1, 1, per * chunk, dk),
+                          lambda b, h, i: (b, h // group, nb - 1 - i, 0))
     wide_k = pl.BlockSpec((1, 1, per * chunk, dk), rows)
     wide_v = pl.BlockSpec((1, 1, per * chunk, dv), rows)
     scalars = pl.BlockSpec((1, 1, per, chunk), rows)
@@ -2069,18 +2104,296 @@ def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do):
         functools.partial(_gdn_bwd_kernel, chunk=chunk, per=per),
         name="gdn_chunk_bwd",
         grid=(B, H, nb),
-        in_specs=[wide_k, wide_k, wide_v, scalars, scalars,
+        in_specs=[key_in, key_in, wide_v, scalars, scalars,
                   pl.BlockSpec((1, 1, per, dk, dv),
                                lambda b, h, i: (b, h, nb - 1 - i, 0, 0)),
                   wide_v],
         out_specs=[wide_k, wide_k, wide_v, scalars, scalars],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dk), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, T, dk), k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(gamma.shape, jnp.float32),
                    jax.ShapeDtypeStruct(beta.shape, jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, gamma, beta, states, do))
+
+
+# ---------------------------------------------------------------------------
+# From a delta net's in-projection to the scan: convolution, SiLU, L2 norms,
+# heads-major layout (ops/delta_rule.py ``conv_delta_rule`` calls the pair)
+# ---------------------------------------------------------------------------
+# ``x`` ``[B, T, Hk * W]`` is the projection with its columns GROUPED by key
+# head (``delta_rule.group_columns``): group ``j`` holds ``[q_j | k_j | the
+# Hv / Hk value heads that read them]``, ``W = 2 dk + (Hv / Hk) dv`` columns,
+# so one block of one array is everything a key head's group needs.  Grid
+# (key head, batch, block of rows).  A block brings the ``GDN_PREP_HALO`` rows
+# before it as a second block of the same array (zero before the row's
+# start) and is copied behind them into VMEM scratch once; every tap of the
+# depthwise convolution is then a read of that scratch at a row offset.  The
+# work goes one head at a time in sub-blocks of ``GDN_PREP_SUB_ROWS`` rows, a
+# loop over them, so the body is traced at one sub-block's size and a
+# sub-block's float32 arrays stay small.  The reverse kernel also brings the
+# rows AFTER the block (its own rows' gradient reaches them through the
+# taps), makes the pre-activation again, sums ``dq`` and ``dk`` over a key
+# head's value heads in float32, and accumulates the convolution kernel's
+# gradient in its output block across batch and rows.
+
+#: rows before (and, in reverse, after) a block: one float32 tile; the
+#: convolution may have up to ``GDN_PREP_HALO + 1`` taps
+GDN_PREP_HALO = 8
+#: rows of the gradients' block after a block: one bf16 tile
+_GDN_PREP_D_HALO = 16
+#: rows per sub-block of the kernels' bodies, one iteration of their loops:
+#: an iteration is a chain the scheduler does not overlap with the next, so
+#: long sub-blocks run faster (PERF.md section 6, PR 42: 128 rows against
+#: 32), and the loop, not an unrolled body, keeps the kernels' jaxprs, which
+#: every trace of a step walks, small
+GDN_PREP_SUB_ROWS = 128
+#: the L2 norms' epsilon (``decoder_block.unit_norm``'s default)
+_GDN_PREP_EPS = 1e-6
+GDN_PREP_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+
+
+def _gdn_prep_pieces(dk: int, dv: int, group: int):
+    """``(first column, width, kind, head in the group)`` of a group's
+    heads."""
+    return ([(0, dk, "q", 0), (dk, dk, "k", 0)]
+            + [(2 * dk + m * dv, dv, "v", m) for m in range(group)])
+
+
+def _gdn_prep_conv(xs_ref, w_ref, r0, n: int, cols, taps: int):
+    """The taps' rows of ``xs_ref`` for output rows ``r0 .. r0 + n`` of the
+    block (``r0`` a multiple of the tile's 8 rows) and their weighted sum, in
+    ``causal_short_conv``'s order."""
+    from jax.experimental import pallas as pl
+
+    first = GDN_PREP_HALO - (taps - 1)
+    # a read at a row that is not a multiple of 8 needs a static offset, so:
+    # the tile-aligned window, then the taps' rows of it
+    window = xs_ref[pl.ds(r0, GDN_PREP_HALO + n), cols]
+    xt = [window[first + j:first + j + n] for j in range(taps)]
+    a = w_ref[0, 0:1, cols] * xt[0]
+    for j in range(1, taps):
+        a = a + w_ref[0, j:j + 1, cols] * xt[j]
+    return xt, a
+
+
+def _gdn_prep_sub_blocks(n: int, sub: int, body, init=None):
+    """``body(first row, carry)`` over the ``n`` sub-blocks of a block: a
+    loop, so that the kernel is traced at one sub-block's size."""
+    from jax.experimental import pallas as pl
+
+    return lax.fori_loop(
+        0, n, lambda t, c: body(pl.multiple_of(t * sub, sub), c), init)
+
+
+def _gdn_prep_fwd_kernel(x_ref, h_ref, w_ref, q_ref, k_ref, v_ref, xs_ref,
+                         *, taps, dk, dv, group, sub):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    R, halo = x_ref.shape[1], GDN_PREP_HALO
+    xs_ref[0:halo, :] = jnp.where(pl.program_id(2) > 0,
+                                  h_ref[0].astype(f32), 0.0)
+    xs_ref[halo:halo + R, :] = x_ref[0].astype(f32)
+    outs = {"q": q_ref, "k": k_ref, "v": v_ref}
+    for c0, width, kind, m in _gdn_prep_pieces(dk, dv, group):
+        cols = slice(c0, c0 + width)
+
+        def rows(r0, _, cols=cols, kind=kind, m=m):
+            _, a = _gdn_prep_conv(xs_ref, w_ref, r0, sub, cols, taps)
+            s = a * jax.nn.sigmoid(a)
+            if kind != "v":
+                s = s * lax.rsqrt(jnp.sum(s * s, axis=-1, keepdims=True)
+                                  + _GDN_PREP_EPS)
+            if kind == "q":
+                s = s * dk ** -0.5
+            outs[kind][0, m, pl.ds(r0, sub), :] = s.astype(outs[kind].dtype)
+
+        _gdn_prep_sub_blocks(R // sub, sub, rows)
+
+
+def _gdn_prep_specs(T: int, W: int, rows: int, taps: int):
+    """Block specs over the grouped projection (a block of rows, the tile of
+    rows before it, the tile after it) and over the grouped convolution
+    kernel; the halos' indices are clamped at the row's two ends, where the
+    kernels put zeros in their place."""
+    from jax.experimental import pallas as pl
+
+    per = rows // GDN_PREP_HALO
+    x = pl.BlockSpec((1, rows, W), lambda j, b, i: (b, i, j))
+    before = pl.BlockSpec(
+        (1, GDN_PREP_HALO, W),
+        lambda j, b, i: (b, jnp.maximum(i * per - 1, 0), j))
+    after = pl.BlockSpec(
+        (1, GDN_PREP_HALO, W),
+        lambda j, b, i: (b, jnp.minimum((i + 1) * per,
+                                        T // GDN_PREP_HALO - 1), j))
+    return x, before, after, pl.BlockSpec((1, taps, W),
+                                          lambda j, b, i: (j, 0, 0))
+
+
+def _gdn_prep_sub(rows: int, sub: int) -> int:
+    sub = min(sub, rows)
+    if rows % sub or sub % GDN_PREP_HALO:
+        raise ValueError(f"a block of {rows} rows is not whole sub-blocks "
+                         f"of {sub}")
+    return sub
+
+
+@_traced_once("dk", "dv", "rows", "out_dtype", "sub")
+def gdn_prep_fwd_pallas(x, w, *, dk: int, dv: int, rows: int, out_dtype,
+                        sub: int = GDN_PREP_SUB_ROWS, interpret):
+    """``x`` ``[B, T, Hk * W]`` float32, grouped columns; ``w`` ``[Hk, L, W]``
+    float32, the convolution kernel grouped alike -> ``q``, ``k`` ``[B, Hk, T,
+    dk]`` (convolved, SiLU, L2-normalised over the head, ``q`` times ``dk **
+    -0.5``) and ``v`` ``[B, Hv, T, dv]`` (convolved, SiLU) in ``out_dtype``:
+    what ``gdn_chunk_fwd_pallas`` takes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = x.shape
+    Hk, taps, W = w.shape
+    group = (W - 2 * dk) // dv
+    xs, before, _, ws = _gdn_prep_specs(T, W, rows, taps)
+    qk = pl.BlockSpec((1, 1, rows, dk), lambda j, b, i: (b, j, i, 0))
+    return pl.pallas_call(
+        functools.partial(_gdn_prep_fwd_kernel, taps=taps, dk=dk, dv=dv,
+                          group=group, sub=_gdn_prep_sub(rows, sub)),
+        name="gdn_prep_fwd",
+        grid=(Hk, B, T // rows),
+        in_specs=[xs, before, ws],
+        out_specs=[qk, qk, pl.BlockSpec((1, group, rows, dv),
+                                        lambda j, b, i: (b, j, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, Hk, T, dk), out_dtype),
+                   jax.ShapeDtypeStruct((B, Hk, T, dk), out_dtype),
+                   jax.ShapeDtypeStruct((B, Hk * group, T, dv), out_dtype)],
+        scratch_shapes=[pltpu.VMEM((GDN_PREP_HALO + rows, W), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=GDN_PREP_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(x, x, w)
+
+
+def _gdn_prep_bwd_kernel(x_ref, h_ref, t_ref, w_ref, dq_ref, dqt_ref, dk_ref,
+                         dkt_ref, dv_ref, dvt_ref, dx_ref, dw_ref, xs_ref,
+                         da_ref, *, taps, dk, dv, group, sub):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    R, halo = x_ref.shape[1], GDN_PREP_HALO
+    i, last = pl.program_id(2), pl.num_programs(2) - 1
+    xs_ref[0:halo, :] = jnp.where(i > 0, h_ref[0].astype(f32), 0.0)
+    xs_ref[halo:halo + R, :] = x_ref[0].astype(f32)
+    xs_ref[halo + R:, :] = t_ref[0].astype(f32)
+
+    @pl.when(jnp.logical_and(pl.program_id(1) == 0, i == 0))
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def grad_rows(d_ref, r0, d0, n, cols, kind, m):
+        """``d loss / d (the convolution's output)`` at rows ``r0 .. r0 + n``
+        of the block (``r0 = R``: the first rows after it), whose gradients
+        are rows ``d0 .. d0 + n`` of ``d_ref``; and the taps' rows of ``x``."""
+        xt, a = _gdn_prep_conv(xs_ref, w_ref, r0, n, cols, taps)
+        rows = pl.ds(d0, n)
+        sg = jax.nn.sigmoid(a)
+        if kind == "v":
+            ds = d_ref[0, m, rows, :].astype(f32)
+        else:       # the transpose of the repeat: the group's sum, float32
+            dn = d_ref[0, 0, rows, :].astype(f32)
+            for other in range(1, group):
+                dn = dn + d_ref[0, other, rows, :].astype(f32)
+            s = a * sg
+            inv = lax.rsqrt(jnp.sum(s * s, axis=-1, keepdims=True)
+                            + _GDN_PREP_EPS)
+            if kind == "q":
+                dn = dn * dk ** -0.5
+            ds = (dn - s * (inv * inv * jnp.sum(dn * s, axis=-1,
+                                                keepdims=True))) * inv
+        return xt, ds * (sg * (1.0 + a * (1.0 - sg)))
+
+    grads = {"q": (dq_ref, dqt_ref), "k": (dk_ref, dkt_ref),
+             "v": (dv_ref, dvt_ref)}
+    for c0, width, kind, m in _gdn_prep_pieces(dk, dv, group):
+        cols = slice(c0, c0 + width)
+        own, after = grads[kind]
+
+        def rows(r0, acc, cols=cols, kind=kind, m=m, own=own):
+            xt, da = grad_rows(own, r0, r0, sub, cols, kind, m)
+            da_ref[pl.ds(r0, sub), cols] = da
+            out = []
+            for j in range(taps):     # one tile of partial sums a tap; the
+                prod = da * xt[j]     # rows of a tile are summed at the end
+                part = acc[j]
+                for t0 in range(0, sub, halo):
+                    part = part + prod[t0:t0 + halo]
+                out.append(part)
+            return tuple(out)
+
+        acc = _gdn_prep_sub_blocks(
+            R // sub, sub, rows,
+            tuple(jnp.zeros((halo, width), f32) for _ in range(taps)))
+        _, da = grad_rows(after, R, 0, halo, cols, kind, m)
+        da_ref[R:R + halo, cols] = jnp.where(i < last, da, 0.0)
+        for j in range(taps):
+            dw_ref[0, j:j + 1, cols] += jnp.sum(acc[j], axis=0, keepdims=True)
+
+        def back(r0, _, cols=cols):   # the convolution's transpose
+            window = da_ref[pl.ds(r0, sub + halo), cols]
+            dx = w_ref[0, taps - 1:taps, cols] * window[:sub]
+            for j in range(taps - 1):
+                dx = dx + w_ref[0, j:j + 1, cols] * window[
+                    taps - 1 - j:taps - 1 - j + sub]
+            dx_ref[0, pl.ds(r0, sub), cols] = dx.astype(dx_ref.dtype)
+
+        _gdn_prep_sub_blocks(R // sub, sub, back)
+
+
+@_traced_once("rows", "sub")
+def gdn_prep_bwd_pallas(x, w, dq, dk_, dv_, *, rows: int,
+                        sub: int = GDN_PREP_SUB_ROWS, interpret):
+    """The transpose of :func:`gdn_prep_fwd_pallas`: its ``x`` and ``w``, and
+    the scan's ``dq``, ``dk`` ``[B, Hv, T, dk]`` (a VALUE head each) and
+    ``dv`` ``[B, Hv, T, dv]`` -> (``dx`` like ``x``, ``dw`` like ``w``)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = x.shape
+    Hk, taps, W = w.shape
+    dk, dv = dq.shape[3], dv_.shape[3]
+    group = dq.shape[1] // Hk
+    per = rows // _GDN_PREP_D_HALO
+
+    def d_specs(width):
+        return [pl.BlockSpec((1, group, rows, width),
+                             lambda j, b, i: (b, j, i, 0)),
+                pl.BlockSpec((1, group, _GDN_PREP_D_HALO, width),
+                             lambda j, b, i: (b, j, jnp.minimum(
+                                 (i + 1) * per,
+                                 T // _GDN_PREP_D_HALO - 1), 0))]
+
+    xs, before, after, ws = _gdn_prep_specs(T, W, rows, taps)
+    dx, dw = pl.pallas_call(
+        functools.partial(_gdn_prep_bwd_kernel, taps=taps, dk=dk, dv=dv,
+                          group=group, sub=_gdn_prep_sub(rows, sub)),
+        name="gdn_prep_bwd",
+        grid=(Hk, B, T // rows),
+        in_specs=[xs, before, after, ws, *d_specs(dk), *d_specs(dk),
+                  *d_specs(dv)],
+        out_specs=[xs, ws],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(w.shape, jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((2 * GDN_PREP_HALO + rows, W), jnp.float32),
+            pltpu.VMEM((GDN_PREP_HALO + rows, W), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=GDN_PREP_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(x, x, x, w, dq, dq, dk_, dk_, dv_, dv_)
+    return dx, dw

@@ -573,6 +573,38 @@ def test_delta_rule_scan_kernels(one_chip, on_tpu):
                     wide, wide, wide, one, one) == 2
 
 
+def test_delta_net_prep_kernels(one_chip, on_tpu):
+    """The gate of ``gdn_prep_fwd`` / ``gdn_prep_bwd`` opens at the cell's
+    shape (16 key and 32 value heads of 128, 4 taps, a row of 8192 in blocks
+    of 512 rows), and the pair compiles with forward and gradient beside
+    the scan's: a block of 512 x 512 float32 with its halo rows, the taps
+    read from VMEM scratch at row offsets that are not multiples of a tile,
+    which interpret mode cannot refuse."""
+    from paddle_tpu.ops import delta_rule as DR
+
+    c = QWEN3NEXT
+    Hk, taps = c["Hv"] // 2, 4
+    rows = DR.prep_kernel_rows(c["T"], Hk, c["Hv"], c["dk"], c["dv"], taps)
+    assert rows == 512
+    assert DR.prep_kernel_rows(c["T"], Hk, c["Hv"], 64, c["dv"], taps) is None
+    assert DR.prep_kernel_rows(c["T"] + 64, Hk, c["Hv"], c["dk"], c["dv"],
+                               taps) is None
+    columns = 2 * Hk * c["dk"] + c["Hv"] * c["dv"]
+
+    def loss(x, kernel, g, beta):
+        return DR.conv_delta_rule(x, kernel, g, beta, key_head_dim=c["dk"],
+                                  value_head_dim=c["dv"], rows=rows).sum()
+
+    one = _struct(one_chip, (1, c["T"], c["Hv"]))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _struct(one_chip, (1, c["T"], columns)),
+        _struct(one_chip, (taps, columns)), one, one).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    for name in ("gdn_prep_fwd", "gdn_prep_bwd", "gdn_chunk_fwd",
+                 "gdn_chunk_bwd"):
+        assert name in text, name
+
+
 def test_causal_attention_kernels_at_heads_of_256(one_chip, on_tpu):
     """Qwen3-Next's full-attention layer: 16 query heads of 256 over 2
     key-value heads, through the same two flash kernels."""
@@ -595,7 +627,11 @@ def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
     recomputation blocks, per-leaf Adam, state donated) compiled for the
     described v5e from shapes alone: the compiler's own count of arguments,
     results and temporaries stays under 15 GB of the chip's 16, with the
-    delta rule's and the attention's kernels in the program."""
+    delta rule's, the delta nets' prep and the attention's kernels in the
+    program.  (That no ``q`` or ``k`` is repeated to 32 heads is asserted on
+    the layer's jaxpr in tests/test_gdn_prep.py, at widths where the shape
+    tells them from ``v``, ``z`` and ``o``: here all five are ``[1, 8192,
+    32, 128]``.)"""
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -633,6 +669,7 @@ def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
         params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
     text = compiled.as_text()
     assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+    assert "gdn_prep_fwd" in text and "gdn_prep_bwd" in text
     assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes

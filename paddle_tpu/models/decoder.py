@@ -5,10 +5,16 @@ layers (a model may have none) and a dropless expert layer in the rest, a
 final RMSNorm, and the next-token cost over a head that is the embedding or a
 matrix of its own.
 
-Layer ``i``: ``h = x + Op_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.
+Two shapes of layer.  By default layer ``i`` has both sub-blocks: ``h = x +
+Op_i(RMSNorm(x))``, ``y = h + FFN_i(RMSNorm(h))``.  With ``ffn_layer_type``
+given, a layer is ONE sub-block, ``y = x + f_i(RMSNorm(x))``: the
+feed-forward alone where its kind is ``ffn_layer_type``, its kind's mixer
+alone elsewhere (two mixers may then stand side by side).
+
 Layer names (and so the device trace's scopes and the parameters'
 prefixes): what the mixer's builder names its layer, ``mlp<i>`` / ``moe<i>``,
-``norm_op<i>``, ``norm_ffn<i>``, ``emb``, ``norm_out``, ``cost``.
+``emb``, ``norm_out``, ``cost``; the norms ``norm_op<i>`` and ``norm_ffn<i>``
+of a layer of two sub-blocks, ``norm<i>`` of a layer of one.
 """
 
 from __future__ import annotations
@@ -31,7 +37,9 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                   shared_gate: bool = False,
                   experts_held: Optional[Sequence[int]] = None,
                   norm_eps: float = 1e-5, zero_centered_norm: bool = False,
-                  tie_head: bool = True, recompute_layers=True):
+                  tie_head: bool = True, recompute_layers=True,
+                  ffn_layer_type: Optional[str] = None,
+                  expert_act: str = "gated_silu"):
     """Returns ``(cost, extras)``: the mean next-token cross-entropy over
     ``tokens`` / ``next_tokens`` (two int sequence feeds of one length), and
     two extra outputs per expert layer, marked for the counters
@@ -49,7 +57,12 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     ``scoring``: how the router scores (``nn.expert_mlp``).
     ``num_dense_layers`` may be 0: every layer is then an expert layer.
     ``zero_centered_norm``: the stack's RMSNorms are ``x / rms(x) * (1 +
-    w)``.  ``recompute_layers`` marks decoder layers as
+    w)``.  ``ffn_layer_type``: every layer is one sub-block with one norm
+    and one residual add, the feed-forward alone where ``layer_types[i]`` is
+    this kind and the kind's mixer alone elsewhere (``num_dense_layers``
+    counts layers as before: a feed-forward layer ``i`` below it is a gated
+    MLP).  ``expert_act``: the experts' form (``nn.expert_mlp``).
+    ``recompute_layers`` marks decoder layers as
     recomputation blocks, one block a layer: ``True`` for every layer, or
     the indices of the layers to recompute (the others hold their
     activations)."""
@@ -68,39 +81,55 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
         routing["scoring"] = scoring
     if shared_gate:
         routing["shared_gate"] = True
-    for i, kind in enumerate(layer_types):
-        if kind not in mixers:
-            raise ValueError(f"layer {i}: unknown layer type {kind!r}")
-        normed = nn.rms_norm(x, eps=norm_eps, name=f"norm_op{i}", **centred)
-        op = mixers[kind](normed, i)
-        h = nn.addto([x, op], name=f"res_op{i}")
-        normed2 = nn.rms_norm(h, eps=norm_eps, name=f"norm_ffn{i}",
-                              **centred)
-        block = [normed, op, h, normed2]
+    if expert_act != "gated_silu":
+        routing["expert_act"] = expert_act
+
+    def feed_forward(normed, i):
+        """Layer ``i``'s feed-forward over ``normed`` and what rides with it
+        (an expert layer's two counters)."""
         if i < num_dense_layers:
-            ffn = nn.gated_mlp(normed2, intermediate_size, name=f"mlp{i}")
+            return nn.gated_mlp(normed, intermediate_size, name=f"mlp{i}"), []
+        ffn = nn.expert_mlp(
+            normed, moe_intermediate_size, num_experts=num_experts,
+            experts_held=experts_held, top_k=num_experts_per_tok,
+            norm_topk_prob=norm_topk_prob,
+            routed_scaling_factor=routed_scaling_factor,
+            shared_size=shared_size, name=f"moe{i}", **routing)
+        load = nn.get_output(ffn, "expert_load", size=1, name=f"moe{i}_load")
+        load.meta["obs_counter"] = {
+            "name": "moe_assignments", "labels": {"layer": f"moe{i}"},
+            "index_label": "expert", "first_index": (experts_held
+                                                     or (0,))[0]}
+        dropped = nn.get_output(ffn, "uncomputed", size=1,
+                                name=f"moe{i}_uncomputed")
+        dropped.meta["obs_counter"] = {
+            "name": "moe_uncomputed_assignments",
+            "labels": {"layer": f"moe{i}"}}
+        return ffn, [load, dropped]
+
+    for i, kind in enumerate(layer_types):
+        if kind not in mixers and kind != ffn_layer_type:
+            raise ValueError(f"layer {i}: unknown layer type {kind!r}")
+        if ffn_layer_type is not None:      # one sub-block a layer
+            normed = nn.rms_norm(x, eps=norm_eps, name=f"norm{i}", **centred)
+            if kind == ffn_layer_type:
+                sub, counters = feed_forward(normed, i)
+            else:
+                sub, counters = mixers[kind](normed, i), []
+            extras += counters
+            x = nn.addto([x, sub], name=f"res{i}")
+            block = [normed, *counters, sub, x]
         else:
-            ffn = nn.expert_mlp(
-                normed2, moe_intermediate_size, num_experts=num_experts,
-                experts_held=experts_held, top_k=num_experts_per_tok,
-                norm_topk_prob=norm_topk_prob,
-                routed_scaling_factor=routed_scaling_factor,
-                shared_size=shared_size, name=f"moe{i}", **routing)
-            load = nn.get_output(ffn, "expert_load", size=1,
-                                 name=f"moe{i}_load")
-            load.meta["obs_counter"] = {
-                "name": "moe_assignments", "labels": {"layer": f"moe{i}"},
-                "index_label": "expert", "first_index": (experts_held
-                                                         or (0,))[0]}
-            dropped = nn.get_output(ffn, "uncomputed", size=1,
-                                    name=f"moe{i}_uncomputed")
-            dropped.meta["obs_counter"] = {
-                "name": "moe_uncomputed_assignments",
-                "labels": {"layer": f"moe{i}"}}
-            extras += [load, dropped]
-            block += [load, dropped]
-        x = nn.addto([h, ffn], name=f"res_ffn{i}")
-        block += [ffn, x]
+            normed = nn.rms_norm(x, eps=norm_eps, name=f"norm_op{i}",
+                                 **centred)
+            op = mixers[kind](normed, i)
+            h = nn.addto([x, op], name=f"res_op{i}")
+            normed2 = nn.rms_norm(h, eps=norm_eps, name=f"norm_ffn{i}",
+                                  **centred)
+            ffn, counters = feed_forward(normed2, i)
+            extras += counters
+            x = nn.addto([h, ffn], name=f"res_ffn{i}")
+            block = [normed, op, h, normed2, *counters, ffn, x]
         if recompute_layers is True or (recompute_layers
                                         and i in recompute_layers):
             nn.remat_block(block, f"layer{i}")

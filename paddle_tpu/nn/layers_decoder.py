@@ -2,14 +2,17 @@
 causal grouped-query self-attention with QK-norm and rotary embedding (and,
 where the model has one, an output gate), latent attention (keys and values
 made from one narrow latent a token), a gated delta net (linear attention:
-a state a head, updated by the gated delta rule), a gated MLP,
+a state a head, updated by the gated delta rule), a Mamba-2 mixer (a
+state-space layer: a state a head, decayed by one scalar a token), a gated
+MLP,
 a dropless expert layer that holds a share of the experts (with shared
 experts beside them, where the model has them), and a next-token cost over a
 head that is the embedding (tied) or a matrix of its own.
 
 The reference (2016) has none of these; they follow its DSL conventions all
 the same (``input=`` first, ``name=``, parameters ``_<name>.<leaf>``, one
-``jax.named_scope`` per layer through ``Topology.apply``).  None has a bias.
+``jax.named_scope`` per layer through ``Topology.apply``).  None has a bias
+but the Mamba-2 mixer's convolution.
 A stack marks the layers of one block with :func:`remat_block`, and
 ``Topology.apply`` then recomputes the block in the backward pass instead of
 holding its activations.
@@ -28,10 +31,13 @@ from paddle_tpu.nn.layers import AttrLike, _pa, _refuse_packed, _seq_like
 from paddle_tpu.ops import decoder_block as DB
 from paddle_tpu.ops import delta_rule as DR
 from paddle_tpu.ops import moe as M
+from paddle_tpu.ops import ssd_scan as SS
+from paddle_tpu.ops.numerics import mxu_cast
 from paddle_tpu.utils.error import ConfigError
 
 __all__ = ["rms_norm", "gated_short_conv", "causal_self_attention",
-           "latent_attention", "gated_delta_net", "gated_mlp", "expert_mlp",
+           "latent_attention", "gated_delta_net", "mamba2_mixer", "gated_mlp",
+           "expert_mlp",
            "lm_head_cost",
            "remat_block"]
 
@@ -100,6 +106,7 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
                           output_gate: bool = False,
                           rotary_dim: Optional[int] = None,
                           zero_centered_norm: bool = False,
+                          qk_norm: bool = True, rotary: bool = True,
                           name: Optional[str] = None) -> LayerOutput:
     """Causal grouped-query self-attention: RMSNorm over every query head
     and every key head (one weight vector each; ``zero_centered_norm``: the
@@ -108,7 +115,9 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
     -0.5`` computed blockwise, output projection.  ``output_gate``: ``W_q``
     gives every head ``[q | gate]`` of ``head_dim`` each, and the attention's
     result is multiplied by ``sigmoid(gate)`` before the output
-    projection."""
+    projection.  ``qk_norm=False``: no norm over the heads, and the layer has
+    no ``q_norm`` / ``k_norm`` leaves; ``rotary=False``: the layer takes no
+    positions (a model whose other mixers carry the order)."""
     name = name or next_name("self_attention")
     if num_heads % num_kv_heads:
         raise ConfigError(f"{name!r}: {num_heads} query heads are not whole "
@@ -127,9 +136,9 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
         ParamSpec(f"_{name}.wv", (D, Hkv * dh), _fan_in(f"_{name}.wv", D)),
         ParamSpec(f"_{name}.wo", (H * dh, D),
                   _fan_in(f"_{name}.wo", H * dh)),
-        ParamSpec(f"_{name}.q_norm", (dh,), norm_w("q_norm")),
-        ParamSpec(f"_{name}.k_norm", (dh,), norm_w("k_norm")),
-    ]
+    ] + ([ParamSpec(f"_{name}.q_norm", (dh,), norm_w("q_norm")),
+          ParamSpec(f"_{name}.k_norm", (dh,), norm_w("k_norm"))]
+         if qk_norm else [])
 
     def forward(ctx, params, a: Act) -> Act:
         if not a.is_seq:
@@ -144,12 +153,14 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
             q, gate = q[..., :dh], q[..., dh:]
         k = O.linear(x, p["wk"]).reshape(B, T, Hkv, dh)
         v = O.linear(x, p["wv"]).reshape(B, T, Hkv, dh)
-        q = DB.rotary_embedding(
-            DB.rms_norm(q, p["q_norm"], norm_eps, zero_centered_norm),
-            rope_theta, rotary_dim)
-        k = DB.rotary_embedding(
-            DB.rms_norm(k, p["k_norm"], norm_eps, zero_centered_norm),
-            rope_theta, rotary_dim)
+
+        def placed(h, norm):     # the head's norm, then its position
+            if qk_norm:
+                h = DB.rms_norm(h, p[norm], norm_eps, zero_centered_norm)
+            return (DB.rotary_embedding(h, rope_theta, rotary_dim)
+                    if rotary else h)
+
+        q, k = placed(q, "q_norm"), placed(k, "k_norm")
         with jax.named_scope("attn_core"):
             o = DB.causal_attention(q, k, v, scale=dh ** -0.5)
         if output_gate:
@@ -324,10 +335,98 @@ def gated_delta_net(input: LayerOutput, *, num_key_heads: int,
     return LayerOutput(name, "gated_delta_net", D, [input], forward, specs)
 
 
-def _gated_mlp_specs(prefix: str, D: int, size: int):
+def mamba2_mixer(input: LayerOutput, *, num_heads: int, head_dim: int,
+                 n_groups: int, state_size: int, conv_kernel_size: int = 4,
+                 norm_eps: float = 1e-5,
+                 name: Optional[str] = None) -> LayerOutput:
+    """A Mamba-2 (SSD) state-space mixer.  ``[z | x | B | C | dt] = u W_in``
+    (``H P + H P + G N + G N + H`` columns, a head's and a group's channels
+    contiguous; ``H`` heads of ``P`` channels, ``G`` groups of ``N`` state
+    channels, no bias).  ``[x | B | C]`` goes through a depthwise causal
+    convolution of ``conv_kernel_size`` taps WITH a bias, then SiLU.  ``dt =
+    softplus(dt + dt_bias)`` (one a head and token, float32, no clamp), ``A =
+    -exp(A_log)`` (one a head).  Every head keeps a state ``[N, P]`` that
+    ``ops.ssd_scan`` carries along the row (zero at its start): ``S_t =
+    exp(dt_t A) S_{t-1} + B_t (dt_t x_t)^T``, ``y_t = S_t^T C_t + D x_t``,
+    head ``h`` reading group ``h // (H // G)``'s ``B`` and ``C``.  Then the
+    gate FIRST and the norm after: ``y * silu(z)``, RMS-normalised over each
+    group's ``H P / G`` channels, times a weight of ``H P``; ``y W_out``.
+
+    Scopes inside the layer's own: ``mamba_proj`` (both projections, the
+    convolution and SiLU, ``dt``, the skip, the gate and the group norm) and
+    ``ssd_scan`` (the recurrence: the kernels ``ssd_chunk_fwd`` /
+    ``ssd_chunk_bwd`` on the TPU, the sums of ``dt A`` and the chunked layout
+    of the scalars around them)."""
+    name = name or next_name("mamba2_mixer")
+    D = input.size
+    H, P, G, N = num_heads, head_dim, n_groups, state_size
+    if H % G:
+        raise ConfigError(f"{name!r}: {H} heads are not whole groups over "
+                          f"{G}")
+    inner, conv = H * P, H * P + 2 * G * N
+    normal = lambda leaf, std: _pa(None, f"_{name}.{leaf}",   # noqa: E731
+                                   init="normal", initial_std=std)
+    specs = [
+        ParamSpec(f"_{name}.w_in", (D, inner + conv + H),
+                  _fan_in(f"_{name}.w_in", D)),
+        ParamSpec(f"_{name}.kernel", (conv_kernel_size, conv),
+                  _fan_in(f"_{name}.kernel", conv_kernel_size)),
+        ParamSpec(f"_{name}.conv_bias", (conv,),
+                  _pa(None, f"_{name}.conv_bias", init="zeros")),
+        ParamSpec(f"_{name}.a_log", (H,), normal("a_log", 1.0)),
+        ParamSpec(f"_{name}.dt_bias", (H,), normal("dt_bias", 1.0)),
+        ParamSpec(f"_{name}.d", (H,), _pa(None, f"_{name}.d", init="ones")),
+        ParamSpec(f"_{name}.norm", (inner,),
+                  _pa(None, f"_{name}.norm", init="ones")),
+        ParamSpec(f"_{name}.w_out", (inner, D),
+                  _fan_in(f"_{name}.w_out", inner)),
+    ]
+
+    def forward(ctx, params, a: Act) -> Act:
+        if not a.is_seq:
+            raise ConfigError(f"mamba2_mixer {name!r} needs a sequence")
+        _refuse_packed(a, name, "mamba2_mixer")
+        p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
+        u = a.value
+        B, T = u.shape[:2]
+        f32 = jnp.float32
+        w = p["w_in"]
+        with jax.named_scope("mamba_proj"):
+            zxbc = O.linear(u, w[:, :inner + conv])
+            # dt's 64 columns as a product of their own with a float32
+            # result: a step that went through a bf16 result would carry
+            # its rounding into every decay of the row
+            uc, wd = mxu_cast(u, w[:, inner + conv:])
+            dt = jax.nn.softplus(
+                jnp.matmul(uc, wd, preferred_element_type=f32)
+                + p["dt_bias"].astype(f32))
+            z = zxbc[..., :inner]
+            xbc = jax.nn.silu(DB.causal_short_conv(
+                zxbc[..., inner:].astype(f32), p["kernel"],
+                p["conv_bias"])).astype(zxbc.dtype)
+            x = xbc[..., :inner].reshape(B, T, H, P)
+            Bm = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
+            Cm = xbc[..., inner + G * N:].reshape(B, T, G, N)
+            A = -jnp.exp(p["a_log"].astype(f32))
+        with jax.named_scope("ssd_scan"):
+            y = SS.ssd_scan(x, Bm, Cm, dt, A)
+        with jax.named_scope("mamba_proj"):
+            y = y.astype(f32) + p["d"].astype(f32)[:, None] * x.astype(f32)
+            y = y.reshape(B, T, inner) * jax.nn.silu(z.astype(f32))
+            y = DB.rms_norm(y.reshape(B, T, G, inner // G),
+                            p["norm"].reshape(G, inner // G), norm_eps)
+            out = O.linear(y.reshape(B, T, inner).astype(zxbc.dtype),
+                           p["w_out"])
+        return _seq_like(a, out)
+
+    return LayerOutput(name, "mamba2_mixer", D, [input], forward, specs)
+
+
+def _mlp_specs(prefix: str, D: int, size: int, gated: bool = True):
     return [
         ParamSpec(f"{prefix}w1", (D, size), _fan_in(f"{prefix}w1", D)),
-        ParamSpec(f"{prefix}w3", (D, size), _fan_in(f"{prefix}w3", D)),
+        *([ParamSpec(f"{prefix}w3", (D, size), _fan_in(f"{prefix}w3", D))]
+          if gated else []),
         ParamSpec(f"{prefix}w2", (size, D), _fan_in(f"{prefix}w2", size)),
     ]
 
@@ -336,12 +435,16 @@ def _gated_mlp(x, w1, w3, w2):
     return O.linear(jax.nn.silu(O.linear(x, w1)) * O.linear(x, w3), w2)
 
 
+def _relu2_mlp(x, w1, w2):
+    return O.linear(jnp.square(jax.nn.relu(O.linear(x, w1))), w2)
+
+
 def gated_mlp(input: LayerOutput, size: int, *,
               name: Optional[str] = None) -> LayerOutput:
     """``W_2(silu(W_1 x) * W_3 x)`` with ``size`` hidden units."""
     name = name or next_name("gated_mlp")
     D = input.size
-    specs = _gated_mlp_specs(f"_{name}.", D, size)
+    specs = _mlp_specs(f"_{name}.", D, size)
 
     def forward(ctx, params, a: Act) -> Act:
         out = _gated_mlp(a.value, *(params[s.name] for s in specs))
@@ -354,11 +457,15 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                experts_held: Optional[Sequence[int]] = None, top_k: int,
                norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
                shared_size: int = 0, scoring: str = "sigmoid",
-               shared_gate: bool = False,
+               shared_gate: bool = False, expert_act: str = "gated_silu",
                name: Optional[str] = None) -> LayerOutput:
-    """A dropless mixture of gated-MLP experts of ``size`` hidden units, as
-    the chip that holds experts ``experts_held = (first, count)`` of
-    ``num_experts`` computes it (default: all of them).  Every token is
+    """A dropless mixture of experts of ``size`` hidden units, as the chip
+    that holds experts ``experts_held = (first, count)`` of ``num_experts``
+    computes it (default: all of them).  An expert is a gated MLP,
+    ``W_2(silu(W_1 x) * W_3 x)`` (``expert_act="gated_silu"``), or, with
+    ``expert_act="relu2"``, TWO matrices and a squared ReLU, ``W_2
+    relu(W_1 x)^2``: the layer, and its shared expert, then have no ``w3``
+    leaf.  Every token is
     routed over all ``num_experts``: with ``scoring="sigmoid"`` by sigmoid
     scores, the ``top_k`` largest of ``score + expert_bias``; with
     ``scoring="softmax"`` by the softmax over all the router's outputs, the
@@ -389,6 +496,9 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
         raise ConfigError(f"{name!r}: unknown scoring {scoring!r}")
     if shared_gate and not shared_size:
         raise ConfigError(f"{name!r}: a gate without a shared expert")
+    if expert_act not in ("gated_silu", "relu2"):
+        raise ConfigError(f"{name!r}: unknown expert_act {expert_act!r}")
+    gated = expert_act == "gated_silu"
     bias = [ParamSpec(f"_{name}.expert_bias", (E,),
                       _pa(None, f"_{name}.expert_bias", init="zeros"))
             ] if scoring == "sigmoid" else []
@@ -396,11 +506,12 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
         ParamSpec(f"_{name}.router", (D, E), _fan_in(f"_{name}.router", D)),
         *bias,
         ParamSpec(f"_{name}.w1", (held, D, size), _fan_in(f"_{name}.w1", D)),
-        ParamSpec(f"_{name}.w3", (held, D, size), _fan_in(f"_{name}.w3", D)),
+        *([ParamSpec(f"_{name}.w3", (held, D, size),
+                     _fan_in(f"_{name}.w3", D))] if gated else []),
         ParamSpec(f"_{name}.w2", (held, size, D),
                   _fan_in(f"_{name}.w2", size)),
     ]
-    shared = (_gated_mlp_specs(f"_{name}.shared_", D, shared_size)
+    shared = (_mlp_specs(f"_{name}.shared_", D, shared_size, gated)
               if shared_size else [])
     gate = [ParamSpec(f"_{name}.shared_gate", (D,),
                       _fan_in(f"_{name}.shared_gate", D))
@@ -418,11 +529,12 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                 idx = jnp.where(a.mask.reshape(-1, 1) > 0, idx, -1)
         tm = M.moe_kernel_row_tile(D, size, idx.size)
         y, load, uncomputed = M.expert_layer(
-            x, idx, weights, p["w1"], p["w3"], p["w2"], num_experts=E,
+            x, idx, weights, p["w1"], p.get("w3"), p["w2"], num_experts=E,
             first_expert=first, tm=tm or 8, kernels=tm is not None)
         if shared:
             with jax.named_scope("moe_shared"):
-                ys = _gated_mlp(x, *(params[s.name] for s in shared))
+                ys = (_gated_mlp if gated else _relu2_mlp)(
+                    x, *(params[s.name] for s in shared))
                 if gate:
                     wg = params[gate[0].name].astype(jnp.float32)
                     ys = ys * jax.nn.sigmoid(jnp.sum(

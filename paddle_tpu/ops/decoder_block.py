@@ -73,18 +73,19 @@ def rotary_embedding(x, theta: float, rotary_dim=None):
     return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
 
 
-def causal_short_conv(z, kernel):
+def causal_short_conv(z, kernel, bias=None):
     """Depthwise causal convolution over time: ``z`` ``[B, T, D]``, ``kernel``
-    ``[L, D]``; ``out_t = sum_j kernel[j] * z[t - (L-1) + j]``, zero before
-    the row's start.  ``L`` shifted multiply-adds: at a few taps this is
-    bandwidth, not a convolution worth a kernel."""
+    ``[L, D]``, ``bias`` ``[D]`` where the model has one; ``out_t = sum_j
+    kernel[j] * z[t - (L-1) + j] (+ bias)``, zero before the row's start.
+    ``L`` shifted multiply-adds: at a few taps this is bandwidth, not a
+    convolution worth a kernel."""
     L, T = kernel.shape[0], z.shape[1]
     zp = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
     k = kernel.astype(z.dtype)
     out = k[0] * zp[:, 0:T]
     for j in range(1, L):
         out = out + k[j] * zp[:, j:j + T]
-    return out
+    return out if bias is None else out + bias.astype(z.dtype)
 
 
 # ---------------------------------------------------------------------------

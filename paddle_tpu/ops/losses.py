@@ -258,13 +258,16 @@ def _tiled_ce_cfg(B, T, D, V):
     cd = jnp.dtype(compute_dtype()).itemsize
     # per row: the d_states accumulator + states, the double-buffered
     # logits tile + its f32 d_l, three lane-padded per-row vectors; per
-    # call: the double-buffered w tile and d_w tile, which grow with D and
-    # not with N.  Held against the installed v5e compiler at twelve
-    # shapes from D=128 to D=4096 (bf16 policy, 112 MiB asked of the
-    # kernel): every shape at or under this budget compiled, and the two
-    # that did not (N=6144,D=2048; N=3072,D=4096) come out over it.
+    # call: the double-buffered w tile and d_w tile and the d_w product's
+    # own float32 result before it is stored, which grow with D and not
+    # with N.  Held against the installed v5e compiler at thirteen shapes
+    # from D=128 to D=4096 (bf16 policy, 112 MiB asked of the kernel):
+    # every shape at or under this budget compiled, and the three that did
+    # not (N=6144,D=2048; N=3072,D=4096; N=4096,D=2688, which asked for
+    # 113.6 MiB and which the estimate without the product's result put
+    # at 100.7) come out over it.
     est = (N * (D * (4 + cd) + vt * (2 * cd + 4) + 3 * 512)
-           + D * vt * (2 * cd + 8))
+           + D * vt * (2 * cd + 16))
     if est > 108 * 1024 * 1024:
         return None
     return rb, vt

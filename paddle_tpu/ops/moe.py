@@ -80,12 +80,16 @@ class Grouping(NamedTuple):
 
 def moe_kernel_row_tile(d_model: int, d_expert: int, assignments: int):
     """The grouped-product kernels' gate: their row tile, or ``None`` for
-    the XLA path.  Needs the TPU backend and lane-aligned widths."""
+    the XLA path.  Needs the TPU backend, a lane-aligned model width and an
+    expert width of whole half tiles (a multiple of 64: an expert of 1856 =
+    29 x 64 runs the kernels, its width tiled with a masked edge where it is
+    a product's result and taken whole where it is summed over; see
+    :func:`_largest_tile`)."""
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
 
     if not compiled_kernels():
         return None
-    if d_model % 128 or d_expert % 128 or assignments < 2048:
+    if d_model % 128 or d_expert % 64 or assignments < 2048:
         return None
     return 256
 
@@ -146,9 +150,17 @@ def group_assignments(key, counts, order, *, tm: int, rows: int) -> Grouping:
 # -- the grouped products ----------------------------------------------------
 
 def _largest_tile(n: int, cap: int) -> int:
+    """The tile of a RESULT's axis of ``n`` (never of the axis a product sums
+    over): ``n`` whole where it fits ``cap``, else the largest multiple of
+    128 under ``cap`` that divides it, else (no such divisor: 1856) the
+    largest multiple of 128 under ``cap``, the kernels' grids then rounding
+    up and the last block hanging over the edge (what is read there is
+    never summed into a column that is kept, what is written there is
+    dropped)."""
     if n <= cap:
         return n
-    return next((t for t in range(cap - cap % 128, 0, -128) if n % t == 0), n)
+    cap -= cap % 128
+    return next((t for t in range(cap, 0, -128) if n % t == 0), cap)
 
 
 def _row_expert(g: Grouping, tm: int):
@@ -220,8 +232,12 @@ def _expert_mlp_fwd(x, weights, w1, w3, w2, g: Grouping, tm, kernels):
         xs = _take_rows(x.astype(cd), row_token)              # [M, D]
     with jax.named_scope("moe_experts"):
         h1 = _gmm(xs, w1, g, tm, kernels, out_dtype=cd)
-        h3 = _gmm(xs, w3, g, tm, kernels, out_dtype=cd)
-        a = (jax.nn.silu(h1.astype(f32)) * h3.astype(f32)).astype(cd)
+        if w3 is None:          # two matrices: W_2 relu(W_1 x)^2
+            h3 = None
+            a = jnp.square(jax.nn.relu(h1.astype(f32))).astype(cd)
+        else:
+            h3 = _gmm(xs, w3, g, tm, kernels, out_dtype=cd)
+            a = (jax.nn.silu(h1.astype(f32)) * h3.astype(f32)).astype(cd)
         ys = _gmm(a, w2, g, tm, kernels, out_dtype=cd)        # [M, D]
     with jax.named_scope("moe_combine"):
         row_w = _take_rows(weights.reshape(-1).astype(f32), g.row_assign)
@@ -244,19 +260,24 @@ def _expert_mlp_bwd(tm, kernels, res, dy):
     with jax.named_scope("moe_experts"):
         da = _gmm(dys, w2, g, tm, kernels, transpose_rhs=True)   # [M, F] f32
         d_w2 = _tgmm(a, dys, g, tm, kernels)
-        h1f, h3f = h1.astype(f32), h3.astype(f32)
-        sig = jax.nn.sigmoid(h1f)
-        dh3 = (da * h1f * sig).astype(cd)
-        dh1 = (da * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(cd)
+        h1f = h1.astype(f32)
+        if w3 is None:
+            dh1 = (da * 2.0 * jax.nn.relu(h1f)).astype(cd)
+            d_w3 = None
+            dxs = _gmm(dh1, w1, g, tm, kernels, transpose_rhs=True)
+        else:
+            h3f = h3.astype(f32)
+            sig = jax.nn.sigmoid(h1f)
+            dh3 = (da * h1f * sig).astype(cd)
+            dh1 = (da * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(cd)
+            d_w3 = _tgmm(xs, dh3, g, tm, kernels).astype(w3.dtype)
+            dxs = (_gmm(dh1, w1, g, tm, kernels, transpose_rhs=True)
+                   + _gmm(dh3, w3, g, tm, kernels, transpose_rhs=True))
         d_w1 = _tgmm(xs, dh1, g, tm, kernels)
-        d_w3 = _tgmm(xs, dh3, g, tm, kernels)
-        dxs = (_gmm(dh1, w1, g, tm, kernels, transpose_rhs=True)
-               + _gmm(dh3, w3, g, tm, kernels, transpose_rhs=True))
     with jax.named_scope("moe_grouping"):
         dx = _add_rows(dxs, row_token, N)
     return (dx.astype(x_like.dtype), d_weights.astype(w_like.dtype),
-            d_w1.astype(w1.dtype), d_w3.astype(w3.dtype),
-            d_w2.astype(w2.dtype), None)
+            d_w1.astype(w1.dtype), d_w3, d_w2.astype(w2.dtype), None)
 
 
 _expert_mlp.defvjp(_expert_mlp_fwd, _expert_mlp_bwd)
@@ -267,9 +288,11 @@ def grouped_expert_mlp(x, weights, g: Grouping, w1, w3, w2, *, tm: int,
     """x ``[N, D]``, weights ``[N, k]`` (the router's, of every choice), the
     experts held ``w1``/``w3`` ``[held, D, F]`` and ``w2`` ``[held, F, D]``
     -> ``[N, D]``: ``sum over the choices held of weight * W_2e(silu(W_1e x)
-    * W_3e x)``.  Only the buffer's rows move: tokens are gathered into
-    rows and rows added back onto tokens, forward and backward, and the
-    backward reads the rows the forward wrote (no second sort)."""
+    * W_3e x)``, or, where ``w3`` is ``None`` (experts of two matrices),
+    ``weight * W_2e relu(W_1e x)^2``.  Only the buffer's rows move: tokens
+    are gathered into rows and rows added back onto tokens, forward and
+    backward, and the backward reads the rows the forward wrote (no second
+    sort)."""
     return _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels)
 
 
@@ -277,7 +300,8 @@ def expert_layer(x, idx, weights, w1, w3, w2, *, num_experts: int,
                  first_expert: int, tm: int, kernels: bool):
     """The part of a dropless expert layer's result that the experts held
     give -> (y ``[N, D]``, assignments per expert held, assignments held
-    that got no row: 0).  The row buffer has its usual size when the
+    that got no row: 0).  ``w3`` is ``None`` for experts of two matrices
+    (:func:`grouped_expert_mlp`).  The row buffer has its usual size when the
     step's routing fits it and the worst routing's size when not
     (:func:`buffer_rows`): one ``lax.cond``, the same rows either way."""
     N, k = idx.shape

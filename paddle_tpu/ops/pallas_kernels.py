@@ -39,6 +39,10 @@ sets takes part:
   reverse (``gdn_chunk_bwd``); ``q`` and ``k`` come at the key heads and a
   value head reads its key head through the index maps.  Gate:
   ``ops/delta_rule.delta_rule_kernel_chunk``.
+- The state-space (SSD) recurrence's chunked scan, forward
+  (``ssd_chunk_fwd``) and reverse (``ssd_chunk_bwd``); a grid step takes a
+  group of heads, whose channels are the lanes, where the layer keeps them.
+  Gate: ``ops/ssd_scan.ssd_kernel_chunk``.
 - A delta net's way from its in-projection to that scan, one pass forward
   (``gdn_prep_fwd``: the depthwise causal convolution, SiLU, the L2 norms,
   ``q``'s scale, the cast, the heads-major layout) and one back
@@ -71,7 +75,8 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "flash_bwd_key_rows",
            "gmm_pallas", "tgmm_pallas",
            "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas",
-           "gdn_prep_fwd_pallas", "gdn_prep_bwd_pallas", "GDN_PREP_HALO"]
+           "gdn_prep_fwd_pallas", "gdn_prep_bwd_pallas", "GDN_PREP_HALO",
+           "ssd_chunk_fwd_pallas", "ssd_chunk_bwd_pallas"]
 
 
 def _compiler_params(**kw):
@@ -1861,7 +1866,9 @@ def gmm_pallas(lhs, rhs, tile_expert, n_active, *, tm: int, tn: int,
     """``out[rows of tile i] = lhs[rows of tile i] @ rhs[tile_expert[i]]``
     for the active tiles (other rows are left unwritten).  lhs ``[M, K]`` in
     the compute dtype, rhs ``[E, K, N]`` (``[E, N, K]`` with
-    ``transpose_rhs``) in its stored dtype, cast a block at a time."""
+    ``transpose_rhs``) in its stored dtype, cast a block at a time.  ``tn``
+    need not divide ``N``: the last block of columns then hangs over the
+    edge, and what it computes there is dropped."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1878,7 +1885,7 @@ def gmm_pallas(lhs, rhs, tile_expert, n_active, *, tm: int, tn: int,
         name="moe_gmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(N // tn, M // tm),
+            grid=(pl.cdiv(N, tn), M // tm),
             in_specs=[
                 pl.BlockSpec((tm, K),
                              lambda j, i, te, na: (_last_active(i, na), 0)),
@@ -1919,7 +1926,8 @@ def tgmm_pallas(lhs, rhs, tile_expert, n_active, *, experts: int, tm: int,
     """``out[e] = sum over the tiles of expert e of lhs_tile^T @ rhs_tile``:
     lhs ``[M, K]``, rhs ``[M, N]`` -> ``[experts, K, N]`` float32.  The
     block of an expert no tile belongs to is never written: the caller
-    zeroes the experts whose count is 0."""
+    zeroes the experts whose count is 0.  ``tk`` and ``tn`` need not divide
+    ``K`` and ``N`` (both are the result's axes; the sum is over rows)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1930,7 +1938,7 @@ def tgmm_pallas(lhs, rhs, tile_expert, n_active, *, experts: int, tm: int,
         name="moe_tgmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(K // tk, N // tn, M // tm),
+            grid=(pl.cdiv(K, tk), pl.cdiv(N, tn), M // tm),
             in_specs=[
                 pl.BlockSpec((tm, tk), lambda a, b, i, te, na:
                              (_last_active(i, na), a)),
@@ -2119,6 +2127,162 @@ def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do, *, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, gamma, beta, states, do))
+
+
+# ---------------------------------------------------------------------------
+# The state-space (SSD) recurrence as a chunked scan (ops/ssd_scan.py has the
+# algebra)
+# ---------------------------------------------------------------------------
+# Grid (batch, group, block of chunks); the last axis is sequential and the
+# GROUP's state ``[N, heads * P]`` float32 (its gradient in the reverse
+# kernel) stays in VMEM scratch across it.  ``x`` and ``y`` are read and
+# written where the layer keeps them, ``[B, T, H * P]``: a group's heads are
+# a block of lanes, and so are ``B`` and ``C`` of ``[B, T, G * N]``.  The
+# chunks' scalars (``dt`` and the sums of ``dt A``) come ``[B, G, n, heads,
+# Q]``, a chunk's a whole lane row a head.  A grid step takes
+# ``KERNEL_BLOCK_CHUNKS`` chunks, unrolled.  The forward writes every
+# chunk's STARTING state out ([B, G, n, N, heads * P] float32); the reverse
+# kernel reads it back and makes the chunk's parts again.
+
+SSD_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _ssd_block(n: int):
+    from paddle_tpu.ops.ssd_scan import KERNEL_BLOCK_CHUNKS
+
+    per = min(n, KERNEL_BLOCK_CHUNKS)
+    if n % per:
+        raise ValueError(f"a row of {n} chunks is not whole blocks of {per}")
+    return per
+
+
+def _ssd_fwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, y_ref, s_ref, S_scr,
+                    *, chunk, per, P):
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops import ssd_scan as SS
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        S_scr[...] = jnp.zeros_like(S_scr)
+
+    for c in range(per):
+        rows = slice(c * chunk, (c + 1) * chunk)
+        S = S_scr[...]
+        s_ref[0, 0, c] = S
+        x = x_ref[0, rows, :]
+        y, S_end = SS.chunk_forward(
+            x, b_ref[0, rows, :], c_ref[0, rows, :], a_ref[0, 0, c],
+            dt_ref[0, 0, c], S, P, x.dtype)
+        y_ref[0, rows, :] = y.astype(y_ref.dtype)
+        S_scr[...] = S_end
+
+
+@_traced_once()
+def ssd_chunk_fwd_pallas(x, Bm, Cm, a, dt, *, interpret):
+    """x ``[B, T, H P]``, Bm, Cm ``[B, T, G N]`` in the compute dtype (head
+    ``h`` of ``H`` reads group ``h // (H // G)``; a head's and a group's
+    channels contiguous); a (``dt A`` summed from each chunk's start) and dt
+    ``[B, G, n, H / G, Q]`` float32 -> (y ``[B, T, H P]`` in x's dtype, every
+    chunk's starting state ``[B, G, n, N, (H / G) P]`` float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, HP = x.shape
+    _, G, n, heads, chunk = a.shape
+    N, W = Bm.shape[2] // G, HP // G
+    per = _ssd_block(n)
+    rows = lambda b, g, i: (b, i, g)  # noqa: E731
+    scalars = pl.BlockSpec((1, 1, per, heads, chunk),
+                           lambda b, g, i: (b, g, i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, chunk=chunk, per=per,
+                          P=W // heads),
+        name="ssd_chunk_fwd",
+        grid=(B, G, n // per),
+        in_specs=[pl.BlockSpec((1, per * chunk, W), rows),
+                  pl.BlockSpec((1, per * chunk, N), rows),
+                  pl.BlockSpec((1, per * chunk, N), rows),
+                  scalars, scalars],
+        out_specs=[pl.BlockSpec((1, per * chunk, W), rows),
+                   pl.BlockSpec((1, 1, per, N, W),
+                                lambda b, g, i: (b, g, i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((B, G, n, N, W), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, W), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=SSD_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(x, Bm, Cm, a, dt)
+
+
+def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, s_ref, dy_ref,
+                    dx_ref, db_ref, dc_ref, da_ref, ddt_ref, dS_scr,
+                    *, chunk, per, P):
+    from jax.experimental import pallas as pl
+
+    from paddle_tpu.ops import ssd_scan as SS
+
+    @pl.when(pl.program_id(2) == 0)     # the row's LAST block: the walk is
+    def _init():                        # reversed by the index maps
+        dS_scr[...] = jnp.zeros_like(dS_scr)
+
+    for c in reversed(range(per)):
+        rows = slice(c * chunk, (c + 1) * chunk)
+        x = x_ref[0, rows, :]
+        dx, dB, dC, da, ddt, dS = SS.chunk_backward(
+            x, b_ref[0, rows, :], c_ref[0, rows, :], a_ref[0, 0, c],
+            dt_ref[0, 0, c], s_ref[0, 0, c], dy_ref[0, rows, :],
+            dS_scr[...], P, x.dtype)
+        dx_ref[0, rows, :] = dx.astype(dx_ref.dtype)
+        db_ref[0, rows, :] = dB.astype(db_ref.dtype)
+        dc_ref[0, rows, :] = dC.astype(dc_ref.dtype)
+        da_ref[0, 0, c] = da
+        ddt_ref[0, 0, c] = ddt
+        dS_scr[...] = dS
+
+
+@_traced_once()
+def ssd_chunk_bwd_pallas(x, Bm, Cm, a, dt, states, dy, *, interpret):
+    """The reverse walk: what :func:`ssd_chunk_fwd_pallas` took and wrote,
+    and ``dy`` ``[B, T, H P]`` -> (dx in x's dtype; dB, dC ``[B, T, G N]``
+    in theirs, a group's heads summed; da, ddt ``[B, G, n, H / G, Q]``
+    float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, HP = x.shape
+    _, G, n, heads, chunk = a.shape
+    N, W = Bm.shape[2] // G, HP // G
+    per = _ssd_block(n)
+    nb = n // per
+    rows = lambda b, g, i: (b, nb - 1 - i, g)  # noqa: E731
+    wide = pl.BlockSpec((1, per * chunk, W), rows)
+    narrow = pl.BlockSpec((1, per * chunk, N), rows)
+    scalars = pl.BlockSpec((1, 1, per, heads, chunk),
+                           lambda b, g, i: (b, g, nb - 1 - i, 0, 0))
+    return tuple(pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, chunk=chunk, per=per,
+                          P=W // heads),
+        name="ssd_chunk_bwd",
+        grid=(B, G, nb),
+        in_specs=[wide, narrow, narrow, scalars, scalars,
+                  pl.BlockSpec((1, 1, per, N, W),
+                               lambda b, g, i: (b, g, nb - 1 - i, 0, 0)),
+                  wide],
+        out_specs=[wide, narrow, narrow, scalars, scalars],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(Bm.shape, Bm.dtype),
+                   jax.ShapeDtypeStruct(Cm.shape, Cm.dtype),
+                   jax.ShapeDtypeStruct(a.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(dt.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, W), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=SSD_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(x, Bm, Cm, a, dt, states, dy))
 
 
 # ---------------------------------------------------------------------------

@@ -668,6 +668,22 @@ def case_gated_delta_net(rng):
                               value_head_dim=3), feed
 
 
+def case_mamba2_mixer(rng):
+    # 2 heads of 3 channels in 1 group with a state of 4, a convolution with
+    # a bias (PR 43)
+    xs, feed = _seq(rng)
+    return nn.mamba2_mixer(_pre_fc(xs, size=8), num_heads=2, head_dim=3,
+                           n_groups=1, state_size=4), feed
+
+
+def case_self_attention_without_qk_norm_or_rotary(rng):
+    # the layer takes no positions and has no q_norm / k_norm leaves (PR 43)
+    xs, feed = _seq(rng)
+    return nn.causal_self_attention(_pre_fc(xs, size=8), num_heads=4,
+                                    num_kv_heads=2, head_dim=2,
+                                    qk_norm=False, rotary=False), feed
+
+
 def case_gated_self_attention(rng):
     # an output gate, rotary on 2 of a head's 4 channels, 1 + w norms (PR 41)
     xs, feed = _seq(rng)
@@ -702,6 +718,15 @@ def case_softmax_expert_mlp_with_a_gated_shared_expert(rng):
     return nn.expert_mlp(_pre_fc(xs), 8, num_experts=3, experts_held=(1, 2),
                          top_k=3, shared_size=6, scoring="softmax",
                          shared_gate=True), feed
+
+
+def case_relu2_expert_mlp_with_a_shared_expert(rng):
+    # experts of TWO matrices and a squared ReLU, the shared one too; every
+    # expert chosen (PR 43)
+    xs, feed = _seq(rng)
+    return nn.expert_mlp(_pre_fc(xs), 8, num_experts=3, experts_held=(1, 2),
+                         top_k=3, shared_size=6, routed_scaling_factor=2.5,
+                         expert_act="relu2"), feed
 
 
 def case_lm_head_cost(rng):

@@ -401,9 +401,12 @@ def test_shares_add_up_to_the_uncut_layer_with_the_shared_expert_once(ref):
 # -- the two older models are what they were -----------------------------------
 
 #: loss and the sum of every gradient's absolute values on seeded weights,
-#: read on the parent commit (3c5f171) with this file's ``_older_model``
+#: read with this file's ``_older_model`` on the parent commit of the PR that
+#: added the model after them (3c5f171 for the first two; 2fd814c, PR 43's
+#: parent, for Qwen3-Next itself)
 PARENT = {"lfm2": (3.9143424034118652, 226.93792724609375),
-          "kanana2": (4.320387840270996, 1011.89697265625)}
+          "kanana2": (4.320387840270996, 1011.89697265625),
+          "qwen3next": (4.521244049072266, 5852.37060546875)}
 
 
 def _older_model(which):
@@ -414,6 +417,16 @@ def _older_model(which):
             num_dense_layers=1, intermediate_size=96,
             moe_intermediate_size=48, num_experts=8, num_experts_per_tok=2,
             num_attention_heads=4, num_key_value_heads=2)
+    elif which == "qwen3next":
+        cost, _ = qwen3_next_net(
+            50, hidden_size=64, num_hidden_layers=4,
+            full_attention_interval=4, linear_num_key_heads=2,
+            linear_num_value_heads=4, linear_key_head_dim=16,
+            linear_value_head_dim=16, linear_conv_kernel_dim=4,
+            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+            partial_rotary_factor=0.25, moe_intermediate_size=48,
+            shared_expert_intermediate_size=32, num_experts=8,
+            num_experts_per_tok=3)
     else:
         cost, _ = kanana2_moe_net(
             50, hidden_size=64, num_hidden_layers=2, first_k_dense_replace=1,
@@ -431,9 +444,11 @@ def _older_model(which):
                                   for g in jax.tree_util.tree_leaves(grads)))
 
 
-@pytest.mark.parametrize("which", ["lfm2", "kanana2"])
+@pytest.mark.parametrize("which", ["lfm2", "kanana2", "qwen3next"])
 def test_older_models_are_bit_for_bit_what_they_were(which):
-    """The arguments this PR adds default to what the two models ran before:
-    their loss and gradients on the CPU are the parent commit's to the last
-    bit."""
+    """The arguments a later model's PR adds (PR 41's, then PR 43's: a layer
+    of one sub-block, experts of two matrices, attention without QK-norm or
+    rotary, a convolution's bias) default to what the older models ran
+    before: their loss and gradients on the CPU are the parent commit's to
+    the last bit."""
     assert _older_model(which) == PARENT[which]

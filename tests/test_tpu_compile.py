@@ -379,6 +379,8 @@ def test_ce_gate_counts_the_weight_tiles(one_chip, on_tpu):
 
     assert _tiled_ce_cfg(192, 32, 2048, V) is None     # N=6144
     assert _tiled_ce_cfg(96, 32, 4096, V) is None      # N=3072
+    # Nemotron-3-Nano's cell (PR 43): the compiler asked 113.6 MiB of 112
+    assert _tiled_ce_cfg(1, 4096, 2688, 16384) is None
     assert _tiled_ce_cfg(160, 32, 2048, V) is not None  # N=5120
     n = _kernels(
         jax.value_and_grad(sequence_softmax_ce_readout, argnums=(0, 1, 2)),
@@ -676,3 +678,141 @@ def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 3 * 4 * 424_340_544 < m.argument_size_in_bytes     # p, m, v
     assert held < 15e9, held
+
+
+# -- a state-space scan and experts of 1856 (PR 43): Nemotron-3-Nano-30B-A3B's
+# -- published widths at the benchmark cell's row of 4096 tokens --------------
+
+NEMOTRON = dict(T=4096, D=2688, H=64, P=64, G=8, N=128, F=1856, held=8,
+                top_k=6, Hq=32, Hkv=2, dh=128)
+
+
+def test_ssd_scan_kernels(one_chip, on_tpu):
+    """The gate admits the cell's row of 4096 at 64 heads of 64 in 8 groups
+    with a state of 128, and the forward and the reverse scan kernel (a
+    group's 128 x 512 state, and its gradient, in VMEM scratch across 8 grid
+    steps of 4 chunks of 128) compile, with x, B and C read where the layer
+    keeps them (a group a block of lanes)."""
+    from paddle_tpu.ops import ssd_scan as SS
+
+    c = NEMOTRON
+    assert SS.ssd_kernel_chunk(c["T"], c["P"], c["H"] // c["G"],
+                               c["N"]) == 128
+    assert SS.ssd_kernel_chunk(c["T"] + 8, c["P"], c["H"] // c["G"],
+                               c["N"]) is None
+
+    def loss(x, Bm, Cm, dt, A):
+        return SS.ssd_scan(x, Bm, Cm, dt, A).astype(jnp.float32).sum()
+
+    bf16 = jnp.bfloat16
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        _struct(one_chip, (1, c["T"], c["H"], c["P"]), bf16),
+        _struct(one_chip, (1, c["T"], c["G"], c["N"]), bf16),
+        _struct(one_chip, (1, c["T"], c["G"], c["N"]), bf16),
+        _struct(one_chip, (1, c["T"], c["H"])),
+        _struct(one_chip, (c["H"],))).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
+
+
+def test_grouped_expert_products_at_a_width_of_1856(one_chip, on_tpu):
+    """8 experts of TWO matrices 2688 x 1856 held of 128, 6 a token: 1856 is
+    29 x 64, no multiple of 128.  The gate opens, the width is tiled with a
+    masked edge where it is a product's result (512 of 1856, 1024 of 1856)
+    and taken whole where it is summed over, and in each row buffer moe_gmm
+    runs twice forward and twice backward and moe_tgmm for the two weight
+    gradients."""
+    from paddle_tpu.ops import moe as M
+
+    c = NEMOTRON
+    N, k = c["T"], c["top_k"]
+    tm = M.moe_kernel_row_tile(c["D"], c["F"], N * k)
+    assert tm == 256
+    assert M.moe_kernel_row_tile(c["D"], c["F"] + 32, N * k) is None
+    assert M._largest_tile(c["F"], 512) == 512
+    assert M._largest_tile(c["F"], 1024) == 1024
+    assert M._largest_tile(c["D"], 512) == 384          # 2688 = 7 x 384
+    assert M.buffer_rows(N, k, 128, c["held"], tm) == (5120, 26624)
+
+    def loss(x, w, w1, w2, idx):
+        return M.expert_layer(x, idx, w, w1, None, w2, num_experts=128,
+                              first_expert=0, tm=tm, kernels=True)[0].sum()
+
+    s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
+    args = [s(N, c["D"]), s(N, k), s(c["held"], c["D"], c["F"]),
+            s(c["held"], c["F"], c["D"]),
+            _struct(one_chip, (N, k), jnp.int32)]
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+                    *args) == 12
+
+
+def test_causal_attention_kernels_at_sixteen_query_heads_a_key_head(one_chip,
+                                                                    on_tpu):
+    """Nemotron-H's attention layer: 32 query heads of 128 over 2 key-value
+    heads (16 a group; LFM2 runs 4, Qwen3-Next 8), through the same two
+    flash kernels."""
+    from paddle_tpu.ops import decoder_block as DB
+
+    c = NEMOTRON
+    assert DB.attention_kernel_blocks(c["T"], c["dh"], c["Hq"], c["Hkv"])
+
+    def loss(q, k, v):
+        return DB.causal_attention(q, k, v, scale=c["dh"] ** -0.5).sum()
+
+    q = _struct(one_chip, (1, c["T"], c["Hq"], c["dh"]))
+    kv = _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"]))
+    assert _kernels(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    q, kv, kv) == 2
+
+
+def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's whole step (the model's loss and gradient under its nine
+    recomputation blocks, per-leaf Adam, state donated) compiled for the
+    described v5e from shapes alone: the compiler's own count of arguments,
+    results and temporaries stays under 13 GB of the chip's 16 (11.4 read,
+    PR 43), with the scan's, the attention's and the grouped products'
+    kernels in the program: no gate is closed."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import manifest
+
+    import paddle_tpu.nn as nn
+    from paddle_tpu.param.optimizers import Adam
+
+    cell = manifest.cell("nemotron3nano-train-b1-t4096")
+    cfg, T = cell["config"], cell["traffic"]["seq_len"]
+    cost, extras = manifest.program(cfg).net(cfg)
+    topo = nn.Topology([cost] + extras)
+    params = {k: _struct(one_chip, spec.shape)
+              for k, spec in topo.param_specs.items()}
+    o = cfg["optimizer"]
+    opt = Adam(learning_rate=o["learning_rate"], beta1=o["beta1"],
+               beta2=o["beta2"], epsilon=o["epsilon"])
+    opt_state = jax.tree_util.tree_map(
+        lambda a: _struct(one_chip, a.shape, a.dtype),
+        jax.eval_shape(opt.init_state, params))
+    ids = (_struct(one_chip, (1, T), jnp.int32),
+           _struct(one_chip, (1,), jnp.int32))
+
+    def step(params, opt_state, feed):
+        def loss(p):
+            outs, _ = topo.apply(p, {}, feed, train=True)
+            return outs["cost"].value, [outs[e.name].value for e in extras]
+
+        (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        return (value, counts) + opt.update(params, grads, opt_state)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
+    text = compiled.as_text()
+    for name in ("ssd_chunk_fwd", "ssd_chunk_bwd", "flash_attn_fwd",
+                 "flash_attn_bwd", "moe_gmm", "moe_tgmm"):
+        assert name in text, name
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 3 * 4 * 666_963_456 < m.argument_size_in_bytes     # p, m, v
+    assert held < 13e9, held

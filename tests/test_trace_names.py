@@ -26,7 +26,8 @@ KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
            "lse_rows", "attn_dec_fwd", "attn_dec_bwd", "ce_readout_fwd",
            "ce_readout_bwd", "topk_lse_readout", "topk_lse_logits",
            "flash_attn_fwd", "flash_attn_bwd", "moe_gmm", "moe_tgmm",
-           "gdn_chunk_fwd", "gdn_chunk_bwd", "gdn_prep_fwd", "gdn_prep_bwd"]
+           "gdn_chunk_fwd", "gdn_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd",
+           "gdn_prep_fwd", "gdn_prep_bwd"]
 
 
 # -- (a) kernels ----------------------------------------------------------
@@ -147,6 +148,34 @@ def test_jitted_step_holds_the_scopes_and_the_same_equations(
     assert not scopes & _scope_names(_name_stacks(bare.jaxpr))
     # names and metadata only: the same equations on the same shapes
     assert str(named) == str(bare)
+
+
+def test_nemotron_h_step_holds_its_layers_scopes():
+    """The scopes the Nemotron-H cell's per-layer metrics read (PR 43): a
+    layer's own (``mamba<i>``, ``attn<i>``, ``moe<i>``, ``i`` the published
+    index) and, inside it, ``mamba_proj`` / ``ssd_scan``, ``attn_core`` and
+    the expert layer's five."""
+    from paddle_tpu.models import nemotron_h_net
+
+    nn.reset_naming()
+    cost, extras = nemotron_h_net(
+        50, hybrid_override_pattern="ME*", hidden_size=16, mamba_num_heads=2,
+        mamba_head_dim=4, n_groups=1, ssm_state_size=4, conv_kernel=4,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=4,
+        moe_intermediate_size=8, moe_shared_expert_intermediate_size=8,
+        n_routed_experts=4, num_experts_per_tok=2)
+    topo = nn.Topology([cost] + extras)
+    params, _ = topo.init(jax.random.PRNGKey(0))
+    ids = (np.ones((2, 6), np.int32), np.full((2,), 6, np.int32))
+    feed = {"tokens": ids, "next_tokens": ids}
+    grad = jax.make_jaxpr(jax.grad(lambda p: topo.apply(
+        p, {}, feed, train=True)[0]["cost"].value))(params)
+    names = _scope_names(_name_stacks(grad.jaxpr))
+    assert {"mamba0", "mamba_proj", "ssd_scan", "moe1", "moe_routing",
+            "moe_grouping", "moe_experts", "moe_combine", "moe_shared",
+            "attn2", "attn_core", "norm0", "norm1", "norm2", "norm_out",
+            "cost"} <= names
+    assert not {"norm_op0", "norm_ffn0", "mlp0", "moe0", "mamba1"} & names
 
 
 def _primitives_under(jaxpr, scope, inside=False, out=None):

@@ -353,10 +353,28 @@ def mamba2_mixer(input: LayerOutput, *, num_heads: int, head_dim: int,
     group's ``H P / G`` channels, times a weight of ``H P``; ``y W_out``.
 
     Scopes inside the layer's own: ``mamba_proj`` (both projections, the
-    convolution and SiLU, ``dt``, the skip, the gate and the group norm) and
-    ``ssd_scan`` (the recurrence: the kernels ``ssd_chunk_fwd`` /
-    ``ssd_chunk_bwd`` on the TPU, the sums of ``dt A`` and the chunked layout
-    of the scalars around them)."""
+    convolution with its bias and SiLU, ``dt``, the skip, the gate and the
+    group norm) and ``ssd_scan`` (the recurrence: the kernels
+    ``ssd_chunk_fwd`` / ``ssd_chunk_bwd`` on the TPU, the sums of ``dt A``
+    and the chunked layout of the scalars around them).  Where
+    ``ops.ssd_scan.prep_kernel_block`` opens (the TPU backend, ``H P``, ``G
+    N`` and the column where ``[x | B | C]`` start multiples of 128, a
+    convolution within the halo, a row of whole blocks that the scan does
+    not pad), what lies between ``W_in``'s product and the scan is the kernel
+    pair ``mamba_prep_fwd`` / ``mamba_prep_bwd`` under ``mamba_proj``, one
+    pass over the ``[x | B | C]`` columns each way
+    (``ops.ssd_scan.conv_ssd_scan``): the forward reads them where the ONE
+    product wrote them and writes x ``[B, T, H P]`` and ``[B | C]`` ``[B, T,
+    2 G N]`` in the compute dtype, of which the scan's kernels read a
+    group's blocks, and x once more as the product's dtype has it (float32
+    unless ``--amp``) for the skip below, which reads what the chain's
+    reads; the
+    reverse takes the scan's ``dx``, ``dB``, ``dC`` and the skip's part of
+    ``dx`` (in x's dtype), and ``W_in``'s gradient is two products (the
+    ``z`` columns' and the ``[x | B | C]`` columns') joined at the weight's
+    size, so nothing is sliced, joined, padded or cast at the activations'
+    size.
+    Elsewhere the chain below runs in ``jax.numpy``."""
     name = name or next_name("mamba2_mixer")
     D = input.size
     H, P, G, N = num_heads, head_dim, n_groups, state_size
@@ -391,8 +409,8 @@ def mamba2_mixer(input: LayerOutput, *, num_heads: int, head_dim: int,
         B, T = u.shape[:2]
         f32 = jnp.float32
         w = p["w_in"]
+        block = SS.prep_kernel_block(T, H, P, G, N, conv_kernel_size, inner)
         with jax.named_scope("mamba_proj"):
-            zxbc = O.linear(u, w[:, :inner + conv])
             # dt's 64 columns as a product of their own with a float32
             # result: a step that went through a bf16 result would carry
             # its rounding into every decay of the row
@@ -400,22 +418,29 @@ def mamba2_mixer(input: LayerOutput, *, num_heads: int, head_dim: int,
             dt = jax.nn.softplus(
                 jnp.matmul(uc, wd, preferred_element_type=f32)
                 + p["dt_bias"].astype(f32))
-            z = zxbc[..., :inner]
-            xbc = jax.nn.silu(DB.causal_short_conv(
-                zxbc[..., inner:].astype(f32), p["kernel"],
-                p["conv_bias"])).astype(zxbc.dtype)
-            x = xbc[..., :inner].reshape(B, T, H, P)
-            Bm = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
-            Cm = xbc[..., inner + G * N:].reshape(B, T, G, N)
             A = -jnp.exp(p["a_log"].astype(f32))
-        with jax.named_scope("ssd_scan"):
-            y = SS.ssd_scan(x, Bm, Cm, dt, A)
+        if block is None:
+            with jax.named_scope("mamba_proj"):
+                zxbc = O.linear(u, w[:, :inner + conv])
+                z = zxbc[..., :inner]
+                xbc = jax.nn.silu(DB.causal_short_conv(
+                    zxbc[..., inner:].astype(f32), p["kernel"],
+                    p["conv_bias"])).astype(zxbc.dtype)
+                x = xbc[..., :inner].reshape(B, T, H, P)
+                Bm = xbc[..., inner:inner + G * N].reshape(B, T, G, N)
+                Cm = xbc[..., inner + G * N:].reshape(B, T, G, N)
+            with jax.named_scope("ssd_scan"):
+                y = SS.ssd_scan(x, Bm, Cm, dt, A)
+        else:       # the product, the kernels and the scan under one vjp
+            z, x, y = SS.conv_ssd_scan(
+                u, w[:, :inner + conv], p["kernel"], p["conv_bias"], dt, A,
+                groups=G, block=block)
         with jax.named_scope("mamba_proj"):
             y = y.astype(f32) + p["d"].astype(f32)[:, None] * x.astype(f32)
             y = y.reshape(B, T, inner) * jax.nn.silu(z.astype(f32))
             y = DB.rms_norm(y.reshape(B, T, G, inner // G),
                             p["norm"].reshape(G, inner // G), norm_eps)
-            out = O.linear(y.reshape(B, T, inner).astype(zxbc.dtype),
+            out = O.linear(y.reshape(B, T, inner).astype(z.dtype),
                            p["w_out"])
         return _seq_like(a, out)
 

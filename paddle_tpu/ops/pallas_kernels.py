@@ -49,6 +49,15 @@ sets takes part:
   (``gdn_prep_bwd``, which also sums ``dq``, ``dk`` over a key head's value
   heads and accumulates the convolution kernel's gradient).  Gate:
   ``ops/delta_rule.prep_kernel_rows``.
+- A Mamba-2 mixer's way from its in-projection to that scan, one pass
+  forward (``mamba_prep_fwd``: the depthwise causal convolution over ``[x |
+  B | C]`` read at their column offset in the projection, the bias, SiLU, the
+  cast; x and ``[B | C]``, two arrays of which the scan's kernels read a
+  group's blocks, and x in the projection's dtype for the layer's skip) and
+  one back
+  (``mamba_prep_bwd``, which also adds the skip's part of ``dx`` and
+  accumulates the convolution kernel's and the bias's gradients).  Gate:
+  ``ops/ssd_scan.prep_kernel_block``.
 
 Where a gate is closed (the CPU, a shape past it, a step that jit partitions
 over a mesh: one with sharding rules, or ``SGDTrainer(mesh=...)``) the
@@ -76,7 +85,8 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "gmm_pallas", "tgmm_pallas",
            "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas",
            "gdn_prep_fwd_pallas", "gdn_prep_bwd_pallas", "GDN_PREP_HALO",
-           "ssd_chunk_fwd_pallas", "ssd_chunk_bwd_pallas"]
+           "ssd_chunk_fwd_pallas", "ssd_chunk_bwd_pallas",
+           "mamba_prep_fwd_pallas", "mamba_prep_bwd_pallas"]
 
 
 def _compiler_params(**kw):
@@ -2137,7 +2147,11 @@ def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do, *, interpret):
 # GROUP's state ``[N, heads * P]`` float32 (its gradient in the reverse
 # kernel) stays in VMEM scratch across it.  ``x`` and ``y`` are read and
 # written where the layer keeps them, ``[B, T, H * P]``: a group's heads are
-# a block of lanes, and so are ``B`` and ``C`` of ``[B, T, G * N]``.  The
+# a block of lanes, and so are ``B`` and ``C`` of ``[B, T, G * N]``; or
+# ``B`` and ``C`` are blocks of ONE array ``[B, T, 2 G * N]``, ``[B | C]`` as
+# ``mamba_prep_fwd`` writes it (``Cm`` ``None``: the same blocks at column
+# blocks ``g`` and ``G + g``; nothing is sliced out of it).  The reverse
+# kernel writes ``dx``, ``dB``, ``dC`` as three arrays either way.  The
 # chunks' scalars (``dt`` and the sums of ``dt A``) come ``[B, G, n, heads,
 # Q]``, a chunk's a whole lane row a head.  A grid step takes
 # ``KERNEL_BLOCK_CHUNKS`` chunks, unrolled.  The forward writes every
@@ -2154,6 +2168,19 @@ def _ssd_block(n: int):
     if n % per:
         raise ValueError(f"a row of {n} chunks is not whole blocks of {per}")
     return per
+
+
+def _ssd_operands(x, Bm, Cm, G: int):
+    """``(operands, N, first column blocks of B and of C)`` of the scan's
+    kernels: three arrays, or with ``Cm`` ``None`` two, ``Bm`` holding ``[B |
+    C]`` ``[B, T, 2 G N]``, of which group ``g`` reads the column blocks ``g``
+    and ``G + g``."""
+    if Cm is not None:
+        return (x, Bm, Cm), Bm.shape[2] // G, (0, 0)
+    if Bm.shape[2] % (2 * G):
+        raise ValueError(f"{Bm.shape[2]} columns are not [B | C] of {G} "
+                         "groups")
+    return (x, Bm, Bm), Bm.shape[2] // (2 * G), (0, G)
 
 
 def _ssd_fwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, y_ref, s_ref, S_scr,
@@ -2182,17 +2209,19 @@ def _ssd_fwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, y_ref, s_ref, S_scr,
 def ssd_chunk_fwd_pallas(x, Bm, Cm, a, dt, *, interpret):
     """x ``[B, T, H P]``, Bm, Cm ``[B, T, G N]`` in the compute dtype (head
     ``h`` of ``H`` reads group ``h // (H // G)``; a head's and a group's
-    channels contiguous); a (``dt A`` summed from each chunk's start) and dt
-    ``[B, G, n, H / G, Q]`` float32 -> (y ``[B, T, H P]`` in x's dtype, every
-    chunk's starting state ``[B, G, n, N, (H / G) P]`` float32)."""
+    channels contiguous), or Bm ``[B, T, 2 G N]``, the two side by side, and
+    Cm ``None``; a (``dt A`` summed from each chunk's start) and dt ``[B, G,
+    n, H / G, Q]`` float32 -> (y ``[B, T, H P]`` in x's dtype, every chunk's
+    starting state ``[B, G, n, N, (H / G) P]`` float32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HP = x.shape
     _, G, n, heads, chunk = a.shape
-    N, W = Bm.shape[2] // G, HP // G
+    operands, N, first = _ssd_operands(x, Bm, Cm, G)
+    W = HP // G
     per = _ssd_block(n)
-    rows = lambda b, g, i: (b, i, g)  # noqa: E731
+    rows = lambda c0: (lambda b, g, i: (b, i, c0 + g))  # noqa: E731
     scalars = pl.BlockSpec((1, 1, per, heads, chunk),
                            lambda b, g, i: (b, g, i, 0, 0))
     return pl.pallas_call(
@@ -2200,11 +2229,11 @@ def ssd_chunk_fwd_pallas(x, Bm, Cm, a, dt, *, interpret):
                           P=W // heads),
         name="ssd_chunk_fwd",
         grid=(B, G, n // per),
-        in_specs=[pl.BlockSpec((1, per * chunk, W), rows),
-                  pl.BlockSpec((1, per * chunk, N), rows),
-                  pl.BlockSpec((1, per * chunk, N), rows),
+        in_specs=[pl.BlockSpec((1, per * chunk, W), rows(0)),
+                  pl.BlockSpec((1, per * chunk, N), rows(first[0])),
+                  pl.BlockSpec((1, per * chunk, N), rows(first[1])),
                   scalars, scalars],
-        out_specs=[pl.BlockSpec((1, per * chunk, W), rows),
+        out_specs=[pl.BlockSpec((1, per * chunk, W), rows(0)),
                    pl.BlockSpec((1, 1, per, N, W),
                                 lambda b, g, i: (b, g, i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -2214,7 +2243,7 @@ def ssd_chunk_fwd_pallas(x, Bm, Cm, a, dt, *, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=SSD_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(x, Bm, Cm, a, dt)
+    )(*operands, a, dt)
 
 
 def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, s_ref, dy_ref,
@@ -2246,20 +2275,22 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, a_ref, dt_ref, s_ref, dy_ref,
 @_traced_once()
 def ssd_chunk_bwd_pallas(x, Bm, Cm, a, dt, states, dy, *, interpret):
     """The reverse walk: what :func:`ssd_chunk_fwd_pallas` took and wrote,
-    and ``dy`` ``[B, T, H P]`` -> (dx in x's dtype; dB, dC ``[B, T, G N]``
-    in theirs, a group's heads summed; da, ddt ``[B, G, n, H / G, Q]``
-    float32)."""
+    and ``dy`` ``[B, T, H P]`` -> (dx in x's dtype; dB, dC ``[B, T, G N]`` in
+    theirs, a group's heads summed, two arrays also where B and C were read
+    from one; da, ddt ``[B, G, n, H / G, Q]`` float32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, HP = x.shape
     _, G, n, heads, chunk = a.shape
-    N, W = Bm.shape[2] // G, HP // G
+    operands, N, first = _ssd_operands(x, Bm, Cm, G)
+    W = HP // G
     per = _ssd_block(n)
     nb = n // per
-    rows = lambda b, g, i: (b, nb - 1 - i, g)  # noqa: E731
-    wide = pl.BlockSpec((1, per * chunk, W), rows)
-    narrow = pl.BlockSpec((1, per * chunk, N), rows)
+    rows = lambda c0: (  # noqa: E731
+        lambda b, g, i: (b, nb - 1 - i, c0 + g))
+    wide = pl.BlockSpec((1, per * chunk, W), rows(0))
+    narrow = pl.BlockSpec((1, per * chunk, N), rows(0))
     scalars = pl.BlockSpec((1, 1, per, heads, chunk),
                            lambda b, g, i: (b, g, nb - 1 - i, 0, 0))
     return tuple(pl.pallas_call(
@@ -2267,14 +2298,17 @@ def ssd_chunk_bwd_pallas(x, Bm, Cm, a, dt, states, dy, *, interpret):
                           P=W // heads),
         name="ssd_chunk_bwd",
         grid=(B, G, nb),
-        in_specs=[wide, narrow, narrow, scalars, scalars,
+        in_specs=[wide,
+                  pl.BlockSpec((1, per * chunk, N), rows(first[0])),
+                  pl.BlockSpec((1, per * chunk, N), rows(first[1])),
+                  scalars, scalars,
                   pl.BlockSpec((1, 1, per, N, W),
                                lambda b, g, i: (b, g, nb - 1 - i, 0, 0)),
                   wide],
         out_specs=[wide, narrow, narrow, scalars, scalars],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct(Bm.shape, Bm.dtype),
-                   jax.ShapeDtypeStruct(Cm.shape, Cm.dtype),
+                   jax.ShapeDtypeStruct((B, T, G * N), operands[1].dtype),
+                   jax.ShapeDtypeStruct((B, T, G * N), operands[2].dtype),
                    jax.ShapeDtypeStruct(a.shape, jnp.float32),
                    jax.ShapeDtypeStruct(dt.shape, jnp.float32)],
         scratch_shapes=[pltpu.VMEM((N, W), jnp.float32)],
@@ -2282,7 +2316,7 @@ def ssd_chunk_bwd_pallas(x, Bm, Cm, a, dt, states, dy, *, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=SSD_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(x, Bm, Cm, a, dt, states, dy))
+    )(*operands, a, dt, states, dy))
 
 
 # ---------------------------------------------------------------------------
@@ -2561,3 +2595,304 @@ def gdn_prep_bwd_pallas(x, w, dq, dk_, dv_, *, rows: int,
         interpret=interpret,
     )(x, x, x, w, dq, dq, dk_, dk_, dv_, dv_)
     return dx, dw
+
+
+# ---------------------------------------------------------------------------
+# From a Mamba-2 mixer's in-projection to the scan: convolution, bias, SiLU
+# (ops/ssd_scan.py ``conv_ssd_scan`` calls the pair)
+# ---------------------------------------------------------------------------
+# ``zxbc`` ``[B, T, ...]`` is the projection as the product wrote it; the
+# convolved columns ``[x | B | C]`` start at column ``offset`` and the index
+# maps read them THERE, a block of ``cols`` columns at a time (``cols``
+# divides the offset and the widths of x and of B, so a block lies in one of
+# the three).  Grid (block of columns, batch, block of rows).  As the delta
+# nets' pair above, whose ``_gdn_prep_conv`` and ``_gdn_prep_sub_blocks`` both
+# bodies call: a block brings the rows before it as a halo block of the same
+# array (one tile of the projection's dtype, of which the last
+# ``GDN_PREP_HALO`` are kept; zero before the row's start) and is copied
+# behind them into float32 VMEM scratch once, the taps are reads of that
+# scratch at a row offset, and the work goes a lane tile of columns at a time
+# in sub-blocks of ``GDN_PREP_SUB_ROWS`` rows, a loop over them.  The forward
+# writes x ``[B, T, H P]`` and ``[B | C]`` ``[B, T, 2 G N]`` in the compute
+# dtype, of which the scan's kernels read a group's blocks (B and C as column
+# blocks ``g`` and ``G + g`` of the one array: at the cell's 16 MB XLA keeps
+# it in VMEM between the two kernels, as it kept the chain's sliced B and
+# C), and x once more in the PROJECTION's dtype for the layer's skip ``D
+# x``: the chain rounds x to the compute dtype for the scan alone, so beside
+# a float32 projection the skip reads float32.  The grid walks x's blocks of
+# columns first; an output's index stands still while the other part's
+# blocks are worked, so every block is written back once.  The reverse
+# kernel takes ``dx``, ``dB``, ``dC`` as the scan's reverse
+# kernel writes them, three arrays, and the skip's part of ``dx`` in the
+# dtype x left in: a block of columns reads the ONE it lies in (the others'
+# index maps stand still meanwhile, so nothing of them is fetched), brings
+# the rows AFTER the block too, makes the pre-activation again, and
+# accumulates the convolution kernel's and the bias's gradients in its second
+# output's block across batch and rows.  The convolution's kernel and bias
+# travel as one ``[1, taps + 1, C]`` float32 array (the taps' rows, then the
+# bias), and so do their gradients.
+
+_MAMBA_PREP_LANES = 128
+
+
+def _tile_rows(dtype) -> int:
+    """Rows of a tile of ``dtype``: 8 of float32, 16 of bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _mamba_prep_fill(xs_ref, x_ref, h_ref, t_ref=None):
+    """The block behind the ``GDN_PREP_HALO`` rows before it (and, where
+    ``t_ref`` is given, before as many after it) in float32 scratch."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    R, halo = x_ref.shape[1], GDN_PREP_HALO
+    xs_ref[0:halo, :] = jnp.where(pl.program_id(2) > 0,
+                                  h_ref[0].astype(f32)[-halo:], 0.0)
+    xs_ref[halo:halo + R, :] = x_ref[0].astype(f32)
+    if t_ref is not None:
+        xs_ref[halo + R:, :] = t_ref[0].astype(f32)[:halo]
+
+
+def _mamba_prep_fwd_kernel(x_ref, h_ref, wb_ref, ox_ref, obc_ref, os_ref,
+                           xs_ref, *, taps, sub, nx):
+    from jax.experimental import pallas as pl
+
+    R, C = x_ref.shape[1:]
+    over_x = pl.program_id(0) < nx
+    _mamba_prep_fill(xs_ref, x_ref, h_ref)
+    for c0 in range(0, C, _MAMBA_PREP_LANES):
+        cols = slice(c0, c0 + _MAMBA_PREP_LANES)
+
+        def rows(r0, _, cols=cols):
+            _, a = _gdn_prep_conv(xs_ref, wb_ref, r0, sub, cols, taps)
+            a = a + wb_ref[0, taps:taps + 1, cols]
+            s = a * jax.nn.sigmoid(a)
+
+            @pl.when(over_x)
+            def _x():       # for the scan, and for the layer's skip
+                ox_ref[0, pl.ds(r0, sub), cols] = s.astype(ox_ref.dtype)
+                os_ref[0, pl.ds(r0, sub), cols] = s.astype(os_ref.dtype)
+
+            @pl.when(jnp.logical_not(over_x))
+            def _bc():
+                obc_ref[0, pl.ds(r0, sub), cols] = s.astype(obc_ref.dtype)
+
+        _gdn_prep_sub_blocks(R // sub, sub, rows)
+
+
+def _mamba_prep_specs(zxbc, T: int, C: int, offset: int, rows: int,
+                      cols: int, taps: int):
+    """Block specs over the projection's ``[x | B | C]`` columns (a block, the
+    tile of rows before it, the tile after it) and over the convolution's
+    ``[1, taps + 1, C]`` kernel and bias; the halos' indices are clamped at
+    the row's two ends, where the kernels put zeros in their place."""
+    from jax.experimental import pallas as pl
+
+    if (offset % cols or C % cols or cols % _MAMBA_PREP_LANES
+            or zxbc.shape[2] < offset + C):
+        raise ValueError(f"blocks of {cols} columns do not tile {C} columns "
+                         f"from column {offset} of {zxbc.shape[2]}")
+    tile = _tile_rows(zxbc.dtype)
+    if T % rows or rows % tile:
+        raise ValueError(f"a row of {T} is not whole blocks of {rows} rows")
+    first, per = offset // cols, rows // tile
+    x = pl.BlockSpec((1, rows, cols), lambda j, b, i: (b, i, first + j))
+    before = pl.BlockSpec(
+        (1, tile, cols),
+        lambda j, b, i: (b, jnp.maximum(i * per - 1, 0), first + j))
+    after = pl.BlockSpec(
+        (1, tile, cols),
+        lambda j, b, i: (b, jnp.minimum((i + 1) * per, T // tile - 1),
+                         first + j))
+    return x, before, after, pl.BlockSpec((1, taps + 1, cols),
+                                          lambda j, b, i: (0, 0, j))
+
+
+@_traced_once("offset", "width", "rows", "cols", "out_dtype")
+def mamba_prep_fwd_pallas(zxbc, wb, *, offset: int, width: int, rows: int,
+                          cols: int, out_dtype, interpret):
+    """``zxbc`` ``[B, T, >= offset + C]``, the in-projection; ``wb`` ``[1,
+    taps + 1, C]`` float32, the convolution's kernel and under it its bias
+    -> ``silu(conv(zxbc[..., offset:offset + C]) + bias)``, float32 inside,
+    as three arrays: its first ``width`` columns (x) ``[B, T, width]`` and
+    the others (``[B | C]``) ``[B, T, C - width]`` in ``out_dtype``, what
+    ``ssd_chunk_fwd_pallas(x, [B | C], None, ...)`` reads, and x once more in
+    ``zxbc``'s dtype, what the layer's skip reads."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = zxbc.shape
+    taps, C = wb.shape[1] - 1, wb.shape[2]
+    xs, before, _, ws = _mamba_prep_specs(zxbc, T, C, offset, rows, cols,
+                                          taps)
+    if not 0 < width < C or width % cols:
+        raise ValueError(f"blocks of {cols} columns do not tile x's {width} "
+                         f"of {C} columns")
+    nx, last = width // cols, (B - 1, T // rows - 1, width // cols - 1)
+
+    # the grid walks x's blocks of columns first: x's outputs stand where
+    # the last of them left them while [B | C]'s are worked, and [B | C]'s
+    # waits at its first block until then, so a block is written back once
+    def x_index(j, b, i):
+        return tuple(jnp.where(j < nx, v, e) for v, e in zip((b, i, j), last))
+
+    def bc_index(j, b, i):
+        return tuple(jnp.where(j < nx, 0, v) for v in (b, i, j - nx))
+
+    return tuple(pl.pallas_call(
+        functools.partial(_mamba_prep_fwd_kernel, taps=taps, nx=nx,
+                          sub=_gdn_prep_sub(rows, GDN_PREP_SUB_ROWS)),
+        name="mamba_prep_fwd",
+        grid=(C // cols, B, T // rows),
+        in_specs=[xs, before, ws],
+        out_specs=[pl.BlockSpec((1, rows, cols), x_index),
+                   pl.BlockSpec((1, rows, cols), bc_index),
+                   pl.BlockSpec((1, rows, cols), x_index)],
+        out_shape=[jax.ShapeDtypeStruct((B, T, width), out_dtype),
+                   jax.ShapeDtypeStruct((B, T, C - width), out_dtype),
+                   jax.ShapeDtypeStruct((B, T, width), zxbc.dtype)],
+        scratch_shapes=[pltpu.VMEM((GDN_PREP_HALO + rows, cols), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=GDN_PREP_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(zxbc, zxbc, wb))
+
+
+def _mamba_prep_bwd_kernel(x_ref, h_ref, t_ref, wb_ref, dx_ref, dxt_ref,
+                           sk_ref, skt_ref, db_ref, dbt_ref, dc_ref, dct_ref,
+                           o_ref, dwb_ref, xs_ref, da_ref, ds_ref,
+                           *, taps, sub, nx, nb):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    R, C = x_ref.shape[1:]
+    halo = GDN_PREP_HALO
+    j, i, last = pl.program_id(0), pl.program_id(2), pl.num_programs(2) - 1
+    _mamba_prep_fill(xs_ref, x_ref, h_ref, t_ref)
+
+    @pl.when(jnp.logical_and(pl.program_id(1) == 0, i == 0))
+    def _init():
+        dwb_ref[...] = jnp.zeros_like(dwb_ref)
+
+    # the gradient of the part this block of columns lies in, float32, the
+    # first rows after the block behind it
+    @pl.when(j < nx)
+    def _x():
+        ds_ref[0:R, :] = dx_ref[0].astype(f32) + sk_ref[0].astype(f32)
+        ds_ref[R:, :] = (dxt_ref[0].astype(f32)[:halo]
+                         + skt_ref[0].astype(f32)[:halo])
+
+    for on, own, after in (
+            (jnp.logical_and(j >= nx, j < nx + nb), db_ref, dbt_ref),
+            (j >= nx + nb, dc_ref, dct_ref)):
+        @pl.when(on)
+        def _narrow(own=own, after=after):
+            ds_ref[0:R, :] = own[0].astype(f32)
+            ds_ref[R:, :] = after[0].astype(f32)[:halo]
+
+    def grad_rows(r0, n, cols):
+        """``d loss / d (the convolution's output)`` at rows ``r0 .. r0 + n``
+        of the block (``r0 = R``: the first rows after it), and the taps'
+        rows of the projection."""
+        xt, a = _gdn_prep_conv(xs_ref, wb_ref, r0, n, cols, taps)
+        a = a + wb_ref[0, taps:taps + 1, cols]
+        sg = jax.nn.sigmoid(a)
+        return xt, ds_ref[pl.ds(r0, n), cols] * (sg * (1.0 + a * (1.0 - sg)))
+
+    for c0 in range(0, C, _MAMBA_PREP_LANES):
+        cols = slice(c0, c0 + _MAMBA_PREP_LANES)
+
+        def rows(r0, acc, cols=cols):
+            xt, da = grad_rows(r0, sub, cols)
+            da_ref[pl.ds(r0, sub), cols] = da
+            out = []
+            for prod, part in zip([da * x for x in xt] + [da], acc):
+                for t0 in range(0, sub, halo):   # one tile of partial sums
+                    part = part + prod[t0:t0 + halo]     # a row of ``dwb``
+                out.append(part)
+            return tuple(out)
+
+        acc = _gdn_prep_sub_blocks(
+            R // sub, sub, rows,
+            tuple(jnp.zeros((halo, _MAMBA_PREP_LANES), f32)
+                  for _ in range(taps + 1)))
+        _, da = grad_rows(R, halo, cols)
+        da_ref[R:R + halo, cols] = jnp.where(i < last, da, 0.0)
+        for k in range(taps + 1):
+            dwb_ref[0, k:k + 1, cols] += jnp.sum(acc[k], axis=0,
+                                                 keepdims=True)
+
+        def back(r0, _, cols=cols):   # the convolution's transpose
+            window = da_ref[pl.ds(r0, sub + halo), cols]
+            dx = wb_ref[0, taps - 1:taps, cols] * window[:sub]
+            for k in range(taps - 1):
+                dx = dx + wb_ref[0, k:k + 1, cols] * window[
+                    taps - 1 - k:taps - 1 - k + sub]
+            o_ref[0, pl.ds(r0, sub), cols] = dx.astype(o_ref.dtype)
+
+        _gdn_prep_sub_blocks(R // sub, sub, back)
+
+
+@_traced_once("offset", "rows", "cols", "out_dtype")
+def mamba_prep_bwd_pallas(zxbc, wb, dx, dskip, dB, dC, *, offset: int,
+                          rows: int, cols: int, out_dtype, interpret):
+    """The transpose of :func:`mamba_prep_fwd_pallas`: its ``zxbc`` and
+    ``wb``, the scan's ``dx`` ``[B, T, H P]``, ``dB``, ``dC`` ``[B, T, G N]``
+    and ``dskip`` ``[B, T, H P]`` in a dtype of its own (what else reaches
+    x: the layer's ``D x``, which read x in the projection's dtype) ->
+    (the gradient of ``zxbc[..., offset:offset + C]`` ``[B, T, C]`` in
+    ``out_dtype``, ``dwb`` like ``wb``: the kernel's and the bias's
+    gradients summed in float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, _ = zxbc.shape
+    taps, C = wb.shape[1] - 1, wb.shape[2]
+    HP, GN = dx.shape[2], dB.shape[2]
+    if HP + 2 * GN != C or HP % cols or GN % cols:
+        raise ValueError(f"blocks of {cols} columns do not tile [x | B | C] "
+                         f"of {HP} + 2 x {GN} columns")
+    xs, before, after, ws = _mamba_prep_specs(zxbc, T, C, offset, rows, cols,
+                                              taps)
+
+    def d_specs(part, lo, n):
+        """A part's own rows and the tile after them, where the block of
+        columns lies in the part (blocks ``lo .. lo + n``); elsewhere the
+        index stands at the part's first block, so that nothing moves."""
+        tile = _tile_rows(part.dtype)
+        per = rows // tile
+
+        def at(row):
+            def index(j, b, i):
+                on = jnp.logical_and(j >= lo, j < lo + n)
+                return tuple(jnp.where(on, v, 0)
+                             for v in (b, row(i), j - lo))
+            return index
+        return [pl.BlockSpec((1, rows, cols), at(lambda i: i)),
+                pl.BlockSpec((1, tile, cols), at(lambda i: jnp.minimum(
+                    (i + 1) * per, T // tile - 1)))]
+
+    nx, nb = HP // cols, GN // cols
+    out = pl.BlockSpec((1, rows, cols), lambda j, b, i: (b, i, j))
+    return tuple(pl.pallas_call(
+        functools.partial(_mamba_prep_bwd_kernel, taps=taps, nx=nx, nb=nb,
+                          sub=_gdn_prep_sub(rows, GDN_PREP_SUB_ROWS)),
+        name="mamba_prep_bwd",
+        grid=(C // cols, B, T // rows),
+        in_specs=[xs, before, after, ws, *d_specs(dx, 0, nx),
+                  *d_specs(dskip, 0, nx), *d_specs(dB, nx, nb),
+                  *d_specs(dC, nx + nb, nb)],
+        out_specs=[out, ws],
+        out_shape=[jax.ShapeDtypeStruct((B, T, C), out_dtype),
+                   jax.ShapeDtypeStruct(wb.shape, jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((2 * GDN_PREP_HALO + rows, cols), jnp.float32),
+            pltpu.VMEM((GDN_PREP_HALO + rows, cols), jnp.float32),
+            pltpu.VMEM((GDN_PREP_HALO + rows, cols), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=GDN_PREP_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(zxbc, zxbc, zxbc, wb, dx, dx, dskip, dskip, dB, dB, dC, dC))

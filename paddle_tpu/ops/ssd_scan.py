@@ -40,7 +40,8 @@ step takes a GROUP: its heads' channels are the lanes (``[Q, 8 x 64]``), the
 state is ``[N, 512]``, the two products with the state and the state's update
 are one product each for all eight heads, ``dB`` and ``dC`` come out summed
 over the group, and ``x``, ``B``, ``C`` and ``y`` are read and written in the
-layer's own layout (tokens by channels: no heads-major copy).  Only the
+layer's own layout (tokens by channels: no heads-major copy), as blocks of
+the prep kernel's two arrays, x and ``[B | C]``, or of three.  Only the
 in-chunk product is a head's own (its ``L``): it runs a lane tile at a time,
 each of a tile's heads against the whole tile with the other heads' lanes
 masked off the result.
@@ -58,9 +59,22 @@ Two paths behind one gate, :func:`ssd_kernel_chunk`: the Pallas kernels
 over chunks, differentiated by JAX.  A row the chunk does not divide is
 PADDED (a padded token has ``dt = 0``: it leaves the state as it is, and its
 output is cut off).
+
+Behind a second gate, :func:`prep_kernel_block`, :func:`conv_ssd_scan` takes
+a Mamba-2 mixer from its INPUT to the scan's output on kernels alone: the
+in-projection's ``[x | B | C]`` columns go through ``mamba_prep_fwd``
+(convolution, bias, SiLU, one cast) where the product wrote them, the scan
+reads x and B and C as blocks of that kernel's arrays (x's, and one of ``[B |
+C]``; the layer's skip reads x from a third, in the product's dtype as the
+chain's does), and in reverse ``mamba_prep_bwd`` takes the scan's three gradients and the skip's; the
+in-projection's transpose is then two products (the ``z`` columns' and the
+``[x | B | C]`` columns') joined at the weight's size, and nothing is sliced,
+joined, padded or cast by XLA at the activations' size in between.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -68,10 +82,12 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.delta_rule import _dot, _iotas, col_of_row, row_of_col
-from paddle_tpu.ops.numerics import acc_dtype, compute_dtype, dot_dtype
+from paddle_tpu.ops.numerics import (acc_dtype, compute_dtype, dot_dtype,
+                                     mxu_cast)
 
 __all__ = ["ssd_scan", "ssd_kernel_chunk", "CHUNK", "KERNEL_BLOCK_CHUNKS",
-           "chunk_forward", "chunk_backward"]
+           "chunk_forward", "chunk_backward", "prep_kernel_block",
+           "conv_ssd_scan"]
 
 #: tokens per chunk
 CHUNK = 128
@@ -303,6 +319,13 @@ def _chunked_scalars(z, G: int, n: int):
     return jnp.transpose(z.reshape(B, n, CHUNK, G, H // G), (0, 3, 1, 4, 2))
 
 
+def _chunk_sums(dt, A, G: int, n: int):
+    """``dt`` ``[B, n CHUNK, H]`` float32, ``A`` ``[H]`` -> the sums of ``dt
+    A`` from each chunk's start and ``dt``, ``[B, G, n, H / G, CHUNK]``."""
+    return (jnp.cumsum(_chunked_scalars(dt * A.astype(dt.dtype), G, n),
+                       axis=-1), _chunked_scalars(dt, G, n))
+
+
 def ssd_scan(x, Bm, Cm, dt, A):
     """The SSD recurrence over a row: ``x`` ``[B, T, H, P]``, ``Bm``, ``Cm``
     ``[B, T, G, N]``, ``dt`` ``[B, T, H]`` (a token's step, ``> 0``), ``A``
@@ -325,9 +348,7 @@ def ssd_scan(x, Bm, Cm, dt, A):
         z = z.reshape(B, T, -1)
         return jnp.pad(z, ((0, 0), (0, pad), (0, 0))) if pad else z
 
-    dt = rows(dt.astype(f32))
-    dtc = _chunked_scalars(dt, G, n)
-    a = jnp.cumsum(_chunked_scalars(dt * A.astype(f32), G, n), axis=-1)
+    a, dtc = _chunk_sums(rows(dt.astype(f32)), A, G, n)
     xf, Bf, Cf = rows(x), rows(Bm), rows(Cm)
     if kernels:
         y = _scan_kernels(xf, Bf, Cf, a, dtc)
@@ -341,3 +362,127 @@ def ssd_scan(x, Bm, Cm, dt, A):
         y = _scan_xla(grouped(xf), grouped(Bf), grouped(Cf), a, dtc, P)
         y = jnp.moveaxis(y, 1, 3).reshape(B, n * CHUNK, H * P)
     return y[:, :T].reshape(B, T, H, P).astype(dot_dtype())
+
+
+# -- from the mixer's input to the scan's output, on kernels alone -----------
+
+def prep_kernel_block(T: int, heads: int, head_dim: int, groups: int,
+                      state: int, taps: int, offset: int):
+    """The gate of ``mamba_prep_fwd`` / ``mamba_prep_bwd``: ``(rows, columns)``
+    of a block, or ``None`` for the layer's ``jax.numpy`` chain.  ``offset``
+    is the in-projection's column where ``[x | B | C]`` start.  Needs the
+    scan's gate open (the TPU backend, heads that tile the lanes, a
+    lane-aligned state, a row of whole chunks), ``H P``, ``G N`` and the
+    offset multiples of the 128 lanes, a convolution whose history fits the
+    halo, a row that the scan does not pad and that whole blocks divide, and
+    a block within the kernels' VMEM."""
+    from paddle_tpu.ops.pallas_kernels import (GDN_PREP_HALO,
+                                               GDN_PREP_SUB_ROWS,
+                                               GDN_PREP_VMEM_LIMIT_BYTES)
+
+    if heads % groups or ssd_kernel_chunk(
+            T, head_dim, heads // groups, state) is None:
+        return None
+    HP, GN, n = heads * head_dim, groups * state, T // CHUNK
+    if offset % _LANES or not 1 <= taps <= GDN_PREP_HALO + 1:
+        return None
+    if n > KERNEL_BLOCK_CHUNKS and n % KERNEL_BLOCK_CHUNKS:
+        return None
+    cols = next(c for c in (512, 256, _LANES)
+                if not (offset % c or HP % c or GN % c))
+    # a column of the reverse kernel's block, float32 at the widest: the
+    # projection, four gradients and the result, each in two buffers, and
+    # three scratch copies; as much again is left for the body's temporaries
+    row_bytes = 4 * (2 * 6 + 3) * cols
+    for rows in (512, 256, GDN_PREP_SUB_ROWS):
+        if T % rows == 0 and 2 * rows * row_bytes <= GDN_PREP_VMEM_LIMIT_BYTES:
+            return rows, cols
+    return None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _proj_conv_scan(u, w, wb, a, dt, inner, block):
+    return _proj_conv_scan_fwd(u, w, wb, a, dt, inner, block)[0]
+
+
+def _proj_conv_scan_fwd(u, w, wb, a, dt, inner, block):
+    from paddle_tpu.ops.matmul import linear
+    from paddle_tpu.ops.pallas_kernels import (mamba_prep_fwd_pallas,
+                                               ssd_chunk_fwd_pallas)
+
+    with jax.named_scope("mamba_proj"):
+        zxbc = linear(u, w)
+        xc, bc, x = mamba_prep_fwd_pallas(
+            zxbc, wb, offset=inner, width=inner, rows=block[0],
+            cols=block[1], out_dtype=compute_dtype())
+        z = zxbc[..., :inner]
+    with jax.named_scope("ssd_scan"):
+        y, states = ssd_chunk_fwd_pallas(xc, bc, None, a, dt)
+        # kept across a recomputation block, as in ``_scan_kernels_fwd``
+        y, states = (checkpoint_name(v, "remat_keep") for v in (y, states))
+    return (z, x, y), (u, w, wb, zxbc, xc, bc, a, dt, states)
+
+
+def _proj_conv_scan_bwd(inner, block, res, cts):
+    from paddle_tpu.ops.pallas_kernels import (mamba_prep_bwd_pallas,
+                                               ssd_chunk_bwd_pallas)
+
+    u, w, wb, zxbc, xc, bc, a, dt, states = res
+    dz, dskip, dy = cts
+    with jax.named_scope("ssd_scan"):
+        dx, dB, dC, da, ddt = ssd_chunk_bwd_pallas(
+            xc, bc, None, a, dt, states, dy.astype(xc.dtype))
+    with jax.named_scope("mamba_proj"):
+        dpre, dwb = mamba_prep_bwd_pallas(
+            zxbc, wb, dx, dskip, dB, dC, offset=inner, rows=block[0],
+            cols=block[1], out_dtype=xc.dtype)
+        # the in-projection's transpose as ``linear``'s own (operands in the
+        # compute dtype, gradients rounded to it), a product pair for z's
+        # columns and one for [x | B | C]'s: what is joined has the weight's
+        # size, and the halves of du are summed in float32 and rounded ONCE,
+        # as the chain's one product rounds it
+        uc, wc = mxu_cast(u, w)
+        f32, rows = acc_dtype(), tuple(range(u.ndim - 1))
+        du, dw = 0.0, []
+        for g, wh in ((dz, wc[:, :inner]), (dpre, wc[:, inner:])):
+            du = du + lax.dot_general(g, wh, (((u.ndim - 1,), (1,)), ((), ())),
+                                      preferred_element_type=f32)
+            dw.append(lax.dot_general(uc, g, ((rows, rows), ((), ())),
+                                      preferred_element_type=f32))
+        return (du.astype(uc.dtype).astype(u.dtype),
+                jnp.concatenate(dw, axis=1).astype(wc.dtype).astype(w.dtype),
+                dwb, da, ddt)
+
+
+_proj_conv_scan.defvjp(_proj_conv_scan_fwd, _proj_conv_scan_bwd)
+
+
+def conv_ssd_scan(u, w, kernel, bias, dt, A, *, groups: int, block):
+    """A Mamba-2 mixer from its input to the scan's output, where
+    :func:`prep_kernel_block` gave ``block``: ``u`` ``[B, T, D]``, ``w`` ``[D,
+    H P + (H P + 2 G N)]`` the in-projection's ``[z | x | B | C]`` columns,
+    ``kernel`` ``[L, H P + 2 G N]`` and ``bias`` the convolution's, ``dt``
+    ``[B, T, H]`` float32 (``> 0``), ``A`` ``[H]`` (``< 0``) -> ``(z [B, T, H
+    P]`` as the product leaves it, ``x`` and ``y`` ``[B, T, H, P]``: the
+    convolved x in the PRODUCT's dtype for the layer's skip, as the chain
+    hands it on (float32 unless ``--amp``), and the recurrence's output as
+    :func:`ssd_scan` returns it).  ``u w`` is ONE product; ``[x | B | C]``
+    are convolved, biased and SiLU'd in float32 and cast once to the compute
+    dtype by ``mamba_prep_fwd``, the scan's kernels read them as blocks of
+    its arrays (x's and ``[B | C]``'s), and the skip's x is that kernel's
+    third output, so its gradient comes back in x's own dtype too.  Scopes
+    ``mamba_proj`` (the product, its transpose and the kernels
+    ``mamba_prep_fwd`` / ``mamba_prep_bwd``) and ``ssd_scan``
+    (``ssd_chunk_fwd`` / ``ssd_chunk_bwd``, the sums of ``dt A`` and the
+    scalars' chunked layout)."""
+    B, T, H = dt.shape
+    f32 = acc_dtype()
+    with jax.named_scope("mamba_proj"):
+        wb = jnp.concatenate([kernel.astype(f32), bias.astype(f32)[None]])[None]
+    with jax.named_scope("ssd_scan"):
+        a, dtc = _chunk_sums(dt.astype(f32), A, groups, T // CHUNK)
+    z, x, y = _proj_conv_scan(u, w, wb, a, dtc, w.shape[1] - wb.shape[2],
+                              block)
+    with jax.named_scope("ssd_scan"):
+        y = y.reshape(B, T, H, -1).astype(dot_dtype())
+    return z, x.reshape(B, T, H, -1), y

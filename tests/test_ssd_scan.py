@@ -171,6 +171,40 @@ def test_forward_kernel_writes_every_chunks_starting_state():
     np.testing.assert_allclose(states[0, :, 1], want, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("heads,width,chunks", [(4, 64, 2), (2, 128, 5)])
+def test_B_and_C_as_blocks_of_one_array_equal_three_arrays(heads, width,
+                                                           chunks):
+    """``Cm`` ``None``: the kernels read B and C as column blocks of ONE ``[B,
+    T, 2 G N]`` array (what ``mamba_prep_fwd`` writes beside x) and give
+    what they give on three arrays: the forward's output and states to the
+    bit, the reverse walk's five gradients to the last digits (two programs
+    of the interpreter's, whose sums XLA:CPU may order differently), ``dx``,
+    ``dB``, ``dC`` as three arrays either way."""
+    x, Bm, Cm, dt, A = inputs(chunks, 2, chunks * Q, heads, width, 2, 128)
+    B, T = x.shape[:2]
+    G = Bm.shape[2]
+    n = -(-chunks // SS.KERNEL_BLOCK_CHUNKS) * SS.KERNEL_BLOCK_CHUNKS \
+        if chunks > SS.KERNEL_BLOCK_CHUNKS else chunks
+    pad = ((0, 0), (0, n * Q - T), (0, 0))
+    three = tuple(jnp.pad(a.reshape(B, T, -1), pad) for a in (x, Bm, Cm))
+    a, dtc = SS._chunk_sums(jnp.pad(dt, pad), A, G, n)
+    two = three[0], jnp.concatenate(three[1:], axis=-1), None
+    dy = jnp.asarray(np.random.RandomState(11).randn(
+        *three[0].shape).astype(np.float32))
+    want = PK.ssd_chunk_fwd_pallas(*three, a, dtc)
+    got = PK.ssd_chunk_fwd_pallas(*two, a, dtc)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    want = PK.ssd_chunk_bwd_pallas(*three, a, dtc, want[1], dy)
+    got = PK.ssd_chunk_bwd_pallas(*two, a, dtc, got[1], dy)
+    assert [g.shape for g in got[:3]] == [t.shape for t in three]
+    for g, w in zip(got, want):
+        assert np.asarray(w).any()
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="are not"):
+        PK.ssd_chunk_fwd_pallas(two[0], two[1][..., :-1], None, a, dtc)
+
+
 def test_heads_that_are_not_whole_groups_are_refused():
     x, Bm, Cm, dt, A = inputs(1, 1, Q, 3, 16, 2, 32)
     with pytest.raises(ValueError, match="whole groups"):

@@ -715,6 +715,77 @@ def test_ssd_scan_kernels(one_chip, on_tpu):
     assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
 
 
+@pytest.mark.parametrize("proj", ["float32", "bfloat16"])
+def test_mamba_prep_kernels(proj, one_chip, on_tpu):
+    """The gate of ``mamba_prep_fwd`` / ``mamba_prep_bwd`` opens at the
+    cell's shape (``[x | B | C]`` 6144 columns from column 4096 of the
+    in-projection, 4 taps, a row of 4096 in blocks of 512 rows by 512
+    columns), and a mixer from its input to the scan's output compiles with
+    forward and gradient: the pair beside the scan's kernels, which read x
+    and B and C as blocks of the pair's arrays.  The projection is float32 as
+    the cell's product leaves it (halo blocks of 8 rows; x for the skip and
+    the skip's gradient float32 beside bf16 arrays) or bfloat16 (a tile of
+    16: ``--amp``, which no cell runs), the taps read from VMEM scratch at
+    row offsets that are not multiples of a tile, which interpret mode
+    cannot refuse."""
+    from paddle_tpu.ops import pallas_kernels as PK
+    from paddle_tpu.ops import ssd_scan as SS
+
+    c = NEMOTRON
+    inner, gn, taps = c["H"] * c["P"], c["G"] * c["N"], 4
+    block = SS.prep_kernel_block(c["T"], c["H"], c["P"], c["G"], c["N"], taps,
+                                 inner)
+    assert block == (512, 512)
+    assert SS.prep_kernel_block(c["T"], c["H"], c["P"], c["G"], c["N"], taps,
+                                inner + 64) is None
+    assert SS.prep_kernel_block(c["T"] + 128, c["H"], c["P"], c["G"], c["N"],
+                                taps, inner) is None
+    conv = inner + 2 * gn
+    bf16 = jnp.bfloat16
+    if proj == "bfloat16":      # the pair alone on a bf16 projection
+        kw = dict(offset=inner, rows=block[0], cols=block[1], out_dtype=bf16)
+        wide = _struct(one_chip, (1, c["T"], inner), bf16)
+        narrow = _struct(one_chip, (1, c["T"], gn), bf16)
+
+        def pair(zxbc, wb, dx, dskip, dB, dC):
+            return (PK.mamba_prep_fwd_pallas(zxbc, wb, width=inner, **kw),
+                    PK.mamba_prep_bwd_pallas(zxbc, wb, dx, dskip, dB, dC,
+                                             **kw))
+
+        text = jax.jit(pair).lower(
+            _struct(one_chip, (1, c["T"], inner + conv), bf16),
+            _struct(one_chip, (1, taps + 1, conv)), wide, wide, narrow,
+            narrow).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        assert "mamba_prep_fwd" in text and "mamba_prep_bwd" in text
+        return
+
+    def loss(u, w, kernel, bias, dt, A):
+        z, x, y = SS.conv_ssd_scan(u, w, kernel, bias, dt, A, groups=c["G"],
+                                   block=block)
+        return sum(v.astype(jnp.float32).sum() for v in (z, x, y))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        _struct(one_chip, (1, c["T"], c["D"]), bf16),
+        _struct(one_chip, (c["D"], inner + conv)),
+        _struct(one_chip, (taps, conv)), _struct(one_chip, (conv,)),
+        _struct(one_chip, (1, c["T"], c["H"])),
+        _struct(one_chip, (c["H"],))).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    for name in ("mamba_prep_fwd", "mamba_prep_bwd", "ssd_chunk_fwd",
+                 "ssd_chunk_bwd"):
+        assert name in text, name
+    assert not _wide_joins(text, f"{c['T']},{conv}]")
+
+
+def _wide_joins(text: str, shape: str, scope: str = ""):
+    """The compiled module's ``concatenate`` and ``pad`` instructions (fused
+    ones too) that touch an array of ``shape`` under ``scope``."""
+    return [line.strip()[:200] for line in text.split("\n")
+            if re.search(r"[\]}] (concatenate|pad)\(", line)
+            and shape in line and scope in line]
+
+
 def test_grouped_expert_products_at_a_width_of_1856(one_chip, on_tpu):
     """8 experts of TWO matrices 2688 x 1856 held of 128, 6 a token: 1856 is
     29 x 64, no multiple of 128.  The gate opens, the width is tiled with a
@@ -765,13 +836,16 @@ def test_causal_attention_kernels_at_sixteen_query_heads_a_key_head(one_chip,
                     q, kv, kv) == 2
 
 
-def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
+_nemotron_step = {}
+
+
+def _nemotron_cell_step(one_chip):
     """The cell's whole step (the model's loss and gradient under its nine
     recomputation blocks, per-leaf Adam, state donated) compiled for the
-    described v5e from shapes alone: the compiler's own count of arguments,
-    results and temporaries stays under 13 GB of the chip's 16 (11.4 read,
-    PR 43), with the scan's, the attention's and the grouped products'
-    kernels in the program: no gate is closed."""
+    described v5e from shapes alone, once for the tests below (each asks for
+    ``on_tpu``, so whichever runs first compiles under the same gates)."""
+    if _nemotron_step:
+        return _nemotron_step["compiled"]
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -805,8 +879,17 @@ def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
         (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
         return (value, counts) + opt.update(params, grads, opt_state)
 
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+    _nemotron_step["compiled"] = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
+    return _nemotron_step["compiled"]
+
+
+def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
+    """The cell's whole step: the compiler's own count of arguments,
+    results and temporaries stays under 13 GB of the chip's 16 (11.4 read,
+    PR 43 and PR 44), with the scan's, the attention's and the grouped
+    products' kernels in the program: no gate is closed."""
+    compiled = _nemotron_cell_step(one_chip)
     text = compiled.as_text()
     for name in ("ssd_chunk_fwd", "ssd_chunk_bwd", "flash_attn_fwd",
                  "flash_attn_bwd", "moe_gmm", "moe_tgmm"):
@@ -816,3 +899,36 @@ def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 3 * 4 * 666_963_456 < m.argument_size_in_bytes     # p, m, v
     assert held < 13e9, held
+
+
+def test_nemotron_cell_step_keeps_the_mixers_way_to_the_scan_on_kernels(
+        one_chip, on_tpu):
+    """PR 44: the four mixers' way from ``W_in``'s product to the scan is
+    ``mamba_prep_fwd`` (8 calls: every block is recomputed) and
+    ``mamba_prep_bwd`` (4) beside the scan's ``ssd_chunk_fwd`` (4: its
+    outputs are kept across the block) and ``ssd_chunk_bwd`` (4), and in the
+    mixers' scopes nothing concatenates or pads an array of the convolved
+    columns' ``[1, 4096, 6144]`` or of the projection's ``[1, 4096, 10240]``
+    (the parent's step pads ``dz`` and the convolution's gradient to 10240
+    columns and the convolution's input by its history)."""
+    text = _nemotron_cell_step(one_chip).as_text()
+    calls = re.findall(r"%\S+ = [^\n]*? custom-call\([^\n]*?"
+                       r'custom_call_target="tpu_custom_call"[^\n]*?'
+                       r'op_name="([^"]*)"', text)
+    by_kernel = {}
+    for op_name in calls:
+        kernel = op_name.rsplit("/", 2)[-2]
+        by_kernel.setdefault(kernel, []).append(op_name)
+    assert {k: len(v) for k, v in by_kernel.items() if k.startswith(
+        ("mamba_prep", "ssd_chunk"))} == {
+            "mamba_prep_fwd": 8, "mamba_prep_bwd": 4, "ssd_chunk_fwd": 4,
+            "ssd_chunk_bwd": 4}
+    for kernel, scope in (("mamba_prep_fwd", "mamba_proj"),
+                          ("mamba_prep_bwd", "mamba_proj"),
+                          ("ssd_chunk_fwd", "ssd_scan"),
+                          ("ssd_chunk_bwd", "ssd_scan")):
+        assert all("mamba" in op.partition(f"/{scope}/")[0]
+                   and op.partition(f"/{scope}/")[2]
+                   for op in by_kernel[kernel]), (kernel, by_kernel[kernel])
+    for shape in ("4096,6144]", "4096,10240]"):
+        assert not _wide_joins(text, shape, "mamba"), shape

@@ -27,7 +27,7 @@ KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
            "ce_readout_bwd", "topk_lse_readout", "topk_lse_logits",
            "flash_attn_fwd", "flash_attn_bwd", "moe_gmm", "moe_tgmm",
            "gdn_chunk_fwd", "gdn_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd",
-           "gdn_prep_fwd", "gdn_prep_bwd"]
+           "gdn_prep_fwd", "gdn_prep_bwd", "mamba_prep_fwd", "mamba_prep_bwd"]
 
 
 # -- (a) kernels ----------------------------------------------------------

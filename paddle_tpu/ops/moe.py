@@ -82,9 +82,12 @@ def moe_kernel_row_tile(d_model: int, d_expert: int, assignments: int):
     """The grouped-product kernels' gate: their row tile, or ``None`` for
     the XLA path.  Needs the TPU backend, a lane-aligned model width and an
     expert width of whole half tiles (a multiple of 64: an expert of 1856 =
-    29 x 64 runs the kernels, its width tiled with a masked edge where it is
-    a product's result and taken whole where it is summed over; see
-    :func:`_largest_tile`)."""
+    29 x 64 runs the kernels).  The model width is always tiled by a divisor;
+    the expert width is tiled with a masked last block where it is a
+    product's RESULT (``x W_1``, ``dy W_2^T``: tiles of 512; ``d_W_1``,
+    ``d_W_2``: tiles of 1024) and taken whole where it is summed over
+    (``a W_2``, ``dh W_1^T``), whichever view a first matrix is read through
+    (:func:`grouped_expert_mlp`, :func:`_largest_tile`)."""
     from paddle_tpu.ops.pallas_kernels import compiled_kernels
 
     if not compiled_kernels():
@@ -156,7 +159,10 @@ def _largest_tile(n: int, cap: int) -> int:
     largest multiple of 128 under ``cap``, the kernels' grids then rounding
     up and the last block hanging over the edge (what is read there is
     never summed into a column that is kept, what is written there is
-    dropped)."""
+    dropped).  The rule holds under both views of a first matrix: the view
+    turns which OPERAND axis a result's axis is (``moe_gmm``'s ``tn`` runs
+    over the rhs block's last axis as stored and over its middle axis
+    through the view), not which axes are results."""
     if n <= cap:
         return n
     cap -= cap % 128
@@ -208,6 +214,76 @@ def _tgmm(lhs, rhs, g: Grouping, tm, kernels):
         for e in range(experts)])
 
 
+# -- a first matrix, read where the chip keeps it ---------------------------
+# The TPU keeps a float32 leaf ``[held, D, F]`` whose last axis is no multiple
+# of 128 lanes TRANSPOSED where the axis before it is one (1856 = 14.5 x 128
+# would be padded to 1920 lanes, 2688 = 21 x 128 is not): the step's entry
+# layout for the leaf, its Adam slots and the new values is ``{1,2,0}``,
+# physically ``[held, F, D]``.  A Mosaic call takes its operands and writes
+# its results in the default layout, so the kernels handed ``w`` cost copies
+# of the whole leaf, and ``d_w`` one more on its way to an update that reads
+# the slots where they are kept.  ``jnp.swapaxes(w, 1, 2)`` of such a leaf is
+# a bitcast into the default layout, so there the three products of a first
+# matrix take the VIEW: the same algorithm and the same pair of kernels, the
+# operand's orientation following the width (PERF.md section 6, PR 45, which
+# also says what the view cannot reach: a ``lax.cond`` around the update, the
+# trainer's guard, takes ITS operands in the default layout too).  The choice
+# reads two static shapes.
+
+#: the scope of the view's products inside ``moe_experts`` on a device trace
+VIEW_SCOPE = "w_view_t"
+
+
+def _kept_transposed(w) -> bool:
+    """Whether the chip keeps the first matrix ``w`` ``[held, D, F]`` with
+    ``D`` minor, so that its products read ``[held, F, D]``."""
+    return w.shape[2] % 128 != 0 and w.shape[1] % 128 == 0
+
+
+def _count_view(view: bool):
+    """``moe_weight_view_total{view=}``: one count a first matrix and trace
+    of the layer's forward (the choice is made when the step is traced)."""
+    from paddle_tpu.obs import get_registry
+
+    get_registry().counter(
+        "moe_weight_view_total",
+        "first matrices of an expert layer by the view the grouped products "
+        "read them through, counted when the layer's forward is traced",
+        labels=("view",), view="transposed" if view else "stored").inc()
+
+
+# the three products of a first matrix; their tiles under each view are
+# listed in grouped_expert_mlp's docstring
+
+def _rows_by_first(xs, w, g: Grouping, tm, kernels, out_dtype):
+    """``xs W_e`` ``[M, F]``."""
+    view = _kept_transposed(w)
+    _count_view(view)
+    if not view:
+        return _gmm(xs, w, g, tm, kernels, out_dtype=out_dtype)
+    with jax.named_scope(VIEW_SCOPE):
+        return _gmm(xs, jnp.swapaxes(w, 1, 2), g, tm, kernels,
+                    transpose_rhs=True, out_dtype=out_dtype)
+
+
+def _rows_by_first_t(dh, w, g: Grouping, tm, kernels):
+    """``dh W_e^T`` ``[M, D]`` float32."""
+    if not _kept_transposed(w):
+        return _gmm(dh, w, g, tm, kernels, transpose_rhs=True)
+    with jax.named_scope(VIEW_SCOPE):
+        return _gmm(dh, jnp.swapaxes(w, 1, 2), g, tm, kernels)
+
+
+def _first_grad(xs, dh, w, g: Grouping, tm, kernels):
+    """``d_W_e = xs_e^T dh_e`` in ``w``'s shape, float32.  Under the view
+    the kernel writes ``[held, F, D]`` and the swap back is a bitcast into
+    the layout the leaf, and an update that reads its slots, are kept in."""
+    if not _kept_transposed(w):
+        return _tgmm(xs, dh, g, tm, kernels)
+    with jax.named_scope(VIEW_SCOPE):
+        return jnp.swapaxes(_tgmm(dh, xs, g, tm, kernels), 1, 2)
+
+
 def _take_rows(a, idx):
     """``a[idx]`` with zeros where ``idx`` is out of range (the sentinels)."""
     return jnp.take(a, idx, axis=0, mode="fill", fill_value=0)
@@ -231,12 +307,12 @@ def _expert_mlp_fwd(x, weights, w1, w3, w2, g: Grouping, tm, kernels):
         row_token = g.row_assign // k                         # N where none
         xs = _take_rows(x.astype(cd), row_token)              # [M, D]
     with jax.named_scope("moe_experts"):
-        h1 = _gmm(xs, w1, g, tm, kernels, out_dtype=cd)
+        h1 = _rows_by_first(xs, w1, g, tm, kernels, cd)
         if w3 is None:          # two matrices: W_2 relu(W_1 x)^2
             h3 = None
             a = jnp.square(jax.nn.relu(h1.astype(f32))).astype(cd)
         else:
-            h3 = _gmm(xs, w3, g, tm, kernels, out_dtype=cd)
+            h3 = _rows_by_first(xs, w3, g, tm, kernels, cd)
             a = (jax.nn.silu(h1.astype(f32)) * h3.astype(f32)).astype(cd)
         ys = _gmm(a, w2, g, tm, kernels, out_dtype=cd)        # [M, D]
     with jax.named_scope("moe_combine"):
@@ -264,16 +340,16 @@ def _expert_mlp_bwd(tm, kernels, res, dy):
         if w3 is None:
             dh1 = (da * 2.0 * jax.nn.relu(h1f)).astype(cd)
             d_w3 = None
-            dxs = _gmm(dh1, w1, g, tm, kernels, transpose_rhs=True)
+            dxs = _rows_by_first_t(dh1, w1, g, tm, kernels)
         else:
             h3f = h3.astype(f32)
             sig = jax.nn.sigmoid(h1f)
             dh3 = (da * h1f * sig).astype(cd)
             dh1 = (da * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(cd)
-            d_w3 = _tgmm(xs, dh3, g, tm, kernels).astype(w3.dtype)
-            dxs = (_gmm(dh1, w1, g, tm, kernels, transpose_rhs=True)
-                   + _gmm(dh3, w3, g, tm, kernels, transpose_rhs=True))
-        d_w1 = _tgmm(xs, dh1, g, tm, kernels)
+            d_w3 = _first_grad(xs, dh3, w3, g, tm, kernels).astype(w3.dtype)
+            dxs = (_rows_by_first_t(dh1, w1, g, tm, kernels)
+                   + _rows_by_first_t(dh3, w3, g, tm, kernels))
+        d_w1 = _first_grad(xs, dh1, w1, g, tm, kernels)
     with jax.named_scope("moe_grouping"):
         dx = _add_rows(dxs, row_token, N)
     return (dx.astype(x_like.dtype), d_weights.astype(w_like.dtype),
@@ -292,7 +368,34 @@ def grouped_expert_mlp(x, weights, g: Grouping, w1, w3, w2, *, tm: int,
     ``weight * W_2e relu(W_1e x)^2``.  Only the buffer's rows move: tokens
     are gathered into rows and rows added back onto tokens, forward and
     backward, and the backward reads the rows the forward wrote (no second
-    sort)."""
+    sort).
+
+    The grouped products on the kernels, for a first matrix ``W`` (``w1``,
+    ``w3``) read as STORED or through the VIEW ``[held, F, D]`` that
+    :func:`_kept_transposed` chooses (``F % 128 != 0`` and ``D % 128 == 0``).
+    Rows go in tiles of ``tm``; ``t | D`` is the largest multiple of 128
+    under the cap that divides ``D``; "masked": the last block hangs over
+    the edge where the tile does not divide ``F``:
+
+    - ``h = xs W`` sums over ``D`` whole; ``F`` in tiles of 512, masked.
+      Stored: ``moe_gmm`` plain, rhs block ``(D, 512)``; view: transposed,
+      rhs block ``(512, D)``.
+    - ``dxs = dh W^T`` sums over ``F`` whole; ``D`` in tiles ``t | D`` of at
+      most 512.  Stored: transposed, rhs block ``(t, F)``; view: plain,
+      rhs block ``(F, t)``.
+    - ``d_W = xs^T dh`` sums over an expert's rows; ``D`` in tiles ``t | D``
+      of at most 1024, ``F`` in tiles of 1024, masked.  Stored: ``moe_tgmm``
+      writes ``[D, F]``; view: it writes ``[F, D]`` and the swap back to the
+      leaf's shape is a bitcast.
+    - ``ys = a W_2`` sums over ``F`` whole: plain, ``D`` in tiles ``t | D``.
+    - ``da = dy W_2^T`` sums over ``D`` whole: transposed, ``F`` in tiles of
+      512, masked.
+    - ``d_W_2 = a^T dy``: ``[F, D]``, ``F`` by 1024, masked, ``D`` by
+      ``t | D``.
+
+    Through the view a first matrix's three products take the shapes
+    ``W_2``'s three have, so no kernel configuration is the view's alone;
+    ``w2`` has ``D`` minor already and is read as stored."""
     return _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels)
 
 

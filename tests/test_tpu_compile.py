@@ -817,6 +817,99 @@ def test_grouped_expert_products_at_a_width_of_1856(one_chip, on_tpu):
                     *args) == 12
 
 
+def _w1_layout_copies(text: str):
+    """The compiled module's ``copy`` instructions that move the relu^2
+    experts' first matrix between layouts: any of its stored shape
+    ``[8, 2688, 1856]`` (kept ``{1,2,0}``, so one to ``{2,1,0}`` feeds a
+    Mosaic call and one to ``{1,2,0}`` comes from one), and any of its view
+    ``[8, 1856, 2688]`` into another layout than the default (``w2`` has the
+    view's shape: a copy of its new value into the donated buffer, default
+    to default, moves no layout and is the toy step's own)."""
+    return [line.strip()[:160] for line in text.split("\n") if re.search(
+        r"= f32\[8,(2688,1856\]\{|1856,2688\]\{(?!2,1,0))[^}]*\} copy\(",
+        line)]
+
+
+def _expert_layer_step(one_chip, D, F):
+    """One relu^2 expert layer as the Nemotron cell steps it, lowered for
+    the described chip: 4096 tokens, top 6 of 128 with 8 held, value and
+    gradient under ``jax.checkpoint``, per-leaf Adam, the leaves and their
+    slots donated."""
+    from paddle_tpu.ops import moe as M
+    from paddle_tpu.param.optimizers import Adam
+
+    c = NEMOTRON
+    N, k, held = c["T"], c["top_k"], c["held"]
+    tm = M.moe_kernel_row_tile(D, F, N * k)
+    assert tm == 256
+    opt = Adam(learning_rate=1e-3)
+
+    @jax.checkpoint
+    def layer(p, x, w, idx):
+        return M.expert_layer(x, idx, w, p["w1"], None, p["w2"],
+                              num_experts=128, first_expert=0, tm=tm,
+                              kernels=True)[0]
+
+    def step(p, state, x, w, idx):
+        value, grads = jax.value_and_grad(
+            lambda p: layer(p, x, w, idx).sum())(p)
+        return (value,) + opt.update(p, grads, state)
+
+    params = {"w1": _struct(one_chip, (held, D, F)),
+              "w2": _struct(one_chip, (held, F, D))}
+    state = jax.tree_util.tree_map(
+        lambda a: _struct(one_chip, a.shape, a.dtype),
+        jax.eval_shape(opt.init_state, params))
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, state, _struct(one_chip, (N, D)), _struct(one_chip, (N, k)),
+        _struct(one_chip, (N, k), jnp.int32))
+
+
+def test_expert_layer_reads_w1_where_the_chip_keeps_it(one_chip, on_tpu,
+                                                       monkeypatch):
+    """PR 45.  The chip keeps ``w1`` ``f32[8, 2688, 1856]``, its Adam slots
+    and the new values with the axis of 2688 minor (entry layout
+    ``{1,2,0}``: 1856 is 14.5 lane tiles), and a Mosaic call takes the
+    default layout: handed the leaf as stored, the step copies it before
+    every grouped product and ``d_w1`` on its way to the update.  Through the
+    transposed view it copies nothing of that shape; the same 12 kernels
+    either way."""
+    from paddle_tpu.ops import moe as M
+
+    c = NEMOTRON
+    text = _expert_layer_step(one_chip, c["D"], c["F"]).compile().as_text()
+    entry = re.search(r"entry_computation_layout=\{[^\n]*", text).group(0)
+    kept = set(re.findall(r"f32\[8,2688,1856\]\{(\d,\d,\d)", entry))
+    assert kept == {"1,2,0"}, kept
+    assert not _w1_layout_copies(text)
+    assert text.count("tpu_custom_call") == 12
+    monkeypatch.setattr(M, "_kept_transposed", lambda w: False)
+    stored = _expert_layer_step(one_chip, c["D"], c["F"]).compile().as_text()
+    assert len(_w1_layout_copies(stored)) >= 4
+    assert stored.count("tpu_custom_call") == 12
+
+
+def test_expert_layer_of_whole_lane_tiles_is_handed_its_leaves_as_stored(
+        one_chip, on_tpu, monkeypatch):
+    """An expert width that is whole lane tiles (LFM2's: D 2048, F 1536)
+    never takes the view: the layer lowers to the same StableHLO with the
+    choice switched off."""
+    from paddle_tpu.ops import moe as M
+
+    def lowered():
+        text = _expert_layer_step(one_chip, 2048, 1536).as_text()
+        assert text.count("tpu_custom_call") == 12
+        # a Mosaic call's serialized body carries its call's source lines
+        return re.sub(r'backend_config = "[^"]*"', "", text)
+
+    assert not M._kept_transposed(jnp.zeros((8, 2048, 1536)))
+    texts = []
+    for choose in (M._kept_transposed, lambda w: False):
+        monkeypatch.setattr(M, "_kept_transposed", choose)
+        texts.append(lowered())
+    assert texts[0] == texts[1]
+
+
 def test_causal_attention_kernels_at_sixteen_query_heads_a_key_head(one_chip,
                                                                     on_tpu):
     """Nemotron-H's attention layer: 32 query heads of 128 over 2 key-value
@@ -899,6 +992,23 @@ def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 3 * 4 * 666_963_456 < m.argument_size_in_bytes     # p, m, v
     assert held < 13e9, held
+
+
+def test_nemotron_cell_step_copies_no_expert_matrix(one_chip, on_tpu):
+    """PR 45: this step (value, gradient and per-leaf Adam, WITHOUT the
+    trainer's guard) held 24 ``copy`` instructions of ``w1``'s shape in the
+    parent, between the layout the leaf is kept in and the one a Mosaic call
+    takes; through the transposed view there is none, and ``moe_gmm`` /
+    ``moe_tgmm`` are called as often (8 expert-layer row buffers of 4 and 2:
+    both branches of a layer's ``lax.cond``).  The trainer's step keeps 7 a
+    layer that are not the kernels': its guard's ``lax.cond`` takes the
+    parameter, its gradient and the two slots in the default layout and
+    hands the three new values back in it (PERF.md section 6, PR 45)."""
+    text = _nemotron_cell_step(one_chip).as_text()
+    assert not _w1_layout_copies(text)
+    calls = re.findall(r"%(moe_gmm|moe_tgmm)[.\d]* = [^\n]*? custom-call\(",
+                       text)
+    assert (calls.count("moe_gmm"), calls.count("moe_tgmm")) == (48, 16)
 
 
 def test_nemotron_cell_step_keeps_the_mixers_way_to_the_scan_on_kernels(

@@ -51,7 +51,7 @@ FOUR_CHIP_RTOL = 2e-4
 @dataclasses.dataclass(frozen=True)
 class Sizes:
     """Everything the phases size themselves by.  The defaults are the
-    published widths (bench.py's seq2seq / seq2seq_decode / lstm rows);
+    published widths (the reference's seqToseq demo and LSTM benchmark);
     tests/test_chip_smoke.py passes a tiny instance."""
     vocab: int = 30000
     dim: int = 512            # embedding = encoder = decoder = attention
@@ -144,7 +144,7 @@ def _seq2seq(sizes: Sizes):
 
 
 def _train_batch(sizes: Sizes, seed: int) -> dict:
-    """bench.py's seq2seq batch: full-length random rows, <s> ... <e>."""
+    """A seq2seq batch: full-length random rows, <s> ... <e>."""
     rng = np.random.RandomState(seed)
     B, S, T, V = sizes.train_batch, sizes.seq_len, sizes.seq_len, sizes.vocab
     core = rng.randint(3, V, (B, T - 1)).astype(np.int32)

@@ -1,13 +1,12 @@
 """Analytic FLOPs walker + chip roofline tables — ONE source of truth.
 
-``bench.py`` computes MFU from analytic matmul+conv FLOPs (XLA's
+MFU is computed from analytic matmul+conv FLOPs (XLA's
 ``cost_analysis`` undercounts ``lax.scan`` bodies and lets
-rematerialization inflate an implementation's op count), and the live MFU
-gauge (``paddle_tpu.obs.timeline``) must report the SAME number for the
-same program — a bench row and a live dashboard that disagree about FLOPs
-turn every perf investigation into an argument about counters (the
-``mfu: null`` drift risk flagged in VERDICT r4 weak #4).  Both import
-from here; neither carries a private copy.
+rematerialization inflate an implementation's op count).  The walker has
+two users, ``SGDTrainer.step_flops`` and through it the live ``train_mfu``
+gauge (``paddle_tpu.obs.timeline``); neither carries a private copy.  The
+peak tables below are held equal to ``benchmark/peaks.json``, what the
+ledger's ``mfu_pct`` divides by (tests/test_repo_account.py).
 
 Counting convention: 2*M*N*K per ``dot_general`` and
 2*out_elems*(filter_spatial*Cin/groups) per ``conv_general_dilated``,

@@ -1,6 +1,6 @@
 """Shared jaxpr traversal — ONE definition of "recurse into sub-jaxprs".
 
-Grown out of ``bench.py``'s FLOPs walker, which recursed into *every*
+Grown out of a FLOPs walker that recursed into *every*
 jaxpr-valued param of every primitive: primitives carrying several
 sub-jaxprs (``custom_vjp_call`` holds the primal *and* fwd/bwd rules,
 ``linear_solve`` holds four) were double-counted.  Here recursion is

@@ -3,8 +3,8 @@
 One instrument panel for every tier (docs/observability.md): the
 process-wide metrics registry (counters/gauges/histograms, Prometheus +
 JSON exposition, ``--metrics_port`` HTTP endpoint), the trainer's
-step-time breakdown with a live MFU gauge (same analytic-FLOPs walker as
-``bench.py`` — ``analysis.flops``), the rank-tagged structured event
+step-time breakdown with a live MFU gauge (the analytic-FLOPs walker
+``analysis.flops`` on the trainer's step), the rank-tagged structured event
 journal (``--obs_journal`` + ``python -m paddle_tpu obs merge``),
 request-level distributed tracing (``obs/trace.py``: span-based
 tail-latency attribution across serving, the decode slot table, and the

@@ -2,7 +2,7 @@
 
 The MFU push (ROADMAP item 3) needs to know whether a model is
 input-bound, launch-bound, or compute-bound *live*, not from an offline
-``bench.py`` capture.  The trainer instruments its loop into phases:
+capture.  The trainer instruments its loop into phases:
 
 ================ ===========================================================
 phase            wall-clock covered
@@ -19,8 +19,8 @@ eval             test()/evaluator runs (mid-pass and end-of-pass)
 Per-phase durations aggregate into per-pass stats AND registry histograms
 (``train_phase_seconds{phase=...}``), so a scrape of ``--metrics_port``
 shows the live breakdown.  The ``step`` phase additionally drives the
-**live MFU gauge**: analytic FLOPs of the traced step (the SAME
-``analysis.flops`` walker ``bench.py`` uses — they cannot disagree)
+**live MFU gauge**: analytic FLOPs of the traced step (the
+``analysis.flops`` walker through ``SGDTrainer.step_flops``)
 divided by measured step seconds and chip peak FLOP/s
 (``train_mfu`` gauge; ``--obs_peak_flops`` overrides the chip table for
 virtual-device runs).
@@ -83,7 +83,7 @@ class StepTimeline:
             "train_step_seconds", "device-synced seconds of the last step")
         self._flops_gauge = reg.gauge(
             "train_step_flops", "analytic FLOPs of one train step "
-            "(analysis.flops walker — same counter as bench.py)")
+            "(analysis.flops walker, SGDTrainer.step_flops)")
         self._pass_stats: Dict[str, _PhaseStat] = {}
         self._pass_t0 = time.perf_counter()
         self.last: Dict[str, float] = {}      # most recent duration per phase

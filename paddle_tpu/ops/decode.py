@@ -7,7 +7,7 @@ generation path — ``models/seq2seq.py`` ``beam_search``/``greedy_decode``,
 the DSL ``SequenceGenerator`` behind ``nn/recurrent.py beam_search`` (and
 through it ``v2.infer`` over a beam_search layer) — drives this engine.
 
-What it replaces (the 13% MFU decode of BENCH_r05): a fixed-``max_len``
+What it replaces: a fixed-``max_len``
 ``lax.scan`` whose every step materialized the full [B*K, V] logits in HBM,
 log-softmaxed them into a second f32 [B*K, V] buffer, and ``top_k``'d over
 K*V.  Here:

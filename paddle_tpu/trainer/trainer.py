@@ -657,9 +657,9 @@ class SGDTrainer:
 
     def step_flops(self, feed: Dict[str, Any]) -> Optional[float]:
         """Analytic matmul+conv FLOPs of ONE train step (forward +
-        backward + optimizer), from the SAME ``analysis.flops`` walker
-        ``bench.py`` uses — the live MFU gauge and the bench rows cannot
-        disagree (pinned by tests/test_obs.py).  Traced through the
+        backward + optimizer), from the ``analysis.flops`` walker: what
+        the live ``train_mfu`` gauge divides (pinned to the walker by
+        tests/test_obs.py).  Traced through the
         function jit wraps (the step with the key's split before it, which
         adds no product), so after a first step this is a look-up."""
         from paddle_tpu.analysis.flops import jaxpr_flops

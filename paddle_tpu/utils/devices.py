@@ -39,7 +39,7 @@ def use_compilation_cache() -> None:
     """Switch JAX's persistent compilation cache on.  Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache stays there — JAX
     reads the variable itself and no code of this repo sets another —
     otherwise it goes to :data:`COMPILATION_CACHE_DIR`.  The one place the
-    repo's entry points (``init``: the CLI, bench.py, chip_smoke.py; the
+    repo's entry points (``init``: the CLI, chip_smoke.py; the
     tests' conftest) place that cache."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         import jax

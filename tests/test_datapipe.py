@@ -697,17 +697,6 @@ def test_trainer_gang_resize_reshards_bound_source(tmp_path, monkeypatch):
     assert cur["offset"] == 2 * 4 * 2  # committed under the OLD world
 
 
-def test_readme_bench_seq_packing_ab_unit():
-    """The new A/B row renders with its unit (no new BENCH capture, so
-    the README table itself stays drift-clean this round)."""
-    from paddle_tpu.utils.readme_bench import render_table
-
-    table = render_table({"seq_packing_ab": [348.2, None, 5.912]},
-                         "BENCH_r99.json")
-    assert ("| seq_packing_ab | 348.2 | samples/s (packed; vs = ×bucketed) "
-            "| — | 5.912× |" in table)
-
-
 # ---------------------------------------------------------------------------
 # gang acceptance: kill a 2-process gang mid-pass with a datapipe source
 # ---------------------------------------------------------------------------

@@ -25,7 +25,6 @@ pytest-timeout in the image) so a supervision bug can never hang tier-1.
 import json
 import os
 import signal
-import sys
 import threading
 import time
 import types
@@ -59,8 +58,6 @@ from paddle_tpu.utils.error import ConfigError
 from tests.conftest import on_accelerator
 from tests.test_gang import (ELASTIC_STUB, TRAIN_WORKER, _reference_run,
                              _supervisor)
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 HARD_TIMEOUT_S = 240
 
@@ -710,19 +707,3 @@ def test_pod_sigkill_midpass_two_pod_gang_recovers_to_oracle(
     for key, v in got3.items():
         np.testing.assert_allclose(v, ref_losses[key], rtol=1e-6,
                                    err_msg=f"joiner {key}")
-
-
-# ---------------------------------------------------------------------------
-# bench + readme registration (satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_row_and_readme_unit_registered():
-    if REPO_ROOT not in sys.path:            # bench.py is a repo-root module
-        sys.path.insert(0, REPO_ROOT)
-    import bench
-
-    assert bench.ROWS["dcn_hierarchy_ab"] is bench.bench_dcn_hierarchy_ab
-    from paddle_tpu.utils.readme_bench import _unit
-
-    assert "hierarchical" in _unit("dcn_hierarchy_ab")

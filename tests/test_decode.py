@@ -1,6 +1,6 @@
 """Fused decode engine (paddle_tpu/ops/decode.py; docs/decode.md).
 
-Four tiers:
+Three tiers:
 - kernel units: the vocab-tiled top-k+logsumexp kernels (both variants,
   interpret mode) must match ``lax.top_k`` + two-pass logsumexp BIT-EXACT
   on indices and within 1e-5 on values, at several (N, D, V, k, alignment)
@@ -10,13 +10,8 @@ Four tiers:
   finished-beam EOS-only masking, early-exit ≡ full-length decode,
   greedy ≡ beam_size=1, and the packed beam gather;
 - surface equivalence: ``SequenceGenerator``'s engine path vs its legacy
-  scan (callback) path; ``v2.infer(audit=True)`` preflight;
-- the README bench-table drift gate (``utils/readme_bench``).
+  scan (callback) path; ``v2.infer(audit=True)`` preflight.
 """
-
-import json
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -32,9 +27,6 @@ from paddle_tpu.ops.decode import (NEG, LinearReadout, LogitsReadout,
                                    greedy_decode)
 from paddle_tpu.ops.pallas_kernels import (topk_lse_logits_pallas,
                                            topk_lse_readout_pallas)
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
 
 
 # ---------------------------------------------------------------------------
@@ -430,34 +422,3 @@ def test_v2_infer_audit_preflight_on_generation_topology():
     ids = paddle.infer(output_layer=gen, parameters=params, input=rows,
                        field="id", audit=True)   # preflight must pass clean
     assert ids.shape == (2, 3, 5)
-
-
-# ---------------------------------------------------------------------------
-# README bench-table drift gate
-# ---------------------------------------------------------------------------
-
-
-def test_readme_bench_table_in_sync():
-    """The README performance table must be regenerated whenever a newer
-    BENCH_r*.json lands: `python -m paddle_tpu.utils.readme_bench`."""
-    from paddle_tpu.utils.readme_bench import update_readme
-
-    in_sync, _ = update_readme(os.path.join(ROOT, "README.md"), check=True)
-    assert in_sync, ("README bench table is stale — run "
-                     "`python -m paddle_tpu.utils.readme_bench`")
-
-
-def test_readme_bench_parses_truncated_driver_tail(tmp_path):
-    """Driver captures keep only the tail of the bench line; the parser
-    must still brace-match the trailing summary out of it."""
-    from paddle_tpu.utils.readme_bench import load_summary, render_table
-
-    tail = ('...TRUNCATED..., "summary": {"seq2seq": [1000.0, 0.41, 1.2], '
-            '"smallnet_b64": "ERROR"}}')
-    p = tmp_path / "BENCH_r99.json"
-    p.write_text(json.dumps({"n": 1, "tail": tail}))
-    summary = load_summary(str(p))
-    assert summary["seq2seq"] == [1000.0, 0.41, 1.2]
-    table = render_table(summary, "BENCH_r99.json")
-    assert "| seq2seq | 1,000 | words/s | 41.0% | 1.2× |" in table
-    assert "| smallnet_b64 | ERROR |" in table

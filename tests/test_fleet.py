@@ -603,13 +603,3 @@ def test_model_publish_dir_and_list_model_dirs(tmp_path):
     os.makedirs(os.path.join(root, "stray"))        # no version dirs
     os.makedirs(os.path.join(root, "_cache"))       # reserved
     assert list_model_dirs(root) == ["seq2seq"]
-
-
-def test_readme_bench_fleet_isolation_row():
-    from paddle_tpu.utils.readme_bench import render_table
-
-    table = render_table({"fleet_isolation_ab": [12.8, None, 1.26]},
-                         "BENCH_r99.json")
-    assert ("| fleet_isolation_ab | 12.8 | "
-            "ms (victim p99, fair share on; vs = ×off) | — | 1.26× |"
-            in table)

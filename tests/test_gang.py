@@ -523,6 +523,10 @@ TRAIN_WORKER = textwrap.dedent("""\
     # protocol latencies (supervisor poll, joiner warmup) land INSIDE
     # training instead of racing past its end
     pace = float(sys.argv[5]) if len(sys.argv) > 5 else 0.0
+    # or, in place of a pace, the survivor of an elastic kill WAITS for what
+    # the protocol does next (the victim's marker, then each world the
+    # supervisor publishes), so that no resize races the end of training
+    hold = len(sys.argv) > 6 and sys.argv[6] == "hold"
     rank = int(os.environ["PADDLE_TPU_PROCESS_ID"])
     FLAGS.save_dir = save_dir
     FLAGS.log_period = 0
@@ -545,6 +549,32 @@ TRAIN_WORKER = textwrap.dedent("""\
 
     handler = record
     marker = os.path.join(out_dir, "fault-fired")
+
+    def wait_for(what, happened):
+        deadline = time.monotonic() + 120.0
+        while not happened():
+            assert time.monotonic() < deadline, f"rank {rank}: no {what}"
+            tr._gang.heartbeat()     # waiting on the supervisor is no hang
+            time.sleep(0.01)
+
+    worlds_seen = 0
+    def record_and_hold(e):
+        # the victim dies as its batch 1:2 begins: this rank goes no
+        # further than 1:1 before that, and then no further than one batch
+        # beyond each world (the shrink's, the grow's) before it is published
+        global worlds_seen
+        record(e)
+        if not isinstance(e, ev.EndIteration):
+            return
+        if (e.pass_id, e.batch_id) == (1, 1):
+            wait_for("fault marker", lambda: os.path.exists(marker))
+        if os.path.exists(marker) and worlds_seen < 2:
+            worlds_seen += 1
+            wait_for(f"world of epoch {worlds_seen}", lambda:
+                     tr._gang.peek_world()["epoch"] >= worlds_seen)
+
+    if hold and mode == "kill" and rank != int(chaos_rank):
+        handler = record_and_hold
     if mode == "resize_die" and rank != int(chaos_rank):
         # the SURVIVOR dies the moment its elastic resize begins — the
         # mid-reshard fault that must fall back to whole-gang relaunch
@@ -592,7 +622,8 @@ def _reference_run(monkeypatch):
     return losses, {k: np.asarray(v) for k, v in tr.params.items()}
 
 
-def _train_gang(tmp_path, mode, chaos_rank, pace=0.0, save_dir=None, **kw):
+def _train_gang(tmp_path, mode, chaos_rank, pace=0.0, save_dir=None,
+                hold=False, **kw):
     script = tmp_path / "worker.py"
     script.write_text(TRAIN_WORKER)
     if save_dir is None:
@@ -601,7 +632,8 @@ def _train_gang(tmp_path, mode, chaos_rank, pace=0.0, save_dir=None, **kw):
     out_dir.mkdir()
     sup = _supervisor(
         2, script,
-        [save_dir, str(out_dir), mode, str(chaos_rank), str(pace)],
+        [save_dir, str(out_dir), mode, str(chaos_rank), str(pace),
+         "hold" if hold else "-"],
         gang_dir=str(tmp_path / "gang"), max_restarts=2, **kw)
     return sup, out_dir
 
@@ -778,8 +810,10 @@ def test_elastic_grow_back_without_save_dir_still_completes(tmp_path):
     is nothing durable to restore — the resize commit is a bare barrier
     and the grow decision broadcasts pass -1 — but the grow must still
     COMPLETE: the survivor shrinks, the replacement joins fresh, and no
-    resize ever times out into the whole-gang-relaunch fallback."""
-    sup, out_dir = _train_gang(tmp_path, "kill", 1, elastic=True, pace=0.1,
+    resize ever times out into the whole-gang-relaunch fallback.  Decided
+    on counts: the survivor waits for each world the supervisor publishes
+    (``hold``) where a pace per batch raced the end of training."""
+    sup, out_dir = _train_gang(tmp_path, "kill", 1, elastic=True, hold=True,
                                save_dir="")
     result = sup.run()
 

@@ -13,7 +13,6 @@ Three tiers:
 
 import json
 import os
-import sys
 import threading
 import time
 
@@ -25,13 +24,11 @@ import pytest
 from paddle_tpu.analysis import (audit_fn, eqn_subjaxprs, find_primitives,
                                  hlo_control_flow, lint_source,
                                  severity_at_least)
+from paddle_tpu.analysis.flops import jaxpr_flops
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 FIXTURE = os.path.join(HERE, "fixtures", "lint_bad_config.py")
-
-if ROOT not in sys.path:  # for `import bench` (repo-root module)
-    sys.path.insert(0, ROOT)
 
 
 def _checks(findings):
@@ -157,15 +154,13 @@ def test_unsharded_op_fires_without_constraints_and_not_with():
 
 
 # ---------------------------------------------------------------------------
-# shared jaxpr walker (the bench.py FLOPs-walker substrate)
+# shared jaxpr walker (what analysis.flops.jaxpr_flops walks with)
 # ---------------------------------------------------------------------------
 
 
 def test_flops_custom_vjp_counted_once():
-    """Satellite bench.py:155 — primitives carrying several sub-jaxprs
-    (custom_vjp holds primal + fwd/bwd rules) must count the primal ONCE."""
-    import bench
-
+    """Primitives carrying several sub-jaxprs (custom_vjp holds primal +
+    fwd/bwd rules) must count the primal ONCE."""
     @jax.custom_vjp
     def f(x, w):
         return x @ w
@@ -179,25 +174,21 @@ def test_flops_custom_vjp_counted_once():
 
     f.defvjp(fwd, bwd)
     x, w = jnp.ones((4, 8)), jnp.ones((8, 16))
-    flops = bench._jaxpr_flops(lambda c: f(*c), (x, w))
+    flops = jaxpr_flops(lambda c: f(*c), (x, w))
     assert flops == 2.0 * 4 * 16 * 8  # one M=4,N=16,K=8 matmul, exactly
 
 
 def test_flops_scan_body_multiplied_by_trip_count():
-    import bench
-
     w = jnp.ones((8, 8))
 
     def fn(c):
         out, _ = jax.lax.scan(lambda c, _: (c @ w, None), c, None, length=10)
         return out
 
-    assert bench._jaxpr_flops(fn, jnp.ones((4, 8))) == 10 * 2.0 * 4 * 8 * 8
+    assert jaxpr_flops(fn, jnp.ones((4, 8))) == 10 * 2.0 * 4 * 8 * 8
 
 
 def test_flops_grad_of_custom_vjp_uses_bwd_rule_once():
-    import bench
-
     @jax.custom_vjp
     def f(x, w):
         return x @ w
@@ -215,7 +206,7 @@ def test_flops_grad_of_custom_vjp_uses_bwd_rule_once():
     def loss(c):
         return f(*c).sum()
 
-    flops = bench._jaxpr_flops(lambda c: jax.grad(loss)(c), (x, w))
+    flops = jaxpr_flops(lambda c: jax.grad(loss)(c), (x, w))
     # fwd matmul + the two bwd matmuls: 2*(4*16*8) each
     assert flops == 3 * (2.0 * 4 * 16 * 8)
 

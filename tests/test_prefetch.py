@@ -1,10 +1,12 @@
 """Double-buffered async feeding (--prefetch_depth; data/feeder.py
 BatchPrefetcher + trainer wiring).
 
-The PR 9 step timeline is the measurement instrument: a paced reader
-(chaos.slow_client — the trickling-input pattern) must show its pacing in
-``data_wait`` WITHOUT prefetch and lose (>=3x share drop) WITH it, because
-prepare + h2d of batch N+1 overlap the device step of batch N.  Semantics
+Prepare + h2d of batch N+1 overlap the device step of batch N: batch N+1
+has been asked of the reader before iteration N ends (tier-1, on counts),
+and with the PR 9 step timeline as the instrument a paced reader
+(chaos.slow_client — the trickling-input pattern) shows its pacing in
+``data_wait`` WITHOUT prefetch and loses it (>=3x share drop) WITH it (the
+``slow`` twin ``_timed``).  Semantics
 are loop-equivalent: identical training trajectory, reader errors still
 attributed to the data tier, bounded read-ahead, and clean drains at
 preemption boundaries (resume stays batch-exact — the checkpoint records
@@ -472,6 +474,44 @@ def _heavy_trainer():
 
 
 def test_prefetch_collapses_data_wait_share(monkeypatch):
+    """What collapses the share, as counts: with --prefetch_depth=2 batch
+    k+1 has been asked of the reader before iteration k ends (the handler
+    WAITS for it: with no producer beside the loop it would never come),
+    without it the reader is asked only after; the timeline records the
+    same phases as often either way.  How much wall-clock that hides is the
+    ``_timed`` twin's to say."""
+    monkeypatch.setattr(FLAGS, "obs_timeline", True)
+    feeds = _feeds(6)
+    tr = _mse_trainer()
+    src, ahead = None, []
+
+    def reader():
+        nonlocal src
+        src = _Pulls(feeds)
+        return iter(src)
+
+    def handler(e):
+        if isinstance(e, ev.EndIteration):
+            if FLAGS.prefetch_depth:
+                src.wait(e.batch_id + 1)
+            ahead.append(len(src.pulled) - (e.batch_id + 1))
+
+    read_ahead, phases = {}, {}
+    for depth in (0, 2):
+        monkeypatch.setattr(FLAGS, "prefetch_depth", depth)
+        ahead.clear()
+        tr.train(reader, num_passes=1, event_handler=handler)
+        read_ahead[depth] = list(ahead)
+        phases[depth] = {k: v["count"] for k, v in
+                         tr.timeline.last_pass_summary["phases"].items()}
+    assert read_ahead[0] == [0] * len(feeds)
+    assert all(a >= 1 for a in read_ahead[2][:-1]), read_ahead
+    assert phases[0] == phases[2]
+    assert phases[2]["step"] == len(feeds)
+
+
+@pytest.mark.slow
+def test_prefetch_collapses_data_wait_share_timed(monkeypatch):
     """Acceptance: (data_wait + h2d) share of the pass drops >=3x on a
     paced reader with --prefetch_depth=2 — the pacing hides behind the
     step instead of serializing with it."""
@@ -499,8 +539,7 @@ def test_prefetch_collapses_data_wait_share(monkeypatch):
 
     # wall-clock shares on a ~30ms pass are load-marginal under the full
     # suite (a single descheduled prefetch thread inflates the depth-2
-    # share) — re-measure up to twice and judge the cleanest run, the
-    # same policy as bench.py's contended-window re-measure
+    # share) — re-measure up to twice and judge the cleanest run
     for attempt in range(3):
         shares, waits = measure()
         if shares[0] >= 3 * shares[2] and waits[2] <= waits[0] / 3:

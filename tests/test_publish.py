@@ -460,14 +460,3 @@ def test_table_reader_reload_stop_typed_journaled_and_counted(
     assert stopped[0]["table"] == "t_pub"
     assert stopped[0]["snap"] == 1
     assert stopped[0]["member"] == "shard-000.npz"
-
-
-def test_readme_bench_publish_reload_ab_unit():
-    """The A/B row renders with its unit (no new BENCH capture this
-    round, so the README table itself stays drift-clean)."""
-    from paddle_tpu.utils.readme_bench import render_table
-
-    table = render_table({"publish_reload_ab": [0.047, None, 0.988]},
-                         "BENCH_r99.json")
-    assert ("| publish_reload_ab | 0.047 | s (hot-swap to ready; "
-            "vs = ×restart) | — | 0.988× |" in table)

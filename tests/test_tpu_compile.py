@@ -38,7 +38,7 @@ def no_persistent_jax_cache():
 pytestmark = pytest.mark.usefixtures("no_persistent_jax_cache")
 
 T_RNN = 100
-#: benchmark/README's LSTM rows (bench.py) + the remat row's B512
+#: the reference's published LSTM rows (BASELINE.md) + a remat row's B512
 RNN_SHAPES = [(64, 256), (64, 512), (64, 1280), (128, 256), (256, 256),
               (512, 256)]
 B, S, T, D, V = 384, 32, 32, 512, 30000   # the flagship train step

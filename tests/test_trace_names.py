@@ -291,10 +291,10 @@ def _loop_spans(path):
     return lines[0]
 
 
-@pytest.mark.parametrize("timeline", [True, False],
-                         ids=["obs_timeline", "no_obs_timeline"])
-def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
-                                                   monkeypatch):
+def _holes_of_one_pass(timeline, tmp_path, monkeypatch):
+    """One traced pass: asserts which spans there are, their order and
+    their nesting, and returns what no span covers, iteration by
+    iteration (ns)."""
     from paddle_tpu.utils.flags import FLAGS
 
     monkeypatch.setattr(FLAGS, "obs_timeline", timeline)
@@ -330,14 +330,34 @@ def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
         edges = [it[0]] + [t for c in named for t in c[:2]] + [it[1]]
         holes.append([edges[i + 1] - edges[i]
                       for i in range(0, len(edges), 2)])
-    # the same holes every iteration; each one's smallest reading, so that
-    # a thread switch inside one of them does not decide (ns on the CPU)
-    assert len({len(h) for h in holes}) == 1
-    assert max(min(h) for h in zip(*holes)) < GLUE_NS, holes
     # what a pass does once (here the EndPass callback) is outside them
     outside = [s[2] for s in spans if s[2] != "iteration" and not any(
         it[0] <= s[0] and s[1] <= it[1] for it in iterations)]
     assert outside == ["callback"]
+    return holes
+
+
+@pytest.mark.parametrize("timeline", [True, False],
+                         ids=["obs_timeline", "no_obs_timeline"])
+def test_trainer_spans_nest_on_the_profilers_clock(timeline, tmp_path,
+                                                   monkeypatch):
+    holes = _holes_of_one_pass(timeline, tmp_path, monkeypatch)
+    # the same holes every iteration, and none negative: the named spans
+    # follow one another and none overlaps the next.  How long a hole is,
+    # is the ``_timed`` twin's to say: under load it is the scheduler's.
+    assert len({len(h) for h in holes}) == 1
+    assert min(min(h) for h in holes) >= 0, holes
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("timeline", [True, False],
+                         ids=["obs_timeline", "no_obs_timeline"])
+def test_trainer_spans_nest_on_the_profilers_clock_timed(timeline, tmp_path,
+                                                         monkeypatch):
+    holes = _holes_of_one_pass(timeline, tmp_path, monkeypatch)
+    # each hole's smallest reading, so that a thread switch inside one of
+    # them does not decide (ns on the CPU)
+    assert max(min(h) for h in zip(*holes)) < GLUE_NS, holes
 
 
 @pytest.mark.parametrize("mode, reason", [

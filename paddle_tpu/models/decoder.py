@@ -15,6 +15,10 @@ Layer names (and so the device trace's scopes and the parameters'
 prefixes): what the mixer's builder names its layer, ``mlp<i>`` / ``moe<i>``,
 ``emb``, ``norm_out``, ``cost``; the norms ``norm_op<i>`` and ``norm_ffn<i>``
 of a layer of two sub-blocks, ``norm<i>`` of a layer of one.
+
+A mixer may bring terms of its own to the loss (a learned indexer's): its
+builder then returns ``(layer, riders)``, and the stack's cost is ``(sum of
+the cross-entropies + sum of the riders marked as terms) / tokens``.
 """
 
 from __future__ import annotations
@@ -48,7 +52,12 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     are passed as ``extra_outputs``).
 
     ``mixers[kind](normed, i)`` builds layer ``i``'s sequence mixer over the
-    normed input, for every ``kind`` in ``layer_types``.  ``experts_held =
+    normed input, for every ``kind`` in ``layer_types``: the layer, or
+    ``(layer, riders)`` where the mixer has auxiliary outputs of its own:
+    a rider whose ``meta["loss_term"]`` is set is a term of the loss (its
+    value a SUM over the batch's tokens, added to the cross-entropies' sum
+    before the division by the tokens), one with ``meta["obs_counter"]``
+    joins the extras; a rider may be both.  ``experts_held =
     (first, count)``: the share of the experts this chip holds (all by
     default); ``vocab_size`` may likewise be a slice of the published
     vocabulary, ids, logits and loss then being over the slice.
@@ -72,7 +81,7 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     emb = nn.embedding(tokens, hidden_size, name="emb",
                        param_attr=nn.ParamAttr(initial_std=0.02,
                                                init="normal"))
-    x, extras = emb, []
+    x, extras, aux_costs = emb, [], []
     # passed only where asked for: the two older models' graphs (and their
     # captured configurations) stay what they were
     centred = {"zero_centered": True} if zero_centered_norm else {}
@@ -107,6 +116,14 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
             "labels": {"layer": f"moe{i}"}}
         return ffn, [load, dropped]
 
+    def mixer(kind, normed, i):
+        """Layer ``i``'s mixer and what rides with it."""
+        built = mixers[kind](normed, i)
+        layer, riders = built if isinstance(built, tuple) else (built, [])
+        aux_costs.extend(r for r in riders if r.meta.get("loss_term"))
+        extras.extend(r for r in riders if "obs_counter" in r.meta)
+        return layer, riders
+
     for i, kind in enumerate(layer_types):
         if kind not in mixers and kind != ffn_layer_type:
             raise ValueError(f"layer {i}: unknown layer type {kind!r}")
@@ -114,26 +131,27 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
             normed = nn.rms_norm(x, eps=norm_eps, name=f"norm{i}", **centred)
             if kind == ffn_layer_type:
                 sub, counters = feed_forward(normed, i)
+                extras += counters
             else:
-                sub, counters = mixers[kind](normed, i), []
-            extras += counters
+                sub, counters = mixer(kind, normed, i)
             x = nn.addto([x, sub], name=f"res{i}")
             block = [normed, *counters, sub, x]
         else:
             normed = nn.rms_norm(x, eps=norm_eps, name=f"norm_op{i}",
                                  **centred)
-            op = mixers[kind](normed, i)
+            op, riders = mixer(kind, normed, i)
             h = nn.addto([x, op], name=f"res_op{i}")
             normed2 = nn.rms_norm(h, eps=norm_eps, name=f"norm_ffn{i}",
                                   **centred)
             ffn, counters = feed_forward(normed2, i)
             extras += counters
             x = nn.addto([h, ffn], name=f"res_ffn{i}")
-            block = [normed, op, h, normed2, *counters, ffn, x]
+            block = [normed, op, *riders, h, normed2, *counters, ffn, x]
         if recompute_layers is True or (recompute_layers
                                         and i in recompute_layers):
             nn.remat_block(block, f"layer{i}")
     out = nn.rms_norm(x, eps=norm_eps, name="norm_out", **centred)
     cost = nn.lm_head_cost(out, targets, embedding=emb if tie_head else None,
-                           name="cost")
+                           name="cost", **({"aux_costs": aux_costs}
+                                           if aux_costs else {}))
     return cost, extras

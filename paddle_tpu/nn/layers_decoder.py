@@ -31,11 +31,13 @@ from paddle_tpu.nn.layers import AttrLike, _pa, _refuse_packed, _seq_like
 from paddle_tpu.ops import decoder_block as DB
 from paddle_tpu.ops import delta_rule as DR
 from paddle_tpu.ops import moe as M
+from paddle_tpu.ops import sparse_attention as SA
 from paddle_tpu.ops import ssd_scan as SS
 from paddle_tpu.ops.numerics import mxu_cast
 from paddle_tpu.utils.error import ConfigError
 
 __all__ = ["rms_norm", "gated_short_conv", "causal_self_attention",
+           "indexed_self_attention",
            "latent_attention", "gated_delta_net", "mamba2_mixer", "gated_mlp",
            "expert_mlp",
            "lm_head_cost",
@@ -100,6 +102,33 @@ def gated_short_conv(input: LayerOutput, *, kernel_size: int = 3,
     return LayerOutput(name, "gated_short_conv", D, [input], forward, specs)
 
 
+def _attention_specs(name, D, H, Hkv, dh, dq, qk_norm=True,
+                     zero_centered_norm=False):
+    """The leaves of grouped-query attention: four projections and, with
+    ``qk_norm``, one norm weight for the query heads and one for the key
+    heads."""
+    norm_w = lambda leaf: _pa(  # noqa: E731
+        None, f"_{name}.{leaf}", init="zeros" if zero_centered_norm
+        else "ones")
+    return [
+        ParamSpec(f"_{name}.wq", (D, H * dq), _fan_in(f"_{name}.wq", D)),
+        ParamSpec(f"_{name}.wk", (D, Hkv * dh), _fan_in(f"_{name}.wk", D)),
+        ParamSpec(f"_{name}.wv", (D, Hkv * dh), _fan_in(f"_{name}.wv", D)),
+        ParamSpec(f"_{name}.wo", (H * dh, D),
+                  _fan_in(f"_{name}.wo", H * dh)),
+    ] + ([ParamSpec(f"_{name}.q_norm", (dh,), norm_w("q_norm")),
+          ParamSpec(f"_{name}.k_norm", (dh,), norm_w("k_norm"))]
+         if qk_norm else [])
+
+
+def _project_heads(x, p, H, Hkv, dh, dq):
+    """``(q [B, T, H, dq], k [B, T, Hkv, dh], v [B, T, Hkv, dh])``."""
+    B, T = x.shape[:2]
+    return (O.linear(x, p["wq"]).reshape(B, T, H, dq),
+            O.linear(x, p["wk"]).reshape(B, T, Hkv, dh),
+            O.linear(x, p["wv"]).reshape(B, T, Hkv, dh))
+
+
 def causal_self_attention(input: LayerOutput, *, num_heads: int,
                           num_kv_heads: int, head_dim: int,
                           rope_theta: float = 10000.0, norm_eps: float = 1e-5,
@@ -127,18 +156,8 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
     if rd % 2 or not 0 < rd <= dh:
         raise ConfigError(f"{name!r}: rotary width {rd} of a head of {dh}")
     dq = 2 * dh if output_gate else dh
-    norm_init = "zeros" if zero_centered_norm else "ones"
-    norm_w = lambda leaf: _pa(  # noqa: E731
-        None, f"_{name}.{leaf}", init=norm_init)
-    specs = [
-        ParamSpec(f"_{name}.wq", (D, H * dq), _fan_in(f"_{name}.wq", D)),
-        ParamSpec(f"_{name}.wk", (D, Hkv * dh), _fan_in(f"_{name}.wk", D)),
-        ParamSpec(f"_{name}.wv", (D, Hkv * dh), _fan_in(f"_{name}.wv", D)),
-        ParamSpec(f"_{name}.wo", (H * dh, D),
-                  _fan_in(f"_{name}.wo", H * dh)),
-    ] + ([ParamSpec(f"_{name}.q_norm", (dh,), norm_w("q_norm")),
-          ParamSpec(f"_{name}.k_norm", (dh,), norm_w("k_norm"))]
-         if qk_norm else [])
+    specs = _attention_specs(name, D, H, Hkv, dh, dq, qk_norm,
+                             zero_centered_norm)
 
     def forward(ctx, params, a: Act) -> Act:
         if not a.is_seq:
@@ -148,11 +167,9 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
         p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
         x = a.value
         B, T = x.shape[:2]
-        q = O.linear(x, p["wq"]).reshape(B, T, H, dq)
+        q, k, v = _project_heads(x, p, H, Hkv, dh, dq)
         if output_gate:
             q, gate = q[..., :dh], q[..., dh:]
-        k = O.linear(x, p["wk"]).reshape(B, T, Hkv, dh)
-        v = O.linear(x, p["wv"]).reshape(B, T, Hkv, dh)
 
         def placed(h, norm):     # the head's norm, then its position
             if qk_norm:
@@ -168,6 +185,91 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
         return _seq_like(a, O.linear(o.reshape(B, T, H * dh), p["wo"]))
 
     return LayerOutput(name, "causal_self_attention", D, [input], forward,
+                       specs)
+
+
+def indexed_self_attention(input: LayerOutput, *, num_heads: int,
+                           num_kv_heads: int, head_dim: int,
+                           indexer_heads: int, indexer_head_dim: int,
+                           topk: int, rope_theta: float = 10000.0,
+                           norm_eps: float = 1e-6,
+                           name: Optional[str] = None) -> LayerOutput:
+    """Causal grouped-query self-attention over the positions a learned
+    indexer keeps (DeepSeek Sparse Attention's lightning indexer).  The main
+    heads are :func:`causal_self_attention`'s: RMSNorm over every query and
+    key head, rotary embedding over the whole head, scale ``head_dim **
+    -0.5``.  The indexer reads the layer's input as a CONSTANT (no gradient
+    leaves it towards the residual stream): ``qI = u W_Iq`` (``indexer_heads``
+    heads of ``indexer_head_dim``), ``kI = LayerNorm(u W_Ik)`` (ONE key
+    head; a weight and a bias), both turned by the rotary embedding, ``w = u
+    W_Iw (indexer_heads * indexer_head_dim) ** -0.5``; query ``t`` scores
+    position ``s <= t`` with ``sum_j w[t, j] relu(qI[t, j] . kI[s])``, keeps
+    its ``min(t + 1, topk)`` best, and every head attends over those alone
+    (``ops.sparse_attention``).  The cross-entropy moves every leaf but the
+    indexer's five (``wiq``, ``wik``, ``wiw``, ``ik_norm``, ``ik_bias``);
+    those learn from ``L_I`` alone, the sum over the queries of ``KL(mean of
+    the heads' probabilities || softmax of the kept scores)``.
+
+    ``Act.state`` carries ``indexer_kl`` (``L_I`` summed over the batch,
+    float32: a term of the loss, see ``lm_head_cost``'s ``aux_costs``) and
+    ``kept_pairs`` (the (query, position) pairs kept, int32).
+
+    Scopes inside the layer's own: ``indexer`` (the indexer's projections,
+    norm, rotary and scores), ``topk_select``, ``attn_core`` (the selected
+    attention) and ``indexer_loss`` (the target, the KL and its gradient
+    into the scores)."""
+    name = name or next_name("indexed_attention")
+    if num_heads % num_kv_heads:
+        raise ConfigError(f"{name!r}: {num_heads} query heads are not whole "
+                          f"groups over {num_kv_heads} key-value heads")
+    if head_dim % 2 or indexer_head_dim % 2 or topk < 1:
+        raise ConfigError(f"{name!r}: heads of {head_dim} and "
+                          f"{indexer_head_dim}, {topk} kept")
+    D, H, Hkv, dh = input.size, num_heads, num_kv_heads, head_dim
+    J, di = indexer_heads, indexer_head_dim
+    specs = _attention_specs(name, D, H, Hkv, dh, dh) + [
+        ParamSpec(f"_{name}.wiq", (D, J * di), _fan_in(f"_{name}.wiq", D)),
+        ParamSpec(f"_{name}.wik", (D, di), _fan_in(f"_{name}.wik", D)),
+        ParamSpec(f"_{name}.wiw", (D, J), _fan_in(f"_{name}.wiw", D)),
+        ParamSpec(f"_{name}.ik_norm", (di,),
+                  _pa(None, f"_{name}.ik_norm", init="ones")),
+        ParamSpec(f"_{name}.ik_bias", (di,),
+                  _pa(None, f"_{name}.ik_bias", init="zeros")),
+    ]
+
+    def forward(ctx, params, a: Act) -> Act:
+        if not a.is_seq:
+            raise ConfigError(f"indexed_self_attention {name!r} needs a "
+                              f"sequence")
+        _refuse_packed(a, name, "indexed_self_attention")
+        p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
+        x = a.value
+        B, T = x.shape[:2]
+        f32 = jnp.float32
+        q, k, v = _project_heads(x, p, H, Hkv, dh, dh)
+        q = DB.rotary_embedding(DB.rms_norm(q, p["q_norm"], norm_eps),
+                                rope_theta)
+        k = DB.rotary_embedding(DB.rms_norm(k, p["k_norm"], norm_eps),
+                                rope_theta)
+        with jax.named_scope("indexer"):
+            u = jax.lax.stop_gradient(x)
+            qI = DB.rotary_embedding(
+                O.linear(u, p["wiq"]).reshape(B, T, J, di), rope_theta)
+            kI = DB.layer_norm(O.linear(u, p["wik"]), p["ik_norm"],
+                               p["ik_bias"], norm_eps)
+            kI = DB.rotary_embedding(kI.reshape(B, T, 1, di),
+                                     rope_theta).reshape(B, T, di)
+            uc, wc = mxu_cast(u, p["wiw"])
+            w = jnp.matmul(uc, wc, preferred_element_type=f32) \
+                * (J * di) ** -0.5
+        o, kl, kept = SA.sparse_attention(q, k, v, qI, kI, w,
+                                          scale=dh ** -0.5, topk=topk,
+                                          real=a.mask)
+        out = _seq_like(a, O.linear(o.reshape(B, T, H * dh), p["wo"]))
+        out.state.update(indexer_kl=jnp.sum(kl), kept_pairs=jnp.sum(kept))
+        return out
+
+    return LayerOutput(name, "indexed_self_attention", D, [input], forward,
                        specs)
 
 
@@ -580,13 +682,17 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
 
 def lm_head_cost(input: LayerOutput, label: LayerOutput, *,
                  embedding: Optional[LayerOutput] = None,
+                 aux_costs: Sequence[LayerOutput] = (),
                  name: Optional[str] = None) -> LayerOutput:
     """Mean next-token cross-entropy over the real positions.  With
     ``embedding`` the head is that layer's matrix (tied): ``logits = h
     E^T``.  Without, the head is a parameter of this layer, ``_<name>.w``
     ``[hidden, label.size]`` (untied): ``logits = h W``.  Through
     ``ops.sequence_softmax_ce_readout``, so the logits are held once, in the
-    compute dtype."""
+    compute dtype.  ``aux_costs``: layers whose (scalar) value is a term of
+    the loss SUMMED over the batch's tokens (an indexer's ``L_I``); the
+    cost is then ``(sum of the cross-entropies + sum of the terms) /
+    tokens``."""
     name = name or next_name("lm_cost")
     if embedding is None:
         head = ParamSpec(f"_{name}.w", (input.size, label.size),
@@ -597,14 +703,20 @@ def lm_head_cost(input: LayerOutput, label: LayerOutput, *,
             raise ConfigError(f"{name!r}: the embedding is {head.shape[1]} "
                               f"wide, the hidden state {input.size}")
 
-    def forward(ctx, params, h: Act, lab: Act) -> Act:
+    def forward(ctx, params, h: Act, lab: Act, *aux: Act) -> Act:
         w = params[head.name]
         w = w if embedding is None else w.T
-        return Act(value=O.sequence_softmax_ce_readout(
-            h.value, w, jnp.zeros((w.shape[1],), w.dtype), lab.value, h.mask))
+        cost = O.sequence_softmax_ce_readout(
+            h.value, w, jnp.zeros((w.shape[1],), w.dtype), lab.value, h.mask)
+        if aux:
+            from paddle_tpu.ops.losses import token_count
 
-    return LayerOutput(name, "lm_head_cost", 1, [input, label], forward,
-                       [head])
+            cost = cost + sum(t.value.astype(cost.dtype) for t in aux) \
+                / token_count(h.mask.astype(cost.dtype))
+        return Act(value=cost)
+
+    return LayerOutput(name, "lm_head_cost", 1, [input, label, *aux_costs],
+                       forward, [head])
 
 
 from paddle_tpu.config.capture import wrap_module as _wrap_module  # noqa: E402

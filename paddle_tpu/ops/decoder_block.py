@@ -28,7 +28,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.numerics import acc_dtype, dot_dtype, mxu_cast
 
-__all__ = ["rms_norm", "unit_norm", "rotary_embedding", "causal_short_conv",
+__all__ = ["rms_norm", "layer_norm", "unit_norm", "rotary_embedding", "causal_short_conv",
            "causal_attention", "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
 
 #: queries per block of the XLA path
@@ -43,6 +43,16 @@ def rms_norm(x, w, eps: float, zero_centered: bool = False):
     inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
     w = w.astype(acc_dtype())
     return (xf * inv * (1.0 + w if zero_centered else w)).astype(x.dtype)
+
+
+def layer_norm(x, w, b, eps: float):
+    """``(x - mean) / sqrt(var + eps) * w + b`` over the last axis;
+    statistics in float32."""
+    xf = x.astype(acc_dtype())
+    xf = xf - jnp.mean(xf, -1, keepdims=True)
+    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (xf * inv * w.astype(acc_dtype())
+            + b.astype(acc_dtype())).astype(x.dtype)
 
 
 def unit_norm(x, eps: float = 1e-6):
@@ -117,19 +127,29 @@ def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None):
     return None
 
 
-def _xla_fwd(q, k, v, scale, block):
+def _block_mask(keep, i, lo, hi):
+    """What queries ``lo..hi-1`` see of positions ``0..hi-1``: the causal
+    mask, or block ``i`` of a selection (``keep``: one bool ``[B, hi - lo,
+    hi]`` a block of queries, the same for every head, all at or before the
+    query)."""
+    if keep is not None:
+        return keep[i][:, None, None]
+    return jnp.arange(hi)[None, :] <= lo + jnp.arange(hi - lo)[:, None]
+
+
+def _xla_fwd(q, k, v, scale, block, keep=None):
     """q ``[B, T, Hkv, G, dh]``, k ``[B, T, Hkv, dh]``, v ``[B, T, Hkv, dv]``
     in the compute dtype -> (out ``[B, T, Hkv, G, dv]`` in float32, lse
-    ``[B, Hkv, G, T]``)."""
+    ``[B, Hkv, G, T]``).  ``keep``: a selection in the causal mask's place
+    (:func:`_block_mask`; ops/sparse_attention.py)."""
     T = q.shape[1]
     f32 = acc_dtype()
     outs, lses = [], []
-    for lo in range(0, T, block):
+    for i, lo in enumerate(range(0, T, block)):
         hi = min(T, lo + block)
         s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, :hi],
                        preferred_element_type=f32) * scale
-        rows = lo + jnp.arange(hi - lo)[:, None]
-        s = jnp.where(jnp.arange(hi)[None, :] <= rows, s, -jnp.inf)
+        s = jnp.where(_block_mask(keep, i, lo, hi), s, -jnp.inf)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
@@ -140,7 +160,7 @@ def _xla_fwd(q, k, v, scale, block):
     return jnp.concatenate(outs, axis=1), jnp.concatenate(lses, axis=-1)
 
 
-def _xla_bwd(q, k, v, out, lse, d_out, scale, block):
+def _xla_bwd(q, k, v, out, lse, d_out, scale, block, keep=None):
     T = q.shape[1]
     f32 = acc_dtype()
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), -1)   # [b,q,h,g]
@@ -148,12 +168,11 @@ def _xla_bwd(q, k, v, out, lse, d_out, scale, block):
     dk = jnp.zeros(k.shape, f32)
     dv = jnp.zeros(v.shape, f32)
     do_c = d_out.astype(v.dtype)
-    for lo in range(0, T, block):
+    for i, lo in enumerate(range(0, T, block)):
         hi = min(T, lo + block)
         s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, :hi],
                        preferred_element_type=f32) * scale
-        rows = lo + jnp.arange(hi - lo)[:, None]
-        live = jnp.arange(hi)[None, :] <= rows
+        live = _block_mask(keep, i, lo, hi)
         p = jnp.where(live, jnp.exp(s - lse[..., lo:hi, None]), 0.0)
         dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_c[:, lo:hi], v[:, :hi],
                         preferred_element_type=f32)

@@ -58,6 +58,12 @@ sets takes part:
   (``mamba_prep_bwd``, which also adds the skip's part of ``dx`` and
   accumulates the convolution kernel's and the bias's gradients).  Gate:
   ``ops/ssd_scan.prep_kernel_block``.
+- Learned sparse attention: the indexer's scores (``indexer_scores``), the
+  selection of a row's ``topk`` best by a threshold found by counting
+  (``topk_select``), the flash kernels under that selection
+  (``flash_attn_sel_fwd`` / ``flash_attn_sel_bwd``: the two flash kernels
+  with ``keep=``) and the indexer's loss with its gradient
+  (``indexer_loss``).  Gate: ``ops/sparse_attention.sparse_kernel_blocks``.
 
 Where a gate is closed (the CPU, a shape past it, a step that jit partitions
 over a mesh: one with sharding rules, or ``SGDTrainer(mesh=...)``) the
@@ -86,7 +92,9 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas",
            "gdn_prep_fwd_pallas", "gdn_prep_bwd_pallas", "GDN_PREP_HALO",
            "ssd_chunk_fwd_pallas", "ssd_chunk_bwd_pallas",
-           "mamba_prep_fwd_pallas", "mamba_prep_bwd_pallas"]
+           "mamba_prep_fwd_pallas", "mamba_prep_bwd_pallas",
+           "indexer_scores_pallas", "topk_select_pallas",
+           "indexer_loss_pallas", "TOPK_SELECT_ROWS"]
 
 
 def _compiler_params(**kw):
@@ -1611,10 +1619,21 @@ def _causal_scores(q, k, qi, kj, *, scale, block_q, block_k):
     return jnp.where(cols <= rows, s, -jnp.inf)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      m_scr, l_scr, acc_scr, *, scale, block_q, block_k):
+def _selected_scores(q, k, keep, *, scale):
+    """The scores of one block pair under a selection: ``keep`` (int8, 1 at
+    the positions a query sees, all at or before it) takes the causal
+    mask's place."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    return jnp.where(keep.astype(jnp.int32) != 0, s, -jnp.inf)
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
+                      selected=False):
     from jax.experimental import pallas as pl
 
+    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi, kj = pl.program_id(2), pl.program_id(3)
 
     @pl.when(kj == 0)
@@ -1626,12 +1645,22 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(kj * block_k <= qi * block_q + block_q - 1)
     def _block():
         v = v_ref[0, 0]
-        s = _causal_scores(q_ref[0, 0], k_ref[0, 0], qi, kj, scale=scale,
-                           block_q=block_q, block_k=block_k)
         m_old = m_scr[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_old - m_new)
+        if selected:
+            # a query may keep nothing in its first blocks of keys: its
+            # running maximum is then still -inf, and the exponentials are
+            # taken against 0 (they are 0 either way)
+            s = _selected_scores(q_ref[0, 0], k_ref[0, 0], keep_ref[0],
+                                 scale=scale)
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+            m_ref = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        else:
+            s = _causal_scores(q_ref[0, 0], k_ref[0, 0], qi, kj, scale=scale,
+                               block_q=block_q, block_k=block_k)
+            m_ref = m_new = jnp.maximum(m_old,
+                                        jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_ref)
+        alpha = jnp.exp(m_old - m_ref)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
@@ -1644,8 +1673,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
-                          block_k: int):
-    """-> (out [B, H, T, dv] in q's dtype, lse [B, H, T, 1] float32)."""
+                          block_k: int, keep=None):
+    """-> (out [B, H, T, dv] in q's dtype, lse [B, H, T, 1] float32).
+    ``keep`` ``[B, T, T]`` int8: the selection (1 where query ``t`` sees
+    position ``s``, all with ``s <= t``, at least one a query, the same for
+    every head); the kernel is then ``flash_attn_sel_fwd`` and reads a tile
+    of it beside every block pair it visits."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1654,19 +1687,25 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
     G = H // k.shape[1]
     nq, nk = T // block_q, T // block_k
 
-    def kv_map(b, h, qi, kj):
-        last = (qi * block_q + block_q - 1) // block_k
-        return (b, h // G, jnp.minimum(kj, last), 0)
+    def last_block(qi):
+        return (qi * block_q + block_q - 1) // block_k
 
-    return pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k),
-        name="flash_attn_fwd",
+    def kv_map(b, h, qi, kj):
+        return (b, h // G, jnp.minimum(kj, last_block(qi)), 0)
+
+    selection = [] if keep is None else [pl.BlockSpec(
+        (1, block_q, block_k),
+        lambda b, h, qi, kj: (b, qi, jnp.minimum(kj, last_block(qi))))]
+    kernel = functools.partial(_flash_fwd_kernel, scale=scale,
+                               block_q=block_q, block_k=block_k,
+                               selected=keep is not None)
+    spec = dict(
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b, h, qi, kj: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, dh), kv_map),
             pl.BlockSpec((1, 1, block_k, dv), kv_map),
+            *selection,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, dv), lambda b, h, qi, kj: (b, h, qi, 0)),
@@ -1681,8 +1720,11 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
             vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
-        interpret=_interpret(),
-    )(q, k, v)
+        interpret=_interpret())
+    if keep is None:
+        return pl.pallas_call(kernel, name="flash_attn_fwd", **spec)(q, k, v)
+    return pl.pallas_call(kernel, name="flash_attn_sel_fwd", **spec)(
+        q, k, v, keep)
 
 
 def flash_bwd_key_rows(T: int, dh: int, dv: int, block_q: int,
@@ -1711,11 +1753,13 @@ def flash_bwd_key_rows(T: int, dh: int, dv: int, block_q: int,
     return -(-nk // n_super) * block_k
 
 
-def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k):
+def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k,
+                 keep=None):
     """(p, ds) of one block pair from the saved statistics, float32."""
     f32 = jnp.float32
-    s = _causal_scores(q, k, qi, kj, scale=scale, block_q=block_q,
-                       block_k=block_k)
+    s = (_causal_scores(q, k, qi, kj, scale=scale, block_q=block_q,
+                        block_k=block_k) if keep is None
+         else _selected_scores(q, k, keep, scale=scale))
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=f32)
@@ -1723,9 +1767,9 @@ def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k):
     return p, p * (dp - delta) * scale
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                      dq_ref, dk_ref, dv_ref, *, scale, block_q, block_k,
-                      n_q, q_first, k_first):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
+                      scale, block_q, block_k, n_q, q_first, k_first,
+                      selected=False):
     """One (block of queries, block of keys) of the backward: the pair's
     probabilities and ``ds`` are made once and feed all three gradients.
     ``dq`` of the block of queries accumulates in its output block over the
@@ -1735,6 +1779,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
     rows."""
     from jax.experimental import pallas as pl
 
+    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    dq_ref, dk_ref, dv_ref = rest
     t, kj = pl.program_id(2), pl.program_id(3)
     qi, kg = q_first + t % n_q, k_first + kj    # blocks of the whole row
 
@@ -1752,7 +1798,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         q, k, do = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
         p, ds = _flash_probs(q, k, v_ref[0, 0], o_ref[0, 0], do,
                              lse_ref[0, 0], qi, kg, scale=scale,
-                             block_q=block_q, block_k=block_k)
+                             block_q=block_q, block_k=block_k,
+                             keep=keep_ref[0] if selected else None)
         ds = ds.astype(q.dtype)
         contract_rows = (((0,), (0,)), ((), ()))
         keys = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
@@ -1765,7 +1812,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
 
 def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
-                          block_q: int, block_k: int):
+                          block_q: int, block_k: int, keep=None):
     """-> (dq [B, H, T, dh], dk [B, Hkv, T, dh], dv [B, Hkv, T, dv]),
     float32; ``o`` and ``do`` are [B, H, T, dv].  ONE kernel,
     ``flash_attn_bwd``, that visits each live block pair once, recomputes
@@ -1776,7 +1823,9 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
     partial sum crosses HBM.  Where a row is too long for that
     (:func:`flash_bwd_key_rows`), the keys are cut
     into super-blocks, one call each over the queries at or after its first
-    key, and the calls' ``dq`` are summed here."""
+    key, and the calls' ``dq`` are summed here.  ``keep``: the selection
+    the forward ran under (``flash_attn_fwd_pallas``); the kernel is then
+    ``flash_attn_sel_bwd``."""
     from jax.experimental import pallas as pl
 
     B, H, T, dh = q.shape
@@ -1794,9 +1843,12 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
         def q_map(b, hk, t, kj):
             return (b, hk * G + t // nq, q_first + t % nq, 0)
 
-        def kv_map(b, hk, t, kj):
+        def key_block(t, kj):
             last = ((q_first + t % nq) * block_q + block_q - 1) // block_k
-            return (b, hk, jnp.minimum(k_first + kj, last), 0)
+            return jnp.minimum(k_first + kj, last)
+
+        def kv_map(b, hk, t, kj):
+            return (b, hk, key_block(t, kj), 0)
 
         def resident(b, hk, t, kj):
             return (b, hk, 0, 0)
@@ -1806,15 +1858,19 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
 
         q_spec = pl.BlockSpec((1, 1, block_q, dh), q_map)
         o_spec = pl.BlockSpec((1, 1, block_q, dv), q_map)
-        return pl.pallas_call(
-            functools.partial(_flash_bwd_kernel, scale=scale,
-                              block_q=block_q, block_k=block_k, n_q=nq,
-                              q_first=q_first, k_first=k_first),
-            name="flash_attn_bwd",
+        selection = [] if keep is None else [pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, hk, t, kj: (b, q_first + t % nq, key_block(t, kj)))]
+        kernel = functools.partial(
+            _flash_bwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
+            n_q=nq, q_first=q_first, k_first=k_first,
+            selected=keep is not None)
+        spec = dict(
             grid=(B, Hkv, G * nq, nk),
             in_specs=[q_spec, pl.BlockSpec((1, 1, block_k, dh), kv_map),
                       pl.BlockSpec((1, 1, block_k, dv), kv_map), o_spec,
-                      o_spec, pl.BlockSpec((1, 1, block_q, 1), q_map)],
+                      o_spec, pl.BlockSpec((1, 1, block_q, 1), q_map),
+                      *selection],
             out_specs=[pl.BlockSpec((1, 1, block_q, dh), dq_map),
                        pl.BlockSpec((1, 1, k_hi - k_lo, dh), resident),
                        pl.BlockSpec((1, 1, k_hi - k_lo, dv), resident)],
@@ -1826,8 +1882,12 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
                 dimension_semantics=("parallel", "parallel", "arbitrary",
                                      "arbitrary"),
                 vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
-            interpret=_interpret(),
-        )(q, k, v, o, do, lse)
+            interpret=_interpret())
+        if keep is None:
+            return pl.pallas_call(kernel, name="flash_attn_bwd", **spec)(
+                q, k, v, o, do, lse)
+        return pl.pallas_call(kernel, name="flash_attn_sel_bwd", **spec)(
+            q, k, v, o, do, lse, keep)
 
     parts = [part(lo, min(lo + key_rows, T)) for lo in range(0, T, key_rows)]
     if len(parts) == 1:
@@ -2896,3 +2956,275 @@ def mamba_prep_bwd_pallas(zxbc, wb, dx, dskip, dB, dC, *, offset: int,
             vmem_limit_bytes=GDN_PREP_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(zxbc, zxbc, zxbc, wb, dx, dx, dskip, dskip, dB, dB, dC, dC))
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention: the indexer's scores, the selection, the
+# indexer's loss (ops/sparse_attention.py has the algebra and the XLA twin)
+# ---------------------------------------------------------------------------
+# Three kernels beside the two flash kernels' ``keep=`` form.  Square tiles
+# of ``block`` queries by ``block`` keys, tiles above the diagonal skipped
+# (their block indices clamped, so a skipped step moves nothing).
+# ``indexer_scores``: I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]), -inf at
+# s > t; the J products of a tile live in VMEM only, the result is ONE
+# [T, T] float32.  ``topk_select``: a block of whole rows of I in VMEM; the
+# k-th largest of a row by bisection on the scores' bits (32 counting
+# passes, exact), ties at the threshold by position (15 more), the row's
+# selection written as int8 with the log-sum-exp of the kept scores.
+# ``indexer_loss``: per tile the 32 heads' probabilities from the forward's
+# lse, summed in VMEM scratch (the grid's innermost axis is the head); on
+# the last head the indexer's scores again, the tile's part of KL(p || q)
+# and of the gradient into qI, kI (resident over a batch row, as the flash
+# backward's dk) and w.  No [T, T] array but I and the selection crosses HBM.
+
+SPARSE_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
+#: rows of scores one ``topk_select`` step holds
+TOPK_SELECT_ROWS = 128
+_INT_MIN = -2 ** 31
+
+
+def _indexer_tile(q_ref, k, w, heads):
+    """The indexer's scores of one tile, float32: ``sum_j w[:, j] relu(q_j
+    k^T)``; ``q_ref`` is the block ``[1, J, rows, d]``."""
+    acc = None
+    for j in range(heads):
+        pre = jax.lax.dot_general(q_ref[0, j], k, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        term = w[:, j:j + 1] * jnp.maximum(pre, 0.0)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _indexer_scores_kernel(q_ref, k_ref, w_ref, o_ref, *, heads, block):
+    from jax.experimental import pallas as pl
+
+    qi, kj = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(kj > qi)
+    def _future():
+        o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    @pl.when(kj <= qi)
+    def _live():
+        acc = _indexer_tile(q_ref, k_ref[0], w_ref[0], heads)
+        rows = qi * block + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        cols = kj * block + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+        o_ref[0] = jnp.where(cols <= rows, acc, -jnp.inf)
+
+
+@_traced_once("block")
+def indexer_scores_pallas(qI, kI, w, *, block: int, interpret: bool):
+    """qI ``[B, J, T, d]``, kI ``[B, T, d]`` (the compute dtype), w ``[B, T,
+    J]`` float32 -> I ``[B, T, T]`` float32, ``-inf`` above the diagonal."""
+    from jax.experimental import pallas as pl
+
+    B, J, T, d = qI.shape
+    n = T // block
+    return pl.pallas_call(
+        functools.partial(_indexer_scores_kernel, heads=J, block=block),
+        name="indexer_scores",
+        grid=(B, n, n),
+        in_specs=[
+            pl.BlockSpec((1, J, block, d), lambda b, qi, kj: (b, 0, qi, 0)),
+            pl.BlockSpec((1, block, d),
+                         lambda b, qi, kj: (b, jnp.minimum(kj, qi), 0)),
+            pl.BlockSpec((1, block, J), lambda b, qi, kj: (b, qi, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block, block),
+                               lambda b, qi, kj: (b, qi, kj)),
+        out_shape=jax.ShapeDtypeStruct((B, T, T), jnp.float32),
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=SPARSE_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(qI, kI, w)
+
+
+def _topk_select_kernel(s_ref, keep_ref, lse_ref, *, topk, rows, pos_bits):
+    from jax.experimental import pallas as pl
+
+    x = s_ref[0]                                       # [rows, T] float32
+    # the scores' bits in an order that is the scores' own (-0.0 is +0.0)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    key = jnp.where(bits == jnp.int32(_INT_MIN), 0, key)
+    first = pl.program_id(1) * rows
+    t = first + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    want = jnp.minimum(t + 1, topk).astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    int_min = jnp.int32(_INT_MIN)
+
+    def count(cond):
+        return jnp.sum(jnp.where(cond, 1.0, 0.0), axis=1, keepdims=True)
+
+    def value_bit(i, tau):      # the largest threshold >= ``want`` scores
+        cand = tau | (jnp.int32(1) << (31 - i))        # reach, bit by bit
+        return jnp.where(count(key >= (cand ^ int_min)) >= want, cand, tau)
+
+    tau = jax.lax.fori_loop(0, 32, value_bit,
+                            jnp.zeros((rows, 1), jnp.int32)) ^ int_min
+    above, equal = key > tau, key == tau
+    need = want - count(above)      # of the scores AT the threshold, the
+                                    # ``need`` lowest positions are kept
+
+    def position_bit(i, p):
+        cand = p | (jnp.int32(1) << (pos_bits - 1 - i))
+        return jnp.where(count(equal & (col < cand)) < need, cand, p)
+
+    last = jax.lax.fori_loop(0, pos_bits, position_bit,
+                             jnp.zeros((rows, 1), jnp.int32))
+    keep = above | (equal & (col <= last))
+    keep_ref[0] = keep.astype(jnp.int8)
+    kept = jnp.where(keep, x, -jnp.inf)
+    m = jnp.max(kept, axis=1, keepdims=True)
+    lse_ref[0] = m + jnp.log(jnp.sum(jnp.exp(kept - m), axis=1,
+                                     keepdims=True))
+
+
+@_traced_once("topk", "rows")
+def topk_select_pallas(scores, *, topk: int, rows: int, interpret: bool):
+    """scores ``[B, T, T]`` float32 with ``-inf`` above the diagonal ->
+    (keep ``[B, T, T]`` int8: 1 at the ``min(t + 1, topk)`` largest of row
+    ``t``, among equal scores the lower position first; lse ``[B, T, 1]``:
+    the log-sum-exp of a row's kept scores)."""
+    from jax.experimental import pallas as pl
+
+    B, T, _ = scores.shape
+    return pl.pallas_call(
+        functools.partial(_topk_select_kernel, topk=topk, rows=rows,
+                          pos_bits=max(1, (T - 1).bit_length())),
+        name="topk_select",
+        grid=(B, T // rows),
+        in_specs=[pl.BlockSpec((1, rows, T), lambda b, r: (b, r, 0))],
+        out_specs=[pl.BlockSpec((1, rows, T), lambda b, r: (b, r, 0)),
+                   pl.BlockSpec((1, rows, 1), lambda b, r: (b, r, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, T), jnp.int8),
+                   jax.ShapeDtypeStruct((B, T, 1), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=SPARSE_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(scores)
+
+
+def _indexer_loss_kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref,
+                         lsei_ref, rows_ref, keep_ref, kl_ref, dqi_ref,
+                         dki_ref, dw_ref, acc_scr, *, scale, heads, idx_heads,
+                         block):
+    from jax.experimental import pallas as pl
+
+    qi, kj, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    live = kj <= qi
+
+    @pl.when((qi == 0) & (kj == 0) & (h == 0))
+    def _zero_keys():
+        dki_ref[...] = jnp.zeros_like(dki_ref)
+
+    @pl.when((kj == 0) & (h == 0))
+    def _zero_queries():
+        kl_ref[...] = jnp.zeros_like(kl_ref)
+        dqi_ref[...] = jnp.zeros_like(dqi_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(live & (h == 0))
+    def _zero_tile():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(live)
+    def _head():        # this head's probabilities, by the forward's lse
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        acc_scr[...] += jnp.exp(s - lse_ref[0, 0])
+
+    @pl.when(live & (h == heads - 1))
+    def _indexer():
+        keep = keep_ref[0].astype(jnp.int32) != 0
+        p = jnp.where(keep, acc_scr[...] * (1.0 / heads), 0.0)
+        kI, w = ki_ref[0], w_ref[0]
+        logq = _indexer_tile(qi_ref, kI, w, idx_heads) - lsei_ref[0]
+        real = rows_ref[0]          # 1 at a real query, 0 at a padded one
+        kl_ref[0] += real * jnp.sum(
+            jnp.where(p > 0.0, p * (jnp.log(p) - logq), 0.0), axis=1,
+            keepdims=True)
+        d_scores = real * (jnp.where(keep, jnp.exp(logq), 0.0) - p)
+        keys = pl.ds(pl.multiple_of(kj * block, block), block)
+        for j in range(idx_heads):
+            qj = qi_ref[0, j]
+            pre = jax.lax.dot_general(qj, kI, (((1,), (1,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+            dw_ref[0, :, j:j + 1] += jnp.sum(
+                d_scores * jnp.maximum(pre, 0.0), axis=1, keepdims=True)
+            g = jnp.where(pre > 0.0, d_scores * w[:, j:j + 1],
+                          0.0).astype(qj.dtype)
+            dqi_ref[0, j] += jnp.dot(g, kI,
+                                     preferred_element_type=jnp.float32)
+            dki_ref[0, keys, :] += jax.lax.dot_general(
+                g, qj, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+
+@_traced_once("scale", "block")
+def indexer_loss_pallas(q, k, lse, qI, kI, w, lse_i, rows, keep, *,
+                        scale: float, block: int, interpret: bool):
+    """The indexer's loss and its gradient in one pass over the tiles.  q
+    ``[B, H, T, dh]``, k ``[B, Hkv, T, dh]``, lse ``[B, H, T, 1]`` (the
+    selected attention's), qI ``[B, J, T, d]``, kI ``[B, T, d]``, w ``[B, T,
+    J]``, lse_i ``[B, T, 1]`` and keep ``[B, T, T]`` (``topk_select``'s),
+    rows ``[B, T, 1]`` float32 (1 at a real query, 0 at a padded one, which
+    then adds nothing) ->
+    (kl ``[B, T, 1]``: a row's ``KL(p || softmax_kept(I))`` with ``p`` the
+    heads' mean probability; dqI ``[B, J, T, d]``, dkI ``[B, T, d]``, dw
+    ``[B, T, J]``: the gradient of the rows' sum, float32)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, T, dh = q.shape
+    G = H // k.shape[1]
+    J, d = qI.shape[1], qI.shape[3]
+    n = T // block
+
+    def key_block(qi, kj):
+        return jnp.minimum(kj, qi)
+
+    return pl.pallas_call(
+        functools.partial(_indexer_loss_kernel, scale=scale, heads=H,
+                          idx_heads=J, block=block),
+        name="indexer_loss",
+        grid=(B, n, n, H),
+        in_specs=[
+            pl.BlockSpec((1, 1, block, dh),
+                         lambda b, qi, kj, h: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block, dh),
+                         lambda b, qi, kj, h: (b, h // G, key_block(qi, kj),
+                                               0)),
+            pl.BlockSpec((1, 1, block, 1),
+                         lambda b, qi, kj, h: (b, h, qi, 0)),
+            pl.BlockSpec((1, J, block, d),
+                         lambda b, qi, kj, h: (b, 0, qi, 0)),
+            pl.BlockSpec((1, block, d),
+                         lambda b, qi, kj, h: (b, key_block(qi, kj), 0)),
+            pl.BlockSpec((1, block, J), lambda b, qi, kj, h: (b, qi, 0)),
+            pl.BlockSpec((1, block, 1), lambda b, qi, kj, h: (b, qi, 0)),
+            pl.BlockSpec((1, block, 1), lambda b, qi, kj, h: (b, qi, 0)),
+            pl.BlockSpec((1, block, block),
+                         lambda b, qi, kj, h: (b, qi, key_block(qi, kj))),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block, 1), lambda b, qi, kj, h: (b, qi, 0)),
+            pl.BlockSpec((1, J, block, d),
+                         lambda b, qi, kj, h: (b, 0, qi, 0)),
+            pl.BlockSpec((1, T, d), lambda b, qi, kj, h: (b, 0, 0)),
+            pl.BlockSpec((1, block, J), lambda b, qi, kj, h: (b, qi, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((B, T, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, J, T, d), jnp.float32),
+                   jax.ShapeDtypeStruct((B, T, d), jnp.float32),
+                   jax.ShapeDtypeStruct((B, T, J), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=SPARSE_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(q, k, lse, qI, kI, w, lse_i, rows, keep)

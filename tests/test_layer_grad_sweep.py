@@ -652,6 +652,18 @@ def case_causal_self_attention(rng):
                                     num_kv_heads=2, head_dim=2), feed
 
 
+def case_indexed_self_attention(rng):
+    # attention over the 3 positions an indexer of 2 heads of 4 keeps of a
+    # row of up to 5 (PR 47): the value's gradient reaches every leaf but the
+    # indexer's five, whose finite differences are 0 too while no selection
+    # flips
+    xs, feed = _seq(rng)
+    return nn.indexed_self_attention(_pre_fc(xs, size=8), num_heads=4,
+                                     num_kv_heads=2, head_dim=2,
+                                     indexer_heads=2, indexer_head_dim=4,
+                                     topk=3), feed
+
+
 def case_latent_attention(rng):
     # keys of 4 + 2 rotary channels beside values of 3, from a latent of 4
     xs, feed = _seq(rng)

@@ -929,16 +929,17 @@ def test_causal_attention_kernels_at_sixteen_query_heads_a_key_head(one_chip,
                     q, kv, kv) == 2
 
 
-_nemotron_step = {}
+_cell_steps = {}
 
 
-def _nemotron_cell_step(one_chip):
-    """The cell's whole step (the model's loss and gradient under its nine
+def _cell_step(one_chip, name):
+    """A cell's whole step (the model's loss and gradient under its
     recomputation blocks, per-leaf Adam, state donated) compiled for the
-    described v5e from shapes alone, once for the tests below (each asks for
-    ``on_tpu``, so whichever runs first compiles under the same gates)."""
-    if _nemotron_step:
-        return _nemotron_step["compiled"]
+    described v5e from shapes alone, once a cell for the tests below (each
+    asks for ``on_tpu``, so whichever runs first compiles under the same
+    gates)."""
+    if name in _cell_steps:
+        return _cell_steps[name]
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -949,7 +950,7 @@ def _nemotron_cell_step(one_chip):
     import paddle_tpu.nn as nn
     from paddle_tpu.param.optimizers import Adam
 
-    cell = manifest.cell("nemotron3nano-train-b1-t4096")
+    cell = manifest.cell(name)
     cfg, T = cell["config"], cell["traffic"]["seq_len"]
     cost, extras = manifest.program(cfg).net(cfg)
     topo = nn.Topology([cost] + extras)
@@ -972,9 +973,19 @@ def _nemotron_cell_step(one_chip):
         (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
         return (value, counts) + opt.update(params, grads, opt_state)
 
-    _nemotron_step["compiled"] = jax.jit(step, donate_argnums=(0, 1)).lower(
+    _cell_steps[name] = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
-    return _nemotron_step["compiled"]
+    return _cell_steps[name]
+
+
+def _nemotron_cell_step(one_chip):
+    return _cell_step(one_chip, "nemotron3nano-train-b1-t4096")
+
+
+def _held_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
 
 
 def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
@@ -988,10 +999,8 @@ def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
                  "flash_attn_bwd", "moe_gmm", "moe_tgmm"):
         assert name in text, name
     m = compiled.memory_analysis()
-    held = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 3 * 4 * 666_963_456 < m.argument_size_in_bytes     # p, m, v
-    assert held < 13e9, held
+    assert _held_bytes(compiled) < 13e9, _held_bytes(compiled)
 
 
 def test_nemotron_cell_step_copies_no_expert_matrix(one_chip, on_tpu):
@@ -1042,3 +1051,78 @@ def test_nemotron_cell_step_keeps_the_mixers_way_to_the_scan_on_kernels(
                    for op in by_kernel[kernel]), (kernel, by_kernel[kernel])
     for shape in ("4096,6144]", "4096,10240]"):
         assert not _wide_joins(text, shape, "mamba"), shape
+
+
+#: Keye-VL-2.0-30B-A3B's attention at the cell's row (benchmark/configs/
+#: keye-vl-2.0-30b-a3b-ep16.json): 32 query heads over 4 key-value heads of
+#: 128, an indexer of 16 heads of 64 over one key head, 2048 kept
+KEYE = dict(T=16384, D=2048, H=32, Hkv=4, dh=128, J=16, d=64, topk=2048)
+
+
+def test_sparse_attention_kernels(one_chip, on_tpu):
+    """The gate of learned sparse attention opens at the cell's shapes (tiles
+    of 1024), and forward and gradient compile as five Mosaic calls: the
+    indexer's scores, the selection (rows of 16384 scores, 128 rows a step,
+    47 counting passes), the two flash kernels under the selection (the
+    first row past 8192 through them: dk and dv of 16384 keys resident at
+    128/128) and the indexer's loss with its gradient.  A row the selection's
+    blocks do not divide, or an indexer head that is no multiple of 64,
+    keeps to the XLA path."""
+    from paddle_tpu.ops import sparse_attention as SA
+
+    c = KEYE
+    assert SA.sparse_kernel_blocks(c["T"], c["dh"], c["H"], c["Hkv"],
+                                   c["d"]) == 1024
+    assert SA.sparse_kernel_blocks(c["T"], c["dh"], c["H"], c["Hkv"],
+                                   c["d"] + 32) is None
+    assert SA.sparse_kernel_blocks(c["T"] + 64, c["dh"], c["H"], c["Hkv"],
+                                   c["d"]) is None
+
+    def loss(q, k, v, qI, kI, w):
+        out, kl, kept = SA.sparse_attention(q, k, v, qI, kI, w,
+                                            scale=c["dh"] ** -0.5,
+                                            topk=c["topk"])
+        return out.sum() + kl.sum(), kept
+
+    args = (_struct(one_chip, (1, c["T"], c["H"], c["dh"])),
+            _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"])),
+            _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"])),
+            _struct(one_chip, (1, c["T"], c["J"], c["d"])),
+            _struct(one_chip, (1, c["T"], c["d"])),
+            _struct(one_chip, (1, c["T"], c["J"])))
+    text = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(6)), has_aux=True)).lower(
+            *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 5
+    for name in ("indexer_scores", "topk_select", "flash_attn_sel_fwd",
+                 "flash_attn_sel_bwd", "indexer_loss"):
+        assert name in text, name
+
+
+def test_keye_cell_step_fits_the_chip(one_chip, on_tpu):
+    """The cell ``keyevl2-train-b1-t16384``'s whole step compiled for the
+    described v5e from shapes alone: the five kernels of learned sparse
+    attention ONCE a layer (their results are kept across the recomputation
+    block: the backward's second forward recomputes the projections, not the
+    indexer, the selection, the attention or the indexer's loss), the
+    grouped products beside them, and the compiler's own count of arguments,
+    results and temporaries under 15.5 GB of the chip's 16.9 (15.05 read, PR
+    47: of it 3-4 GB the expert layer's worst-routing branch, which is
+    reserved and not run; the chip's own peak read 13.64)."""
+    compiled = _cell_step(one_chip, "keyevl2-train-b1-t16384")
+    calls = re.findall(r"%\S+ = [^\n]*? custom-call\([^\n]*?"
+                       r'custom_call_target="tpu_custom_call"[^\n]*?'
+                       r'op_name="([^"]*)"', compiled.as_text())
+    by_kernel = {}
+    for op_name in calls:
+        kernel = op_name.rsplit("/", 2)[-2]
+        by_kernel[kernel] = by_kernel.get(kernel, 0) + 1
+    assert {k: by_kernel.get(k) for k in (
+        "indexer_scores", "topk_select", "flash_attn_sel_fwd",
+        "flash_attn_sel_bwd", "indexer_loss")} == {
+            "indexer_scores": 4, "topk_select": 4, "flash_attn_sel_fwd": 4,
+            "flash_attn_sel_bwd": 4, "indexer_loss": 4}
+    assert "moe_gmm" in by_kernel and "moe_tgmm" in by_kernel
+    m = compiled.memory_analysis()
+    assert 3 * 4 * 314_396_160 < m.argument_size_in_bytes     # p, m, v
+    assert _held_bytes(compiled) < 15.5e9, _held_bytes(compiled)

@@ -25,9 +25,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
            "lse_rows", "attn_dec_fwd", "attn_dec_bwd", "ce_readout_fwd",
            "ce_readout_bwd", "topk_lse_readout", "topk_lse_logits",
-           "flash_attn_fwd", "flash_attn_bwd", "moe_gmm", "moe_tgmm",
+           "flash_attn_fwd", "flash_attn_sel_fwd", "flash_attn_bwd",
+           "flash_attn_sel_bwd", "moe_gmm", "moe_tgmm",
            "gdn_chunk_fwd", "gdn_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd",
-           "gdn_prep_fwd", "gdn_prep_bwd", "mamba_prep_fwd", "mamba_prep_bwd"]
+           "gdn_prep_fwd", "gdn_prep_bwd", "mamba_prep_fwd", "mamba_prep_bwd",
+           "indexer_scores", "topk_select", "indexer_loss"]
 
 
 # -- (a) kernels ----------------------------------------------------------
@@ -176,6 +178,34 @@ def test_nemotron_h_step_holds_its_layers_scopes():
             "attn2", "attn_core", "norm0", "norm1", "norm2", "norm_out",
             "cost"} <= names
     assert not {"norm_op0", "norm_ffn0", "mlp0", "moe0", "mamba1"} & names
+
+
+def test_keye_vl2_step_holds_its_layers_scopes():
+    """The scopes the Keye-VL-2.0 cell's per-layer metrics read (PR 47): a
+    layer's own (``attn<i>``, ``moe<i>``) and, inside ``attn<i>``,
+    ``indexer``, ``topk_select``, ``attn_core`` and ``indexer_loss``, in the
+    forward and (``attn_core``, ``indexer_loss``) in the backward."""
+    from paddle_tpu.models import keye_vl2_net
+
+    nn.reset_naming()
+    cost, extras = keye_vl2_net(
+        50, hidden_size=16, num_hidden_layers=2, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=4, indexer_num_heads=2,
+        indexer_head_dim=4, topk=3, moe_intermediate_size=8, num_experts=4,
+        num_experts_per_tok=2)
+    assert [e.name for e in extras if "attn" in e.name] == [
+        "attn0_kept", "attn0_kl", "attn1_kept", "attn1_kl"]
+    topo = nn.Topology([cost] + extras)
+    params, _ = topo.init(jax.random.PRNGKey(0))
+    ids = (np.ones((2, 6), np.int32), np.full((2,), 6, np.int32))
+    feed = {"tokens": ids, "next_tokens": ids}
+    grad = jax.make_jaxpr(jax.grad(lambda p: topo.apply(
+        p, {}, feed, train=True)[0]["cost"].value))(params)
+    names = _scope_names(_name_stacks(grad.jaxpr))
+    assert {"attn0", "attn1", "indexer", "topk_select", "attn_core",
+            "indexer_loss", "moe0", "moe1", "moe_routing", "moe_experts",
+            "norm_op0", "norm_ffn1", "norm_out", "cost"} <= names
+    assert not {"mlp0", "moe_shared", "norm0"} & names
 
 
 def _primitives_under(jaxpr, scope, inside=False, out=None):

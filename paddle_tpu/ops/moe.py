@@ -225,10 +225,8 @@ def _tgmm(lhs, rhs, g: Grouping, tm, kernels):
 # the slots where they are kept.  ``jnp.swapaxes(w, 1, 2)`` of such a leaf is
 # a bitcast into the default layout, so there the three products of a first
 # matrix take the VIEW: the same algorithm and the same pair of kernels, the
-# operand's orientation following the width (PERF.md section 6, PR 45, which
-# also says what the view cannot reach: a ``lax.cond`` around the update, the
-# trainer's guard, takes ITS operands in the default layout too).  The choice
-# reads two static shapes.
+# operand's orientation following the width (PERF.md section 6, PR 45).  The
+# choice reads two static shapes.
 
 #: the scope of the view's products inside ``moe_experts`` on a device trace
 VIEW_SCOPE = "w_view_t"

@@ -215,8 +215,22 @@ class Optimizer:
         statics: Optional[Dict[str, bool]] = None,
         sparse_rows: Optional[Dict[str, Any]] = None,  # bool mask path or int K
         clip: bool = True,  # False: caller already applied global-norm clip
+        finite: Optional[Any] = None,  # device bool; False holds everything
     ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
-        """``sparse_rows`` marks row-sparse parameters (embedding tables with
+        """``finite`` is the bad-step guard's predicate (resilience/guard.py):
+        where it is False, every parameter, every slot and ``step`` come back
+        bit for bit as they went in.  The hold is a select in each leaf's own
+        update and takes the form that leaf's path already holds rows by: a
+        dense leaf ``where(finite, new, old)`` in the expression of
+        ``update_leaf`` (one fused pass, which reads the old values anyway),
+        the row-sparse paths ``finite`` joined to ``touched``, so that a
+        ``K``-row leaf is never selected over at table size.  No leaf crosses
+        a conditional for it: a conditional takes its operands in the default
+        layout, and the chip keeps some leaves otherwise (PERF.md, PR 48).  A
+        held step pays a whole update's time.  ``None`` (no guard) adds
+        nothing to the program.
+
+        ``sparse_rows`` marks row-sparse parameters (embedding tables with
         ParamAttr(sparse_grad=True)): rows a batch never touched keep their
         value AND optimizer slots unchanged — the reference's sparse-row
         update semantics (SparseRowCpuMatrix / SparseMomentum,
@@ -244,6 +258,18 @@ class Optimizer:
         if self.gradient_clipping_threshold > 0 and clip:
             grads, _ = clip_by_global_norm(grads, self.gradient_clipping_threshold)
 
+        def hold(new, old):
+            if finite is None:
+                return new
+            return jax.tree_util.tree_map(
+                lambda n, o: jnp.where(finite, n, o), new, old)
+
+        def touched_rows(g):
+            touched = jnp.any(g != 0, axis=tuple(range(1, g.ndim)))
+            # a held step touches no row: the K path then takes its fast
+            # branch with nothing live, not the full-table fallback
+            return touched if finite is None else touched & finite
+
         def _masked_update(p, g, old_slots, touched, lr_eff):
             """Full-tensor update with untouched rows held — the ONE masked
             path shared by sparse_rows=True and the K fast path's overflow
@@ -261,7 +287,7 @@ class Optimizer:
             p2 = sel(p2, p)
             s2 = jax.tree_util.tree_map(
                 lambda n, o: sel(n, o)
-                if getattr(n, "shape", None) == p.shape else n,
+                if getattr(n, "shape", None) == p.shape else hold(n, o),
                 s2, old_slots)
             return p2.astype(p.dtype), s2
 
@@ -280,7 +306,7 @@ class Optimizer:
                     and 0 < kind < p.shape[0]):
                 # ---- row fast path: touch only K candidate rows ----
                 K = int(kind)
-                touched = jnp.any(g != 0, axis=tuple(range(1, p.ndim)))
+                touched = touched_rows(g)
 
                 def _fast(_, p=p, g=g, touched=touched, K=K,
                           old_slots=old_slots, scale=scale, decay=decay):
@@ -303,16 +329,16 @@ class Optimizer:
                     n_touched <= K, _fast, _overflow, None)
                 continue
             if kind and p.ndim >= 2:  # sparse_rows=True: masked path
-                touched = jnp.any(g != 0, axis=tuple(range(1, p.ndim)))
                 new_params[k], new_slots[k] = _masked_update(
-                    p, g, old_slots, touched, lr * scale)
+                    p, g, old_slots, touched_rows(g), lr * scale)
                 continue
             p2, s2 = self.update_leaf(
                 p, _regularize(p, g, decay, self.l1_rate), old_slots,
                 lr * scale, step)
-            new_params[k] = p2.astype(p.dtype)
-            new_slots[k] = s2
-        return new_params, {"step": step, "slots": new_slots}
+            new_params[k] = hold(p2.astype(p.dtype), p)
+            new_slots[k] = hold(s2, old_slots)
+        return new_params, {"step": hold(step, opt_state["step"]),
+                            "slots": new_slots}
 
     def row_apply(self, p, rows, g_rows, old_slots, live, lr_eff, step, *,
                   decay: float = 0.0, oob_drop: bool = False):

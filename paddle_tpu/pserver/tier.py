@@ -312,11 +312,14 @@ class PServerTier:
         return total
 
     def apply_grads(self, state: Dict[str, Any], feed,
-                    proxy_grads: Dict[Tuple[str, str], Any]):
+                    proxy_grads: Dict[Tuple[str, str], Any], *,
+                    finite=None):
         """Push the proxy cotangents into the sharded tables; returns the
         next pserver state pytree.  Pure/traced — called inside the jitted
-        step (and inside the bad-step guard's cond, so a non-finite step
-        holds tables, slots, and dirty masks unchanged)."""
+        step.  ``finite`` is the bad-step guard's predicate: where it is
+        False every id of the step becomes the sentinel the row scatter
+        already drops, so tables, slots and dirty masks come back unchanged
+        at the cost of the step's own N rows, never a pass over a table."""
         step = state["step"] + 1
         lr = self.optimizer.lr_at(step)
         new_tables, new_slots, new_dirty = {}, {}, {}
@@ -329,6 +332,8 @@ class PServerTier:
                 segs_g.append(g)
             ids = jnp.concatenate(segs_ids)
             g = jnp.concatenate(segs_g)
+            if finite is not None:
+                ids = jnp.where(finite, ids, state["tables"][pname].shape[0])
             scale = self.lr_scales.get(pname, 1.0)
             decay = self.decays.get(pname, 0.0) + self.optimizer.l2_rate
             new_tables[pname], new_slots[pname], new_dirty[pname] = (
@@ -337,6 +342,8 @@ class PServerTier:
                     state["slots"][pname], state["dirty"][pname], ids, g,
                     axis=self.axis, lr_eff=lr * scale, step=step,
                     decay=decay, dcn_axis=self.dcn_axis))
+        if finite is not None:
+            step = jnp.where(finite, step, state["step"])
         return {"step": step, "tables": new_tables, "slots": new_slots,
                 "dirty": new_dirty}
 

@@ -7,8 +7,9 @@ tier of the trainer survivable (docs/resilience.md):
   a manifest (per-array CRC32 + original dtypes + wall-clock + meta),
   ``keep_last_n`` retention, and a validating ``latest_pass`` that skips
   corrupt directories;
-- **guard** — in-jit finite checks on loss and gradient global-norm with a
-  ``lax.cond`` skip of the optimizer update (no host syncs; audited by
+- **guard** — in-jit finite checks on loss and gradient global-norm; a
+  bad step is held by a select in each leaf's own optimizer update (no
+  ``lax.cond`` over the state, no host syncs; audited by
   ``paddle_tpu.analysis``);
 - **reader** — ``resilient_reader`` retry/backoff/skip-bad-batch wrapper;
 - **signals** — SIGTERM/SIGINT -> checkpoint-at-batch-boundary + clean

@@ -2,9 +2,17 @@
 
 The mixed-precision-training discipline: one NaN/Inf loss or gradient must
 not poison the parameters forever, so the finite checks run ON DEVICE
-(``jnp.isfinite`` of the loss and of the gradient global-norm) and a
-``lax.cond`` selects between the real optimizer update and an identity
-step.  Nothing here crosses the host link — the trainer reads the skip
+(``jnp.isfinite`` of the loss and of the gradient global-norm) and the
+predicate goes down into the optimizer's own update, which holds each leaf
+by a select where it computes the new value (``Optimizer.update``'s
+``finite``): parameters, slots, the step count, layer state and a pserver
+tier's tables come back bit for bit.  There is no ``lax.cond`` over the
+state: a conditional takes and returns its operands in the default layout,
+and the chip keeps some leaves otherwise, so each of those was copied seven
+times a step (PERF.md, PR 48).  The price is that a skipped step pays a
+whole update's time instead of none; skips are rare by construction
+(``max_bad_steps`` aborts a run that strings them together).  Nothing here
+crosses the host link — the trainer reads the skip
 flag from the step's extras at the same cadence it already pulls the loss,
 and ``analysis.audit_fn`` verifies the guarded step stays
 host-transfer-free (tests/test_resilience.py gate).
@@ -37,8 +45,14 @@ def global_grad_norm(grads) -> jnp.ndarray:
                         for g in leaves))
 
 
+def _hold(finite, new, old):
+    """``new`` where the step is finite, ``old`` where not, leaf by leaf."""
+    return jax.tree_util.tree_map(
+        lambda n, o: jnp.where(finite, n, o), new, old)
+
+
 def guarded_update(
-    update_fn: Callable[[Any, Any, Any], Tuple[Any, Any]],
+    update_fn: Callable[[Any, Any, Any, Any], Tuple[Any, Any]],
     *,
     loss,
     grads,
@@ -47,30 +61,25 @@ def guarded_update(
     new_state,
     old_state,
 ) -> Tuple[Any, Any, Any, Dict[str, jnp.ndarray]]:
-    """Apply ``update_fn(params, grads, opt_state)`` only when the step is
-    finite; otherwise hold params, optimizer slots, AND layer state (a NaN
-    forward also poisons BN running stats) unchanged.
+    """Run ``update_fn(params, grads, opt_state, finite)`` with the step's
+    finite predicate; where it is False the update holds params and
+    optimizer slots unchanged (``Optimizer.update(finite=...)``, leaf by
+    leaf), and layer state is held here the same way (a NaN forward also
+    poisons BN running stats).
 
     Returns ``(new_params, new_opt_state, selected_state, extras)`` where
     extras carries device scalars: ``grad_norm`` and ``bad_step`` (1 when
-    the update was skipped).  Pure and jit/pjit-safe; both cond branches
-    are traced, only one executes.
+    the update was skipped).  Pure and jit/pjit-safe; no conditional, so
+    a skipped step runs the update's arithmetic and discards it.
     """
     gnorm = global_grad_norm(grads)
     finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-
-    def _apply(op):
-        p, g, o = op
-        return update_fn(p, g, o)
-
-    def _skip(op):
-        p, _, o = op
-        return p, o
-
-    new_params, new_opt = jax.lax.cond(
-        finite, _apply, _skip, (params, grads, opt_state))
-    sel_state = jax.lax.cond(
-        finite, lambda s: s[0], lambda s: s[1], (new_state, old_state))
+    # the update squares each gradient again (Adam's second moment), and
+    # with no conditional between them XLA would share the norm's squares
+    # with it: every g*g kept beside its g until the update runs
+    grads = jax.lax.optimization_barrier(grads)
+    new_params, new_opt = update_fn(params, grads, opt_state, finite)
+    sel_state = _hold(finite, new_state, old_state)
     extras = {
         "grad_norm": gnorm,
         "bad_step": (~finite).astype(jnp.int32),
@@ -96,7 +105,7 @@ def init_loss_scale(scale: float, *,
 
 
 def scaled_guarded_update(
-    update_fn: Callable[[Any, Any, Any], Tuple[Any, Any]],
+    update_fn: Callable[[Any, Any, Any, Any], Tuple[Any, Any]],
     *,
     loss,
     scaled_grads,
@@ -134,25 +143,17 @@ def scaled_guarded_update(
     gnorm_s = global_grad_norm(scaled_grads)
     loss_finite = jnp.isfinite(loss)
     finite = jnp.isfinite(gnorm_s) & loss_finite
-    # unscale in f32; inv=0 on overflow keeps the (discarded) skip-branch
-    # operands NaN-free so XLA's speculative execution can't trap
+    # unscale in f32; inv=0 on overflow zeroes what the update (run and
+    # then discarded by its own select) is fed, wherever that was finite
     inv = jnp.where(finite, 1.0 / scale, 0.0)
     grads = jax.tree_util.tree_map(
         lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
         scaled_grads)
 
-    def _apply(op):
-        p, g, o = op
-        return update_fn(p, g, o)
-
-    def _skip(op):
-        p, _, o = op
-        return p, o
-
-    new_params, new_opt = jax.lax.cond(
-        finite, _apply, _skip, (params, grads, opt_state))
-    sel_state = jax.lax.cond(
-        finite, lambda s: s[0], lambda s: s[1], (new_state, old_state))
+    # (no barrier as in ``guarded_update``: the update squares the unscaled
+    # gradients, other values than the norm squared)
+    new_params, new_opt = update_fn(params, grads, opt_state, finite)
+    sel_state = _hold(finite, new_state, old_state)
 
     good = jnp.where(finite, amp_state["good_steps"] + 1, 0)
     grow = (growth_interval > 0) & (good >= growth_interval)

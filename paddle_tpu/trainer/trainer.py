@@ -391,7 +391,8 @@ class SGDTrainer:
             proxies = tier.make_proxies(feed) if tier is not None else {}
             # --amp: the loss-scale state rides INSIDE opt_state (donated,
             # checkpointed); split it out so the optimizer sees only its
-            # own keys and the scale update happens OUTSIDE the skip cond
+            # own keys; the scale's state machine runs beside the update
+            # and advances on a skipped step too
             amp_state = opt_state.get("amp") if amp else None
             opt_core = {k: v for k, v in opt_state.items() if k != "amp"}
 
@@ -430,7 +431,10 @@ class SGDTrainer:
                 jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
                     params, proxies))
 
-            def do_update(pack, gpack, o):
+            def do_update(pack, gpack, o, finite=None):
+                # ``finite`` (the guard's predicate, a device bool) holds a
+                # bad step where each leaf is updated: the optimizer by a
+                # select per leaf, the tier by dropping the step's rows
                 p, ps_in = pack
                 g, pxg = gpack
                 clip = True
@@ -451,17 +455,19 @@ class SGDTrainer:
                 np_, no_ = opt.update(
                     p, g, o,
                     lr_scales=lr_scales, decays=decays, statics=statics,
-                    sparse_rows=sparse_rows, clip=clip,
+                    sparse_rows=sparse_rows, clip=clip, finite=finite,
                 )
-                ps_out = (tier.apply_grads(ps_in, feed, pxg)
+                ps_out = (tier.apply_grads(ps_in, feed, pxg, finite=finite)
                           if tier is not None else ps_in)
+                # a held leaf went in masked (__init__, rebuild_masks), and
+                # a 0/1 mask over a masked leaf gives the same bits
                 return (apply_masks(np_, masks), ps_out), no_
 
             if amp:
                 # loss scaling REQUIRES the skip machinery: an overflow is
                 # a normal rescale event, so the guard is always on under
-                # --amp (scale halves + step skips, outside the cond so
-                # the scale advances even on a skip)
+                # --amp (scale halves + step skips; the scale advances
+                # even on a skip)
                 ((new_params, new_ps), new_opt, new_state, new_amp,
                  gextras) = scaled_guarded_update(
                     do_update, loss=loss, scaled_grads=(grads, px_grads),
@@ -473,10 +479,10 @@ class SGDTrainer:
                 new_opt = {**new_opt, "amp": new_amp}
             elif guard:
                 # finite checks on loss + grad global-norm (row grads
-                # included), update skipped via lax.cond — on-device, no
-                # host round-trip (gated by the audit in
-                # tests/test_resilience.py); a skip holds pserver tables,
-                # slots, and dirty masks too
+                # included), a bad step held by a select in each leaf's
+                # own update — on-device, no host round-trip (gated by
+                # the audit in tests/test_resilience.py); a skip holds
+                # pserver tables, slots, and dirty masks too
                 (new_params, new_ps), new_opt, new_state, gextras = (
                     guarded_update(
                         do_update, loss=loss, grads=(grads, px_grads),

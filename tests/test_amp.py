@@ -390,10 +390,12 @@ def _apply_fixtures():
 
 
 def _hand_apply(opt, params, grads, opt_state, *, lr_scales=None,
-                decays=None, statics=None, **_):
+                decays=None, statics=None, finite=None, **_):
     """What ``Optimizer.update`` has to equal on dense leaves, written out:
     ``update_leaf`` on each leaf alone, with that leaf's own scalars,
-    parameter and slots (clipping first, over all the gradients)."""
+    parameter and slots (clipping first, over all the gradients), and
+    under the bad-step guard's ``finite`` the old value where it is
+    False."""
     from paddle_tpu.param.optimizers import clip_by_global_norm
 
     step = opt_state["step"] + 1
@@ -411,9 +413,15 @@ def _hand_apply(opt, params, grads, opt_state, *, lr_scales=None,
         decay = (decays or {}).get(k, 0.0) + opt.l2_rate
         if decay:
             g = g + decay * p
-        p2, new_slots[k] = opt.update_leaf(
+        p2, s2 = opt.update_leaf(
             p, g, slots, lr * (lr_scales or {}).get(k, 1.0), step)
-        new_params[k] = p2.astype(p.dtype)
+        new_params[k], new_slots[k] = p2.astype(p.dtype), s2
+        if finite is not None:
+            new_params[k] = jnp.where(finite, new_params[k], p)
+            new_slots[k] = tuple(jnp.where(finite, n, o)
+                                 for n, o in zip(s2, slots))
+    if finite is not None:
+        step = jnp.where(finite, step, opt_state["step"])
     return new_params, {"step": step, "slots": new_slots}
 
 

@@ -166,14 +166,18 @@ def test_step_fingerprint_matches_hand_applied_per_leaf_update(monkeypatch):
     from paddle_tpu.param.optimizers import Adam
 
     class HandApplied(Adam):
-        def update(self, params, grads, opt_state, **_):
+        def update(self, params, grads, opt_state, *, finite, **_):
+            # the trainer's guard is on: each new value under its select
             step = opt_state["step"] + 1
             out = {k: self.update_leaf(p, grads[k], opt_state["slots"][k],
                                        self.lr_at(step), step)
                    for k, p in params.items()}
-            return ({k: v[0] for k, v in out.items()},
-                    {"step": step,
-                     "slots": {k: v[1] for k, v in out.items()}})
+            new = ({k: v[0] for k, v in out.items()},
+                   {"step": step,
+                    "slots": {k: v[1] for k, v in out.items()}})
+            return jax.tree_util.tree_map(
+                lambda n, o: jnp.where(finite, n, o), new,
+                (params, opt_state))
 
     monkeypatch.setattr(FLAGS, "sdc_check_every", 2)
     fps = {}

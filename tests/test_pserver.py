@@ -329,6 +329,78 @@ def test_bad_step_guard_holds_tables_and_slots(rng):
         np.testing.assert_array_equal(np.asarray(x), y)
 
 
+@pytest.mark.parametrize("amp", [False, True], ids=["guard", "amp"])
+@pytest.mark.parametrize("shape", [(8,), (1,)], ids=["mesh8", "mesh1"])
+def test_bad_step_leaves_the_tier_bit_for_bit_and_no_trace(rng, monkeypatch,
+                                                           shape, amp):
+    """A NaN batch leaves the tier's tables, their slots, the dirty masks and
+    its step count bit for bit, with and without ``--amp``, on the sharded
+    push and on the one-shard path, and without ``--amp`` the next finite
+    batch ends where a trainer that never saw the NaN batch ends."""
+    from paddle_tpu.utils.flags import FLAGS
+
+    monkeypatch.setattr(FLAGS, "amp", amp)
+    mesh = make_mesh(shape, ("model",))
+    feeds = _toy_feeds(rng, n=2)
+    bad = dict(feeds[1])
+    bad["y"] = np.full_like(feeds[1]["y"], np.nan)
+
+    def host(t):
+        return jax.tree_util.tree_map(lambda a: np.asarray(a).copy(),
+                                      t.pserver.state())
+
+    def equal(a, b):
+        la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            np.testing.assert_array_equal(x, y)
+
+    t = SGDTrainer(_toy_net(), Adam(learning_rate=0.05), seed=1, mesh=mesh,
+                   guard_nonfinite=True)
+    t.train_batch(feeds[0])
+    held = host(t)
+    assert held["dirty"]["_u_emb.w0"].any() and int(held["step"]) == 1
+    t.train_batch(bad)
+    assert int(jax.device_get(t._last_extras["bad_step"])) == 1
+    equal(host(t), held)
+    if amp:
+        return      # the next step runs at the halved scale
+    t.train_batch(feeds[1])
+    nn.reset_naming()
+    ref = SGDTrainer(_toy_net(), Adam(learning_rate=0.05), seed=1, mesh=mesh,
+                     guard_nonfinite=True)
+    ref.train_batch(feeds[0])
+    ref.train_batch(feeds[1])
+    equal(host(t), host(ref))
+    equal(jax.tree_util.tree_map(np.asarray, (t.params, t.opt_state)),
+          jax.tree_util.tree_map(np.asarray, (ref.params, ref.opt_state)))
+
+
+def test_guarded_tier_step_selects_over_no_table(rng):
+    """The guard's hold of a tier is the step's own ids turned into the
+    sentinel the row scatter drops: the guarded step's jaxpr holds no
+    ``select_n`` as large as a table or as a shard of one (a pass over a
+    100 M-row table), and no ``cond`` that takes one."""
+    from paddle_tpu.analysis.jaxpr_walk import walk_eqns
+
+    mesh = make_mesh((8,), ("model",))
+    t = SGDTrainer(_toy_net(vocab=104), Adam(learning_rate=0.05), seed=1,
+                   mesh=mesh, guard_nonfinite=True)
+    v_pad, dim = t.pserver.tables["_u_emb.w0"].data.shape
+    assert (v_pad, dim) == (104, 16)           # 13 rows a shard: no other
+    big = {(v_pad, dim), (v_pad // 8, dim), (v_pad,), (v_pad // 8,)}
+    closed = jax.make_jaxpr(t._step_fn)(
+        t.params, t.state, t.opt_state, t.pserver.state(),
+        jax.random.PRNGKey(0), _toy_feeds(rng, vocab=104, n=1)[0])
+    eqns = list(walk_eqns(closed.jaxpr))
+    assert {"shard_map", "scatter"} <= {e.primitive.name for e, _ in eqns}
+    assert not [p for e, p in eqns if e.primitive.name == "select_n"
+                and e.outvars[0].aval.shape in big]
+    assert not [p for e, p in eqns if e.primitive.name == "cond"
+                and any(getattr(v.aval, "shape", None) in big
+                        for v in e.invars)]
+
+
 def test_trainer_surfaces_feeder_dropped_features(rng):
     """Satellite: sparse-bag truncation is observable in _last_extras."""
     from paddle_tpu.data.feeder import DataFeeder

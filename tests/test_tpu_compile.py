@@ -625,59 +625,22 @@ def test_causal_attention_kernels_at_heads_of_256(one_chip, on_tpu):
 
 
 def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
-    """The cell's whole step (the model's loss and gradient under its
-    recomputation blocks, per-leaf Adam, state donated) compiled for the
-    described v5e from shapes alone: the compiler's own count of arguments,
-    results and temporaries stays under 15 GB of the chip's 16, with the
-    delta rule's, the delta nets' prep and the attention's kernels in the
-    program.  (That no ``q`` or ``k`` is repeated to 32 heads is asserted on
-    the layer's jaxpr in tests/test_gdn_prep.py, at widths where the shape
-    tells them from ``v``, ``z`` and ``o``: here all five are ``[1, 8192,
-    32, 128]``.)"""
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from benchmark import manifest
-
-    import paddle_tpu.nn as nn
-    from paddle_tpu.param.optimizers import Adam
-
-    cell = manifest.cell("qwen3next-train-b1-t8192")
-    cfg, T = cell["config"], cell["traffic"]["seq_len"]
-    cost, extras = manifest.program(cfg).net(cfg)
-    topo = nn.Topology([cost] + extras)
-    params = {k: _struct(one_chip, spec.shape)
-              for k, spec in topo.param_specs.items()}
-    o = cfg["optimizer"]
-    opt = Adam(learning_rate=o["learning_rate"], beta1=o["beta1"],
-               beta2=o["beta2"], epsilon=o["epsilon"])
-    opt_state = jax.tree_util.tree_map(
-        lambda a: _struct(one_chip, a.shape, a.dtype),
-        jax.eval_shape(opt.init_state, params))
-    ids = (_struct(one_chip, (1, T), jnp.int32),
-           _struct(one_chip, (1,), jnp.int32))
-
-    def step(params, opt_state, feed):
-        def loss(p):
-            outs, _ = topo.apply(p, {}, feed, train=True)
-            return outs["cost"].value, [outs[e.name].value for e in extras]
-
-        (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
-        return (value, counts) + opt.update(params, grads, opt_state)
-
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
+    """The cell's whole step as its trainer builds it (``_cell_step``,
+    below) compiled for the described v5e from shapes alone: the compiler's
+    own count of arguments, results and temporaries stays under 15 GB of the
+    chip's 16, with the delta rule's, the delta nets' prep and the
+    attention's kernels in the program.  (That no ``q`` or ``k`` is repeated
+    to 32 heads is asserted on the layer's jaxpr in tests/test_gdn_prep.py,
+    at widths where the shape tells them from ``v``, ``z`` and ``o``: here
+    all five are ``[1, 8192, 32, 128]``.)"""
+    compiled = _cell_step(one_chip, "qwen3next-train-b1-t8192")
     text = compiled.as_text()
     assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
     assert "gdn_prep_fwd" in text and "gdn_prep_bwd" in text
     assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
     m = compiled.memory_analysis()
-    held = (m.argument_size_in_bytes + m.output_size_in_bytes
-            - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 3 * 4 * 424_340_544 < m.argument_size_in_bytes     # p, m, v
-    assert held < 15e9, held
+    assert _held_bytes(compiled) < 15e9, _held_bytes(compiled)
 
 
 # -- a state-space scan and experts of 1856 (PR 43): Nemotron-3-Nano-30B-A3B's
@@ -933,8 +896,9 @@ _cell_steps = {}
 
 
 def _cell_step(one_chip, name):
-    """A cell's whole step (the model's loss and gradient under its
-    recomputation blocks, per-leaf Adam, state donated) compiled for the
+    """A cell's whole step as its trainer builds it (the model's loss and
+    gradient under its recomputation blocks, per-leaf Adam inside the
+    bad-step guard, state donated) compiled for the
     described v5e from shapes alone, once a cell for the tests below (each
     asks for ``on_tpu``, so whichever runs first compiles under the same
     gates)."""
@@ -949,6 +913,7 @@ def _cell_step(one_chip, name):
 
     import paddle_tpu.nn as nn
     from paddle_tpu.param.optimizers import Adam
+    from paddle_tpu.resilience.guard import guarded_update
 
     cell = manifest.cell(name)
     cfg, T = cell["config"], cell["traffic"]["seq_len"]
@@ -971,7 +936,13 @@ def _cell_step(one_chip, name):
             return outs["cost"].value, [outs[e.name].value for e in extras]
 
         (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
-        return (value, counts) + opt.update(params, grads, opt_state)
+        # the bad-step guard around the update, as ``SGDTrainer._build_step``
+        # has it: every trainer cell sets ``guard_nonfinite``
+        new_params, new_opt, _, gextras = guarded_update(
+            lambda p, g, o, finite: opt.update(p, g, o, finite=finite),
+            loss=value, grads=grads, params=params, opt_state=opt_state,
+            new_state={}, old_state={})
+        return value, counts, gextras, new_params, new_opt
 
     _cell_steps[name] = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt_state, {"tokens": ids, "next_tokens": ids}).compile()
@@ -989,32 +960,51 @@ def _held_bytes(compiled):
 
 
 def test_nemotron_cell_step_fits_the_chip(one_chip, on_tpu):
-    """The cell's whole step: the compiler's own count of arguments,
-    results and temporaries stays under 13 GB of the chip's 16 (11.4 read,
-    PR 43 and PR 44), with the scan's, the attention's and the grouped
-    products' kernels in the program: no gate is closed."""
+    """The cell's whole step, the trainer's guard around its update: the
+    compiler's own count of arguments, results and temporaries stays under
+    13.3 GB of the chip's 16 (13.20 read, PR 48; 11.4 without the guard, PR
+    43 and PR 44: the guard's norm needs every gradient before the first
+    leaf is updated, 2.67 GB alive together; the guard as a ``lax.cond``
+    read 13.27), with the scan's, the attention's and the grouped products'
+    kernels in the program: no gate is closed.  Every parameter and both of
+    its Adam slots come back in the buffer they went in by: the select that
+    holds a bad step keeps no second copy of the state."""
     compiled = _nemotron_cell_step(one_chip)
     text = compiled.as_text()
     for name in ("ssd_chunk_fwd", "ssd_chunk_bwd", "flash_attn_fwd",
                  "flash_attn_bwd", "moe_gmm", "moe_tgmm"):
         assert name in text, name
     m = compiled.memory_analysis()
-    assert 3 * 4 * 666_963_456 < m.argument_size_in_bytes     # p, m, v
-    assert _held_bytes(compiled) < 13e9, _held_bytes(compiled)
+    state = 3 * 4 * 666_963_456                               # p, m, v
+    assert state < m.argument_size_in_bytes
+    assert state <= m.alias_size_in_bytes, m.alias_size_in_bytes
+    assert _held_bytes(compiled) < 13.3e9, _held_bytes(compiled)
+
+
+def _layout_copies(text: str, shape: str):
+    """The compiled module's ``copy`` instructions of an ``f32`` array of
+    ``shape`` (``"2688,10304"``), whatever the two layouts."""
+    return [line.strip()[:160] for line in text.split("\n") if re.search(
+        r"= f32\[" + shape + r"\]\{[^}]*\} copy\(", line)]
 
 
 def test_nemotron_cell_step_copies_no_expert_matrix(one_chip, on_tpu):
-    """PR 45: this step (value, gradient and per-leaf Adam, WITHOUT the
-    trainer's guard) held 24 ``copy`` instructions of ``w1``'s shape in the
-    parent, between the layout the leaf is kept in and the one a Mosaic call
-    takes; through the transposed view there is none, and ``moe_gmm`` /
-    ``moe_tgmm`` are called as often (8 expert-layer row buffers of 4 and 2:
-    both branches of a layer's ``lax.cond``).  The trainer's step keeps 7 a
-    layer that are not the kernels': its guard's ``lax.cond`` takes the
-    parameter, its gradient and the two slots in the default layout and
-    hands the three new values back in it (PERF.md section 6, PR 45)."""
+    """PR 45: the step without the trainer's guard held 24 ``copy``
+    instructions of ``w1``'s shape in the parent, between the layout the leaf
+    is kept in and the one a Mosaic call takes; through the transposed view
+    there is none, and ``moe_gmm`` / ``moe_tgmm`` are called as often (8
+    expert-layer row buffers of 4 and 2: both branches of a layer's
+    ``lax.cond``).  PR 48: this IS the trainer's step, guard and all, and it
+    holds none of the mixers' ``W_in`` ``[2688, 10304]`` either: the guard
+    holds a bad step by a select inside each leaf's update, where as a
+    ``lax.cond`` over parameters, gradients and slots it took each leaf the
+    chip keeps transposed in the default layout and handed it back in it,
+    seven copies a leaf (28 + 28 in this module, 21.6 ms a step on the
+    chip)."""
     text = _nemotron_cell_step(one_chip).as_text()
     assert not _w1_layout_copies(text)
+    assert not _layout_copies(text, "2688,10304")
+    assert not _layout_copies(text, "10304,2688")
     calls = re.findall(r"%(moe_gmm|moe_tgmm)[.\d]* = [^\n]*? custom-call\(",
                        text)
     assert (calls.count("moe_gmm"), calls.count("moe_tgmm")) == (48, 16)

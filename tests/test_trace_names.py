@@ -233,11 +233,14 @@ def _seq2seq_update_jaxpr():
 
 
 def _trainer_update_jaxpr():
+    # with the bad-step guard's predicate, as the trainer's step hands it
+    # down: the selects that hold a bad step are the update's own equations
     tr = _lstm_trainer()
-    return jax.make_jaxpr(lambda p, g, o: tr.optimizer.update(
+    assert tr.guard_nonfinite
+    return jax.make_jaxpr(lambda p, g, o, finite: tr.optimizer.update(
         p, g, o, lr_scales=tr.lr_scales, decays=tr.decays,
-        statics=tr.statics, sparse_rows=tr.sparse_rows))(
-        tr.params, tr.params, tr.opt_state)
+        statics=tr.statics, sparse_rows=tr.sparse_rows, finite=finite))(
+        tr.params, tr.params, tr.opt_state, np.bool_(True))
 
 
 @pytest.mark.parametrize("build_step, build_update", [

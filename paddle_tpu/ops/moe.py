@@ -16,6 +16,18 @@ worst-case size (every token sends ``min(k, held)`` choices here); the layer
 takes the small one whenever the step's routing fits it, so no routing drops
 a token; only the tiles that hold rows are computed.
 
+Index work.  A gather or a scatter of SCALARS runs on the TPU as a walk, 5-10
+ns an element whatever the table's size, and a layer has ``A = N k``
+assignments (131,072 at a row of 16384, top 8) where its buffer has a third
+to a seventh as many rows.  So no step of the dispatch walks the
+assignments: the router's chosen scores are picked by comparison (a one-hot
+over the router's outputs summed, exact: one term is not zero), the sorted
+keys are never made (the counts say them), and a row finds its assignment by
+ONE gather of the buffer's rows out of ``order``; what is left over ``A``
+is the stable sort itself, once a layer.  Rows of ``D`` numbers still move
+by the buffer's rows (``_take_rows``, ``_add_rows``).  PERF.md section 6,
+PR 49, has the chip's numbers.
+
 Precision.  The router (``x W_r``, the sigmoid or softmax, the selection and
 the weights) runs in float32 at ``highest`` precision whatever the operand
 policy: a selection made on bf16 scores picks other experts than the float32
@@ -39,6 +51,16 @@ __all__ = ["route_tokens", "count_assignments", "group_assignments",
            "moe_kernel_row_tile", "Grouping"]
 
 
+def _pick(table, idx):
+    """``table[..., idx]`` along the last axis, by comparison: a one-hot of
+    ``idx`` over the axis, the one term that is not zero summed out (exact),
+    which XLA fuses into a pass over ``table``; its transpose is a select
+    and a sum.  For where a gather would walk ``idx`` a scalar at a time
+    (the module's "Index work")."""
+    hot = idx[..., None] == jnp.arange(table.shape[-1], dtype=idx.dtype)
+    return jnp.sum(jnp.where(hot, table, 0), axis=-1)
+
+
 def route_tokens(x, w_router, bias, *, top_k: int, norm_topk: bool,
                  scaling: float, scoring: str = "sigmoid"):
     """x ``[N, D]`` -> (experts ``[N, k]`` int32, weights ``[N, k]``
@@ -48,7 +70,11 @@ def route_tokens(x, w_router, bias, *, top_k: int, norm_topk: bool,
     ``norm_topk``, the chosen scores are divided by their sum plus 1e-6.
     ``scoring="softmax"``: scores are the softmax over all the router's
     outputs, the ``top_k`` largest are chosen (``bias`` is ``None``) and,
-    with ``norm_topk``, divided by their sum, with no epsilon."""
+    with ``norm_topk``, divided by their sum, with no epsilon.  The chosen
+    scores are picked from ``s`` by comparison with ``idx``, not gathered:
+    one fused pass over ``s`` forward, a select and a sum over the choices
+    backward, where ``take_along_axis`` walks ``N k`` scalars (and its
+    gradient sorts as many indices for a scatter-add); the same floats."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"route_tokens: unknown scoring {scoring!r}")
     f32 = acc_dtype()
@@ -62,7 +88,7 @@ def route_tokens(x, w_router, bias, *, top_k: int, norm_topk: bool,
         bias.astype(f32))
     _, idx = jax.lax.top_k(picked, top_k)
     idx = checkpoint_name(idx, "remat_keep")
-    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    chosen = _pick(s[:, None, :], idx)
     if norm_topk:
         chosen = chosen / (jnp.sum(chosen, -1, keepdims=True)
                            + (1e-6 if scoring == "sigmoid" else 0.0))
@@ -111,10 +137,14 @@ def buffer_rows(tokens: int, top_k: int, experts: int, held: int, tm: int):
 
 def count_assignments(idx, *, first_expert: int, held: int):
     """idx ``[N, k]``, the experts each token chose over ALL experts ->
-    (key ``[N*k]``: the expert held, 0..held-1, or ``held`` for an expert
-    held elsewhere; counts ``[held]``; order ``[N*k]``: the assignments
-    sorted by key, stably).  The sort is kept across a recomputation block
-    (``remat_keep``): the backward's second forward does not sort again."""
+    (counts ``[held]``: the assignments to each expert held; order
+    ``[N*k]``: the assignments sorted, stably, by the expert held, those to
+    experts held elsewhere last).  The sort is kept across a recomputation
+    block (``remat_keep``): the backward's second forward does not sort
+    again.  Only ``order`` leaves the sort: the keys in sorted order are what
+    the counts say (``counts[0]`` zeros, then ``counts[1]`` ones, ...), and
+    :func:`group_assignments` reads them there, where ``key[order]`` would
+    be a walk over the assignments."""
     local = idx.reshape(-1) - first_expert
     key = jnp.where((local >= 0) & (local < held), local, held)
     key = key.astype(jnp.int32)
@@ -122,32 +152,40 @@ def count_assignments(idx, *, first_expert: int, held: int):
                      dtype=jnp.int32)
     order = checkpoint_name(jnp.argsort(key, stable=True).astype(jnp.int32),
                             "remat_keep")
-    return key, counts, order
+    return counts, order
 
 
-def group_assignments(key, counts, order, *, tm: int, rows: int) -> Grouping:
+def group_assignments(counts, order, *, tm: int, rows: int) -> Grouping:
     """Rows for the assignments held, sorted by expert, every group padded
-    to whole tiles of ``tm``, in a buffer of ``rows``."""
-    A, held = key.shape[0], counts.shape[0]
-    sorted_key = key[order]
+    to whole tiles of ``tm``, in a buffer of ``rows`` (whole tiles).  Made
+    from the ROWS' side: a tile's expert follows from the counts, a row's
+    rank in its group from its tile, the row is live where the rank is
+    under the group's count, and then it holds ``order[start of the group in
+    the sorted assignments + rank]``: one gather of ``rows`` scalars.  (The
+    assignments' side, a row computed for each of the ``N*k`` sorted
+    assignments and ``order`` scattered to it, walks ``N*k`` elements to
+    fill a buffer a third to a seventh of that.)  What is a table of
+    ``held`` entries is picked by comparison (:func:`_pick`), a tile at a
+    time."""
+    A, held = order.shape[0], counts.shape[0]
     tiles = -(-counts // tm)
-    group_row0 = (jnp.cumsum(tiles) - tiles) * tm             # [held]
-    sorted_start = jnp.cumsum(counts) - counts
-    safe = jnp.minimum(sorted_key, held - 1)
-    rank = jnp.arange(A, dtype=jnp.int32) - sorted_start[safe]
-    row = jnp.where(sorted_key < held, group_row0[safe] + rank, rows)
-    row = jnp.minimum(row, rows).astype(jnp.int32)   # past the buffer: none
-    row_assign = jnp.full((rows,), A, jnp.int32).at[row].set(order,
-                                                             mode="drop")
     ends = jnp.cumsum(tiles)                                   # [held]
     n_tiles = rows // tm
+    tile = jnp.arange(n_tiles, dtype=jnp.int32)
     tile_expert = jnp.minimum(
-        jnp.sum(jnp.arange(n_tiles)[:, None] >= ends[None, :], axis=1),
+        jnp.sum(tile[:, None] >= ends[None, :], axis=1),
         held - 1).astype(jnp.int32)
-    placed = jnp.sum(row < rows, dtype=jnp.int32)
+    # a tile past the groups reads the last expert's tables: its ranks are
+    # past that group's whole tiles, so past its count
+    rank = ((tile - _pick(ends - tiles, tile_expert)) * tm)[:, None] + (
+        jnp.arange(tm, dtype=jnp.int32)[None, :])              # [n_tiles, tm]
+    live = rank < _pick(counts, tile_expert)[:, None]
+    sorted_at = _pick(jnp.cumsum(counts) - counts, tile_expert)[:, None] + rank
+    row_assign = jnp.take(order, jnp.where(live, sorted_at, A).reshape(-1),
+                          mode="fill", fill_value=A)
     return Grouping(row_assign, tile_expert,
                     jnp.minimum(ends[-1:], n_tiles).astype(jnp.int32),
-                    counts, jnp.sum(counts) - placed)
+                    counts, jnp.sum(counts) - jnp.sum(live, dtype=jnp.int32))
 
 
 # -- the grouped products ----------------------------------------------------
@@ -408,13 +446,13 @@ def expert_layer(x, idx, weights, w1, w3, w2, *, num_experts: int,
     N, k = idx.shape
     held = w1.shape[0]
     with jax.named_scope("moe_grouping"):
-        key, counts, order = count_assignments(
+        counts, order = count_assignments(
             idx, first_expert=first_expert, held=held)
     usual, worst = buffer_rows(N, k, num_experts, held, tm)
 
     def run(rows):
         with jax.named_scope("moe_grouping"):
-            g = group_assignments(key, counts, order, tm=tm, rows=rows)
+            g = group_assignments(counts, order, tm=tm, rows=rows)
         return (grouped_expert_mlp(x, weights, g, w1, w3, w2, tm=tm,
                                    kernels=kernels), g.uncomputed)
 

@@ -344,10 +344,10 @@ def test_padded_positions_are_no_tokens():
 def test_grouping_places_every_assignment_held_once(held, tm):
     idx = jnp.asarray(np.random.default_rng(held).integers(0, 8, (40, 2)),
                       jnp.int32)
-    key, counts, order = M.count_assignments(idx, first_expert=2, held=held)
+    counts, order = M.count_assignments(idx, first_expert=2, held=held)
     usual, worst = M.buffer_rows(40, 2, 8, held, tm)
     assert usual <= worst and worst >= 40 * min(2, held) + held * (tm - 1)
-    g = M.group_assignments(key, counts, order, tm=tm, rows=worst)
+    g = M.group_assignments(counts, order, tm=tm, rows=worst)
     here = ((np.asarray(idx) >= 2) & (np.asarray(idx) < 2 + held)).reshape(-1)
     row_assign = np.asarray(g.row_assign)
     rows = np.flatnonzero(row_assign < here.size)
@@ -361,7 +361,7 @@ def test_grouping_places_every_assignment_held_once(held, tm):
     assert rows.max(initial=-1) < int(g.n_active[0]) * tm
     assert int(g.uncomputed) == 0
     # a buffer too small for the routing owns up to what it left out
-    small = M.group_assignments(key, counts, order, tm=tm, rows=tm)
+    small = M.group_assignments(counts, order, tm=tm, rows=tm)
     assert int(small.uncomputed) == here.sum() - int(
         (np.asarray(small.row_assign) < here.size).sum()) > 0
 
@@ -440,8 +440,8 @@ def test_grouped_product_kernels_match_the_masked_loop():
     ws = [jax.random.normal(jax.random.fold_in(key, i), s) * s[1] ** -0.5
           for i, s in ((2, (held, D, F)), (3, (held, D, F)),
                        (4, (held, F, D)))]
-    key, counts, order = M.count_assignments(idx, first_expert=2, held=held)
-    g = M.group_assignments(key, counts, order, tm=16,
+    counts, order = M.count_assignments(idx, first_expert=2, held=held)
+    g = M.group_assignments(counts, order, tm=16,
                             rows=M.buffer_rows(N, 2, 8, held, 16)[1])
     assert int(g.counts[1]) == 0
 

@@ -69,10 +69,10 @@ def test_first_matrix_products_match_the_plain_loop(D, F, view, kernels,
     assert M._kept_transposed(jnp.zeros((HELD, D, F))) is view
     if view:    # a hanging block where the tile does not divide F
         assert F <= 512 or M._largest_tile(F, 512) == 512 and F % 512
-    key, counts, order = M.count_assignments(
+    counts, order = M.count_assignments(
         jnp.asarray(idx), first_expert=FIRST, held=HELD)
     g = M.group_assignments(
-        key, counts, order, tm=TM,
+        counts, order, tm=TM,
         rows=M.buffer_rows(N, K, EXPERTS, HELD, TM)[1])
     assert int(g.counts[1]) == 0 and int(g.uncomputed) == 0
     w = np.random.default_rng(1).standard_normal((N, D))

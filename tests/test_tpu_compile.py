@@ -1116,3 +1116,69 @@ def test_keye_cell_step_fits_the_chip(one_chip, on_tpu):
     m = compiled.memory_analysis()
     assert 3 * 4 * 314_396_160 < m.argument_size_in_bytes     # p, m, v
     assert _held_bytes(compiled) < 15.5e9, _held_bytes(compiled)
+
+
+def _instructions(text: str, scope: str):
+    """``(op, result, operand shapes, op_name)`` of the compiled module's
+    ``gather``, ``scatter`` and ``sort`` instructions and ``kCustom`` fusions
+    (the TPU's gathers and scatters run as such) whose ``op_name`` lies
+    under ``scope``.  Shapes without their layouts; an operand is looked up
+    by its name, which is the module's own."""
+    bare = lambda s: re.sub(r"\{[^}]*\}", "", s)  # noqa: E731
+    shape_of, found = {}, []
+    for line in text.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (.*?) ([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        shape_of[name] = bare(result)
+        on = re.search(r'op_name="([^"]*)"', rest)
+        if on is None or f"/{scope}/" not in on.group(1):
+            continue
+        if op == "fusion" and "kind=kCustom" in rest:
+            op = "kCustom"
+        if op in ("gather", "scatter", "sort", "kCustom"):
+            found.append((op, bare(result), re.findall(
+                r"%[^\s,)]+", rest.split(")")[0]), on.group(1)))
+    return [(op, result, [shape_of.get(o, "?") for o in operands], on)
+            for op, result, operands, on in found]
+
+
+@pytest.mark.parametrize("cell,tokens,k,layers", [
+    ("qwen3next-train-b1-t8192", 8192, 10, 4),
+    ("keyevl2-train-b1-t16384", 16384, 8, 4)])
+def test_expert_cell_step_walks_no_assignments(cell, tokens, k, layers,
+                                               one_chip, on_tpu):
+    """PR 49.  A gather or a scatter of scalars runs on the chip as a walk,
+    5-10 ns an element, and the expert layer's index work held three over
+    the ``A = N k`` assignments a layer and pass: ``take_along_axis`` for
+    the chosen scores (and a scatter-add into ``[N, E]`` behind a sort of
+    ``A`` indices for its gradient), ``key[order]`` for the sorted keys and
+    a scatter of ``order`` to the rows.  In the cell's compiled step nothing
+    under ``moe_routing`` is a gather, a scatter or a ``kCustom`` fusion;
+    under ``moe_grouping`` no gather makes ``A`` elements, no scatter takes
+    ``A`` indices or updates and no ``kCustom`` fusion makes ``A`` elements
+    (the buffer's rows are gathered from ``order`` ``[A]`` and the tokens'
+    rows move as before: both by the buffer's ``M`` rows, which is what
+    is left); and the selection's sort and the grouping's run once a layer,
+    in the forward, not again in the recomputed one."""
+    A = f"[{tokens * k}"
+    text = _cell_step(one_chip, cell).as_text()
+    routing = _instructions(text, "moe_routing")
+    assert routing and all(i[0] == "sort" for i in routing), [
+        i for i in routing if i[0] != "sort"][:4]
+    grouping = _instructions(text, "moe_grouping")
+    assert any(op == "gather" and operands[0].startswith("s32" + A)
+               for op, _, operands, _ in grouping)      # the rows' one gather
+    walks = [i for i in grouping
+             if i[0] in ("gather", "kCustom") and A in i[1]
+             or i[0] == "scatter" and any(A in o for o in i[2][1:])]
+    assert not walks, walks[:4]
+    sorts = [(result, on) for op, result, _, on in routing + grouping
+             if op == "sort"]
+    assert not [s for s in sorts if "rematted_computation" in s[1]]
+    forward = [s for s in sorts if "transpose(" not in s[1]]
+    assert len([s for s in forward if "/moe_routing/top_k" in s[1]]) == layers
+    assert len([s for s in forward if "/moe_grouping/" in s[1]]) == layers
+    # the backward's sorts are the row scatter-adds' own, over a buffer's rows
+    assert not [s for s in sorts if "transpose(" in s[1] and A in s[0]]

@@ -10,3 +10,4 @@ from paddle_tpu.models.kanana2 import kanana2_moe_net
 from paddle_tpu.models.qwen3_next import qwen3_next_net
 from paddle_tpu.models.nemotron_h import nemotron_h_net
 from paddle_tpu.models.keye_vl2 import keye_vl2_net
+from paddle_tpu.models.laguna import laguna_net

@@ -43,7 +43,8 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                   norm_eps: float = 1e-5, zero_centered_norm: bool = False,
                   tie_head: bool = True, recompute_layers=True,
                   ffn_layer_type: Optional[str] = None,
-                  expert_act: str = "gated_silu"):
+                  expert_act: str = "gated_silu",
+                  selection_bias: bool = True):
     """Returns ``(cost, extras)``: the mean next-token cross-entropy over
     ``tokens`` / ``next_tokens`` (two int sequence feeds of one length), and
     two extra outputs per expert layer, marked for the counters
@@ -70,7 +71,8 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     and one residual add, the feed-forward alone where ``layer_types[i]`` is
     this kind and the kind's mixer alone elsewhere (``num_dense_layers``
     counts layers as before: a feed-forward layer ``i`` below it is a gated
-    MLP).  ``expert_act``: the experts' form (``nn.expert_mlp``).
+    MLP).  ``expert_act``: the experts' form, ``selection_bias``: whether
+    sigmoid scoring has its ``expert_bias`` leaf (``nn.expert_mlp``).
     ``recompute_layers`` marks decoder layers as
     recomputation blocks, one block a layer: ``True`` for every layer, or
     the indices of the layers to recompute (the others hold their
@@ -92,6 +94,8 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
         routing["shared_gate"] = True
     if expert_act != "gated_silu":
         routing["expert_act"] = expert_act
+    if not selection_bias:
+        routing["selection_bias"] = False
 
     def feed_forward(normed, i):
         """Layer ``i``'s feed-forward over ``normed`` and what rides with it

@@ -20,7 +20,7 @@ holding its activations.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -103,10 +103,10 @@ def gated_short_conv(input: LayerOutput, *, kernel_size: int = 3,
 
 
 def _attention_specs(name, D, H, Hkv, dh, dq, qk_norm=True,
-                     zero_centered_norm=False):
-    """The leaves of grouped-query attention: four projections and, with
-    ``qk_norm``, one norm weight for the query heads and one for the key
-    heads."""
+                     zero_centered_norm=False, head_gate=False):
+    """The leaves of grouped-query attention: four projections, with
+    ``qk_norm`` one norm weight for the query heads and one for the key
+    heads, and with ``head_gate`` the head-wise gate's ``wg`` ``[D, H]``."""
     norm_w = lambda leaf: _pa(  # noqa: E731
         None, f"_{name}.{leaf}", init="zeros" if zero_centered_norm
         else "ones")
@@ -118,7 +118,9 @@ def _attention_specs(name, D, H, Hkv, dh, dq, qk_norm=True,
                   _fan_in(f"_{name}.wo", H * dh)),
     ] + ([ParamSpec(f"_{name}.q_norm", (dh,), norm_w("q_norm")),
           ParamSpec(f"_{name}.k_norm", (dh,), norm_w("k_norm"))]
-         if qk_norm else [])
+         if qk_norm else []) + (
+        [ParamSpec(f"_{name}.wg", (D, H), _fan_in(f"_{name}.wg", D))]
+        if head_gate else [])
 
 
 def _project_heads(x, p, H, Hkv, dh, dq):
@@ -132,32 +134,64 @@ def _project_heads(x, p, H, Hkv, dh, dq):
 def causal_self_attention(input: LayerOutput, *, num_heads: int,
                           num_kv_heads: int, head_dim: int,
                           rope_theta: float = 10000.0, norm_eps: float = 1e-5,
-                          output_gate: bool = False,
+                          output_gate=False,
                           rotary_dim: Optional[int] = None,
                           zero_centered_norm: bool = False,
                           qk_norm: bool = True, rotary: bool = True,
+                          window: Optional[int] = None,
+                          rope_scaling: Optional[Mapping] = None,
                           name: Optional[str] = None) -> LayerOutput:
     """Causal grouped-query self-attention: RMSNorm over every query head
     and every key head (one weight vector each; ``zero_centered_norm``: the
     ``1 + w`` form), rotary embedding (on the first ``rotary_dim`` channels
     of a head where given, else on all), softmax at scale ``head_dim **
-    -0.5`` computed blockwise, output projection.  ``output_gate``: ``W_q``
-    gives every head ``[q | gate]`` of ``head_dim`` each, and the attention's
-    result is multiplied by ``sigmoid(gate)`` before the output
-    projection.  ``qk_norm=False``: no norm over the heads, and the layer has
+    -0.5`` computed blockwise, output projection.  ``output_gate=True``:
+    ``W_q`` gives every head ``[q | gate]`` of ``head_dim`` each, and the
+    attention's result is multiplied by ``sigmoid(gate)`` before the output
+    projection; ``output_gate="head"``: the gate is ONE number a head and
+    token, ``sigmoid(x W_g)`` with ``W_g`` ``[D, H]`` (leaf ``wg``).
+    ``qk_norm=False``: no norm over the heads, and the layer has
     no ``q_norm`` / ``k_norm`` leaves; ``rotary=False``: the layer takes no
-    positions (a model whose other mixers carry the order)."""
+    positions (a model whose other mixers carry the order).
+
+    ``window``: a query sees its last ``window`` positions alone, its own
+    among them (``ops.causal_attention``); the core then runs under the
+    scope ``attn_window`` where a full layer's keeps ``attn_core``, and
+    ``Act.state`` carries ``window_pairs`` (the (query, position) pairs the
+    mask lets through, real queries only, int32: ``sum_t min(t + 1,
+    window)`` a full row).  ``rope_scaling``: a config's ``rope_parameters``
+    block of ``rope_type`` ``yarn`` (``rope_theta``, ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``): the rotary embedding's frequencies and the factor
+    on its cos and sin are ``ops.decoder_block.yarn_frequencies``'s over the
+    turned channels; ``rope_type`` ``default`` scales nothing."""
     name = name or next_name("self_attention")
     if num_heads % num_kv_heads:
         raise ConfigError(f"{name!r}: {num_heads} query heads are not whole "
                           f"groups over {num_kv_heads} key-value heads")
+    if output_gate not in (False, True, "head"):
+        raise ConfigError(f"{name!r}: unknown output_gate {output_gate!r}")
+    if window is not None and window < 1:
+        raise ConfigError(f"{name!r}: a window of {window} positions")
     D, H, Hkv, dh = input.size, num_heads, num_kv_heads, head_dim
     rd = dh if rotary_dim is None else rotary_dim
     if rd % 2 or not 0 < rd <= dh:
         raise ConfigError(f"{name!r}: rotary width {rd} of a head of {dh}")
-    dq = 2 * dh if output_gate else dh
+    scaled = {}
+    kind = (rope_scaling or {}).get("rope_type", "default")
+    if kind == "yarn":
+        inv_freq, factor = DB.yarn_frequencies(rd, **{
+            k: rope_scaling[k] for k in (
+                "rope_theta", "factor", "original_max_position_embeddings",
+                "beta_fast", "beta_slow", "attention_factor")
+            if k in rope_scaling})
+        scaled = {"inv_freq": inv_freq, "factor": factor}
+    elif kind != "default":
+        raise ConfigError(f"{name!r}: unknown rope_type {kind!r}")
+    head_gate = output_gate == "head"
+    dq = 2 * dh if output_gate is True else dh
     specs = _attention_specs(name, D, H, Hkv, dh, dq, qk_norm,
-                             zero_centered_norm)
+                             zero_centered_norm, head_gate)
 
     def forward(ctx, params, a: Act) -> Act:
         if not a.is_seq:
@@ -168,21 +202,33 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
         x = a.value
         B, T = x.shape[:2]
         q, k, v = _project_heads(x, p, H, Hkv, dh, dq)
-        if output_gate:
+        if output_gate is True:
             q, gate = q[..., :dh], q[..., dh:]
+        elif head_gate:
+            gate = O.linear(x, p["wg"])[..., None]
 
         def placed(h, norm):     # the head's norm, then its position
             if qk_norm:
                 h = DB.rms_norm(h, p[norm], norm_eps, zero_centered_norm)
-            return (DB.rotary_embedding(h, rope_theta, rotary_dim)
+            return (DB.rotary_embedding(h, rope_theta, rotary_dim, **scaled)
                     if rotary else h)
 
         q, k = placed(q, "q_norm"), placed(k, "k_norm")
-        with jax.named_scope("attn_core"):
-            o = DB.causal_attention(q, k, v, scale=dh ** -0.5)
+        if window is None:
+            with jax.named_scope("attn_core"):
+                o = DB.causal_attention(q, k, v, scale=dh ** -0.5)
+        else:
+            with jax.named_scope("attn_window"):
+                o = DB.causal_attention(q, k, v, scale=dh ** -0.5,
+                                        window=window)
         if output_gate:
             o = o * jax.nn.sigmoid(gate.astype(o.dtype))
-        return _seq_like(a, O.linear(o.reshape(B, T, H * dh), p["wo"]))
+        out = _seq_like(a, O.linear(o.reshape(B, T, H * dh), p["wo"]))
+        if window is not None:
+            seen = jnp.minimum(jnp.arange(1, T + 1), window)
+            out.state["window_pairs"] = jnp.sum(
+                jnp.where(a.mask > 0, seen[None, :], 0)).astype(jnp.int32)
+        return out
 
     return LayerOutput(name, "causal_self_attention", D, [input], forward,
                        specs)
@@ -585,6 +631,7 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                norm_topk_prob: bool = True, routed_scaling_factor: float = 1.0,
                shared_size: int = 0, scoring: str = "sigmoid",
                shared_gate: bool = False, expert_act: str = "gated_silu",
+               selection_bias: bool = True,
                name: Optional[str] = None) -> LayerOutput:
     """A dropless mixture of experts of ``size`` hidden units, as the chip
     that holds experts ``experts_held = (first, count)`` of ``num_experts``
@@ -596,8 +643,9 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
     routed over all ``num_experts``: with ``scoring="sigmoid"`` by sigmoid
     scores, the ``top_k`` largest of ``score + expert_bias``; with
     ``scoring="softmax"`` by the softmax over all the router's outputs, the
-    ``top_k`` largest, and the layer has no ``expert_bias``; the weights are
-    normalised over the chosen where ``norm_topk_prob``
+    ``top_k`` largest, and the layer has no ``expert_bias`` (nor has it with
+    ``selection_bias=False``: sigmoid scores chosen as they are); the
+    weights are normalised over the chosen where ``norm_topk_prob``
     (``ops.moe.route_tokens``).  The layer's value is the part of the result
     that the experts held give; no assignment to an expert held is dropped,
     whatever the routing.  On one chip there is no exchange, and nothing
@@ -628,7 +676,7 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
     gated = expert_act == "gated_silu"
     bias = [ParamSpec(f"_{name}.expert_bias", (E,),
                       _pa(None, f"_{name}.expert_bias", init="zeros"))
-            ] if scoring == "sigmoid" else []
+            ] if scoring == "sigmoid" and selection_bias else []
     specs = [
         ParamSpec(f"_{name}.router", (D, E), _fan_in(f"_{name}.router", D)),
         *bias,

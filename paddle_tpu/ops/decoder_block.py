@@ -15,7 +15,10 @@ into super-blocks of keys by ``flash_bwd_key_rows``, and no row leaves the
 kernels for it); elsewhere a loop over blocks of queries in XLA, each block
 against the keys at or before its last row, with the same saved statistics
 (the output and the rows' log-sum-exp) and a backward that recomputes each
-block's scores.
+block's scores.  With ``window=`` a query sees its last ``window`` positions
+alone, and both paths walk the band's blocks and no others
+(``flash_attn_win_fwd`` / ``flash_attn_win_bwd``; in XLA each block of
+queries against the keys from ``window - 1`` before its first row on).
 """
 
 from __future__ import annotations
@@ -24,12 +27,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from paddle_tpu.ops.numerics import acc_dtype, dot_dtype, mxu_cast
 
-__all__ = ["rms_norm", "layer_norm", "unit_norm", "rotary_embedding", "causal_short_conv",
-           "causal_attention", "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
+__all__ = ["rms_norm", "layer_norm", "unit_norm", "rotary_embedding",
+           "yarn_frequencies", "causal_short_conv", "causal_attention",
+           "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
 
 #: queries per block of the XLA path
 ATTN_XLA_BLOCK = 512
@@ -63,24 +68,68 @@ def unit_norm(x, eps: float = 1e-6):
                                + eps)).astype(x.dtype)
 
 
-def rotary_embedding(x, theta: float, rotary_dim=None):
+def rotary_embedding(x, theta: float, rotary_dim=None, *, inv_freq=None,
+                     factor: float = 1.0):
     """x ``[B, T, heads, dh]`` at positions ``0..T-1``: the half-rotation
     form (the first half of a head's channels pairs with the second).
     ``rotary_dim``: turn the first ``rotary_dim`` channels only (paired
-    among themselves) and pass the rest through."""
+    among themselves) and pass the rest through.  ``inv_freq``: the
+    frequencies of the turned channels' pairs, one a pair, in ``theta ** (-2j
+    / width)``'s place (a scaled embedding: :func:`yarn_frequencies`);
+    ``factor`` multiplies cos and sin (YaRN's attention factor)."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         return jnp.concatenate(
-            [rotary_embedding(x[..., :rotary_dim], theta),
+            [rotary_embedding(x[..., :rotary_dim], theta, inv_freq=inv_freq,
+                              factor=factor),
              x[..., rotary_dim:]], axis=-1)
     T, dh = x.shape[1], x.shape[-1]
     f32 = acc_dtype()
-    inv = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
+    else:
+        inv = jnp.asarray(inv_freq, f32)
+        if inv.shape != (dh // 2,):
+            raise ValueError(f"{inv.shape[0]} frequencies for {dh} channels")
     ang = jnp.arange(T, dtype=f32)[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(f32)
     x1, x2 = xf[..., :dh // 2], xf[..., dh // 2:]
     return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+
+
+def yarn_frequencies(width: int, *, rope_theta: float, factor: float,
+                     original_max_position_embeddings: int,
+                     beta_fast: float = 32.0, beta_slow: float = 1.0,
+                     attention_factor=None):
+    """``(inv_freq [width / 2] float32, attention factor)`` of a rotary
+    embedding over ``width`` channels scaled by YaRN, from the numbers of a
+    config's ``rope_parameters`` (``rope_type`` ``yarn``), as HF's
+    ``_compute_yarn_parameters`` makes them.  Pair ``j`` turns at ``f_j =
+    rope_theta ** (-2j / width)`` where it completes more than ``beta_fast``
+    turns over the original context (``j <= low``), at ``f_j / factor``
+    where fewer than ``beta_slow`` (``j >= high``), and on a linear ramp
+    between; ``low = floor(c(beta_fast))``, ``high = ceil(c(beta_slow))``,
+    ``c(n) = width ln(original / (2 pi n)) / (2 ln rope_theta)``, both kept
+    inside ``0 .. width - 1``.  The attention factor multiplies cos and sin
+    (``0.1 ln(factor) + 1`` where the config gives none)."""
+    def pair_of(turns):
+        return (width * np.log(original_max_position_embeddings
+                               / (turns * 2 * np.pi))
+                / (2 * np.log(rope_theta)))
+
+    low = max(int(np.floor(pair_of(beta_fast))), 0)
+    high = min(int(np.ceil(pair_of(beta_slow))), width - 1)
+    plain = float(rope_theta) ** (-np.arange(0, width, 2, dtype=np.float64)
+                                  / width)
+    ramp = np.clip((np.arange(width // 2) - low)
+                   / ((high - low) or 0.001), 0.0, 1.0)
+    inv = plain * (1.0 - ramp) + plain / factor * ramp
+    if attention_factor is None:
+        attention_factor = 0.1 * np.log(factor) + 1.0
+    return inv.astype(np.float32), float(attention_factor)
 
 
 def causal_short_conv(z, kernel, bias=None):
@@ -103,7 +152,8 @@ def causal_short_conv(z, kernel, bias=None):
 # ---------------------------------------------------------------------------
 
 
-def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None):
+def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None,
+                            window=None):
     """The flash kernels' gate: ``(block_q, block_k)`` or ``None`` for the
     XLA path.  ``dh`` is the width of a query and key head, ``dv`` that of a
     value head (``dh`` where not given).  Needs the TPU backend, a row length
@@ -113,8 +163,18 @@ def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None):
     backward keeps resident is reckoned against the kernels' VMEM budget by
     ``pallas_kernels.flash_bwd_key_rows`` (the whole row's ``dk`` and ``dv``
     up to 23k rows at 192/128 and 35k at 64/64 with blocks of 1024, beyond
-    that super-blocks of keys), inside ``flash_attn_bwd_pallas``."""
-    from paddle_tpu.ops.pallas_kernels import compiled_kernels
+    that super-blocks of keys), inside ``flash_attn_bwd_pallas``.
+
+    The band's rule, with ``window``: the kernels visit whole block pairs, so
+    blocks wider than the window would spend most of a visited pair on
+    positions outside it (at blocks of 1024 a window of 512 uses a quarter
+    of each visited pair, at 512 a half): the blocks are the largest that
+    divide the row and are no wider than the window (but 128 at least).
+    The window kernels have no walk over super-blocks of keys: a row too
+    long for the backward to keep whole takes the XLA path, which walks the
+    band too."""
+    from paddle_tpu.ops.pallas_kernels import (compiled_kernels,
+                                               flash_bwd_key_rows)
 
     if not compiled_kernels():
         return None
@@ -122,45 +182,62 @@ def attention_kernel_blocks(T: int, dh: int, H: int, Hkv: int, dv=None):
     if dh % 64 or dv % 64 or H % Hkv:
         return None
     for blk in (1024, 512, 256, 128):
+        if window is not None and blk > max(window, 128):
+            continue
         if T % blk == 0 and T >= 2 * blk:
+            if window is not None and flash_bwd_key_rows(T, dh, dv, blk,
+                                                         blk) < T:
+                return None
             return blk, blk
     return None
 
 
-def _block_mask(keep, i, lo, hi):
-    """What queries ``lo..hi-1`` see of positions ``0..hi-1``: the causal
+def _block_mask(keep, i, lo, hi, first=0, window=None):
+    """What queries ``lo..hi-1`` see of positions ``first..hi-1``: the causal
     mask, or block ``i`` of a selection (``keep``: one bool ``[B, hi - lo,
     hi]`` a block of queries, the same for every head, all at or before the
-    query)."""
+    query), or, with ``window``, the band ``t - window < s <= t``."""
     if keep is not None:
         return keep[i][:, None, None]
-    return jnp.arange(hi)[None, :] <= lo + jnp.arange(hi - lo)[:, None]
+    rows = lo + jnp.arange(hi - lo)[:, None]
+    if window is None:
+        return jnp.arange(hi)[None, :] <= rows
+    cols = jnp.arange(first, hi)[None, :]
+    return (cols <= rows) & (cols > rows - window)
 
 
-def _xla_fwd(q, k, v, scale, block, keep=None):
+def _band_start(lo: int, window) -> int:
+    """The first position any query from ``lo`` on sees."""
+    return 0 if window is None else max(0, lo - window + 1)
+
+
+def _xla_fwd(q, k, v, scale, block, keep=None, window=None):
     """q ``[B, T, Hkv, G, dh]``, k ``[B, T, Hkv, dh]``, v ``[B, T, Hkv, dv]``
     in the compute dtype -> (out ``[B, T, Hkv, G, dv]`` in float32, lse
     ``[B, Hkv, G, T]``).  ``keep``: a selection in the causal mask's place
-    (:func:`_block_mask`; ops/sparse_attention.py)."""
+    (:func:`_block_mask`; ops/sparse_attention.py).  ``window``: the band in
+    its place, each block of queries against the band's keys alone."""
     T = q.shape[1]
     f32 = acc_dtype()
     outs, lses = [], []
     for i, lo in enumerate(range(0, T, block)):
         hi = min(T, lo + block)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, :hi],
+        k0 = _band_start(lo, window)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, k0:hi],
                        preferred_element_type=f32) * scale
-        s = jnp.where(_block_mask(keep, i, lo, hi), s, -jnp.inf)
+        s = jnp.where(_block_mask(keep, i, lo, hi, k0, window), s, -jnp.inf)
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v[:, :hi],
+        o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v[:, k0:hi],
                        preferred_element_type=f32)
         outs.append(o / jnp.moveaxis(l, 3, 1))     # l [b,h,g,q,1]->[b,q,h,g,1]
         lses.append((m + jnp.log(l))[..., 0])
     return jnp.concatenate(outs, axis=1), jnp.concatenate(lses, axis=-1)
 
 
-def _xla_bwd(q, k, v, out, lse, d_out, scale, block, keep=None):
+def _xla_bwd(q, k, v, out, lse, d_out, scale, block, keep=None,
+             window=None):
     T = q.shape[1]
     f32 = acc_dtype()
     delta = jnp.sum(d_out.astype(f32) * out.astype(f32), -1)   # [b,q,h,g]
@@ -170,70 +247,72 @@ def _xla_bwd(q, k, v, out, lse, d_out, scale, block, keep=None):
     do_c = d_out.astype(v.dtype)
     for i, lo in enumerate(range(0, T, block)):
         hi = min(T, lo + block)
-        s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, :hi],
+        k0 = _band_start(lo, window)
+        s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, lo:hi], k[:, k0:hi],
                        preferred_element_type=f32) * scale
-        live = _block_mask(keep, i, lo, hi)
+        live = _block_mask(keep, i, lo, hi, k0, window)
         p = jnp.where(live, jnp.exp(s - lse[..., lo:hi, None]), 0.0)
-        dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_c[:, lo:hi], v[:, :hi],
+        dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_c[:, lo:hi], v[:, k0:hi],
                         preferred_element_type=f32)
         dl = jnp.moveaxis(delta[:, lo:hi], 1, 3)[..., None]    # [b,h,g,q,1]
         ds = (p * (dp - dl) * scale).astype(q.dtype)
-        dq.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, k[:, :hi],
+        dq.append(jnp.einsum("bhgqk,bkhd->bqhgd", ds, k[:, k0:hi],
                              preferred_element_type=f32))
-        dk = dk.at[:, :hi].add(jnp.einsum(
+        dk = dk.at[:, k0:hi].add(jnp.einsum(
             "bhgqk,bqhgd->bkhd", ds, q[:, lo:hi], preferred_element_type=f32))
-        dv = dv.at[:, :hi].add(jnp.einsum(
+        dv = dv.at[:, k0:hi].add(jnp.einsum(
             "bhgqk,bqhgd->bkhd", p.astype(v.dtype), do_c[:, lo:hi],
             preferred_element_type=f32))
     return jnp.concatenate(dq, axis=1), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attention(q, k, v, scale):
-    return _attention_fwd(q, k, v, scale)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attention(q, k, v, scale, window):
+    return _attention_fwd(q, k, v, scale, window)[0]
 
 
-def _attention_fwd(q, k, v, scale):
+def _attention_fwd(q, k, v, scale, window):
     B, T, H, dh = q.shape
     Hkv, dv = k.shape[2], v.shape[3]
     qc, kc, vc = mxu_cast(q, k, v)
     like = tuple(jnp.zeros((0,), a.dtype) for a in (q, k, v))
-    blocks = attention_kernel_blocks(T, dh, H, Hkv, dv)
+    blocks = attention_kernel_blocks(T, dh, H, Hkv, dv, window)
     if blocks is not None:
         from paddle_tpu.ops.pallas_kernels import flash_attn_fwd_pallas
 
         # heads-major: a (head, block of rows) is one contiguous tile
         qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (qc, kc, vc))
         oh, lse = flash_attn_fwd_pallas(qh, kh, vh, scale=scale,
-                                        block_q=blocks[0], block_k=blocks[1])
+                                        block_q=blocks[0], block_k=blocks[1],
+                                        window=window)
         # kept across a recomputation block: the backward's second forward
         # recomputes the projections, not the attention
         oh, lse = (checkpoint_name(a, "remat_keep") for a in (oh, lse))
         out = jnp.swapaxes(oh, 1, 2)
         return out.astype(dot_dtype()), (qh, kh, vh, oh, lse, like)
     qg = qc.reshape(B, T, Hkv, H // Hkv, dh)
-    out, lse = _xla_fwd(qg, kc, vc, scale, ATTN_XLA_BLOCK)
+    out, lse = _xla_fwd(qg, kc, vc, scale, ATTN_XLA_BLOCK, window=window)
     return (out.reshape(B, T, H, dv).astype(dot_dtype()),
             (qg, kc, vc, out, lse, like))
 
 
-def _attention_bwd(scale, res, d_out):
+def _attention_bwd(scale, window, res, d_out):
     q, k, v, out, lse, like = res
     if out.ndim == 4:       # the kernels' heads-major residuals
         from paddle_tpu.ops.pallas_kernels import flash_attn_bwd_pallas
 
         blocks = attention_kernel_blocks(q.shape[2], q.shape[3], q.shape[1],
-                                         k.shape[1], v.shape[3])
+                                         k.shape[1], v.shape[3], window)
         do = jnp.swapaxes(d_out, 1, 2).astype(q.dtype)
         dq, dk, dv = flash_attn_bwd_pallas(
             q, k, v, out, lse, do, scale=scale, block_q=blocks[0],
-            block_k=blocks[1])
+            block_k=blocks[1], window=window)
         return tuple(jnp.swapaxes(a, 1, 2).astype(z.dtype)
                      for a, z in zip((dq, dk, dv), like))
     B, T, Hkv, G, dh = q.shape
     dq, dk, dv = _xla_bwd(q, k, v, out, lse,
                           d_out.reshape(B, T, Hkv, G, v.shape[3]), scale,
-                          ATTN_XLA_BLOCK)
+                          ATTN_XLA_BLOCK, window=window)
     return tuple(a.astype(z.dtype) for a, z in zip(
         (dq.reshape(B, T, Hkv * G, dh), dk, dv), like))
 
@@ -241,12 +320,21 @@ def _attention_bwd(scale, res, d_out):
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-def causal_attention(q, k, v, *, scale: float):
+def causal_attention(q, k, v, *, scale: float, window=None):
     """Causal softmax attention with grouped key-value heads: q ``[B, T, H,
     dh]``, k ``[B, T, Hkv, dh]``, v ``[B, T, Hkv, dv]`` (key-value head ``j``
     serves query heads ``j*G .. j*G+G-1``, ``G = H / Hkv``) -> ``[B, T, H,
     dv]``.  The values' width is their own: latent attention scores with
     192-wide queries and keys and sums 128-wide values.  bf16 operands under
     the default policy, float32 scores and statistics; the scores exist one
-    block at a time, forward and backward."""
-    return _attention(q, k, v, float(scale))
+    block at a time, forward and backward.  ``window``: query ``t`` sees
+    positions ``t - window < s <= t`` alone (its own position counts); what
+    lies outside contributes exactly nothing, forward and backward, and a
+    window no shorter than the row is no window."""
+    if window is not None:
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"a window of {window} positions")
+        if window >= q.shape[1]:
+            window = None
+    return _attention(q, k, v, float(scale), window)

@@ -32,7 +32,11 @@ sets takes part:
 - Causal flash attention, forward (``flash_attn_fwd``) and one backward
   (``flash_attn_bwd``: dq, dk and dv from one set of probabilities).  Gate:
   ``ops/decoder_block.attention_kernel_blocks``; how many keys' ``dk`` and
-  ``dv`` a backward call keeps in VMEM: :func:`flash_bwd_key_rows`.
+  ``dv`` a backward call keeps in VMEM: :func:`flash_bwd_key_rows`.  Under a
+  window (a query sees its last ``window`` positions) the same two kernels
+  are ``flash_attn_win_fwd`` / ``flash_attn_win_bwd``: the key axis of their
+  grids is the band of a block of queries (:func:`flash_band_blocks`) and
+  starts at the band's first block.
 - Grouped matrix products over experts.  Gate:
   ``ops/moe.moe_kernel_row_tile``.
 - The gated delta rule's chunked scan, forward (``gdn_chunk_fwd``) and
@@ -87,7 +91,7 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "attn_dec_fwd_pallas", "attn_dec_bwd_pallas",
            "topk_lse_readout_pallas", "topk_lse_logits_pallas", "TOPK_LANES",
            "flash_attn_fwd_pallas", "flash_attn_bwd_pallas",
-           "flash_bwd_key_rows",
+           "flash_bwd_key_rows", "flash_band_blocks",
            "gmm_pallas", "tgmm_pallas",
            "gdn_chunk_fwd_pallas", "gdn_chunk_bwd_pallas",
            "gdn_prep_fwd_pallas", "gdn_prep_bwd_pallas", "GDN_PREP_HALO",
@@ -1604,6 +1608,11 @@ def topk_lse_logits_pallas(logits, *, vocab: int, k: int, row_block: int,
 # resident dq is G x [T, dh], 8 and 16; the walk then fetches a key and a
 # value block a step (0.64 MiB) and not a query, an output and a gradient
 # block (0.9).  No partial sum crosses HBM.
+# Under a window the key axis of both grids is the band of a block of queries
+# and no more (a skipped grid step still costs its fixed time): step ``j`` of
+# a block of queries is key block ``first block of its band + j``, clamped to
+# the diagonal's and skipped past it; inside a visited pair the mask is the
+# band's, ``t - window < s <= t``.
 
 #: scoped VMEM the attention kernels ask for (score tiles of 1024 x 1024
 #: float32 and their bf16 copies, beside the operand blocks and, in the
@@ -1628,15 +1637,44 @@ def _selected_scores(q, k, keep, *, scale):
     return jnp.where(keep.astype(jnp.int32) != 0, s, -jnp.inf)
 
 
+def _band_scores(q, k, qi, kj, *, scale, block_q, block_k, window):
+    """The scores of one block pair under a window: query ``t`` sees the
+    ``window`` positions ``t - window < s <= t`` (its own among them)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((cols <= rows) & (cols > rows - window), s, -jnp.inf)
+
+
+def _band_first_block(qi, *, block_q, block_k, window):
+    """The first block of keys that a block of queries sees under a
+    window (``qi`` a Python int or a traced index)."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def flash_band_blocks(T: int, block_q: int, block_k: int, window: int) -> int:
+    """Blocks of keys in the band of a block of queries, the widest over the
+    row: the key axis of the window kernels' grids (2 at blocks of 512 or
+    1024 under a window of 512, 3 at 256)."""
+    return max((qi * block_q + block_q - 1) // block_k
+               - max(qi * block_q - (window - 1), 0) // block_k + 1
+               for qi in range(T // block_q))
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
-                      selected=False):
+                      selected=False, window=None):
     from jax.experimental import pallas as pl
 
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi, kj = pl.program_id(2), pl.program_id(3)
+    step = kj          # the walk's step; ``kj`` is the key block of the row
+    if window is not None:
+        kj = step + _band_first_block(qi, block_q=block_q, block_k=block_k,
+                                      window=window)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -1646,12 +1684,16 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
     def _block():
         v = v_ref[0, 0]
         m_old = m_scr[...]
-        if selected:
-            # a query may keep nothing in its first blocks of keys: its
-            # running maximum is then still -inf, and the exponentials are
-            # taken against 0 (they are 0 either way)
-            s = _selected_scores(q_ref[0, 0], k_ref[0, 0], keep_ref[0],
-                                 scale=scale)
+        if selected or window is not None:
+            # a query may keep nothing in its first blocks of keys (under a
+            # window: the band's first block holds nothing of the block's
+            # last queries): its running maximum is then still -inf, and the
+            # exponentials are taken against 0 (they are 0 either way)
+            s = (_selected_scores(q_ref[0, 0], k_ref[0, 0], keep_ref[0],
+                                  scale=scale) if selected
+                 else _band_scores(q_ref[0, 0], k_ref[0, 0], qi, kj,
+                                   scale=scale, block_q=block_q,
+                                   block_k=block_k, window=window))
             m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
             m_ref = jnp.where(m_new == -jnp.inf, 0.0, m_new)
         else:
@@ -1666,31 +1708,41 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    @pl.when(kj == pl.num_programs(3) - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _fin():
         o_ref[0, 0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
         lse_ref[0, 0] = m_scr[...] + jnp.log(l_scr[...])
 
 
 def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
-                          block_k: int, keep=None):
+                          block_k: int, keep=None, window=None):
     """-> (out [B, H, T, dv] in q's dtype, lse [B, H, T, 1] float32).
     ``keep`` ``[B, T, T]`` int8: the selection (1 where query ``t`` sees
     position ``s``, all with ``s <= t``, at least one a query, the same for
     every head); the kernel is then ``flash_attn_sel_fwd`` and reads a tile
-    of it beside every block pair it visits."""
+    of it beside every block pair it visits.  ``window``: query ``t`` sees
+    positions ``t - window < s <= t``; the kernel is then
+    ``flash_attn_win_fwd``, its grid's key axis the band of a block of
+    queries (:func:`flash_band_blocks`) from the band's first block on."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if keep is not None and window is not None:
+        raise ValueError("a window under a selection has no kernel")
     B, H, T, dh = q.shape
     dv = v.shape[3]
     G = H // k.shape[1]
     nq, nk = T // block_q, T // block_k
+    if window is not None:
+        nk = flash_band_blocks(T, block_q, block_k, window)
 
     def last_block(qi):
         return (qi * block_q + block_q - 1) // block_k
 
     def kv_map(b, h, qi, kj):
+        if window is not None:
+            kj = kj + _band_first_block(qi, block_q=block_q, block_k=block_k,
+                                        window=window)
         return (b, h // G, jnp.minimum(kj, last_block(qi)), 0)
 
     selection = [] if keep is None else [pl.BlockSpec(
@@ -1698,7 +1750,7 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
         lambda b, h, qi, kj: (b, qi, jnp.minimum(kj, last_block(qi))))]
     kernel = functools.partial(_flash_fwd_kernel, scale=scale,
                                block_q=block_q, block_k=block_k,
-                               selected=keep is not None)
+                               selected=keep is not None, window=window)
     spec = dict(
         grid=(B, H, nq, nk),
         in_specs=[
@@ -1721,6 +1773,9 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
                                  "arbitrary"),
             vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
         interpret=_interpret())
+    if window is not None:
+        return pl.pallas_call(kernel, name="flash_attn_win_fwd", **spec)(
+            q, k, v)
     if keep is None:
         return pl.pallas_call(kernel, name="flash_attn_fwd", **spec)(q, k, v)
     return pl.pallas_call(kernel, name="flash_attn_sel_fwd", **spec)(
@@ -1730,10 +1785,10 @@ def flash_attn_fwd_pallas(q, k, v, *, scale: float, block_q: int,
 def flash_bwd_key_rows(T: int, dh: int, dv: int, block_q: int,
                        block_k: int) -> int:
     """Rows of keys whose ``dk`` and ``dv`` one ``flash_attn_bwd`` call keeps
-    resident in VMEM: the whole row where that fits the kernels' budget (both
-    benchmark cells' 8192; up to 23k rows at 192/128 and 35k at 64/64 with
-    blocks of 1024), else the row cut into equal super-blocks of whole key
-    blocks.  Counted against ``FLASH_VMEM_LIMIT_BYTES``: the resident pair in
+    resident in VMEM: the whole row where that fits the kernels' budget (the
+    benchmark cells' rows of 4096, 8192 and 16384; up to 23k rows at 192/128
+    and 35k at 64/64 or 128/128 with blocks of 1024), else the row cut into
+    equal super-blocks of whole key blocks.  Counted against ``FLASH_VMEM_LIMIT_BYTES``: the resident pair in
     float32 with lanes padded to 128, twice (as if the pipeline kept two
     buffers of an output block), the block pair's four float32 score tiles
     and four bf16 ones (``p``, ``ds`` and their transposes), and the operand,
@@ -1754,12 +1809,16 @@ def flash_bwd_key_rows(T: int, dh: int, dv: int, block_q: int,
 
 
 def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k,
-                 keep=None):
+                 keep=None, window=None):
     """(p, ds) of one block pair from the saved statistics, float32."""
     f32 = jnp.float32
-    s = (_causal_scores(q, k, qi, kj, scale=scale, block_q=block_q,
-                        block_k=block_k) if keep is None
-         else _selected_scores(q, k, keep, scale=scale))
+    if window is not None:
+        s = _band_scores(q, k, qi, kj, scale=scale, block_q=block_q,
+                         block_k=block_k, window=window)
+    else:
+        s = (_causal_scores(q, k, qi, kj, scale=scale, block_q=block_q,
+                            block_k=block_k) if keep is None
+             else _selected_scores(q, k, keep, scale=scale))
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=f32)
@@ -1769,27 +1828,31 @@ def _flash_probs(q, k, v, o, do, lse, qi, kj, *, scale, block_q, block_k,
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
                       scale, block_q, block_k, n_q, q_first, k_first,
-                      selected=False):
+                      selected=False, window=None):
     """One (block of queries, block of keys) of the backward: the pair's
     probabilities and ``ds`` are made once and feed all three gradients.
     ``dq`` of the block of queries accumulates in its output block over the
     walk along the keys; ``dk`` and ``dv`` of ALL the call's keys stay in
     their output blocks over both inner axes, and every query block (of
     every head of the group, in turn) adds its part at its key block's
-    rows."""
+    rows.  Under a ``window`` the walk starts at the band's first block."""
     from jax.experimental import pallas as pl
 
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     dq_ref, dk_ref, dv_ref = rest
-    t, kj = pl.program_id(2), pl.program_id(3)
-    qi, kg = q_first + t % n_q, k_first + kj    # blocks of the whole row
+    t, step = pl.program_id(2), pl.program_id(3)
+    qi, kj = q_first + t % n_q, step
+    if window is not None:      # the call holds the whole row's keys
+        kj = step + _band_first_block(qi, block_q=block_q, block_k=block_k,
+                                      window=window)
+    kg = k_first + kj                           # blocks of the whole row
 
-    @pl.when((t == 0) & (kj == 0))
+    @pl.when((t == 0) & (step == 0))
     def _zero():
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_ref[...] = jnp.zeros_like(dq_ref)
 
@@ -1799,7 +1862,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
         p, ds = _flash_probs(q, k, v_ref[0, 0], o_ref[0, 0], do,
                              lse_ref[0, 0], qi, kg, scale=scale,
                              block_q=block_q, block_k=block_k,
-                             keep=keep_ref[0] if selected else None)
+                             keep=keep_ref[0] if selected else None,
+                             window=window)
         ds = ds.astype(q.dtype)
         contract_rows = (((0,), (0,)), ((), ()))
         keys = pl.ds(pl.multiple_of(kj * block_k, block_k), block_k)
@@ -1812,7 +1876,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest,
 
 
 def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
-                          block_q: int, block_k: int, keep=None):
+                          block_q: int, block_k: int, keep=None,
+                          window=None):
     """-> (dq [B, H, T, dh], dk [B, Hkv, T, dh], dv [B, Hkv, T, dv]),
     float32; ``o`` and ``do`` are [B, H, T, dv].  ONE kernel,
     ``flash_attn_bwd``, that visits each live block pair once, recomputes
@@ -1825,13 +1890,19 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
     into super-blocks, one call each over the queries at or after its first
     key, and the calls' ``dq`` are summed here.  ``keep``: the selection
     the forward ran under (``flash_attn_fwd_pallas``); the kernel is then
-    ``flash_attn_sel_bwd``."""
+    ``flash_attn_sel_bwd``.  ``window``: the forward's; the kernel is then
+    ``flash_attn_win_bwd``, the walk along the keys of a block of queries
+    is its band's blocks alone, and the row has to be one the call keeps
+    whole (``decoder_block.attention_kernel_blocks`` sees to that)."""
     from jax.experimental import pallas as pl
 
     B, H, T, dh = q.shape
     Hkv, dv = k.shape[1], v.shape[3]
     G = H // Hkv
     key_rows = flash_bwd_key_rows(T, dh, dv, block_q, block_k)
+    if window is not None and (keep is not None or key_rows < T):
+        raise ValueError(f"no window kernel under a selection, or for a row "
+                         f"of {T} cut into super-blocks of {key_rows} keys")
 
     def part(k_lo, k_hi):
         """The gradients of keys ``k_lo..k_hi`` and what they add to the
@@ -1839,12 +1910,18 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
         k_first, q_first = k_lo // block_k, k_lo // block_q
         q_lo = q_first * block_q
         nq, nk = (T - q_lo) // block_q, (k_hi - k_lo) // block_k
+        if window is not None:
+            nk = flash_band_blocks(T, block_q, block_k, window)
 
         def q_map(b, hk, t, kj):
             return (b, hk * G + t // nq, q_first + t % nq, 0)
 
         def key_block(t, kj):
-            last = ((q_first + t % nq) * block_q + block_q - 1) // block_k
+            qi = q_first + t % nq
+            if window is not None:
+                kj = kj + _band_first_block(qi, block_q=block_q,
+                                            block_k=block_k, window=window)
+            last = (qi * block_q + block_q - 1) // block_k
             return jnp.minimum(k_first + kj, last)
 
         def kv_map(b, hk, t, kj):
@@ -1864,7 +1941,7 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
         kernel = functools.partial(
             _flash_bwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
             n_q=nq, q_first=q_first, k_first=k_first,
-            selected=keep is not None)
+            selected=keep is not None, window=window)
         spec = dict(
             grid=(B, Hkv, G * nq, nk),
             in_specs=[q_spec, pl.BlockSpec((1, 1, block_k, dh), kv_map),
@@ -1883,6 +1960,9 @@ def flash_attn_bwd_pallas(q, k, v, o, lse, do, *, scale: float,
                                      "arbitrary"),
                 vmem_limit_bytes=FLASH_VMEM_LIMIT_BYTES),
             interpret=_interpret())
+        if window is not None:
+            return pl.pallas_call(kernel, name="flash_attn_win_bwd", **spec)(
+                q, k, v, o, do, lse)
         if keep is None:
             return pl.pallas_call(kernel, name="flash_attn_bwd", **spec)(
                 q, k, v, o, do, lse)

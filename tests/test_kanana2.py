@@ -495,7 +495,7 @@ def test_custom_vjp_takes_the_kernels_where_the_gate_opens(monkeypatch):
 
     want, want_g = run()
     monkeypatch.setattr(DB, "attention_kernel_blocks",
-                        lambda T, dh, H, Hkv, dv=None: (128, 128))
+                        lambda T, dh, H, Hkv, dv=None, window=None: (128, 128))
     text = str(jax.make_jaxpr(lambda: run())())
     assert text.count("flash_attn_fwd") == text.count("flash_attn_bwd") == 1
     assert "flash_attn_dq" not in text and "flash_attn_dkv" not in text

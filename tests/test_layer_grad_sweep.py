@@ -652,6 +652,17 @@ def case_causal_self_attention(rng):
                                     num_kv_heads=2, head_dim=2), feed
 
 
+def case_window_self_attention(rng):
+    # a window of 2 positions in a row of up to 5, a head-wise gate, no head
+    # norms, the rotary embedding under YaRN on half a head (PR 50)
+    xs, feed = _seq(rng)
+    return nn.causal_self_attention(
+        _pre_fc(xs, size=8), num_heads=4, num_kv_heads=2, head_dim=4,
+        qk_norm=False, output_gate="head", rotary_dim=2, window=2,
+        rope_scaling=dict(rope_type="yarn", rope_theta=100.0, factor=4.0,
+                          original_max_position_embeddings=2)), feed
+
+
 def case_indexed_self_attention(rng):
     # attention over the 3 positions an indexer of 2 heads of 4 keeps of a
     # row of up to 5 (PR 47): the value's gradient reaches every leaf but the

@@ -1118,6 +1118,91 @@ def test_keye_cell_step_fits_the_chip(one_chip, on_tpu):
     assert _held_bytes(compiled) < 15.5e9, _held_bytes(compiled)
 
 
+#: Laguna-XS.2's window layers at the cell's row (benchmark/configs/
+#: laguna-xs.2-ep32.json): 64 query heads over 8 key-value heads of 128, a
+#: window of 512
+LAGUNA = dict(T=16384, H=64, Hkv=8, dh=128, window=512)
+
+
+def _kernel_calls(text: str) -> dict:
+    """kernel name -> Mosaic calls of that name in a compiled module."""
+    calls = re.findall(r"%\S+ = [^\n]*? custom-call\([^\n]*?"
+                       r'custom_call_target="tpu_custom_call"[^\n]*?'
+                       r'op_name="([^"]*)"', text)
+    by_kernel = {}
+    for op_name in calls:
+        kernel = op_name.rsplit("/", 2)[-2]
+        by_kernel[kernel] = by_kernel.get(kernel, 0) + 1
+    return by_kernel
+
+
+def test_window_attention_kernels(one_chip, on_tpu):
+    """The gate's band rule gives the cell's window layers blocks of 512
+    (1024 without a window), and the two window kernels compile at 64 query
+    heads over 8 key-value heads of 128 and a row of 16384 (dk and dv of
+    16384 keys resident at 128/128), their grids holding the band's blocks
+    alone: 2 key blocks a block of queries where the unwindowed kernels at
+    the same blocks would walk 32 (a count of grid steps, not a timing)."""
+    from paddle_tpu.ops import decoder_block as DB
+    from paddle_tpu.ops import pallas_kernels as PK
+
+    c = LAGUNA
+    assert DB.attention_kernel_blocks(c["T"], c["dh"], c["H"],
+                                      c["Hkv"]) == (1024, 1024)
+    assert DB.attention_kernel_blocks(c["T"], c["dh"], c["H"], c["Hkv"],
+                                      window=c["window"]) == (512, 512)
+    assert PK.flash_bwd_key_rows(c["T"], c["dh"], c["dh"], 512,
+                                 512) == c["T"]
+
+    def loss(q, k, v):
+        return DB.causal_attention(q, k, v, scale=c["dh"] ** -0.5,
+                                   window=c["window"]).sum()
+
+    step = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    q = _struct(one_chip, (1, c["T"], c["H"], c["dh"]))
+    kv = _struct(one_chip, (1, c["T"], c["Hkv"], c["dh"]))
+    grids = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(step)(q, kv, kv).jaxpr)
+    nq, G = c["T"] // 512, c["H"] // c["Hkv"]
+    assert grids == {"flash_attn_win_fwd": (1, c["H"], nq, 2),
+                     "flash_attn_win_bwd": (1, c["Hkv"], G * nq, 2)}
+    text = jax.jit(step).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_attn_win_fwd" in text and "flash_attn_win_bwd" in text
+
+
+def test_laguna_cell_step_fits_the_chip(one_chip, on_tpu):
+    """The cell ``lagunaxs2-train-b1-t16384``'s whole step compiled for the
+    described v5e from shapes alone: the window kernels ONCE a window layer
+    and the unwindowed flash kernels once a full layer (their results are
+    kept across the recomputation block), the grouped products beside them,
+    and the compiler's own count of arguments, results and temporaries
+    under 16.3 GB of the chip's 16.9 (16.01 read, PR 50; the chip's own peak
+    read 14.67: as on Keye's cell, the expert layer's worst-routing branch is
+    reserved and not run)."""
+    compiled = _cell_step(one_chip, "lagunaxs2-train-b1-t16384")
+    by_kernel = _kernel_calls(compiled.as_text())
+    assert {k: by_kernel.get(k) for k in (
+        "flash_attn_win_fwd", "flash_attn_win_bwd", "flash_attn_fwd",
+        "flash_attn_bwd")} == {
+            "flash_attn_win_fwd": 3, "flash_attn_win_bwd": 3,
+            "flash_attn_fwd": 2, "flash_attn_bwd": 2}
+    assert "flash_attn_sel_fwd" not in by_kernel
+    assert "moe_gmm" in by_kernel and "moe_tgmm" in by_kernel
+    m = compiled.memory_analysis()
+    assert 3 * 4 * 389_634_048 < m.argument_size_in_bytes     # p, m, v
+    assert _held_bytes(compiled) < 16.3e9, _held_bytes(compiled)
+
+
 def _instructions(text: str, scope: str):
     """``(op, result, operand shapes, op_name)`` of the compiled module's
     ``gather``, ``scatter`` and ``sort`` instructions and ``kCustom`` fusions
@@ -1146,7 +1231,8 @@ def _instructions(text: str, scope: str):
 
 @pytest.mark.parametrize("cell,tokens,k,layers", [
     ("qwen3next-train-b1-t8192", 8192, 10, 4),
-    ("keyevl2-train-b1-t16384", 16384, 8, 4)])
+    ("keyevl2-train-b1-t16384", 16384, 8, 4),
+    ("lagunaxs2-train-b1-t16384", 16384, 8, 4)])
 def test_expert_cell_step_walks_no_assignments(cell, tokens, k, layers,
                                                one_chip, on_tpu):
     """PR 49.  A gather or a scatter of scalars runs on the chip as a walk,

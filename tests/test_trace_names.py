@@ -25,8 +25,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
            "lse_rows", "attn_dec_fwd", "attn_dec_bwd", "ce_readout_fwd",
            "ce_readout_bwd", "topk_lse_readout", "topk_lse_logits",
-           "flash_attn_fwd", "flash_attn_sel_fwd", "flash_attn_bwd",
-           "flash_attn_sel_bwd", "moe_gmm", "moe_tgmm",
+           "flash_attn_win_fwd", "flash_attn_fwd", "flash_attn_sel_fwd",
+           "flash_attn_win_bwd", "flash_attn_bwd", "flash_attn_sel_bwd",
+           "moe_gmm", "moe_tgmm",
            "gdn_chunk_fwd", "gdn_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd",
            "gdn_prep_fwd", "gdn_prep_bwd", "mamba_prep_fwd", "mamba_prep_bwd",
            "indexer_scores", "topk_select", "indexer_loss"]
@@ -206,6 +207,50 @@ def test_keye_vl2_step_holds_its_layers_scopes():
             "indexer_loss", "moe0", "moe1", "moe_routing", "moe_experts",
             "norm_op0", "norm_ffn1", "norm_out", "cost"} <= names
     assert not {"mlp0", "moe_shared", "norm0"} & names
+
+
+def test_laguna_step_holds_its_layers_scopes():
+    """The scopes the Laguna-XS.2 cell's per-layer metrics read (PR 50): a
+    layer's own (``attn<i>``, ``mlp0``, ``moe<i>``) and, inside ``attn<i>``,
+    ``attn_core`` in a full layer and ``attn_window`` in a window layer,
+    forward and backward; the window layers alone bring a counter."""
+    from paddle_tpu.models import laguna_net
+
+    nn.reset_naming()
+    cost, extras = laguna_net(
+        50, hidden_size=16,
+        layer_types=["full_attention", "sliding_attention",
+                     "full_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse"],
+        num_attention_heads_per_layer=[2, 4, 2], num_key_value_heads=2,
+        head_dim=4, sliding_window=3,
+        rope_parameters={
+            "full_attention": dict(
+                rope_theta=100.0, rope_type="yarn", factor=4.0,
+                original_max_position_embeddings=4, beta_slow=1,
+                beta_fast=4, partial_rotary_factor=0.5),
+            "sliding_attention": dict(rope_type="default", rope_theta=1e4,
+                                      partial_rotary_factor=1)},
+        intermediate_size=24, moe_intermediate_size=8,
+        shared_expert_intermediate_size=8, num_experts=4,
+        num_experts_per_tok=2, moe_routed_scaling_factor=2.5)
+    assert [e.name for e in extras if "attn" in e.name] == ["attn1_pairs"]
+    topo = nn.Topology([cost] + extras)
+    params, _ = topo.init(jax.random.PRNGKey(0))
+    ids = (np.ones((2, 6), np.int32), np.full((2,), 6, np.int32))
+    feed = {"tokens": ids, "next_tokens": ids}
+    grad = jax.make_jaxpr(jax.grad(lambda p: topo.apply(
+        p, {}, feed, train=True)[0]["cost"].value))(params)
+    stacks = _name_stacks(grad.jaxpr)
+    names = _scope_names(stacks)
+    assert {"attn0", "attn1", "attn2", "attn_core", "attn_window", "mlp0",
+            "moe1", "moe2", "moe_routing", "moe_experts", "moe_shared",
+            "norm_op0", "norm_ffn2", "norm_out", "cost"} <= names
+    assert not {"moe0", "mlp1", "indexer", "norm0"} & names
+    # the window's core under the window layer alone, the full core under
+    # the full layers alone
+    assert not [s for s in stacks if "attn_window" in s and "attn1" not in s]
+    assert not [s for s in stacks if "attn_core" in s and "attn1" in s]
 
 
 def _primitives_under(jaxpr, scope, inside=False, out=None):

@@ -152,7 +152,11 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
     token, ``sigmoid(x W_g)`` with ``W_g`` ``[D, H]`` (leaf ``wg``).
     ``qk_norm=False``: no norm over the heads, and the layer has
     no ``q_norm`` / ``k_norm`` leaves; ``rotary=False``: the layer takes no
-    positions (a model whose other mixers carry the order).
+    positions (a model whose other mixers carry the order).  The rotary
+    embedding's pass over q and over k runs under the scope ``rotary``
+    inside the layer's own (``ops.decoder_block.rotary_embedding``: one pass
+    over whole heads, the channels a ``rotary_dim`` passes through riding in
+    it).
 
     ``window``: a query sees its last ``window`` positions alone, its own
     among them (``ops.causal_attention``); the core then runs under the
@@ -263,7 +267,8 @@ def indexed_self_attention(input: LayerOutput, *, num_heads: int,
     Scopes inside the layer's own: ``indexer`` (the indexer's projections,
     norm, rotary and scores), ``topk_select``, ``attn_core`` (the selected
     attention) and ``indexer_loss`` (the target, the KL and its gradient
-    into the scores)."""
+    into the scores); ``rotary`` (every rotary pass: the main heads' in the
+    layer's scope, the indexer's inside ``indexer``)."""
     name = name or next_name("indexed_attention")
     if num_heads % num_kv_heads:
         raise ConfigError(f"{name!r}: {num_heads} query heads are not whole "
@@ -331,14 +336,18 @@ def latent_attention(input: LayerOutput, *, num_heads: int,
     (``qk_nope_head_dim``) and ``v`` (``v_head_dim``); ``k_rope``
     (``qk_rope_head_dim``) is ONE head that every query head shares.  Queries
     are ``x W_q`` split per head into ``q_nope | q_rope`` (no query latent).
-    The rotary embedding turns ``q_rope`` and ``k_rope`` only; scores are
+    The rotary embedding turns ``q_rope`` and ``k_rope`` only: on the last
+    ``qk_rope_head_dim`` channels of the whole query head, in place (the
+    span ``(qk_nope_head_dim, qk_nope_head_dim + qk_rope_head_dim)`` of
+    ``ops.decoder_block.rotary_embedding``: no slice, no concatenation), and
+    on the lone key head before it is broadcast; scores are
     ``[q_nope | q_rope] . [k_nope | k_rope]`` at scale ``(qk_nope_head_dim
     + qk_rope_head_dim) ** -0.5``; no bias, no QK-norm.
 
     Scopes inside the layer's own: ``mla_proj`` (the query projection, the
-    down- and up-projection, the latent's norm, rotary, assembling q and k)
-    and ``attn_core`` (``causal_attention`` with keys wider than values);
-    the output projection is the rest."""
+    down- and up-projection, the latent's norm, rotary under its own scope
+    ``rotary``, assembling k) and ``attn_core`` (``causal_attention`` with
+    keys wider than values); the output projection is the rest."""
     name = name or next_name("latent_attention")
     D, H = input.size, num_heads
     r, dn, dr, dv = (kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
@@ -369,9 +378,7 @@ def latent_attention(input: LayerOutput, *, num_heads: int,
             k_rope = DB.rotary_embedding(
                 ckv[..., r:].reshape(B, T, 1, dr), rope_theta)
             kv = O.linear(c, p["wkv_b"]).reshape(B, T, H, dn + dv)
-            q = jnp.concatenate(
-                [q[..., :dn], DB.rotary_embedding(q[..., dn:], rope_theta)],
-                axis=-1)
+            q = DB.rotary_embedding(q, rope_theta, span=(dn, dn + dr))
             k = jnp.concatenate(
                 [kv[..., :dn], jnp.broadcast_to(k_rope, (B, T, H, dr))],
                 axis=-1)

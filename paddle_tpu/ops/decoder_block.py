@@ -19,11 +19,18 @@ block's scores.  With ``window=`` a query sees its last ``window`` positions
 alone, and both paths walk the band's blocks and no others
 (``flash_attn_win_fwd`` / ``flash_attn_win_bwd``; in XLA each block of
 queries against the keys from ``window - 1`` before its first row on).
+
+The rotary embedding is one elementwise pass over whole heads, ``x * C +
+partner(x) * S``, forward and (the sine negated) backward: on the TPU the
+kernel ``rotary_turn``, which finds a channel's partner by a lane roll in
+registers (:func:`rotary_kernel_blocks`), elsewhere the same expression in
+XLA.  No half of a head is sliced off and nothing is concatenated.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +40,8 @@ from jax.ad_checkpoint import checkpoint_name
 from paddle_tpu.ops.numerics import acc_dtype, dot_dtype, mxu_cast
 
 __all__ = ["rms_norm", "layer_norm", "unit_norm", "rotary_embedding",
-           "yarn_frequencies", "causal_short_conv", "causal_attention",
-           "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
+           "rotary_kernel_blocks", "yarn_frequencies", "causal_short_conv",
+           "causal_attention", "attention_kernel_blocks", "ATTN_XLA_BLOCK"]
 
 #: queries per block of the XLA path
 ATTN_XLA_BLOCK = 512
@@ -68,36 +75,136 @@ def unit_norm(x, eps: float = 1e-6):
                                + eps)).astype(x.dtype)
 
 
-def rotary_embedding(x, theta: float, rotary_dim=None, *, inv_freq=None,
-                     factor: float = 1.0):
+def _partner(x, span):
+    """Channel ``j``'s partner in the half-rotation at channel ``j``: ``j +
+    rd/2`` on the first half of ``span`` ``(a, b)``, ``j - rd/2`` on its
+    second (``rd = b - a``); outside the span whatever the rolls bring, which
+    the sine's table multiplies by zero.  No slice of a head is made."""
+    a, b = span
+    dh, half = x.shape[-1], (b - a) // 2
+    if b - a == dh:                   # a whole head: one roll by half of it
+        return jnp.roll(x, half, axis=-1)
+    first = jnp.arange(dh) < a + half
+    return jnp.where(first, jnp.roll(x, -half, axis=-1),
+                     jnp.roll(x, half, axis=-1))
+
+
+def rotary_kernel_blocks(T: int, H: int, dh: int):
+    """The rotary kernel's gate: ``(heads_major, L, block_rows, block_lanes)``
+    or ``None`` for the XLA form.  The kernel turns slabs of ``L`` lanes that
+    hold whole heads.  Heads of whole lane tiles (128, 256) are a slab each
+    and go in heads-major, ``[B, H, T, dh]``, the layout the flash kernels
+    read and write; any other width goes in as the token-major ``[B, T, H
+    dh]`` with ``L = lcm(dh, 128)`` (two heads of 64 a slab; two of 192 on
+    three tiles), which needs the heads to fill whole slabs: a lone head of
+    64 keeps to XLA.  Needs the TPU backend and a row that blocks of 16
+    rows or more divide (whole sublane tiles of float32 and of bfloat16); a
+    block is the most slabs within 2 MB of float32 that divide the heads."""
+    from paddle_tpu.ops.pallas_kernels import compiled_kernels
+
+    if not compiled_kernels():
+        return None
+    heads_major = dh % 128 == 0
+    L = dh if heads_major else math.lcm(dh, 128)
+    slabs, part = divmod(H * dh, L)
+    if part:
+        return None
+    for rows in (512, 256, 128, 64, 32, 16):
+        per = [n for n in range(1, slabs + 1)
+               if slabs % n == 0 and n * L * rows * 4 <= 2 << 20]
+        if T % rows == 0 and per:
+            return heads_major, L, rows, per[-1] * L
+    return None
+
+
+def _turned(x, cos, sin, span):
+    """``x * cos + partner(x) * sin`` over the whole last axis, in float32,
+    rounded to ``x``'s dtype: one elementwise pass, the kernel
+    ``rotary_turn`` where :func:`rotary_kernel_blocks` opens."""
+    B, T, H, dh = x.shape
+    with jax.named_scope("rotary"):
+        blocks = rotary_kernel_blocks(T, H, dh)
+        if blocks is None:
+            xf = x.astype(acc_dtype())
+            return (xf * cos[None, :, None, :] + _partner(xf, span)
+                    * sin[None, :, None, :]).astype(x.dtype)
+        from paddle_tpu.ops.pallas_kernels import rotary_pallas
+
+        heads_major, L, rows, lanes = blocks
+        view = (jnp.swapaxes(x, 1, 2) if heads_major
+                else x.reshape(B, T, H * dh))
+        out = rotary_pallas(
+            view, jnp.tile(cos, (1, L // dh)), jnp.tile(sin, (1, L // dh)),
+            head_dim=dh, span=span, block_rows=rows, block_lanes=lanes)
+        return jnp.swapaxes(out, 1, 2) if heads_major else out.reshape(x.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rotate(x, cos, sin, span):
+    return _turned(x, cos, sin, span)
+
+
+def _rotate_fwd(x, cos, sin, span):
+    return _turned(x, cos, sin, span), (cos, sin)
+
+
+def _rotate_bwd(span, tables, d_out):
+    # the rotation is linear and its matrix orthogonal up to ``factor``: the
+    # transpose is the same pass with the sine negated, and nothing of x is
+    # kept
+    cos, sin = tables
+    return _turned(d_out, cos, -sin, span), None, None
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+def rotary_embedding(x, theta: float, rotary_dim=None, *, span=None,
+                     inv_freq=None, factor: float = 1.0):
     """x ``[B, T, heads, dh]`` at positions ``0..T-1``: the half-rotation
-    form (the first half of a head's channels pairs with the second).
-    ``rotary_dim``: turn the first ``rotary_dim`` channels only (paired
-    among themselves) and pass the rest through.  ``inv_freq``: the
-    frequencies of the turned channels' pairs, one a pair, in ``theta ** (-2j
-    / width)``'s place (a scaled embedding: :func:`yarn_frequencies`);
-    ``factor`` multiplies cos and sin (YaRN's attention factor)."""
-    if rotary_dim is not None and rotary_dim != x.shape[-1]:
-        return jnp.concatenate(
-            [rotary_embedding(x[..., :rotary_dim], theta, inv_freq=inv_freq,
-                              factor=factor),
-             x[..., rotary_dim:]], axis=-1)
+    form (the first half of the turned channels pairs with the second).
+    ``span`` ``(a, b)``: turn channels ``a..b-1`` only (paired among
+    themselves) and pass the rest through; ``rotary_dim`` is the span ``(0,
+    rotary_dim)``; neither: the whole head.  ``inv_freq``: the frequencies
+    of the turned channels' pairs, one a pair, in ``theta ** (-2j /
+    width)``'s place (a scaled embedding: :func:`yarn_frequencies`);
+    ``factor`` multiplies cos and sin (YaRN's attention factor).
+
+    The rotation is ONE elementwise pass over the whole head, ``x * C +
+    partner(x) * S``, with two ``[T, dh]`` float32 tables made once a call
+    from the positions: ``C`` holds cos on the span and 1 outside it, ``S``
+    holds -sin on the span's first half, +sin on its second and 0 outside;
+    ``partner`` brings channel ``j +- (b - a) / 2`` to channel ``j``
+    (:func:`_partner`).  No slice of a head is made and nothing is
+    concatenated at the activations' size, so the channels passed through
+    ride in the same pass.  Float32 arithmetic whatever ``x``'s dtype, the
+    result in ``x``'s dtype.  The backward (a ``jax.custom_vjp``) is the same
+    pass over the cotangent with the sine negated; its residuals are the two
+    tables alone."""
     T, dh = x.shape[1], x.shape[-1]
+    if span is None:
+        span = (0, dh if rotary_dim is None else rotary_dim)
+    elif rotary_dim is not None:
+        raise ValueError("rotary_dim and span are two names of one thing")
+    a, b = (int(i) for i in span)
+    if not 0 <= a < b <= dh or (b - a) % 2:
+        raise ValueError(f"rotary span {a}:{b} of a head of {dh}")
     f32 = acc_dtype()
     if inv_freq is None:
-        inv = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
+        inv = theta ** (-jnp.arange(0, b - a, 2, dtype=f32) / (b - a))
     else:
         inv = jnp.asarray(inv_freq, f32)
-        if inv.shape != (dh // 2,):
-            raise ValueError(f"{inv.shape[0]} frequencies for {dh} channels")
+        if inv.shape != ((b - a) // 2,):
+            raise ValueError(f"{inv.shape[0]} frequencies for {b - a} "
+                             f"channels")
     ang = jnp.arange(T, dtype=f32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
     if factor != 1.0:
         cos, sin = cos * factor, sin * factor
-    xf = x.astype(f32)
-    x1, x2 = xf[..., :dh // 2], xf[..., dh // 2:]
-    return (xf * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+    ones, zeros = jnp.ones((T, dh), f32), jnp.zeros((T, dh), f32)
+    return _rotate(
+        x, jnp.concatenate([ones[:, :a], cos, cos, ones[:, b:]], -1),
+        jnp.concatenate([zeros[:, :a], -sin, sin, zeros[:, b:]], -1), (a, b))
 
 
 def yarn_frequencies(width: int, *, rope_theta: float, factor: float,

@@ -68,6 +68,10 @@ sets takes part:
   (``flash_attn_sel_fwd`` / ``flash_attn_sel_bwd``: the two flash kernels
   with ``keep=``) and the indexer's loss with its gradient
   (``indexer_loss``).  Gate: ``ops/sparse_attention.sparse_kernel_blocks``.
+- The rotary embedding as one elementwise pass over whole heads
+  (``rotary_turn``, forward and, with the sine negated, backward): a
+  channel's partner by a lane roll in registers.  Gate:
+  ``ops/decoder_block.rotary_kernel_blocks``.
 
 Where a gate is closed (the CPU, a shape past it, a step that jit partitions
 over a mesh: one with sharding rules, or ``SGDTrainer(mesh=...)``) the
@@ -98,7 +102,7 @@ __all__ = ["pallas_available", "compiled_kernels", "xla_paths_only",
            "ssd_chunk_fwd_pallas", "ssd_chunk_bwd_pallas",
            "mamba_prep_fwd_pallas", "mamba_prep_bwd_pallas",
            "indexer_scores_pallas", "topk_select_pallas",
-           "indexer_loss_pallas", "TOPK_SELECT_ROWS"]
+           "indexer_loss_pallas", "TOPK_SELECT_ROWS", "rotary_pallas"]
 
 
 def _compiler_params(**kw):
@@ -3308,3 +3312,91 @@ def indexer_loss_pallas(q, k, lse, qI, kI, w, lse_i, rows, keep, *,
             vmem_limit_bytes=SPARSE_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(q, k, lse, qI, kI, w, lse_i, rows, keep)
+
+
+# ---------------------------------------------------------------------------
+# rotary embedding: one elementwise pass over whole heads
+# ---------------------------------------------------------------------------
+#
+# ``y = x * C + partner(x) * S`` (ops/decoder_block.rotary_embedding).  In
+# XLA a channel's partner is a slice and a concatenation on the lane axis,
+# which the TPU compiles into half-lane reads and writes of the whole array;
+# here it is ``pltpu.roll`` on a slab whose lanes are whole heads, in
+# registers.  A slab is ``[rows, L]``: a head of ``L = head_dim`` lanes where
+# that is whole lane tiles (x heads-major, ``[B, H, T, dh]``: what the flash
+# kernels read and write, so nothing is transposed on the way), else
+# ``L = lcm(head_dim, 128)`` lanes of the token-major ``[B, T, H dh]`` view
+# (a head of 64: two a slab; of 192: two heads on three tiles).  The tables
+# come ``[T, L]`` and a block of them serves every slab of a block of rows.
+
+#: scoped VMEM the rotary kernel asks for (blocks of 2 MB in and out, twice)
+ROTARY_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def _rotary_kernel(x_ref, cos_ref, sin_ref, o_ref, *, head_dim, span):
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads_major = len(x_ref.shape) == 4
+    a, b = span
+    half = (b - a) // 2
+    cos, sin = cos_ref[...], sin_ref[...]
+    L = cos.shape[-1]
+    first = None
+    if b - a != L:
+        # which of its two partners a lane takes: the channel within its head
+        lane = lax.broadcasted_iota(jnp.int32, cos.shape, 1)
+        ch = lane
+        for i in range(1, L // head_dim):
+            ch = jnp.where(lane >= i * head_dim, lane - i * head_dim, ch)
+        first = ch < a + half
+    slabs = x_ref.shape[1] if heads_major else x_ref.shape[2] // L
+    for g in range(slabs):
+        at = (0, g) if heads_major else (0, slice(None),
+                                         slice(g * L, (g + 1) * L))
+        x = x_ref[at].astype(jnp.float32)
+        if first is None:
+            p = pltpu.roll(x, half, 1)
+        else:
+            p = jnp.where(first, pltpu.roll(x, L - half, 1),
+                          pltpu.roll(x, half, 1))
+        o_ref[at] = (x * cos + p * sin).astype(o_ref.dtype)
+
+
+@_traced_once("head_dim", "span", "block_rows", "block_lanes")
+def rotary_pallas(x, cos, sin, *, head_dim, span, block_rows, block_lanes,
+                  interpret):
+    """x heads-major ``[B, H, T, dh]`` (``dh`` whole lane tiles) or
+    token-major ``[B, T, H dh]``; cos, sin ``[T, L]`` float32 with the sine signed (-sin
+    on the span's first half, +sin on its second, 0 outside it) and ``L`` the
+    slab's lanes (the tables of one head, repeated over a slab's heads) ->
+    ``x * cos + partner(x) * sin`` in ``x``'s shape and dtype, float32
+    arithmetic.  ``span`` ``(a, b)``: the turned channels of a head.  A
+    block is ``block_rows`` positions by ``block_lanes`` lanes (a whole
+    number of slabs)."""
+    from jax.experimental import pallas as pl
+
+    L = cos.shape[-1]
+    table = pl.BlockSpec((block_rows, L), lambda b, i, j: (i, 0))
+    if x.ndim == 4:
+        B, H, T, _ = x.shape
+        per = block_lanes // L
+        block = pl.BlockSpec((1, per, block_rows, L),
+                             lambda b, i, j: (b, j, i, 0))
+        grid = (B, T // block_rows, H // per)
+    else:
+        B, T, W = x.shape
+        block = pl.BlockSpec((1, block_rows, block_lanes),
+                             lambda b, i, j: (b, i, j))
+        grid = (B, T // block_rows, W // block_lanes)
+    return pl.pallas_call(
+        functools.partial(_rotary_kernel, head_dim=head_dim, span=span),
+        name="rotary_turn",
+        grid=grid,
+        in_specs=[block, table, table],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_compiler_params(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=ROTARY_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+    )(x, cos, sin)

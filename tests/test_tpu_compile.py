@@ -1268,3 +1268,114 @@ def test_expert_cell_step_walks_no_assignments(cell, tokens, k, layers,
     assert len([s for s in forward if "/moe_grouping/" in s[1]]) == layers
     # the backward's sorts are the row scatter-adds' own, over a buffer's rows
     assert not [s for s in sorts if "transpose(" in s[1] and A in s[0]]
+
+
+def _top_level(text: str):
+    """``(name, op, result, operand names, op_name)`` of the compiled
+    module's instructions outside the fusions' bodies (what the chip runs
+    one by one), shapes without their layouts."""
+    bare = lambda s: re.sub(r"\{[^}]*\}", "", s)  # noqa: E731
+    found, inside_fusion = [], False
+    for line in text.split("\n"):
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            inside_fusion = head.group(1).startswith("fused_computation")
+            continue
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (.*?) ([\w-]+)\((.*)", line)
+        if inside_fusion or not m:
+            continue
+        name, result, op, rest = m.groups()
+        on = re.search(r'op_name="([^"]*)"', rest)
+        found.append((name, op, bare(result), re.findall(
+            r"%[^\s,)]+", rest.split(")")[0]), on.group(1) if on else ""))
+    return found
+
+
+def _turns(text: str, parts: str):
+    """``(rotary_turn calls, what produced each call's operand, the other
+    instructions under the scope rotary at an activation's size)`` of a
+    compiled module, after asserting that no instruction the chip runs
+    makes an array of one of the shapes ``parts`` (a part of a head of q or
+    k, as the slices the rotary embedding was made of did)."""
+    rows = _top_level(text)
+    sliced = [r for r in rows if re.search(r"f32\[(%s)\]" % parts, r[2])]
+    assert not sliced, sliced[:4]
+    op_of = {name: op for name, op, _, _, _ in rows}
+    under = [r for r in rows if "/rotary/" in r[4]
+             and r[1] not in ("bitcast", "get-tuple-element")]
+    turns = [r for r in under if "/rotary_turn/" in r[4]]
+    assert all(r[1] == "custom-call" for r in turns)
+    assert all(re.search(r"(attn|mla)\d+\)*/(mla_proj/)?rotary/", r[4])
+               for r in turns), [r[4] for r in turns][:4]
+    big = [r for r in under if r not in turns and re.search(
+        r"\[1,\d+,\d+,\d+\]|\[1,\d+,\d{4,}\]", r[2])]
+    return turns, [op_of.get(r[3][0]) for r in turns], big
+
+
+def test_laguna_cell_step_turns_whole_heads_in_one_pass(one_chip, on_tpu):
+    """PR 51.  The rotary embedding was slices of half a head (a quarter
+    under YaRN's partial span), a negation and concatenations in float32,
+    which the chip ran as ``slice_negate_fusion``s through half-lane reads
+    and writes of the whole ``[T, H, dh]`` array, six a layer (3.16 ms each
+    on the window layers, 2.44 on the full ones).  In the cell's compiled
+    step it is the kernel ``rotary_turn`` under the scope ``rotary`` inside
+    its layer's own, one call a pass (forward, recomputed, backward) for q
+    and one for k: 5 layers x 2 x 3; no instruction makes a part of a head
+    (64 of 128 in a window layer; 32 or 96 in a full one), and nothing is
+    transposed on the way in: a head of 128 is whole lane tiles, the kernel
+    takes its operand heads-major, so a forward call reads what the
+    projection's fusion wrote and a backward call what the attention's
+    backward kernel wrote.  What else runs under ``rotary`` at the
+    activations' size is the backward's one convert-and-transpose an
+    operand, which hands the projection's two backward products their bf16
+    operand (XLA names it after the kernel's ``swapaxes``)."""
+    text = _cell_step(one_chip, "lagunaxs2-train-b1-t16384").as_text()
+    assert _kernel_calls(text).get("rotary_turn") == 30
+    turns, sources, big = _turns(
+        text, r"1,16384,(64|48|8),(64|32|96)|1,(64|48|8),16384,(64|32|96)")
+    assert len(turns) == 30
+    assert set(sources) <= {"fusion", "get-tuple-element"}, sources
+    assert all(r[1] == "copy" for r in big) and len(big) <= 10, big[:4]
+
+
+def test_latent_attention_turns_its_queries_in_place(one_chip, on_tpu):
+    """PR 51.  Kanana-2's latent attention at the cell's shapes (32 query
+    heads of 128 + 64, the rotary embedding on the last 64; a row of 8192):
+    the layer's value and gradient compiled for the described chip hold two
+    ``rotary_turn`` calls (forward, backward) over the WHOLE 192-wide query
+    heads, where the layer sliced ``q_rope`` off, turned its halves
+    (``[8192, 32, 32]`` slices: fourteen fusions of 0.82 ms a layer in the
+    cell's step) and concatenated ``q_nope`` back.  A head of 192 is no
+    whole lane tiles, so the kernel takes the token-major ``[T, 32 x 192]``
+    in slabs of 384 lanes: the forward call reads what the projection
+    wrote; the backward's ``dq``, which the attention's kernel writes
+    heads-major, is transposed to it once.  The lone key head of 64 fills
+    no slab and stays in XLA (2 MB a pass)."""
+    import paddle_tpu.nn as nn
+    from paddle_tpu.ops import decoder_block as DB
+
+    T, D = 8192, 2048
+    assert DB.rotary_kernel_blocks(T, 32, 192) == (False, 384, 512, 768)
+    assert DB.rotary_kernel_blocks(T, 1, 64) is None
+    nn.reset_naming()
+    x = nn.data("x", size=D, is_seq=True)
+    layer = nn.latent_attention(
+        x, num_heads=32, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_theta=1e6, name="mla0")
+    topo = nn.Topology([layer])
+    params = {k: _struct(one_chip, spec.shape)
+              for k, spec in topo.param_specs.items()}
+    feed = {"x": (_struct(one_chip, (1, T, D)),
+                  _struct(one_chip, (1,), jnp.int32))}
+
+    def loss(p, feed):
+        return topo.apply(p, {}, feed, train=True)[0]["mla0"].value.sum()
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, feed).compile().as_text()
+    assert _kernel_calls(text).get("rotary_turn") == 2
+    turns, sources, big = _turns(text, r"1,8192,32,(32|64)|1,32,8192,(32|64)")
+    assert all("[1,8192,6144]" in r[2] for r in turns)
+    assert sorted(sources) == ["copy", "fusion"], sources
+    assert [r[1] for r in big] == ["copy"], big[:4]
+

@@ -30,7 +30,7 @@ KERNELS = ["lstm_seq_fwd", "gru_seq_fwd", "lstm_seq_bwd", "gru_seq_bwd",
            "moe_gmm", "moe_tgmm",
            "gdn_chunk_fwd", "gdn_chunk_bwd", "ssd_chunk_fwd", "ssd_chunk_bwd",
            "gdn_prep_fwd", "gdn_prep_bwd", "mamba_prep_fwd", "mamba_prep_bwd",
-           "indexer_scores", "topk_select", "indexer_loss"]
+           "indexer_scores", "topk_select", "indexer_loss", "rotary_turn"]
 
 
 # -- (a) kernels ----------------------------------------------------------
@@ -204,8 +204,9 @@ def test_keye_vl2_step_holds_its_layers_scopes():
         p, {}, feed, train=True)[0]["cost"].value))(params)
     names = _scope_names(_name_stacks(grad.jaxpr))
     assert {"attn0", "attn1", "indexer", "topk_select", "attn_core",
-            "indexer_loss", "moe0", "moe1", "moe_routing", "moe_experts",
-            "norm_op0", "norm_ffn1", "norm_out", "cost"} <= names
+            "indexer_loss", "rotary", "moe0", "moe1", "moe_routing",
+            "moe_experts", "norm_op0", "norm_ffn1", "norm_out",
+            "cost"} <= names
     assert not {"mlp0", "moe_shared", "norm0"} & names
 
 
@@ -243,14 +244,22 @@ def test_laguna_step_holds_its_layers_scopes():
         p, {}, feed, train=True)[0]["cost"].value))(params)
     stacks = _name_stacks(grad.jaxpr)
     names = _scope_names(stacks)
-    assert {"attn0", "attn1", "attn2", "attn_core", "attn_window", "mlp0",
-            "moe1", "moe2", "moe_routing", "moe_experts", "moe_shared",
-            "norm_op0", "norm_ffn2", "norm_out", "cost"} <= names
+    assert {"attn0", "attn1", "attn2", "attn_core", "attn_window", "rotary",
+            "mlp0", "moe1", "moe2", "moe_routing", "moe_experts",
+            "moe_shared", "norm_op0", "norm_ffn2", "norm_out",
+            "cost"} <= names
     assert not {"moe0", "mlp1", "indexer", "norm0"} & names
     # the window's core under the window layer alone, the full core under
     # the full layers alone
     assert not [s for s in stacks if "attn_window" in s and "attn1" not in s]
     assert not [s for s in stacks if "attn_core" in s and "attn1" in s]
+    # the rotary embedding (PR 51) under every attention layer's own scope:
+    # forward, recomputed in the layer's block, and backward
+    # (the custom VJP's backward pass carries the layer's bare name),
+    # and nowhere else
+    turned = {s for s in stacks if "rotary" in _scope_names({s})}
+    assert turned == {f"{way}/rotary" for i in range(3) for way in (
+        f"jvp(attn{i})", f"rematted_computation/attn{i}", f"attn{i}")}
 
 
 def _primitives_under(jaxpr, scope, inside=False, out=None):

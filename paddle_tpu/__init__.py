@@ -10,9 +10,20 @@ reference's MultiGradientMachine + parameter-server tier becomes SPMD sharding
 over a ``jax.sharding.Mesh`` with ICI collectives.
 """
 
+import time as _time
+
+_T0 = _time.perf_counter()   # the set-up record's first phase begins here
+
 __version__ = "0.1.0"
 
 from paddle_tpu.utils import FLAGS, logger
 from paddle_tpu.utils.devices import init
 
 __all__ = ["FLAGS", "logger", "init", "__version__"]
+
+# ... and ends here: ``import``, from this file's first line to its last
+# (obs/timeline.py SetupRecord; the subpackages that import the most,
+# ``trainer``, ``nn`` and ``models``, add their own to the same phase)
+from paddle_tpu.obs.timeline import setup_record as _setup_record
+
+_setup_record().add("import", _T0)

@@ -4,7 +4,10 @@ One instrument panel for every tier (docs/observability.md): the
 process-wide metrics registry (counters/gauges/histograms, Prometheus +
 JSON exposition, ``--metrics_port`` HTTP endpoint), the trainer's
 step-time breakdown with a live MFU gauge (the analytic-FLOPs walker
-``analysis.flops`` on the trainer's step), the rank-tagged structured event
+``analysis.flops`` on the trainer's step), the process's set-up record
+(``obs/timeline.py``: import, init, the trainer's build and the first
+iteration as phases, JAX's own trace / lower / compile events as their
+parts), the rank-tagged structured event
 journal (``--obs_journal`` + ``python -m paddle_tpu obs merge``),
 request-level distributed tracing (``obs/trace.py``: span-based
 tail-latency attribution across serving, the decode slot table, and the
@@ -27,7 +30,8 @@ from paddle_tpu.obs.registry import (Counter, Gauge, Histogram,
                                      MetricsRegistry, ensure_metrics_server,
                                      get_registry, reset_registry,
                                      start_metrics_server)
-from paddle_tpu.obs.timeline import PHASES, StepTimeline
+from paddle_tpu.obs.timeline import (PHASES, StepTimeline, close_setup,
+                                     reset_setup, setup_phase, setup_record)
 from paddle_tpu.obs.trace import (Span, Tracer, collect_traces,
                                   format_trace_tree, get_tracer,
                                   perfetto_trace, reset_tracer,
@@ -44,6 +48,10 @@ __all__ = [
     "ensure_metrics_server",
     "StepTimeline",
     "PHASES",
+    "setup_phase",
+    "setup_record",
+    "close_setup",
+    "reset_setup",
     "EventJournal",
     "journal_path",
     "journal_files",

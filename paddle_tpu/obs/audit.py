@@ -18,6 +18,10 @@ the speculative wide ``spec_verify_step`` are traced with tracing armed
 (``--obs_journal`` + ``--trace_sample``) vs off and must be
 equation-identical — spans are host-side bookkeeping around calls the
 loop already makes; tracing adds ZERO compiled equations.
+
+The set-up record (obs/timeline.py) is held to the same: the train step
+traced inside an open phase and after the record has closed is one
+program.
 """
 
 from __future__ import annotations
@@ -176,6 +180,29 @@ def audit_telemetry_step() -> List[Finding]:
                             "tracing armed — spans must stay host-side "
                             f"({len(a.jaxpr.eqns)} vs "
                             f"{len(b.jaxpr.eqns)} top-level eqns)"))
+        # the set-up record (obs/timeline.py): the step traced inside an
+        # open phase, as its first call is, against the step traced after
+        # the close, as every later program is
+        from paddle_tpu.obs import timeline
+
+        keep_record = timeline._RECORD
+        try:
+            timeline._RECORD = timeline.SetupRecord()
+            with timeline.setup_phase("first_step"):
+                rec_open = jax.make_jaxpr(tr._step_fn)(*args)
+            timeline._RECORD.closed = True
+            with timeline.setup_phase("first_step"):
+                rec_closed = jax.make_jaxpr(tr._step_fn)(*args)
+        finally:
+            timeline._RECORD = keep_record
+        if str(rec_open) != str(rec_closed):
+            findings.append(Finding(
+                check="obs-setup-drift", severity="ERROR",
+                where="obs:train_step",
+                message="the compiled train step DIFFERS inside a phase of "
+                        "the set-up record — a phase is two clock reads "
+                        f"around the call ({len(rec_open.jaxpr.eqns)} vs "
+                        f"{len(rec_closed.jaxpr.eqns)} top-level eqns)"))
     except Exception as e:  # a step that fails to trace is itself a finding
         findings.append(Finding(
             check="obs-build", severity="ERROR", where="obs:train_step",

@@ -19,7 +19,7 @@ from __future__ import annotations
 import json
 import os
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import ExitStack, contextmanager, nullcontext
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -28,6 +28,8 @@ import numpy as np
 
 from paddle_tpu.data.feeder import PreparedFeed, PrepareError
 from paddle_tpu.nn.graph import LayerOutput, Topology
+from paddle_tpu.obs.timeline import (close_setup, in_setup_phase, setup_phase,
+                                     setup_record)
 from paddle_tpu.param.optimizers import Optimizer, ParameterAverager, SGD
 from paddle_tpu.resilience import (DCNPartitioned, GangResized,
                                    PreemptionHandler, ReaderError,
@@ -56,6 +58,14 @@ def _span(name: str, **stats):
     blocking fetch from the device inside ``step``, so their count per
     ``iteration`` is the count of host syncs a step pays."""
     return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **stats)
+
+
+#: the loop's phases in the set-up record (obs/timeline.py), while the first
+#: iteration of the process's first pass runs: everything up to the first
+#: feed on the device is ``data``, the first call of the step and the fetch
+#: that ends it ``first_step``; a phase not named here keeps its name
+_SETUP_PHASE_OF = {"data_wait": "data", "prepare": "data", "h2d": "data",
+                   "step": "first_step"}
 
 
 #: consecutive SDC rollbacks a survivor tolerates before declaring the
@@ -109,6 +119,7 @@ class SGDTrainer:
     """v2-style trainer: ``SGDTrainer(cost=..., optimizer=...)``, then
     ``.train(reader, num_passes, event_handler, feeder)``."""
 
+    @in_setup_phase("trainer_build")
     def __init__(
         self,
         cost,
@@ -165,10 +176,12 @@ class SGDTrainer:
 
             if mesh is None:
                 raise ValueError("pipeline training requires a mesh")
-            self.topology = PipelinedTopology([*costs, *extra_outputs],
-                                              mesh=mesh, **pipeline)
+            with setup_phase("topology"):
+                self.topology = PipelinedTopology([*costs, *extra_outputs],
+                                                  mesh=mesh, **pipeline)
         else:
-            self.topology = Topology([*costs, *extra_outputs])
+            with setup_phase("topology"):
+                self.topology = Topology([*costs, *extra_outputs])
         self.optimizer = optimizer or SGD(learning_rate=0.01)
         self.mesh = mesh
         self.data_axis = data_axis
@@ -234,14 +247,15 @@ class SGDTrainer:
                     self.lr_scales.pop(name, None)
                     self.decays.pop(name, None)
 
-        self.params, self.state = self.topology.init(init_key, skip=routed)
-
         # StaticPruningHook analog: masks fixed from initial magnitudes,
         # re-applied after every update inside the jitted step
         from paddle_tpu.param.hooks import apply_masks, build_masks
 
-        self.masks = build_masks(self.params, self.pruning_ratios)
-        self.params = apply_masks(self.params, self.masks)
+        with setup_phase("params"):
+            self.params, self.state = self.topology.init(init_key,
+                                                         skip=routed)
+            self.masks = build_masks(self.params, self.pruning_ratios)
+            self.params = apply_masks(self.params, self.masks)
 
         # mixed precision (--amp; docs/mixed_precision.md): forward and
         # backward run in bf16 end-to-end (ops/numerics dtype policy reads
@@ -261,12 +275,15 @@ class SGDTrainer:
         self.amp = bool(FLAGS.amp if amp is None else amp)
         self.remat = bool(FLAGS.remat if remat is None else remat)
         self.amp_overflows_total = 0
-        self.opt_state = self.optimizer.init_state(self.params)
-        if self.amp:
-            self.opt_state["amp"] = init_loss_scale(FLAGS.loss_scale)
-        self.avg_params = self.averager.init_state(self.params) if self.averager else None
+        with setup_phase("opt_state"):
+            self.opt_state = self.optimizer.init_state(self.params)
+            if self.amp:
+                self.opt_state["amp"] = init_loss_scale(FLAGS.loss_scale)
+            self.avg_params = (self.averager.init_state(self.params)
+                               if self.averager else None)
         if self.mesh is not None:
-            self._place_sharded()
+            with setup_phase("params"):   # their placement over the mesh
+                self._place_sharded()
         # bad-step guard (resilience/guard.py): skip non-finite updates
         # inside the jitted step; counters live host-side on the trainer
         self.guard_nonfinite = (FLAGS.guard_nonfinite if guard_nonfinite is None
@@ -318,15 +335,8 @@ class SGDTrainer:
                                      "guard-skipped non-finite steps"),
             "checkpoints": reg.counter("train_checkpoints_total",
                                        "checkpoint commits published"),
-            "publishes": reg.counter("train_publishes_total",
-                                     "gated deploy bundles published"),
             "resizes": reg.counter("train_resizes_total",
                                    "elastic resizes adopted"),
-            "sdc_checks": reg.counter("train_sdc_checks_total",
-                                      "cross-replica integrity checks"),
-            "sdc_mismatch": reg.counter(
-                "train_sdc_mismatch_total",
-                "cross-replica fingerprint mismatches"),
         }
         self.timeline = None
         self._journal = None
@@ -348,7 +358,8 @@ class SGDTrainer:
         # train() call like the journal
         self._tracer = None
         self._step_span = None
-        self._step = self._build_step()
+        with setup_phase("step_build"):
+            self._step = self._build_step()
         self._eval_fns: Dict[str, Callable] = {}
 
     # ------------------------------------------------------------------
@@ -633,6 +644,15 @@ class SGDTrainer:
             if span is not None:
                 span.end()
 
+    @contextmanager
+    def _ph_setup(self, name: str):
+        """``_ph`` while the set-up record is open (the loop's first
+        iteration in a process): the same phase inside the record's
+        (``_SETUP_PHASE_OF``).  The loop swaps back to ``_ph`` itself when
+        the iteration has completed, so a later step pays nothing."""
+        with setup_phase(_SETUP_PHASE_OF.get(name, name)), self._ph(name):
+            yield
+
     @property
     def _h2d_measurable(self) -> bool:
         """Whether an explicit synced transfer would measure anything
@@ -667,7 +687,12 @@ class SGDTrainer:
         the live ``train_mfu`` gauge divides (pinned to the walker by
         tests/test_obs.py).  Traced through the
         function jit wraps (the step with the key's split before it, which
-        adds no product), so after a first step this is a look-up."""
+        adds no product), so after a first step the TRACE is a look-up
+        (the set-up record's ``flops_trace`` reads ``trace`` 0.000 s, one
+        event, on every cell) and what is left is the walk over the
+        jaxpr's equations: 0.004 s on the LSTM cell, 0.017-0.045 on five of
+        the decoder cells, 0.053-0.056 on Nemotron-3-Nano's, the largest
+        read (PERF.md section 6, PR 52), once a ``train()`` call."""
         from paddle_tpu.analysis.flops import jaxpr_flops
 
         if self.mesh is not None:
@@ -957,6 +982,13 @@ class SGDTrainer:
         # children are the timeline phases, with gang events attached
         tracer = self._tracer = get_tracer()
         self._step_span = None
+        # the set-up record (obs/timeline.py; docs/observability.md
+        # "Set-up") is open until the first iteration of the process's
+        # first pass has completed; until then the loop's phases go into
+        # it as well (_ph_setup).  The one look at it of a train() call
+        setting_up = not setup_record().closed
+        ph = self._ph_setup if setting_up else self._ph
+        first_it = ExitStack()
         profiler = self._profiler = (
             ProfilerCapture(FLAGS.profile_dir, FLAGS.profile_steps)
             if FLAGS.profile_dir and FLAGS.profile_steps else None)
@@ -1023,6 +1055,7 @@ class SGDTrainer:
         # restores the last verified checkpoint and rewinds the schedule
         # to its pass instead of exiting the loop
         schedule = _PassSchedule(start_pass, num_passes)
+        first_it.enter_context(setup_phase("first_iteration"))
         try:
             for pass_id in schedule:
                 handler(ev.BeginPass(pass_id))
@@ -1048,9 +1081,10 @@ class SGDTrainer:
                         f"{type(e).__name__}: {e}")
 
                 try:
-                    if src is not None:
-                        src.seek(pass_id)
-                    it = iter(reader())
+                    with setup_phase("data"):
+                        if src is not None:
+                            src.seek(pass_id)
+                        it = iter(reader())
                 except Exception as e:
                     raise _reader_failed(e) from e
                 self._prefetcher = None
@@ -1095,7 +1129,8 @@ class SGDTrainer:
                             depth=FLAGS.prefetch_depth)
 
                 if not skip:
-                    _wrap_prefetch()
+                    with setup_phase("data"):
+                        _wrap_prefetch()
                 batch_id = first_batch
                 while True:
                     # one StepTraceAnnotation per batch (XProf groups by
@@ -1163,7 +1198,7 @@ class SGDTrainer:
                                 self._preempt_exit(pass_id, batch_id + skip,
                                                    preemption, handler)
                                 return
-                        with self._ph("data_wait"):
+                        with ph("data_wait"):
                             try:
                                 data_batch = next(it, None)
                             except PrepareError as e:
@@ -1196,10 +1231,10 @@ class SGDTrainer:
                             continue
                         if jr is not None:
                             jr.set_context(batch_id=batch_id)
-                        with self._ph("callback"):
+                        with ph("callback"):
                             handler(ev.BeginIteration(pass_id, batch_id))
                         prefetched = isinstance(data_batch, PreparedFeed)
-                        with self._ph("prepare"):
+                        with ph("prepare"):
                             feed = (data_batch.feed if prefetched
                                     else feeder(data_batch) if feeder
                                     else data_batch)
@@ -1210,7 +1245,7 @@ class SGDTrainer:
                             # that follows starts device-resident (on single-
                             # device CPU there is no boundary to measure —
                             # skipped, the alias-copy rides inside `step`)
-                            with self._ph("h2d"):
+                            with ph("h2d"):
                                 feed = self._device_feed(feed)
                         if profiler is not None:
                             # BEFORE the step: a window armed at batch b
@@ -1219,7 +1254,7 @@ class SGDTrainer:
                             # and make the first post-compile step untraceable
                             profiler.tick()
                         try:
-                            with self._ph("step"):
+                            with ph("step"):
                                 loss = self.train_batch(feed)
                                 # the phase ends with the step's device work
                                 # done: where train_batch fetched a flag the
@@ -1244,11 +1279,15 @@ class SGDTrainer:
                         with _span("extras"):
                             if tl is not None and tl.wants_mfu and \
                                     not tl.flops_attempted:
-                                # ONE extra host-side trace per compiled
-                                # program, only when a chip peak is resolvable
-                                # — a failed trace (None) is not retried per
-                                # batch
-                                tl.set_flops(self.step_flops(feed))
+                                # ONE extra host-side trace per train()
+                                # call (the timeline is the call's), only
+                                # when a chip peak is resolvable — a failed
+                                # trace (None) is not retried per batch.  A
+                                # phase of set-up the first time, and on the
+                                # profiler's trace every time
+                                with setup_phase("flops_trace",
+                                                 span_after_close=True):
+                                    tl.set_flops(self.step_flops(feed))
                                 tl.recompute_mfu()
                             if src is not None:
                                 # corrupt shard records the source skipped
@@ -1276,7 +1315,7 @@ class SGDTrainer:
                                     "step_time_s": tl.last.get("step"),
                                     "mfu": tl.mfu,
                                 }
-                        with self._ph("callback"):
+                        with ph("callback"):
                             handler(ev.EndIteration(pass_id, batch_id, cost))
                         with _span("close"):
                             if self._step_span is not None:
@@ -1333,11 +1372,18 @@ class SGDTrainer:
                                 # mid-pass eval — test_period batches
                                 # (Trainer.cpp trainOneBatch "testing" branch;
                                 # 0 = per pass only)
-                                with self._ph("eval"):
+                                with ph("eval"):
                                     mid = self.test(test_reader, feeder=feeder)
                                 logger.info(
                                     "Pass %d, Batch %d, Test cost %.5f",
                                     pass_id, batch_id + 1, mid["cost"])
+                    if setting_up:
+                        # the process's first iteration has completed: the
+                        # set-up record ends, publishes itself, and the loop
+                        # goes back to _ph alone
+                        first_it.close()
+                        close_setup(jr)
+                        setting_up, ph = False, self._ph
                     batch_id += 1
                 self._close_prefetcher()
                 if rolled_back:
@@ -1348,16 +1394,16 @@ class SGDTrainer:
                     continue
                 result = {}
                 if test_reader is not None:
-                    with self._ph("eval"):
+                    with ph("eval"):
                         result = self.test(test_reader, feeder=feeder)
-                with self._ph("callback"):
+                with ph("callback"):
                     handler(ev.EndPass(pass_id, evaluator=result))
                 if jr is not None:
                     jr.record("end_pass", batches=batch_id)
                 if FLAGS.save_dir and FLAGS.saving_period and (
                     (pass_id + 1) % FLAGS.saving_period == 0
                 ):
-                    with self._ph("checkpoint"):
+                    with ph("checkpoint"):
                         try:
                             self.save(FLAGS.save_dir, pass_id)
                         except GangResized as e:
@@ -1375,7 +1421,7 @@ class SGDTrainer:
                     # checkpoint bytes — never from live memory, so an
                     # unverified or quarantined pass is unpublishable by
                     # construction; a refusal is journaled, never fatal
-                    with self._ph("checkpoint"):
+                    with ph("checkpoint"):
                         self.publish(FLAGS.publish_dir, FLAGS.save_dir)
                 if tl is not None:
                     if FLAGS.enable_timers:
@@ -1405,6 +1451,7 @@ class SGDTrainer:
                     gang.heartbeat()
                     time.sleep(0.05)
         finally:
+            first_it.close()   # a pass that ran no iteration to its end
             if self._step_span is not None:
                 # an exception mid-batch: the half-told step never
                 # reaches the journal (incidents retain+end explicitly)
@@ -1521,7 +1568,6 @@ class SGDTrainer:
                 raise _SdcRollback(pass_id, batch_id + 1,
                                    cursor_ready=True)
             return
-        self._obs_counters["sdc_checks"].inc()
         fps = {int(r): int(v) for r, v in raw.items()}
         if getattr(gang, "pod_size", 1) > 1:
             # dcn topology: pods (not ranks) are the bit-identical
@@ -1535,7 +1581,6 @@ class SGDTrainer:
             self._sdc_agreed_fps.append(fp)
             return
         self.sdc_mismatches_total += 1
-        self._obs_counters["sdc_mismatch"].inc()
         jr = self._journal
         if jr is not None:
             # fsync'd: the incident anchor the merged postmortem orders
@@ -2139,7 +2184,6 @@ class SGDTrainer:
         except PublishRefused as e:
             logger.warning("publish refused (%s): %s", e.reason, e)
             return None
-        self._obs_counters["publishes"].inc()
         return vdir
 
     def load(self, save_dir: str, pass_id: int, *,

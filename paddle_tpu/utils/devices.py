@@ -76,17 +76,19 @@ def init(argv: Optional[list] = None) -> list:
     paddle/trainer/TrainerMain.cpp:32-49).  Parses flags, selects platform,
     seeds determinism. Returns leftover argv."""
     global _initialized
+    from paddle_tpu.obs.timeline import setup_phase
     from paddle_tpu.utils.flags import FLAGS, parse_flags
 
-    rest = parse_flags(argv)
-    if not _initialized:
-        if FLAGS.num_virtual_devices:
-            force_virtual_devices(FLAGS.num_virtual_devices)
-        if FLAGS.platform:
-            os.environ["JAX_PLATFORMS"] = FLAGS.platform
-        use_compilation_cache()
-        _initialized = True
-    apply_numeric_traps()
+    with setup_phase("init"):   # the set-up record (docs/observability.md)
+        rest = parse_flags(argv)
+        if not _initialized:
+            if FLAGS.num_virtual_devices:
+                force_virtual_devices(FLAGS.num_virtual_devices)
+            if FLAGS.platform:
+                os.environ["JAX_PLATFORMS"] = FLAGS.platform
+            use_compilation_cache()
+            _initialized = True
+        apply_numeric_traps()
     return rest
 
 

@@ -3056,10 +3056,18 @@ def mamba_prep_bwd_pallas(zxbc, wb, dx, dskip, dB, dC, *, offset: int,
 # passes, exact), ties at the threshold by position (15 more), the row's
 # selection written as int8 with the log-sum-exp of the kept scores.
 # ``indexer_loss``: per tile the 32 heads' probabilities from the forward's
-# lse, summed in VMEM scratch (the grid's innermost axis is the head); on
-# the last head the indexer's scores again, the tile's part of KL(p || q)
-# and of the gradient into qI, kI (resident over a batch row, as the flash
-# backward's dk) and w.  No [T, T] array but I and the selection crosses HBM.
+# lse, summed in VMEM scratch.  The grid's innermost axis is the KEY-VALUE
+# head (PR 53): a step holds a tile and a group's query heads, walks the
+# tile slab by slab and adds the group's heads to a slab, in the heads'
+# order, before the slab goes back, so the scratch is read and written once
+# a group and the key block is fetched once a group.  The group's lse comes
+# with the queries along the lanes (``[B, Hkv, G, T]``, 32 KB a block: a
+# block of ``[.., 1024, 1]`` columns is fetched as 4 MB of lane padding) and
+# is turned into columns once a step.  On the last group the indexer's
+# scores are READ from I (the tile ``indexer_scores`` wrote), then the tile's
+# part of KL(p || q) and of the gradient into qI, kI (resident over a batch
+# row, as the flash backward's dk) and w.  No [T, T] array but I and the
+# selection crosses HBM; I is read twice (``topk_select``, ``indexer_loss``).
 
 SPARSE_VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 #: rows of scores one ``topk_select`` step holds
@@ -3191,42 +3199,67 @@ def topk_select_pallas(scores, *, topk: int, rows: int, interpret: bool):
     )(scores)
 
 
+def _indexer_loss_slab(block: int):
+    """(rows, columns) of the piece of a tile that ``indexer_loss`` adds a
+    group's heads to at a time: a quarter of the tile's rows by up to four
+    lane tiles of its columns (256 x 512 at tiles of 1024).  The wider the
+    piece, the more columns share a row's lse, whose spread over the lanes
+    binds a piece of one lane tile; at 512 columns the MXU binds."""
+    return block // 4, min(block, 512)
+
+
 def _indexer_loss_kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref,
-                         lsei_ref, rows_ref, keep_ref, kl_ref, dqi_ref,
-                         dki_ref, dw_ref, acc_scr, *, scale, heads, idx_heads,
-                         block):
+                         lsei_ref, rows_ref, keep_ref, scores_ref, kl_ref,
+                         dqi_ref, dki_ref, dw_ref, acc_scr, lse_scr, *, scale,
+                         heads, group, idx_heads, block):
     from jax.experimental import pallas as pl
 
-    qi, kj, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    qi, kj, kv = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     live = kj <= qi
 
-    @pl.when((qi == 0) & (kj == 0) & (h == 0))
+    @pl.when((qi == 0) & (kj == 0) & (kv == 0))
     def _zero_keys():
         dki_ref[...] = jnp.zeros_like(dki_ref)
 
-    @pl.when((kj == 0) & (h == 0))
+    @pl.when((kj == 0) & (kv == 0))
     def _zero_queries():
         kl_ref[...] = jnp.zeros_like(kl_ref)
         dqi_ref[...] = jnp.zeros_like(dqi_ref)
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    @pl.when(live & (h == 0))
+    @pl.when(live & (kv == 0))
     def _zero_tile():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     @pl.when(live)
-    def _head():        # this head's probabilities, by the forward's lse
-        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        acc_scr[...] += jnp.exp(s - lse_ref[0, 0])
+    def _group():       # this group's probabilities, by the forward's lse
+        rows, cols = _indexer_loss_slab(block)
+        across = block // cols
+        lse_scr[...] = lse_ref[0, 0].T      # a head's lse down a column
 
-    @pl.when(live & (h == heads - 1))
+        def add(i, carry):
+            r = pl.ds(pl.multiple_of(i // across * rows, rows), rows)
+            c = pl.ds(pl.multiple_of(i % across * cols, cols), cols)
+            keys = k_ref[0, 0, c, :]
+            acc = acc_scr[r, c]
+            for h in range(group):      # head by head: the order of the sum
+                s = jax.lax.dot_general(
+                    q_ref[0, h, r, :], keys, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                acc = acc + jnp.exp(s - lse_scr[r, h:h + 1])
+            acc_scr[r, c] = acc
+            return carry
+
+        jax.lax.fori_loop(0, block // rows * across, add, 0)
+
+    @pl.when(live & (kv == heads // group - 1))
     def _indexer():
         keep = keep_ref[0].astype(jnp.int32) != 0
         p = jnp.where(keep, acc_scr[...] * (1.0 / heads), 0.0)
         kI, w = ki_ref[0], w_ref[0]
-        logq = _indexer_tile(qi_ref, kI, w, idx_heads) - lsei_ref[0]
+        # the scores as ``indexer_scores`` wrote them: -inf above the
+        # diagonal, where ``keep`` is 0 and every use below is masked
+        logq = scores_ref[0] - lsei_ref[0]
         real = rows_ref[0]          # 1 at a real query, 0 at a padded one
         kl_ref[0] += real * jnp.sum(
             jnp.where(p > 0.0, p * (jnp.log(p) - logq), 0.0), axis=1,
@@ -3249,14 +3282,17 @@ def _indexer_loss_kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref,
 
 
 @_traced_once("scale", "block")
-def indexer_loss_pallas(q, k, lse, qI, kI, w, lse_i, rows, keep, *,
+def indexer_loss_pallas(q, k, lse, qI, kI, w, lse_i, rows, keep, scores, *,
                         scale: float, block: int, interpret: bool):
-    """The indexer's loss and its gradient in one pass over the tiles.  q
-    ``[B, H, T, dh]``, k ``[B, Hkv, T, dh]``, lse ``[B, H, T, 1]`` (the
-    selected attention's), qI ``[B, J, T, d]``, kI ``[B, T, d]``, w ``[B, T,
-    J]``, lse_i ``[B, T, 1]`` and keep ``[B, T, T]`` (``topk_select``'s),
-    rows ``[B, T, 1]`` float32 (1 at a real query, 0 at a padded one, which
-    then adds nothing) ->
+    """The indexer's loss and its gradient in one pass over the tiles, a
+    grid step a tile and key-value head.  q ``[B, H, T, dh]``, k ``[B, Hkv,
+    T, dh]``, lse ``[B, H, T, 1]`` (the selected attention's; the kernel
+    takes it as ``[B, Hkv, G, T]``), qI ``[B, J, T, d]``, kI ``[B, T, d]``,
+    w ``[B, T, J]``, lse_i ``[B, T, 1]`` and keep ``[B, T, T]``
+    (``topk_select``'s), rows ``[B, T, 1]`` float32 (1 at a real query, 0 at
+    a padded one, which then adds nothing), scores ``[B, T, T]`` float32
+    (``indexer_scores_pallas``'s of the same qI, kI, w: read, not made
+    again) ->
     (kl ``[B, T, 1]``: a row's ``KL(p || softmax_kept(I))`` with ``p`` the
     heads' mean probability; dqI ``[B, J, T, d]``, dkI ``[B, T, d]``, dw
     ``[B, T, J]``: the gradient of the rows' sum, float32)."""
@@ -3264,7 +3300,8 @@ def indexer_loss_pallas(q, k, lse, qI, kI, w, lse_i, rows, keep, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, T, dh = q.shape
-    G = H // k.shape[1]
+    Hkv = k.shape[1]
+    G = H // Hkv
     J, d = qI.shape[1], qI.shape[3]
     n = T // block
 
@@ -3273,45 +3310,47 @@ def indexer_loss_pallas(q, k, lse, qI, kI, w, lse_i, rows, keep, *,
 
     return pl.pallas_call(
         functools.partial(_indexer_loss_kernel, scale=scale, heads=H,
-                          idx_heads=J, block=block),
+                          group=G, idx_heads=J, block=block),
         name="indexer_loss",
-        grid=(B, n, n, H),
+        grid=(B, n, n, Hkv),
         in_specs=[
+            pl.BlockSpec((1, G, block, dh),
+                         lambda b, qi, kj, g: (b, g, qi, 0)),
             pl.BlockSpec((1, 1, block, dh),
-                         lambda b, qi, kj, h: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block, dh),
-                         lambda b, qi, kj, h: (b, h // G, key_block(qi, kj),
-                                               0)),
-            pl.BlockSpec((1, 1, block, 1),
-                         lambda b, qi, kj, h: (b, h, qi, 0)),
+                         lambda b, qi, kj, g: (b, g, key_block(qi, kj), 0)),
+            pl.BlockSpec((1, 1, G, block),
+                         lambda b, qi, kj, g: (b, g, 0, qi)),
             pl.BlockSpec((1, J, block, d),
-                         lambda b, qi, kj, h: (b, 0, qi, 0)),
+                         lambda b, qi, kj, g: (b, 0, qi, 0)),
             pl.BlockSpec((1, block, d),
-                         lambda b, qi, kj, h: (b, key_block(qi, kj), 0)),
-            pl.BlockSpec((1, block, J), lambda b, qi, kj, h: (b, qi, 0)),
-            pl.BlockSpec((1, block, 1), lambda b, qi, kj, h: (b, qi, 0)),
-            pl.BlockSpec((1, block, 1), lambda b, qi, kj, h: (b, qi, 0)),
+                         lambda b, qi, kj, g: (b, key_block(qi, kj), 0)),
+            pl.BlockSpec((1, block, J), lambda b, qi, kj, g: (b, qi, 0)),
+            pl.BlockSpec((1, block, 1), lambda b, qi, kj, g: (b, qi, 0)),
+            pl.BlockSpec((1, block, 1), lambda b, qi, kj, g: (b, qi, 0)),
             pl.BlockSpec((1, block, block),
-                         lambda b, qi, kj, h: (b, qi, key_block(qi, kj))),
+                         lambda b, qi, kj, g: (b, qi, key_block(qi, kj))),
+            pl.BlockSpec((1, block, block),
+                         lambda b, qi, kj, g: (b, qi, key_block(qi, kj))),
         ],
         out_specs=[
-            pl.BlockSpec((1, block, 1), lambda b, qi, kj, h: (b, qi, 0)),
+            pl.BlockSpec((1, block, 1), lambda b, qi, kj, g: (b, qi, 0)),
             pl.BlockSpec((1, J, block, d),
-                         lambda b, qi, kj, h: (b, 0, qi, 0)),
-            pl.BlockSpec((1, T, d), lambda b, qi, kj, h: (b, 0, 0)),
-            pl.BlockSpec((1, block, J), lambda b, qi, kj, h: (b, qi, 0)),
+                         lambda b, qi, kj, g: (b, 0, qi, 0)),
+            pl.BlockSpec((1, T, d), lambda b, qi, kj, g: (b, 0, 0)),
+            pl.BlockSpec((1, block, J), lambda b, qi, kj, g: (b, qi, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((B, T, 1), jnp.float32),
                    jax.ShapeDtypeStruct((B, J, T, d), jnp.float32),
                    jax.ShapeDtypeStruct((B, T, d), jnp.float32),
                    jax.ShapeDtypeStruct((B, T, J), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32),
+                        pltpu.VMEM((block, G), jnp.float32)],
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=SPARSE_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(q, k, lse, qI, kI, w, lse_i, rows, keep)
+    )(q, k, lse.reshape(B, Hkv, G, T), qI, kI, w, lse_i, rows, keep, scores)
 
 
 # ---------------------------------------------------------------------------

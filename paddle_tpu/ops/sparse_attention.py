@@ -28,8 +28,10 @@ them.  On the TPU at tile-aligned shapes (:func:`sparse_kernel_blocks`) the
 kernels of ops/pallas_kernels.py: ``indexer_scores``, ``topk_select`` (the
 threshold of a row found by counting, no sort), the two flash kernels under
 the selection (``keep=``) and ``indexer_loss``; of ``[T, T]`` arrays only
-the scores (float32) and the selection (int8) cross HBM, and the selection
-is kept across a recomputation block.  Elsewhere a loop over blocks of
+the scores (float32) and the selection (int8) cross HBM: the scores are
+written once and read twice (by ``topk_select`` and, a tile at a time, by
+``indexer_loss``, which does not make them again), and the selection is
+kept across a recomputation block.  Elsewhere a loop over blocks of
 ``ATTN_XLA_BLOCK`` queries in XLA with the same mathematics; at ``T <=
 topk`` its attention is ``causal_attention``'s bit for bit.
 """
@@ -168,7 +170,7 @@ def _sparse_fwd(q, k, v, qI, kI, w, real, scale, topk):
         with jax.named_scope("indexer_loss"):
             kl_rows, dqI, dkI, dw = PK.indexer_loss_pallas(
                 qh, kh, lse, qIh, kIc, wf, lse_i, real[..., None], keep,
-                scale=scale, block=block)
+                scores, scale=scale, block=block)
             kl = jnp.sum(kl_rows, (1, 2))
             dqI = jnp.swapaxes(dqI, 1, 2)
         kept = jnp.sum(jnp.where(real[..., None] > 0, keep, 0), (1, 2),

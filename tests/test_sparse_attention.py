@@ -116,11 +116,14 @@ def dense(q, k, v, qI, kI, w, scale, topk):
 
 
 @pytest.mark.parametrize("path", PATHS, indirect=True)
-@pytest.mark.parametrize("t,topk", [(256, 64), (384, 100)])
-def test_op_matches_the_dense_form(path, t, topk):
+@pytest.mark.parametrize("t,topk,heads", [(256, 64, 4), (384, 100, 4),
+                                          (384, 100, 8)])
+def test_op_matches_the_dense_form(path, t, topk, heads):
     """Output, ``L_I``, the pairs kept and all six gradients, with repeated
-    scores in every row; a loss that weighs both the output and ``L_I``."""
-    args = operands(t)
+    scores in every row; a loss that weighs both the output and ``L_I``.
+    With 8 heads a key-value head's group is 4 wide: ``indexer_loss`` adds a
+    group in one grid step, over three tiles a side."""
+    args = operands(t, heads=heads)
     scale = 64 ** -0.5
     weights = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32).reshape(
         args[0].shape) * 0.1)
@@ -254,11 +257,19 @@ def test_the_target_sums_to_one_over_the_kept_positions():
 
 
 @pytest.mark.parametrize("path", PATHS, indirect=True)
-def test_padded_queries_add_nothing_to_the_indexers_loss(path):
+@pytest.mark.parametrize("t,heads,lengths", [(256, 4, (256, 200)),
+                                             (384, 8, (380, 200))])
+def test_padded_queries_add_nothing_to_the_indexers_loss(path, t, heads,
+                                                         lengths,
+                                                         monkeypatch):
     """``real`` 0 at a row's last queries: their pairs are not counted and
-    ``L_I`` and its gradient are those of the real queries alone."""
-    args = operands(256)
-    real = jnp.asarray(np.arange(256)[None, :] < np.array([[256], [200]]),
+    ``L_I`` and its gradient are those of the real queries alone.  The
+    second case has groups of 4 heads over 2 key-value heads, three tiles a
+    side and both rows padded: a group's sum crosses the kernel's slabs, its
+    tiles and the diagonal; there the kernels are held to the XLA path
+    too."""
+    args = operands(t, heads=heads)
+    real = jnp.asarray(np.arange(t)[None, :] < np.array(lengths)[:, None],
                        jnp.float32)
     run = lambda *a: SA.sparse_attention(  # noqa: E731
         *a, scale=0.125, topk=64, real=real)
@@ -282,6 +293,77 @@ def test_padded_queries_add_nothing_to_the_indexers_loss(path):
                      argnums=(3, 4, 5))(*args)
     for g, g0 in zip(got_g, want_g):
         assert rel(g, g0) <= 2e-5
+    if path == "kernels":
+        monkeypatch.setattr(SA, "sparse_kernel_blocks", lambda *a: None)
+        np.testing.assert_allclose(kl, run(*args)[1], rtol=1e-5)
+        xla_g = jax.grad(lambda *a: jnp.sum(run(*a)[1]),
+                         argnums=(3, 4, 5))(*args)
+        for g, g0 in zip(got_g, xla_g):
+            assert rel(g, g0) <= 2e-5
+
+
+def loss_kernel_operands(t, block):
+    """``indexer_loss_pallas``'s operands as the kernels' path makes them,
+    heads-major, float32; 8 query heads over 2 key-value heads."""
+    q, k, v, qI, kI, w = operands(t, heads=8)
+    qh, kh, vh, qIh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v, qI))
+    scores = PK.indexer_scores_pallas(qIh, kI, w, block=block)
+    keep, lse_i = PK.topk_select_pallas(scores, topk=100, rows=128)
+    _, lse = PK.flash_attn_fwd_pallas(qh, kh, vh, scale=0.125, block_q=block,
+                                      block_k=block, keep=keep)
+    real = jnp.ones((B, t, 1), jnp.float32)
+    return [qh, kh, lse, qIh, kI, w, lse_i, real, keep], scores
+
+
+def test_indexer_loss_reads_minus_inf_above_the_diagonal_as_nothing():
+    """The scores the kernel reads hold ``-inf`` above the diagonal where
+    the tile it used to make again held finite numbers: every use is under
+    the selection's mask, so no NaN reaches ``kl`` or a gradient, and the
+    results are bit for bit those of scores that are finite there."""
+    t, block = 256, 128
+    args, scores = loss_kernel_operands(t, block)
+    assert bool(jnp.all(jnp.isneginf(scores[:, 0, 1:])))
+    got = PK.indexer_loss_pallas(*args, scores, scale=0.125, block=block)
+    finite = jnp.where(jnp.isneginf(scores), 0.25, scores)
+    want = PK.indexer_loss_pallas(*args, finite, scale=0.125, block=block)
+    for name, a, b in zip(("kl", "dqI", "dkI", "dw"), got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    assert float(jnp.sum(got[0])) > 0
+
+
+def _grids(fn, *args):
+    """kernel name -> grid of every ``pallas_call`` in ``fn``'s jaxpr."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = tuple(
+                    eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_indexer_loss_steps_once_a_tile_and_key_value_head():
+    """At the cell's shapes (``tests/test_tpu_compile.py`` ``KEYE``: a row of
+    16384 in tiles of 1024, 32 query heads over 4 key-value heads) the
+    kernel's grid is a tile by a KEY-VALUE head, 1,024 steps: the query
+    head is no grid axis (PR 53; it was ``(1, 16, 16, 32)``)."""
+    T_, H, Hkv, dh, J, d, block = 16384, 32, 4, 128, 16, 64, 1024
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt)
+    f32 = jnp.float32
+    grids = _grids(
+        lambda *a: PK.indexer_loss_pallas(*a, scale=dh ** -0.5, block=block),
+        s((1, H, T_, dh)), s((1, Hkv, T_, dh)), s((1, H, T_, 1), f32),
+        s((1, J, T_, d)), s((1, T_, d)), s((1, T_, J), f32),
+        s((1, T_, 1), f32), s((1, T_, 1), f32), s((1, T_, T_), jnp.int8),
+        s((1, T_, T_), f32))
+    assert grids == {"indexer_loss": (1, 16, 16, 4)}
 
 
 # -- the layer and the model against the plain reference ---------------------

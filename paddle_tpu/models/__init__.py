@@ -16,3 +16,4 @@ with _setup_phase("import"):   # the set-up record: this package's import
     from paddle_tpu.models.nemotron_h import nemotron_h_net
     from paddle_tpu.models.keye_vl2 import keye_vl2_net
     from paddle_tpu.models.laguna import laguna_net
+    from paddle_tpu.models.ouro import ouro_net

@@ -16,6 +16,22 @@ prefixes): what the mixer's builder names its layer, ``mlp<i>`` / ``moe<i>``,
 ``emb``, ``norm_out``, ``cost``; the norms ``norm_op<i>`` and ``norm_ffn<i>``
 of a layer of two sub-blocks, ``norm<i>`` of a layer of one.
 
+A stack may run its layers several times over ONE set of weights
+(``loops``): pass ``t``'s layers are named ``loop<t>/attn<i>``,
+``loop<t>/mlp<i>`` and so on (the device trace's scope path of an operation
+then holds the pass AND the layer) while their leaves keep the names of a
+stack of one pass (``_attn<i>.wq``): ``Topology`` holds one spec and one
+array a name, and a leaf's gradient is the sum over its uses.  The final
+norm runs after EVERY pass, its result the next pass's input.  With
+``post_norm`` a sub-block's result is normed again before it is added
+(sandwich: ``h = x + RMSNorm(Op_i(RMSNorm(x)))``; norms ``post_op<i>`` and
+``post_ffn<i>``).  With ``exit_gate`` every pass is an exit: the head's
+cross-entropy of every token after each pass, a gate's logit a token, and
+the expected loss over the exits (``nn.loop_exit_cost``); the exits' layers
+are ``exits/pass<t>/norm_out``, ``.../exit_head``, ``.../exit_gate`` and
+``exits/exit_mix``, their leaves ``_norm_out.w``, ``_cost.w``,
+``_exit_gate.w`` / ``.b``.
+
 A mixer may bring terms of its own to the loss (a learned indexer's): its
 builder then returns ``(layer, riders)``, and the stack's cost is ``(sum of
 the cross-entropies + sum of the riders marked as terms) / tokens``.
@@ -44,7 +60,9 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                   tie_head: bool = True, recompute_layers=True,
                   ffn_layer_type: Optional[str] = None,
                   expert_act: str = "gated_silu",
-                  selection_bias: bool = True):
+                  selection_bias: bool = True, loops: int = 1,
+                  post_norm: bool = False, exit_gate: bool = False,
+                  exit_beta: float = 0.1):
     """Returns ``(cost, extras)``: the mean next-token cross-entropy over
     ``tokens`` / ``next_tokens`` (two int sequence feeds of one length), and
     two extra outputs per expert layer, marked for the counters
@@ -76,7 +94,31 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     ``recompute_layers`` marks decoder layers as
     recomputation blocks, one block a layer: ``True`` for every layer, or
     the indices of the layers to recompute (the others hold their
-    activations)."""
+    activations).
+
+    ``loops``: the layers run this many times over the same leaves, the
+    final norm after every pass; a mixer's builder is then called
+    ``mixers[kind](normed, i, scope)`` with ``scope`` ``"loop<t>/"``, which
+    it puts before its layer's name and NOT before its leaves' (``name=
+    f"{scope}attn{i}", param_name=f"attn{i}"``); one recomputation block a
+    (pass, layer).  Every feed-forward of a looped stack is a gated MLP
+    (``nn.expert_mlp`` names its leaves after its layer), and a layer has
+    both sub-blocks.  ``post_norm``: the sandwich norms.  ``exit_gate``: the
+    cost is ``nn.loop_exit_cost`` over the passes' exits at ``exit_beta``,
+    each exit (final norm, head, cross-entropy, gate) a recomputation block
+    of its own where layers are recomputed, so that ONE exit's logits are
+    alive at a time; the extras then carry the sums over the step's real
+    tokens of each exit's mass, of each exit's cross-entropy and of the
+    entropy, for the counters ``loop_exit_mass{step}``,
+    ``loop_exit_ce{step}`` and ``loop_exit_entropy``."""
+    if loops < 1:
+        raise ValueError(f"a stack of {loops} passes")
+    if loops > 1 and (ffn_layer_type is not None
+                      or num_dense_layers < len(layer_types)):
+        raise ValueError("a looped stack has layers of two sub-blocks whose "
+                         "feed-forward is a gated MLP")
+    if post_norm and ffn_layer_type is not None:
+        raise ValueError("sandwich norms are of a layer of two sub-blocks")
     tokens = nn.data("tokens", size=vocab_size, is_seq=True, dtype="int32")
     targets = nn.data("next_tokens", size=vocab_size, is_seq=True,
                       dtype="int32")
@@ -97,11 +139,13 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     if not selection_bias:
         routing["selection_bias"] = False
 
-    def feed_forward(normed, i):
+    def feed_forward(normed, i, scope=""):
         """Layer ``i``'s feed-forward over ``normed`` and what rides with it
         (an expert layer's two counters)."""
         if i < num_dense_layers:
-            return nn.gated_mlp(normed, intermediate_size, name=f"mlp{i}"), []
+            shared = {"param_name": f"mlp{i}"} if scope else {}
+            return nn.gated_mlp(normed, intermediate_size,
+                                name=f"{scope}mlp{i}", **shared), []
         ffn = nn.expert_mlp(
             normed, moe_intermediate_size, num_experts=num_experts,
             experts_held=experts_held, top_k=num_experts_per_tok,
@@ -120,42 +164,87 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
             "labels": {"layer": f"moe{i}"}}
         return ffn, [load, dropped]
 
-    def mixer(kind, normed, i):
+    def mixer(kind, normed, i, scope=""):
         """Layer ``i``'s mixer and what rides with it."""
-        built = mixers[kind](normed, i)
+        built = mixers[kind](normed, i, *([scope] if scope else []))
         layer, riders = built if isinstance(built, tuple) else (built, [])
         aux_costs.extend(r for r in riders if r.meta.get("loss_term"))
         extras.extend(r for r in riders if "obs_counter" in r.meta)
         return layer, riders
 
-    for i, kind in enumerate(layer_types):
-        if kind not in mixers and kind != ffn_layer_type:
-            raise ValueError(f"layer {i}: unknown layer type {kind!r}")
-        if ffn_layer_type is not None:      # one sub-block a layer
-            normed = nn.rms_norm(x, eps=norm_eps, name=f"norm{i}", **centred)
-            if kind == ffn_layer_type:
-                sub, counters = feed_forward(normed, i)
-                extras += counters
+    def norm(x, name, scope=""):
+        """An RMSNorm.  Under a ``scope`` the layer is ``<scope><name>`` and
+        its leaf ``_<name>.w`` whatever the scope: one leaf for every pass."""
+        shared = {"param_attr": nn.ParamAttr(
+            name=f"_{name}.w", init="zeros" if zero_centered_norm else "ones")
+        } if scope else {}
+        return nn.rms_norm(x, eps=norm_eps, name=f"{scope}{name}", **centred,
+                           **shared)
+
+    def sub_block(result, name, scope):
+        """A sub-block's layers, the last of them what the residual add
+        takes: its result, and under ``post_norm`` that normed again."""
+        return [result, norm(result, name, scope)] if post_norm else [result]
+
+    outs = []
+    for t in range(loops):
+        scope = f"loop{t}/" if loops > 1 else ""
+        for i, kind in enumerate(layer_types):
+            if kind not in mixers and kind != ffn_layer_type:
+                raise ValueError(f"layer {i}: unknown layer type {kind!r}")
+            if ffn_layer_type is not None:      # one sub-block a layer
+                normed = norm(x, f"norm{i}")
+                if kind == ffn_layer_type:
+                    sub, counters = feed_forward(normed, i)
+                    extras += counters
+                else:
+                    sub, counters = mixer(kind, normed, i)
+                x = nn.addto([x, sub], name=f"res{i}")
+                block = [normed, *counters, sub, x]
             else:
-                sub, counters = mixer(kind, normed, i)
-            x = nn.addto([x, sub], name=f"res{i}")
-            block = [normed, *counters, sub, x]
-        else:
-            normed = nn.rms_norm(x, eps=norm_eps, name=f"norm_op{i}",
-                                 **centred)
-            op, riders = mixer(kind, normed, i)
-            h = nn.addto([x, op], name=f"res_op{i}")
-            normed2 = nn.rms_norm(h, eps=norm_eps, name=f"norm_ffn{i}",
-                                  **centred)
-            ffn, counters = feed_forward(normed2, i)
-            extras += counters
-            x = nn.addto([h, ffn], name=f"res_ffn{i}")
-            block = [normed, op, *riders, h, normed2, *counters, ffn, x]
-        if recompute_layers is True or (recompute_layers
-                                        and i in recompute_layers):
-            nn.remat_block(block, f"layer{i}")
-    out = nn.rms_norm(x, eps=norm_eps, name="norm_out", **centred)
-    cost = nn.lm_head_cost(out, targets, embedding=emb if tie_head else None,
-                           name="cost", **({"aux_costs": aux_costs}
-                                           if aux_costs else {}))
+                normed = norm(x, f"norm_op{i}", scope)
+                op, riders = mixer(kind, normed, i, scope)
+                op = sub_block(op, f"post_op{i}", scope)
+                h = nn.addto([x, op[-1]], name=f"{scope}res_op{i}")
+                normed2 = norm(h, f"norm_ffn{i}", scope)
+                ffn, counters = feed_forward(normed2, i, scope)
+                extras += counters
+                ffn = sub_block(ffn, f"post_ffn{i}", scope)
+                x = nn.addto([h, ffn[-1]], name=f"{scope}res_ffn{i}")
+                block = [normed, *op, *riders, h, normed2, *counters, *ffn, x]
+            if recompute_layers is True or (recompute_layers
+                                            and i in recompute_layers):
+                nn.remat_block(block, f"{scope}layer{i}")
+        x = norm(x, "norm_out", f"exits/pass{t}/" if exit_gate else scope)
+        outs.append(x)
+    head = emb if tie_head else None
+    if not exit_gate:
+        cost = nn.lm_head_cost(x, targets, embedding=head, name="cost",
+                               **({"aux_costs": aux_costs}
+                                  if aux_costs else {}))
+        return cost, extras
+    if aux_costs:
+        raise ValueError("a mixer's own loss terms beside the exits' "
+                         "expected loss: not built")
+    ces, gates = [], []
+    for t, out in enumerate(outs):
+        ces.append(nn.lm_head_token_cost(
+            out, targets, embedding=head, name=f"exits/pass{t}/exit_head",
+            param_name="cost"))
+        if t < loops - 1:        # the last exit takes the mass that is left
+            gates.append(nn.token_gate(out, name=f"exits/pass{t}/exit_gate",
+                                       param_name="exit_gate"))
+        if recompute_layers:
+            nn.remat_block([out, ces[t], *gates[t:]], f"exit{t}")
+    cost = nn.loop_exit_cost(ces, gates, targets, beta=exit_beta,
+                             name="exits/exit_mix")
+    for key, counter, by_step in (("exit_mass", "loop_exit_mass", True),
+                                  ("exit_ce", "loop_exit_ce", True),
+                                  ("exit_entropy", "loop_exit_entropy",
+                                   False)):
+        rider = nn.get_output(cost, key, size=loops if by_step else 1,
+                              name=key)
+        rider.meta["obs_counter"] = {"name": counter, **(
+            {"index_label": "step", "first_index": 1} if by_step else {})}
+        extras.append(rider)
     return cost, extras

@@ -7,12 +7,18 @@ state-space layer: a state a head, decayed by one scalar a token), a gated
 MLP,
 a dropless expert layer that holds a share of the experts (with shared
 experts beside them, where the model has them), and a next-token cost over a
-head that is the embedding (tied) or a matrix of its own.
+head that is the embedding (tied) or a matrix of its own: the mean over the
+tokens, or one cross-entropy a token for a cost that weighs them itself (the
+expected loss over the exits of a stack whose layers run several times, with
+the gate that gives each exit its weight).
 
 The reference (2016) has none of these; they follow its DSL conventions all
 the same (``input=`` first, ``name=``, parameters ``_<name>.<leaf>``, one
 ``jax.named_scope`` per layer through ``Topology.apply``).  None has a bias
 but the Mamba-2 mixer's convolution.
+A layer's leaves are named after the layer unless it is given a
+``param_name`` (``rms_norm``: a ``param_attr`` with a name): layers that name
+the same leaves are several applications of one set of weights.
 A stack marks the layers of one block with :func:`remat_block`, and
 ``Topology.apply`` then recomputes the block in the backward pass instead of
 holding its activations.
@@ -40,7 +46,8 @@ __all__ = ["rms_norm", "gated_short_conv", "causal_self_attention",
            "indexed_self_attention",
            "latent_attention", "gated_delta_net", "mamba2_mixer", "gated_mlp",
            "expert_mlp",
-           "lm_head_cost",
+           "lm_head_cost", "lm_head_token_cost", "token_gate",
+           "loop_exit_cost",
            "remat_block"]
 
 
@@ -140,7 +147,8 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
                           qk_norm: bool = True, rotary: bool = True,
                           window: Optional[int] = None,
                           rope_scaling: Optional[Mapping] = None,
-                          name: Optional[str] = None) -> LayerOutput:
+                          name: Optional[str] = None,
+                          param_name: Optional[str] = None) -> LayerOutput:
     """Causal grouped-query self-attention: RMSNorm over every query head
     and every key head (one weight vector each; ``zero_centered_norm``: the
     ``1 + w`` form), rotary embedding (on the first ``rotary_dim`` channels
@@ -168,7 +176,13 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
     ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
     ``attention_factor``): the rotary embedding's frequencies and the factor
     on its cos and sin are ``ops.decoder_block.yarn_frequencies``'s over the
-    turned channels; ``rope_type`` ``default`` scales nothing."""
+    turned channels; ``rope_type`` ``default`` scales nothing.
+
+    ``param_name``: what the leaves are named after, ``_<param_name>.wq``
+    and so on (default: the layer's own name).  Two layers given the same
+    one are two applications of ONE set of weights: ``Topology`` holds one
+    spec and one array a name, and a leaf's gradient is the sum over its
+    uses (a stack whose layers run several times)."""
     name = name or next_name("self_attention")
     if num_heads % num_kv_heads:
         raise ConfigError(f"{name!r}: {num_heads} query heads are not whole "
@@ -194,7 +208,7 @@ def causal_self_attention(input: LayerOutput, *, num_heads: int,
         raise ConfigError(f"{name!r}: unknown rope_type {kind!r}")
     head_gate = output_gate == "head"
     dq = 2 * dh if output_gate is True else dh
-    specs = _attention_specs(name, D, H, Hkv, dh, dq, qk_norm,
+    specs = _attention_specs(param_name or name, D, H, Hkv, dh, dq, qk_norm,
                              zero_centered_norm, head_gate)
 
     def forward(ctx, params, a: Act) -> Act:
@@ -620,11 +634,15 @@ def _relu2_mlp(x, w1, w2):
 
 
 def gated_mlp(input: LayerOutput, size: int, *,
-              name: Optional[str] = None) -> LayerOutput:
-    """``W_2(silu(W_1 x) * W_3 x)`` with ``size`` hidden units."""
+              name: Optional[str] = None,
+              param_name: Optional[str] = None) -> LayerOutput:
+    """``W_2(silu(W_1 x) * W_3 x)`` with ``size`` hidden units.
+    ``param_name``: what the three leaves are named after (default: the
+    layer's name); layers given the same one share them
+    (:func:`causal_self_attention`)."""
     name = name or next_name("gated_mlp")
     D = input.size
-    specs = _mlp_specs(f"_{name}.", D, size)
+    specs = _mlp_specs(f"_{param_name or name}.", D, size)
 
     def forward(ctx, params, a: Act) -> Act:
         out = _gated_mlp(a.value, *(params[s.name] for s in specs))
@@ -735,6 +753,20 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                        specs + shared + gate)
 
 
+def _head_spec(name: str, input: LayerOutput, label: LayerOutput,
+               embedding: Optional[LayerOutput]) -> ParamSpec:
+    """The head's leaf: the embedding's matrix (tied) or ``_<name>.w``
+    ``[hidden, label.size]``."""
+    if embedding is None:
+        return ParamSpec(f"_{name}.w", (input.size, label.size),
+                         _fan_in(f"_{name}.w", input.size))
+    head = embedding.param_specs[0]
+    if head.shape[1] != input.size:
+        raise ConfigError(f"{name!r}: the embedding is {head.shape[1]} "
+                          f"wide, the hidden state {input.size}")
+    return head
+
+
 def lm_head_cost(input: LayerOutput, label: LayerOutput, *,
                  embedding: Optional[LayerOutput] = None,
                  aux_costs: Sequence[LayerOutput] = (),
@@ -749,14 +781,7 @@ def lm_head_cost(input: LayerOutput, label: LayerOutput, *,
     cost is then ``(sum of the cross-entropies + sum of the terms) /
     tokens``."""
     name = name or next_name("lm_cost")
-    if embedding is None:
-        head = ParamSpec(f"_{name}.w", (input.size, label.size),
-                         _fan_in(f"_{name}.w", input.size))
-    else:
-        head = embedding.param_specs[0]
-        if head.shape[1] != input.size:
-            raise ConfigError(f"{name!r}: the embedding is {head.shape[1]} "
-                              f"wide, the hidden state {input.size}")
+    head = _head_spec(name, input, label, embedding)
 
     def forward(ctx, params, h: Act, lab: Act, *aux: Act) -> Act:
         w = params[head.name]
@@ -772,6 +797,117 @@ def lm_head_cost(input: LayerOutput, label: LayerOutput, *,
 
     return LayerOutput(name, "lm_head_cost", 1, [input, label, *aux_costs],
                        forward, [head])
+
+
+def lm_head_token_cost(input: LayerOutput, label: LayerOutput, *,
+                       embedding: Optional[LayerOutput] = None,
+                       name: Optional[str] = None,
+                       param_name: Optional[str] = None) -> LayerOutput:
+    """The next-token cross-entropy of EVERY position, ``[B, T]`` float32
+    (a padded position's too: what reads it masks), over the head
+    :func:`lm_head_cost` has: the embedding's matrix, or a leaf
+    ``_<param_name>.w`` (default: the layer's name) that layers given the
+    same ``param_name`` share.  Through
+    ``ops.softmax_ce_readout_per_token``: the kernels and the logits'
+    dtype are ``lm_head_cost``'s, and the backward pass takes a cotangent a
+    token, so a cost may weigh each token's cross-entropy by something that
+    has a gradient of its own (:func:`loop_exit_cost`)."""
+    name = name or next_name("lm_token_cost")
+    head = _head_spec(param_name or name, input, label, embedding)
+
+    def forward(ctx, params, h: Act, lab: Act) -> Act:
+        w = params[head.name]
+        w = w if embedding is None else w.T
+        return _seq_like(h, O.softmax_ce_readout_per_token(
+            h.value, w, jnp.zeros((w.shape[1],), w.dtype), lab.value))
+
+    return LayerOutput(name, "lm_head_token_cost", 1, [input, label],
+                       forward, [head])
+
+
+def token_gate(input: LayerOutput, *, name: Optional[str] = None,
+               param_name: Optional[str] = None) -> LayerOutput:
+    """One number a token, ``x . w + b`` in float32, ``[B, T]``: a gate's
+    logit.  Leaves ``_<param_name>.w`` ``[hidden]`` and ``_<param_name>.b``
+    ``[1]`` (default: the layer's name; layers given the same one share
+    them)."""
+    name = name or next_name("token_gate")
+    pre = f"_{param_name or name}"
+    specs = [ParamSpec(f"{pre}.w", (input.size,),
+                       _fan_in(f"{pre}.w", input.size)),
+             ParamSpec(f"{pre}.b", (1,), _pa(None, f"{pre}.b", init="zeros"))]
+
+    def forward(ctx, params, a: Act) -> Act:
+        f32 = jnp.float32
+        g = jnp.sum(a.value.astype(f32) * params[specs[0].name].astype(f32),
+                    -1) + params[specs[1].name].astype(f32)
+        return _seq_like(a, g) if a.is_seq else Act(value=g)
+
+    return LayerOutput(name, "token_gate", 1, [input], forward, specs)
+
+
+def exit_distribution(gates):
+    """``[R, ...]`` from the ``R - 1`` gate logits ``[R - 1, ...]`` of all
+    exits but the last, float32: ``lam_t = sigmoid(g_t)``, ``S_t = S_(t-1)
+    (1 - lam_t)`` from ``S_0 = 1``, ``p_t = lam_t S_(t-1)`` and the mass
+    that is left, ``S_(R-1)``, on the last exit.  ``1 - lam_t`` is taken as
+    ``sigmoid(-g_t)`` (no cancellation where a gate saturates), so a token's
+    masses add up to 1 within rounding whatever the logits."""
+    g = jnp.asarray(gates, jnp.float32)
+    stay = jnp.cumprod(jax.nn.sigmoid(-g), axis=0)          # S_1 .. S_(R-1)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], 0)
+    return jnp.concatenate([jax.nn.sigmoid(g) * before, stay[-1:]], 0)
+
+
+def loop_exit_cost(exits: Sequence[LayerOutput],
+                   gates: Sequence[LayerOutput], label: LayerOutput, *,
+                   beta: float = 0.1,
+                   name: Optional[str] = None) -> LayerOutput:
+    """The expected loss of a stack that may leave after any of its ``R``
+    passes.  ``exits``: the ``R`` per-token cross-entropies ``CE_t`` ``[B,
+    T]`` (:func:`lm_head_token_cost`), ``gates``: the gate logits ``g_t``
+    ``[B, T]`` of all exits but the last (:func:`token_gate`), ``label``:
+    the targets (their mask says which tokens are real).  With ``p`` the
+    token's :func:`exit_distribution`::
+
+        cost = sum over real tokens [sum_t p_t CE_t - beta H(p)] / tokens
+
+    ``H(p) = -sum_t p_t log p_t``, the entropy against a uniform prior over
+    the exits (``beta`` 0: the expected cross-entropy alone).  All of it in
+    float32; ``log p_t`` is taken of 1 where ``p_t`` is 0 in float32, so a
+    saturated gate gives that exit no entropy and no NaN, forward or
+    backward.  One exit and no gate: ``p_1 = 1``, the plain mean
+    cross-entropy.
+
+    ``Act.state`` carries the sums over the real tokens of ``p_t``
+    (``exit_mass`` ``[R]``), of ``CE_t`` (``exit_ce`` ``[R]``) and of
+    ``H(p)`` (``exit_entropy``)."""
+    name = name or next_name("loop_exit_cost")
+    exits, gates = list(exits), list(gates)
+    if not exits or len(gates) != len(exits) - 1:
+        raise ConfigError(f"{name!r}: {len(exits)} exits take "
+                          f"{max(len(exits) - 1, 0)} gates, not {len(gates)}")
+
+    def forward(ctx, params, lab: Act, *acts: Act) -> Act:
+        from paddle_tpu.ops.losses import token_count
+
+        f32 = jnp.float32
+        mask = lab.mask.astype(f32)
+        ce = jnp.stack([a.value.astype(f32) for a in acts[:len(exits)]])
+        if gates:
+            p = exit_distribution([a.value for a in acts[len(exits):]])
+        else:
+            p = jnp.ones_like(ce)
+        entropy = -jnp.sum(p * jnp.log(jnp.where(p > 0, p, 1.0)), 0)
+        per_tok = jnp.sum(p * ce, 0) - beta * entropy
+        cost = jnp.sum(per_tok * mask) / token_count(mask)
+        return Act(value=cost, state={
+            "exit_mass": jnp.sum(p * mask, (1, 2)),
+            "exit_ce": jnp.sum(ce * mask, (1, 2)),
+            "exit_entropy": jnp.sum(entropy * mask)})
+
+    return LayerOutput(name, "loop_exit_cost", 1, [label, *exits, *gates],
+                       forward, [])
 
 
 from paddle_tpu.config.capture import wrap_module as _wrap_module  # noqa: E402

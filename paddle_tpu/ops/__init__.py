@@ -22,6 +22,7 @@ from paddle_tpu.ops.losses import (
     masked_token_mean,
     sequence_cross_entropy,
     sequence_softmax_ce_readout,
+    softmax_ce_readout_per_token,
 )
 from paddle_tpu.ops.sequence import (
     PACK_KEYS,

@@ -277,18 +277,20 @@ def _tiled_ce_cfg(B, T, D, V):
 def _tiled_ce_fn(rb, vt, V, sdt, wdt, bdt):
     """custom_vjp instance for one static (row_block, v_tile, V, dtypes)
     configuration of the vocab-tiled Pallas CE (kernels in
-    ops/pallas_kernels.py: ce_readout_fwd/bwd_pallas)."""
+    ops/pallas_kernels.py: ce_readout_fwd/bwd_pallas): ``[B, T]``
+    cross-entropies out, a ``[B, T]`` cotangent in (the kernels never saw
+    the mask: a mean over real tokens is its caller's)."""
     from paddle_tpu.ops.pallas_kernels import (ce_readout_bwd_pallas,
                                                ce_readout_fwd_pallas)
 
     f32 = jnp.float32
 
     @jax.custom_vjp
-    def tiled(states, w, b, labels, mask):
-        loss, _ = fwd(states, w, b, labels, mask)
-        return loss
+    def tiled(states, w, b, labels):
+        per_tok, _ = fwd(states, w, b, labels)
+        return per_tok
 
-    def fwd(states, w, b, labels, mask):
+    def fwd(states, w, b, labels):
         from paddle_tpu.ops.numerics import mxu_cast
 
         B, T, D = states.shape
@@ -303,30 +305,51 @@ def _tiled_ce_fn(rb, vt, V, sdt, wdt, bdt):
         lab = labels.astype(jnp.int32).reshape(N, 1)
         per_tok, lse, logits = ce_readout_fwd_pallas(
             sc, w_p, b_p, lab, row_block=rb, v_tile=vt)
-        loss = masked_token_mean(per_tok.reshape(B, T), mask)
         # residual saves the PRIMAL w (free — aliases the input); the padded
         # compute-dtype copy is re-derived in bwd rather than pinning an
         # extra [D, Vp] buffer across the fwd->bwd interval
-        return loss, (sc, w, lab, lse, logits, mask)
+        return per_tok.reshape(B, T), (sc, w, lab, lse, logits)
 
     def bwd(res, d):
         from paddle_tpu.ops.numerics import mxu_cast
 
-        sc, w, lab, lse, logits, mask = res
+        sc, w, lab, lse, logits = res
         w_p = jnp.pad(mxu_cast(w), ((0, 0), (0, logits.shape[1] - V)))
         N, D = sc.shape
-        B, T = mask.shape
-        mask_f = mask.astype(f32)
-        denom = token_count(mask_f)
-        scale = (d * mask_f / denom).reshape(N, 1)
+        B, T = d.shape
         d_states, d_w_p, d_b_p = ce_readout_bwd_pallas(
-            logits, sc, w_p, lab, lse, scale, v_tile=vt)
+            logits, sc, w_p, lab, lse, d.reshape(N, 1), v_tile=vt)
         return (d_states.reshape(B, T, D).astype(sdt),
                 d_w_p[:, :V].astype(wdt),
-                d_b_p[0, :V].astype(bdt), None, None)
+                d_b_p[0, :V].astype(bdt), None)
 
     tiled.defvjp(fwd, bwd)
     return tiled
+
+
+def softmax_ce_readout_per_token(states, w, b, labels):
+    """The readout's cross-entropy of every position, ``[B, T]`` float32:
+    ``logsumexp(states w + b) - (states w + b)[label]``, padded positions
+    included (the caller masks).  What :func:`sequence_softmax_ce_readout`
+    is the masked mean of, on the same two paths: the vocab-tiled kernel
+    pair where ``_tiled_ce_cfg`` admits the shape (its backward takes the
+    ``[B, T]`` cotangent as the kernel's per-row scale), else the logits
+    once in the compute dtype and XLA's reductions.  A loss that weighs
+    each token's cross-entropy by something that has a gradient of its own
+    (``nn.loop_exit_cost``) reads this form."""
+    cfg = _tiled_ce_cfg(states.shape[0], states.shape[1], states.shape[2],
+                        w.shape[1])
+    if cfg is not None:
+        fn = _tiled_ce_fn(cfg[0], cfg[1], int(w.shape[1]),
+                          str(states.dtype), str(w.dtype), str(b.dtype))
+        return fn(states, w, b, labels)
+    logits = _readout_logits(states, w, b)
+    lf32 = lambda: logits.astype(jnp.float32)          # fused upcast per use
+    m = jnp.max(lf32(), axis=-1, keepdims=True)
+    lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(lf32() - m), axis=-1))
+    lab = jnp.expand_dims(labels.astype(jnp.int32), -1)
+    tok = jnp.squeeze(jnp.take_along_axis(logits, lab, axis=-1), -1)
+    return lse - tok.astype(jnp.float32)
 
 
 def sequence_softmax_ce_readout(states, w, b, labels, mask):
@@ -343,21 +366,12 @@ def sequence_softmax_ce_readout(states, w, b, labels, mask):
     d_logits buffer never exists in HBM.  Off-TPU (or gated shapes), the
     logits are materialized once in the compute dtype and XLA's fused
     reductions produce the statistics — both match ``linear`` +
-    ``sequence_cross_entropy`` numerics to bf16 rounding.
+    ``sequence_cross_entropy`` numerics to bf16 rounding.  The masked mean
+    of :func:`softmax_ce_readout_per_token`.
     """
-    cfg = _tiled_ce_cfg(states.shape[0], states.shape[1], states.shape[2],
-                        w.shape[1])
-    if cfg is not None:
-        fn = _tiled_ce_fn(cfg[0], cfg[1], int(w.shape[1]),
-                          str(states.dtype), str(w.dtype), str(b.dtype))
-        return fn(states, w, b, labels, mask)
-    if _USE_PALLAS_LSE_READOUT:
+    if _USE_PALLAS_LSE_READOUT and _tiled_ce_cfg(
+            states.shape[0], states.shape[1], states.shape[2],
+            w.shape[1]) is None:
         return _ce_readout_fused(states, w, b, labels, mask)
-    logits = _readout_logits(states, w, b)
-    lf32 = lambda: logits.astype(jnp.float32)          # fused upcast per use
-    m = jnp.max(lf32(), axis=-1, keepdims=True)
-    lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(lf32() - m), axis=-1))
-    lab = jnp.expand_dims(labels.astype(jnp.int32), -1)
-    tok = jnp.squeeze(jnp.take_along_axis(logits, lab, axis=-1), -1)
-    per_tok = lse - tok.astype(jnp.float32)
-    return masked_token_mean(per_tok, mask)
+    return masked_token_mean(
+        softmax_ce_readout_per_token(states, w, b, labels), mask)

@@ -76,6 +76,12 @@ OUTDATED_PINS = {
         "nemotron3nano-train-b1-t4096 and its seven after them (what still "
         "holds of it is asserted again, by position relative to its "
         "neighbours, in tests/benchmark/test_nemotron_cell.py)",
+    "tests/benchmark/test_setup_phase_s.py::"
+    "test_only_the_lstm_cell_holds_them[ouro-train-b1-t4096]":
+        "its last line pins the list of ALL cells as PR 52 found it; PR 54 "
+        "appended ouro-train-b1-t4096 (that the cell holds none of the "
+        "eight set-up metrics is held with ``==`` on its whole per-layer "
+        "set in tests/benchmark/test_ouro_cell.py)",
 }
 
 

@@ -761,6 +761,68 @@ def case_lm_head_cost(rng):
     return nn.lm_head_cost(nn.rms_norm(emb), lab, embedding=emb), feed
 
 
+def _token_costs(rng, exits=3):
+    """``exits`` normed states over one embedding, the targets, the feed."""
+    ids, feed = _ids(rng)
+    lab = nn.data("next", size=V, is_seq=True, dtype="int32")
+    feed["next"] = (rng.randint(0, V, (B, T)).astype(np.int32),
+                    feed["ids"][1])
+    emb = nn.embedding(ids, D, vocab_size=V, name="emb")
+    states = [nn.rms_norm(_pre_fc(emb, name=f"pre{t}"), name=f"n{t}")
+              for t in range(exits)]
+    return states, lab, feed
+
+
+def case_lm_head_token_cost(rng):
+    # one cross-entropy a position over an untied head (PR 54); the sweep's
+    # random weights on the output are the per-token cotangent
+    (state,), lab, feed = _token_costs(rng, exits=1)
+    return nn.lm_head_token_cost(state, lab), feed
+
+
+def case_token_gate(rng):
+    xs, feed = _seq(rng)
+    return nn.token_gate(_pre_fc(xs)), feed
+
+
+def case_loop_exit_cost(rng):
+    # three exits through ONE head, two gates through ONE vector and bias:
+    # the expected loss with its entropy term (PR 54)
+    states, lab, feed = _token_costs(rng)
+    ces = [nn.lm_head_token_cost(s, lab, name=f"ce{t}", param_name="head")
+           for t, s in enumerate(states)]
+    gates = [nn.token_gate(s, name=f"g{t}", param_name="gate")
+             for t, s in enumerate(states[:-1])]
+    return nn.loop_exit_cost(ces, gates, lab, beta=0.1), feed
+
+
+def _sandwich(x, scope, eps=1e-6):
+    """A layer with a norm before AND after each sub-block, its leaves named
+    without ``scope``."""
+    shared = lambda n: nn.ParamAttr(name=f"_{n}.w", init="ones")  # noqa: E731
+    norm = lambda v, n: nn.rms_norm(v, eps=eps, name=scope + n,   # noqa: E731
+                                    param_attr=shared(n))
+    a = nn.causal_self_attention(norm(x, "n1"), num_heads=4, num_kv_heads=4,
+                                 head_dim=2, qk_norm=False,
+                                 name=scope + "attn", param_name="attn")
+    h = nn.addto([x, norm(a, "n2")], name=scope + "h")
+    m = nn.gated_mlp(norm(h, "n3"), 8, name=scope + "mlp", param_name="mlp")
+    return nn.addto([h, norm(m, "n4")], name=scope + "y")
+
+
+def case_sandwich_layer(rng):
+    # h = x + RMS(Attn(RMS(x))), y = h + RMS(MLP(RMS(h))) (PR 54)
+    xs, feed = _seq(rng)
+    return _sandwich(_pre_fc(xs, size=8), ""), feed
+
+
+def case_layer_pair_sharing_its_leaves(rng):
+    # the same layer applied twice under two scopes: eleven leaves, each
+    # used twice, and a leaf's gradient the sum over its uses (PR 54)
+    xs, feed = _seq(rng)
+    return _sandwich(_sandwich(_pre_fc(xs, size=8), "loop0/"), "loop1/"), feed
+
+
 FORWARD_ONLY = {"maxid", "sampling_id", "eos_id", "eos_trim", "crf_decoding",
                 "priorbox"}
 

@@ -933,7 +933,7 @@ def _cell_step(one_chip, name):
     def step(params, opt_state, feed):
         def loss(p):
             outs, _ = topo.apply(p, {}, feed, train=True)
-            return outs["cost"].value, [outs[e.name].value for e in extras]
+            return outs[cost.name].value, [outs[e.name].value for e in extras]
 
         (value, counts), grads = jax.value_and_grad(loss, has_aux=True)(params)
         # the bad-step guard around the update, as ``SGDTrainer._build_step``
@@ -1379,3 +1379,35 @@ def test_latent_attention_turns_its_queries_in_place(one_chip, on_tpu):
     assert sorted(sources) == ["copy", "fusion"], sources
     assert [r[1] for r in big] == ["copy"], big[:4]
 
+
+
+def test_ouro_cell_step_fits_the_chip(one_chip, on_tpu):
+    """PR 54.  The cell ``ouro-train-b1-t4096``'s whole step compiled for the
+    described v5e from shapes alone: six layers run four times over ONE set
+    of weights.  The compiled step takes each leaf ONCE (71 parameters, 71
+    x 2 Adam slots, the step counter and the feed: a leaf that four layers
+    of the graph read is one argument) and every parameter and slot comes
+    back in the buffer it went in by; 24 applications a step call the flash
+    kernels once each (their results are kept across a recomputation
+    block); each of the four exits is a recomputation block, so the head's
+    forward kernel runs twice an exit and its backward once, the first
+    decoder cell whose head runs the tiled pair at all; no instruction makes
+    an exit's logits in float32.  The compiler's own count of arguments,
+    results and temporaries stays under 14.0e9 bytes."""
+    compiled = _cell_step(one_chip, "ouro-train-b1-t4096")
+    text = compiled.as_text()
+    by_kernel = _kernel_calls(text)
+    assert {k: by_kernel.get(k) for k in (
+        "flash_attn_fwd", "flash_attn_bwd", "ce_readout_fwd",
+        "ce_readout_bwd")} == {
+            "flash_attn_fwd": 24, "flash_attn_bwd": 24, "ce_readout_fwd": 8,
+            "ce_readout_bwd": 4}
+    assert by_kernel.get("rotary_turn") == 24 * 2 * 3
+    assert not re.search(r"f32\[(1,)?4096,49152\]", text)
+    m = compiled.memory_analysis()
+    state = 3 * 4 * 509_661_185                               # p, m, v
+    assert state < m.argument_size_in_bytes < state + 1e6
+    assert state <= m.alias_size_in_bytes, m.alias_size_in_bytes
+    entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
+    assert len(re.findall(r" parameter\(\d+\)", entry)) == 3 * 71 + 1 + 4
+    assert _held_bytes(compiled) < 14.0e9, _held_bytes(compiled)

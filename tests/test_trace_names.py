@@ -262,6 +262,62 @@ def test_laguna_step_holds_its_layers_scopes():
         f"jvp(attn{i})", f"rematted_computation/attn{i}", f"attn{i}")}
 
 
+def test_ouro_step_holds_its_passes_and_exits_scopes():
+    """The scopes the Ouro-2.6B cell's per-layer metrics read (PR 54): the
+    pass ``loop<t>`` OUTSIDE the layer's own scope (``attn<i>``, ``mlp<i>``
+    and their norms), forward, recomputed and backward; ``exits`` with
+    ``exit_head``, ``exit_gate`` and ``exit_mix`` inside and no pass's scope
+    around them; the three counters ride the step as extra outputs."""
+    from paddle_tpu.models import ouro_net
+
+    nn.reset_naming()
+    cost, extras = ouro_net(
+        50, hidden_size=16, layer_types=["full_attention"] * 2,
+        num_attention_heads=4, num_key_value_heads=4, head_dim=4,
+        intermediate_size=24, total_ut_steps=4)
+    assert cost.name == "exits/exit_mix"
+    assert {e.name: e.meta["obs_counter"] for e in extras} == {
+        "exit_mass": {"name": "loop_exit_mass", "index_label": "step",
+                      "first_index": 1},
+        "exit_ce": {"name": "loop_exit_ce", "index_label": "step",
+                    "first_index": 1},
+        "exit_entropy": {"name": "loop_exit_entropy"}}
+    topo = nn.Topology([cost] + extras)
+    params, _ = topo.init(jax.random.PRNGKey(0))
+    ids = (np.ones((2, 6), np.int32), np.full((2,), 6, np.int32))
+    feed = {"tokens": ids, "next_tokens": ids}
+    grad = jax.make_jaxpr(jax.grad(lambda p: topo.apply(
+        p, {}, feed, train=True)[0][cost.name].value))(params)
+    stacks = _name_stacks(grad.jaxpr)
+    names = _scope_names(stacks)
+    assert {"loop0", "loop1", "loop2", "loop3", "attn0", "attn1", "mlp0",
+            "mlp1", "attn_core", "rotary", "norm_op0", "post_op0",
+            "norm_ffn1", "post_ffn1", "res_op0", "res_ffn1", "exits",
+            "pass0", "pass3", "norm_out", "exit_head", "exit_gate",
+            "exit_mix"} <= names
+    assert not {"loop4", "moe0", "cost", "pass4"} & names
+    # every layer's operation lies under a pass, the pass outside the layer
+    for s in stacks:
+        parts = [p for p in s.replace("(", "/").replace(")", "/").split("/")
+                 if p]
+        if any(p in ("attn0", "attn1", "mlp0", "mlp1") for p in parts):
+            at = min(parts.index(p) for p in parts
+                     if p in ("attn0", "attn1", "mlp0", "mlp1"))
+            assert parts[at - 1] in ("loop0", "loop1", "loop2", "loop3"), s
+        if "exits" in parts:         # an exit belongs to no pass's scope
+            assert not {"loop0", "loop1", "loop2", "loop3"} & set(parts), s
+    # each pass's core under that pass: forward, recomputed and backward
+    for t in range(4):
+        under = {s for s in stacks if f"loop{t}" in _scope_names({s})
+                 and "attn_core" in _scope_names({s})}
+        assert any("rematted_computation" in s for s in under), t
+        assert any("jvp" in s for s in under), t
+    # the gate is read after every pass but the last
+    gated = {s.split("exits/")[1].split("/")[0] for s in stacks
+             if "exit_gate" in s and "exits/" in s}
+    assert gated == {"pass0", "pass1", "pass2"}
+
+
 def _primitives_under(jaxpr, scope, inside=False, out=None):
     """The primitives of the equations ``scope`` encloses, through
     sub-jaxprs: an equation is inside when its own name stack holds the

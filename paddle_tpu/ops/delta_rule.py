@@ -35,10 +35,11 @@ Two paths behind one gate, :func:`delta_rule_kernel_chunk` (the TPU backend,
 head widths that are multiples of 128, a row of whole chunks): the Pallas
 kernels ``gdn_chunk_fwd`` / ``gdn_chunk_bwd`` of ops/pallas_kernels.py under
 a ``jax.custom_vjp`` (the chunk axis of the grid is sequential and ``S``
-stays in VMEM; the forward writes every chunk's starting state out, and the
-backward walks the chunks in reverse with ``dS`` resident, recomputing a
-chunk's ``T``, ``R`` and ``Vn`` from the saved state); and the same algebra
-in ``jax.numpy`` with a ``lax.scan`` over chunks, differentiated by JAX.
+stays in VMEM; the forward writes every chunk's starting state and its
+solve ``T`` out, and the backward walks the chunks in reverse with ``dS``
+resident, reading ``T`` back and recomputing a chunk's ``R`` and ``Vn`` from
+the saved state); and the same algebra in ``jax.numpy`` with a ``lax.scan``
+over chunks, differentiated by JAX.
 The chunk's algebra is written once, on 2-D arrays, and both paths call it
 (:func:`chunk_forward`; the kernels' :func:`chunk_backward` is its
 transpose by hand).
@@ -148,8 +149,12 @@ def _unit_lower_inverse(A):
     return T
 
 
-def _chunk_parts(q, k, gcol, grow, bcol, dt):
-    """What a chunk needs that does not wait for the state."""
+def _chunk_parts(q, k, gcol, grow, bcol, dt, T=None):
+    """What a chunk needs that does not wait for the state; ``T`` where the
+    caller has the chunk's solve already (the reverse walk).  The forward's
+    solve stays HERE, between ``A`` and ``P``: the kernel's schedule follows
+    the order of the equations (12,198 bundles a block of 8 chunks for the
+    described v5e; 13,326 with the solve after the exponentials)."""
     C = q.shape[0]
     i, j = _iotas(C)
     decay = jnp.exp(jnp.where(i >= j, gcol - grow, -jnp.inf))   # d_ij
@@ -160,7 +165,7 @@ def _chunk_parts(q, k, gcol, grow, bcol, dt):
     glast = jnp.sum(jnp.where(j[:1] == C - 1, grow, 0.0), axis=1,
                     keepdims=True)
     return {"decay": decay, "kk": kk, "A": A, "strict": i > j,
-            "T": _unit_lower_inverse(A),
+            "T": _unit_lower_inverse(A) if T is None else T,
             "P": decay * _dot(q, k, 1, 1, dt),
             "e": jnp.exp(gcol), "ec": jnp.exp(glast - gcol),
             "a": jnp.exp(glast)}
@@ -178,22 +183,27 @@ def chunk_forward(q, k, v, gcol, grow, bcol, S, dt):
     """One chunk of one head.  ``q``, ``k`` ``[C, dk]``, ``v`` ``[C, dv]``;
     ``gcol`` ``[C, 1]`` and ``grow`` ``[1, C]``: the sums of ``g`` from the
     chunk's start, both ways round; ``bcol`` ``[C, 1]``; ``S`` ``[dk, dv]``
-    float32.  Returns ``(o [C, dv], S_end)``, float32."""
+    float32.  Returns ``(o [C, dv], S_end, T [C, C])``, float32: ``T`` is
+    the chunk's solve ``(I + A)^-1``, which depends on ``k``, ``g`` and
+    ``beta`` alone and which the forward kernel keeps for the reverse walk."""
     p = _chunk_parts(q, k, gcol, grow, bcol, dt)
     qf, kf, _, _, _, Vn = _chunk_state_parts(p, q, k, v, bcol, S, dt)
     o = _dot(p["e"] * qf, S, 1, 0, dt) + _dot(p["P"], Vn, 1, 0, dt)
-    return o, p["a"] * S + _dot(p["ec"] * kf, Vn, 0, 0, dt)
+    return o, p["a"] * S + _dot(p["ec"] * kf, Vn, 0, 0, dt), p["T"]
 
 
-def chunk_backward(q, k, v, gcol, grow, bcol, S, dO, dS1, dt):
+def chunk_backward(q, k, v, gcol, grow, bcol, S, T, dO, dS1, dt):
     """The transpose of :func:`chunk_forward`, by hand, for the kernel:
-    ``dO`` ``[C, dv]`` and ``dS1`` (the gradient of the chunk's END state)
-    in, ``(dq, dk, dv, dgamma_col [C, 1], dgamma_row [1, C], dgamma_last
-    [1, 1], dbeta_col, dS)`` out, float32.  The gradient of the chunk's sums
-    of ``g`` comes in three parts: ``dgamma_col + transpose(dgamma_row)``,
-    and ``dgamma_last`` for the last token's alone.  ``T``, ``R`` and ``Vn``
-    are made again from ``S``."""
-    p = _chunk_parts(q, k, gcol, grow, bcol, dt)
+    ``T`` (the solve :func:`chunk_forward` returned for this chunk), ``dO``
+    ``[C, dv]`` and ``dS1`` (the gradient of the chunk's END state) in,
+    ``(dq, dk, dv, dgamma_col [C, 1], dgamma_row [1, C], dgamma_last [1, 1],
+    dbeta_col, dS)`` out, float32.  The gradient of the chunk's sums of ``g``
+    comes in three parts: ``dgamma_col + transpose(dgamma_row)``, and
+    ``dgamma_last`` for the last token's alone.  ``R`` and ``Vn`` are made
+    again from ``S``, and what else does not wait for the state (the decays,
+    ``k k^T``, ``A``, ``P``) from the operands: one pass of the MXU or vector
+    work each.  The solve, ten float32 products at ``highest``, is not."""
+    p = _chunk_parts(q, k, gcol, grow, bcol, dt, T)
     qf, kf, vf, Kb, R, Vn = _chunk_state_parts(p, q, k, v, bcol, S, dt)
     e, ec, a, decay = p["e"], p["ec"], p["a"], p["decay"]
     Qe, Ke = e * qf, ec * kf
@@ -240,8 +250,9 @@ def _scan_xla(q, k, v, gamma, beta):
 
     def one(S, x):
         qc, kc, vc, gc, bc = x
-        return chunk_forward(qc, kc, vc, gc[:, None], gc[None, :],
-                             bc[:, None], S, dt)[::-1]
+        o, S_end, _ = chunk_forward(qc, kc, vc, gc[:, None], gc[None, :],
+                                    bc[:, None], S, dt)
+        return S_end, o
 
     chunks_first = tuple(jnp.moveaxis(a, 2, 0) for a in (q, k, v, gamma, beta))
     S0 = jnp.zeros((B, H, q.shape[-1], v.shape[-1]), jnp.float32)
@@ -254,10 +265,13 @@ def _scan_xla(q, k, v, gamma, beta):
 def _scan_fwd(q, k, v, gamma, beta):
     from paddle_tpu.ops.pallas_kernels import gdn_chunk_fwd_pallas
 
-    o, states = gdn_chunk_fwd_pallas(q, k, v, gamma, beta)
-    # kept across a recomputation block, as attention's output is: the
-    # backward's second forward makes the projections again, not the scan
-    return tuple(checkpoint_name(a, "remat_keep") for a in (o, states))
+    # (o, every chunk's starting state, every chunk's solve), all three kept
+    # across a recomputation block, as attention's output is: the backward's
+    # second forward makes the projections again, not the scan, and the
+    # reverse walk reads the solve (256 bytes a token and head beside the
+    # states' 1,024): ten float32 products at ``highest`` it does not make
+    return tuple(checkpoint_name(a, "remat_keep")
+                 for a in gdn_chunk_fwd_pallas(q, k, v, gamma, beta))
 
 
 @jax.custom_vjp
@@ -270,16 +284,16 @@ def _scan_kernels(q, k, v, gamma, beta):
 
 def _scan_kernels_fwd(q, k, v, gamma, beta):
     q, k, v = (a.astype(compute_dtype()) for a in (q, k, v))
-    o, states = _scan_fwd(q, k, v, gamma, beta)
-    return o, (q, k, v, gamma, beta, states)
+    o, states, solves = _scan_fwd(q, k, v, gamma, beta)
+    return o, (q, k, v, gamma, beta, states, solves)
 
 
 def _scan_kernels_bwd(res, do):
     from paddle_tpu.ops.pallas_kernels import gdn_chunk_bwd_pallas
 
-    q, k, v, gamma, beta, states = res
+    q, k, v, gamma, beta, states, solves = res
     dq, dk, dv, dgamma, dbeta = gdn_chunk_bwd_pallas(
-        q, k, v, gamma, beta, states, do.astype(q.dtype))
+        q, k, v, gamma, beta, states, solves, do.astype(q.dtype))
     B, Hk, T, d = q.shape
     f32 = jnp.float32
     # the transpose of the heads' repeat: a group's sum
@@ -398,18 +412,18 @@ def _conv_scan_fwd(x, w, gamma, beta, dk, dv, rows):
         q, k, v = gdn_prep_fwd_pallas(x, w, dk=dk, dv=dv, rows=rows,
                                       out_dtype=compute_dtype())
     with jax.named_scope("gdn_scan"):
-        o, states = _scan_fwd(q, k, v, gamma, beta)
-    return o, (x, w, q, k, v, gamma, beta, states)
+        o, states, solves = _scan_fwd(q, k, v, gamma, beta)
+    return o, (x, w, q, k, v, gamma, beta, states, solves)
 
 
 def _conv_scan_bwd(dk, dv, rows, res, do):
     from paddle_tpu.ops.pallas_kernels import (gdn_chunk_bwd_pallas,
                                                gdn_prep_bwd_pallas)
 
-    x, w, q, k, v, gamma, beta, states = res
+    x, w, q, k, v, gamma, beta, states, solves = res
     with jax.named_scope("gdn_scan"):
         dq, dk_, dv_, dgamma, dbeta = gdn_chunk_bwd_pallas(
-            q, k, v, gamma, beta, states, do.astype(q.dtype))
+            q, k, v, gamma, beta, states, solves, do.astype(q.dtype))
     with jax.named_scope("gdn_proj"):
         dx, dw = gdn_prep_bwd_pallas(x, w, dq, dk_, dv_, rows=rows)
     return dx, dw, dgamma, dbeta

@@ -39,9 +39,11 @@ sets takes part:
   starts at the band's first block.
 - Grouped matrix products over experts.  Gate:
   ``ops/moe.moe_kernel_row_tile``.
-- The gated delta rule's chunked scan, forward (``gdn_chunk_fwd``) and
-  reverse (``gdn_chunk_bwd``); ``q`` and ``k`` come at the key heads and a
-  value head reads its key head through the index maps.  Gate:
+- The gated delta rule's chunked scan, forward (``gdn_chunk_fwd``, which
+  also writes every chunk's starting state and its 64 x 64 solve) and
+  reverse (``gdn_chunk_bwd``, which reads both and solves nothing); ``q``
+  and ``k`` come at the key heads and a value head reads its key head
+  through the index maps.  Gate:
   ``ops/delta_rule.delta_rule_kernel_chunk``.
 - The state-space (SSD) recurrence's chunked scan, forward
   (``ssd_chunk_fwd``) and reverse (``ssd_chunk_bwd``); a grid step takes a
@@ -2122,8 +2124,10 @@ def tgmm_pallas(lhs, rhs, tile_expert, n_active, *, experts: int, tm: int,
 # that do.  ``g``'s sums and ``beta`` come lane-dense, one chunk a row
 # ([.., N, 64]); the column forms the algebra needs are made in VMEM.  The
 # forward writes every chunk's STARTING state out ([B, H, N, dk, dv]
-# float32); the reverse kernel reads it back and makes the chunk's T, R and
-# Vn again.
+# float32) and its solve T ([B, H, 64, N * 64] float32, chunk ``c`` at lanes
+# ``64 c`` on: two neighbouring chunks fill a lane tile, and nothing is
+# padded to 128); the reverse kernel reads both back and makes the chunk's R
+# and Vn again, not the solve.
 
 def _traced_once(*static):
     """``jax.jit`` around a kernel's wrapper.  A step calls such a wrapper
@@ -2154,8 +2158,8 @@ def _gdn_block(T: int, chunk: int):
     return n, per
 
 
-def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, S_scr,
-                    *, chunk, per):
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, t_ref,
+                    S_scr, *, chunk, per):
     from jax.experimental import pallas as pl
 
     from paddle_tpu.ops import delta_rule as DR
@@ -2170,10 +2174,11 @@ def _gdn_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, S_scr,
         S = S_scr[...]
         s_ref[0, 0, c] = S
         q = q_ref[0, 0, rows, :]
-        o, S_end = DR.chunk_forward(
+        o, S_end, T = DR.chunk_forward(
             q, k_ref[0, 0, rows, :], v_ref[0, 0, rows, :],
             DR.col_of_row(grow), grow, DR.col_of_row(brow), S, q.dtype)
         o_ref[0, 0, rows, :] = o.astype(o_ref.dtype)
+        t_ref[0, 0, :, rows] = T
         S_scr[...] = S_end
 
 
@@ -2184,7 +2189,9 @@ def gdn_chunk_fwd_pallas(q, k, v, gamma, beta, *, interpret):
     Hk)`` through the index maps, and nothing is repeated in HBM; gamma
     (``g`` summed from each chunk's start) and beta ``[B, H, N, C]`` float32
     -> (o ``[B, H, T, dv]`` in q's dtype, every chunk's starting state ``[B,
-    H, N, dk, dv]`` float32)."""
+    H, N, dk, dv]`` float32, every chunk's solve ``(I + A)^-1`` ``[B, H, C,
+    N C]`` float32 as ``chunk_forward`` made it, chunk ``c`` in columns ``c
+    C`` on)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -2204,9 +2211,12 @@ def gdn_chunk_fwd_pallas(q, k, v, gamma, beta, *, interpret):
                   pl.BlockSpec((1, 1, per, chunk), rows)],
         out_specs=[pl.BlockSpec((1, 1, per * chunk, dv), rows),
                    pl.BlockSpec((1, 1, per, dk, dv),
-                                lambda b, h, i: (b, h, i, 0, 0))],
+                                lambda b, h, i: (b, h, i, 0, 0)),
+                   pl.BlockSpec((1, 1, chunk, per * chunk),
+                                lambda b, h, i: (b, h, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, n, dk, dv), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, H, n, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, chunk, T), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -2214,7 +2224,7 @@ def gdn_chunk_fwd_pallas(q, k, v, gamma, beta, *, interpret):
     )(q, k, v, gamma, beta)
 
 
-def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
+def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, t_ref, do_ref,
                     dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dS_scr,
                     *, chunk, per):
     from jax.experimental import pallas as pl
@@ -2233,7 +2243,8 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
         dq, dk, dv, dg_col, dg_row, dg_last, db, dS = DR.chunk_backward(
             q, k_ref[0, 0, rows, :], v_ref[0, 0, rows, :],
             DR.col_of_row(grow), grow, DR.col_of_row(brow), s_ref[0, 0, c],
-            do_ref[0, 0, rows, :], dS_scr[...], q.dtype)
+            t_ref[0, 0, :, rows], do_ref[0, 0, rows, :], dS_scr[...],
+            q.dtype)
         dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
         dk_ref[0, 0, rows, :] = dk.astype(dk_ref.dtype)
         dv_ref[0, 0, rows, :] = dv.astype(dv_ref.dtype)
@@ -2244,11 +2255,13 @@ def _gdn_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
 
 
 @_traced_once()
-def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do, *, interpret):
-    """The reverse walk: what :func:`gdn_chunk_fwd_pallas` took and wrote,
-    and ``do`` ``[B, H, T, dv]`` -> (dq, dk ``[B, H, T, dk]``, a VALUE head
-    each: the sum over a key head's group is the caller's; dv in q's dtype;
-    dgamma, dbeta ``[B, H, N, C]`` float32)."""
+def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, solves, do, *,
+                         interpret):
+    """The reverse walk: what :func:`gdn_chunk_fwd_pallas` took and wrote
+    (its second and third result, ``states`` and ``solves``: the kernel
+    makes no chunk's solve again), and ``do`` ``[B, H, T, dv]`` -> (dq, dk
+    ``[B, H, T, dk]``, a VALUE head each: the sum over a key head's group is
+    the caller's; dv in q's dtype; dgamma, dbeta ``[B, H, N, C]`` float32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -2269,6 +2282,8 @@ def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do, *, interpret):
         in_specs=[key_in, key_in, wide_v, scalars, scalars,
                   pl.BlockSpec((1, 1, per, dk, dv),
                                lambda b, h, i: (b, h, nb - 1 - i, 0, 0)),
+                  pl.BlockSpec((1, 1, chunk, per * chunk),
+                               lambda b, h, i: (b, h, 0, nb - 1 - i)),
                   wide_v],
         out_specs=[wide_k, wide_k, wide_v, scalars, scalars],
         out_shape=[jax.ShapeDtypeStruct((B, H, T, dk), q.dtype),
@@ -2280,7 +2295,7 @@ def gdn_chunk_bwd_pallas(q, k, v, gamma, beta, states, do, *, interpret):
         compiler_params=_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, gamma, beta, states, do))
+    )(q, k, v, gamma, beta, states, solves, do))
 
 
 # ---------------------------------------------------------------------------

@@ -575,6 +575,46 @@ def test_delta_rule_scan_kernels(one_chip, on_tpu):
                     wide, wide, wide, one, one) == 2
 
 
+def _products(jaxpr, out=None):
+    """Every ``dot_general`` of a jaxpr and its sub-jaxprs (a kernel's body
+    is the ``jaxpr`` of its ``pallas_call``): ``[is it at HIGHEST]``."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            precision = eqn.params["precision"]
+            out.append(jax.lax.Precision.HIGHEST in (
+                precision if isinstance(precision, tuple) else (precision,)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _products(sub, out)
+    return out
+
+
+def test_delta_rule_reverse_kernel_solves_nothing(on_tpu):
+    """A chunk's 64 x 64 solve is ten float32 products at ``highest``, six
+    passes of the MXU each, and the forward kernel's body holds them: 17
+    products a chunk, ten of them the solve's, in a block of 8 chunks.  The
+    reverse kernel reads the forward's ``T`` (PR 55): 18 products a chunk,
+    one pass each, and not one at ``highest``."""
+    from paddle_tpu.ops import delta_rule as DR
+    from paddle_tpu.ops import pallas_kernels as PK
+
+    c = QWEN3NEXT
+    per, n = DR.KERNEL_BLOCK_CHUNKS, c["T"] // DR.CHUNK
+    bf16 = jnp.bfloat16
+    key = jax.ShapeDtypeStruct((1, c["Hv"] // 2, c["T"], c["dk"]), bf16)
+    wide = jax.ShapeDtypeStruct((1, c["Hv"], c["T"], c["dv"]), bf16)
+    row = jax.ShapeDtypeStruct((1, c["Hv"], n, DR.CHUNK), jnp.float32)
+    forward = jax.make_jaxpr(PK.gdn_chunk_fwd_pallas)(key, key, wide, row, row)
+    _, states, solves = forward.out_avals
+    assert solves.shape == (1, c["Hv"], DR.CHUNK, c["T"])      # lane-dense
+    products = _products(forward.jaxpr)
+    assert (len(products), sum(products)) == (17 * per, 10 * per)
+    reverse = jax.make_jaxpr(PK.gdn_chunk_bwd_pallas)(
+        key, key, wide, row, row, states, solves, wide)
+    products = _products(reverse.jaxpr)
+    assert (len(products), sum(products)) == (18 * per, 0)
+
+
 def test_delta_net_prep_kernels(one_chip, on_tpu):
     """The gate of ``gdn_prep_fwd`` / ``gdn_prep_bwd`` opens at the cell's
     shape (16 key and 32 value heads of 128, 4 taps, a row of 8192 in blocks
@@ -629,7 +669,10 @@ def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
     below) compiled for the described v5e from shapes alone: the compiler's
     own count of arguments, results and temporaries stays under 15 GB of the
     chip's 16, with the delta rule's, the delta nets' prep and the
-    attention's kernels in the program.  (That no ``q`` or ``k`` is repeated
+    attention's kernels in the program, and every chunk's solve kept for the
+    reverse walk beside the states (PR 55: 67 MB a delta net, ``f32[1, 32,
+    64, 8192]``, a result of ``gdn_chunk_fwd`` and an operand of
+    ``gdn_chunk_bwd``).  (That no ``q`` or ``k`` is repeated
     to 32 heads is asserted on the layer's jaxpr in tests/test_gdn_prep.py,
     at widths where the shape tells them from ``v``, ``z`` and ``o``: here
     all five are ``[1, 8192, 32, 128]``.)"""
@@ -638,6 +681,13 @@ def test_qwen3next_cell_step_fits_the_chip(one_chip, on_tpu):
     assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
     assert "gdn_prep_fwd" in text and "gdn_prep_bwd" in text
     assert "flash_attn_fwd" in text and "flash_attn_bwd" in text
+    calls = _kernel_calls(text)
+    assert calls["gdn_chunk_fwd"] == calls["gdn_chunk_bwd"] == 3
+    results = [line.split(" custom-call(")[0] for line in text.split("\n")
+               if "gdn_chunk_fwd" in line.split(" = ")[0]
+               and " custom-call(" in line]
+    assert len(results) == 3 and all("f32[1,32,64,8192]{" in r
+                                     for r in results), results
     m = compiled.memory_analysis()
     assert 3 * 4 * 424_340_544 < m.argument_size_in_bytes     # p, m, v
     assert _held_bytes(compiled) < 15e9, _held_bytes(compiled)

@@ -17,3 +17,4 @@ with _setup_phase("import"):   # the set-up record: this package's import
     from paddle_tpu.models.keye_vl2 import keye_vl2_net
     from paddle_tpu.models.laguna import laguna_net
     from paddle_tpu.models.ouro import ouro_net
+    from paddle_tpu.models.smallthinker import smallthinker_net

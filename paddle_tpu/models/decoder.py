@@ -62,7 +62,7 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                   expert_act: str = "gated_silu",
                   selection_bias: bool = True, loops: int = 1,
                   post_norm: bool = False, exit_gate: bool = False,
-                  exit_beta: float = 0.1):
+                  exit_beta: float = 0.1, early_router: bool = False):
     """Returns ``(cost, extras)``: the mean next-token cross-entropy over
     ``tokens`` / ``next_tokens`` (two int sequence feeds of one length), and
     two extra outputs per expert layer, marked for the counters
@@ -91,6 +91,15 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     counts layers as before: a feed-forward layer ``i`` below it is a gated
     MLP).  ``expert_act``: the experts' form, ``selection_bias``: whether
     sigmoid scoring has its ``expert_bias`` leaf (``nn.expert_mlp``).
+    ``early_router``: layer ``i``'s router reads the MIXER's normed input
+    (``norm_op<i>``), not the feed-forward's: its choice, its weights and
+    its sort by expert are the layer ``moe<i>/moe_routing``, which runs
+    ahead of the mixer, and its gradient reaches ``norm_op<i>`` and ``x``,
+    not ``h``; the experts read ``norm_ffn<i>`` as before (a layer of two
+    sub-blocks).  Where the experts' form counts them
+    (``expert_act="gated_relu"``), the extras carry one more counter an
+    expert layer, ``moe_gate_zero_units`` (the hidden units the ReLU gate
+    made exactly zero).
     ``recompute_layers`` marks decoder layers as
     recomputation blocks, one block a layer: ``True`` for every layer, or
     the indices of the layers to recompute (the others hold their
@@ -119,6 +128,9 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                          "feed-forward is a gated MLP")
     if post_norm and ffn_layer_type is not None:
         raise ValueError("sandwich norms are of a layer of two sub-blocks")
+    if early_router and ffn_layer_type is not None:
+        raise ValueError("an early router reads the mixer's input: a layer "
+                         "of two sub-blocks")
     tokens = nn.data("tokens", size=vocab_size, is_seq=True, dtype="int32")
     targets = nn.data("next_tokens", size=vocab_size, is_seq=True,
                       dtype="int32")
@@ -139,19 +151,22 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
     if not selection_bias:
         routing["selection_bias"] = False
 
-    def feed_forward(normed, i, scope=""):
+    def feed_forward(normed, i, scope="", router_input=None):
         """Layer ``i``'s feed-forward over ``normed`` and what rides with it
-        (an expert layer's two counters)."""
+        (an expert layer's counters, an early router's layer first)."""
         if i < num_dense_layers:
             shared = {"param_name": f"mlp{i}"} if scope else {}
             return nn.gated_mlp(normed, intermediate_size,
                                 name=f"{scope}mlp{i}", **shared), []
+        # passed only where asked for (the captured configurations, above)
+        early = ({"router_input": router_input}
+                 if router_input is not None else {})
         ffn = nn.expert_mlp(
             normed, moe_intermediate_size, num_experts=num_experts,
             experts_held=experts_held, top_k=num_experts_per_tok,
             norm_topk_prob=norm_topk_prob,
             routed_scaling_factor=routed_scaling_factor,
-            shared_size=shared_size, name=f"moe{i}", **routing)
+            shared_size=shared_size, name=f"moe{i}", **routing, **early)
         load = nn.get_output(ffn, "expert_load", size=1, name=f"moe{i}_load")
         load.meta["obs_counter"] = {
             "name": "moe_assignments", "labels": {"layer": f"moe{i}"},
@@ -162,7 +177,16 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
         dropped.meta["obs_counter"] = {
             "name": "moe_uncomputed_assignments",
             "labels": {"layer": f"moe{i}"}}
-        return ffn, [load, dropped]
+        riders = [load, dropped]
+        if ffn.meta.get("zero_gates"):
+            zeros = nn.get_output(ffn, "gate_zero", size=1,
+                                  name=f"moe{i}_gate_zero")
+            zeros.meta["obs_counter"] = {"name": "moe_gate_zero_units",
+                                         "labels": {"layer": f"moe{i}"}}
+            riders.append(zeros)
+        if router_input is not None:    # the router's own layer
+            riders.insert(0, ffn.parents[-1])
+        return ffn, riders
 
     def mixer(kind, normed, i, scope=""):
         """Layer ``i``'s mixer and what rides with it."""
@@ -207,8 +231,9 @@ def decoder_stack(vocab_size: int, *, hidden_size: int,
                 op = sub_block(op, f"post_op{i}", scope)
                 h = nn.addto([x, op[-1]], name=f"{scope}res_op{i}")
                 normed2 = norm(h, f"norm_ffn{i}", scope)
-                ffn, counters = feed_forward(normed2, i, scope)
-                extras += counters
+                ffn, counters = feed_forward(
+                    normed2, i, scope, normed if early_router else None)
+                extras += [c for c in counters if "obs_counter" in c.meta]
                 ffn = sub_block(ffn, f"post_ffn{i}", scope)
                 x = nn.addto([h, ffn[-1]], name=f"{scope}res_ffn{i}")
                 block = [normed, *op, *riders, h, normed2, *counters, *ffn, x]

@@ -304,6 +304,13 @@ class Topology:
                 visit(p)
             seen[id(node)] = 1
             order.append(node)
+            # layers that asked to run right after this one, ahead of
+            # whatever else reads it (an early router: nn.expert_mlp); one
+            # that is being visited got here through this parent and
+            # follows it anyway
+            for nxt in node.meta.get("run_next", ()):
+                if seen.get(id(nxt)) != 0:
+                    visit(nxt)
 
         for out in outputs:
             visit(out)

@@ -633,6 +633,16 @@ def _relu2_mlp(x, w1, w2):
     return O.linear(jnp.square(jax.nn.relu(O.linear(x, w1))), w2)
 
 
+def _reglu_mlp(x, w1, w3, w2):
+    return O.linear(jax.nn.relu(O.linear(x, w1)) * O.linear(x, w3), w2)
+
+
+#: ``expert_act`` -> the shared expert's MLP, one an entry of
+#: ``ops.moe.EXPERT_ACTS``
+_SHARED_MLPS = {"gated_silu": _gated_mlp, "relu2": _relu2_mlp,
+                "gated_relu": _reglu_mlp}
+
+
 def gated_mlp(input: LayerOutput, size: int, *,
               name: Optional[str] = None,
               param_name: Optional[str] = None) -> LayerOutput:
@@ -657,14 +667,16 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                shared_size: int = 0, scoring: str = "sigmoid",
                shared_gate: bool = False, expert_act: str = "gated_silu",
                selection_bias: bool = True,
+               router_input: Optional[LayerOutput] = None,
                name: Optional[str] = None) -> LayerOutput:
     """A dropless mixture of experts of ``size`` hidden units, as the chip
     that holds experts ``experts_held = (first, count)`` of ``num_experts``
-    computes it (default: all of them).  An expert is a gated MLP,
-    ``W_2(silu(W_1 x) * W_3 x)`` (``expert_act="gated_silu"``), or, with
-    ``expert_act="relu2"``, TWO matrices and a squared ReLU, ``W_2
-    relu(W_1 x)^2``: the layer, and its shared expert, then have no ``w3``
-    leaf.  Every token is
+    computes it (default: all of them).  ``expert_act`` names the experts'
+    form (``ops.moe.EXPERT_ACTS``): ``"gated_silu"``, a gated MLP ``W_2(silu(
+    W_1 x) * W_3 x)``; ``"gated_relu"`` (ReGLU), ``W_2(relu(W_1 x) * W_3
+    x)``, a hidden unit exactly zero wherever ``W_1 x <= 0``; ``"relu2"``,
+    TWO matrices and a squared ReLU, ``W_2 relu(W_1 x)^2``: the layer, and
+    its shared expert, then have no ``w3`` leaf.  Every token is
     routed over all ``num_experts``: with ``scoring="sigmoid"`` by sigmoid
     scores, the ``top_k`` largest of ``score + expert_bias``; with
     ``scoring="softmax"`` by the softmax over all the router's outputs, the
@@ -676,16 +688,28 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
     whatever the routing.  On one chip there is no exchange, and nothing
     stands in for the other chips.
 
-    ``shared_size``: the hidden units of the shared experts, ONE gated MLP
-    (``_<name>.shared_w1`` / ``w3`` / ``w2``) that every token passes
-    through, added to the routed result: unweighted, or, with
+    ``router_input``: the router reads ANOTHER layer's output than the
+    experts do (an early router: the sequence mixer's normed input, where
+    ``input`` is the feed-forward's).  The logits, the choice, the weights
+    and the sort by expert are then a layer of their own, ``<name>/
+    moe_routing`` (the router's leaves keep their names, ``_<name>.router``),
+    which runs right after ``router_input`` does and so ahead of whatever
+    else reads it; the router's gradient goes to ``router_input`` and none
+    of it to ``input``.  Without it the router reads ``input``, inside this
+    layer, under the scope ``moe_routing``.
+
+    ``shared_size``: the hidden units of the shared experts, ONE MLP of the
+    experts' form (``_<name>.shared_w1`` / ``w3`` / ``w2``) that every token
+    passes through, added to the routed result: unweighted, or, with
     ``shared_gate``, times ``sigmoid(x . w_g)`` (``_<name>.shared_gate``,
     one weight a hidden channel); every chip of the deployment computes it
     whole on its own tokens.  Scope ``moe_shared``.
 
     ``Act.state`` carries ``expert_load`` (assignments per expert held,
-    int32) and ``uncomputed`` (assignments to an expert held that no row
-    was computed for: 0)."""
+    int32), ``uncomputed`` (assignments to an expert held that no row
+    was computed for: 0) and ``gate_zero`` (of the computed rows' ``size``
+    gate values, how many the ReLU made exactly zero: counted for
+    ``"gated_relu"``, 0 for the other forms)."""
     name = name or next_name("expert_mlp")
     D, E = input.size, num_experts
     first, held = experts_held or (0, num_experts)
@@ -696,15 +720,19 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
         raise ConfigError(f"{name!r}: unknown scoring {scoring!r}")
     if shared_gate and not shared_size:
         raise ConfigError(f"{name!r}: a gate without a shared expert")
-    if expert_act not in ("gated_silu", "relu2"):
-        raise ConfigError(f"{name!r}: unknown expert_act {expert_act!r}")
-    gated = expert_act == "gated_silu"
+    if expert_act not in M.EXPERT_ACTS:
+        raise ConfigError(f"{name!r}: unknown expert_act {expert_act!r}; "
+                          f"have {sorted(M.EXPERT_ACTS)}")
+    if router_input is not None and router_input.size != D:
+        raise ConfigError(f"{name!r}: the router's input is "
+                          f"{router_input.size} wide, the experts' {D}")
+    gated = M.EXPERT_ACTS[expert_act].gated
     bias = [ParamSpec(f"_{name}.expert_bias", (E,),
                       _pa(None, f"_{name}.expert_bias", init="zeros"))
             ] if scoring == "sigmoid" and selection_bias else []
-    specs = [
-        ParamSpec(f"_{name}.router", (D, E), _fan_in(f"_{name}.router", D)),
-        *bias,
+    router = [ParamSpec(f"_{name}.router", (D, E),
+                        _fan_in(f"_{name}.router", D)), *bias]
+    experts = [
         ParamSpec(f"_{name}.w1", (held, D, size), _fan_in(f"_{name}.w1", D)),
         *([ParamSpec(f"_{name}.w3", (held, D, size),
                      _fan_in(f"_{name}.w3", D))] if gated else []),
@@ -717,23 +745,48 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                       _fan_in(f"_{name}.shared_gate", D))
             ] if shared_gate else []
 
-    def forward(ctx, params, a: Act) -> Act:
-        p = {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
+    def leaves(params, specs):
+        return {s.name.rsplit(".", 1)[1]: params[s.name] for s in specs}
+
+    def route(p, a: Act):
+        """``(experts [N, k], weights [N, k])`` of ``a``'s tokens; the caller
+        opens the scope."""
+        idx, weights = M.route_tokens(
+            a.value.reshape(-1, D), p["router"], p.get("expert_bias"),
+            top_k=top_k, norm_topk=norm_topk_prob,
+            scaling=routed_scaling_factor, scoring=scoring)
+        if a.is_seq:     # a padded position is no token: nothing held
+            idx = jnp.where(a.mask.reshape(-1, 1) > 0, idx, -1)
+        return idx, weights
+
+    def route_ahead(ctx, params, a: Act) -> Act:
+        """The early router's layer (named ``<name>/moe_routing``, which is
+        its scope): the weights, and as state the choice and its sort."""
+        idx, weights = route(leaves(params, router), a)
+        with jax.named_scope("moe_grouping"):
+            counts, order = M.count_assignments(idx, first_expert=first,
+                                                held=held)
+        return Act(value=weights,
+                   state={"idx": idx, "counts": counts, "order": order})
+
+    def forward(ctx, params, a: Act, routed: Optional[Act] = None) -> Act:
+        p = leaves(params, experts if routed else router + experts)
         x = a.value.reshape(-1, D)
-        with jax.named_scope("moe_routing"):
-            idx, weights = M.route_tokens(
-                x, p["router"], p.get("expert_bias"), top_k=top_k,
-                norm_topk=norm_topk_prob, scaling=routed_scaling_factor,
-                scoring=scoring)
-            if a.is_seq:     # a padded position is no token: nothing held
-                idx = jnp.where(a.mask.reshape(-1, 1) > 0, idx, -1)
+        if routed:
+            idx, weights = routed.state["idx"], routed.value
+            presorted = routed.state["counts"], routed.state["order"]
+        else:
+            with jax.named_scope("moe_routing"):
+                idx, weights = route(p, a)
+            presorted = None
         tm = M.moe_kernel_row_tile(D, size, idx.size)
-        y, load, uncomputed = M.expert_layer(
+        y, load, uncomputed, gate_zero = M.expert_layer(
             x, idx, weights, p["w1"], p.get("w3"), p["w2"], num_experts=E,
-            first_expert=first, tm=tm or 8, kernels=tm is not None)
+            first_expert=first, tm=tm or 8, kernels=tm is not None,
+            expert_act=expert_act, sorted_by_expert=presorted)
         if shared:
             with jax.named_scope("moe_shared"):
-                ys = (_gated_mlp if gated else _relu2_mlp)(
+                ys = _SHARED_MLPS[expert_act](
                     x, *(params[s.name] for s in shared))
                 if gate:
                     wg = params[gate[0].name].astype(jnp.float32)
@@ -742,15 +795,24 @@ def expert_mlp(input: LayerOutput, size: int, *, num_experts: int,
                     ).astype(ys.dtype)
                 y = y + ys
         y = y.reshape(a.value.shape)
-        state = {"expert_load": load, "uncomputed": uncomputed}
+        state = {"expert_load": load, "uncomputed": uncomputed,
+                 "gate_zero": gate_zero}
         if a.is_seq:
             out = _seq_like(a, y)
             out.state.update(state)
             return out
         return Act(value=y, state=state)
 
-    return LayerOutput(name, "expert_mlp", D, [input], forward,
-                       specs + shared + gate)
+    meta = {"zero_gates": True} if M.EXPERT_ACTS[expert_act].zero_gates \
+        else {}
+    if router_input is None:
+        return LayerOutput(name, "expert_mlp", D, [input], forward,
+                           router + experts + shared + gate, meta=meta)
+    ahead = LayerOutput(f"{name}/moe_routing", "expert_router", top_k,
+                        [router_input], route_ahead, router)
+    router_input.meta.setdefault("run_next", []).append(ahead)
+    return LayerOutput(name, "expert_mlp", D, [input, ahead], forward,
+                       experts + shared + gate, meta=meta)
 
 
 def _head_spec(name: str, input: LayerOutput, label: LayerOutput,

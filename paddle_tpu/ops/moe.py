@@ -1,7 +1,8 @@
 """A dropless expert layer as ONE chip of an expert-parallel deployment runs
 it: route every token over ALL the experts, keep the assignments that land
 on the experts held here, group them by expert (ragged groups, no capacity,
-nothing dropped), run the gated MLP of each group's expert as grouped matrix
+nothing dropped), run each group's expert (a gated MLP or one of the other
+forms of ``EXPERT_ACTS``) as grouped matrix
 products, and combine the results back onto the tokens with the router's
 weights.  What the experts held elsewhere would have added is left out: on
 one chip the layer runs without its exchange, and nothing stands in for it.
@@ -38,7 +39,7 @@ dtype with float32 accumulation, like every other matrix product.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +48,7 @@ from jax.ad_checkpoint import checkpoint_name
 from paddle_tpu.ops.numerics import acc_dtype, compute_dtype, dot_dtype
 
 __all__ = ["route_tokens", "count_assignments", "group_assignments",
-           "grouped_expert_mlp", "expert_layer", "buffer_rows",
+           "grouped_expert_mlp", "expert_layer", "buffer_rows", "EXPERT_ACTS",
            "moe_kernel_row_tile", "Grouping"]
 
 
@@ -331,12 +332,62 @@ def _add_rows(rows, idx, n):
         rows, mode="drop")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels):
-    return _expert_mlp_fwd(x, weights, w1, w3, w2, g, tm, kernels)[0]
+class _Form(NamedTuple):
+    """One form of expert: ``a = act(h1, h3)`` between the first matrices'
+    products and ``W_2``, all float32.  ``grads(da, h1, h3) -> (dh1, dh3)``;
+    ``h3`` and ``dh3`` are ``None`` for a form of two matrices.
+    ``zero_gates``: whether the layer counts the hidden units the form's
+    ReLU gate made exactly zero (the counter ``moe_gate_zero_units``)."""
+    gated: bool
+    act: Callable
+    grads: Callable
+    zero_gates: bool = False
 
 
-def _expert_mlp_fwd(x, weights, w1, w3, w2, g: Grouping, tm, kernels):
+def _gated_silu_grads(da, h1, h3):
+    sig = jax.nn.sigmoid(h1)
+    return da * h3 * sig * (1.0 + h1 * (1.0 - sig)), da * h1 * sig
+
+
+#: ``expert_act`` -> the experts' form.  ``gated_silu``: ``W_2 (silu(W_1 x) *
+#: W_3 x)``; ``relu2``: two matrices, ``W_2 relu(W_1 x)^2``; ``gated_relu``
+#: (ReGLU): ``W_2 (relu(W_1 x) * W_3 x)``, a hidden unit exactly zero, forward
+#: and backward, wherever ``W_1 x <= 0``.
+EXPERT_ACTS = {
+    "gated_silu": _Form(True, lambda h1, h3: jax.nn.silu(h1) * h3,
+                        _gated_silu_grads),
+    "relu2": _Form(False, lambda h1, h3: jnp.square(jax.nn.relu(h1)),
+                   lambda da, h1, h3: (da * 2.0 * jax.nn.relu(h1), None)),
+    "gated_relu": _Form(True, lambda h1, h3: jax.nn.relu(h1) * h3,
+                        lambda da, h1, h3: (da * h3 * (h1 > 0),
+                                            da * jax.nn.relu(h1)),
+                        zero_gates=True),
+}
+
+
+def _form(expert_act: str, w3) -> _Form:
+    if expert_act not in EXPERT_ACTS:
+        raise ValueError(f"unknown expert_act {expert_act!r}; "
+                         f"have {sorted(EXPERT_ACTS)}")
+    form = EXPERT_ACTS[expert_act]
+    if form.gated != (w3 is not None):
+        raise ValueError(f"expert_act {expert_act!r} takes "
+                         f"{'a' if form.gated else 'no'} w3")
+    return form
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels, expert_act):
+    return _expert_mlp_fwd(x, weights, w1, w3, w2, g, tm, kernels,
+                           expert_act)[0]
+
+
+def _expert_mlp_fwd(x, weights, w1, w3, w2, g: Grouping, tm, kernels,
+                    expert_act):
+    """-> ``(y [N, D], gate_zero)``: ``gate_zero`` is how many of the live
+    rows' ``W_1 x`` a ReLU gate made exactly zero (int32; 0 for a form
+    without one)."""
+    form = _form(expert_act, w3)
     N, k = weights.shape
     f32, cd = acc_dtype(), compute_dtype()
     with jax.named_scope("moe_grouping"):
@@ -344,23 +395,26 @@ def _expert_mlp_fwd(x, weights, w1, w3, w2, g: Grouping, tm, kernels):
         xs = _take_rows(x.astype(cd), row_token)              # [M, D]
     with jax.named_scope("moe_experts"):
         h1 = _rows_by_first(xs, w1, g, tm, kernels, cd)
-        if w3 is None:          # two matrices: W_2 relu(W_1 x)^2
-            h3 = None
-            a = jnp.square(jax.nn.relu(h1.astype(f32))).astype(cd)
-        else:
-            h3 = _rows_by_first(xs, w3, g, tm, kernels, cd)
-            a = (jax.nn.silu(h1.astype(f32)) * h3.astype(f32)).astype(cd)
+        h3 = (_rows_by_first(xs, w3, g, tm, kernels, cd) if form.gated
+              else None)
+        a = form.act(h1.astype(f32),
+                     h3.astype(f32) if form.gated else None).astype(cd)
         ys = _gmm(a, w2, g, tm, kernels, out_dtype=cd)        # [M, D]
+        gate_zero = jnp.zeros((), jnp.int32)
+        if form.zero_gates:
+            live = (g.row_assign < N * k)[:, None]
+            gate_zero = jnp.sum(live & (h1 <= 0), dtype=jnp.int32)
     with jax.named_scope("moe_combine"):
         row_w = _take_rows(weights.reshape(-1).astype(f32), g.row_assign)
         y = _add_rows(ys.astype(f32) * row_w[:, None], row_token, N)
-    return y.astype(dot_dtype()), (xs, h1, h3, a, ys, row_w, w1, w3, w2, g,
-                                   jnp.zeros((0,), x.dtype),
-                                   jnp.zeros((0, k), weights.dtype))
+    return (y.astype(dot_dtype()), gate_zero), (
+        xs, h1, h3, a, ys, row_w, w1, w3, w2, g, jnp.zeros((0,), x.dtype),
+        jnp.zeros((0, k), weights.dtype))
 
 
-def _expert_mlp_bwd(tm, kernels, res, dy):
+def _expert_mlp_bwd(tm, kernels, expert_act, res, cts):
     xs, h1, h3, a, ys, row_w, w1, w3, w2, g, x_like, w_like = res
+    form, dy = EXPERT_ACTS[expert_act], cts[0]
     N, k = dy.shape[0], w_like.shape[1]
     f32, cd = acc_dtype(), compute_dtype()
     row_token = g.row_assign // k
@@ -372,19 +426,17 @@ def _expert_mlp_bwd(tm, kernels, res, dy):
     with jax.named_scope("moe_experts"):
         da = _gmm(dys, w2, g, tm, kernels, transpose_rhs=True)   # [M, F] f32
         d_w2 = _tgmm(a, dys, g, tm, kernels)
-        h1f = h1.astype(f32)
-        if w3 is None:
-            dh1 = (da * 2.0 * jax.nn.relu(h1f)).astype(cd)
-            d_w3 = None
-            dxs = _rows_by_first_t(dh1, w1, g, tm, kernels)
-        else:
-            h3f = h3.astype(f32)
-            sig = jax.nn.sigmoid(h1f)
-            dh3 = (da * h1f * sig).astype(cd)
-            dh1 = (da * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(cd)
+        dh1, dh3 = form.grads(da, h1.astype(f32),
+                              h3.astype(f32) if form.gated else None)
+        dh1 = dh1.astype(cd)
+        if form.gated:
+            dh3 = dh3.astype(cd)
             d_w3 = _first_grad(xs, dh3, w3, g, tm, kernels).astype(w3.dtype)
             dxs = (_rows_by_first_t(dh1, w1, g, tm, kernels)
                    + _rows_by_first_t(dh3, w3, g, tm, kernels))
+        else:
+            d_w3 = None
+            dxs = _rows_by_first_t(dh1, w1, g, tm, kernels)
         d_w1 = _first_grad(xs, dh1, w1, g, tm, kernels)
     with jax.named_scope("moe_grouping"):
         dx = _add_rows(dxs, row_token, N)
@@ -396,12 +448,12 @@ _expert_mlp.defvjp(_expert_mlp_fwd, _expert_mlp_bwd)
 
 
 def grouped_expert_mlp(x, weights, g: Grouping, w1, w3, w2, *, tm: int,
-                       kernels: bool):
+                       kernels: bool, expert_act: str = "gated_silu"):
     """x ``[N, D]``, weights ``[N, k]`` (the router's, of every choice), the
     experts held ``w1``/``w3`` ``[held, D, F]`` and ``w2`` ``[held, F, D]``
-    -> ``[N, D]``: ``sum over the choices held of weight * W_2e(silu(W_1e x)
-    * W_3e x)``, or, where ``w3`` is ``None`` (experts of two matrices),
-    ``weight * W_2e relu(W_1e x)^2``.  Only the buffer's rows move: tokens
+    -> ``[N, D]``: ``sum over the choices held of weight * E_e(x)``, ``E_e``
+    of the form ``expert_act`` names (:data:`EXPERT_ACTS`; ``w3`` is ``None``
+    for a form of two matrices).  Only the buffer's rows move: tokens
     are gathered into rows and rows added back onto tokens, forward and
     backward, and the backward reads the rows the forward wrote (no second
     sort).
@@ -432,34 +484,41 @@ def grouped_expert_mlp(x, weights, g: Grouping, w1, w3, w2, *, tm: int,
     Through the view a first matrix's three products take the shapes
     ``W_2``'s three have, so no kernel configuration is the view's alone;
     ``w2`` has ``D`` minor already and is read as stored."""
-    return _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels)
+    return _expert_mlp(x, weights, w1, w3, w2, g, tm, kernels, expert_act)[0]
 
 
 def expert_layer(x, idx, weights, w1, w3, w2, *, num_experts: int,
-                 first_expert: int, tm: int, kernels: bool):
+                 first_expert: int, tm: int, kernels: bool,
+                 expert_act: str = "gated_silu", sorted_by_expert=None):
     """The part of a dropless expert layer's result that the experts held
     give -> (y ``[N, D]``, assignments per expert held, assignments held
-    that got no row: 0).  ``w3`` is ``None`` for experts of two matrices
-    (:func:`grouped_expert_mlp`).  The row buffer has its usual size when the
-    step's routing fits it and the worst routing's size when not
-    (:func:`buffer_rows`): one ``lax.cond``, the same rows either way."""
+    that got no row: 0, hidden units a ReLU gate made exactly zero: 0 for a
+    form without one).  ``expert_act``: the experts' form
+    (:func:`grouped_expert_mlp`).  ``sorted_by_expert``: ``(counts, order)``
+    of :func:`count_assignments` where the router made them already (a
+    router that runs ahead of its experts, beside another layer).  The row
+    buffer has its usual size when the step's routing fits it and the worst
+    routing's size when not (:func:`buffer_rows`): one ``lax.cond``, the
+    same rows either way."""
     N, k = idx.shape
     held = w1.shape[0]
-    with jax.named_scope("moe_grouping"):
-        counts, order = count_assignments(
-            idx, first_expert=first_expert, held=held)
+    if sorted_by_expert is None:
+        with jax.named_scope("moe_grouping"):
+            sorted_by_expert = count_assignments(
+                idx, first_expert=first_expert, held=held)
+    counts, order = sorted_by_expert
     usual, worst = buffer_rows(N, k, num_experts, held, tm)
 
     def run(rows):
         with jax.named_scope("moe_grouping"):
             g = group_assignments(counts, order, tm=tm, rows=rows)
-        return (grouped_expert_mlp(x, weights, g, w1, w3, w2, tm=tm,
-                                   kernels=kernels), g.uncomputed)
+        return (*_expert_mlp(x, weights, w1, w3, w2, g, tm, kernels,
+                             expert_act), g.uncomputed)
 
     if usual == worst:
-        y, uncomputed = run(worst)
+        y, gate_zero, uncomputed = run(worst)
     else:
         fits = jnp.sum(-(-counts // tm)) * tm <= usual
-        y, uncomputed = jax.lax.cond(fits, lambda: run(usual),
-                                     lambda: run(worst))
-    return y, counts, uncomputed
+        y, gate_zero, uncomputed = jax.lax.cond(
+            fits, lambda: run(usual), lambda: run(worst))
+    return y, counts, uncomputed, gate_zero

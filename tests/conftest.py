@@ -82,6 +82,12 @@ OUTDATED_PINS = {
         "appended ouro-train-b1-t4096 (that the cell holds none of the "
         "eight set-up metrics is held with ``==`` on its whole per-layer "
         "set in tests/benchmark/test_ouro_cell.py)",
+    "tests/benchmark/test_setup_phase_s.py::"
+    "test_only_the_lstm_cell_holds_them[smallthinker-train-b1-t16384]":
+        "its last line pins the list of ALL cells as PR 52 found it; PR 57 "
+        "appended smallthinker-train-b1-t16384 (that the cell holds none of "
+        "the eight set-up metrics is held with ``==`` on its whole "
+        "per-layer set in tests/benchmark/test_smallthinker_cell.py)",
 }
 
 

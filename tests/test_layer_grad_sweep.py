@@ -752,6 +752,19 @@ def case_relu2_expert_mlp_with_a_shared_expert(rng):
                          expert_act="relu2"), feed
 
 
+def case_gated_relu_expert_mlp_with_an_early_router(rng):
+    # ReLU-gated experts whose router reads ANOTHER layer's output (PR 57):
+    # every expert chosen, so a finite difference moves no token; the
+    # router's leaf and the layer in front of it get their gradient through
+    # the router's layer, the experts' through the experts' input; the
+    # shared expert takes the experts' form too
+    xs, feed = _seq(rng)
+    return nn.expert_mlp(_pre_fc(xs), 8, num_experts=3, experts_held=(1, 2),
+                         top_k=3, scoring="softmax", expert_act="gated_relu",
+                         shared_size=6,
+                         router_input=_pre_fc(xs, name="pre_router")), feed
+
+
 def case_lm_head_cost(rng):
     ids, feed = _ids(rng)
     lab = nn.data("next", size=0, is_seq=True, dtype="int32")

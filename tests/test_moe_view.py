@@ -80,7 +80,9 @@ def test_first_matrix_products_match_the_plain_loop(D, F, view, kernels,
     def layer(a):
         return M.grouped_expert_mlp(a["x"], a["wts"], g, a["w1"],
                                     a.get("w3"), a["w2"], tm=TM,
-                                    kernels=kernels)
+                                    kernels=kernels, expert_act=(
+                                        "gated_silu" if "w3" in a
+                                        else "relu2"))
 
     got, got_g = jax.value_and_grad(
         lambda a: jnp.sum(layer(a) * w.astype(np.float32)))(
@@ -116,7 +118,8 @@ def test_the_view_is_counted_when_the_layer_is_traced():
         return a, lambda a: jnp.sum(M.expert_layer(
             a["x"], jnp.asarray(idx), a["wts"], a["w1"], a.get("w3"),
             a["w2"], num_experts=EXPERTS, first_expert=FIRST, tm=TM,
-            kernels=False)[0])
+            kernels=False,
+            expert_act="gated_silu" if gated else "relu2")[0])
 
     def trace(D, F, gated):
         a, f = layer(D, F, gated)

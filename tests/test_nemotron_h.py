@@ -459,7 +459,8 @@ def test_grouped_kernels_with_a_masked_edge_match_the_masked_loop(gated):
         def f(x, wts, w1, w2, *w3):
             y = M.expert_layer(x, idx, wts, w1, w3[0] if w3 else None, w2,
                                num_experts=E, first_expert=0, tm=tm,
-                               kernels=kernels)[0]
+                               kernels=kernels, expert_act=(
+                                   "gated_silu" if gated else "relu2"))[0]
             return jnp.sum(jnp.square(y))
         return f
 

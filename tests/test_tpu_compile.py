@@ -820,7 +820,8 @@ def test_grouped_expert_products_at_a_width_of_1856(one_chip, on_tpu):
 
     def loss(x, w, w1, w2, idx):
         return M.expert_layer(x, idx, w, w1, None, w2, num_experts=128,
-                              first_expert=0, tm=tm, kernels=True)[0].sum()
+                              first_expert=0, tm=tm, kernels=True,
+                              expert_act="relu2")[0].sum()
 
     s = lambda *shape: _struct(one_chip, shape)  # noqa: E731
     args = [s(N, c["D"]), s(N, k), s(c["held"], c["D"], c["F"]),
@@ -861,7 +862,7 @@ def _expert_layer_step(one_chip, D, F):
     def layer(p, x, w, idx):
         return M.expert_layer(x, idx, w, p["w1"], None, p["w2"],
                               num_experts=128, first_expert=0, tm=tm,
-                              kernels=True)[0]
+                              kernels=True, expert_act="relu2")[0]
 
     def step(p, state, x, w, idx):
         value, grads = jax.value_and_grad(
@@ -1461,3 +1462,48 @@ def test_ouro_cell_step_fits_the_chip(one_chip, on_tpu):
     entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", text, re.S).group(1)
     assert len(re.findall(r" parameter\(\d+\)", entry)) == 3 * 71 + 1 + 4
     assert _held_bytes(compiled) < 14.0e9, _held_bytes(compiled)
+
+
+def test_smallthinker_cell_step_fits_the_chip(one_chip, on_tpu):
+    """PR 57.  The cell ``smallthinker-train-b1-t16384``'s whole step compiled
+    for the described v5e from shapes alone: the window kernels ONCE a window
+    layer and the unwindowed flash kernels once in the full layer (their
+    results are kept across the recomputation block), at 28 query heads over
+    4 key-value heads (a group of seven) and blocks of 1024, the band FIVE
+    blocks a block of queries where Laguna-XS.2's is two; the grouped
+    products beside them; no rotary pass in ``attn0`` (the full layer takes
+    no positions) and q's and k's in each window layer, a call a pass; the
+    router's layer ahead of its layer's attention in the program's order;
+    and the compiler's own count of arguments, results and temporaries under
+    the chip's 16.9 GB (the count is in PERF.md section 4)."""
+    from paddle_tpu.ops import decoder_block as DB
+    from paddle_tpu.ops import pallas_kernels as PK
+
+    assert DB.attention_kernel_blocks(16384, 128, 28, 4) == (1024, 1024)
+    assert DB.attention_kernel_blocks(16384, 128, 28, 4, window=4096) == (
+        1024, 1024)
+    assert PK.flash_band_blocks(16384, 1024, 1024, 4096) == 5
+    assert PK.flash_band_blocks(16384, 512, 512, 512) == 2      # Laguna's
+    compiled = _cell_step(one_chip, "smallthinker-train-b1-t16384")
+    text = compiled.as_text()
+    by_kernel = _kernel_calls(text)
+    assert {k: by_kernel.get(k) for k in (
+        "flash_attn_win_fwd", "flash_attn_win_bwd", "flash_attn_fwd",
+        "flash_attn_bwd")} == {
+            "flash_attn_win_fwd": 3, "flash_attn_win_bwd": 3,
+            "flash_attn_fwd": 1, "flash_attn_bwd": 1}
+    assert "moe_gmm" in by_kernel and "moe_tgmm" in by_kernel
+    # q and k, forward, recomputed and backward, in three window layers
+    assert by_kernel.get("rotary_turn") == 3 * 2 * 3
+    turns = re.findall(r'op_name="([^"]*/rotary_turn/[^"]*)"', text)
+    assert turns and not [t for t in turns if "attn0" in t]
+    assert all(re.search(r"attn[123]\)*/rotary/", t) for t in turns)
+    # the early router's own layer, its sort inside it
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/moe0/moe_routing/" in n for n in names)
+    assert any("/moe3/moe_routing/moe_grouping/" in n for n in names)
+    m = compiled.memory_analysis()
+    assert 3 * 4 * 370_547_200 < m.argument_size_in_bytes     # p, m, v
+    print("smallthinker cell step: held bytes", _held_bytes(compiled),
+          "argument", m.argument_size_in_bytes, "temp", m.temp_size_in_bytes)
+    assert _held_bytes(compiled) < 16.3e9, _held_bytes(compiled)

@@ -10,6 +10,7 @@ import ast
 import contextlib
 import glob
 import os
+import re
 
 import numpy as np
 import pytest
@@ -260,6 +261,74 @@ def test_laguna_step_holds_its_layers_scopes():
     turned = {s for s in stacks if "rotary" in _scope_names({s})}
     assert turned == {f"{way}/rotary" for i in range(3) for way in (
         f"jvp(attn{i})", f"rematted_computation/attn{i}", f"attn{i}")}
+
+
+def test_smallthinker_step_holds_its_layers_scopes():
+    """The scopes the SmallThinker cell's per-layer metrics read (PR 57):
+    ``moe<i>/moe_routing`` is the early router's OWN layer (the sort by
+    expert under ``moe_grouping`` inside it), traced AHEAD of ``attn<i>``;
+    ``attn_core`` under the full layer alone, which holds no ``rotary``;
+    ``attn_window`` and ``rotary`` under the window layers alone;
+    ``moe_experts`` inside ``moe<i>``; and the counter
+    ``moe_gate_zero_units`` an expert layer beside the older two."""
+    from paddle_tpu.models import smallthinker_net
+
+    nn.reset_naming()
+    cost, extras = smallthinker_net(
+        50, hidden_size=16, num_attention_heads=7, num_key_value_heads=1,
+        head_dim=4, sliding_window_layout=[0, 1], rope_layout=[0, 1],
+        sliding_window_size=3, rope_theta=1.5e6, moe_ffn_hidden_size=8,
+        moe_num_primary_experts=4, moe_num_active_primary_experts=2)
+    counters = [(e.name, e.meta["obs_counter"]["name"]) for e in extras]
+    assert counters == [
+        ("moe0_load", "moe_assignments"),
+        ("moe0_uncomputed", "moe_uncomputed_assignments"),
+        ("moe0_gate_zero", "moe_gate_zero_units"),
+        ("attn1_pairs", "window_attn_pairs"),
+        ("moe1_load", "moe_assignments"),
+        ("moe1_uncomputed", "moe_uncomputed_assignments"),
+        ("moe1_gate_zero", "moe_gate_zero_units")]
+    topo = nn.Topology([cost] + extras)
+    params, _ = topo.init(jax.random.PRNGKey(0))
+    ids = (np.ones((2, 6), np.int32), np.full((2,), 6, np.int32))
+    feed = {"tokens": ids, "next_tokens": ids}
+    fwd = jax.make_jaxpr(lambda p: topo.apply(
+        p, {}, feed, train=True)[0]["cost"].value)(params)
+    grad = jax.make_jaxpr(jax.grad(lambda p: topo.apply(
+        p, {}, feed, train=True)[0]["cost"].value))(params)
+    stacks = _name_stacks(grad.jaxpr)
+    names = _scope_names(stacks)
+    assert {"attn0", "attn1", "attn_core", "attn_window", "rotary", "moe0",
+            "moe1", "moe_routing", "moe_grouping", "moe_experts",
+            "moe_combine", "norm_op0", "norm_ffn1", "norm_out",
+            "cost"} <= names
+    assert not {"mlp0", "moe_shared", "indexer", "norm0"} & names
+    assert not [s for s in stacks if "attn_window" in s and "attn1" not in s]
+    assert not [s for s in stacks if "attn_core" in s and "attn0" not in s]
+    assert not [s for s in stacks if "rotary" in s and "attn1" not in s]
+    # the router's layer: its scope is moe<i>/moe_routing, the sort inside
+    routed = [s for s in stacks if "moe_routing" in _scope_names({s})]
+    assert routed and all(re.search(r"moe[01]\)*/moe_routing", s)
+                          for s in routed)
+    assert any(re.search(r"moe0\)*/moe_routing\)*/moe_grouping", s)
+               for s in routed)
+    # ... and in the program's order it stands before its layer's attention
+    order = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            order.append(str(eqn.source_info.name_stack))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(fwd.jaxpr)
+    first = lambda what: next(i for i, s in enumerate(order)   # noqa: E731
+                              if what in s)
+    assert first("moe0/moe_routing") < first("attn0")
+    assert first("attn0") < first("moe1/moe_routing") < first("attn1")
 
 
 def test_ouro_step_holds_its_passes_and_exits_scopes():
